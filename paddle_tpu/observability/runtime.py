@@ -65,11 +65,8 @@ class StepStats:
             return float(override)
         if not self._peak_resolved:
             self._peak_resolved = True
-            try:
-                from paddle_tpu.utils import flops as flops_mod
-                self._peak_flops = flops_mod.device_peak_flops()
-            except Exception:
-                self._peak_flops = None
+            from paddle_tpu.utils import flops as flops_mod
+            self._peak_flops = flops_mod.device_peak_flops()
         return self._peak_flops
 
     # -- recording -------------------------------------------------------
@@ -193,11 +190,8 @@ def mfu_ratio(flops_per_step: Optional[float], step_time_s: float,
     from paddle_tpu import flags
     peak = float(flags.get("peak_flops")) or None
     if peak is None:
-        try:
-            from paddle_tpu.utils import flops as flops_mod
-            peak = flops_mod.device_peak_flops(device)
-        except Exception:
-            peak = None
+        from paddle_tpu.utils import flops as flops_mod
+        peak = flops_mod.device_peak_flops(device)
     if not peak:
         return None
     return flops_per_step / step_time_s / peak

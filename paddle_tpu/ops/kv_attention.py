@@ -224,18 +224,19 @@ def _kv_quant(rows):
     return q.astype(jnp.int8), scale.astype(jnp.float32)
 
 
-def _paged_gather(flat, scales, rows, h, dt):
+def _paged_gather(flat, scales, rows, h, dt, mesh=None):
     """Gather K/V rows through page-table row indices: flat [R, H, D]
     storage (fp32 | bf16 | int8 codes), scales [R, H] fp32 or None,
     rows [N] int32 (sentinel rows >= R clamp to the last pool row —
     their contribution is exactly zeroed by the attention mask).
     Returns [N, H, D] in the compute dtype. Tier selection per
     ops/pallas: the scalar-prefetch DMA kernel on aligned TPU shapes
-    (ops/pallas/paged_attention.py), the jnp refer path otherwise."""
+    (ops/pallas/paged_attention.py), the jnp refer path otherwise
+    (``mesh``: see ``kernel_enabled``)."""
     r, _, dk = flat.shape
     idx = jnp.minimum(rows, r - 1)
     from paddle_tpu.ops import pallas as _plk
-    if _plk.kernel_enabled(128, h * dk):
+    if _plk.kernel_enabled(128, h * dk, mesh=mesh):
         from paddle_tpu.ops.pallas import paged_attention as _pk
         interp = _plk.interpret_mode()
         if scales is not None:
@@ -371,8 +372,10 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     # gather every slot's logical cache through its table row
     rows = (table[:, :, None] * ps
             + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(-1)
-    kk = _paged_gather(flat_k, fks, rows, h, dt).reshape(b, s_len, h, d)
-    vv = _paged_gather(flat_v, fvs, rows, h, dt).reshape(b, s_len, h, d)
+    kk = _paged_gather(flat_k, fks, rows, h, dt,
+                       ctx.mesh).reshape(b, s_len, h, d)
+    vv = _paged_gather(flat_v, fvs, rows, h, dt,
+                       ctx.mesh).reshape(b, s_len, h, d)
 
     s = jax.lax.dot_general(q, kk, (((3,), (3,)), ((0, 2), (0, 2))),
                             preferred_element_type=jnp.float32)
@@ -516,8 +519,10 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
 
     rows = (table[:, :, None] * ps
             + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(-1)
-    kk = _paged_gather(flat_k, fks, rows, h, dt).reshape(b, s_len, h, d)
-    vv = _paged_gather(flat_v, fvs, rows, h, dt).reshape(b, s_len, h, d)
+    kk = _paged_gather(flat_k, fks, rows, h, dt,
+                       ctx.mesh).reshape(b, s_len, h, d)
+    vv = _paged_gather(flat_v, fvs, rows, h, dt,
+                       ctx.mesh).reshape(b, s_len, h, d)
 
     s = jax.lax.dot_general(q, kk, (((3,), (3,)), ((0, 2), (0, 2))),
                             preferred_element_type=jnp.float32)

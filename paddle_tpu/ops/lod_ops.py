@@ -212,7 +212,7 @@ def _fused_embedding_seq_pool(ctx, ins, attrs):
     # refer/interpreter tier (and the only tier off-TPU).
     if w.ndim == 2 and ids.ndim == 2:
         from paddle_tpu.ops import pallas as pk
-        if pk.kernel_enabled(128, w.shape[1]):
+        if pk.kernel_enabled(128, w.shape[1], mesh=ctx.mesh):
             return single(pk.fused_embed_seq_pool(w, ids, lens,
                                                   pk.interpret_mode()))
     emb = w[ids]                                   # [B, T, D]

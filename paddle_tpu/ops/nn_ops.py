@@ -627,7 +627,8 @@ def _fused_linear_ce(ctx, ins, attrs):
         x, w = _amp_cast(attrs, x, w)
     n, d = x.shape
     v = w.shape[1]
-    use_kernel = (pk.kernel_enabled(128, d) and fce.supported(n, d, v)) \
+    use_kernel = (pk.kernel_enabled(128, d, mesh=ctx.mesh)
+                  and fce.supported(n, d, v)) \
         or (pk.interpret_mode()
             and __import__("os").environ.get(
                 "PADDLE_TPU_FORCE_PALLAS", "0") == "1")
@@ -820,7 +821,7 @@ def _fused_attention_block(ctx, ins, attrs):
     m = x_q.shape[-1]
     d = m // h
     from paddle_tpu.ops import pallas as pk
-    if pk.kernel_enabled(128, d):
+    if pk.kernel_enabled(128, d, mesh=mesh):
         eng = pk.flash_engage(t_q, t_k, d, causal)
         if eng:
             bq, bk = eng
@@ -904,5 +905,5 @@ def _attention(ctx, ins, attrs):
     else:
         out = ra.full_attention(q, k, v, causal=causal, scale=scale,
                                 bias=bias, dropout_p=dropout_p, seed=seed,
-                                layout=layout)
+                                layout=layout, mesh=mesh)
     return single(out)

@@ -25,10 +25,9 @@ import jax
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialise raises here: a chip that cannot
+    # be reached must not read as "not on TPU" and turn every kernel off
+    return jax.default_backend() == "tpu"
 
 
 def kernels_disabled() -> bool:
@@ -43,12 +42,22 @@ def interpret_mode() -> bool:
     return not on_tpu()
 
 
-def kernel_enabled(min_align: int = 128, *dims) -> bool:
+def kernel_enabled(min_align: int = 128, *dims, mesh=None) -> bool:
     """Pallas path is worth it only when the lane dims align to hardware
-    tiles; otherwise the refer (jnp) tier wins."""
+    tiles; otherwise the refer (jnp) tier wins.
+
+    ``mesh``: the mesh the calling emitter lowers under (``ctx.mesh``).
+    XLA cannot partition a Mosaic call ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    under a mesh of more than one device an emitter's kernel is off and
+    the refer tier — which XLA does partition — runs. Kernels already
+    inside a shard_map region (parallel/ring_attention.py) see per-shard
+    arrays and pass no mesh."""
     if kernels_disabled():
         return False
     if not on_tpu():
+        return False
+    if mesh is not None and mesh.size > 1:
         return False
     return all(d % min_align == 0 for d in dims)
 

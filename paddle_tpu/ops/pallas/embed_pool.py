@@ -30,14 +30,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.pallas.embed_cache import pad_to, sublane_tile
+
 _BB = 8             # batch rows per grid step (fp32 sublane tile)
 
 
-def _embed_pool_kernel(ids_ref, lens_ref, w_hbm, o_ref, row_ref, sem_ref,
-                       *, t_total):
+def _embed_pool_kernel(ids_ref, lens_ref, w_hbm, o_ref, grp_ref, sem_ref,
+                       *, t_total, g):
     """ids_ref [Bp, T] / lens_ref [Bp] in SMEM (scalar prefetch);
-    w_hbm [V, D] stays in HBM; o_ref [BB, D] output tile in VMEM;
-    row_ref [2, 1, D] VMEM double buffer; sem_ref DMA semaphores (2,)."""
+    w_hbm [Vp, D] stays in HBM; o_ref [BB, D] output tile in VMEM;
+    grp_ref [2, g, D] VMEM double buffer; sem_ref DMA semaphores (2,).
+    Mosaic slices an HBM ref only at whole sublane tiles, so each DMA
+    carries the aligned g-row group holding the id'd row and the row is
+    picked out by mask in VMEM (see embed_cache.py)."""
     i = pl.program_id(0)
     d = o_ref.shape[-1]
 
@@ -45,27 +50,31 @@ def _embed_pool_kernel(ids_ref, lens_ref, w_hbm, o_ref, row_ref, sem_ref,
         b = i * _BB + j
         n = lens_ref[b]
 
-        def row_dma(slot, t):
+        def group_dma(slot, t):
+            start = pl.multiple_of((ids_ref[b, t] // g) * g, g)
             return pltpu.make_async_copy(
-                w_hbm.at[pl.ds(ids_ref[b, t], 1), :],
-                row_ref.at[slot], sem_ref.at[slot])
+                w_hbm.at[pl.ds(start, g), :],
+                grp_ref.at[slot], sem_ref.at[slot])
 
-        row_dma(0, 0).start()
+        group_dma(0, 0).start()
 
         def body(t, acc):
             slot = jax.lax.rem(t, 2)
 
             @pl.when(t + 1 < t_total)
             def _():
-                row_dma(jax.lax.rem(t + 1, 2), t + 1).start()
+                group_dma(jax.lax.rem(t + 1, 2), t + 1).start()
 
-            row_dma(slot, t).wait()
-            row = row_ref[slot][0].astype(jnp.float32)      # [D]
-            return acc + jnp.where(t < n, row, 0.0)
+            group_dma(slot, t).wait()
+            grp = grp_ref[slot].astype(jnp.float32)         # [g, D]
+            sub = jax.lax.broadcasted_iota(jnp.int32, grp.shape, 0)
+            keep = (sub == ids_ref[b, t] % g) & (t < n)
+            return acc + jnp.sum(jnp.where(keep, grp, 0.0), axis=0,
+                                 keepdims=True)
 
         acc = jax.lax.fori_loop(0, t_total, body,
-                                jnp.zeros((d,), jnp.float32))
-        o_ref[j] = acc.astype(o_ref.dtype)
+                                jnp.zeros((1, d), jnp.float32))
+        o_ref[pl.ds(j, 1), :] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -87,22 +96,23 @@ def _embed_pool_impl(w, ids, lens, interpret=False):
         ids = jnp.concatenate([ids, jnp.zeros((pad, t), ids.dtype)])
         lens = jnp.concatenate([lens, jnp.zeros((pad,), lens.dtype)])
     bp = ids.shape[0]
+    g = sublane_tile(w.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # ids + lens live in SMEM
         grid=(bp // _BB,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],   # W stays in HBM
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],   # W stays in HBM
         out_specs=pl.BlockSpec((_BB, d), lambda i, ids, lens: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, 1, d), w.dtype),
+            pltpu.VMEM((2, g, d), w.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_embed_pool_kernel, t_total=t),
+        functools.partial(_embed_pool_kernel, t_total=t, g=g),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bp, d), w.dtype),
         interpret=interpret,
-    )(ids, lens, w)
+    )(ids, lens, pad_to(w, g))
     return out[:b]
 
 

@@ -1,26 +1,26 @@
-"""Page-table K/V gather kernels for the paged KV cache (Pallas TPU;
-ISSUE 17 tentpole).
+"""Page-table K/V gather for the paged KV cache (Pallas TPU; ISSUE 17
+tentpole).
 
 The paged decode attention reads each slot's K/V through its page
 table: logical cache position ``j`` of slot ``b`` lives at flat pool
 row ``table[b, j // page_size] * page_size + j % page_size`` of the
 ``[n_pages * page_size, H * D]`` pool view. The row-index vector is
 computed in-graph from the (static-shape) page-table feed and rides
-into the kernel via SCALAR PREFETCH — same construction as
-``embed_cache.py``: indices in SMEM, the pool resident in HBM
-(``pltpu.ANY``), each row moved HBM->VMEM with ``make_async_copy`` on a
-2-slot rotation so the next row's DMA overlaps the current one, fp32
-sublane tile ``_BB = 8`` as the grid granularity.
+into the kernel via SCALAR PREFETCH — the row-gather kernel of
+``embed_cache.py``, whose module docstring has the DMA construction
+(and why each DMA carries the row's whole sublane-tile group).
 
-- :func:`gather_rows` — ``pool[rows] -> [K, D]``, rows clamped into
-  range (page-table sentinel entries — unallocated span, inactive
-  slots — point one past the pool; their gathered rows are garbage the
-  attention mask zeroes exactly).
-- :func:`gather_rows_dequant` — the codec read: int8 code rows plus
-  one fp32 scale per (position, head) row gathered in the SAME grid
-  step (two interleaved DMA rotations) and dequantized in VMEM before
-  the output tile is written — ``FLAGS_kv_cache_codec=int8`` never
-  materializes a full-pool fp32 copy.
+- :func:`gather_rows` — ``pool[rows] -> [K, D]`` for fp32 / bf16 / int8
+  storage, rows clamped into range (page-table sentinel entries —
+  unallocated span, inactive slots — point one past the pool; their
+  gathered rows are garbage the attention mask zeroes exactly).
+- :func:`gather_rows_dequant` — the codec read: int8 code rows gathered
+  by the kernel and multiplied, in VMEM before the output tile is
+  written, by their fp32 per-(position, head) scales —
+  ``FLAGS_kv_cache_codec=int8`` never materializes a full-pool fp32
+  copy. Mosaic has no in-kernel ``[D] -> [H, Dk]`` reshape, so the
+  gathered ``[K, H]`` scales are broadcast to ``[K, D]`` outside the
+  ``pallas_call`` and enter as a regular VMEM tile.
 
 Page WRITES (one row per decode step per slot, a whole prompt per
 prefill) stay on the jnp scatter-with-drop path in
@@ -28,113 +28,16 @@ prefill) stay on the jnp scatter-with-drop path in
 ``proglint --memory`` audit gates, and XLA already emits them as an
 in-place dynamic-update per row.
 
-Both kernels run under ``interpret=True`` on the CPU test backend
+Both run under ``interpret=True`` on the CPU test backend
 (tests/test_pallas_kernels.py discipline; tier selection via
 ``ops.pallas.kernel_enabled``).
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-_BB = 8             # rows per grid step (fp32 sublane tile)
-
-
-def _gather_kernel(rows_ref, pool_hbm, o_ref, row_ref, sem_ref):
-    """rows_ref [Kp] in SMEM; pool_hbm [R, D] in HBM; o_ref [BB, D]
-    output tile in VMEM; row_ref [2, 1, D] double buffer."""
-    i = pl.program_id(0)
-    cap = pool_hbm.shape[0]
-
-    def row_dma(buf, j):
-        idx = jnp.minimum(rows_ref[i * _BB + j], cap - 1)
-        return pltpu.make_async_copy(
-            pool_hbm.at[pl.ds(idx, 1), :],
-            row_ref.at[buf], sem_ref.at[buf])
-
-    row_dma(0, 0).start()
-    for j in range(_BB):                        # static sublane unroll
-        if j + 1 < _BB:
-            row_dma((j + 1) % 2, j + 1).start()
-        row_dma(j % 2, j).wait()
-        o_ref[j] = row_ref[j % 2][0]
-
-
-def _pad_rows(rows):
-    k = rows.shape[0]
-    rows = rows.astype(jnp.int32)
-    kp = -(-k // _BB) * _BB
-    if kp != k:
-        rows = jnp.concatenate(
-            [rows, jnp.zeros((kp - k,), rows.dtype)])
-    return rows, k, kp
-
-
-def gather_rows(pool, rows, interpret: bool = False):
-    """pool [R, D], rows [K] int -> [K, D] = pool[rows] (rows clamped
-    into range — sentinel page-table entries read the last pool row,
-    whose contribution the attention mask zeroes exactly)."""
-    r, d = pool.shape
-    rows, k, kp = _pad_rows(rows)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,          # row ids live in SMEM
-        grid=(kp // _BB,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],  # pool in HBM
-        out_specs=pl.BlockSpec((_BB, d), lambda i, rows: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, 1, d), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    out = pl.pallas_call(
-        _gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((kp, d), pool.dtype),
-        interpret=interpret,
-    )(rows, pool)
-    return out[:k]
-
-
-def _gather_dequant_kernel(rows_ref, pool_hbm, scale_hbm, o_ref,
-                           row_ref, scl_ref, sem_v, sem_s, *, heads):
-    """rows_ref [Kp] in SMEM; pool_hbm [R, D] int8 codes and scale_hbm
-    [R, H] fp32 scales in HBM; o_ref [BB, D] fp32 tile. Code and scale
-    rows ride two interleaved 2-slot DMA rotations; dequantization
-    (code * per-head scale) happens in VMEM between wait and store."""
-    i = pl.program_id(0)
-    cap = pool_hbm.shape[0]
-
-    def val_dma(buf, j):
-        idx = jnp.minimum(rows_ref[i * _BB + j], cap - 1)
-        return pltpu.make_async_copy(
-            pool_hbm.at[pl.ds(idx, 1), :],
-            row_ref.at[buf], sem_v.at[buf])
-
-    def scl_dma(buf, j):
-        idx = jnp.minimum(rows_ref[i * _BB + j], cap - 1)
-        return pltpu.make_async_copy(
-            scale_hbm.at[pl.ds(idx, 1), :],
-            scl_ref.at[buf], sem_s.at[buf])
-
-    val_dma(0, 0).start()
-    scl_dma(0, 0).start()
-    d = pool_hbm.shape[1]
-    dk = d // heads
-    for j in range(_BB):                        # static sublane unroll
-        if j + 1 < _BB:
-            val_dma((j + 1) % 2, j + 1).start()
-            scl_dma((j + 1) % 2, j + 1).start()
-        val_dma(j % 2, j).wait()
-        scl_dma(j % 2, j).wait()
-        codes = row_ref[j % 2][0].astype(jnp.float32)       # [D]
-        scale = scl_ref[j % 2][0]                           # [H]
-        o_ref[j] = (codes.reshape(heads, dk)
-                    * scale[:, None]).reshape(d)
+from paddle_tpu.ops.pallas.embed_cache import gather_rows  # noqa: F401
 
 
 def gather_rows_dequant(pool, scales, rows, heads: int,
@@ -145,24 +48,7 @@ def gather_rows_dequant(pool, scales, rows, heads: int,
     r, d = pool.shape
     if d % heads:
         raise ValueError(f"row width {d} not divisible by heads {heads}")
-    rows, k, kp = _pad_rows(rows)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(kp // _BB,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),   # codes in HBM
-                  pl.BlockSpec(memory_space=pltpu.ANY)],  # scales in HBM
-        out_specs=pl.BlockSpec((_BB, d), lambda i, rows: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, 1, d), pool.dtype),
-            pltpu.VMEM((2, 1, scales.shape[1]), scales.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_gather_dequant_kernel, heads=heads),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((kp, d), jnp.float32),
-        interpret=interpret,
-    )(rows, pool, scales)
-    return out[:k]
+    idx = jnp.clip(rows.astype(jnp.int32), 0, r - 1)
+    row_scales = jnp.repeat(jnp.take(scales, idx, axis=0), d // heads,
+                            axis=1)                     # [K, D]
+    return gather_rows(pool, idx, scales=row_scales, interpret=interpret)

@@ -103,7 +103,7 @@ def _dynamic_lstm(ctx, ins, attrs):
         # VMEM budget (the backward is the hungriest: w + the dw
         # accumulator + double-buffered seq blocks); H=512/B=64 fits
         vmem_bytes = (2 * H * 4 * H + 4 * B * 4 * H + 10 * B * H) * 4
-        if (pk.kernel_enabled(128, H) and B % 8 == 0
+        if (pk.kernel_enabled(128, H, mesh=ctx.mesh) and B % 8 == 0
                 and vmem_bytes <= 12 * 1024 * 1024):
             if use_peepholes:
                 peep_arr = jnp.concatenate(
@@ -192,7 +192,7 @@ def _dynamic_gru(ctx, ins, attrs):
             and attrs.get("activation", "tanh") == "tanh"):
         from paddle_tpu.ops import pallas as pk
         vmem_bytes = (2 * H * 3 * H + 4 * B * 3 * H + 8 * B * H) * 4
-        if (pk.kernel_enabled(128, H) and B % 8 == 0
+        if (pk.kernel_enabled(128, H, mesh=ctx.mesh) and B % 8 == 0
                 and vmem_bytes <= 12 * 1024 * 1024):
             sl = (seq_lens.reshape(-1, 1).astype(jnp.int32)
                   if seq_lens is not None

@@ -68,7 +68,7 @@ def _sequence_pool(ctx, ins, attrs):
     # budget — beyond that the refer tier's XLA pipeline wins anyway.
     if (x.ndim == 3 and pooltype in ("SUM", "AVERAGE", "SQRT")):
         from paddle_tpu.ops import pallas as pk
-        if (pk.kernel_enabled(128, x.shape[2])
+        if (pk.kernel_enabled(128, x.shape[2], mesh=ctx.mesh)
                 and 8 * T * x.shape[2] * 4 <= 4 * 1024 * 1024):
             lens_ = _lens_or_full(seq_lens, B, T)
             return {"Out": [pk.masked_seqpool(x, lens_, pooltype, False)]}

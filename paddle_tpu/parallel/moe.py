@@ -23,10 +23,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map            # jax >= 0.8
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _shard_moe(x, gate_w, w1, b1, w2, b2, *, ep_axis, n_experts,

@@ -23,10 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map            # jax >= 0.8
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def stack_stage_params(per_stage_params):

@@ -227,12 +227,7 @@ def sp_attention(q, k, v, mesh, sp_axis: str, causal: bool = False,
     does not force a reshard of activations that are already dp×tp
     partitioned (both dims are embarrassingly parallel here)."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map            # jax >= 0.8
-        _relax_kw = "check_vma"
-    except ImportError:                      # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-        _relax_kw = "check_rep"              # pre-0.8 name of the checker
+    from jax import shard_map
 
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
@@ -265,7 +260,7 @@ def sp_attention(q, k, v, mesh, sp_axis: str, causal: bool = False,
     sp_size = mesh.shape[sp_axis]
     uses_flash = impl == "ring" and _use_flash_blocks(
         q.shape[2] // sp_size, k.shape[2] // sp_size, q.shape[3])
-    kwargs = {_relax_kw: False} if uses_flash else {}
+    kwargs = {"check_vma": False} if uses_flash else {}
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
     seed = jnp.asarray(seed, jnp.int32).reshape(1)
@@ -277,7 +272,8 @@ def sp_attention(q, k, v, mesh, sp_axis: str, causal: bool = False,
 
 
 def full_attention(q, k, v, causal: bool = False, scale=None, bias=None,
-                   dropout_p: float = 0.0, seed=None, layout: str = "bhtd"):
+                   dropout_p: float = 0.0, seed=None, layout: str = "bhtd",
+                   mesh=None):
     """Single-device attention ([B, H, Tq, D] x [B, H, Tk, D]); also the
     emitter fallback when no sp axis is configured. On TPU with aligned
     shapes this routes to the Pallas flash kernel (ops/pallas/ — the jit-
@@ -309,7 +305,7 @@ def full_attention(q, k, v, causal: bool = False, scale=None, bias=None,
         tk = k.shape[2]
     if bias is None:
         from paddle_tpu.ops import pallas as pk
-        if pk.kernel_enabled(128, d) and tq >= 2048:
+        if pk.kernel_enabled(128, d, mesh=mesh) and tq >= 2048:
             bq, bk = pk.pick_blocks(tq, tk)
             if bq and bk:
                 if bthd:

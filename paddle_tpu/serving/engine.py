@@ -75,8 +75,8 @@ def _sha256_file(path: str) -> str:
 
 def save_executable(path: str, lowered) -> bool:
     """Serialize a lowered+compiled executable with a sha256 sidecar.
-    Returns False (and writes nothing) when the backend does not
-    round-trip executable serialization."""
+    Returns False, with a warning that names the error, when the
+    backend does not round-trip executable serialization."""
     try:
         from jax.experimental import serialize_executable as se
         payload = se.serialize(lowered.compile())
@@ -85,7 +85,10 @@ def save_executable(path: str, lowered) -> bool:
         with open(path + ".sha256", "w") as f:
             f.write(_sha256_file(path))
         return True
-    except Exception:
+    except Exception as e:
+        import warnings
+        warnings.warn(f"AOT executable {path} was not persisted: "
+                      f"{type(e).__name__}: {e}", stacklevel=2)
         return False
 
 
@@ -110,7 +113,10 @@ def load_executable(path: str):
         with open(path, "rb") as f:
             payload = pickle.load(f)
         return se.deserialize_and_load(*payload)
-    except Exception:
+    except Exception as e:
+        import warnings
+        warnings.warn(f"AOT executable {path} did not load: "
+                      f"{type(e).__name__}: {e}", stacklevel=2)
         return None
 
 
@@ -325,10 +331,23 @@ class GenerativeModel:
             if aot is not None:
                 try:
                     fetches, new_state = aot(*args)
-                except Exception:
-                    # backend mis-mapped the deserialized executable:
-                    # degrade to the (warmed) compile path for the rest
-                    # of the run
+                except Exception as e:
+                    # an OOM would only repeat on the jit path (and its
+                    # forensics belong to the first failure)
+                    if obs_memory.is_oom_error(e):
+                        raise
+                    # backend mis-mapped the deserialized executable
+                    # (XLA:CPU under forced device counts does): degrade
+                    # to the (warmed) compile path for the rest of the
+                    # run — counted and announced, never silent
+                    import warnings
+                    warnings.warn(
+                        f"AOT executable {aot_key} of model "
+                        f"{self.name!r} failed on this backend "
+                        f"({type(e).__name__}); falling back to the "
+                        f"compile path", stacklevel=2)
+                    smetrics.AOT_FALLBACK.labels(
+                        model=self.name, cause="backend_error").inc()
                     self._aot.pop(aot_key, None)
                     fetches, new_state = cb.fn(*args)
             else:
@@ -432,12 +451,9 @@ class GenerativeModel:
         else:
             cb = self._cb_decode
             feeds = self._decode_feeds(bucket)
-        try:
-            lowered = cb.fn.lower(*self._args(cb, feeds))
-            save_executable(self._aot_path(dirname, kind, bucket, p_len),
-                            lowered)
-        except Exception:
-            pass
+        lowered = cb.fn.lower(*self._args(cb, feeds))
+        save_executable(self._aot_path(dirname, kind, bucket, p_len),
+                        lowered)
 
     def load_compiled(self, dirname: str) -> int:
         """Load every persisted executable matching this program
@@ -942,11 +958,8 @@ class SlotGenerativeModel:
             cb, feeds = self._cb_verify, self._verify_feeds()
         else:
             cb, feeds = self._cb_decode, self._decode_feeds()
-        try:
-            lowered = cb.fn.lower(*self._args(cb, feeds))
-            save_executable(self._aot_path(dirname, kind, p_len), lowered)
-        except Exception:
-            pass
+        lowered = cb.fn.lower(*self._args(cb, feeds))
+        save_executable(self._aot_path(dirname, kind, p_len), lowered)
 
     def load_compiled(self, dirname: str) -> int:
         n = 0

@@ -112,6 +112,10 @@ def main(argv=None) -> int:
     if not flags.get("trace_role"):
         flags.set("trace_role", "replica")
 
+    # a respawned replica finds what its predecessor compiled
+    from paddle_tpu.utils import chip
+    chip.compile_cache_dir()
+
     from paddle_tpu.serving.server import ModelServer
     # oom_exit (default True): a dispatch OOM kills this process
     # WITHOUT acking errors — the supervising router finds the memdump,

@@ -332,13 +332,16 @@ class Router:
             os.path.join(self._workdir, f"replica{r.index}-flight"))
         for k, v in (spec.get("env") or {}).items():
             env[k] = str(v)                # per-slot spec env wins
-        r.proc = subprocess.Popen(
-            [sys.executable, "-m", "paddle_tpu.serving.replica",
-             "--spec-json", json.dumps(spec),
-             "--endpoint-file", r.endpoint_file,
-             "--replica-id", str(r.index)],
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-            env=env)
+        # the child's output is the only record of why it could not
+        # start (a replica that cannot get the chip says so here)
+        with open(os.path.join(self._workdir, f"replica{r.index}.log"),
+                  "ab") as log:
+            r.proc = subprocess.Popen(
+                [sys.executable, "-m", "paddle_tpu.serving.replica",
+                 "--spec-json", json.dumps(spec),
+                 "--endpoint-file", r.endpoint_file,
+                 "--replica-id", str(r.index)],
+                stdout=log, stderr=subprocess.STDOUT, env=env)
         with r.lock:
             r.endpoint = None
             r.gen += 1

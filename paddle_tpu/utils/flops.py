@@ -443,15 +443,29 @@ _PEAK_HBM = {
 }
 
 
-def device_peak_flops(device=None) -> Optional[float]:
-    """Peak bf16 FLOP/s of the attached chip, or None off-TPU."""
+def _spec_sheet(table, what: str, device) -> Optional[float]:
+    """``table[device_kind]`` of ``device`` (default: the first
+    attached device). None on the CPU platform, which has no spec
+    sheet; an accelerator the table does not know is an error — a
+    utilization over an assumed peak is not a measurement."""
     import jax
     if device is None:
-        devs = jax.devices()
-        if not devs:
-            return None
-        device = devs[0]
-    return _PEAK_FLOPS.get(getattr(device, "device_kind", ""), None)
+        device = jax.devices()[0]
+    if getattr(device, "platform", "") == "cpu":
+        return None
+    kind = getattr(device, "device_kind", "")
+    if kind not in table:
+        raise KeyError(
+            f"no {what} on record for device_kind {kind!r} "
+            f"(known: {sorted(table)}) — add it to "
+            f"paddle_tpu/utils/flops.py with its source")
+    return table[kind]
+
+
+def device_peak_flops(device=None) -> Optional[float]:
+    """Peak bf16 FLOP/s of the attached chip; None on the CPU platform,
+    KeyError for an accelerator missing from ``_PEAK_FLOPS``."""
+    return _spec_sheet(_PEAK_FLOPS, "peak bf16 FLOP/s", device)
 
 
 def device_peak_hbm(device=None) -> Optional[float]:
@@ -462,13 +476,7 @@ def device_peak_hbm(device=None) -> Optional[float]:
     override = flags.get("peak_hbm")
     if override and override > 0:
         return float(override)
-    import jax
-    if device is None:
-        devs = jax.devices()
-        if not devs:
-            return None
-        device = devs[0]
-    return _PEAK_HBM.get(getattr(device, "device_kind", ""), None)
+    return _spec_sheet(_PEAK_HBM, "peak HBM bytes/s", device)
 
 
 # HBM capacity (bytes) by device_kind — spec-sheet fallback when PJRT
@@ -496,24 +504,19 @@ def device_hbm_bytes(device=None) -> Optional[float]:
         return float(override)
     import jax
     if device is None:
-        devs = jax.devices()
-        if not devs:
-            return None
-        device = devs[0]
+        device = jax.devices()[0]
     if getattr(device, "platform", "") == "cpu":
         return None
-    try:
-        stats = device.memory_stats()
-    except Exception:
-        stats = None
+    stats = device.memory_stats()
     if stats and stats.get("bytes_limit"):
         return float(stats["bytes_limit"])
-    return _HBM_BYTES.get(getattr(device, "device_kind", ""), None)
+    return _spec_sheet(_HBM_BYTES, "HBM capacity", device)
 
 
 def mfu(program, batch_size: int, step_seconds: float,
         device=None) -> Optional[float]:
-    """Model FLOPs Utilization in [0, 1], or None off-TPU."""
+    """Model FLOPs Utilization in [0, 1]; None on the CPU platform
+    (KeyError for an accelerator with no peak on record)."""
     peak = device_peak_flops(device)
     if not peak or step_seconds <= 0:
         return None

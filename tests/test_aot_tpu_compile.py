@@ -1,0 +1,187 @@
+"""Ask the TPU compiler, without a chip, whether it accepts the Pallas
+kernels of the main path at their real widths.
+
+Interpret mode (every other kernel test) checks results, not Mosaic's
+rules: kernels that passed all of those were refused on the first chip
+compile for a slice not aligned to the tiling and for an in-kernel
+reshape. libtpu is installed here and compiles for a DESCRIBED
+``v5e:2x2`` topology (on-chip-measurement guide, section 2 step 3), so
+each case lowers one kernel for that device and requires a
+``tpu_custom_call`` in the compiled program. Nothing runs: a compile
+that passes is not a chip run.
+
+Named to sort early — the tier-1 run is cut at a time limit, and a test
+the clock never reaches guards nothing.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")    # no log files in /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described v5e:2x2, with the persistent
+    compile cache off around the module (an entry written for a
+    described device cannot be read back without a chip, and the retry
+    warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu, or it cannot describe a v5e
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: "
+                    f"{type(e).__name__}: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e[0])
+
+
+F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+
+def _paged_gather(dtype):
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    return pa.gather_rows, [((4096, 1024), dtype), ((2048,), I32)]
+
+
+def _paged_dequant():
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    return (lambda p, s, r: pa.gather_rows_dequant(p, s, r, heads=8),
+            [((4096, 1024), I8), ((4096, 8), F32), ((2048,), I32)])
+
+
+def _cache_gather(width):
+    # capacity + 1 rows, as HotRowsCache allocates: the ragged-tile path
+    from paddle_tpu.ops.pallas import embed_cache as ec
+    return ec.gather_rows, [((4097, width), F32), ((512,), I32)]
+
+
+def _cache_scatter(width):
+    from paddle_tpu.ops.pallas import embed_cache as ec
+    return ec.scatter_rows, [((4097, width), F32), ((512,), I32),
+                             ((512, width), F32)]
+
+
+def _embed_pool(width):
+    from paddle_tpu.ops.pallas import fused_embed_seq_pool
+    return fused_embed_seq_pool, [((10000, width), F32), ((64, 20), I32),
+                                  ((64,), I32)]
+
+
+def _flash(t, d):
+    """fwd + bwd at the committed autotune-table blocks for (T, d)."""
+    from paddle_tpu.ops.pallas import flash_attention, flash_engage
+    bq, bk = flash_engage(t, t, d, True)
+    b = 8192 // t
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, bq=bq, bk=bk)
+                       .astype(F32))
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [((b, 8, t, d), BF16)] * 3)
+
+
+def _fused_ce():
+    from paddle_tpu.ops.pallas import fused_linear_ce
+
+    def loss(x, w, labels):
+        return jnp.sum(fused_linear_ce(x, w, labels, label_smoothing=0.1))
+    return (jax.grad(loss, argnums=(0, 1)),
+            [((8192, 512), BF16), ((512, 32000), BF16), ((8192, 1), I32)])
+
+
+def _fused_lstm():
+    from paddle_tpu.ops.pallas import fused_lstm_train
+    t, b, h = 100, 64, 512
+
+    def loss(xproj, w, peep, lens, h0, c0):
+        return sum(jnp.sum(o) for o in
+                   fused_lstm_train(xproj, w, peep, lens, h0, c0))
+    return (jax.grad(loss, argnums=(0, 1, 2, 4, 5)),
+            [((t, b, 4 * h), F32), ((h, 4 * h), F32), ((1, 3 * h), F32),
+             ((b, 1), I32), ((b, h), F32), ((b, h), F32)])
+
+
+CASES = {
+    "paged_gather-f32-4096x1024": lambda: _paged_gather(F32),
+    "paged_gather-bf16-4096x1024": lambda: _paged_gather(BF16),
+    "paged_gather-int8-dequant-4096x1024": _paged_dequant,
+    "embed_cache-gather-w128": lambda: _cache_gather(128),
+    "embed_cache-gather-w256": lambda: _cache_gather(256),
+    "embed_cache-scatter-w128": lambda: _cache_scatter(128),
+    "embed_cache-scatter-w256": lambda: _cache_scatter(256),
+    "embed_pool-w128": lambda: _embed_pool(128),
+    "embed_pool-w256": lambda: _embed_pool(256),
+    "flash-fwd+bwd-T512-d128": lambda: _flash(512, 128),
+    "flash-fwd+bwd-T2048-d64": lambda: _flash(2048, 64),
+    "fused_ce-fwd+bwd-8192x512x32000": _fused_ce,
+    "fused_lstm-fwd+bwd-100x64x512": _fused_lstm,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, case):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mesh_step_compiles_with_kernels_gated(v5e, chip, monkeypatch):
+    """XLA refuses to partition a Mosaic call ("wrap the call in a
+    shard_map"): a step lowered under a mesh must take the refer tier
+    where a lone chip takes the flash kernel (T=512, d_head=128 engages
+    it). ``on_tpu`` is steered here because trace-time gates ask
+    ``jax.default_backend()``, which is the CPU in this process."""
+    import numpy as np
+    from jax.sharding import Mesh
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.core.lowering import CompiledBlock
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.parallel import DistributeConfig
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[512, 256], dtype="float32")
+        loss = fluid.layers.mean(fluid.layers.fused_multi_head_attention(
+            x, x, d_model=256, n_head=2, causal=True))
+    scope = fluid.Scope()
+    fluid.Executor(fluid.TPUPlace()).run(startup, scope=scope)
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+
+    def compiled_text(dist):
+        cb = CompiledBlock(main.desc, 0, ["x"], [loss.name], dist=dist)
+        # under a mesh the jit's own in_shardings place the arguments
+        where = {} if dist is not None else {"sharding": chip}
+
+        def struct(a):
+            return jax.ShapeDtypeStruct(np.shape(a), a.dtype, **where)
+        state, consts = cb._gather_state(scope)
+        return cb.fn.lower(
+            jax.tree_util.tree_map(struct, state),
+            jax.tree_util.tree_map(struct, consts),
+            {"x": jax.ShapeDtypeStruct((8, 512, 256), F32, **where)},
+            jax.ShapeDtypeStruct((), jnp.uint32, **where)
+        ).compile().as_text()
+
+    assert "tpu_custom_call" in compiled_text(None)
+    mesh = Mesh(np.asarray(v5e), ("dp",))
+    text = compiled_text(DistributeConfig(mesh=mesh, data_axis="dp"))
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text          # the mean over the dp-split batch

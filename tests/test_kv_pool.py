@@ -249,15 +249,25 @@ def test_kv_gauges_preregistered_in_exporter_catalog():
 # Pallas page-gather kernels (interpret mode on CPU)
 # ---------------------------------------------------------------------------
 
-def test_paged_gather_kernel_interpret():
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+def test_paged_gather_kernel_interpret(dtype):
+    """Every storage dtype of FLAGS_kv_cache_codec: 24 rows is a whole
+    number of fp32 tiles (8) but a ragged last tile for bf16 (16) and
+    int8 (32) — the padded-group path."""
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import paged_attention as pa
     rng = np.random.RandomState(0)
-    pool = rng.randn(24, 16).astype(np.float32)
+    pool = jnp.asarray(rng.randn(24, 16) * 50).astype(dtype)
+    if dtype != "int8":
+        pool = pool.at[5, 3].set(-0.0)       # the select is bit-exact
     rows = rng.randint(0, 30, size=13)       # includes sentinel overflow
-    got = np.asarray(pa.gather_rows(jnp.asarray(pool), jnp.asarray(rows),
-                                    interpret=True))
-    np.testing.assert_array_equal(got, pool[np.minimum(rows, 23)])
+    rows[0] = 5
+    got = pa.gather_rows(pool, jnp.asarray(rows), interpret=True)
+    assert got.dtype == pool.dtype
+    want = np.asarray(pool.astype(jnp.float32))[np.minimum(rows, 23)]
+    got = np.asarray(got.astype(jnp.float32))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.signbit(got), np.signbit(want))
 
 
 def test_paged_gather_dequant_kernel_interpret():

@@ -335,10 +335,11 @@ def test_pallas_gather_scatter_rows_interpret():
     out = pk.gather_rows(cache, slots, interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   ref[[0, 11, 3, 3, 7]])
-    rows = jnp.asarray(rng.randn(3, 8).astype(np.float32))
-    # slot 12 (== capacity) is out of range -> dropped, not written
-    new = pk.scatter_rows(cache, jnp.asarray([2, 5, 12], jnp.int32), rows,
-                          interpret=True)
+    rows = jnp.asarray(rng.randn(4, 8).astype(np.float32))
+    # slot 12 (== capacity) and slot -1 are out of range -> dropped,
+    # not written (and not clamped onto an edge row)
+    new = pk.scatter_rows(cache, jnp.asarray([2, 5, 12, -1], jnp.int32),
+                          rows, interpret=True)
     got = np.asarray(new)
     np.testing.assert_array_equal(got[2], np.asarray(rows)[0])
     np.testing.assert_array_equal(got[5], np.asarray(rows)[1])

@@ -330,10 +330,7 @@ def test_spmd_metrics_mesh_gauge_and_flat_resharding():
 
 def _shard_map_sum(x_local, codec):
     """Per-device addends reduced over 'dp' with the flagged codec."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.parallel import collective
     mesh = make_mesh()
@@ -342,7 +339,7 @@ def _shard_map_sum(x_local, codec):
         return collective.grad_all_reduce(xs[0], "dp", codec=codec)
 
     return shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P(),
-                     check_rep=False)(x_local)
+                     check_vma=False)(x_local)
 
 
 def test_grad_allreduce_codec_parity():
@@ -385,10 +382,7 @@ def test_grad_allreduce_codec_training_window():
     to gradients): a dp=8 shard_map training loop whose gradient
     exchange rides the int8 codec must track the exact-codec loss
     curve within rtol 1e-2 and stay finite."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.parallel import collective
     mesh = make_mesh()
@@ -411,7 +405,7 @@ def test_grad_allreduce_codec_training_window():
 
         step = shard_map(local_grad, mesh=mesh,
                          in_specs=(P("dp"), P("dp"), P()),
-                         out_specs=P(), check_rep=False)
+                         out_specs=P(), check_vma=False)
         losses = []
         for _ in range(steps):
             g = step(xs, ys, w)

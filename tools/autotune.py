@@ -44,11 +44,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _device_name() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    """The table's ``device`` stamp, which a lookup trusts — so a
+    backend that cannot say what it is raises. Call this only AFTER the
+    last bench.py child has exited: it takes the chip for this process,
+    and a child started afterwards could never get it."""
+    import jax
+    return jax.devices()[0].device_kind
 
 
 # ------------------------------------------------------------------ flash

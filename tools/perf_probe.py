@@ -105,46 +105,6 @@ def copy_band_report(hlo_text: str, block=None, batch_size=None,
             "relayout_total_count": sum(r["count"] for r in rows)}
 
 
-def build_model(model, amp=True, nhwc=True, passes_spec=None,
-                batch_size=None):
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu import models
-    from bench import _apply_tpu_passes
-
-    builders = {
-        "resnet50": (models.resnet.build, {}),
-        "alexnet": (models.alexnet.build, {}),
-        "vgg": (models.vgg.build, {}),
-        "se_resnext": (models.se_resnext.build, {}),
-        "googlenet": (models.googlenet.build, {}),
-        "transformer": (models.transformer.build,
-                        {"max_len": 256, "src_vocab": 32000,
-                         "tgt_vocab": 32000, "fused_attention": True}),
-        "transformer_big": (models.transformer.build,
-                            {"max_len": 512, "src_vocab": 32000,
-                             "tgt_vocab": 32000, "d_model": 1024,
-                             "d_inner": 4096, "n_head": 8, "n_layer": 6,
-                             "fused_attention": True,
-                             "fused_head": True}),
-    }
-    build_fn, kw = builders[model]
-    main_p, startup = fluid.Program(), fluid.Program()
-    main_p.random_seed = 1
-    with fluid.program_guard(main_p, startup):
-        loss, _, feed_specs = build_fn(is_train=True, **kw)
-        applied = _apply_tpu_passes(
-            main_p, model, batch_size, passes_spec, is_test=False,
-            feed_names=sorted(feed_specs), fetch_names=[loss.name])
-        if amp:
-            from paddle_tpu.contrib.mixed_precision import \
-                rewrite_program_amp
-            rewrite_program_amp(main_p)
-        if nhwc:
-            from paddle_tpu.contrib.layout import rewrite_program_nhwc
-            rewrite_program_nhwc(main_p)
-    return main_p, startup, loss, feed_specs, applied
-
-
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("model", nargs="?", default="resnet50")
@@ -164,12 +124,15 @@ def main():
     model, inner = args.model, args.inner
 
     import paddle_tpu.fluid as fluid
-    from bench import DEFAULT_BATCH_SIZES, _device_batch
+    from bench import (DEFAULT_BATCH_SIZES, _device_batch,
+                       build_train_program)
     from paddle_tpu.core.lowering import CompiledBlock
+    from paddle_tpu.utils import chip
+    chip.compile_cache_dir()
 
     bs = args.batch_size or DEFAULT_BATCH_SIZES.get(model, 128)
-    main_p, startup, loss, feed_specs, applied = build_model(
-        model, passes_spec=args.passes, batch_size=bs)
+    main_p, startup, loss, feed_specs, applied = build_train_program(
+        model, bs, passes_spec=args.passes)
     if applied or args.passes:
         print(json.dumps({"passes": applied}), flush=True)
 

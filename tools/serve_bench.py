@@ -1035,9 +1035,15 @@ def main(argv=None):
             os.path.abspath(__file__))), path) \
             if not os.path.isabs(path) else path
 
+    # every arm below runs JAX in this process (the router arms build
+    # their model here too before spawning replicas, which is why they
+    # cannot run on one chip: ROADMAP S7/R6)
+    from paddle_tpu.utils import chip
+    chip.compile_cache_dir()
+
     if not args.skip_decode:
         row = {"bench": "serving",
-               "device": os.environ.get("JAX_PLATFORMS", "auto"),
+               **chip.device_record(),
                "decode": bench_decode(args)}
         if not args.skip_load:
             row["load"] = bench_load(args)
@@ -1052,7 +1058,7 @@ def main(argv=None):
 
     if not args.skip_gen:
         gen = {"bench": "serving_generation",
-               "device": os.environ.get("JAX_PLATFORMS", "auto"),
+               **chip.device_record(),
                "generation": bench_generation(args)}
         with open(_resolve(args.gen_out), "w") as f:
             json.dump(gen, f, indent=2)
@@ -1065,7 +1071,7 @@ def main(argv=None):
 
     if args.kv:
         krow = {"bench": "serving_paged_kv",
-                "device": os.environ.get("JAX_PLATFORMS", "auto"),
+                **chip.device_record(),
                 "paged_kv": bench_paged(args)}
         with open(_resolve(args.kv_out), "w") as f:
             json.dump(krow, f, indent=2)
@@ -1083,7 +1089,7 @@ def main(argv=None):
 
     if args.spec:
         srow = {"bench": "serving_speculative",
-                "device": os.environ.get("JAX_PLATFORMS", "auto"),
+                **chip.device_record(),
                 "speculative": bench_spec(args)}
         with open(_resolve(args.spec_out), "w") as f:
             json.dump(srow, f, indent=2)
@@ -1102,7 +1108,7 @@ def main(argv=None):
 
     if args.replicas:
         rrow = {"bench": "serving_router",
-                "device": os.environ.get("JAX_PLATFORMS", "auto"),
+                **chip.device_record(),
                 "router": bench_router(args)}
         with open(_resolve(args.router_out), "w") as f:
             json.dump(rrow, f, indent=2)
@@ -1117,7 +1123,7 @@ def main(argv=None):
 
     if args.autoscale:
         arow = {"bench": "serving_autoscaler",
-                "device": os.environ.get("JAX_PLATFORMS", "auto"),
+                **chip.device_record(),
                 "autoscaler": bench_autoscaled(args)}
         with open(_resolve(args.autoscale_out), "w") as f:
             json.dump(arow, f, indent=2)
