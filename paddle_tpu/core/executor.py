@@ -147,6 +147,10 @@ class Executor:
         NOTE for stateless (inference) programs: a RESIDENT batch reused
         across the scan is loop-invariant and XLA computes the step once;
         benchmark such programs with per-step data (stacked feeds)."""
+        # the ONE tracing check of a dispatch: every span below is
+        # recorded from timestamps taken only when it was true
+        trace_on = _obs_tracing.active()
+        t_run = time.perf_counter() if trace_on else 0.0
         if program is None:
             from paddle_tpu.fluid import framework as fw
             program = fw.default_main_program()
@@ -449,10 +453,12 @@ class Executor:
         # the flags-unset hot path pays nothing here (<2% overhead
         # contract on the bench step loop)
         span = (_obs_tracing.span("executor.run", iterations=iterations)
-                if (obs_on or _obs_tracing.active())
+                if (obs_on or trace_on)
                 else contextlib.nullcontext())
         on_place = (jax.default_device(self.device) if self._pin
                     else contextlib.nullcontext())
+        # the block stamps the end of its state gather here
+        marks = [] if trace_on else None
         try:
             with span, on_place:
                 # chaos site: the OOM-forensics test arms
@@ -462,10 +468,19 @@ class Executor:
                     seed0 = self._step + 1
                     self._step += iterations
                     outs = cb.run_steps(scope, feeds, seed0, iterations,
-                                        stacked=stacked)
+                                        stacked=stacked, marks=marks)
                 else:
                     self._step += 1
-                    outs = cb(scope, feeds, self._step)
+                    outs = cb(scope, feeds, self._step, marks=marks)
+                if marks:
+                    # executor.prepare: entry of run() to the jitted
+                    # call (feed conversion and placement, the state
+                    # gather); executor.dispatch: the call and the
+                    # write-back, until the async dispatch returns
+                    tracer = _obs_tracing.default_tracer()
+                    tracer.record("executor.prepare", t_run, marks[0])
+                    tracer.record("executor.dispatch", marks[0],
+                                  time.perf_counter())
         except Exception as e:
             # RESOURCE_EXHAUSTED forensics: write the memdump (top live
             # buffers + the failing program's compiled breakdown)

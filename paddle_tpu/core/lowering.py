@@ -13,6 +13,7 @@ in HBM — the functional equivalent of the reference's mutable Scope.
 from __future__ import annotations
 
 import itertools
+import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -23,6 +24,7 @@ import numpy as np
 from paddle_tpu.core import ir
 from paddle_tpu.core import selected_rows as sr
 from paddle_tpu.core.registry import EmitContext, get_op
+from paddle_tpu.observability import runtime as _obs_runtime
 
 # ensure all builtin emitters are registered on import
 import paddle_tpu.ops  # noqa: F401
@@ -283,6 +285,7 @@ class CompiledBlock:
                  feed_names: Sequence[str], fetch_names: Sequence[str],
                  is_test: bool = False, donate: bool = True, dist=None):
         self._obs_tag = next(CompiledBlock._SEQ)
+        _obs_runtime.install_compile_listener()
         # build-time program verification (FLAGS_verify_program or a
         # BuildStrategy.verify_program request): reject malformed
         # programs with rule + op provenance BEFORE tracing, where the
@@ -607,7 +610,7 @@ class CompiledBlock:
                 pass
 
     def run_steps(self, scope, feeds: Dict[str, Any], step_seed0: int,
-                  iterations: int, stacked=False):
+                  iterations: int, stacked=False, marks=None):
         """Run `iterations` training steps in one device-side loop.
         `feeds` maps name -> array (resident batch, reused every step) or,
         with stacked=True (or the name listed in a stacked iterable),
@@ -617,7 +620,11 @@ class CompiledBlock:
         threaded_ssa_graph_executor.cc)."""
         state, consts = self._resident_state(scope)
         fn = self._multi_fn(iterations, stacked)
-        fetches, new_state = fn(state, consts, feeds, np.uint32(step_seed0))
+        if marks is not None:
+            marks.append(time.perf_counter())
+        with _obs_runtime.dispatching(self.obs_label):
+            fetches, new_state = fn(state, consts, feeds,
+                                    np.uint32(step_seed0))
         self._finish_dispatch(scope, new_state, consts)
         return fetches
 
@@ -822,9 +829,17 @@ class CompiledBlock:
             self._input_shardings()
         return self._param_sharding_fn(name)
 
-    def __call__(self, scope, feeds: Dict[str, Any], step_seed: int):
+    def __call__(self, scope, feeds: Dict[str, Any], step_seed: int,
+                 marks=None):
+        """One step. ``marks`` (the executor's, while tracing) gets the
+        perf_counter reading between the state gather and the jitted
+        call: where ``executor.prepare`` ends and ``executor.dispatch``
+        starts."""
         state, consts = self._resident_state(scope)
-        fetches, new_state = self.fn(state, consts, feeds,
-                                     np.uint32(step_seed))
+        if marks is not None:
+            marks.append(time.perf_counter())
+        with _obs_runtime.dispatching(self.obs_label):
+            fetches, new_state = self.fn(state, consts, feeds,
+                                         np.uint32(step_seed))
         self._finish_dispatch(scope, new_state, consts)
         return fetches

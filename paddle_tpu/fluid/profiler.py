@@ -34,7 +34,8 @@ def reset_profiler():
 
 def export_spans(path: str):
     """Write (name, start, end, tid) span rows (csv-quoted — names are
-    arbitrary caller strings) — input for tools/timeline.py."""
+    arbitrary caller strings) — input for ``tools/trace_collect.py
+    --profile_path``."""
     import csv
     with open(path, "w", newline="") as f:
         w = csv.writer(f)
@@ -44,7 +45,8 @@ def export_spans(path: str):
 
 def spans_to_chrome_trace(spans, pid=0):
     """(name, start_s, end_s[, tid]) rows → chrome://tracing JSON dict
-    (reference capability: tools/timeline.py output format). Rows from
+    (reference capability: tools/timeline.py output format; here
+    ``tools/trace_collect.merge_span_files``). Rows from
     :func:`export_spans` carry the real thread id in column 4."""
     events = []
     for row in spans:

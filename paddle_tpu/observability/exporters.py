@@ -296,12 +296,11 @@ def _preregister_catalog():
     except Exception:
         pass
     try:
-        # pass-pipeline + autotune-cache families (paddle_pass_*,
-        # paddle_autotune_*): applied/rewrites/duration per pass, cache
-        # hit/miss per region kind, and the measurement counter whose
-        # zero-ness IS the CI determinism contract
-        from paddle_tpu import passes as _tpu_passes
-        _tpu_passes.declare_metrics()
+        # autotune-cache families (paddle_autotune_*): cache hit/miss
+        # per region kind, and the measurement counter whose zero-ness
+        # IS the CI determinism contract
+        from paddle_tpu.passes import autotune as _autotune
+        _autotune.declare_metrics()
     except Exception:
         pass
 

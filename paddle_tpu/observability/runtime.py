@@ -8,6 +8,13 @@ last ``window`` samples and the throughput gauges are recomputed from
 the window on every record — an operator scraping /metrics sees a
 moving-average rate, not a lifetime mean.
 
+Compile stages are program counters too: ONE process-wide
+``jax.monitoring`` duration listener (:func:`install_compile_listener`)
+feeds ``paddle_compile_seconds_total`` / ``paddle_compile_events_total``
+by ``{stage, program}``, where ``program`` is the compiled block being
+dispatched on the listening thread (:func:`dispatching`), ``other``
+outside any dispatch.
+
 MFU comes from XLA's own compiled-computation cost analysis
 (``jit_fn.lower(...).compile().cost_analysis()['flops']``, the
 per-signature truth about what the compiler actually emitted), cached
@@ -22,10 +29,12 @@ value instead of null).
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Any, Dict, Optional
 
 from paddle_tpu.observability import metrics
+from paddle_tpu.observability import trace_context as _tctx
 
 STEPS_TOTAL = metrics.counter(
     "paddle_steps_total", "Training/executor steps dispatched")
@@ -44,6 +53,21 @@ MFU = metrics.gauge(
     "paddle_mfu_ratio", "Model FLOPs Utilization in [0,1]: achieved "
     "FLOP/s over peak (FLAGS_peak_flops or the chip spec sheet); 0 when "
     "no peak is known")
+
+COMPILE_SECONDS = metrics.counter(
+    "paddle_compile_seconds_total",
+    "Wall seconds jax spent per compile stage: trace (the Python of "
+    "the lowering rules), lower (jaxpr to MLIR), backend_compile (XLA, "
+    "or the persistent cache's load when it hits), cache_load (the "
+    "cache retrieval alone, inside backend_compile). Nested events "
+    "count once, so a stage never exceeds the wall time it ran in",
+    labelnames=("stage", "program"))
+COMPILE_EVENTS = metrics.counter(
+    "paddle_compile_events_total",
+    "jax compile-stage events by the program being dispatched "
+    "(CompiledBlock.obs_label; 'other' outside any dispatch). "
+    "stage=backend_compile counts jit-cache misses",
+    labelnames=("stage", "program"))
 
 
 class StepStats:
@@ -195,3 +219,129 @@ def mfu_ratio(flops_per_step: Optional[float], step_time_s: float,
     if not peak:
         return None
     return flops_per_step / step_time_s / peak
+
+
+# -- compile stages (one jax.monitoring listener) -------------------------
+
+_STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+OTHER_PROGRAM = "other"
+# per thread and stage. One traced program fires ~10 000 nested trace
+# events before its own arrives and absorbs them, so the bound is far
+# above that: a guard on memory, not a working size. Past it the older
+# half collapses into one interval, and only an event that starts inside
+# that half (a caller of over 65 000 nested jits) would be miscounted
+_MAX_INTERVALS = 1 << 17
+
+# per thread: the program being dispatched, and per stage the disjoint
+# (start, end, seconds counted inside) intervals already seen
+_compile_local = threading.local()
+_listener_lock = threading.Lock()
+_listener_installed = False
+# (stage, program) -> (events child, seconds child): labels() costs a
+# lock and a key build, and one traced program fires tens of thousands
+# of nested trace events
+_children: Dict[Any, Any] = {}
+
+
+class dispatching:
+    """``with dispatching(cb.obs_label): jitted(...)`` — names the
+    program for the compile events of this thread. Two attribute stores
+    per dispatch, nothing else; the previous name comes back on exit, so
+    a jit outside any dispatch counts under ``other``."""
+
+    __slots__ = ("program", "_prev")
+
+    def __init__(self, program: str):
+        self.program = program
+
+    def __enter__(self):
+        self._prev = getattr(_compile_local, "program", OTHER_PROGRAM)
+        _compile_local.program = self.program
+
+    def __exit__(self, *exc):
+        _compile_local.program = self._prev
+
+
+def _own_seconds(stage: str, start: float, end: float) -> float:
+    """Seconds of [start, end) not yet counted for ``stage`` on this
+    thread. jax fires a nested jit's event before its caller's and
+    inside the caller's duration, so events arrive ordered by their end
+    and nest like calls: when a caller's event arrives, the intervals
+    that started inside it are its callees', they leave the list, and
+    the caller is reduced by the seconds counted in them. A stage's
+    total is then wall time, counted once. The list holds what no caller
+    has absorbed yet, each interval with the seconds counted inside."""
+    by_stage = getattr(_compile_local, "intervals", None)
+    if by_stage is None:
+        by_stage = _compile_local.intervals = {}
+    done = by_stage.get(stage)
+    if done is None:
+        done = by_stage[stage] = []
+    own = end - start
+    held = 0.0                  # all the seconds of what is absorbed
+    while done and done[-1][1] > start:
+        a, b, counted = done.pop()
+        held += counted
+        if a < start:
+            # straddles the start (two clock readings apart): only what
+            # lies inside reduces the caller; intervals stay disjoint
+            counted = min(counted, b - start)
+            start = a
+        own -= counted
+    own = max(own, 0.0)
+    done.append((start, end, held + own))
+    if len(done) > _MAX_INTERVALS:
+        half = len(done) // 2
+        done[:half] = [(done[0][0], done[half - 1][1],
+                        sum(c for _a, _b, c in done[:half]))]
+    return own
+
+
+def _on_compile_event(event: str, duration: float, **kw) -> None:
+    stage = _STAGE_OF_EVENT.get(event)
+    if stage is None:
+        return
+    now = time.perf_counter()
+    key = (stage, getattr(_compile_local, "program", OTHER_PROGRAM))
+    pair = _children.get(key)
+    if pair is None:
+        pair = _children[key] = (COMPILE_EVENTS.labels(*key),
+                                 COMPILE_SECONDS.labels(*key))
+    pair[0].inc()
+    pair[1].inc(_own_seconds(stage, now - duration, now))
+    if _tctx.active():
+        # retroactive: lands inside the serving.prefill@P or
+        # executor.run span whose dispatch compiled
+        _tctx.record_span("compile." + stage, now - duration, now,
+                          ctx=_tctx.current(), program=key[1],
+                          fun=kw.get("fun_name"))
+
+
+def install_compile_listener() -> None:
+    """Register the process's one compile listener (idempotent; jax has
+    no way to unregister a single listener, so it stays)."""
+    global _listener_installed
+    if _listener_installed:
+        return
+    with _listener_lock:
+        if _listener_installed:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _listener_installed = True
+
+
+def backend_compile_count() -> int:
+    """jit-cache misses (``backend_compile_duration`` events) seen
+    process-wide since the listener was installed; installs it. Flat
+    across a window means no recompile in it."""
+    install_compile_listener()
+    return int(sum(child.value for (stage, _program), child
+                   in COMPILE_EVENTS.children().items()
+                   if stage == "backend_compile"))

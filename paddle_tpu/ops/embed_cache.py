@@ -72,26 +72,17 @@ CACHE_OCCUPANCY = _metrics.gauge(
 
 
 # -- compile-counter witness -------------------------------------------------
-# one process-global jax.monitoring listener, registered at import and
-# never unregistered (clear_event_listeners would nuke everyone's):
-# backend_compile_duration fires once per REAL XLA compile and never on
-# a cache-hit dispatch, so a flat count across a training window IS the
-# zero-steady-state-recompiles witness.
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compile_count = [0]
-
-
-def _on_event_duration(event, duration, **kw):   # pragma: no cover - thin
-    if event == _COMPILE_EVENT:
-        _compile_count[0] += 1
-
-
-jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
-
 
 def compile_count() -> int:
-    """Real backend compiles observed process-wide since import."""
-    return _compile_count[0]
+    """jit-cache misses observed process-wide (the program's one
+    ``jax.monitoring`` listener, ``observability.runtime``): a
+    ``backend_compile_duration`` event fires once per compile request
+    and never on a cache-hit dispatch, so a flat count across a training
+    window IS the zero-steady-state-recompiles witness. Counting starts
+    at the first call or the first ``CompiledBlock``, whichever comes
+    first: take a delta."""
+    from paddle_tpu.observability import runtime as _obs_runtime
+    return _obs_runtime.backend_compile_count()
 
 
 # -- pow2-bucketed device row ops -------------------------------------------

@@ -135,6 +135,7 @@ def gather_rows(table, rows, scales=None, interpret: bool = False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kp, d), out_dtype),
         interpret=interpret,
+        name="gather_rows",
     )(*operands)
     return out[:k]
 

@@ -32,7 +32,6 @@ fluid stack in.
 
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Sequence
 
 from paddle_tpu.passes import autotune  # noqa: F401  (leaf module)
@@ -59,25 +58,6 @@ def register_all():
     return list(TRAIN_PIPELINE) + ["conv_bn_fold_pass"]
 
 
-def declare_metrics():
-    """Pass + autotune metric families (get-or-create; also called from
-    the exporter catalog preregistration)."""
-    from paddle_tpu.observability import metrics as obs_metrics
-    applied = obs_metrics.counter(
-        "paddle_pass_applied_total",
-        "IR pass applications over a program, per pass", ("pass_name",))
-    rewrites = obs_metrics.counter(
-        "paddle_pass_rewrites_total",
-        "op-level rewrites performed by IR passes (ops removed or "
-        "replaced), per pass", ("pass_name",))
-    duration = obs_metrics.histogram(
-        "paddle_pass_duration_seconds",
-        "wall time of one IR pass application over one program",
-        ("pass_name",))
-    autotune.declare_metrics()
-    return applied, rewrites, duration
-
-
 def pin_op_indices(block) -> None:
     """Stamp every op with its current index (`__op_index__`) before a
     pass pipeline mutates the block. The lowering salts per-op rng by
@@ -91,24 +71,15 @@ def pin_op_indices(block) -> None:
 
 
 def run_pass(p, name: str, block, scope=None) -> int:
-    """Apply one instantiated pass to a block with the observability
-    contract every application path shares (BuildStrategy and
-    apply_pipeline): paddle_pass_applied_total / _rewrites_total
-    counters + the per-pass duration histogram. Returns the number of
-    ops removed/replaced."""
+    """Apply one instantiated pass to a block, the way every
+    application path does (BuildStrategy and apply_pipeline). Returns
+    the number of ops removed/replaced."""
     from paddle_tpu.fluid import ir_pass as irp
-    applied_fam, rewrites_fam, duration_fam = declare_metrics()
     if hasattr(p, "scope"):
         p.scope = scope
     n_before = len(block.ops)
-    t0 = time.perf_counter()
     p(irp.Graph(block))
-    duration_fam.labels(pass_name=name).observe(time.perf_counter() - t0)
-    applied_fam.labels(pass_name=name).inc()
-    delta = n_before - len(block.ops)
-    if delta > 0:
-        rewrites_fam.labels(pass_name=name).inc(delta)
-    return max(delta, 0)
+    return max(n_before - len(block.ops), 0)
 
 
 def pipeline_for(program=None, is_test: Optional[bool] = None,
@@ -147,7 +118,6 @@ def apply_pipeline(program, scope=None, names: Optional[Sequence[str]] = None,
     the "every rewritten program re-verified" guarantee."""
     register_all()
     from paddle_tpu.fluid import ir_pass as irp
-    applied_fam, rewrites_fam, duration_fam = declare_metrics()
 
     if names is None:
         names = pipeline_for(program, is_test=is_test, model=model,

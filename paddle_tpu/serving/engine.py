@@ -47,10 +47,12 @@ import hashlib
 import json
 import os
 import pickle
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from paddle_tpu.observability import runtime as obs_runtime
 from paddle_tpu.observability import trace_context as tctx
 from paddle_tpu.serving import bucketing
 from paddle_tpu.serving import metrics as smetrics
@@ -308,8 +310,16 @@ class GenerativeModel:
         return state, consts, feeds, np.uint32(0)
 
     def _run(self, cb, aot_key, feeds) -> np.ndarray:
+        """One dispatch of ``cb`` and the blocking fetch of its first
+        output. While tracing, the host's time in here is named:
+        ``serving.<kind>.args`` (scope lookups, padding), ``.dispatch``
+        (until the async call returns, and the state write-back) and
+        ``.fetch`` (blocked on the device), ``<kind>`` being ``prefill``
+        or ``decode`` by ``aot_key[0]`` (a verify step is a decode)."""
         from paddle_tpu.observability import memory as obs_memory
         from paddle_tpu.utils import faults
+        trace_on = tctx.active()
+        t0 = time.perf_counter() if trace_on else 0.0
         plan = None
         dist = getattr(self, "dist", None)
         if dist is not None and getattr(dist, "mesh", None) is not None:
@@ -324,41 +334,54 @@ class GenerativeModel:
                 feeds, plan = _padding.pad_feeds_to_multiple(
                     feeds, int(dist.mesh.shape[ax]))
         args = self._args(cb, feeds)
+        t1 = time.perf_counter() if trace_on else 0.0
         try:
             # chaos site for the serving OOM-forensics path
             faults.inject("serving.dispatch")
             aot = self._aot.get(aot_key)
-            if aot is not None:
-                try:
-                    fetches, new_state = aot(*args)
-                except Exception as e:
-                    # an OOM would only repeat on the jit path (and its
-                    # forensics belong to the first failure)
-                    if obs_memory.is_oom_error(e):
-                        raise
-                    # backend mis-mapped the deserialized executable
-                    # (XLA:CPU under forced device counts does): degrade
-                    # to the (warmed) compile path for the rest of the
-                    # run — counted and announced, never silent
-                    import warnings
-                    warnings.warn(
-                        f"AOT executable {aot_key} of model "
-                        f"{self.name!r} failed on this backend "
-                        f"({type(e).__name__}); falling back to the "
-                        f"compile path", stacklevel=2)
-                    smetrics.AOT_FALLBACK.labels(
-                        model=self.name, cause="backend_error").inc()
-                    self._aot.pop(aot_key, None)
+            # compile events of this thread count under the block's name
+            with obs_runtime.dispatching(cb.obs_label):
+                if aot is not None:
+                    try:
+                        fetches, new_state = aot(*args)
+                    except Exception as e:
+                        # an OOM would only repeat on the jit path (and
+                        # its forensics belong to the first failure)
+                        if obs_memory.is_oom_error(e):
+                            raise
+                        # backend mis-mapped the deserialized executable
+                        # (XLA:CPU under forced device counts does):
+                        # degrade to the (warmed) compile path for the
+                        # rest of the run — counted and announced, never
+                        # silent
+                        import warnings
+                        warnings.warn(
+                            f"AOT executable {aot_key} of model "
+                            f"{self.name!r} failed on this backend "
+                            f"({type(e).__name__}); falling back to the "
+                            f"compile path", stacklevel=2)
+                        smetrics.AOT_FALLBACK.labels(
+                            model=self.name, cause="backend_error").inc()
+                        self._aot.pop(aot_key, None)
+                        fetches, new_state = cb.fn(*args)
+                else:
                     fetches, new_state = cb.fn(*args)
-            else:
-                fetches, new_state = cb.fn(*args)
         except Exception as e:
             if obs_memory.is_oom_error(e):
                 obs_memory.oom_dump(cb, self.scope, e, feeds=feeds)
             raise
         for n, v in new_state.items():
             self.scope.set_var(n, v)
+        t2 = time.perf_counter() if trace_on else 0.0
         out = np.asarray(fetches[0])
+        if trace_on:
+            t3 = time.perf_counter()
+            kind = ("serving.prefill" if aot_key[0].startswith("prefill")
+                    else "serving.decode")
+            ctx = tctx.current()
+            tctx.record_span(kind + ".args", t0, t1, ctx=ctx)
+            tctx.record_span(kind + ".dispatch", t1, t2, ctx=ctx)
+            tctx.record_span(kind + ".fetch", t2, t3, ctx=ctx)
         if plan is not None:
             out = plan.slice_fetch(out)
         return out
@@ -788,6 +811,20 @@ class SlotGenerativeModel:
                 is_test=True, donate=True, dist=dist)
             self.spec_k = int(ver_feeds["tok"][0][1]) - 1
         self.drafter = drafter if drafter is not None else NgramDrafter()
+        # metric children bound once: labels() builds a key and takes a
+        # lock per call, and a 48-slot step made ~100 of them
+        self._m_prefills = smetrics.PREFILLS.labels(model=name)
+        self._m_admissions = smetrics.SLOT_ADMISSIONS.labels(model=name)
+        self._m_tokens = smetrics.TOKENS_GENERATED.labels(model=name)
+        self._m_decode_steps = smetrics.DECODE_STEPS.labels(model=name)
+        self._m_tokens_per_step = smetrics.TOKENS_PER_STEP.labels(
+            model=name)
+        self._m_occupancy = smetrics.SLOT_OCCUPANCY.labels(model=name)
+        if ver is not None:
+            self._m_spec_proposed = smetrics.SPEC_PROPOSED.labels(
+                model=name)
+            self._m_spec_accepted = smetrics.SPEC_ACCEPTED.labels(
+                model=name)
         self._discover_pool(dec_main, dec_feeds)
         self._warmed: set = set()
         self._aot: Dict[Tuple, object] = {}
@@ -1020,32 +1057,44 @@ class SlotGenerativeModel:
                 f"max_new {budget} outside the cache budget "
                 f"(1..{self.cache_len - p_len} for a prompt padded to "
                 f"bucket {p_len})")
+        # one tracing check per admission; timestamps only when on
+        trace_on = tctx.active()
+        t0 = time.perf_counter() if trace_on else 0.0
         self._reserve_capacity(slot, prompt, p_len, budget)
+        if trace_on:
+            tctx.record_span("serving.admit.reserve", t0,
+                             time.perf_counter(), ctx=tctx.current(),
+                             model=self.name, slot=slot)
         key = (self.PREFILL, p_len)
         if key not in self._warmed:
             smetrics.count_compile(self.name, f"steady_{self.PREFILL}")
             self._warmed.add(key)
-        ids = np.zeros((1, p_len, 1), np.int64)
-        ids[0, :length, 0] = prompt
         # span named by the PROMPT BUCKET the admission landed on, under
         # the admitting request's trace (the scheduler activates it)
         try:
             with tctx.span(f"serving.prefill@{p_len}", model=self.name,
-                           slot=slot):
-                tok = self._run(self._cb_prefill[p_len], key, {
+                           slot=slot) as pctx:
+                t0 = time.perf_counter() if trace_on else 0.0
+                ids = np.zeros((1, p_len, 1), np.int64)
+                ids[0, :length, 0] = prompt
+                feeds = {
                     "ids": ids,
                     **self._admit_feeds(slot, p_len),
                     "seq_len": np.asarray([[length]], np.int64),
                     "seed": np.asarray([[int(seed)]], np.int64),
                     "temperature": np.asarray([[float(temperature)]],
                                               np.float32),
-                    "top_k": np.asarray([[int(top_k)]], np.int64)})
+                    "top_k": np.asarray([[int(top_k)]], np.int64)}
+                if trace_on:
+                    tctx.record_span("serving.prefill.feeds", t0,
+                                     time.perf_counter(), ctx=pctx)
+                tok = self._run(self._cb_prefill[p_len], key, feeds)
         except BaseException:
             self._release_capacity(slot)
             raise
-        smetrics.PREFILLS.labels(model=self.name).inc()
-        smetrics.SLOT_ADMISSIONS.labels(model=self.name).inc()
-        smetrics.TOKENS_GENERATED.labels(model=self.name).inc()
+        self._m_prefills.inc()
+        self._m_admissions.inc()
+        self._m_tokens.inc()
         first = int(np.asarray(tok).reshape(-1)[0])
         self._active[slot] = True
         self._tok[slot] = first
@@ -1066,8 +1115,7 @@ class SlotGenerativeModel:
         if done:
             self.release(slot, cause=done)
         else:
-            smetrics.SLOT_OCCUPANCY.labels(model=self.name).set(
-                self.occupancy())
+            self._m_occupancy.set(self.occupancy())
         return slot, first, done
 
     def step(self) -> List[Tuple[int, int, Optional[str]]]:
@@ -1092,12 +1140,18 @@ class SlotGenerativeModel:
         if (self.DECODE,) not in self._warmed:
             smetrics.count_compile(self.name, f"steady_{self.DECODE}")
             self._warmed.add((self.DECODE,))
-        out = self._run(self._cb_decode, (self.DECODE,),
-                        self._decode_feeds())
+        # one tracing check per step; timestamps only when on
+        trace_on = tctx.active()
+        t0 = time.perf_counter() if trace_on else 0.0
+        feeds = self._decode_feeds()
+        if trace_on:
+            tctx.record_span("serving.decode.feeds", t0,
+                             time.perf_counter())
+        out = self._run(self._cb_decode, (self.DECODE,), feeds)
+        t0 = time.perf_counter() if trace_on else 0.0
         out = np.asarray(out).reshape(-1)
-        smetrics.DECODE_STEPS.labels(model=self.name).inc()
-        smetrics.TOKENS_GENERATED.labels(model=self.name).inc(
-            int(live.size))
+        self._m_decode_steps.inc()
+        self._m_tokens.inc(int(live.size))
         events = []
         for slot in live:
             slot = int(slot)
@@ -1105,7 +1159,7 @@ class SlotGenerativeModel:
             self._tok[slot] = tok
             self._gen_count[slot] += 1
             self._hist[slot].append(tok)
-            smetrics.TOKENS_PER_STEP.labels(model=self.name).observe(1.0)
+            self._m_tokens_per_step.observe(1.0)
             eos = self._eos[slot]
             done = None
             if eos is not None and tok == eos:
@@ -1115,8 +1169,10 @@ class SlotGenerativeModel:
             if done:
                 self.release(slot, cause=done)
             events.append((slot, tok, done))
-        smetrics.SLOT_OCCUPANCY.labels(model=self.name).set(
-            self.occupancy())
+        self._m_occupancy.set(self.occupancy())
+        if trace_on:
+            tctx.record_span("serving.decode.commit", t0,
+                             time.perf_counter())
         return events
 
     def _step_verify(self, live) -> List[Tuple[int, int, Optional[str]]]:
@@ -1130,6 +1186,8 @@ class SlotGenerativeModel:
         bit-identical to the non-speculative scheduler; temperature>0
         stays lossless because acceptance compares against the exact
         counter-based sample of each (seed, step)."""
+        trace_on = tctx.active()
+        t0 = time.perf_counter() if trace_on else 0.0
         s, k1 = self.n_slots, self.spec_k + 1
         tok_w = np.zeros((s, k1, 1), np.int64)
         tok_w[:, 0, 0] = self._tok
@@ -1156,11 +1214,16 @@ class SlotGenerativeModel:
         if (self.VERIFY,) not in self._warmed:
             smetrics.count_compile(self.name, f"steady_{self.VERIFY}")
             self._warmed.add((self.VERIFY,))
-        out = self._run(self._cb_verify, (self.VERIFY,),
-                        self._verify_feeds(tok_w, win_len))
+        feeds = self._verify_feeds(tok_w, win_len)
+        if trace_on:
+            # the drafter's proposals are part of building the window
+            tctx.record_span("serving.decode.feeds", t0,
+                             time.perf_counter())
+        out = self._run(self._cb_verify, (self.VERIFY,), feeds)
+        t0 = time.perf_counter() if trace_on else 0.0
         out = np.asarray(out).reshape(s, k1)
-        smetrics.DECODE_STEPS.labels(model=self.name).inc()
-        smetrics.SPEC_PROPOSED.labels(model=self.name).inc(proposed)
+        self._m_decode_steps.inc()
+        self._m_spec_proposed.inc(proposed)
         events = []
         committed_total = accepted_total = 0
         for slot in live:
@@ -1188,16 +1251,15 @@ class SlotGenerativeModel:
                 if done:
                     break
             committed_total += n_commit
-            smetrics.TOKENS_PER_STEP.labels(model=self.name).observe(
-                float(n_commit))
+            self._m_tokens_per_step.observe(float(n_commit))
             if done:
                 self.release(slot, cause=done)
-        smetrics.SPEC_ACCEPTED.labels(model=self.name).inc(
-            accepted_total)
-        smetrics.TOKENS_GENERATED.labels(model=self.name).inc(
-            committed_total)
-        smetrics.SLOT_OCCUPANCY.labels(model=self.name).set(
-            self.occupancy())
+        self._m_spec_accepted.inc(accepted_total)
+        self._m_tokens.inc(committed_total)
+        self._m_occupancy.set(self.occupancy())
+        if trace_on:
+            tctx.record_span("serving.decode.commit", t0,
+                             time.perf_counter())
         return events
 
     def release(self, slot: int, cause: str = "cancelled"):
@@ -1210,14 +1272,13 @@ class SlotGenerativeModel:
         self._eos[slot] = None
         smetrics.SLOT_EVICTIONS.labels(model=self.name,
                                        cause=cause).inc()
-        smetrics.SLOT_OCCUPANCY.labels(model=self.name).set(
-            self.occupancy())
+        self._m_occupancy.set(self.occupancy())
 
     def reset(self):
         self._active[:] = False
         self._gen_count[:] = 0
         self._eos = [None] * self.n_slots
-        smetrics.SLOT_OCCUPANCY.labels(model=self.name).set(0.0)
+        self._m_occupancy.set(0.0)
 
     # -- convenience: drive the pool to completion -----------------------
     def generate(self, prompts: Sequence, max_new: Optional[int] = None,

@@ -255,7 +255,18 @@ def forbid_compiles():
 
 def count_compile(model: str, kind: str):
     """Record (and, under :func:`forbid_compiles`, reject) an executable
-    build. Call BEFORE the build so the forbidden case never compiles."""
+    build. Call BEFORE the build so the forbidden case never compiles.
+
+    What it counts is the FIRST USE of a (model, bucket) key by an
+    engine — a warm-up dispatch, or an unwarmed signature at steady
+    state (``kind`` then starts with ``steady_``) — not a compile: a
+    warmed key the persistent cache serves still counts once, and a jit
+    recompile of a warmed key (a new layout or committed-ness) does not
+    count at all. Compiles themselves, by stage and by program, are
+    ``paddle_compile_events_total`` / ``paddle_compile_seconds_total``
+    (``observability.runtime``, the process's one ``jax.monitoring``
+    listener). The chip benchmark reads this counter's delta over the
+    window next to that listener's."""
     if compiles_forbidden():
         raise CompileForbiddenError(
             f"serving executable build ({kind}) for model {model!r} "

@@ -446,6 +446,9 @@ class _SlotHostedModel(_HostedModel):
         self._slot_owner: Dict[int, tuple] = {}
         self.sched_steps = 0
         self.sched_slot_steps = 0       # occupied slot-steps (occupancy)
+        # the loop's per-step and per-token metric children, bound once
+        self._m_batches = smetrics.BATCHES.labels(model=name)
+        self._m_inter_token = smetrics.INTER_TOKEN.labels(model=name)
         super().__init__(name, engine, max_queue_depth, linger_s,
                          dedup_capacity, oom_exit=oom_exit)
 
@@ -571,14 +574,24 @@ class _SlotHostedModel(_HostedModel):
             try:
                 self._reap_cancelled()
                 self._admit()
+                # one flag check per turn of the loop, not per token:
+                # the disabled path pays a single boolean
+                trace_on = tctx.active()
                 if engine.active_count() == 0:
+                    t_idle = time.perf_counter() if trace_on else 0.0
+                    waited = False
                     with self.cond:
                         if not self.queue:
                             self.cond.wait(timeout=0.05)
+                            waited = True
+                    if trace_on and waited:
+                        # nothing in flight and nothing queued: the
+                        # device's idle time under this span is the
+                        # wait for arrivals (one span per wait)
+                        tctx.record_span(
+                            "serving.sched.idle", t_idle,
+                            time.perf_counter(), model=self.name)
                     continue
-                # one flag check per pool step, not per token: the
-                # disabled path pays a single boolean
-                trace_on = tctx.active()
                 t_step = time.perf_counter() if trace_on else 0.0
                 try:
                     events = engine.step()
@@ -590,7 +603,7 @@ class _SlotHostedModel(_HostedModel):
                     continue
                 self.sched_steps += 1
                 self.sched_slot_steps += len(events)
-                smetrics.BATCHES.labels(model=self.name).inc()
+                self._m_batches.inc()
                 now = time.perf_counter()
                 for slot, tok, done in events:
                     owner = self._slot_owner.get(slot)
@@ -605,14 +618,19 @@ class _SlotHostedModel(_HostedModel):
                             "serving.decode_step", t_step, now,
                             ctx=stream.req.ctx, slot=slot,
                             model=self.name)
-                    smetrics.INTER_TOKEN.labels(
-                        model=self.name).observe(
+                    self._m_inter_token.observe(
                         now - stream.last_tok_t[pi])
                     stream.last_tok_t[pi] = now
                     if done:
                         del self._slot_owner[slot]
                         del stream.slot2pi[slot]
                         self._maybe_settle(stream)
+                if trace_on:
+                    # token append, INTER_TOKEN observes, settles (and,
+                    # while tracing, the per-slot spans above)
+                    tctx.record_span(
+                        "serving.sched.commit", now,
+                        time.perf_counter(), model=self.name)
             except Exception:
                 # never let the scheduler die; back off so a
                 # persistent bookkeeping error can't hot-spin the

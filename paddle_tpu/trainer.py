@@ -13,7 +13,6 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from paddle_tpu.observability import tracing as _tracing
 
 
 class BeginPass:
@@ -86,24 +85,21 @@ class SGD:
         for pass_id in range(num_passes):
             event_handler(BeginPass(pass_id))
             costs = []
-            with _tracing.span("trainer.pass"):
-                for batch_id, batch in enumerate(reader()):
-                    event_handler(BeginIteration(pass_id, batch_id))
-                    feed = self._feed_dict(batch, feed_order)
-                    # step span: aggregates always (thread-safe event
-                    # table), a timeline span under an active profiler;
-                    # the executor records the step-stats sample
-                    # (steps/s, examples/s, MFU gauges) when
-                    # observability is enabled
-                    with _tracing.span("trainer.step"):
-                        vals = self.exe.run(self.main, feed=feed,
-                                            fetch_list=fetch)
-                    cost = float(np.asarray(vals[0]).reshape(()))
-                    costs.append(cost)
-                    metrics = {k: np.asarray(v) for k, v in
-                               zip(self.extra_fetch, vals[1:])}
-                    event_handler(EndIteration(pass_id, batch_id, cost,
-                                               metrics))
+            for batch_id, batch in enumerate(reader()):
+                event_handler(BeginIteration(pass_id, batch_id))
+                feed = self._feed_dict(batch, feed_order)
+                # the executor records each step: the executor.run span
+                # under an active profiler, the step-stats sample
+                # (steps/s, examples/s, MFU gauges) when observability
+                # is enabled
+                vals = self.exe.run(self.main, feed=feed,
+                                    fetch_list=fetch)
+                cost = float(np.asarray(vals[0]).reshape(()))
+                costs.append(cost)
+                metrics = {k: np.asarray(v) for k, v in
+                           zip(self.extra_fetch, vals[1:])}
+                event_handler(EndIteration(pass_id, batch_id, cost,
+                                           metrics))
             event_handler(EndPass(pass_id,
                                   {"mean_cost": float(np.mean(costs))
                                    if costs else float("nan")}))

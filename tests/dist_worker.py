@@ -128,7 +128,8 @@ def main():
     exe.run(startup)
 
     # optional per-process span capture for the merged-timeline test
-    # (reference: tools/timeline.py:27-30 merges trainer1=f1,trainer2=f2)
+    # (tools/trace_collect.py --profile_path merges
+    # trainer1=f1,trainer2=f2 as the reference's tools/timeline.py did)
     import contextlib
     spans_dir = os.environ.get("PADDLE_TEST_SPANS_DIR")
     if spans_dir:

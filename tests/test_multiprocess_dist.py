@@ -130,17 +130,18 @@ def test_two_process_transformer_dp_loss_curve_parity():
 
 
 def test_merged_multi_trainer_timeline(tmp_path):
-    """tools/timeline.py merges per-trainer span files into ONE chrome
-    trace with a pid lane per trainer (reference: tools/timeline.py:27-30
-    accepts 'trainer1=f1,trainer2=f2,ps=f3') — the observability story
-    for the multi-process training this suite exercises."""
+    """tools/trace_collect.py merges per-trainer span files into ONE
+    chrome trace with a pid lane per trainer (reference:
+    tools/timeline.py:27-30 accepts 'trainer1=f1,trainer2=f2,ps=f3') —
+    the observability story for the multi-process training this suite
+    exercises."""
     spans_dir = str(tmp_path)
     _run_workers(2, "mlp", 6,
                  extra_env={"PADDLE_TEST_SPANS_DIR": spans_dir})
     files = sorted(os.listdir(spans_dir))
     assert files == ["spans_rank0.csv", "spans_rank1.csv"], files
 
-    from tools.timeline import merge_span_files, parse_profile_paths
+    from tools.trace_collect import merge_span_files, parse_profile_paths
     arg = ",".join(f"trainer{r}={os.path.join(spans_dir, f)}"
                    for r, f in enumerate(files))
     named = parse_profile_paths(arg)
@@ -152,12 +153,18 @@ def test_merged_multi_trainer_timeline(tmp_path):
     labels = {e["pid"]: e["args"]["name"] for e in trace["traceEvents"]
               if e["ph"] == "M" and e["name"] == "process_name"}
     assert labels == {0: "trainer0", 1: "trainer1"}
-    # each lane carries that rank's training span(s)
+    # each lane carries that rank's training span(s), no other rank's,
+    # and that rank's own executor spans (recorded whenever a profiler
+    # is active: executor.run with its prepare / dispatch parts)
     for pid, label in labels.items():
         rank_events = [e["name"] for e in trace["traceEvents"]
                        if e["ph"] == "X" and e["pid"] == pid]
-        assert rank_events and all(
-            n.startswith(f"rank{pid}/") for n in rank_events), rank_events
+        mine = [n for n in rank_events if n.startswith(f"rank{pid}/")]
+        rest = [n for n in rank_events if n not in mine]
+        assert mine, rank_events
+        assert rest and all(n.startswith(("executor.", "compile."))
+                            for n in rest), rank_events
+        assert "executor.run" in rest and "executor.dispatch" in rest
 
     # single-file form still works (no metadata lane)
     single = merge_span_files(parse_profile_paths(

@@ -165,7 +165,7 @@ def test_tracer_concurrent_spans_carry_real_tids():
 
 def test_profiler_export_spans_tid_column(tmp_path):
     """export_spans rows carry the tid in column 4 and round-trip
-    through spans_to_chrome_trace (tools/timeline.py input format)."""
+    through spans_to_chrome_trace (tools/trace_collect.py --profile_path input format)."""
     import csv
     from paddle_tpu.fluid import profiler
     profiler.reset_profiler()

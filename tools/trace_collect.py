@@ -29,15 +29,25 @@ request's span ancestry to cross those roles in order — the replicated
 serving deployment's three-hop stitch (client span -> router.route ->
 replica handler; docs/serving.md "Deployment").
 
-Single-process host timelines from profiler CSVs stay with
-``tools/timeline.py``; this tool is its cross-process sibling and
-shares the chrome-trace idiom (one pid lane per input, "M" metadata
-naming the lanes).
+Profiler span CSVs (``fluid.profiler.export_spans``, one per process)
+merge here too, one pid lane per file (the reference's
+``tools/timeline.py --profile_path trainer0=a.csv,trainer1=b.csv``
+grammar; :func:`parse_profile_paths`, :func:`merge_span_files`):
+
+    python tools/trace_collect.py --profile_path spans.csv -o out.json
+    python tools/trace_collect.py \
+        --profile_path trainer0=a.csv,trainer1=b.csv -o merged.json
+
+A lane carries every span its process recorded: a rank's own events
+and, because the executor records ``executor.run`` (with
+``executor.prepare`` / ``executor.dispatch``) whenever a profiler is
+active, those too.
 """
 
 from __future__ import annotations
 
 import argparse
+import csv
 import json
 import os
 import sys
@@ -150,6 +160,40 @@ def merge(paths: List[str]) -> dict:
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
+# -- profiler span CSVs (one process each) ------------------------------
+
+def parse_profile_paths(arg: str) -> List[Tuple[Optional[str], str]]:
+    """'file' -> [(None, file)]; 'n1=f1,n2=f2' -> [(n1, f1), (n2, f2)]
+    (the reference's argument grammar, tools/timeline.py:27-30)."""
+    if "=" not in arg:
+        return [(None, arg)]
+    out = []
+    for part in arg.split(","):
+        if not part:
+            continue
+        name, _, path = part.partition("=")
+        if not path:
+            raise ValueError(
+                f"bad --profile_path segment {part!r}: want name=file")
+        out.append((name, path))
+    return out
+
+
+def merge_span_files(named_paths) -> dict:
+    """[(label, span_csv_path), ...] -> one chrome trace dict with one
+    pid lane per input file, labeled via process_name metadata events."""
+    from paddle_tpu.fluid.profiler import spans_to_chrome_trace
+    events: List[dict] = []
+    for pid, (label, path) in enumerate(named_paths):
+        with open(path, newline="") as f:
+            rows = [row for row in csv.reader(f) if len(row) >= 3]
+        events.extend(spans_to_chrome_trace(rows, pid=pid)["traceEvents"])
+        if label is not None:
+            events.append({"name": "process_name", "ph": "M", "pid": pid,
+                           "args": {"name": label}})
+    return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
 def check_chain(paths: List[str], chain: List[str]) -> List[str]:
     """Require at least one request whose span ancestry crosses the
     given roles in order (e.g. ``client,router,replica``): walking a
@@ -256,9 +300,14 @@ def check(paths: List[str],
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="merge span spools into one Perfetto trace")
-    ap.add_argument("spool_dir",
+    ap.add_argument("spool_dir", nargs="?",
                     help="FLAGS_trace_spool_dir of the run (or one "
                          ".jsonl spool file)")
+    ap.add_argument("--profile_path", default=None,
+                    help="instead of spools: a span csv from "
+                         "profiler.export_spans, or a comma list "
+                         "trainer0=a.csv,trainer1=b.csv, merged into "
+                         "one timeline with a lane per file (needs -o)")
     ap.add_argument("-o", "--out", default=None,
                     help="output path (default: <spool_dir>/trace.json)")
     ap.add_argument("--check", action="store_true",
@@ -269,6 +318,21 @@ def main(argv=None) -> int:
                          "one request's span ancestry must cross in "
                          "order (e.g. client,router,replica)")
     args = ap.parse_args(argv)
+
+    if args.profile_path:
+        if not args.out or args.spool_dir or args.check:
+            ap.error("--profile_path takes -o and nothing else")
+        named = parse_profile_paths(args.profile_path)
+        trace = merge_span_files(named)
+        with open(args.out, "w") as f:
+            json.dump(trace, f)
+        n_x = sum(1 for e in trace["traceEvents"] if e.get("ph") == "X")
+        print(f"wrote {args.out} ({n_x} spans, {len(named)} process "
+              f"lane{'s' if len(named) != 1 else ''}) — open in "
+              f"ui.perfetto.dev")
+        return 0
+    if not args.spool_dir:
+        ap.error("a spool directory (or --profile_path) is required")
 
     paths = find_spools(args.spool_dir)
     if not paths:
