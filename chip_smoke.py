@@ -208,6 +208,17 @@ def check_row_kernels(cfg: dict):
                              np.asarray(table.astype(jnp.float32))
                              [want_idx]),
               f"gather_rows != take for {jnp.dtype(dtype).name}")
+    ps = 16                     # a whole number of fp32 and bf16 tiles
+    pages = rng.randint(0, r // ps + 2, size=max(k // ps, 3))
+    for dtype in (jnp.float32, jnp.bfloat16):
+        table = jnp.asarray(f32 * 40).astype(dtype)
+        got = jax.jit(lambda t, p: paged_attention.gather_pages(
+            t, p, ps, interpret=interp))(table, jnp.asarray(pages))
+        want = table.reshape(r // ps, ps, d)[
+            np.minimum(pages, r // ps - 1)].reshape(-1, d)
+        check(np.array_equal(np.asarray(got.astype(jnp.float32)),
+                             np.asarray(want.astype(jnp.float32))),
+              f"gather_pages != take for {jnp.dtype(dtype).name}")
     codes = jnp.asarray(rng.randint(-127, 128, (r, d)), jnp.int8)
     scales = jnp.asarray(np.abs(rng.randn(r, h)), jnp.float32)
     got = jax.jit(lambda c, s, i: paged_attention.gather_rows_dequant(
@@ -226,8 +237,9 @@ def check_row_kernels(cfg: dict):
     want[slots[slots < r]] = rows[slots < r]
     check(np.array_equal(np.asarray(got), want),
           "scatter_rows != table.at[slots].set(rows)")
-    say("parity", row_kernels="gather f32/bf16/int8, dequant, scatter "
-        "== jnp reference (exact)", shape=[r, d], interpreted=interp)
+    say("parity", row_kernels="gather rows f32/bf16/int8, gather pages "
+        "f32/bf16, dequant, scatter == jnp reference (exact)",
+        shape=[r, d], interpreted=interp)
 
 
 def check_host_callback_ops():

@@ -267,6 +267,9 @@ def _preregister_catalog():
                 # eviction/occupancy and per-shard wire bytes
                 # (docs/performance.md 'Sharded embedding tables')
                 "paddle_tpu.ops.embed_cache",
+                # which tier the paged K/V gather was lowered to
+                # (paddle_kv_gather_lowered_total{path})
+                "paddle_tpu.ops.kv_attention",
                 "paddle_tpu.distributed.sharded_table"):
         try:
             importlib.import_module(mod)

@@ -75,8 +75,21 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
+from paddle_tpu.observability import metrics as _metrics
 
 from paddle_tpu.ops import attention_block as _ab
+
+# exporter-catalog family (docs/serving.md "Metric names"; preregistered
+# via exporters._preregister_catalog importing this module). Counts
+# LOWERINGS, not steps: one increment per K or V gather each time a
+# paged decode / verify program is traced, labelled with the tier
+# ``_paged_gather`` chose — the program-side witness of which kernel a
+# deployment's geometry gets (the device trace names the same thing:
+# ``gather_pages.NN`` / ``gather_rows.NN`` custom calls).
+KV_GATHER_LOWERED = _metrics.counter(
+    "paddle_kv_gather_lowered_total",
+    "Paged K/V gathers lowered, by tier (pages|rows|take)",
+    labelnames=("path",))
 
 
 def _scores_to_probs(s, mask, dt):
@@ -224,34 +237,58 @@ def _kv_quant(rows):
     return q.astype(jnp.int8), scale.astype(jnp.float32)
 
 
-def _paged_gather(flat, scales, rows, h, dt, mesh=None):
-    """Gather K/V rows through page-table row indices: flat [R, H, D]
-    storage (fp32 | bf16 | int8 codes), scales [R, H] fp32 or None,
-    rows [N] int32 (sentinel rows >= R clamp to the last pool row —
-    their contribution is exactly zeroed by the attention mask).
-    Returns [N, H, D] in the compute dtype. Tier selection per
-    ops/pallas: the scalar-prefetch DMA kernel on aligned TPU shapes
-    (ops/pallas/paged_attention.py), the jnp refer path otherwise
-    (``mesh``: see ``kernel_enabled``)."""
-    r, _, dk = flat.shape
-    idx = jnp.minimum(rows, r - 1)
+def _gather_tier(flat, scales, ps, mesh=None) -> str:
+    """Which implementation gathers a paged pool, decided from what is
+    being lowered and never from a flag (``mesh``: see
+    ``kernel_enabled``). On aligned TPU shapes a Pallas kernel of
+    ops/pallas/paged_attention.py: ``"pages"`` (a whole page per DMA)
+    when the storage has no codec scales and a page is a whole number
+    of the dtype's sublane tiles, ``"rows"`` (a row per DMA, dequant
+    included) for int8 pages and page sizes that are not. ``"take"``,
+    the jnp refer path, otherwise."""
     from paddle_tpu.ops import pallas as _plk
-    if _plk.kernel_enabled(128, h * dk, mesh=mesh):
+    from paddle_tpu.ops.pallas.embed_cache import sublane_tile
+    _, h, dk = flat.shape
+    if not _plk.kernel_enabled(128, h * dk, mesh=mesh):
+        return "take"
+    if scales is None and ps % sublane_tile(flat.dtype) == 0:
+        return "pages"
+    return "rows"
+
+
+def _paged_gather(flat, scales, table, ps, dt, mesh=None):
+    """Gather every slot's logical cache through the page table: flat
+    [R, H, D] storage (fp32 | bf16 | int8 codes), scales [R, H] fp32 or
+    None, table [B, MP] int32 page ids (sentinel ids >= n_pages clamp
+    to the last page — what they gather is exactly zeroed by the
+    attention mask). Returns [B * MP * ps, H, D] in the compute dtype,
+    the same bits whichever tier (:func:`_gather_tier`) moves them."""
+    r, h, dk = flat.shape
+    tier = _gather_tier(flat, scales, ps, mesh)
+    KV_GATHER_LOWERED.labels(path=tier).inc()
+    if tier != "take":
+        from paddle_tpu.ops import pallas as _plk
         from paddle_tpu.ops.pallas import paged_attention as _pk
         interp = _plk.interpret_mode()
-        if scales is not None:
-            out = _pk.gather_rows_dequant(
-                flat.reshape(r, h * dk), scales, idx, h,
-                interpret=interp)
-        else:
-            out = _pk.gather_rows(flat.reshape(r, h * dk), idx,
-                                  interpret=interp)
+        pool = flat.reshape(r, h * dk)
+    if tier == "pages":
+        out = _pk.gather_pages(pool, table.reshape(-1), ps,
+                               interpret=interp)
         return out.reshape(-1, h, dk).astype(dt)
-    out = jnp.take(flat, idx, axis=0)
-    if scales is not None:
-        out = out.astype(jnp.float32) * jnp.take(scales, idx,
-                                                 axis=0)[..., None]
-    return out.astype(dt)
+    rows = (table[:, :, None] * ps
+            + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(-1)
+    idx = jnp.minimum(rows, r - 1)
+    if tier == "take":
+        out = jnp.take(flat, idx, axis=0)
+        if scales is not None:
+            out = out.astype(jnp.float32) * jnp.take(scales, idx,
+                                                     axis=0)[..., None]
+    elif scales is not None:
+        out = _pk.gather_rows_dequant(pool, scales, idx, h,
+                                      interpret=interp)
+    else:
+        out = _pk.gather_rows(pool, idx, interpret=interp)
+    return out.reshape(-1, h, dk).astype(dt)
 
 
 def _paged_pools(ins, codec, h):
@@ -326,7 +363,7 @@ def _kv_attention_prefill_paged(ctx, ins, attrs):
                  "PAGED KV pool — write row and gather rows resolved "
                  "through the per-slot page table feed (static shapes: "
                  "zero steady-state compiles; Pallas scalar-prefetch "
-                 "gather on TPU, ops/pallas/paged_attention.py)")
+                 "page gather on TPU, ops/pallas/paged_attention.py)")
 def _kv_attention_decode_paged(ctx, ins, attrs):
     """X [B,1,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H, Dk]
     (+ PageKS/PageVS when codec=int8), PageTable [B, MP] int (flat page
@@ -370,11 +407,9 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t[:, 0], codec)
 
     # gather every slot's logical cache through its table row
-    rows = (table[:, :, None] * ps
-            + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(-1)
-    kk = _paged_gather(flat_k, fks, rows, h, dt,
+    kk = _paged_gather(flat_k, fks, table, ps, dt,
                        ctx.mesh).reshape(b, s_len, h, d)
-    vv = _paged_gather(flat_v, fvs, rows, h, dt,
+    vv = _paged_gather(flat_v, fvs, table, ps, dt,
                        ctx.mesh).reshape(b, s_len, h, d)
 
     s = jax.lax.dot_general(q, kk, (((3,), (3,)), ((0, 2), (0, 2))),
@@ -517,11 +552,9 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     flat_v, fvs = _paged_write(flat_v, fvs, wrow,
                                v_t.reshape(-1, h, dk), codec)
 
-    rows = (table[:, :, None] * ps
-            + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(-1)
-    kk = _paged_gather(flat_k, fks, rows, h, dt,
+    kk = _paged_gather(flat_k, fks, table, ps, dt,
                        ctx.mesh).reshape(b, s_len, h, d)
-    vv = _paged_gather(flat_v, fvs, rows, h, dt,
+    vv = _paged_gather(flat_v, fvs, table, ps, dt,
                        ctx.mesh).reshape(b, s_len, h, d)
 
     s = jax.lax.dot_general(q, kk, (((3,), (3,)), ((0, 2), (0, 2))),

@@ -2,8 +2,11 @@
 (Pallas TPU; ISSUE 14 tentpole — the TPP argument, arXiv:2104.05755:
 keep the cache maintenance hot loop a small set of reusable TPU-native
 primitives instead of bespoke per-model code). The hot-rows embedding
-cache uses both; the paged KV pool (``paged_attention.py``) reuses the
-gather.
+cache uses both. The paged KV pool (``paged_attention.py``) reuses the
+gather only where its indices are NOT whole aligned pages — int8
+storage (with the dequant) and page sizes that are not a whole number
+of tiles; fp32 and bf16 pools move a page per DMA there
+(``gather_pages``, ISSUE 25).
 
 Row indices ride in SMEM via scalar prefetch and the table stays in HBM
 (``pl.ANY``). Mosaic slices an HBM ref only at whole-tile granularity
@@ -13,8 +16,9 @@ carries the ALIGNED TILE GROUP that contains the row, on a 2-slot
 rotation so the next group's DMA overlaps the current one, and the row
 is selected from the group in VMEM (a masked integer-domain reduce —
 bit-exact for every dtype, -0.0 and NaN payloads included). That is a
-``sublane_tile``-fold read amplification; moving whole pages per DMA
-instead is ROADMAP S2.
+``sublane_tile``-fold read amplification and one DMA a row: 242 ns a
+row and 4 % of the HBM roofline on the v5e (PERF.md, PR 23-25) — the
+price of arbitrary row indices, which the embedding cache has.
 
 - :func:`gather_rows` — ``table[rows] -> [K, D]``, optionally scaled
   per element in the same grid step (the int8 KV dequant).

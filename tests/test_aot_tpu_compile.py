@@ -59,6 +59,13 @@ def _paged_gather(dtype):
     return pa.gather_rows, [((4096, 1024), dtype), ((2048,), I32)]
 
 
+def _paged_gather_pages(dtype):
+    # the benchmark's decode cell: 3072 pages of 16 rows, table 48 x 64
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    return (lambda p, t: pa.gather_pages(p, t, 16),
+            [((49152, 1024), dtype), ((48 * 64,), I32)])
+
+
 def _paged_dequant():
     from paddle_tpu.ops.pallas import paged_attention as pa
     return (lambda p, s, r: pa.gather_rows_dequant(p, s, r, heads=8),
@@ -121,6 +128,8 @@ CASES = {
     "paged_gather-f32-4096x1024": lambda: _paged_gather(F32),
     "paged_gather-bf16-4096x1024": lambda: _paged_gather(BF16),
     "paged_gather-int8-dequant-4096x1024": _paged_dequant,
+    "paged_gather_pages-f32-49152x1024": lambda: _paged_gather_pages(F32),
+    "paged_gather_pages-bf16-49152x1024": lambda: _paged_gather_pages(BF16),
     "embed_cache-gather-w128": lambda: _cache_gather(128),
     "embed_cache-gather-w256": lambda: _cache_gather(256),
     "embed_cache-scatter-w128": lambda: _cache_scatter(128),

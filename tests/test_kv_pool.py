@@ -286,6 +286,166 @@ def test_paged_gather_dequant_kernel_interpret():
     np.testing.assert_allclose(got, want, rtol=0, atol=0)
 
 
+def _bits(x):
+    """An array's raw bits (uint of its width): the comparison that
+    tells -0.0 from 0.0 and one NaN payload from another."""
+    x = np.asarray(x)
+    return x.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[x.itemsize])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_gather_pages_kernel_interpret(dtype):
+    """gather_pages == pool.reshape(n_pages, ps, D)[clip(pages)] BIT
+    for bit: a -0.0 and a NaN with a payload travel unchanged (no
+    select, no arithmetic), sentinel ids >= n_pages read the last
+    page, and 45 table entries are neither a multiple of the copies in
+    flight (32) nor fewer than them — the rolling window wraps."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    n_pages, ps, d = 10, 16, 128
+    rng = np.random.RandomState(0)
+    pool = jnp.asarray(rng.randn(n_pages * ps, d) * 50).astype(dtype)
+    pool = pool.at[5 * ps + 3, 7].set(-0.0)
+    uint = jnp.uint32 if dtype == "float32" else jnp.uint16
+    nan = jax.lax.bitcast_convert_type(
+        jnp.asarray(0x7FC12345 if dtype == "float32" else 0x7FC5, uint),
+        pool.dtype)
+    pool = pool.at[9 * ps + 15, 0].set(nan)
+    pages = rng.randint(0, n_pages + 3, size=45)    # sentinels >= n_pages
+    pages[:3] = (5, 9, n_pages)
+    assert pages.shape[0] % pa._WINDOW and pages.shape[0] > pa._WINDOW
+    got = pa.gather_pages(pool, jnp.asarray(pages), ps, interpret=True)
+    assert got.dtype == pool.dtype and got.shape == (45 * ps, d)
+    want = _bits(pool).reshape(n_pages, ps, d)[
+        np.clip(pages, 0, n_pages - 1)].reshape(-1, d)
+    np.testing.assert_array_equal(_bits(got), want)
+    # fewer entries than copies in flight: the window is cut to them
+    got = pa.gather_pages(pool, jnp.asarray(pages[:5]), ps, interpret=True)
+    np.testing.assert_array_equal(_bits(got), want[:5 * ps])
+
+
+def _paged_op_inputs(codec, verify, seed=0):
+    """Random inputs of kv_attention_decode_paged / _verify_paged at a
+    tiny geometry whose pages are whole tiles of every float storage
+    dtype (16 rows): 3 slots (one inactive), 6 pages, tables with
+    sentinel entries past each slot's span."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kv_attention as kva
+    rng = np.random.RandomState(seed)
+    b, h, dk, n_pages, ps, mp = 3, 2, 8, 6, 16, 3
+    m, k1 = h * dk, (3 if verify else 1)
+    store = {"none": jnp.float32, "bf16": jnp.bfloat16}.get(codec)
+    ins = {"X": [jnp.asarray(rng.randn(b, k1, m), jnp.float32)]}
+    for w in ("Wq", "Wk", "Wv", "Wo"):
+        ins[w] = [jnp.asarray(rng.randn(m, m) * 0.3, jnp.float32)]
+    for name in ("PageK", "PageV"):
+        rows = jnp.asarray(rng.randn(n_pages, ps, h, dk), jnp.float32)
+        if codec == "int8":
+            codes, scale = kva._kv_quant(rows)
+            ins[name], ins[name + "S"] = [codes], [scale]
+        else:
+            ins[name] = [rows.astype(store)]
+    ins["PageTable"] = [jnp.asarray(
+        [[4, 1, n_pages], [0, 5, 2], [n_pages] * mp], jnp.int32)]
+    col = lambda *v: [jnp.asarray(v, jnp.int32).reshape(-1, 1)]
+    ins.update(Pos=col(20, 33, 0), SeqLen=col(13, 16, 0),
+               GenStart=col(16, 16, 0), Active=col(1, 1, 0))
+    if verify:
+        ins["WinLen"] = col(3, 2, 1)
+    return ins, {"n_head": h, "codec": codec}
+
+
+@pytest.mark.parametrize("codec", ["none", "bf16", "int8"])
+@pytest.mark.parametrize("op", ["kv_attention_decode_paged",
+                                "kv_attention_verify_paged"])
+def test_paged_ops_identical_through_every_gather_tier(op, codec,
+                                                       monkeypatch):
+    """The page kernel, the row kernel and jnp.take move the same bits
+    wherever the mask looks, so the decode and verify ops give
+    identical outputs AND pools through each (the tier is forced HERE,
+    by patching the function that chooses it; the product has no
+    switch). They differ only in what a SENTINEL entry reads — the last
+    page against the last row, 16 times — which no active slot attends.
+    int8 pages never take the page kernel (no dequant there)."""
+    import types
+    from paddle_tpu.core.registry import get_op
+    from paddle_tpu.ops import kv_attention as kva
+    ins, attrs = _paged_op_inputs(codec, verify=op.endswith("verify_paged"))
+    ctx = types.SimpleNamespace(mesh=None)
+    results = {}
+    for tier in ("take", "rows") + (("pages",) if codec != "int8" else ()):
+        monkeypatch.setattr(kva, "_gather_tier", lambda *a, t=tier: t)
+        before = kva.KV_GATHER_LOWERED.labels(path=tier).value
+        results[tier] = get_op(op).emit(ctx, ins, attrs)
+        assert kva.KV_GATHER_LOWERED.labels(path=tier).value == before + 2
+    want = results.pop("take")
+    assert np.all(np.isfinite(np.asarray(want["Out"][0])))
+    active = np.asarray(ins["Active"][0]).reshape(-1) > 0
+    for tier, got in results.items():
+        assert sorted(got) == sorted(want)
+        for slot in want:
+            g, w = _bits(got[slot][0]), _bits(want[slot][0])
+            if slot == "Out":       # a free slot's output row is
+                g, w = g[active], w[active]     # meaningless by contract
+            np.testing.assert_array_equal(
+                g, w, err_msg=f"{op} {codec}: {slot}, {tier} vs take")
+
+
+@pytest.mark.parametrize("case, want", [
+    ("cpu-f32-ps16", "take"),           # no kernel tier off the chip
+    ("kernel-f32-ps16", "pages"),       # the benchmark's pool
+    ("kernel-bf16-ps16", "pages"),
+    ("kernel-bf16-ps8", "rows"),        # half a bf16 tile
+    ("kernel-f32-ps4", "rows"),
+    ("kernel-int8-ps16", "rows"),       # 32-row tile, and the dequant
+])
+def test_kv_gather_lowering_counter_names_the_tier(case, want,
+                                                   monkeypatch):
+    """Lowering a paged decode counts one K and one V gather under the
+    tier the pool's dtype, page size and codec select:
+    paddle_kv_gather_lowered_total{path}. ``kernel_enabled`` is steered
+    here because it asks for a TPU backend."""
+    import types
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.registry import get_op
+    from paddle_tpu.ops import kv_attention as kva
+    from paddle_tpu.ops import pallas as plk
+    tier, storage, ps = case.split("-")
+    ps = int(ps[2:])
+    codec = {"f32": "none", "bf16": "bf16", "int8": "int8"}[storage]
+    ins, attrs = _paged_op_inputs(codec, verify=False)
+    for name in ("PageK", "PageV", "PageKS", "PageVS"):
+        if name in ins:             # same rows, pages of ``ps``
+            a = ins[name][0]
+            ins[name] = [a.reshape((-1, ps) + a.shape[2:])]
+    ins["PageTable"] = [jnp.zeros((3, 48 // ps), jnp.int32)]
+    if tier == "kernel":
+        monkeypatch.setattr(plk, "kernel_enabled",
+                            lambda *a, **k: True)
+    fam = kva.KV_GATHER_LOWERED
+    before = {t: fam.labels(path=t).value
+              for t in ("pages", "rows", "take")}
+    jax.eval_shape(lambda i: get_op("kv_attention_decode_paged").emit(
+        types.SimpleNamespace(mesh=None), i, attrs), ins)
+    grew = {t: fam.labels(path=t).value - before[t] for t in before}
+    assert grew == {t: (2 if t == want else 0) for t in before}
+
+
+def test_kv_gather_counter_counts_a_real_program_and_is_cataloged():
+    """Warming the paged engine lowers its decode program on this CPU:
+    K and V of both layers take the refer path, and the family is in
+    the scrape's catalog at zero traffic."""
+    from paddle_tpu.observability import exporters, metrics as obs_metrics
+    from paddle_tpu.ops import kv_attention as kva
+    _paged_lm()
+    assert kva.KV_GATHER_LOWERED.labels(path="take").value >= 4
+    exporters._preregister_catalog()
+    assert "paddle_kv_gather_lowered_total" in \
+        obs_metrics.default_registry().snapshot()
+
+
 # ---------------------------------------------------------------------------
 # engine: paged views vs the sequential oracle
 # ---------------------------------------------------------------------------
