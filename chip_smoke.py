@@ -42,6 +42,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -438,9 +439,24 @@ def serve_variant(cfg: dict, label: str, layout: str, codec, oracle):
             engine._cb_decode, engine._decode_feeds())
         ).compile().as_text()
         n_custom = text.count("tpu_custom_call")
-        if layout == "paged" and jax.default_backend() == "tpu":
-            check(n_custom > 0, "paged decode step holds no Pallas "
-                  "gather (tpu_custom_call)")
+        pool = {}
+        if layout == "paged":
+            # the pool variable: [n_pages, page_size, H*D], which the
+            # chip keeps row-major at rest (minor_to_major 2,1,0)
+            shape = next(tuple(a.shape) for n, a in engine.scope.iter_vars()
+                         if n.endswith("_page_k_0"))
+            check(shape == (engine.n_pages, engine.page_size, s["d_model"]),
+                  f"pool variable is {shape}")
+            dims = ",".join(str(d) for d in shape)
+            at_rest = set(re.findall(r"\w+\[%s\]\{([\d,]+)" % dims,
+                                     text.splitlines()[0]))
+            pool = {"pool_shape": list(shape),
+                    "pool_minor_to_major": sorted(at_rest)}
+            if jax.default_backend() == "tpu":
+                check(n_custom > 0, "paged decode step holds no Pallas "
+                      "gather (tpu_custom_call)")
+                check(at_rest == {"2,1,0"}, f"pool at rest is {at_rest}, "
+                      f"not row-major: every step would transpose it")
         how = _check_against_oracle(cfg, oracle, prompts[:2], outs[:2])
         say("server", variant=label, views=T.slot_modes(layout),
             warm_up_s=round(warm_s, 2),
@@ -450,7 +466,7 @@ def serve_variant(cfg: dict, label: str, layout: str, codec, oracle):
             in_flight_at_second_wave=in_flight,
             compiles_after_warm_up=0, aot_fallbacks=0,
             tpu_custom_calls_in_decode_step=n_custom, oracle_check=how,
-            peak_bytes_in_use=_peak_bytes())
+            peak_bytes_in_use=_peak_bytes(), **pool)
     finally:
         server.stop()
 

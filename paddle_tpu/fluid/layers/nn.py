@@ -737,8 +737,10 @@ def kv_attention_prefill_paged(x, rows, d_model, n_head, page_k, page_v,
                                param_attr=None, name=None):
     """Paged-pool prefill (ISSUE 17): causal self-attention over the
     prompt whose K/V rows scatter into the PAGED pool caches
-    (``page_k``/``page_v``, persistable [n_pages, page_size, H, D] vars
-    read and written under the same names — donated state) at the
+    (``page_k``/``page_v``, persistable [n_pages, page_size, H*D] vars
+    read and written under the same names — donated state; the whole
+    model width on the minor dimension keeps them row-major at rest on
+    the TPU, ops/kv_attention.py:_paged_pools) at the
     per-position flat row indices ``rows`` [B*T, 1] from the slot's
     page-table lease. Sentinel rows (>= n_pages*page_size) DROP — how
     prefix-SHARED pages are skipped (already resident, bit-identical:
@@ -772,7 +774,10 @@ def kv_attention_decode_paged(x, page_table, pos, seq_len, gen_start,
     position j of slot b resolves through the page-table feed
     (``page_table`` [B, max_pages] int — a STATIC-shape feed, so every
     join/leave/page mix dispatches the same executable, zero
-    steady-state compiles). The gather runs the scalar-prefetch Pallas
+    steady-state compiles). ``page_k``/``page_v`` are the
+    [n_pages, page_size, H*D] pools of ``kv_attention_prefill_paged``
+    (+ [n_pages, page_size, H] scale planes under ``codec='int8'``).
+    The gather runs the scalar-prefetch Pallas
     kernel on TPU (ops/pallas/paged_attention.py) and dequantizes
     in-gather under ``codec='int8'``. x [B, 1, M] -> [B, 1, M]
     (ops/kv_attention.py; docs/serving.md 'Paged KV cache')."""
@@ -839,6 +844,7 @@ def kv_attention_verify_paged(x, page_table, pos, seq_len, gen_start,
     window can never write another slot's pages (admission reserves the
     draft-window overshoot, ``PagePool.span_for(draft_window=K)``).
     x [B, K+1, M], page_table [B, max_pages] int,
+    page_k/page_v [n_pages, page_size, H*D],
     pos/seq_len/gen_start/active/win_len [B, 1] int -> [B, K+1, M]
     (ops/kv_attention.py; docs/serving.md 'Speculative decoding')."""
     helper = LayerHelper("kv_attention_verify_paged", name=name)

@@ -205,7 +205,7 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 #                    on-device. This is the executable the in-flight
 #                    scheduler re-dispatches forever (ISSUE 9).
 #   "prefill_paged" / "decode_paged" — the slot pair over a PAGED pool
-#                    (ISSUE 17): [n_pages, page_size, H, D] page pools
+#                    (ISSUE 17): [n_pages, page_size, H*D] page pools
 #                    replace the worst-case [n_slots, S, H, D] region;
 #                    prefill writes through per-position flat row
 #                    indices (sentinel = shared-prefix skip), decode
@@ -237,7 +237,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     ``cache_len`` decouples the cache size from this view's prompt
     bucket (ladder prefills at P < P_max still write full-size caches);
     slot AND paged modes need ``n_slots``. The paged views (ISSUE 17)
-    swap the [n_slots, S, H, D] pool for [n_pages, page_size, H, D]
+    swap the [n_slots, S, H, D] pool for [n_pages, page_size, H*D]
     page pools behind a per-slot page-table feed — ``page_size`` must
     divide cache_len (the decode gather then covers exactly cache_len
     logical rows: fp32 paged decode is bit-identical to the slot op);
@@ -468,7 +468,10 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                 attn_in, pos, seq_len, gen_start, active, win_len,
                 d_model, n_head, pk, pv, param_attr=attn_pa(i))
         elif mode.endswith("_paged"):
-            pshape = [n_pages, page_size, n_head, d_k]
+            # the whole model width on the minor dimension: row-major
+            # at rest on the TPU, a page contiguous (ops/kv_attention
+            # .py:_paged_pools has why [.., n_head, d_k] was not)
+            pshape = [n_pages, page_size, n_head * d_k]
             pk = pool_var(f"{name}_page_k_{i}", pshape, store_dt)
             pv = pool_var(f"{name}_page_v_{i}", pshape, store_dt)
             pks = pvs = None

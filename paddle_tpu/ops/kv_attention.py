@@ -248,8 +248,7 @@ def _gather_tier(flat, scales, ps, mesh=None) -> str:
     the jnp refer path, otherwise."""
     from paddle_tpu.ops import pallas as _plk
     from paddle_tpu.ops.pallas.embed_cache import sublane_tile
-    _, h, dk = flat.shape
-    if not _plk.kernel_enabled(128, h * dk, mesh=mesh):
+    if not _plk.kernel_enabled(128, flat.shape[1], mesh=mesh):
         return "take"
     if scales is None and ps % sublane_tile(flat.dtype) == 0:
         return "pages"
@@ -258,68 +257,98 @@ def _gather_tier(flat, scales, ps, mesh=None) -> str:
 
 def _paged_gather(flat, scales, table, ps, dt, mesh=None):
     """Gather every slot's logical cache through the page table: flat
-    [R, H, D] storage (fp32 | bf16 | int8 codes), scales [R, H] fp32 or
-    None, table [B, MP] int32 page ids (sentinel ids >= n_pages clamp
-    to the last page — what they gather is exactly zeroed by the
-    attention mask). Returns [B * MP * ps, H, D] in the compute dtype,
-    the same bits whichever tier (:func:`_gather_tier`) moves them."""
-    r, h, dk = flat.shape
+    [R, M] storage (fp32 | bf16 | int8 codes; M = H * Dk), scales
+    [R, H] fp32 or None, table [B, MP] int32 page ids (sentinel ids >=
+    n_pages clamp to the last page — what they gather is exactly zeroed
+    by the attention mask). Returns [B, MP * ps, M] in the compute
+    dtype, the same bits whichever tier (:func:`_gather_tier`) moves
+    them. The pool goes to the kernels as it is stored: no reshape
+    touches its minor dimension."""
+    r, m = flat.shape
     tier = _gather_tier(flat, scales, ps, mesh)
     KV_GATHER_LOWERED.labels(path=tier).inc()
     if tier != "take":
         from paddle_tpu.ops import pallas as _plk
         from paddle_tpu.ops.pallas import paged_attention as _pk
         interp = _plk.interpret_mode()
-        pool = flat.reshape(r, h * dk)
     if tier == "pages":
-        out = _pk.gather_pages(pool, table.reshape(-1), ps,
+        out = _pk.gather_pages(flat, table.reshape(-1), ps,
                                interpret=interp)
-        return out.reshape(-1, h, dk).astype(dt)
-    rows = (table[:, :, None] * ps
-            + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(-1)
-    idx = jnp.minimum(rows, r - 1)
-    if tier == "take":
-        out = jnp.take(flat, idx, axis=0)
-        if scales is not None:
-            out = out.astype(jnp.float32) * jnp.take(scales, idx,
-                                                     axis=0)[..., None]
-    elif scales is not None:
-        out = _pk.gather_rows_dequant(pool, scales, idx, h,
-                                      interpret=interp)
     else:
-        out = _pk.gather_rows(pool, idx, interpret=interp)
-    return out.reshape(-1, h, dk).astype(dt)
+        rows = (table[:, :, None] * ps
+                + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
+                ).reshape(-1)
+        idx = jnp.minimum(rows, r - 1)
+        if tier == "take":
+            out = jnp.take(flat, idx, axis=0)
+            if scales is not None:
+                h = scales.shape[1]
+                out = (out.astype(jnp.float32).reshape(-1, h, m // h)
+                       * jnp.take(scales, idx, axis=0)[..., None])
+        elif scales is not None:
+            out = _pk.gather_rows_dequant(flat, scales, idx,
+                                          scales.shape[1],
+                                          interpret=interp)
+        else:
+            out = _pk.gather_rows(flat, idx, interpret=interp)
+    return out.reshape(table.shape[0], -1, m).astype(dt)
 
 
-def _paged_pools(ins, codec, h):
-    """The paged pool operands as flat [R, H, D] views (+ flat [R, H]
-    scale views for int8). Reshaping [n_pages, ps, H, D] -> [R, H, D]
-    is a bitcast — XLA keeps the donated input/output aliasing through
-    it (proglint --memory witnesses this)."""
+def _paged_pools(ins, codec):
+    """The paged pool operands as flat [R, M] row views, M = H * Dk
+    (+ flat [R, H] scale views for int8), and the pool's geometry.
+
+    The pool variable is [n_pages, page_size, M]. With the whole model
+    width on the minor dimension an fp32 (8, 128) or bf16 (16, 128)
+    tile is full, so the TPU keeps the pool row-major at rest, a page
+    is ``page_size * M`` contiguous elements, and [n_pages, ps, M] ->
+    [R, M] is a bitcast: scatter, gather kernel and state output share
+    one layout and the donated input/output aliasing holds through it
+    (proglint --memory and tests/test_aot_tpu_compile.py witness
+    this). It was NOT one while the variable was [n_pages, ps, H, Dk]:
+    a 64-wide minor dimension fills half a tile, the chip kept that
+    pool with the PAGE index on the lanes, and every program transposed
+    every pool in and out (PERF.md, PR 25 and PR 28)."""
     page_k, page_v = first(ins, "PageK"), first(ins, "PageV")
-    n_pages, ps = int(page_k.shape[0]), int(page_k.shape[1])
-    dk = int(page_k.shape[3])
+    n_pages, ps, m = (int(d) for d in page_k.shape)
     rtot = n_pages * ps
-    flat_k = page_k.reshape(rtot, h, dk)
-    flat_v = page_v.reshape(rtot, h, dk)
+    flat_k = page_k.reshape(rtot, m)
+    flat_v = page_v.reshape(rtot, m)
     fks = fvs = None
     if codec == "int8":
-        fks = first(ins, "PageKS").reshape(rtot, h)
-        fvs = first(ins, "PageVS").reshape(rtot, h)
+        fks = first(ins, "PageKS").reshape(rtot, -1)
+        fvs = first(ins, "PageVS").reshape(rtot, -1)
     return flat_k, flat_v, fks, fvs, n_pages, ps, rtot
 
 
-def _paged_write(flat, fscale, rows, vals, codec):
-    """Scatter K/V rows (and int8 scales) at flat ``rows``; sentinel
-    rows (>= R: skipped shared-prefix positions, inactive slots) DROP —
-    the copy-on-write contract: a shared page is never written, the
-    divergent request's rows land in its own private page."""
-    if codec == "int8":
-        codes, scale = _kv_quant(vals.astype(jnp.float32))
-        flat = flat.at[rows].set(codes, mode="drop")
-        fscale = fscale.at[rows].set(scale, mode="drop")
-        return flat, fscale
-    return flat.at[rows].set(vals.astype(flat.dtype), mode="drop"), None
+def _paged_write(flat, fscale, rows, vals):
+    """Scatter new K/V rows ``vals`` [N, M] (the projections, flattened
+    over heads) into the flat [R, M] pool at ``rows``; sentinel rows
+    (>= R: skipped shared-prefix positions, inactive slots) DROP — the
+    copy-on-write contract: a shared page is never written, the
+    divergent request's rows land in its own private page. With scale
+    planes ``fscale`` [R, H] (codec int8) the NEW rows are quantised
+    per (position, head) on their own [N, H, Dk] view — never on a view
+    of the pool — and the codes land as [N, M] rows like any other."""
+    n, m = vals.shape
+    if fscale is None:
+        return flat.at[rows].set(vals.astype(flat.dtype),
+                                 mode="drop"), None
+    codes, scale = _kv_quant(
+        vals.astype(jnp.float32).reshape(n, fscale.shape[1], -1))
+    return (flat.at[rows].set(codes.reshape(n, m), mode="drop"),
+            fscale.at[rows].set(scale, mode="drop"))
+
+
+def _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps):
+    """The paged ops' outputs, pools back in their declared shapes."""
+    res = {"Out": [out],
+           "PageKOut": [flat_k.reshape(n_pages, ps, -1)],
+           "PageVOut": [flat_v.reshape(n_pages, ps, -1)]}
+    if fks is not None:
+        res["PageKSOut"] = [fks.reshape(n_pages, ps, -1)]
+        res["PageVSOut"] = [fvs.reshape(n_pages, ps, -1)]
+    return res
 
 
 @register_op("kv_attention_prefill_paged", no_grad=True,
@@ -330,7 +359,7 @@ def _paged_write(flat, fscale, rows, vals, codec):
                  "bit-identical by construction: K/V at position j "
                  "depends only on token j)")
 def _kv_attention_prefill_paged(ctx, ins, attrs):
-    """X [B,T,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H, Dk]
+    """X [B,T,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H * Dk]
     (+ PageKS/PageVS [n_pages, ps, H] fp32 when codec=int8),
     Rows [B*T, 1] int: flat pool row per prompt position, sentinel
     (>= n_pages*ps) for shared-prefix and skipped positions -> Out
@@ -341,21 +370,12 @@ def _kv_attention_prefill_paged(ctx, ins, attrs):
     h = int(attrs["n_head"])
     codec = str(attrs.get("codec", "none"))
     rows = jnp.asarray(first(ins, "Rows")).reshape(-1).astype(jnp.int32)
-    flat_k, flat_v, fks, fvs, n_pages, ps, _ = _paged_pools(ins, codec, h)
+    flat_k, flat_v, fks, fvs, n_pages, ps, _ = _paged_pools(ins, codec)
     out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
-    dk = flat_k.shape[2]
-    flat_k, fks = _paged_write(flat_k, fks, rows,
-                               k.reshape(-1, h, dk), codec)
-    flat_v, fvs = _paged_write(flat_v, fvs, rows,
-                               v.reshape(-1, h, dk), codec)
-    shape4 = (n_pages, ps, h, dk)
-    res = {"Out": [out],
-           "PageKOut": [flat_k.reshape(shape4)],
-           "PageVOut": [flat_v.reshape(shape4)]}
-    if codec == "int8":
-        res["PageKSOut"] = [fks.reshape(n_pages, ps, h)]
-        res["PageVSOut"] = [fvs.reshape(n_pages, ps, h)]
-    return res
+    m = x.shape[2]
+    flat_k, fks = _paged_write(flat_k, fks, rows, k.reshape(-1, m))
+    flat_v, fvs = _paged_write(flat_v, fvs, rows, v.reshape(-1, m))
+    return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 
 
 @register_op("kv_attention_decode_paged", no_grad=True,
@@ -365,7 +385,7 @@ def _kv_attention_prefill_paged(ctx, ins, attrs):
                  "zero steady-state compiles; Pallas scalar-prefetch "
                  "page gather on TPU, ops/pallas/paged_attention.py)")
 def _kv_attention_decode_paged(ctx, ins, attrs):
-    """X [B,1,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H, Dk]
+    """X [B,1,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H * Dk]
     (+ PageKS/PageVS when codec=int8), PageTable [B, MP] int (flat page
     id per logical page; sentinel n_pages past the slot's span),
     Pos/SeqLen/GenStart/Active [B,1] int — geometry identical to
@@ -382,7 +402,7 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     d = m // h
     dt = x.dtype
     flat_k, flat_v, fks, fvs, n_pages, ps, rtot = \
-        _paged_pools(ins, codec, h)
+        _paged_pools(ins, codec)
     table = jnp.asarray(first(ins, "PageTable")).astype(jnp.int32)
     mp = table.shape[1]
     s_len = mp * ps
@@ -403,8 +423,8 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     wpage = jnp.take_along_axis(table, (pos // ps)[:, None],
                                 axis=1)[:, 0]
     wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
-    flat_k, fks = _paged_write(flat_k, fks, wrow, k_t[:, 0], codec)
-    flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t[:, 0], codec)
+    flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, m))
+    flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, m))
 
     # gather every slot's logical cache through its table row
     kk = _paged_gather(flat_k, fks, table, ps, dt,
@@ -425,14 +445,7 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     out = jax.lax.dot_general(c, wo.reshape(h, d, m),
                               (((1, 3), (0, 1)), ((), ())),
                               preferred_element_type=jnp.float32).astype(dt)
-    shape4 = (n_pages, ps, h, d)
-    res = {"Out": [out],
-           "PageKOut": [flat_k.reshape(shape4)],
-           "PageVOut": [flat_v.reshape(shape4)]}
-    if codec == "int8":
-        res["PageKSOut"] = [fks.reshape(n_pages, ps, h)]
-        res["PageVSOut"] = [fvs.reshape(n_pages, ps, h)]
-    return res
+    return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 
 
 @register_op("kv_attention_verify", no_grad=True,
@@ -504,7 +517,7 @@ def _kv_attention_verify(ctx, ins, attrs):
                  "rows drop: beyond-lease and inactive writes never "
                  "land), gather and mask as kv_attention_decode_paged")
 def _kv_attention_verify_paged(ctx, ins, attrs):
-    """X [B,K1,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H, Dk]
+    """X [B,K1,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H * Dk]
     (+ PageKS/PageVS when codec=int8), PageTable [B, MP] int,
     Pos/SeqLen/GenStart/Active/WinLen [B,1] — geometry identical to
     kv_attention_verify with the cache row for logical position j at
@@ -521,7 +534,7 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     d = m // h
     dt = x.dtype
     flat_k, flat_v, fks, fvs, n_pages, ps, rtot = \
-        _paged_pools(ins, codec, h)
+        _paged_pools(ins, codec)
     table = jnp.asarray(first(ins, "PageTable")).astype(jnp.int32)
     mp = table.shape[1]
     s_len = mp * ps
@@ -546,11 +559,8 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
                                 axis=1)
     ok = active[:, None] & (i[None, :] < wlen[:, None]) & (wp < s_len)
     wrow = jnp.where(ok, wpage * ps + wp % ps, rtot).reshape(-1)
-    dk = flat_k.shape[2]
-    flat_k, fks = _paged_write(flat_k, fks, wrow,
-                               k_t.reshape(-1, h, dk), codec)
-    flat_v, fvs = _paged_write(flat_v, fvs, wrow,
-                               v_t.reshape(-1, h, dk), codec)
+    flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(-1, m))
+    flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(-1, m))
 
     kk = _paged_gather(flat_k, fks, table, ps, dt,
                        ctx.mesh).reshape(b, s_len, h, d)
@@ -570,14 +580,7 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     out = jax.lax.dot_general(c, wo.reshape(h, d, m),
                               (((1, 3), (0, 1)), ((), ())),
                               preferred_element_type=jnp.float32).astype(dt)
-    shape4 = (n_pages, ps, h, d)
-    res = {"Out": [out],
-           "PageKOut": [flat_k.reshape(shape4)],
-           "PageVOut": [flat_v.reshape(shape4)]}
-    if codec == "int8":
-        res["PageKSOut"] = [fks.reshape(n_pages, ps, h)]
-        res["PageVSOut"] = [fvs.reshape(n_pages, ps, h)]
-    return res
+    return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 
 
 @register_op("token_sample", no_grad=True,

@@ -4,9 +4,14 @@ tentpole, page-granular since ISSUE 25).
 The paged decode attention reads each slot's K/V through its page
 table: logical cache position ``j`` of slot ``b`` lives at flat pool
 row ``table[b, j // page_size] * page_size + j % page_size`` of the
-``[n_pages * page_size, H * D]`` pool view. So every run of
-``page_size`` gathered rows is ONE WHOLE PAGE, contiguous in the pool
-and starting at a multiple of ``page_size`` rows. Two kernels read it,
+``[n_pages * page_size, H * D]`` pool view — since ISSUE 28 a bitcast
+of the pool variable, which is declared ``[n_pages, page_size, H * D]``
+so that the TPU keeps it row-major at rest
+(``ops/kv_attention.py:_paged_pools``; as ``[.., H, D]`` with D = 64 it
+was transposed into this view, whole, before every call). So every run
+of ``page_size`` gathered rows is ONE WHOLE PAGE, contiguous in the
+pool and starting at a multiple of ``page_size`` rows. Two kernels read
+it,
 and ``ops/kv_attention.py:_paged_gather`` picks between them from the
 storage dtype, the page size and the codec:
 

@@ -31,7 +31,7 @@ steady-state serving performs zero XLA compilations
 
 - :class:`PagedSlotGenerativeModel` (ISSUE 17) — the slot engine over a
   PAGED KV pool: slots address their cache through a per-slot page
-  table into one shared ``[n_pages, page_size, H, D]`` pool, admission
+  table into one shared ``[n_pages, page_size, H*D]`` pool, admission
   is gated by FREE PAGES for the request's span (prompt bucket + token
   budget) instead of a whole worst-case row, and requests with a
   common prompt prefix physically share full prefix pages through a
@@ -1318,7 +1318,8 @@ class SlotGenerativeModel:
 class PagedSlotGenerativeModel(SlotGenerativeModel):
     """Slot engine over a PAGED KV pool (ISSUE 17): the decode program
     reads each slot's K/V through a ``[n_slots, max_pages]`` page-table
-    feed into one shared ``[n_pages, page_size, H, D]`` pool per layer,
+    feed into one shared ``[n_pages, page_size, H*D]`` pool per layer
+    (row-major at rest on the chip: ops/kv_attention.py:_paged_pools),
     so HBM holds pages for the requests actually in flight instead of
     ``n_slots`` worst-case rows. Admission acquires
     ``ceil((prompt_bucket + budget) / page_size)`` pages from

@@ -251,7 +251,7 @@ def op_fwd_flops(block, op_type, inputs, outputs, attrs, batch,
         if x is None or tbl is None or pk is None:
             return 0.0
         b, k1, m = x[-3], x[-2], x[-1]
-        s = tbl[-1] * pk[-3]
+        s = tbl[-1] * pk[-2]     # pool [n_pages, page_size, H*Dk]
         h = int(attrs.get("n_head", 1))
         d = m // max(h, 1)
         return 2.0 * b * m * m * 4.0 * k1 + 2.0 * b * h * k1 * s * d * 2.0

@@ -194,3 +194,131 @@ def test_mesh_step_compiles_with_kernels_gated(v5e, chip, monkeypatch):
     text = compiled_text(DistributeConfig(mesh=mesh, data_axis="dp"))
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text          # the mean over the dp-split batch
+
+
+# ---------------------------------------------------------------------------
+# the paged KV pool's layout at rest (PR 28): a property only the TPU
+# compiler decides, read from the HLO it emits for the benchmark's pool
+# ---------------------------------------------------------------------------
+
+_POOL = dict(n_pages=3072, ps=16, h=16, dk=64, slots=48, table=64)
+
+
+def _lower_paged_op(op, store, chip, bucket=512):
+    """Compile one paged op at the serving cell's geometry (3072 pages
+    of 16 x 1024, 48 slots x 64 table entries; batch-1 prefill of
+    ``bucket`` tokens) with its pools donated -> optimized HLO text."""
+    import types
+    from paddle_tpu.core.registry import get_op
+    g = _POOL
+    m = g["h"] * g["dk"]
+    b, t = (1, bucket) if op == "kv_attention_prefill_paged" \
+        else (g["slots"], 1)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    pools = {n: struct((g["n_pages"], g["ps"], m), store)
+             for n in ("PageK", "PageV")}
+    col = struct((b, 1), I32)
+    ins = {"X": struct((b, t, m), F32)}
+    ins.update({w: struct((m, m), F32) for w in ("Wq", "Wk", "Wv", "Wo")})
+    if op == "kv_attention_prefill_paged":
+        ins["Rows"] = struct((b * t, 1), I32)
+    else:
+        ins.update(PageTable=struct((b, g["table"]), I32), Pos=col,
+                   SeqLen=col, GenStart=col, Active=col)
+    attrs = {"n_head": g["h"],
+             "codec": "bf16" if store == BF16 else "none"}
+
+    def step(pools, ins):
+        slots = {k: [v] for k, v in {**ins, **pools}.items()}
+        out = get_op(op).emit(types.SimpleNamespace(mesh=None), slots,
+                              attrs)
+        return {k: v[0] for k, v in out.items()}
+    return jax.jit(step, donate_argnums=(0,)).lower(pools, ins)\
+        .compile().as_text()
+
+
+def _hlo_ops(text):
+    """name -> (opcode, element count of the result, operand names,
+    the line) for the instructions of a compiled module's text."""
+    import math
+    import re
+    ops = {}
+    for line in text.splitlines():
+        hit = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \(?\w+\[([\d,]*)\]\S* "
+                       r"([\w\-]+)\((.*)", line)
+        if hit:
+            name, dims, opcode, rest = hit.groups()
+            count = math.prod(int(d) for d in dims.split(",") if d)
+            ops[name] = (opcode, count, re.findall(r"%([\w.\-]+)", rest),
+                         line)
+    return ops
+
+
+def _comes_from(ops, name, name_prefix):
+    """Does instruction ``name`` depend, through its pool-sized
+    operands, on one whose name starts with ``name_prefix`` (XLA names
+    an instruction after its opcode or its kernel: ``copy.13``,
+    ``gather_pages.2``)?"""
+    size = ops[name][1]
+    seen, todo = set(), [name]
+    while todo:
+        cur = todo.pop()
+        if cur.startswith(name_prefix):
+            return True
+        for src in ops[cur][2]:
+            if src in ops and src not in seen and ops[src][1] == size:
+                seen.add(src)
+                todo.append(src)
+    return False
+
+
+@pytest.mark.parametrize("store", ["float32", "bfloat16"])
+@pytest.mark.parametrize("op, copies_after_gather", [
+    ("kv_attention_prefill_paged", 0),
+    # the gathered K and V ([48*1024, 1024], by chance the pool's size)
+    # are each relaid twice for the two M=1 contractions, whose
+    # multiply-reduce wants the cache position on the lanes (PERF.md
+    # section 5); nothing else of that size may be copied
+    ("kv_attention_decode_paged", 4),
+])
+def test_paged_pool_is_row_major_at_rest_on_v5e(chip, monkeypatch, op,
+                                                copies_after_gather,
+                                                store):
+    """The pool variable [n_pages, page_size, H*Dk] is row-major at
+    rest: (a) parameters and results carry the descending layout, (b)
+    the prefill copies no pool, (c) the decode copies none on the way
+    INTO ``gather_pages`` and only the gathered caches after it, (d)
+    each pool's input and output share a buffer. With a 64-wide minor
+    dimension ([.., H, Dk]) the same programs transposed every pool in
+    and out: 109 of a 136 ms decode step (PERF.md, PR 25 and PR 28)."""
+    import re
+    from paddle_tpu.ops import pallas as pk
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    g = _POOL
+    text = _lower_paged_op(op, jnp.dtype(store), chip)
+    head = text.splitlines()[0]
+    short = {"float32": "f32", "bfloat16": "bf16"}[store]
+    pool = rf"{short}\[{g['n_pages']},{g['ps']},{g['h'] * g['dk']}\]"
+    at_rest = re.findall(pool + r"\{([\d,]+)", head)
+    assert len(at_rest) == 4 and set(at_rest) == {"2,1,0"}, head  # (a)
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
+    assert aliased and aliased.group(1).count("-alias") == 2, head  # (d)
+
+    ops = _hlo_ops(text)
+    n_pool = g["n_pages"] * g["ps"] * g["h"] * g["dk"]
+    copies = [n for n, (opcode, count, _, _) in ops.items()
+              if opcode in ("copy", "transpose") and count == n_pool]
+    gathers = [n for n in ops if n.startswith("gather_pages")]
+    if op == "kv_attention_prefill_paged":
+        assert not gathers
+    else:
+        assert len(gathers) == 2 and \
+            all("tpu_custom_call" in ops[n][3] for n in gathers)
+        for n in gathers:                                       # (c)
+            assert not _comes_from(ops, n, "copy"), ops[n][3][:200]
+    assert len(copies) <= copies_after_gather, \
+        [ops[n][3][:160] for n in copies]                   # (b), (c)
+    for n in copies:
+        assert _comes_from(ops, n, "gather_pages"), ops[n][3][:200]

@@ -325,40 +325,50 @@ def test_paged_gather_pages_kernel_interpret(dtype):
     np.testing.assert_array_equal(_bits(got), want[:5 * ps])
 
 
-def _paged_op_inputs(codec, verify, seed=0):
-    """Random inputs of kv_attention_decode_paged / _verify_paged at a
-    tiny geometry whose pages are whole tiles of every float storage
-    dtype (16 rows): 3 slots (one inactive), 6 pages, tables with
-    sentinel entries past each slot's span."""
+def _paged_op_inputs(codec, op="decode", seed=0):
+    """Random inputs of kv_attention_prefill_paged / _decode_paged /
+    _verify_paged at a tiny geometry whose pages are whole tiles of
+    every float storage dtype (16 rows), pools in the shape they are
+    declared in, [n_pages, ps, H * Dk]: 3 slots (one inactive), 6
+    pages, tables with sentinel entries past each slot's span; the
+    prefill writes one 16-token prompt with a sentinel (shared-prefix)
+    stretch in the middle."""
     import jax.numpy as jnp
     from paddle_tpu.ops import kv_attention as kva
     rng = np.random.RandomState(seed)
     b, h, dk, n_pages, ps, mp = 3, 2, 8, 6, 16, 3
-    m, k1 = h * dk, (3 if verify else 1)
+    m = h * dk
+    b, t = {"prefill": (1, 16), "decode": (b, 1), "verify": (b, 3)}[op]
     store = {"none": jnp.float32, "bf16": jnp.bfloat16}.get(codec)
-    ins = {"X": [jnp.asarray(rng.randn(b, k1, m), jnp.float32)]}
+    ins = {"X": [jnp.asarray(rng.randn(b, t, m), jnp.float32)]}
     for w in ("Wq", "Wk", "Wv", "Wo"):
         ins[w] = [jnp.asarray(rng.randn(m, m) * 0.3, jnp.float32)]
     for name in ("PageK", "PageV"):
         rows = jnp.asarray(rng.randn(n_pages, ps, h, dk), jnp.float32)
         if codec == "int8":
             codes, scale = kva._kv_quant(rows)
-            ins[name], ins[name + "S"] = [codes], [scale]
+            ins[name], ins[name + "S"] = [codes.reshape(n_pages, ps, m)], \
+                [scale]
         else:
-            ins[name] = [rows.astype(store)]
+            ins[name] = [rows.reshape(n_pages, ps, m).astype(store)]
+    attrs = {"n_head": h, "codec": codec}
+    if op == "prefill":
+        rows = 4 * ps + np.arange(t)
+        rows[5:9] = n_pages * ps            # skipped: dropped writes
+        ins["Rows"] = [jnp.asarray(rows, jnp.int32).reshape(-1, 1)]
+        return ins, attrs
     ins["PageTable"] = [jnp.asarray(
         [[4, 1, n_pages], [0, 5, 2], [n_pages] * mp], jnp.int32)]
     col = lambda *v: [jnp.asarray(v, jnp.int32).reshape(-1, 1)]
     ins.update(Pos=col(20, 33, 0), SeqLen=col(13, 16, 0),
                GenStart=col(16, 16, 0), Active=col(1, 1, 0))
-    if verify:
+    if op == "verify":
         ins["WinLen"] = col(3, 2, 1)
-    return ins, {"n_head": h, "codec": codec}
+    return ins, attrs
 
 
 @pytest.mark.parametrize("codec", ["none", "bf16", "int8"])
-@pytest.mark.parametrize("op", ["kv_attention_decode_paged",
-                                "kv_attention_verify_paged"])
+@pytest.mark.parametrize("op", ["prefill", "decode", "verify"])
 def test_paged_ops_identical_through_every_gather_tier(op, codec,
                                                        monkeypatch):
     """The page kernel, the row kernel and jnp.take move the same bits
@@ -367,21 +377,32 @@ def test_paged_ops_identical_through_every_gather_tier(op, codec,
     by patching the function that chooses it; the product has no
     switch). They differ only in what a SENTINEL entry reads — the last
     page against the last row, 16 times — which no active slot attends.
-    int8 pages never take the page kernel (no dequant there)."""
+    int8 pages never take the page kernel (no dequant there). The
+    prefill gathers nothing — whichever tier is named, it lowers no
+    gather and writes the same pools, in the declared 3-D shape."""
     import types
     from paddle_tpu.core.registry import get_op
     from paddle_tpu.ops import kv_attention as kva
-    ins, attrs = _paged_op_inputs(codec, verify=op.endswith("verify_paged"))
+    ins, attrs = _paged_op_inputs(codec, op)
+    op = f"kv_attention_{op}_paged"
+    gathers = 0 if op == "kv_attention_prefill_paged" else 2
     ctx = types.SimpleNamespace(mesh=None)
     results = {}
     for tier in ("take", "rows") + (("pages",) if codec != "int8" else ()):
         monkeypatch.setattr(kva, "_gather_tier", lambda *a, t=tier: t)
         before = kva.KV_GATHER_LOWERED.labels(path=tier).value
         results[tier] = get_op(op).emit(ctx, ins, attrs)
-        assert kva.KV_GATHER_LOWERED.labels(path=tier).value == before + 2
+        assert kva.KV_GATHER_LOWERED.labels(path=tier).value == \
+            before + gathers
     want = results.pop("take")
     assert np.all(np.isfinite(np.asarray(want["Out"][0])))
-    active = np.asarray(ins["Active"][0]).reshape(-1) > 0
+    for name in ("PageK", "PageV", "PageKS", "PageVS"):
+        if name in ins:             # pools leave as they came
+            got = want[name + "Out"][0]
+            assert (got.shape, got.dtype) == \
+                (ins[name][0].shape, ins[name][0].dtype)
+    active = np.asarray(ins["Active"][0]).reshape(-1) > 0 \
+        if "Active" in ins else slice(None)
     for tier, got in results.items():
         assert sorted(got) == sorted(want)
         for slot in want:
@@ -390,6 +411,45 @@ def test_paged_ops_identical_through_every_gather_tier(op, codec,
                 g, w = g[active], w[active]     # meaningless by contract
             np.testing.assert_array_equal(
                 g, w, err_msg=f"{op} {codec}: {slot}, {tier} vs take")
+
+
+@pytest.mark.parametrize("op", ["decode", "verify"])
+def test_paged_fp32_bit_identical_to_contiguous_op(op):
+    """fp32 paged decode / verify == the contiguous op over the cache
+    the page table describes, BIT for bit on every active row: the
+    paged ops contract K and V as the gather leaves them ([B, S, H*Dk],
+    a block-diagonal query, ops/kv_attention.py:_attend_gathered),
+    which adds only selected zeros to the contiguous ops' products and
+    sums. The rows each writes are the same too."""
+    import types
+    import jax.numpy as jnp
+    from paddle_tpu.core.registry import get_op
+    ins, attrs = _paged_op_inputs("none", op, seed=3)
+    ctx = types.SimpleNamespace(mesh=None)
+    got = get_op(f"kv_attention_{op}_paged").emit(ctx, ins, attrs)
+    n_pages, ps, m = ins["PageK"][0].shape
+    h = attrs["n_head"]
+    table = np.minimum(np.asarray(ins["PageTable"][0]), n_pages - 1)
+    rows = (table[:, :, None] * ps + np.arange(ps)).reshape(
+        table.shape[0], -1)                             # [B, S]
+
+    def cache(pool):
+        return jnp.asarray(np.asarray(pool).reshape(-1, h, m // h)[rows])
+    cins = {k: v for k, v in ins.items()
+            if not k.startswith("Page")}
+    cins["CacheK"] = [cache(ins["PageK"][0])]
+    cins["CacheV"] = [cache(ins["PageV"][0])]
+    name = "kv_attention_decode" if op == "decode" else \
+        "kv_attention_verify"
+    want = get_op(name).emit(ctx, cins, {"n_head": h})
+    active = np.asarray(ins["Active"][0]).reshape(-1) > 0
+    assert active.any() and not active.all()
+    np.testing.assert_array_equal(_bits(got["Out"][0])[active],
+                                  _bits(want["Out"][0])[active])
+    for pool, c in (("PageKOut", "CacheKOut"), ("PageVOut", "CacheVOut")):
+        np.testing.assert_array_equal(
+            _bits(cache(got[pool][0]))[active],
+            _bits(want[c][0])[active])
 
 
 @pytest.mark.parametrize("case, want", [
@@ -415,7 +475,7 @@ def test_kv_gather_lowering_counter_names_the_tier(case, want,
     tier, storage, ps = case.split("-")
     ps = int(ps[2:])
     codec = {"f32": "none", "bf16": "bf16", "int8": "int8"}[storage]
-    ins, attrs = _paged_op_inputs(codec, verify=False)
+    ins, attrs = _paged_op_inputs(codec)
     for name in ("PageK", "PageV", "PageKS", "PageVS"):
         if name in ins:             # same rows, pages of ``ps``
             a = ins[name][0]
@@ -655,6 +715,68 @@ def test_page_pool_census_classification():
     page_bufs = [b for b in cen["buffers"] if "_page_" in b["name"]]
     assert page_bufs
     assert all(b["family"] == "kv_cache" for b in page_bufs)
+
+
+@pytest.mark.parametrize("codec", ["none", "int8"])
+def test_pool_variable_is_3d_and_engine_and_census_read_it(codec):
+    """Every paged mode and codec declares ``*_page_k/v_*`` as
+    [n_pages, page_size, n_head * d_k] in the storage dtype (row-major
+    at rest on the chip, PERF.md PR 28; the int8 scale planes stay
+    [n_pages, page_size, n_head]); ``_discover_pool`` reads n_pages and
+    page_size from its two leading dimensions, the device arrays have
+    the declared shape, and the census counts exactly their bytes."""
+    m = _paged_lm(codec)
+    cfg, store = _LM_CFG, {"none": np.float32, "int8": np.int8}[codec]
+    assert (m.n_pages, m.page_size) == (16, 4)
+
+    def declared(name):         # (shape, dtype) a *_page_* variable has
+        if "_page_ks_" in name or "_page_vs_" in name:
+            return (16, 4, cfg["n_head"]), np.float32
+        return (16, 4, cfg["d_model"]), store
+    programs = T.build_decoder_lm_programs(
+        **cfg, prompt_buckets=(4, 8), n_slots=4, page_size=4,
+        kv_codec=codec, spec_k=2,
+        modes=("prefill_paged", "decode_paged", "decode_verify_paged"))
+    assert len(programs) == 5           # two buckets + their alias
+    for mode in programs:
+        block = programs[mode][0].desc.global_block
+        pools = {n: v for n, v in block.vars.items() if "_page_" in n}
+        assert len(pools) == cfg["n_layer"] * (4 if codec == "int8" else 2)
+        for n, v in pools.items():
+            assert tuple(v.shape) == declared(n)[0], (mode, n)
+    kv_bytes = 0
+    for n, arr in m.scope.iter_vars():
+        if "_page_" in n:
+            assert (arr.shape, arr.dtype) == declared(n), n
+            kv_bytes += arr.nbytes
+    rows = m.n_pages * m.page_size * cfg["n_layer"] * 2
+    per_row = {"none": 4 * cfg["d_model"],
+               "int8": cfg["d_model"] + 4 * cfg["n_head"]}[codec]
+    assert kv_bytes == rows * per_row
+    assert obs_memory.kv_pool_bytes(m.scope) == kv_bytes
+
+
+def test_verify_paged_flops_read_page_size_not_n_pages():
+    """utils/flops.py credits kv_attention_verify_paged with dots over
+    the cache length max_pages * page_size — the pool's SECOND
+    dimension; with the 3-D pool ``shape[-3]`` is n_pages."""
+    from paddle_tpu.utils import flops
+    progs = T.build_decoder_lm_programs(
+        **_LM_CFG, prompt_buckets=(8,), n_slots=4, page_size=4,
+        n_pages=40, spec_k=2,
+        modes=("decode_verify_paged", "decode_verify"))
+    per_op = {}
+    for mode in ("decode_verify_paged", "decode_verify"):
+        main = progs[mode][0]
+        block = main.desc.global_block
+        ops = [op for op in block.ops
+               if op.type.startswith("kv_attention_verify")]
+        assert len(ops) == _LM_CFG["n_layer"]
+        per_op[mode] = flops._op_flops(main.desc, block, ops[0], 4)
+    b, k1, m, s = 4, 3, _LM_CFG["d_model"], 16      # cache_len 8 + 8
+    want = 2.0 * b * m * m * 4 * k1 + 2.0 * b * k1 * s * m * 2
+    assert per_op["decode_verify_paged"] == want
+    assert per_op["decode_verify_paged"] == per_op["decode_verify"]
 
 
 def test_server_maps_exhaustion_to_typed_wire_kind():
