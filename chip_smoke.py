@@ -21,9 +21,8 @@ fact; any failure stops the run, exit code 1, last line ``"ok": false``):
               batch 16, amp-bf16, default pass pipeline) for three
               multi-step dispatches.
 3. server   — decoder_lm at those widths (prompt ladder 128/256/512,
-              128 new tokens, 16 slots) on ModelServer: contiguous KV,
-              paged KV fp32 and int8; eight overlapping requests each,
-              zero compiles
+              128 new tokens, 16 slots) on ModelServer: paged KV fp32
+              and int8; eight overlapping requests each, zero compiles
               after warm-up, zero AOT fallbacks, greedy tokens checked
               against GenerativeModel.full_forward_generate.
 4. spmd     — (``--four-chips`` only) the phase-1 model and
@@ -378,8 +377,8 @@ def _counter_total(family) -> float:
     return sum(child.value for child in family.children().values())
 
 
-def serve_variant(cfg: dict, label: str, layout: str, codec, oracle):
-    """One KV layout on a ModelServer: warm up (the only compiles),
+def serve_variant(cfg: dict, label: str, codec, oracle):
+    """One KV codec on a ModelServer: warm up (the only compiles),
     eight overlapping requests, checks."""
     import jax
     from paddle_tpu import serving
@@ -390,7 +389,7 @@ def serve_variant(cfg: dict, label: str, layout: str, codec, oracle):
     name = "lm_" + label
     t0 = time.time()
     programs = T.build_decoder_lm_programs(
-        **s, modes=T.slot_modes(layout), kv_codec=codec)
+        **s, modes=T.slot_modes(), kv_codec=codec)
     engine = serving.make_slot_model(name, programs)
     server = serving.ModelServer()
     try:
@@ -439,26 +438,22 @@ def serve_variant(cfg: dict, label: str, layout: str, codec, oracle):
             engine._cb_decode, engine._decode_feeds())
         ).compile().as_text()
         n_custom = text.count("tpu_custom_call")
-        pool = {}
-        if layout == "paged":
-            # the pool variable: [n_pages, page_size, H*D], which the
-            # chip keeps row-major at rest (minor_to_major 2,1,0)
-            shape = next(tuple(a.shape) for n, a in engine.scope.iter_vars()
-                         if n.endswith("_page_k_0"))
-            check(shape == (engine.n_pages, engine.page_size, s["d_model"]),
-                  f"pool variable is {shape}")
-            dims = ",".join(str(d) for d in shape)
-            at_rest = set(re.findall(r"\w+\[%s\]\{([\d,]+)" % dims,
-                                     text.splitlines()[0]))
-            pool = {"pool_shape": list(shape),
-                    "pool_minor_to_major": sorted(at_rest)}
-            if jax.default_backend() == "tpu":
-                check(n_custom > 0, "paged decode step holds no Pallas "
-                      "gather (tpu_custom_call)")
-                check(at_rest == {"2,1,0"}, f"pool at rest is {at_rest}, "
-                      f"not row-major: every step would transpose it")
+        # the pool variable: [n_pages, page_size, H*D], which the
+        # chip keeps row-major at rest (minor_to_major 2,1,0)
+        shape = next(tuple(a.shape) for n, a in engine.scope.iter_vars()
+                     if n.endswith("_page_k_0"))
+        check(shape == (engine.n_pages, engine.page_size, s["d_model"]),
+              f"pool variable is {shape}")
+        dims = ",".join(str(d) for d in shape)
+        at_rest = set(re.findall(r"\w+\[%s\]\{([\d,]+)" % dims,
+                                 text.splitlines()[0]))
+        if jax.default_backend() == "tpu":
+            check(n_custom > 0, "paged decode step holds no Pallas "
+                  "gather (tpu_custom_call)")
+            check(at_rest == {"2,1,0"}, f"pool at rest is {at_rest}, "
+                  f"not row-major: every step would transpose it")
         how = _check_against_oracle(cfg, oracle, prompts[:2], outs[:2])
-        say("server", variant=label, views=T.slot_modes(layout),
+        say("server", variant=label, views=T.slot_modes(),
             warm_up_s=round(warm_s, 2),
             requests=8, prompt_lens=lens, budgets=budgets,
             tokens_returned=int(sum(len(o) for o in outs)),
@@ -466,7 +461,8 @@ def serve_variant(cfg: dict, label: str, layout: str, codec, oracle):
             in_flight_at_second_wave=in_flight,
             compiles_after_warm_up=0, aot_fallbacks=0,
             tpu_custom_calls_in_decode_step=n_custom, oracle_check=how,
-            peak_bytes_in_use=_peak_bytes(), **pool)
+            peak_bytes_in_use=_peak_bytes(), pool_shape=list(shape),
+            pool_minor_to_major=sorted(at_rest))
     finally:
         server.stop()
 
@@ -514,9 +510,8 @@ def phase_server(cfg: dict):
     oracle = serving.GenerativeModel(
         "lm_oracle", T.build_decoder_lm_programs(**s),
         serving.BucketPolicy((2,)))
-    serve_variant(cfg, "contiguous", "contiguous", None, oracle)
-    serve_variant(cfg, "paged_fp32", "paged", "none", oracle)
-    serve_variant(cfg, "paged_int8", "paged", "int8", oracle)
+    serve_variant(cfg, "paged_fp32", "none", oracle)
+    serve_variant(cfg, "paged_int8", "int8", oracle)
 
 
 # --------------------------------------------------------------- phase 4

@@ -1,9 +1,9 @@
 """Cross-view program contracts: one scope, many executables, one truth.
 
-The decoder_lm serving family emits 8+ program views (full, prefill@P,
-prefill/decode_slot, prefill/decode_paged, decode_verify[_paged]) that
-all dispatch against ONE scope — the weights, KV pools and page pools
-are shared state. Nothing in the per-program verifier can see the
+The decoder_lm serving family emits 6+ program views (full, prefill@P,
+decode, prefill_paged@P, decode_paged, decode_verify_paged) that all
+dispatch against ONE scope — the weights, KV caches and page pools are
+shared state. Nothing in the per-program verifier can see the
 hazards that live BETWEEN views: a persistable whose shape/dtype drifts
 across builders, a startup whose rng-salted initializers slid to
 different op indices (two views would disagree on the weights they
@@ -53,9 +53,8 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 from paddle_tpu.analysis.diagnostics import Diagnostic, Severity
 from paddle_tpu.analysis.rules import register_rule
 
-DECODER_LM_MODES = ("full", "prefill", "decode", "prefill_slot",
-                    "decode_slot", "prefill_paged", "decode_paged",
-                    "decode_verify", "decode_verify_paged")
+DECODER_LM_MODES = ("full", "prefill", "decode", "prefill_paged",
+                    "decode_paged", "decode_verify_paged")
 
 _KV_CODECS = ("none", "bf16", "int8")
 _STORE_DTYPES = {"none": "float32", "bf16": "bfloat16", "int8": "int8"}
@@ -130,14 +129,13 @@ def validate_geometry(mode: str, prompt_len: int, max_new: int,
     """Validate + normalize one view's geometry constants; raises
     ``ValueError`` with the same contracts the view builders used to
     enforce inline. The single source of truth for defaults: cache_len
-    (prompt_len + max_new), spec_k (4), page_size (4), n_pages (the
-    contiguous pool's capacity) and kv_codec (FLAGS_kv_cache_codec)."""
+    (prompt_len + max_new), spec_k (4), page_size (4), n_pages (every
+    slot at full length) and kv_codec (FLAGS_kv_cache_codec)."""
     _count("geometry")
     if mode not in DECODER_LM_MODES:
         raise ValueError(f"decoder_lm mode {mode!r} not in "
                          f"{DECODER_LM_MODES}")
-    if (mode.endswith("_slot") or mode.endswith("_paged")
-            or mode.startswith("decode_verify")) and not n_slots:
+    if mode.endswith("_paged") and not n_slots:
         raise ValueError(f"mode {mode!r} needs n_slots")
     prompt_len = int(prompt_len)
     max_new = int(max_new)
@@ -147,9 +145,9 @@ def validate_geometry(mode: str, prompt_len: int, max_new: int,
                          f"{cache_len}")
     n_slots = int(n_slots) if n_slots else None
 
-    if mode.startswith("decode_verify"):
+    if mode == "decode_verify_paged":
         # verify-window geometry: K >= 1 (K = 0 is plain decode — use
-        # decode_slot/decode_paged), and the K+1 window must fit the
+        # decode_paged), and the K+1 window must fit the
         # generated region it could commit into
         spec_k = int(spec_k) if spec_k else 4
         if spec_k < 1:
@@ -410,7 +408,7 @@ def rule_geometry_drift(ctx) -> Iterable[Diagnostic]:
                             f"({g.cache_len}/{g.page_size}={want})",
                     var="page_table", details={"view": key})
         tok = v.feed_specs.get("tok")
-        if g.mode.startswith("decode_verify") and tok is not None:
+        if g.mode == "decode_verify_paged" and tok is not None:
             k1 = int(tok[0][1])
             if g.window is not None and k1 != g.window:
                 yield Diagnostic(
@@ -418,10 +416,8 @@ def rule_geometry_drift(ctx) -> Iterable[Diagnostic]:
                     message=f"view {key!r}: tok window width {k1} != "
                             f"spec_k+1 ({g.window})",
                     var="tok", details={"view": key})
-        if g.n_slots and tok is not None and (
-                g.mode.startswith("decode_verify")
-                or g.mode.endswith("_slot") and g.mode != "prefill_slot"
-                or g.mode == "decode_paged"):
+        if g.n_slots and tok is not None and g.mode in (
+                "decode_paged", "decode_verify_paged"):
             s = int(tok[0][0])
             if s != g.n_slots:
                 yield Diagnostic(

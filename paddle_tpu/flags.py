@@ -218,17 +218,11 @@ define("grad_allreduce_codec", str, "none",
        "FLAGS_embed_exchange_codec applied to gradients (EQuARX, "
        "arXiv:2506.17615). Parity contract: "
        "tests/test_spmd_exec.py codec window.")
-define("kv_cache_layout", str, "contiguous",
-       "Decode KV-cache layout for the slot-pool serving engine "
-       "(serving/engine.py): 'contiguous' reserves one worst-case "
-       "[n_slots, S, H, D] region per layer; 'paged' breaks the cache "
-       "into fixed-size pages behind a per-slot page table "
-       "(serving/kv_pool.py) with prompt-prefix sharing — admission is "
-       "by free-PAGE count, so short requests stop paying the "
-       "worst-case reservation (docs/serving.md 'Paged KV cache').")
 define("kv_cache_codec", str, "none",
-       "Storage codec for the PAGED KV pool (kv_cache_layout=paged): "
-       "'none' stores fp32 (bit-exact vs the contiguous pool), 'bf16' "
+       "Storage codec for the slot server's paged KV pool "
+       "(serving/engine.py, serving/kv_pool.py; docs/serving.md 'Paged "
+       "KV cache'): 'none' stores fp32 (decode bit-exact against the "
+       "wave op kv_attention_decode over the same rows), 'bf16' "
        "truncates to 2 bytes/elem, 'int8' stores int8 codes + one fp32 "
        "scale per (position, head) row — the per-row-scale discipline "
        "of FLAGS_embed_exchange_codec applied at rest. Quantize on "

@@ -12,9 +12,9 @@ from paddle_tpu.fluid.layers.nn import (  # noqa: F401
     clip, conv2d, conv2d_transpose,
     cos_sim, crf_decoding, cross_entropy, dropout, embedding, expand, fc,
     fused_linear_cross_entropy, fused_multi_head_attention,
-    kv_attention_prefill, kv_attention_prefill_slot, kv_attention_decode,
+    kv_attention_prefill, kv_attention_decode,
     kv_attention_prefill_paged, kv_attention_decode_paged,
-    kv_attention_verify, kv_attention_verify_paged,
+    kv_attention_verify_paged,
     token_sample,
     gather, hsigmoid, huber_loss, l2_normalize, label_smooth, layer_norm,
     linear_chain_crf, log, matmul, mean, mul, nce, one_hot, pool2d,

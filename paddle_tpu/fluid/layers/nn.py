@@ -679,31 +679,6 @@ def kv_attention_prefill(x, d_model, n_head, cache_k, cache_v,
     return out
 
 
-def kv_attention_prefill_slot(x, slot, d_model, n_head, pool_k, pool_v,
-                              param_attr=None, name=None):
-    """In-flight-batching prefill: causal self-attention over the prompt
-    whose K/V rows are scattered into a LIVE pool cache
-    (``pool_k``/``pool_v``, persistable [n_slots, S, H, D] vars, read
-    and written under the same names — donated state) at the per-row
-    ``slot`` indices, so a new request joins a running decode without
-    disturbing the slots mid-flight. The whole [S, H, D] row is written
-    (zeros beyond the prompt), so a reused slot never leaks its previous
-    occupant. x [B, T, M], slot [B, 1] int -> [B, T, M]
-    (ops/kv_attention.py; docs/serving.md)."""
-    helper = LayerHelper("kv_attention_prefill_slot", name=name)
-    ws = _attention_projection_params(helper, d_model, param_attr)
-    out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op("kv_attention_prefill_slot",
-                     inputs={"X": [x], "Wq": [ws[0]], "Wk": [ws[1]],
-                             "Wv": [ws[2]], "Wo": [ws[3]],
-                             "PoolK": [pool_k], "PoolV": [pool_v],
-                             "Slot": [slot]},
-                     outputs={"Out": [out], "PoolKOut": [pool_k],
-                              "PoolVOut": [pool_v]},
-                     attrs={"n_head": int(n_head)})
-    return out
-
-
 def kv_attention_decode(x, pos, seq_len, gen_start, active, d_model,
                         n_head, cache_k, cache_v, param_attr=None,
                         name=None):
@@ -735,8 +710,10 @@ def kv_attention_decode(x, pos, seq_len, gen_start, active, d_model,
 def kv_attention_prefill_paged(x, rows, d_model, n_head, page_k, page_v,
                                page_ks=None, page_vs=None, codec="none",
                                param_attr=None, name=None):
-    """Paged-pool prefill (ISSUE 17): causal self-attention over the
-    prompt whose K/V rows scatter into the PAGED pool caches
+    """In-flight-batching prefill (ISSUE 9, 17): causal self-attention
+    over the prompt whose K/V rows scatter into the LIVE paged pool
+    caches, so a new request joins a running decode without disturbing
+    the slots mid-flight
     (``page_k``/``page_v``, persistable [n_pages, page_size, H*D] vars
     read and written under the same names — donated state; the whole
     model width on the minor dimension keeps them row-major at rest on
@@ -801,45 +778,21 @@ def kv_attention_decode_paged(x, page_table, pos, seq_len, gen_start,
     return out
 
 
-def kv_attention_verify(x, pos, seq_len, gen_start, active, win_len,
-                        d_model, n_head, cache_k, cache_v,
-                        param_attr=None, name=None):
-    """Speculative-decode verify step (ISSUE 19) over the contiguous KV
-    cache: score a [B, K+1] token window — position 0 the row's last
-    committed token, positions 1..K the drafts — in ONE causal dispatch,
-    writing window position i's k/v at cache row ``pos + i`` where
-    ``active`` and ``i < win_len``. Position i attends over
-    {j < seq_len} ∪ {gen_start <= j <= pos + i}, so its output is
-    bit-identical to i sequential ``kv_attention_decode`` steps over the
-    same tokens — the losslessness guarantee the engine's accept rule
-    rests on. Rollback of rejected positions is overwrite-in-place: they
-    sit above the committed frontier and the mask never admits them.
-    x [B, K+1, M], pos/seq_len/gen_start/active/win_len [B, 1] int ->
-    [B, K+1, M] (ops/kv_attention.py; docs/serving.md 'Speculative
-    decoding')."""
-    helper = LayerHelper("kv_attention_verify", name=name)
-    ws = _attention_projection_params(helper, d_model, param_attr)
-    out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op("kv_attention_verify",
-                     inputs={"X": [x], "Wq": [ws[0]], "Wk": [ws[1]],
-                             "Wv": [ws[2]], "Wo": [ws[3]],
-                             "CacheK": [cache_k], "CacheV": [cache_v],
-                             "Pos": [pos], "SeqLen": [seq_len],
-                             "GenStart": [gen_start],
-                             "Active": [active], "WinLen": [win_len]},
-                     outputs={"Out": [out], "CacheKOut": [cache_k],
-                              "CacheVOut": [cache_v]},
-                     attrs={"n_head": int(n_head)})
-    return out
-
-
 def kv_attention_verify_paged(x, page_table, pos, seq_len, gen_start,
                               active, win_len, d_model, n_head, page_k,
                               page_v, page_ks=None, page_vs=None,
                               codec="none", param_attr=None, name=None):
-    """Speculative-decode verify over the PAGED KV pool: window geometry
-    identical to ``kv_attention_verify``, each window position's write
-    row resolved through the page-table feed. Writes that fall past the
+    """Speculative-decode verify step (ISSUE 19) over the paged KV pool:
+    score a [B, K+1] token window — position 0 the row's last committed
+    token, positions 1..K the drafts — in ONE causal dispatch, writing
+    window position i's k/v at logical cache row ``pos + i`` (resolved
+    through the page-table feed) where ``active`` and ``i < win_len``.
+    Position i attends over {j < seq_len} ∪ {gen_start <= j <= pos + i},
+    so its output is what i sequential ``kv_attention_decode_paged``
+    steps over the same tokens produce — the losslessness guarantee the
+    engine's accept rule rests on. Rollback of rejected positions is
+    overwrite-in-place: they sit above the committed frontier and the
+    mask never admits them. Writes that fall past the
     slot's leased span resolve to the sentinel page and DROP — a draft
     window can never write another slot's pages (admission reserves the
     draft-window overshoot, ``PagePool.span_for(draft_window=K)``).

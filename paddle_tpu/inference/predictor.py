@@ -336,7 +336,12 @@ class PaddlePredictor:
                 stacklevel=3)
             return None
         try:
-            return se.deserialize_and_load(*blob["payload"]), sig
+            # the executable was compiled for the predictor's ONE device;
+            # left to its default the loader hands it every local device
+            # and each call then fails on a host with several
+            return se.deserialize_and_load(
+                *blob["payload"],
+                execution_devices=[self._exe.device]), sig
         except Exception as e:
             warnings.warn(f"AOT executable {os.path.basename(path)} "
                           f"failed to deserialize ({type(e).__name__}) — "

@@ -194,33 +194,28 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 #   "decode"       — ONE token per call with per-row geometry
 #                    (pos/seq_len/gen_start/active), O(1) per token
 #                    instead of a fresh full forward.
-#   "prefill_slot" — the in-flight-batching prefill: ONE request
+#   "prefill_paged" — the in-flight-batching prefill: ONE request
 #                    (batch 1) whose K/V rows are scattered into the
-#                    [n_slots, S, H, D] POOL caches at a slot index;
-#                    fetches the first generated token, sampled
-#                    on-device (layers.token_sample).
-#   "decode_slot"  — one decode step over the WHOLE slot pool: a fully
+#                    per-layer [n_pages, page_size, H*D] page pools
+#                    through per-position flat row indices (sentinel =
+#                    shared-prefix skip); fetches the first generated
+#                    token, sampled on-device (layers.token_sample).
+#   "decode_paged" — one decode step over the WHOLE slot pool: a fully
 #                    static [n_slots]-row program (free slots ride along
-#                    masked) that samples each row's next token
-#                    on-device. This is the executable the in-flight
-#                    scheduler re-dispatches forever (ISSUE 9).
-#   "prefill_paged" / "decode_paged" — the slot pair over a PAGED pool
-#                    (ISSUE 17): [n_pages, page_size, H*D] page pools
-#                    replace the worst-case [n_slots, S, H, D] region;
-#                    prefill writes through per-position flat row
-#                    indices (sentinel = shared-prefix skip), decode
-#                    resolves reads/writes through a [n_slots,
-#                    max_pages] page-table feed. Same numerics — fp32
-#                    paged greedy output is bit-identical to the slot
-#                    views; FLAGS_kv_cache_codec stores bf16/int8.
-#   "decode_verify" / "decode_verify_paged" — the speculative-decoding
-#                    verify step (ISSUE 19): score a [n_slots, K+1]
-#                    token window (last committed token + K drafts) in
-#                    ONE causal dispatch over the slot/paged pool and
-#                    sample every window position on-device. The
-#                    engine's draft→verify→commit loop re-dispatches
-#                    this executable instead of decode_slot/paged,
-#                    committing up to K+1 tokens per step.
+#                    masked) that resolves reads/writes through a
+#                    [n_slots, max_pages] page-table feed and samples
+#                    each row's next token on-device. This is the
+#                    executable the in-flight scheduler re-dispatches
+#                    forever (ISSUE 9, 17). FLAGS_kv_cache_codec stores
+#                    the pages as bf16/int8.
+#   "decode_verify_paged" — the speculative-decoding verify step
+#                    (ISSUE 19): score a [n_slots, K+1] token window
+#                    (last committed token + K drafts) in ONE causal
+#                    dispatch over the paged pool and sample every
+#                    window position on-device. The engine's
+#                    draft→verify→commit loop re-dispatches this
+#                    executable instead of decode_paged, committing up
+#                    to K+1 tokens per step.
 # Every parameter is explicitly named (LayerHelper's auto names are
 # globally unique, so cross-program sharing REQUIRES explicit names).
 # ---------------------------------------------------------------------------
@@ -231,21 +226,20 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                cache_len=None, n_slots=None, page_size=None,
                n_pages=None, kv_codec=None, spec_k=None):
     """Emit the `mode` view ("full" | "prefill" | "decode" |
-    "prefill_slot" | "decode_slot" | "prefill_paged" | "decode_paged" |
-    "decode_verify" | "decode_verify_paged")
+    "prefill_paged" | "decode_paged" | "decode_verify_paged")
     of the decoder-only LM into the current default programs.
     ``cache_len`` decouples the cache size from this view's prompt
     bucket (ladder prefills at P < P_max still write full-size caches);
-    slot AND paged modes need ``n_slots``. The paged views (ISSUE 17)
-    swap the [n_slots, S, H, D] pool for [n_pages, page_size, H*D]
-    page pools behind a per-slot page-table feed — ``page_size`` must
-    divide cache_len (the decode gather then covers exactly cache_len
-    logical rows: fp32 paged decode is bit-identical to the slot op);
-    ``n_pages`` defaults to the contiguous pool's capacity
+    the paged modes need ``n_slots``. The paged views (ISSUE 17) keep
+    K/V in [n_pages, page_size, H*D] page pools behind a per-slot
+    page-table feed — ``page_size`` must divide cache_len (the decode
+    gather then covers exactly cache_len logical rows: fp32 paged
+    decode is bit-identical to the wave op ``kv_attention_decode``);
+    ``n_pages`` defaults to every slot at full length
     (n_slots * cache_len / page_size); ``kv_codec`` defaults to
     FLAGS_kv_cache_codec ('none' | 'bf16' | 'int8' storage). Returns
     (output_var, feed_specs) — logits for full/prefill/decode, the
-    on-device-sampled next token for the slot/paged views.
+    on-device-sampled next token for the paged views.
 
     The verify views (ISSUE 19) take ``spec_k`` (default 4): K drafted
     tokens per step, scored together with the last committed token as a
@@ -287,13 +281,17 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     # indices in every mode's startup for the views to share weights.
     _pool_fills = []
 
-    def pool_var(pname, shape=None, dtype="float32"):
-        shape = shape or [int(n_slots), cache_len, n_head, d_k]
+    def pool_var(pname, shape, dtype="float32"):
         v = main.global_block().create_var(
             name=pname, shape=shape, dtype=dtype,
             persistable=True, stop_gradient=True)
         _pool_fills.append((pname, shape, dtype))
         return v
+
+    def sdata(nm, shape, dtype="int64"):
+        # the slot views' feeds are fully static (no batch dimension)
+        return layers.data(name=nm, shape=shape, dtype=dtype,
+                           append_batch_size=False)
 
     if mode == "decode":
         tok = layers.data(name="tok", shape=[1, 1], dtype="int64")
@@ -308,12 +306,8 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                       "gen_start": ([-1, 1], "int64"),
                       "active": ([-1, 1], "int64")}
         x_ids, t = tok, 1
-    elif mode in ("decode_slot", "decode_paged"):
+    elif mode == "decode_paged":
         S = int(n_slots)
-
-        def sdata(nm, shape, dtype="int64"):
-            return layers.data(name=nm, shape=shape, dtype=dtype,
-                               append_batch_size=False)
         tok = sdata("tok", [S, 1, 1])
         pos = sdata("pos", [S, 1])
         seq_len = sdata("seq_len", [S, 1])
@@ -332,20 +326,15 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                       "sample_step": ([S, 1], "int64"),
                       "temperature": ([S, 1], "float32"),
                       "top_k": ([S, 1], "int64")}
-        if mode == "decode_paged":
-            # the slot -> page indirection rides in as a STATIC-shape
-            # feed: any admission/release/page mix dispatches the same
-            # executable (sentinel entries point one past the pool)
-            page_table = sdata("page_table", [S, max_pages])
-            feed_specs["page_table"] = ([S, max_pages], "int64")
+        # the slot -> page indirection rides in as a STATIC-shape
+        # feed: any admission/release/page mix dispatches the same
+        # executable (sentinel entries point one past the pool)
+        page_table = sdata("page_table", [S, max_pages])
+        feed_specs["page_table"] = ([S, max_pages], "int64")
         x_ids, t = tok, 1
-    elif mode in ("decode_verify", "decode_verify_paged"):
+    elif mode == "decode_verify_paged":
         S = int(n_slots)
         k1 = int(spec_k) + 1
-
-        def sdata(nm, shape, dtype="int64"):
-            return layers.data(name=nm, shape=shape, dtype=dtype,
-                               append_batch_size=False)
         # the window feed: position 0 the row's last committed token,
         # 1..K the drafts. The sampling feeds are PER WINDOW POSITION
         # ([S, K+1]): sample_step[b, i] = gen_count[b] + i, so window
@@ -372,17 +361,12 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                       "sample_step": ([S, k1], "int64"),
                       "temperature": ([S, k1], "float32"),
                       "top_k": ([S, k1], "int64")}
-        if mode == "decode_verify_paged":
-            page_table = sdata("page_table", [S, max_pages])
-            feed_specs["page_table"] = ([S, max_pages], "int64")
+        page_table = sdata("page_table", [S, max_pages])
+        feed_specs["page_table"] = ([S, max_pages], "int64")
         x_ids, t = tok, k1
-    elif mode in ("prefill_slot", "prefill_paged"):
+    elif mode == "prefill_paged":
         # one request at a time joins the pool (batch 1, static)
         t = prompt_len
-
-        def sdata(nm, shape, dtype="int64"):
-            return layers.data(name=nm, shape=shape, dtype=dtype,
-                               append_batch_size=False)
         ids = sdata("ids", [1, t, 1])
         seq_len = sdata("seq_len", [1, 1])
         seed_in = sdata("seed", [1, 1])
@@ -393,14 +377,10 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                       "seed": ([1, 1], "int64"),
                       "temperature": ([1, 1], "float32"),
                       "top_k": ([1, 1], "int64")}
-        if mode == "prefill_slot":
-            slot = sdata("slot", [1, 1])
-            feed_specs["slot"] = ([1, 1], "int64")
-        else:
-            # flat pool row per prompt position from the page lease —
-            # sentinel rows skip prefix-shared pages (already resident)
-            page_rows = sdata("page_rows", [t, 1])
-            feed_specs["page_rows"] = ([t, 1], "int64")
+        # flat pool row per prompt position from the page lease —
+        # sentinel rows skip prefix-shared pages (already resident)
+        page_rows = sdata("page_rows", [t, 1])
+        feed_specs["page_rows"] = ([t, 1], "int64")
         x_ids = ids
     else:
         t = prompt_len if mode == "prefill" else cache_len
@@ -411,7 +391,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     emb = layers.embedding(x_ids, size=[vocab, d_model],
                            param_attr=pa("emb"))
     x = layers.scale(emb, scale=d_model ** 0.5)
-    if mode in ("decode", "decode_slot", "decode_paged"):
+    if mode in ("decode", "decode_paged"):
         # semantic position of this token for row b is
         # seq_len[b] + generated-so-far = seq_len + (pos - gen_start)
         # (prompts are right-padded to their bucket; the cache SLOT is
@@ -421,7 +401,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
         pe_t = layers.gather(pe, pos_ids)                  # [B, M]
         pe_t = layers.reshape(pe_t, shape=[-1, 1, d_model])
         x = layers.elementwise_add(x, pe_t)
-    elif mode in ("decode_verify", "decode_verify_paged"):
+    elif mode == "decode_verify_paged":
         # semantic position of window position i for row b is
         # seq_len[b] + (pos[b] + i - gen_start[b]) — and since
         # sample_step[b, i] = (pos - gen_start + 1) + i that is exactly
@@ -447,26 +427,6 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             attn = layers.fused_multi_head_attention(
                 attn_in, attn_in, d_model, n_head, causal=True,
                 param_attr=attn_pa(i))
-        elif mode.endswith("_slot"):
-            pk = pool_var(f"{name}_slot_k_{i}")
-            pv = pool_var(f"{name}_slot_v_{i}")
-            if mode == "prefill_slot":
-                attn = layers.kv_attention_prefill_slot(
-                    attn_in, slot, d_model, n_head, pk, pv,
-                    param_attr=attn_pa(i))
-            else:
-                attn = layers.kv_attention_decode(
-                    attn_in, pos, seq_len, gen_start, active, d_model,
-                    n_head, pk, pv, param_attr=attn_pa(i))
-        elif mode == "decode_verify":
-            # verify over the CONTIGUOUS slot pool — same persistable
-            # pool vars as prefill_slot/decode_slot, so one scope serves
-            # the whole slot family plus its verify view
-            pk = pool_var(f"{name}_slot_k_{i}")
-            pv = pool_var(f"{name}_slot_v_{i}")
-            attn = layers.kv_attention_verify(
-                attn_in, pos, seq_len, gen_start, active, win_len,
-                d_model, n_head, pk, pv, param_attr=attn_pa(i))
         elif mode.endswith("_paged"):
             # the whole model width on the minor dimension: row-major
             # at rest on the TPU, a page contiguous (ops/kv_attention
@@ -536,7 +496,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             name=pname, shape=shape, dtype=fdt, persistable=True)
         ConstantInitializer(0.0)(sv, startup.global_block())
 
-    if mode in ("prefill_slot", "prefill_paged"):
+    if mode == "prefill_paged":
         # first generated token, sampled on-device from the logits row
         # at the prompt's true end (batch 1: flatten [1,P,V] -> [P,V])
         flat = layers.reshape(logits, shape=[-1, vocab])
@@ -546,17 +506,13 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
         zero = layers.fill_constant([1, 1], "int64", 0)
         tok_out = layers.token_sample(last, temp, top_k, seed_in, zero)
         return tok_out, feed_specs
-    if mode in ("decode_slot", "decode_paged"):
-        flat = layers.reshape(logits, shape=[-1, vocab])   # [S, V]
-        tok_out = layers.token_sample(flat, temp, top_k, seed_in,
-                                      sample_step)
-        return tok_out, feed_specs
-    if mode in ("decode_verify", "decode_verify_paged"):
-        # sample EVERY window position on-device ([S*K1, V] flat): row
-        # b*K1+i is the token the sequential engine would emit at step
-        # sample_step[b, i] given the window's prefix — the host accept
-        # rule is then a pure token comparison against the drafts
-        flat = layers.reshape(logits, shape=[-1, vocab])   # [S*K1, V]
+    if mode in ("decode_paged", "decode_verify_paged"):
+        # decode: [S, V] flat. Verify samples EVERY window position
+        # on-device ([S*K1, V] flat): row b*K1+i is the token the
+        # sequential engine would emit at step sample_step[b, i] given
+        # the window's prefix — the host accept rule is then a pure
+        # token comparison against the drafts
+        flat = layers.reshape(logits, shape=[-1, vocab])
         tok_out = layers.token_sample(flat, temp, top_k, seed_in,
                                       sample_step)
         return tok_out, feed_specs
@@ -579,12 +535,12 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
 
     ``prompt_buckets`` (ascending lengths, largest == prompt_len) emits
     one prefill view PER bucket — keys ``prefill@P`` (and
-    ``prefill_slot@P`` / ``prefill_paged@P`` when slot/paged modes are
-    requested), with the bare mode name aliased to the largest bucket.
-    ``n_slots`` sizes the decode slot pool for the slot AND paged
-    views; ``page_size``/``n_pages``/``kv_codec`` shape the paged pool
-    (ISSUE 17 — see decoder_lm); ``spec_k`` sizes the verify window of
-    the ``decode_verify``/``decode_verify_paged`` views (ISSUE 19)."""
+    ``prefill_paged@P`` when the paged modes are requested), with the
+    bare mode name aliased to the largest bucket. ``n_slots`` sizes
+    the decode slot pool of the paged views; ``page_size``/``n_pages``/
+    ``kv_codec`` shape the page pool (ISSUE 17 — see decoder_lm);
+    ``spec_k`` sizes the verify window of the ``decode_verify_paged``
+    view (ISSUE 19)."""
     cache_len = prompt_len + max_new
     buckets = tuple(sorted(set(int(b)
                                for b in (prompt_buckets or (prompt_len,)))))
@@ -608,7 +564,7 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
         out[key] = (main, startup, feed_specs, outv.name)
 
     for mode in modes:
-        if mode in ("prefill", "prefill_slot", "prefill_paged"):
+        if mode in ("prefill", "prefill_paged"):
             for p in buckets:
                 emit(f"{mode}@{p}", mode, p)
             out[mode] = out[f"{mode}@{buckets[-1]}"]
@@ -617,29 +573,26 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
     return out
 
 
-def slot_modes(layout=None, spec=False):
-    """The slot-engine program modes for a KV-cache layout
-    (FLAGS_kv_cache_layout by default) — the one switch a serving
-    stack flips to go paged: pass the result as ``modes=`` to
+def slot_modes(layout="paged", spec=False):
+    """The slot engine's program modes: pass the result as ``modes=`` to
     :func:`build_decoder_lm_programs` and hand the programs to
     :func:`paddle_tpu.serving.engine.make_slot_model`. ``spec=True``
     adds the speculative-decode verify view (ISSUE 19) — the engine
-    discovers it by key and switches step() to draft→verify→commit."""
-    from paddle_tpu import flags as _flags
-    layout = layout or _flags.get("kv_cache_layout")
-    if layout not in ("contiguous", "paged"):
-        raise ValueError(f"FLAGS_kv_cache_layout {layout!r} not in "
-                         f"('contiguous', 'paged')")
-    if layout == "paged":
-        modes = ("prefill_paged", "decode_paged")
-        return modes + ("decode_verify_paged",) if spec else modes
-    modes = ("prefill_slot", "decode_slot")
-    return modes + ("decode_verify",) if spec else modes
+    discovers it by key and switches step() to draft→verify→commit.
+    ``layout`` is vestigial (ROADMAP.md Design): the benchmark's runner
+    passes its configuration's ``"paged"``, the only layout there is."""
+    if layout != "paged":
+        raise ValueError(
+            f"slot_modes: KV layout {layout!r} is not 'paged' — the "
+            f"contiguous slot layout was removed at PR 29, the paged "
+            f"pool is the slot server's only KV layout")
+    modes = ("prefill_paged", "decode_paged")
+    return modes + ("decode_verify_paged",) if spec else modes
 
 
 def contracts_lint_family():
     """``proglint --contracts`` default target: the full decoder_lm
-    serving family (every mode, bucketed prefills, slot + paged + verify
+    serving family (every mode, bucketed prefills, wave + paged + verify
     views) at lint-sized dims — the cross-view contract verifier
     (analysis/contracts.py) runs over what this returns."""
     from paddle_tpu.analysis.contracts import DECODER_LM_MODES
@@ -661,43 +614,26 @@ def serve_lint_decode():
     decoder_lm("decode")
 
 
-def serve_lint_prefill_slot():
-    """proglint --module entry: the in-flight-batching prefill that
-    scatters one request's K/V into the slot-pool caches."""
-    decoder_lm("prefill_slot", n_slots=4)
-
-
-def serve_lint_decode_slot():
-    """proglint --module entry: the slot-pool decode step with on-device
-    token sampling (the in-flight scheduler's executable)."""
-    decoder_lm("decode_slot", n_slots=4)
-
-
 def serve_lint_prefill_paged():
-    """proglint --module entry: the paged-pool prefill that scatters one
-    request's K/V through its page-table lease (shared-prefix rows
-    dropped via sentinel — ISSUE 17)."""
+    """proglint --module entry: the in-flight-batching prefill that
+    scatters one request's K/V through its page-table lease (shared-
+    prefix rows dropped via sentinel — ISSUE 17)."""
     decoder_lm("prefill_paged", n_slots=4)
 
 
 def serve_lint_decode_paged():
-    """proglint --module entry: the paged-pool decode step — page-table
-    feed indirection, donated page pools (the proglint --memory target
-    for the paged layout)."""
+    """proglint --module entry: the slot-pool decode step with on-device
+    token sampling (the in-flight scheduler's executable) — page-table
+    feed indirection, donated page pools (the proglint --memory
+    target)."""
     decoder_lm("decode_paged", n_slots=4)
 
 
-def serve_lint_verify():
-    """proglint --module entry: the speculative-decode verify step over
-    the contiguous slot pool — [n_slots, K+1] window, on-device
-    sampling of every window position (ISSUE 19)."""
-    decoder_lm("decode_verify", n_slots=4)
-
-
 def serve_lint_verify_paged():
-    """proglint --module entry: the speculative-decode verify step over
-    the PAGED pool — window writes resolved through the page-table
-    feed, beyond-lease rows dropped via sentinel (ISSUE 19)."""
+    """proglint --module entry: the speculative-decode verify step —
+    [n_slots, K+1] window, on-device sampling of every window position,
+    window writes resolved through the page-table feed, beyond-lease
+    rows dropped via sentinel (ISSUE 19)."""
     decoder_lm("decode_verify_paged", n_slots=4)
 
 

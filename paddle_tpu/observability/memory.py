@@ -60,8 +60,8 @@ HBM_WATERMARK = metrics.gauge(
     "census bytes since start")
 HBM_KV_POOL = metrics.gauge(
     "paddle_hbm_kv_pool_bytes", "Exact KV-cache pool bytes resident for "
-    "a serving model (sum of its *_cache_/*_slot_/*_page_ k/v arrays "
-    "incl. codec scale planes); the paged layout's page economy is the "
+    "a serving model (sum of its *_cache_/*_page_ k/v arrays "
+    "incl. codec scale planes); the page pool's economy is the "
     "paddle_kv_pages_* family (serving/metrics.py)", ("model",))
 DONATION_VIOLATIONS = metrics.counter(
     "paddle_donation_violations_total", "State vars the runtime donated "
@@ -305,10 +305,10 @@ _WATERMARK_HIST: deque = deque(maxlen=256)
 _watermark_peak = 0
 _CENSUS_LOCK = threading.Lock()
 
-# matches the contiguous caches (_cache_k_0 / _slot_v_1), the paged
+# matches the wave engine's caches (_cache_k_0), the slot engine's page
 # pools (_page_k_0), and the paged codec's scale planes (_page_ks_0 /
 # _page_vs_0) — all kv_cache family
-_KV_RE = re.compile(r"_(cache|slot|page)_(k|v)s?_\d+$")
+_KV_RE = re.compile(r"_(cache|page)_(k|v)s?_\d+$")
 # optimizer accumulators are '<param>_<kind>_N' (fluid/optimizer.py
 # _add_accumulator); the kinds below are every _add_accumulator call site
 _ACC_RE = re.compile(

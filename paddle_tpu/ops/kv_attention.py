@@ -16,13 +16,13 @@ full-forward graph token for token:
   PERSISTABLE vars, so ``CompiledBlock`` carries them into the serving
   scope (created_persistable) where the decode program finds them.
 
-- ``kv_attention_prefill_slot`` — the in-flight-batching prefill: same
-  causal attention, but the K/V rows are scattered into a POOL cache
-  ``[n_slots, S, H, D]`` at per-row slot indices (``Slot [B, 1]``), so a
-  new request's cache joins a live pool without disturbing the slots
-  that are mid-decode. The whole ``[S, H, D]`` row is written (zeros
-  beyond the prompt), so a reused slot never leaks its previous
-  occupant's keys.
+- ``kv_attention_prefill_paged`` — the in-flight-batching prefill: same
+  causal attention, but the K/V rows are scattered into the shared
+  ``[n_pages, page_size, H*D]`` page pool at per-position flat row
+  indices (``PageRows [T, 1]``), so a new request's cache joins a live
+  pool without disturbing the slots that are mid-decode. A reused page
+  never leaks its previous occupant's keys: the decode mask admits only
+  rows this request wrote.
 
 - ``kv_attention_decode`` — ONE new token per ROW per call, with fully
   per-row geometry: ``Pos [B,1]`` is each row's cache write index,
@@ -32,11 +32,12 @@ full-forward graph token for token:
   flows through the batch untouched. Every decode step of every mix of
   in-flight requests runs the SAME static-shape executable: zero
   steady-state compiles. (The wave-per-batch path is the special case
-  Pos = GenStart + step, Active = 1.)
+  Pos = GenStart + step, Active = 1.) ``kv_attention_decode_paged`` is
+  the slot server's form: the same geometry with each row's cache read
+  and written through a ``[n_slots, max_pages]`` page table.
 
-- ``kv_attention_verify`` / ``kv_attention_verify_paged`` — the
-  speculative-decoding verify step (ISSUE 19): score a ``[B, K+1]``
-  token window per row in ONE causal dispatch. Window position 0 is the
+- ``kv_attention_verify_paged`` — the speculative-decoding verify step
+  (ISSUE 19): score a ``[B, K+1]`` token window per row in ONE causal dispatch. Window position 0 is the
   row's last committed token (its KV row is re-written with identical
   values — the projection depends only on the token and the weights),
   positions 1..K are the drafted tokens. ``WinLen [B,1]`` bounds how
@@ -44,9 +45,9 @@ full-forward graph token for token:
   and beyond ``WinLen`` produce outputs the host ignores. Rollback of
   rejected positions is free: rejected rows sit ABOVE the committed
   frontier, the mask ``j <= pos + i`` never admits them once the host
-  rewinds, and the next window overwrites them in place (contiguous) or
-  through still-leased pages (paged — the lease keeps the pages, only
-  the slot's logical length rewinds).
+  rewinds, and the next window overwrites them through the still-leased
+  pages (the lease keeps the pages, only the slot's logical length
+  rewinds).
 
 - ``token_sample`` — on-device next-token selection: greedy argmax when
   ``temperature <= 0`` or ``top_k == 1`` (bit-identical to host argmax
@@ -142,33 +143,6 @@ def _kv_attention_prefill(ctx, ins, attrs):
     cache_k = jnp.pad(k.astype(dt), pad)
     cache_v = jnp.pad(v.astype(dt), pad)
     return {"Out": [out], "CacheK": [cache_k], "CacheV": [cache_v]}
-
-
-@register_op("kv_attention_prefill_slot", no_grad=True,
-             ref="TPU-native serving op: causal prefill whose K/V rows "
-                 "join a live [n_slots, S, H, D] pool cache at per-row "
-                 "slot indices (in-flight batching; the pool is "
-                 "read+written under one var name — donated state)")
-def _kv_attention_prefill_slot(ctx, ins, attrs):
-    """X [B,T,M], Wq..Wo [M,M], PoolK/PoolV [NS,S,H,Dk], Slot [B,1] int
-    -> Out [B,T,M] + the pools with rows ``Slot`` overwritten by this
-    prompt's padded K/V (zeros beyond T — a reused slot never leaks its
-    previous occupant). attrs: n_head."""
-    x = first(ins, "X")
-    wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
-    pool_k, pool_v = first(ins, "PoolK"), first(ins, "PoolV")
-    slot = first(ins, "Slot")
-    h = int(attrs["n_head"])
-    t = x.shape[1]
-    cache_len = pool_k.shape[1]
-    out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
-    pad = [(0, 0), (0, cache_len - t), (0, 0), (0, 0)]
-    rows_k = jnp.pad(k.astype(pool_k.dtype), pad)    # [B,S,H,D]
-    rows_v = jnp.pad(v.astype(pool_v.dtype), pad)
-    idx = jnp.asarray(slot).reshape(-1).astype(jnp.int32)
-    pool_k = pool_k.at[idx].set(rows_k)
-    pool_v = pool_v.at[idx].set(rows_v)
-    return {"Out": [out], "PoolKOut": [pool_k], "PoolVOut": [pool_v]}
 
 
 @register_op("kv_attention_decode", no_grad=True,
@@ -392,8 +366,8 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     kv_attention_decode; the cache row for logical position j lives at
     flat row table[b, j//ps]*ps + j%ps. attrs: n_head, codec. The mask
     {j < seq_len} ∪ {gen_start <= j <= pos} zeroes sentinel/garbage
-    rows EXACTLY, so fp32 paged decode is bit-identical to the
-    contiguous op."""
+    rows EXACTLY, so fp32 paged decode is bit-identical to
+    kv_attention_decode over the same rows."""
     x = first(ins, "X")
     wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
     h = int(attrs["n_head"])
@@ -419,7 +393,8 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
 
     # this step's write row through the page table, sentinel (dropped)
     # for inactive slots — a free slot's pages are bit-identical before
-    # and after the step, same contract as the contiguous one-hot write
+    # and after the step, same contract as kv_attention_decode's gated
+    # one-hot write
     wpage = jnp.take_along_axis(table, (pos // ps)[:, None],
                                 axis=1)[:, 0]
     wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
@@ -448,68 +423,6 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 
 
-@register_op("kv_attention_verify", no_grad=True,
-             ref="TPU-native serving op: speculative-decode verify — "
-                 "score a [B, K+1] draft window against the contiguous "
-                 "KV cache in one causal dispatch, writing the window's "
-                 "rows in place (rollback = overwrite next dispatch)")
-def _kv_attention_verify(ctx, ins, attrs):
-    """X [B,K1,M] (window: last committed token + K drafts), Wq..Wo
-    [M,M], CacheK/CacheV [B,S,H,Dk], Pos [B,1] int (cache row of window
-    position 0 — the row's committed frontier), SeqLen/GenStart/Active
-    [B,1] as in kv_attention_decode, WinLen [B,1] int (valid window
-    positions, 1..K1; 1 degenerates to plain decode). attrs: n_head.
-
-    Writes k/v for window position i at cache row ``pos + i`` where
-    ``active & i < win_len & pos + i < S``; attends position i over
-    {j < seq_len} ∪ {gen_start <= j <= pos + i} — causal INSIDE the
-    window, so Out[:, i] is bit-identical to what i sequential
-    kv_attention_decode steps over the same tokens would produce."""
-    x = first(ins, "X")
-    wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
-    cache_k, cache_v = first(ins, "CacheK"), first(ins, "CacheV")
-    h = int(attrs["n_head"])
-    b, k1, m = x.shape
-    s_len = cache_k.shape[1]
-    d = m // h
-    dt = x.dtype
-
-    pos = jnp.asarray(first(ins, "Pos")).reshape(-1).astype(jnp.int32)
-    lens = jnp.asarray(first(ins, "SeqLen")).reshape(-1).astype(jnp.int32)
-    gen0 = jnp.asarray(first(ins, "GenStart")).reshape(-1)\
-        .astype(jnp.int32)
-    active = jnp.asarray(first(ins, "Active")).reshape(-1) > 0
-    wlen = jnp.asarray(first(ins, "WinLen")).reshape(-1).astype(jnp.int32)
-
-    q = _ab._proj(x, wq, h)                     # [B,K1,H,D]
-    k_t = _ab._proj(x, wk, h).astype(cache_k.dtype)
-    v_t = _ab._proj(x, wv, h).astype(cache_v.dtype)
-
-    j = jnp.arange(s_len, dtype=jnp.int32)
-    off = j[None, :] - pos[:, None]                         # [B,S]
-    wmask = active[:, None] & (off >= 0) & (off < wlen[:, None])
-    widx = jnp.clip(off, 0, k1 - 1)[:, :, None, None]       # [B,S,1,1]
-    cache_k = jnp.where(wmask[:, :, None, None],
-                        jnp.take_along_axis(k_t, widx, axis=1), cache_k)
-    cache_v = jnp.where(wmask[:, :, None, None],
-                        jnp.take_along_axis(v_t, widx, axis=1), cache_v)
-
-    s = jax.lax.dot_general(q, cache_k, (((3,), (3,)), ((0, 2), (0, 2))),
-                            preferred_element_type=jnp.float32)
-    s = s.astype(jnp.float32) * (float(d) ** -0.5)   # [B,H,K1,S]
-    i = jnp.arange(k1, dtype=jnp.int32)
-    valid = (j[None, None, :] < lens[:, None, None]) | \
-            ((j[None, None, :] >= gen0[:, None, None]) &
-             (j[None, None, :] <= (pos[:, None] + i[None, :])[:, :, None]))
-    p = _scores_to_probs(s, valid[:, None], dt)      # [B,H,K1,S]
-    c = jax.lax.dot_general(p, cache_v, (((3,), (1,)), ((0, 1), (0, 2))),
-                            preferred_element_type=jnp.float32).astype(dt)
-    out = jax.lax.dot_general(c, wo.reshape(h, d, m),
-                              (((1, 3), (0, 1)), ((), ())),
-                              preferred_element_type=jnp.float32).astype(dt)
-    return {"Out": [out], "CacheKOut": [cache_k], "CacheVOut": [cache_v]}
-
-
 @register_op("kv_attention_verify_paged", no_grad=True,
              ref="TPU-native serving op: speculative-decode verify over "
                  "the PAGED KV pool — the K+1 window's write rows "
@@ -517,12 +430,20 @@ def _kv_attention_verify(ctx, ins, attrs):
                  "rows drop: beyond-lease and inactive writes never "
                  "land), gather and mask as kv_attention_decode_paged")
 def _kv_attention_verify_paged(ctx, ins, attrs):
-    """X [B,K1,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H * Dk]
-    (+ PageKS/PageVS when codec=int8), PageTable [B, MP] int,
-    Pos/SeqLen/GenStart/Active/WinLen [B,1] — geometry identical to
-    kv_attention_verify with the cache row for logical position j at
-    flat row table[b, j//ps]*ps + j%ps. attrs: n_head, codec. Window
-    writes that fall past the slot's leased span hit the table's
+    """X [B,K1,M] (window: last committed token + K drafts), Wq..Wo
+    [M,M], PageK/PageV [n_pages, ps, H * Dk] (+ PageKS/PageVS when
+    codec=int8), PageTable [B, MP] int, Pos [B,1] int (logical cache
+    row of window position 0 — the row's committed frontier),
+    SeqLen/GenStart/Active [B,1] as in kv_attention_decode, WinLen
+    [B,1] int (valid window positions, 1..K1; 1 degenerates to plain
+    decode). The cache row for logical position j is flat row
+    table[b, j//ps]*ps + j%ps. attrs: n_head, codec.
+
+    Writes k/v for window position i at logical row ``pos + i`` where
+    ``active & i < win_len & pos + i < S``; attends position i over
+    {j < seq_len} ∪ {gen_start <= j <= pos + i} — causal INSIDE the
+    window, so Out[:, i] is what i sequential kv_attention_decode_paged
+    steps over the same tokens would produce. Window writes that fall past the slot's leased span hit the table's
     sentinel page (row >= n_pages*ps) and DROP — a draft window can
     never corrupt another slot's pages (the admission span reserves
     the draft-window overshoot, serving/kv_pool.py)."""

@@ -38,8 +38,6 @@ _LAZY = {
     "GenerativeModel": ("paddle_tpu.serving.engine", "GenerativeModel"),
     "SlotGenerativeModel": ("paddle_tpu.serving.engine",
                             "SlotGenerativeModel"),
-    "PagedSlotGenerativeModel": ("paddle_tpu.serving.engine",
-                                 "PagedSlotGenerativeModel"),
     "make_slot_model": ("paddle_tpu.serving.engine", "make_slot_model"),
     "PagePool": ("paddle_tpu.serving.kv_pool", "PagePool"),
     "PagesExhaustedError": ("paddle_tpu.serving.kv_pool",

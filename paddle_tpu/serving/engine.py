@@ -22,23 +22,21 @@ steady-state serving performs zero XLA compilations
   full forward per token; ``analyzed_flops`` of the decode executable
   is independent of the decode position by construction.
 
-- :class:`SlotGenerativeModel` — in-flight batched decoding (ISSUE 9):
-  the decode executable is ONE fixed-shape ``[n_slots]``-row program
-  over pool caches; requests JOIN a free slot mid-flight (prefill
-  scatters their cache rows in) and LEAVE on EOS/max-tokens/cancel, so
-  the device stays saturated with whatever work exists right now — no
-  wave barrier, with on-device temperature/top-k sampling per slot.
-
-- :class:`PagedSlotGenerativeModel` (ISSUE 17) — the slot engine over a
-  PAGED KV pool: slots address their cache through a per-slot page
-  table into one shared ``[n_pages, page_size, H*D]`` pool, admission
-  is gated by FREE PAGES for the request's span (prompt bucket + token
-  budget) instead of a whole worst-case row, and requests with a
-  common prompt prefix physically share full prefix pages through a
-  refcounted radix tree (``serving/kv_pool.py``). Same zero-
-  steady-state-compile contract: the page table is a fixed-shape
+- :class:`SlotGenerativeModel` — in-flight batched decoding (ISSUE 9)
+  over a PAGED KV pool (ISSUE 17): the decode executable is ONE
+  fixed-shape ``[n_slots]``-row program; requests JOIN a free slot
+  mid-flight (prefill scatters their cache rows in) and LEAVE on
+  EOS/max-tokens/cancel, so the device stays saturated with whatever
+  work exists right now — no wave barrier, with on-device
+  temperature/top-k sampling per slot. Slots address their cache
+  through a per-slot page table into one shared ``[n_pages, page_size,
+  H*D]`` pool, admission is gated by FREE PAGES for the request's span
+  (prompt bucket + token budget) instead of a whole worst-case row,
+  and requests with a common prompt prefix physically share full
+  prefix pages through a refcounted radix tree
+  (``serving/kv_pool.py``). The page table is a fixed-shape
   ``[n_slots, max_pages]`` feed, so join/leave churn never re-lowers.
-  ``make_slot_model`` picks the engine class off the program keys.
+  ``make_slot_model`` builds it.
 """
 
 from __future__ import annotations
@@ -54,7 +52,7 @@ import numpy as np
 
 from paddle_tpu.observability import runtime as obs_runtime
 from paddle_tpu.observability import trace_context as tctx
-from paddle_tpu.serving import bucketing
+from paddle_tpu.serving import bucketing, kv_pool
 from paddle_tpu.serving import metrics as smetrics
 from paddle_tpu.utils import padding as _padding
 
@@ -562,9 +560,9 @@ class GenerativeModel:
                               max_new: Optional[int] = None
                               ) -> List[np.ndarray]:
         """The O(T)-per-token baseline: a fresh full causal forward for
-        every emitted token (requires the "full" program). Exists so
-        tools/serve_bench.py can measure the KV-cache speedup against
-        the exact same weights."""
+        every emitted token (requires the "full" program): the greedy
+        oracle the tests and chip_smoke.py compare the KV-cache paths
+        against, on the exact same weights."""
         if self._full is None:
             raise RuntimeError("no 'full' program was provided")
         max_new = self.max_new if max_new is None else int(max_new)
@@ -725,14 +723,29 @@ class ModelDrafter:
 
 class SlotGenerativeModel:
     """In-flight batched decoding over a persistent decode-slot pool
-    (ISSUE 9): the decode executable is ONE fixed-shape
-    ``[n_slots]``-row program where each slot carries its own KV-cache
-    rows, per-row position/active geometry, and per-request sampling
-    state. Requests JOIN a free slot mid-flight (``admit`` prefills the
-    prompt at the nearest prompt bucket and scatters its cache rows into
-    the pool via ``kv_attention_prefill_slot``) and LEAVE on
-    EOS/max-tokens (``step`` reports the leave and frees the slot) — no
-    wave barrier, zero steady-state compiles.
+    (ISSUE 9) whose KV cache is PAGED (ISSUE 17): the decode executable
+    is ONE fixed-shape ``[n_slots]``-row program where each slot carries
+    per-row position/active geometry and per-request sampling state, and
+    reads its K/V through a ``[n_slots, max_pages]`` page-table feed
+    into one shared ``[n_pages, page_size, H*D]`` pool per layer
+    (row-major at rest on the chip: ops/kv_attention.py:_paged_pools),
+    so HBM holds pages for the requests actually in flight instead of
+    ``n_slots`` worst-case rows. Requests JOIN a free slot mid-flight
+    (``admit`` prefills the prompt at the nearest prompt bucket and
+    scatters its cache rows into the slot's pages via
+    ``kv_attention_prefill_paged``) and LEAVE on EOS/max-tokens
+    (``step`` reports the leave and frees the slot) — no wave barrier,
+    zero steady-state compiles (the page table is a fixed-shape feed:
+    join/leave churn re-dispatches, never re-lowers).
+
+    Admission acquires ``ceil((prompt_bucket + budget) / page_size)``
+    pages from :class:`~paddle_tpu.serving.kv_pool.PagePool`; full pages
+    of the TRUE prompt are shared with earlier requests carrying the
+    same token prefix (radix tree, refcounted — prefill skips recomputed
+    writes into shared pages via sentinel row ids, the copy-on-write
+    boundary page is always private). ``FLAGS_kv_cache_codec`` may
+    store the pool as bf16 or int8+per-(position, head) scale planes;
+    the dequantizing gather lives in ``ops/pallas/paged_attention.py``.
 
     Sampling runs ON DEVICE (``token_sample``): greedy when
     ``temperature <= 0`` or ``top_k == 1`` (bit-matches the greedy
@@ -740,20 +753,19 @@ class SlotGenerativeModel:
     the per-request seed + token index — a sampled stream replays
     identically across server restarts.
 
-    Built from ``build_decoder_lm_programs(..., modes=("prefill_slot",
-    "decode_slot"), n_slots=..., prompt_buckets=...)``. Thread
-    discipline: one dispatcher at a time (the server's scheduler
+    Built from ``build_decoder_lm_programs(..., modes=slot_modes(),
+    n_slots=..., prompt_buckets=..., n_pages=..., page_size=...)``.
+    Thread discipline: one dispatcher at a time (the server's scheduler
     thread); ``admit``/``step``/``release`` are not internally locked."""
 
-    # the program-key pair this engine dispatches; the paged subclass
-    # swaps in its views and everything keyed on these (warmup, AOT
-    # tags, compile-counter kinds) follows. VERIFY is the OPTIONAL
+    # the program keys this engine dispatches; warmup, AOT tags and
+    # compile-counter kinds are keyed on them. VERIFY is the OPTIONAL
     # speculative-decoding view (ISSUE 19): when the program family
     # carries it, step() switches from one-token decode to
     # draft→verify→commit over a [n_slots, K+1] window.
-    PREFILL = "prefill_slot"
-    DECODE = "decode_slot"
-    VERIFY = "decode_verify"
+    PREFILL = "prefill_paged"
+    DECODE = "decode_paged"
+    VERIFY = "decode_verify_paged"
 
     def __init__(self, name: str, programs: Dict, scope=None,
                  init: bool = True, dist=None, drafter=None):
@@ -767,9 +779,10 @@ class SlotGenerativeModel:
             if key == pk or key.startswith(pk + "@"):
                 pre[int(val[2]["ids"][0][1])] = val
         if not pre or dk not in programs:
-            raise ValueError(f"programs must contain {pk!r} and {dk!r} "
-                             f"views (build_decoder_lm_programs(..., "
-                             f"n_slots=...))")
+            raise ValueError(
+                f"programs must contain the {pk!r} and {dk!r} views "
+                f"(build_decoder_lm_programs(..., modes=slot_modes(), "
+                f"n_slots=...)); got {sorted(programs)}")
         self.prompt_buckets = tuple(sorted(pre))
         self.prompt_len = self.prompt_buckets[-1]
         dec_main, dec_start, dec_feeds, dec_fetch = programs[dk]
@@ -850,13 +863,33 @@ class SlotGenerativeModel:
         self._hist: List[List[int]] = [[] for _ in range(s)]
 
     def _discover_pool(self, dec_main, dec_feeds):
-        """Read the KV capacity off the decode program's pool vars.
-        Contiguous layout: ``*_slot_k_0`` is ``[n_slots, cache_len, H,
-        D]``. The paged subclass overrides this to size its page pool."""
+        """Size the page pool and the host page-table mirror off the
+        decode program's pool vars and page-table feed."""
         pool_vars = [v for n, v in dec_main.desc.global_block.vars.items()
-                     if n.endswith("_slot_k_0")]
-        self.cache_len = int(pool_vars[0].shape[1]) if pool_vars else 0
+                     if n.endswith("_page_k_0")]
+        if not pool_vars:
+            raise ValueError(
+                f"model {self.name!r}: decode_paged program has no "
+                f"*_page_k_* pool vars")
+        self.n_pages = int(pool_vars[0].shape[0])
+        self.page_size = int(pool_vars[0].shape[1])
+        self.max_pages = int(dec_feeds["page_table"][0][1])
+        self.cache_len = self.max_pages * self.page_size
         self.max_new = self.cache_len - self.prompt_len
+        if self.n_pages < self.max_pages:
+            raise ValueError(
+                f"model {self.name!r}: pool of {self.n_pages} pages "
+                f"cannot hold one worst-case request ({self.max_pages} "
+                f"pages) — admission could never succeed")
+        self.pool = kv_pool.PagePool(self.n_pages, self.page_size,
+                                     model=self.name)
+        # row-write sentinel: one past the flat pool -> scatter drops it
+        self._row_sentinel = self.n_pages * self.page_size
+        # host page-table mirror; n_pages is the TABLE sentinel (gather
+        # rows land past the pool and are clamped+masked on device)
+        self._table = np.full((self.n_slots, self.max_pages),
+                              self.n_pages, np.int64)
+        self._pending_rows: Optional[np.ndarray] = None
 
     # -- plumbing (same dispatch/AOT discipline as GenerativeModel) ------
     _args = GenerativeModel._args
@@ -872,6 +905,9 @@ class SlotGenerativeModel:
     def occupancy(self) -> float:
         return self.active_count() / float(self.n_slots)
 
+    def free_pages(self) -> int:
+        return self.pool.free_count()
+
     def _decode_feeds(self):
         return {"tok": self._tok[:, None, None],
                 "pos": (self._gen0 + self._gen_count - 1)[:, None],
@@ -881,7 +917,8 @@ class SlotGenerativeModel:
                 "seed": self._seed[:, None],
                 "sample_step": self._gen_count[:, None],
                 "temperature": self._temp[:, None],
-                "top_k": self._topk[:, None]}
+                "top_k": self._topk[:, None],
+                "page_table": self._table.copy()}
 
     def _verify_feeds(self, tok_w=None, win_len=None):
         """The verify dispatch's fixed-shape feeds. The sampling feeds
@@ -907,7 +944,8 @@ class SlotGenerativeModel:
                 "seed": np.tile(self._seed[:, None], (1, k1)),
                 "sample_step": steps,
                 "temperature": np.tile(self._temp[:, None], (1, k1)),
-                "top_k": np.tile(self._topk[:, None], (1, k1))}
+                "top_k": np.tile(self._topk[:, None], (1, k1)),
+                "page_table": self._table.copy()}
 
     def _prefill_feeds(self, p_len: int):
         return {"ids": np.zeros((1, p_len, 1), np.int64),
@@ -918,26 +956,57 @@ class SlotGenerativeModel:
                 "top_k": np.zeros((1, 1), np.int64)}
 
     def _admit_feeds(self, slot: int, p_len: int):
-        """The layout-specific prefill feed: WHERE the prompt's KV rows
-        land. Contiguous: the slot index (its whole cache row)."""
-        return {"slot": np.asarray([[slot]], np.int64)}
+        """Prefill feed: the flat pool row for each prompt position —
+        or the drop sentinel for positions whose pages are SHARED with
+        the radix tree (their K/V is already resident and bit-identical
+        by construction; rewriting would race other readers only in
+        spirit, but skipping also keeps the write volume proportional
+        to the non-shared suffix). Warmup (no reservation pending)
+        feeds all sentinels: compile the shapes, write nothing."""
+        rows = self._pending_rows
+        self._pending_rows = None
+        if rows is None:
+            rows = np.full((p_len, 1), self._row_sentinel, np.int64)
+        return {"page_rows": rows}
 
-    def _reserve_capacity(self, slot: int, prompt, p_len: int,
-                          budget: int):
-        """Admission-time capacity hook. Contiguous layout reserves
-        nothing beyond the slot itself; the paged subclass acquires
-        pages here (and raises SlotExhaustedError when the pool can't
-        cover the request's span)."""
+    def _reserve_capacity(self, slot, prompt, p_len, budget):
+        """Admission-time capacity: lease the request's pages (raises
+        SlotExhaustedError when the pool can't cover its span), fill
+        the slot's table row and stage the prefill's write rows."""
+        # draft_window=0 even under speculation: _step_verify caps each
+        # window at remaining-1 drafts, so verify writes never pass row
+        # p_len + budget - 1. An engine drafting a FULL window at the
+        # max_new boundary would need span_for(..., draft_window=spec_k)
+        # here — the off-by-K the span formula's parameter guards.
+        span = self.pool.span_for(p_len + budget, draft_window=0)
+        try:
+            pages, n_shared = self.pool.acquire(
+                slot, [int(t) for t in prompt], span)
+        except kv_pool.PagesExhaustedError as e:
+            raise SlotExhaustedError(
+                f"model {self.name!r}: page pool cannot cover a "
+                f"{span}-page admission (free_pages="
+                f"{self.pool.free_count()}, evictable_cached="
+                f"{self.pool.cached_count()}, pages_total="
+                f"{self.n_pages}, free_slots={self.free_count()}, "
+                f"active_slots={self.active_count()})") from e
+        ps = self.page_size
+        idx = np.arange(p_len)
+        rows = np.asarray(pages, np.int64)[idx // ps] * ps + idx % ps
+        rows[idx < n_shared * ps] = self._row_sentinel
+        self._pending_rows = rows[:, None]
+        self._table[slot, :] = self.n_pages
+        self._table[slot, :span] = pages
 
-    def _release_capacity(self, slot: int):
-        """Failure twin of :meth:`_reserve_capacity`: undo the
-        admission-time reservation when the prefill dispatch raises
-        before the slot goes live. ``release`` won't run for such a
-        slot (it never became active), so without this hook the paged
-        pool would keep the lease forever — and since ``admit`` always
-        picks the lowest free slot, every later admission would retry
-        the same slot and trip its already-holds-a-lease guard.
-        Contiguous layout reserved nothing."""
+    def _release_capacity(self, slot):
+        """A prefill dispatch died after acquire: abort the lease (the
+        pages it inserted into the prefix tree were never written, so
+        they must not survive as cache), scrub the slot's table row,
+        and drop any not-yet-consumed write rows so the next unrelated
+        admission can't inherit them."""
+        self.pool.abort(slot)
+        self._table[slot, :] = self.n_pages
+        self._pending_rows = None
 
     # -- warmup / AOT ----------------------------------------------------
     def warmup(self, aot_dir: Optional[str] = None,
@@ -985,7 +1054,8 @@ class SlotGenerativeModel:
         tag = kind + (f"_p{p_len}" if p_len else "")
         return os.path.join(
             dirname,
-            f"__slot_{tag}_s{self.n_slots}.{self._fingerprint[:12]}.pax")
+            f"__paged_{tag}_s{self.n_slots}_pg{self.n_pages}"
+            f"x{self.page_size}.{self._fingerprint[:12]}.pax")
 
     def _persist_one(self, dirname: str, kind: str,
                      p_len: Optional[int] = None):
@@ -1263,11 +1333,13 @@ class SlotGenerativeModel:
         return events
 
     def release(self, slot: int, cause: str = "cancelled"):
-        """LEAVE: free ``slot`` for the next admission (its pool cache
-        rows are fully overwritten by that admission's prefill, so
-        nothing is scrubbed here)."""
+        """LEAVE: free ``slot`` and return its page lease for the next
+        admission (nothing is scrubbed on the device: the next holder's
+        mask admits only rows it wrote itself)."""
         if not self._active[slot]:
             return
+        self.pool.release(slot)
+        self._table[slot, :] = self.n_pages
         self._active[slot] = False
         self._eos[slot] = None
         smetrics.SLOT_EVICTIONS.labels(model=self.name,
@@ -1275,6 +1347,9 @@ class SlotGenerativeModel:
         self._m_occupancy.set(self.occupancy())
 
     def reset(self):
+        self.pool.reset()
+        self._table[:] = self.n_pages
+        self._pending_rows = None
         self._active[:] = False
         self._gen_count[:] = 0
         self._eos = [None] * self.n_slots
@@ -1315,162 +1390,16 @@ class SlotGenerativeModel:
                 for i in range(len(prompts))]
 
 
-class PagedSlotGenerativeModel(SlotGenerativeModel):
-    """Slot engine over a PAGED KV pool (ISSUE 17): the decode program
-    reads each slot's K/V through a ``[n_slots, max_pages]`` page-table
-    feed into one shared ``[n_pages, page_size, H*D]`` pool per layer
-    (row-major at rest on the chip: ops/kv_attention.py:_paged_pools),
-    so HBM holds pages for the requests actually in flight instead of
-    ``n_slots`` worst-case rows. Admission acquires
-    ``ceil((prompt_bucket + budget) / page_size)`` pages from
-    :class:`~paddle_tpu.serving.kv_pool.PagePool`; full pages of the
-    TRUE prompt are shared with earlier requests carrying the same
-    token prefix (radix tree, refcounted — prefill skips recomputed
-    writes into shared pages via sentinel row ids, the copy-on-write
-    boundary page is always private). ``FLAGS_kv_cache_codec`` may
-    store the pool as bf16 or int8+per-(position, head) scale planes;
-    the dequantizing gather lives in ``ops/pallas/paged_attention.py``.
-
-    Drop-in for :class:`SlotGenerativeModel` everywhere the server
-    cares: same ``admit``/``step``/``release``/``generate`` surface,
-    same zero-steady-state-compile warmup contract (the page table is a
-    fixed-shape feed — join/leave churn re-dispatches, never
-    re-lowers). Built from ``build_decoder_lm_programs(..., modes=
-    ("prefill_paged", "decode_paged"), n_slots=..., n_pages=...,
-    page_size=...)``."""
-
-    PREFILL = "prefill_paged"
-    DECODE = "decode_paged"
-    VERIFY = "decode_verify_paged"
-
-    def _discover_pool(self, dec_main, dec_feeds):
-        from paddle_tpu.serving import kv_pool
-        pool_vars = [v for n, v in dec_main.desc.global_block.vars.items()
-                     if n.endswith("_page_k_0")]
-        if not pool_vars:
-            raise ValueError(
-                f"model {self.name!r}: decode_paged program has no "
-                f"*_page_k_* pool vars")
-        self.n_pages = int(pool_vars[0].shape[0])
-        self.page_size = int(pool_vars[0].shape[1])
-        self.max_pages = int(dec_feeds["page_table"][0][1])
-        self.cache_len = self.max_pages * self.page_size
-        self.max_new = self.cache_len - self.prompt_len
-        if self.n_pages < self.max_pages:
-            raise ValueError(
-                f"model {self.name!r}: pool of {self.n_pages} pages "
-                f"cannot hold one worst-case request ({self.max_pages} "
-                f"pages) — admission could never succeed")
-        self.pool = kv_pool.PagePool(self.n_pages, self.page_size,
-                                     model=self.name)
-        # row-write sentinel: one past the flat pool -> scatter drops it
-        self._row_sentinel = self.n_pages * self.page_size
-        # host page-table mirror; n_pages is the TABLE sentinel (gather
-        # rows land past the pool and are clamped+masked on device)
-        self._table = np.full((self.n_slots, self.max_pages),
-                              self.n_pages, np.int64)
-        self._pending_rows: Optional[np.ndarray] = None
-
-    def free_pages(self) -> int:
-        return self.pool.free_count()
-
-    def _decode_feeds(self):
-        feeds = SlotGenerativeModel._decode_feeds(self)
-        feeds["page_table"] = self._table.copy()
-        return feeds
-
-    def _verify_feeds(self, tok_w=None, win_len=None):
-        feeds = SlotGenerativeModel._verify_feeds(self, tok_w, win_len)
-        feeds["page_table"] = self._table.copy()
-        return feeds
-
-    def _admit_feeds(self, slot: int, p_len: int):
-        """Prefill feed: the flat pool row for each prompt position —
-        or the drop sentinel for positions whose pages are SHARED with
-        the radix tree (their K/V is already resident and bit-identical
-        by construction; rewriting would race other readers only in
-        spirit, but skipping also keeps the write volume proportional
-        to the non-shared suffix). Warmup (no reservation pending)
-        feeds all sentinels: compile the shapes, write nothing."""
-        rows = self._pending_rows
-        self._pending_rows = None
-        if rows is None:
-            rows = np.full((p_len, 1), self._row_sentinel, np.int64)
-        return {"page_rows": rows}
-
-    def _reserve_capacity(self, slot, prompt, p_len, budget):
-        from paddle_tpu.serving import kv_pool
-        # draft_window=0 even under speculation: _step_verify caps each
-        # window at remaining-1 drafts, so verify writes never pass row
-        # p_len + budget - 1. An engine drafting a FULL window at the
-        # max_new boundary would need span_for(..., draft_window=spec_k)
-        # here — the off-by-K the span formula's parameter guards.
-        span = self.pool.span_for(p_len + budget, draft_window=0)
-        try:
-            pages, n_shared = self.pool.acquire(
-                slot, [int(t) for t in prompt], span)
-        except kv_pool.PagesExhaustedError as e:
-            raise SlotExhaustedError(
-                f"model {self.name!r}: page pool cannot cover a "
-                f"{span}-page admission (free_pages="
-                f"{self.pool.free_count()}, evictable_cached="
-                f"{self.pool.cached_count()}, pages_total="
-                f"{self.n_pages}, free_slots={self.free_count()}, "
-                f"active_slots={self.active_count()})") from e
-        ps = self.page_size
-        idx = np.arange(p_len)
-        rows = np.asarray(pages, np.int64)[idx // ps] * ps + idx % ps
-        rows[idx < n_shared * ps] = self._row_sentinel
-        self._pending_rows = rows[:, None]
-        self._table[slot, :] = self.n_pages
-        self._table[slot, :span] = pages
-
-    def _release_capacity(self, slot):
-        """A prefill dispatch died after acquire: abort the lease (the
-        pages it inserted into the prefix tree were never written, so
-        they must not survive as cache), scrub the slot's table row,
-        and drop any not-yet-consumed write rows so the next unrelated
-        admission can't inherit them."""
-        self.pool.abort(slot)
-        self._table[slot, :] = self.n_pages
-        self._pending_rows = None
-
-    def release(self, slot: int, cause: str = "cancelled"):
-        if self._active[slot]:
-            self.pool.release(slot)
-            self._table[slot, :] = self.n_pages
-        SlotGenerativeModel.release(self, slot, cause=cause)
-
-    def reset(self):
-        self.pool.reset()
-        self._table[:] = self.n_pages
-        self._pending_rows = None
-        SlotGenerativeModel.reset(self)
-
-    def _aot_path(self, dirname: str, kind: str,
-                  p_len: Optional[int] = None) -> str:
-        tag = kind + (f"_p{p_len}" if p_len else "")
-        return os.path.join(
-            dirname,
-            f"__paged_{tag}_s{self.n_slots}_pg{self.n_pages}"
-            f"x{self.page_size}.{self._fingerprint[:12]}.pax")
-
-
 def make_slot_model(name: str, programs: Dict, scope=None,
                     init: bool = True, dist=None,
                     drafter=None) -> SlotGenerativeModel:
-    """Build the slot engine matching ``programs``' layout: paged views
-    (``prefill_paged``/``decode_paged``, from ``FLAGS_kv_cache_layout=
-    paged`` via ``transformer.slot_modes()``) get
-    :class:`PagedSlotGenerativeModel`; the contiguous slot views get
-    :class:`SlotGenerativeModel`. ``dist`` (a ``DistributeConfig``)
-    lowers every view over its mesh — see docs/serving.md. ``drafter``
-    overrides the speculative proposer (default
-    :class:`NgramDrafter`) for engines built with a verify view."""
-    if any(k == "decode_paged" or k == "prefill_paged"
-           or k.startswith("prefill_paged@") for k in programs):
-        return PagedSlotGenerativeModel(name, programs, scope=scope,
-                                        init=init, dist=dist,
-                                        drafter=drafter)
+    """Build the slot engine over ``programs`` (from
+    ``build_decoder_lm_programs(..., modes=transformer.slot_modes())``);
+    raises ``ValueError`` naming the missing ``prefill_paged`` /
+    ``decode_paged`` views when handed a family without them. ``dist``
+    (a ``DistributeConfig``) lowers every view over its mesh — see
+    docs/serving.md. ``drafter`` overrides the speculative proposer
+    (default :class:`NgramDrafter`) for engines built with a verify
+    view."""
     return SlotGenerativeModel(name, programs, scope=scope, init=init,
                                dist=dist, drafter=drafter)

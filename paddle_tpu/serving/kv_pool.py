@@ -1,15 +1,14 @@
-"""Paged KV-cache page pool: the host-side allocator behind the
-``kv_cache_layout=paged`` serving engine (ISSUE 17 tentpole).
+"""Paged KV-cache page pool: the host-side allocator behind the slot
+serving engine (``serving/engine.py:SlotGenerativeModel``, ISSUE 17).
 
-The contiguous slot pool reserves one worst-case ``[n_slots, S, H, D]``
-region per layer; ``paddle_hbm_kv_pool_bytes`` (PR 15) shows exactly
-what short requests waste inside it. The paged layout breaks that
-reservation into ``n_pages`` fixed-size pages (``[n_pages, page_size,
-H, D]`` per layer on device) and admits by FREE-PAGE count: a request
-whose prompt pads to bucket ``P`` with token budget ``B`` holds
-``span = ceil((P + B) / page_size)`` pages, not ``S`` rows — so the
-same HBM budget carries several times the concurrent decode slots
-(SERVE_r05, docs/serving.md "Paged KV cache").
+A cache that reserved one worst-case ``[n_slots, S, H, D]`` region per
+layer would waste, for every short request, the rows it never reaches.
+The pool holds ``n_pages`` fixed-size pages instead (``[n_pages,
+page_size, H*D]`` per layer on device) and admits by FREE-PAGE count:
+a request whose prompt pads to bucket ``P`` with token budget ``B``
+holds ``span = ceil((P + B) / page_size)`` pages, not ``S`` rows — so
+the same HBM budget carries more concurrent decode slots
+(docs/serving.md "Paged KV cache").
 
 This module is pure host bookkeeping — device K/V bytes never move
 through it. Three cooperating structures:

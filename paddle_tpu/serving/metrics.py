@@ -46,7 +46,7 @@ QUEUE_WAIT = _metrics.histogram(
     "Admission-to-dispatch wait (enqueue until the batcher coalesces "
     "the request into a wave, or the slot scheduler pops it for "
     "admission) — the queueing-delay component the depth gauge cannot "
-    "show; p50/p99 surface in tools/serve_bench.py",
+    "show",
     labelnames=("model",))
 BATCH_OCCUPANCY = _metrics.gauge(
     "paddle_serving_batch_occupancy_ratio",

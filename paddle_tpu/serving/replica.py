@@ -65,9 +65,12 @@ def build_engine(model_spec: dict):
         from paddle_tpu.models import transformer as T
         params = dict(model_spec.get("params") or {})
         if model_spec.get("slots", True):
-            params.setdefault("modes", ("prefill_slot", "decode_slot"))
+            # page geometry the spec leaves out comes from
+            # analysis.contracts.validate_geometry: page_size 4,
+            # n_pages = every slot at full length
+            params.setdefault("modes", T.slot_modes("paged"))
             params.setdefault("n_slots", 2)
-            return engine.SlotGenerativeModel(
+            return engine.make_slot_model(
                 name, T.build_decoder_lm_programs(name=name, **params))
         params.setdefault("modes", ("prefill", "decode"))
         programs = T.build_decoder_lm_programs(name=name, **params)

@@ -354,8 +354,7 @@ class _HostedModel:
         max_new = max(r.max_new for r in wave)
         toks = self.engine.generate(prompts, max_new=max_new)
         # the wave yields no token before it drains: TTFT == settle time
-        # (the honest control-arm number the slot scheduler is measured
-        # against in tools/serve_bench.py)
+        # (the honest control-arm number for the slot scheduler)
         now = time.perf_counter()
         i = 0
         for r in wave:
@@ -537,7 +536,7 @@ class _SlotHostedModel(_HostedModel):
                         top_k=req.top_k, max_new=req.max_new,
                         eos_id=req.eos_id)
             except SlotExhaustedError:
-                # paged engines can run out of PAGES while slots remain
+                # the engine can run out of PAGES while slots remain
                 # free (free_count() gates only slots); the request is
                 # fine — put the prompt back and retry after a leave
                 stream.pending.appendleft((pi, prompt))

@@ -2,8 +2,7 @@
 persistent compilation cache, and name the device in what it prints.
 
 Called by ``chip_smoke.py``, each ``bench.py`` row,
-``tools/serve_bench.py``, ``tools/perf_probe.py`` and
-``serving/replica.py``'s main — never at package import, so the CPU
+``tools/perf_probe.py`` and ``serving/replica.py``'s main — never at package import, so the CPU
 test suite keeps JAX's default (no persistent cache).
 """
 

@@ -207,16 +207,6 @@ def op_fwd_flops(block, op_type, inputs, outputs, attrs, batch,
         h = int(attrs.get("n_head", 1))
         d = m // max(h, 1)
         return 2.0 * b * m * m * 4.0 * t + 2.0 * b * h * t * t * d
-    if op_type == "kv_attention_prefill_slot":
-        # same math as kv_attention_prefill; the pool scatter is a copy,
-        # not flops
-        x = ishape("X")
-        if x is None:
-            return 0.0
-        b, t, m = x[-3], x[-2], x[-1]
-        h = int(attrs.get("n_head", 1))
-        d = m // max(h, 1)
-        return 2.0 * b * m * m * 4.0 * t + 2.0 * b * h * t * t * d
     if op_type == "kv_attention_decode":
         # one token per row: projections (4 × [B,1,M]·[M,M]) + dots over
         # the STATIC cache length — independent of the decode position
@@ -230,23 +220,14 @@ def op_fwd_flops(block, op_type, inputs, outputs, attrs, batch,
         h = int(attrs.get("n_head", 1))
         d = m // max(h, 1)
         return 2.0 * b * m * m * 4.0 + 2.0 * b * h * s * d * 2.0
-    if op_type == "kv_attention_verify":
+    if op_type == "kv_attention_verify_paged":
         # draft-verify window: K+1 tokens per row through the decode
         # math — projections (4 × [B,K1,M]·[M,M]) + dots of every window
         # position against the static cache length (the verify dispatch
         # scores the whole window causally in ONE pass, so the credit is
-        # K1 decode-steps' worth, which is exactly what it replaces)
-        x, ck = ishape("X"), ishape("CacheK")
-        if x is None or ck is None:
-            return 0.0
-        b, k1, m = x[-3], x[-2], x[-1]
-        s = ck[-3]
-        h = int(attrs.get("n_head", 1))
-        d = m // max(h, 1)
-        return 2.0 * b * m * m * 4.0 * k1 + 2.0 * b * h * k1 * s * d * 2.0
-    if op_type == "kv_attention_verify_paged":
-        # same as kv_attention_verify with the cache length coming from
-        # the page-table view: max_pages * page_size rows per slot
+        # K1 decode-steps' worth, which is exactly what it replaces).
+        # The cache length comes from the page-table view: max_pages *
+        # page_size rows per slot
         x, tbl, pk = ishape("X"), ishape("PageTable"), ishape("PageK")
         if x is None or tbl is None or pk is None:
             return 0.0

@@ -42,11 +42,11 @@ def main():
     done_file = os.path.join(share, "done.txt")
     if rank == 0:
         from paddle_tpu.models import transformer as T
-        sgm = serving.SlotGenerativeModel(
+        sgm = serving.make_slot_model(
             "lm", T.build_decoder_lm_programs(
                 prompt_len=8, max_new=8, vocab=32, d_model=16,
                 d_inner=32, n_head=2, n_layer=2,
-                modes=("prefill_slot", "decode_slot"), n_slots=2))
+                modes=T.slot_modes(), n_slots=2))
         sgm.warmup()
         server = serving.ModelServer()
         server.add_model(sgm)

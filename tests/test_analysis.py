@@ -665,11 +665,13 @@ def _decoder_family(modes):
 
 def test_contracts_full_family_green():
     """The contract the CI gate (proglint --contracts) enforces: the
-    whole decoder_lm family — wave, slot, paged and verify views over
+    whole decoder_lm family — wave, paged and verify views over
     every prompt bucket — passes every cross-view rule."""
+    from paddle_tpu.analysis.contracts import DECODER_LM_MODES
     from paddle_tpu.models import transformer
     fam = transformer.contracts_lint_family()
-    assert len(fam) == 15
+    # six modes; the two prefills fan out to a view per bucket + alias
+    assert len(DECODER_LM_MODES) == 6 and len(fam) == 10
     diags = analysis.verify_family(fam)
     assert diags == [], [d.format() for d in diags]
 
@@ -728,10 +730,11 @@ def test_validate_geometry_record():
     assert g.max_pages == 4 and g.n_pages == 4 * g.max_pages
     assert g.store_dtype == "float32"          # FLAGS default codec
     with pytest.raises(ValueError, match="needs n_slots"):
-        validate_geometry("decode_slot", 8, 8)
+        validate_geometry("decode_paged", 8, 8)
     with pytest.raises(ValueError, match="must divide"):
         validate_geometry("prefill_paged", 8, 8, n_slots=4, page_size=3)
     with pytest.raises(ValueError, match="verify window"):
-        validate_geometry("decode_verify", 8, 8, n_slots=4, spec_k=16)
+        validate_geometry("decode_verify_paged", 8, 8, n_slots=4,
+                          spec_k=16)
     with pytest.raises(ValueError, match="not in"):
         validate_geometry("nope", 8, 8)

@@ -112,8 +112,16 @@ def test_sigkill_blackbox_names_kill_point(tmp_path):
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         cwd=REPO_ROOT, env=env)
     try:
-        line = p.stdout.readline()
-        assert line.startswith("READY"), line
+        # stderr rides on the same pipe: XLA's own log lines (a CPU
+        # compile cache made on another machine logs one per load) may
+        # come before READY
+        seen = []
+        for line in p.stdout:
+            if line.startswith("READY"):
+                break
+            seen.append(line)
+        else:
+            raise AssertionError("no READY line:\n" + "".join(seen)[-2000:])
         endpoint = line.split()[1]
         host, port = endpoint.rsplit(":", 1)
         import socket

@@ -210,12 +210,12 @@ def _slot_model():
     sgm = _SLOT_CACHE.get("sgm")
     if sgm is None:
         from paddle_tpu.models import transformer as T
-        sgm = serving.SlotGenerativeModel(
+        sgm = serving.make_slot_model(
             "lm_chaos_slot",
             T.build_decoder_lm_programs(
                 prompt_len=8, max_new=512, vocab=32, d_model=16,
                 d_inner=32, n_head=2, n_layer=2,
-                modes=("prefill_slot", "decode_slot"), n_slots=2))
+                modes=T.slot_modes(), n_slots=2))
         sgm.warmup()
         _SLOT_CACHE["sgm"] = sgm
     return sgm

@@ -121,6 +121,7 @@ def test_smoke_rehearsal_passes_and_says_cpu(tmp_path):
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert last == {"ok": True, "device": {"platform": "cpu",
                                            "kind": "cpu", "count": 1}}
-    for variant in ("contiguous", "paged_fp32", "paged_int8"):
+    for variant in ("paged_fp32", "paged_int8"):
         assert f'"variant": "{variant}"' in r.stdout
-    assert r.stdout.count('"compiles_after_warm_up": 0') == 3
+    assert '"variant": "contiguous"' not in r.stdout
+    assert r.stdout.count('"compiles_after_warm_up": 0') == 2

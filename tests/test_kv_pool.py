@@ -1,7 +1,7 @@
 """Paged KV-cache subsystem tests (ISSUE 17, docs/serving.md "Paged KV
 cache"): the PagePool allocator + prefix radix tree, the page-pool
 metric gauges asserted against a known admission schedule, the Pallas
-page-gather kernels in interpret mode, and the PagedSlotGenerativeModel
+page-gather kernels in interpret mode, and the SlotGenerativeModel
 engine — greedy bit-parity with the sequential full-forward oracle,
 prefix sharing witnessed by refcounts with bit-identical COW divergence,
 zero steady-state recompiles, the int8 KV codec's sampling-replay
@@ -26,7 +26,7 @@ _CACHE = {}
 
 
 def _paged_lm(codec="none"):
-    """One warmed PagedSlotGenerativeModel per codec, shared by the
+    """One warmed SlotGenerativeModel per codec, shared by the
     engine tests (same config/seed discipline as test_serving's
     ``_shared_slot_lm`` — warmup costs several jit compiles on CPU)."""
     key = "paged_" + codec
@@ -47,8 +47,7 @@ def _paged_lm(codec="none"):
 def _tiny_paged():
     """A page-starved engine (4 pages = ONE bucket-8 admission) shared
     by the exhaustion-message and server put-back tests: pages run out
-    while slots stay free, the layout-specific shed the contiguous
-    engine can never hit."""
+    while slots stay free — the shed only a page economy can hit."""
     m = _CACHE.get("tiny")
     if m is None:
         m = seng.make_slot_model(
@@ -415,12 +414,17 @@ def test_paged_ops_identical_through_every_gather_tier(op, codec,
 
 @pytest.mark.parametrize("op", ["decode", "verify"])
 def test_paged_fp32_bit_identical_to_contiguous_op(op):
-    """fp32 paged decode / verify == the contiguous op over the cache
-    the page table describes, BIT for bit on every active row: the
-    paged ops contract K and V as the gather leaves them ([B, S, H*Dk],
-    a block-diagonal query, ops/kv_attention.py:_attend_gathered),
-    which adds only selected zeros to the contiguous ops' products and
-    sums. The rows each writes are the same too."""
+    """fp32 paged decode == the wave op ``kv_attention_decode`` over the
+    cache the page table describes, BIT for bit on every active row:
+    the paged op reshapes the gathered rows to [B, S, H, Dk] and runs
+    the same per-head contraction over them, so the page indirection
+    adds nothing to the products and sums, and the rows it writes are
+    the same too. The verify window has no wave twin: it is held to a
+    plain float32 statement of the window attention written here
+    (rtol 1e-6), the rows it writes compared exactly; its token-level
+    identity with sequential paged decode is
+    tests/test_spec_decode.py::test_spec_greedy_bit_identical_zero_
+    recompiles."""
     import types
     import jax.numpy as jnp
     from paddle_tpu.core.registry import get_op
@@ -439,17 +443,57 @@ def test_paged_fp32_bit_identical_to_contiguous_op(op):
             if not k.startswith("Page")}
     cins["CacheK"] = [cache(ins["PageK"][0])]
     cins["CacheV"] = [cache(ins["PageV"][0])]
-    name = "kv_attention_decode" if op == "decode" else \
-        "kv_attention_verify"
-    want = get_op(name).emit(ctx, cins, {"n_head": h})
     active = np.asarray(ins["Active"][0]).reshape(-1) > 0
     assert active.any() and not active.all()
-    np.testing.assert_array_equal(_bits(got["Out"][0])[active],
-                                  _bits(want["Out"][0])[active])
+    if op == "decode":
+        want = get_op("kv_attention_decode").emit(ctx, cins, {"n_head": h})
+        np.testing.assert_array_equal(_bits(got["Out"][0])[active],
+                                      _bits(want["Out"][0])[active])
+    else:
+        want = _window_attention_reference(cins, h)
+        np.testing.assert_allclose(np.asarray(got["Out"][0])[want["seen"]],
+                                   np.asarray(want["Out"][0])[want["seen"]],
+                                   rtol=1e-6, atol=1e-6)
     for pool, c in (("PageKOut", "CacheKOut"), ("PageVOut", "CacheVOut")):
         np.testing.assert_array_equal(
             _bits(cache(got[pool][0]))[active],
             _bits(want[c][0])[active])
+
+
+def _window_attention_reference(ins, h):
+    """The verify window in plain float32 ``jax.numpy`` over [B, S, H, Dk]
+    caches: window position i of row b writes its k/v at cache row
+    pos + i (where active, i < win_len and the row exists) and attends
+    over {j < seq_len} ∪ {gen_start <= j <= pos + i}. ``seen`` marks the
+    [B, K1] outputs the host reads (active rows, i < win_len)."""
+    import jax
+    import jax.numpy as jnp
+    x = ins["X"][0]
+    b, k1, m = x.shape
+    d = m // h
+    q, k, v = ((x @ ins[w][0]).reshape(b, k1, h, d)
+               for w in ("Wq", "Wk", "Wv"))
+    pos, lens, gen0, act, wlen = (
+        np.asarray(ins[n][0]).reshape(-1)
+        for n in ("Pos", "SeqLen", "GenStart", "Active", "WinLen"))
+    ck, cv = ins["CacheK"][0], ins["CacheV"][0]
+    s_len = ck.shape[1]
+    seen = np.zeros((b, k1), bool)
+    for r in range(b):
+        for i in range(k1):
+            if act[r] and i < wlen[r] and pos[r] + i < s_len:
+                ck = ck.at[r, pos[r] + i].set(k[r, i])
+                cv = cv.at[r, pos[r] + i].set(v[r, i])
+                seen[r, i] = True
+    j = np.arange(s_len)
+    valid = (j[None, None, :] < lens[:, None, None]) | (
+        (j[None, None, :] >= gen0[:, None, None])
+        & (j[None, None, :] <= (pos[:, None] + np.arange(k1))[:, :, None]))
+    s = jnp.einsum("bihd,bjhd->bhij", q, ck) * d ** -0.5
+    p = jax.nn.softmax(jnp.where(valid[:, None], s, -jnp.inf), axis=-1)
+    c = jnp.einsum("bhij,bjhd->bihd", p, cv).reshape(b, k1, m)
+    return {"Out": [c @ ins["Wo"][0]], "CacheKOut": [ck],
+            "CacheVOut": [cv], "seen": seen}
 
 
 @pytest.mark.parametrize("case, want", [
@@ -512,7 +556,9 @@ def test_kv_gather_counter_counts_a_real_program_and_is_cataloged():
 
 def test_make_slot_model_factory_and_geometry():
     m = _paged_lm()
-    assert isinstance(m, seng.PagedSlotGenerativeModel)
+    assert type(m) is seng.SlotGenerativeModel
+    assert not seng.SlotGenerativeModel.__subclasses__()
+    assert not hasattr(seng, "PagedSlotGenerativeModel")
     assert (m.n_pages, m.page_size, m.max_pages) == (16, 4, 4)
     assert m.cache_len == 16 and m.n_slots == 4
     assert m.free_pages() == 16
@@ -542,16 +588,53 @@ def test_paged_greedy_matches_sequential_oracle_zero_recompiles():
 
 
 def test_paged_slot_layout_helper():
+    """One layout: the helper takes no flag, and the contiguous layout
+    (removed at PR 29) is refused by name, as is its flag."""
     from paddle_tpu import flags
-    assert T.slot_modes() == ("prefill_slot", "decode_slot")
-    assert T.slot_modes("paged") == ("prefill_paged", "decode_paged")
-    flags.set("kv_cache_layout", "paged")
-    try:
-        assert T.slot_modes() == ("prefill_paged", "decode_paged")
-    finally:
-        flags.reset("kv_cache_layout")
+    assert T.slot_modes() == T.slot_modes("paged") == (
+        "prefill_paged", "decode_paged")
+    with pytest.raises(ValueError, match="removed at PR 29"):
+        T.slot_modes("contiguous")
     with pytest.raises(ValueError):
         T.slot_modes("ragged")
+    with pytest.raises(KeyError):
+        flags.get("kv_cache_layout")
+
+
+def test_make_slot_model_refuses_a_family_without_paged_views():
+    """The wave family (prefill/decode/full) is not a slot family: the
+    constructor says which views it wants."""
+    with pytest.raises(ValueError, match="prefill_paged.*decode_paged"):
+        seng.make_slot_model(
+            "lm_wave_only", T.build_decoder_lm_programs(**_LM_CFG),
+            init=False)
+    progs = T.build_decoder_lm_programs(
+        **_LM_CFG, modes=("decode_paged",), n_slots=2)
+    with pytest.raises(ValueError, match="prefill_paged"):
+        seng.make_slot_model("lm_no_prefill", progs, init=False)
+
+
+def test_replica_decoder_lm_spec_builds_the_paged_engine():
+    """A replica's ``decoder_lm`` spec with ``slots: true`` builds the
+    paged slot engine at validate_geometry's defaults (page_size 4,
+    every slot at full length) and serves the wave oracle's tokens."""
+    from paddle_tpu.serving import replica
+    eng = replica.build_engine(
+        {"kind": "decoder_lm", "name": "lm_replica_paged", "slots": True,
+         "params": dict(_LM_CFG, n_slots=2)})
+    assert type(eng) is seng.SlotGenerativeModel
+    assert (eng.page_size, eng.n_pages, eng.n_slots) == (4, 8, 2)
+    server = serving.ModelServer()
+    try:
+        server.add_model(eng)
+        prompts = [np.asarray([5, 9, 2]), np.asarray([7, 1, 30, 4, 12])]
+        got = [server.generate("lm_replica_paged", [p], max_new=6,
+                               timeout=120)[0] for p in prompts]
+    finally:
+        server.stop()
+    want = _oracle_lm().full_forward_generate(prompts, max_new=6)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_engine_prefix_sharing_cow_bit_identical():
@@ -763,20 +846,15 @@ def test_verify_paged_flops_read_page_size_not_n_pages():
     from paddle_tpu.utils import flops
     progs = T.build_decoder_lm_programs(
         **_LM_CFG, prompt_buckets=(8,), n_slots=4, page_size=4,
-        n_pages=40, spec_k=2,
-        modes=("decode_verify_paged", "decode_verify"))
-    per_op = {}
-    for mode in ("decode_verify_paged", "decode_verify"):
-        main = progs[mode][0]
-        block = main.desc.global_block
-        ops = [op for op in block.ops
-               if op.type.startswith("kv_attention_verify")]
-        assert len(ops) == _LM_CFG["n_layer"]
-        per_op[mode] = flops._op_flops(main.desc, block, ops[0], 4)
+        n_pages=40, spec_k=2, modes=("decode_verify_paged",))
+    main = progs["decode_verify_paged"][0]
+    block = main.desc.global_block
+    ops = [op for op in block.ops
+           if op.type == "kv_attention_verify_paged"]
+    assert len(ops) == _LM_CFG["n_layer"]
     b, k1, m, s = 4, 3, _LM_CFG["d_model"], 16      # cache_len 8 + 8
     want = 2.0 * b * m * m * 4 * k1 + 2.0 * b * k1 * s * m * 2
-    assert per_op["decode_verify_paged"] == want
-    assert per_op["decode_verify_paged"] == per_op["decode_verify"]
+    assert flops._op_flops(main.desc, block, ops[0], 4) == want
 
 
 def test_server_maps_exhaustion_to_typed_wire_kind():

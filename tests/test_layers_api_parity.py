@@ -9,6 +9,7 @@ Plus functional smoke tests for the round-3 surface additions (wrappers
 execute, not just resolve)."""
 
 import glob
+import os
 import re
 
 import numpy as np
@@ -18,6 +19,13 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 
 REFERENCE_LAYERS_GLOB = "/root/reference/python/paddle/fluid/layers/*.py"
+
+# the surface scrapes read the reference's sources; a machine without
+# them says so instead of failing (a standing red hides a new one)
+needs_reference = pytest.mark.skipif(
+    not os.path.isdir("/root/reference/python/paddle"),
+    reason="the reference sources (/root/reference) are not on this "
+           "machine")
 
 # internal helpers the reference's __all__ exposes but which are codegen
 # machinery, not user API (layer_function_generator.py)
@@ -34,6 +42,7 @@ def _reference_names():
     return names - NOT_USER_API
 
 
+@needs_reference
 def test_every_reference_layer_name_resolves():
     ref = _reference_names()
     assert len(ref) > 200, "reference scrape looks broken"
@@ -395,6 +404,7 @@ FLUID_MODULE_PAIRS = {
 }
 
 
+@needs_reference
 @pytest.mark.parametrize("ref_mod,our_mod", sorted(FLUID_MODULE_PAIRS.items()))
 def test_fluid_module_surface_resolves(ref_mod, our_mod):
     import importlib
@@ -496,6 +506,7 @@ def test_save_load_params_excludes_lr_state(tmp_path):
     assert sorted(loaded) == sorted(saved)
 
 
+@needs_reference
 def test_reader_decorator_surface_resolves():
     src = open("/root/reference/python/paddle/reader/decorator.py",
                encoding="utf-8", errors="ignore").read()
@@ -596,7 +607,8 @@ def test_py_reader_provider_error_propagates():
     reader.reset()
 
 
-def test_compat_module_surface_and_behavior():
+@needs_reference
+def test_compat_module_surface_resolves():
     src = open("/root/reference/python/paddle/compat.py",
                encoding="utf-8", errors="ignore").read()
     names = set()
@@ -605,6 +617,10 @@ def test_compat_module_surface_and_behavior():
     from paddle_tpu import compat
     missing = sorted(n for n in names if not hasattr(compat, n))
     assert not missing, missing
+
+
+def test_compat_module_behavior():
+    from paddle_tpu import compat
     assert compat.to_text(b"abc") == "abc"
     assert compat.to_bytes("abc") == b"abc"
     assert compat.to_text([b"a", b"b"]) == ["a", "b"]

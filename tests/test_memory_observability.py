@@ -171,7 +171,8 @@ def test_census_families_and_watermark():
 def test_classify_known_names():
     obs_memory.note_params(["emb_table"])
     obs_memory.register_buffer_family("emb_table_rows", "embed_cache")
-    assert obs_memory.classify("lm_slot_k_0") == "kv_cache"
+    assert obs_memory.classify("lm_page_k_0") == "kv_cache"
+    assert obs_memory.classify("lm_page_vs_1") == "kv_cache"
     assert obs_memory.classify("lm_cache_v_1") == "kv_cache"
     assert obs_memory.classify("fc_0.w_0_moment1_0") == "optimizer_moment"
     assert obs_memory.classify("fc_0.w_0_velocity_0") == "optimizer_moment"
@@ -185,21 +186,22 @@ def test_classify_known_names():
 # -- serving KV pool ------------------------------------------------------
 
 def test_kv_pool_gauge_exact_bytes():
-    """The slot pool is [n_slots, cache_len, n_head, d_head] fp32 per
-    layer per k/v — the gauge must match that product EXACTLY."""
+    """The slot engine's page pool is [n_pages, page_size, d_model] fp32
+    per layer per k/v, by default every slot at full length — the
+    gauge must match that product EXACTLY."""
     from paddle_tpu import serving
     from paddle_tpu.models import transformer as T
     n_slots, prompt_len, max_new = 2, 4, 4
     d_model, n_head, n_layer = 16, 2, 2
-    sgm = serving.SlotGenerativeModel(
+    sgm = serving.make_slot_model(
         "lm_membytes",
         T.build_decoder_lm_programs(
             prompt_len=prompt_len, max_new=max_new, vocab=32,
             d_model=d_model, d_inner=32, n_head=n_head, n_layer=n_layer,
-            modes=("prefill_slot", "decode_slot"), n_slots=n_slots))
+            modes=T.slot_modes(), n_slots=n_slots))
     cache_len = prompt_len + max_new
-    d_head = d_model // n_head
-    expect = n_slots * cache_len * n_head * d_head * 4 * n_layer * 2
+    assert sgm.n_pages * sgm.page_size == n_slots * cache_len
+    expect = n_slots * cache_len * d_model * 4 * n_layer * 2
     got = obs_memory.kv_pool_bytes(sgm.scope, "lm_membytes")
     assert got == expect
     assert obs_memory.HBM_KV_POOL.labels(

@@ -69,11 +69,11 @@ def _shared_slot_lm():
     oracle."""
     sgm = _LM_CACHE.get("sgm")
     if sgm is None:
-        sgm = serving.SlotGenerativeModel(
+        sgm = serving.make_slot_model(
             "lm_slot_shared",
             T.build_decoder_lm_programs(
                 **_LM_CFG, prompt_buckets=(4, 8),
-                modes=("prefill_slot", "decode_slot"), n_slots=4))
+                modes=T.slot_modes(), n_slots=4))
         sgm.warmup()
         _LM_CACHE["sgm"] = sgm
     return sgm
@@ -596,11 +596,10 @@ def test_on_device_sampling_parity_and_restart_reproducibility():
     seeds = [101, 102, 103]
     s1 = sgm.generate(prompts, max_new=8, temperature=0.8, top_k=5,
                       seeds=seeds)
-    sgm2 = serving.SlotGenerativeModel(
+    sgm2 = serving.make_slot_model(
         "lm_slot_restart",
         T.build_decoder_lm_programs(
-            **_LM_CFG, modes=("prefill_slot", "decode_slot"),
-            n_slots=2))
+            **_LM_CFG, modes=T.slot_modes(), n_slots=2))
     sgm2.warmup()
     s2 = sgm2.generate(prompts, max_new=8, temperature=0.8, top_k=5,
                        seeds=seeds)
@@ -736,9 +735,8 @@ def test_load_mixed_shapes_and_decode_speedup(tmp_path):
     assert smetrics.latency_percentile("clf_load", 0.99) > 0
     assert total / elapsed > 5          # sanity floor, not a perf claim
 
-    # decode speedup vs the full-forward baseline (the serve_bench
-    # headline at T=64 is recorded in SERVE_r01.json; here a smaller
-    # config with a conservative floor keeps CI deterministic)
+    # decode speedup vs the full-forward baseline (a small config
+    # with a conservative floor keeps CI deterministic)
     progs = T.build_decoder_lm_programs(
         prompt_len=32, max_new=32, vocab=128, d_model=64, d_inner=256,
         n_head=4, n_layer=2)

@@ -1,7 +1,7 @@
 """Speculative decoding tests (ISSUE 19, docs/serving.md "Speculative
 decoding"): the draft-verify slot engine must be LOSSLESS — greedy
 output bit-identical to the non-speculative slot scheduler and the
-sequential full-forward oracle on BOTH KV layouts under
+sequential full-forward oracle under
 ``forbid_compiles``, seeded sampling replays deterministically, EOS
 truncates mid-window commits — plus the acceptance-economy metrics
 (proposed/accepted counters, the tokens-per-step histogram) asserted
@@ -24,23 +24,20 @@ _LM_CFG = dict(prompt_len=8, max_new=8, vocab=32, d_model=16,
 _CACHE = {}
 
 
-def _spec_lm(layout="contiguous", spec_k=3):
-    """One warmed draft-verify engine per (layout, spec_k), shared by
-    the module (warmup costs several jit compiles on CPU). Tests that
-    swap ``m.drafter`` must restore it — the fixture resets state, not
-    the proposer."""
-    key = f"spec_{layout}_{spec_k}"
+def _spec_lm(spec_k=3):
+    """One warmed draft-verify engine per spec_k, shared by the module
+    (warmup costs several jit compiles on CPU). Tests that swap
+    ``m.drafter`` must restore it — the fixture resets state, not the
+    proposer."""
+    key = f"spec_paged_{spec_k}"
     m = _CACHE.get(key)
     if m is None:
-        kw = dict(page_size=4) if layout == "paged" else {}
         m = seng.make_slot_model(
             "lm_" + key,
             T.build_decoder_lm_programs(
                 **_LM_CFG, prompt_buckets=(4, 8),
-                modes=T.slot_modes(
-                    None if layout == "contiguous" else layout,
-                    spec=True),
-                n_slots=4, spec_k=spec_k, **kw))
+                modes=T.slot_modes(spec=True),
+                n_slots=4, spec_k=spec_k, page_size=4))
         m.warmup()
         _CACHE[key] = m
     m.reset()
@@ -48,18 +45,15 @@ def _spec_lm(layout="contiguous", spec_k=3):
     return m
 
 
-def _base_lm(layout="contiguous"):
-    key = "base_" + layout
+def _base_lm():
+    key = "base_paged"
     m = _CACHE.get(key)
     if m is None:
-        kw = dict(page_size=4) if layout == "paged" else {}
         m = seng.make_slot_model(
             "lm_" + key,
             T.build_decoder_lm_programs(
                 **_LM_CFG, prompt_buckets=(4, 8),
-                modes=T.slot_modes(
-                    None if layout == "contiguous" else layout),
-                n_slots=4, **kw))
+                modes=T.slot_modes(), n_slots=4, page_size=4))
         m.warmup()
         _CACHE[key] = m
     m.reset()
@@ -102,18 +96,17 @@ class _CannedDrafter:
 
 
 # ---------------------------------------------------------------------------
-# losslessness: greedy bit-parity on both layouts, zero recompiles
+# losslessness: greedy bit-parity, zero recompiles
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
-def test_spec_greedy_bit_identical_zero_recompiles(layout):
+def test_spec_greedy_bit_identical_zero_recompiles():
     """Acceptance criterion: greedy speculative output == the
     non-speculative slot scheduler == the sequential full-forward
     oracle, token for token, with the WHOLE speculative generation
     under forbid_compiles (one verify executable serves every
     draft-length mix via the win_len feed)."""
-    m = _spec_lm(layout)
-    mb = _base_lm(layout)
+    m = _spec_lm()
+    mb = _base_lm()
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, 32, (int(n),)) for n in (3, 4, 7, 8, 5, 2)]
     gm = _oracle_lm()                    # chunk: oracle buckets top at 4
@@ -170,11 +163,11 @@ def test_spec_sampled_matches_nonspec_and_replays():
 def test_spec_sampled_survives_restart():
     """Cross-engine determinism: a SECOND engine built from scratch
     (the restart scenario — fresh program build, init, warmup; here
-    even a different KV layout) replays the identical seeded stream,
+    even a different verify window) replays the identical seeded stream,
     because the Gumbel noise is a pure function of (seed, step,
     vocab index) — no mutable RNG stream survives in either process."""
     m = _spec_lm()
-    m2 = _spec_lm("paged")
+    m2 = _spec_lm(spec_k=2)
     prompts = [[9, 4, 2, 17], [21, 5]]
     kw = dict(max_new=6, temperature=1.1, top_k=4, seeds=[7, 8])
     a = m.generate(prompts, **kw)
@@ -291,15 +284,17 @@ def test_spec_metrics_canned_schedule_on_scrape_endpoint():
 
 def test_verify_view_geometry_validation():
     with pytest.raises(ValueError):      # spec_k must be >= 1
-        T.decoder_lm("decode_verify", **_LM_CFG, n_slots=2, spec_k=-1)
+        T.decoder_lm("decode_verify_paged", **_LM_CFG, n_slots=2,
+                     spec_k=-1)
     with pytest.raises(ValueError):      # window must fit the budget
-        T.decoder_lm("decode_verify", **_LM_CFG, n_slots=2, spec_k=9)
+        T.decoder_lm("decode_verify_paged", **_LM_CFG, n_slots=2,
+                     spec_k=9)
     with pytest.raises(ValueError):      # verify views need a pool
-        T.decoder_lm("decode_verify", **_LM_CFG)
+        T.decoder_lm("decode_verify_paged", **_LM_CFG)
 
 
 def test_slot_modes_spec_helper():
-    assert T.slot_modes(spec=True) == (
-        "prefill_slot", "decode_slot", "decode_verify")
-    assert T.slot_modes("paged", spec=True) == (
+    # the positional "paged" is what the benchmark's runner passes
+    assert T.slot_modes(spec=True) == T.slot_modes("paged", spec=True) == (
         "prefill_paged", "decode_paged", "decode_verify_paged")
+    assert T.slot_modes() == ("prefill_paged", "decode_paged")

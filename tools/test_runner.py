@@ -34,8 +34,8 @@ import sys
 # builds; the full zoo is covered by tests/test_analysis.py)
 LINT_MODELS = ("mnist", "smallnet")
 
-# the serving programs (prefill + KV-cache decode, wave AND slot-pool
-# views) are linted in is-test mode via `proglint --all`, which
+# the serving programs (prefill + KV-cache decode, wave AND paged
+# slot-pool views) are linted in is-test mode via `proglint --all`, which
 # auto-discovers every serve_lint_* entry of models/transformer — a new
 # serving view only needs a serve_lint_ function to join the gate, not
 # an edit here (ISSUE 8/9; docs/serving.md)
@@ -335,7 +335,7 @@ print("spmd smoke ok: dp=8 one-step parity, 0 steady-state recompiles")
 """
 
 
-# the spec-decode smoke: one contiguous slot engine WITH a verify view
+# the spec-decode smoke: one slot engine WITH a verify view
 # vs one without, same weights discipline (per-engine init is seeded by
 # program build), greedy over a mixed prompt set — the draft-verify
 # stream must be token-for-token identical, and the whole speculative
