@@ -7,7 +7,13 @@ Inference-only ops (no VJP — serving programs are is_test), all spelled
 with the same numerics as ``ops/attention_block.py`` (fp32 MXU
 accumulation via preferred_element_type, softmax in fp32, probabilities
 applied in the storage dtype) so a prefill+decode transcript matches the
-full-forward graph token for token:
+full-forward graph token for token. The decode-side ops (wave decode,
+paged decode, paged verify) share ONE contraction over the cache,
+:func:`_decode_contract`: it consumes K and V as ``[B, S, H*Dk]`` — the
+model width on the minor dimension, as the paged gather leaves them and
+never re-laid — through a block-diagonal query, and with fp32 compute
+its two dots run at precision HIGHEST (an fp32 cache is multiplied in
+fp32, not rounded to bf16 by a default MXU pass):
 
 - ``kv_attention_prefill`` — causal self-attention over the whole
   (padded) prompt in one shot, PLUS the cache side effect: the K/V
@@ -101,6 +107,47 @@ def _scores_to_probs(s, mask, dt):
     return p.astype(dt)
 
 
+def _decode_contract(q, k, v, valid, dt):
+    """The decode-side attention contraction, over a cache that keeps
+    the model width on its minor dimension: q [B, K1, H, Dk] (K1 query
+    rows per batch row: 1 for a decode step, the window for a verify),
+    k / v [B, S, M] with M = H * Dk exactly as ``_paged_gather`` leaves
+    them, valid [B, K1, S] bool -> context [B, K1, H, Dk] in ``dt``.
+
+    The cache is never reshaped to [.., H, Dk]: a 64-wide head is half
+    a lane tile, and a per-head contraction of one query row made the
+    TPU relay each gathered cache twice with the position on the lanes
+    (29.4 of a 55.8 ms decode step; PERF.md, PR 30). Instead the QUERY
+    is made block-diagonal: row (k1, h) of ``qbd`` [B, K1*H, M] holds
+    head h's Dk values of q[:, k1] in head h's own lanes and selected
+    zeros elsewhere, so ``qbd . k`` over all M lanes is head h's score
+    (the same products plus exact zeros), and row (k1, h) of ``p . v``
+    over S is head h's context in head h's own lanes. H times the
+    useful MXU work, under the HBM time of reading the cache while
+    K1 * H stays below ~80 (fp32) / ~240 (bf16).
+
+    fp32 accumulation always; with fp32 compute both dots run at
+    precision HIGHEST, which is what an fp32 cache states: a DEFAULT
+    MXU pass would round K and V to bf16."""
+    b, k1, h, d = q.shape
+    m = h * d
+    prec = jax.lax.Precision.HIGHEST if dt == jnp.float32 else None
+    own = jnp.eye(h, dtype=bool)[None, None, :, :, None]  # row h, lanes h'
+    qbd = jnp.where(own, q[:, :, None], 0).reshape(b, k1 * h, m)
+    s = jax.lax.dot_general(qbd, k, (((2,), (2,)), ((0,), (0,))),
+                            precision=prec,
+                            preferred_element_type=jnp.float32)
+    s = s.astype(jnp.float32).reshape(b, k1, h, -1) * (float(d) ** -0.5)
+    p = _scores_to_probs(s, valid[:, :, None, :], dt)    # [B,K1,H,S]
+    c = jax.lax.dot_general(p.reshape(b, k1 * h, -1), v,
+                            (((2,), (1,)), ((0,), (0,))),
+                            precision=prec,
+                            preferred_element_type=jnp.float32)
+    # row (k1, h) keeps head h's own lanes: the sum adds exact zeros
+    c = jnp.where(own, c.reshape(b, k1, h, h, d), 0).sum(axis=3)
+    return c.astype(dt)
+
+
 def _causal_prefill(x, wq, wk, wv, wo, h):
     """Shared prefill math: causal self-attention over X [B,T,M] plus
     the K/V projections ([B,T,H,D]) the caller caches."""
@@ -165,7 +212,6 @@ def _kv_attention_decode(ctx, ins, attrs):
     h = int(attrs["n_head"])
     b, _, m = x.shape
     s_len = cache_k.shape[1]
-    d = m // h
     dt = x.dtype
 
     pos = jnp.asarray(first(ins, "Pos")).reshape(-1).astype(jnp.int32)
@@ -185,17 +231,13 @@ def _kv_attention_decode(ctx, ins, attrs):
     cache_k = jnp.where(write[:, :, None, None], k_t, cache_k)
     cache_v = jnp.where(write[:, :, None, None], v_t, cache_v)
 
-    s = jax.lax.dot_general(q, cache_k, (((3,), (3,)), ((0, 2), (0, 2))),
-                            preferred_element_type=jnp.float32)
-    s = s.astype(jnp.float32) * (float(d) ** -0.5)   # [B,H,1,S]
     valid = (j[None, :] < lens[:, None]) | \
             ((j[None, :] >= gen0[:, None]) &
              (j[None, :] <= pos[:, None]))           # [B,S]
-    p = _scores_to_probs(s, valid[:, None, None, :], dt)
-    c = jax.lax.dot_general(p, cache_v, (((3,), (1,)), ((0, 1), (0, 2))),
-                            preferred_element_type=jnp.float32).astype(dt)
-    out = jax.lax.dot_general(c, wo.reshape(h, d, m),
-                              (((1, 3), (0, 1)), ((), ())),
+    c = _decode_contract(q, cache_k.reshape(b, s_len, m),
+                         cache_v.reshape(b, s_len, m), valid[:, None], dt)
+    out = jax.lax.dot_general(c, wo.reshape(h, -1, m),
+                              (((2, 3), (0, 1)), ((), ())),
                               preferred_element_type=jnp.float32).astype(dt)
     return {"Out": [out], "CacheKOut": [cache_k], "CacheVOut": [cache_v]}
 
@@ -366,14 +408,16 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     kv_attention_decode; the cache row for logical position j lives at
     flat row table[b, j//ps]*ps + j%ps. attrs: n_head, codec. The mask
     {j < seq_len} ∪ {gen_start <= j <= pos} zeroes sentinel/garbage
-    rows EXACTLY, so fp32 paged decode is bit-identical to
-    kv_attention_decode over the same rows."""
+    rows EXACTLY. The gathered [B, S, H*Dk] caches go to
+    ``_decode_contract`` as they are — no reshape to [.., H, Dk], no
+    relayout — and kv_attention_decode hands the same function its
+    caches viewed the same way, so fp32 paged decode is bit-identical
+    to kv_attention_decode over the same rows by construction."""
     x = first(ins, "X")
     wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
     h = int(attrs["n_head"])
     codec = str(attrs.get("codec", "none"))
     b, _, m = x.shape
-    d = m // h
     dt = x.dtype
     flat_k, flat_v, fks, fvs, n_pages, ps, rtot = \
         _paged_pools(ins, codec)
@@ -402,23 +446,16 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, m))
 
     # gather every slot's logical cache through its table row
-    kk = _paged_gather(flat_k, fks, table, ps, dt,
-                       ctx.mesh).reshape(b, s_len, h, d)
-    vv = _paged_gather(flat_v, fvs, table, ps, dt,
-                       ctx.mesh).reshape(b, s_len, h, d)
+    kk = _paged_gather(flat_k, fks, table, ps, dt, ctx.mesh)  # [B,S,M]
+    vv = _paged_gather(flat_v, fvs, table, ps, dt, ctx.mesh)
 
-    s = jax.lax.dot_general(q, kk, (((3,), (3,)), ((0, 2), (0, 2))),
-                            preferred_element_type=jnp.float32)
-    s = s.astype(jnp.float32) * (float(d) ** -0.5)   # [B,H,1,S]
     j = jnp.arange(s_len, dtype=jnp.int32)
     valid = (j[None, :] < lens[:, None]) | \
             ((j[None, :] >= gen0[:, None]) &
              (j[None, :] <= pos[:, None]))           # [B,S]
-    p = _scores_to_probs(s, valid[:, None, None, :], dt)
-    c = jax.lax.dot_general(p, vv, (((3,), (1,)), ((0, 1), (0, 2))),
-                            preferred_element_type=jnp.float32).astype(dt)
-    out = jax.lax.dot_general(c, wo.reshape(h, d, m),
-                              (((1, 3), (0, 1)), ((), ())),
+    c = _decode_contract(q, kk, vv, valid[:, None], dt)
+    out = jax.lax.dot_general(c, wo.reshape(h, -1, m),
+                              (((2, 3), (0, 1)), ((), ())),
                               preferred_element_type=jnp.float32).astype(dt)
     return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 
@@ -451,8 +488,7 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
     h = int(attrs["n_head"])
     codec = str(attrs.get("codec", "none"))
-    b, k1, m = x.shape
-    d = m // h
+    _, k1, m = x.shape
     dt = x.dtype
     flat_k, flat_v, fks, fvs, n_pages, ps, rtot = \
         _paged_pools(ins, codec)
@@ -483,23 +519,16 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(-1, m))
     flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(-1, m))
 
-    kk = _paged_gather(flat_k, fks, table, ps, dt,
-                       ctx.mesh).reshape(b, s_len, h, d)
-    vv = _paged_gather(flat_v, fvs, table, ps, dt,
-                       ctx.mesh).reshape(b, s_len, h, d)
+    kk = _paged_gather(flat_k, fks, table, ps, dt, ctx.mesh)  # [B,S,M]
+    vv = _paged_gather(flat_v, fvs, table, ps, dt, ctx.mesh)
 
-    s = jax.lax.dot_general(q, kk, (((3,), (3,)), ((0, 2), (0, 2))),
-                            preferred_element_type=jnp.float32)
-    s = s.astype(jnp.float32) * (float(d) ** -0.5)   # [B,H,K1,S]
     j = jnp.arange(s_len, dtype=jnp.int32)
     valid = (j[None, None, :] < lens[:, None, None]) | \
             ((j[None, None, :] >= gen0[:, None, None]) &
              (j[None, None, :] <= (pos[:, None] + i[None, :])[:, :, None]))
-    p = _scores_to_probs(s, valid[:, None], dt)      # [B,H,K1,S]
-    c = jax.lax.dot_general(p, vv, (((3,), (1,)), ((0, 1), (0, 2))),
-                            preferred_element_type=jnp.float32).astype(dt)
-    out = jax.lax.dot_general(c, wo.reshape(h, d, m),
-                              (((1, 3), (0, 1)), ((), ())),
+    c = _decode_contract(q, kk, vv, valid, dt)        # [B,K1,S] mask
+    out = jax.lax.dot_general(c, wo.reshape(h, -1, m),
+                              (((2, 3), (0, 1)), ((), ())),
                               preferred_element_type=jnp.float32).astype(dt)
     return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 

@@ -207,13 +207,15 @@ _POOL = dict(n_pages=3072, ps=16, h=16, dk=64, slots=48, table=64)
 def _lower_paged_op(op, store, chip, bucket=512):
     """Compile one paged op at the serving cell's geometry (3072 pages
     of 16 x 1024, 48 slots x 64 table entries; batch-1 prefill of
-    ``bucket`` tokens) with its pools donated -> optimized HLO text."""
+    ``bucket`` tokens; a verify window of 5, spec_k 4) with its pools
+    donated -> optimized HLO text."""
     import types
     from paddle_tpu.core.registry import get_op
     g = _POOL
     m = g["h"] * g["dk"]
-    b, t = (1, bucket) if op == "kv_attention_prefill_paged" \
-        else (g["slots"], 1)
+    b, t = {"kv_attention_prefill_paged": (1, bucket),
+            "kv_attention_verify_paged": (g["slots"], 5)}.get(
+                op, (g["slots"], 1))
 
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -227,6 +229,8 @@ def _lower_paged_op(op, store, chip, bucket=512):
     else:
         ins.update(PageTable=struct((b, g["table"]), I32), Pos=col,
                    SeqLen=col, GenStart=col, Active=col)
+        if op == "kv_attention_verify_paged":
+            ins["WinLen"] = col
     attrs = {"n_head": g["h"],
              "codec": "bf16" if store == BF16 else "none"}
 
@@ -275,24 +279,23 @@ def _comes_from(ops, name, name_prefix):
 
 
 @pytest.mark.parametrize("store", ["float32", "bfloat16"])
-@pytest.mark.parametrize("op, copies_after_gather", [
-    ("kv_attention_prefill_paged", 0),
-    # the gathered K and V ([48*1024, 1024], by chance the pool's size)
-    # are each relaid twice for the two M=1 contractions, whose
-    # multiply-reduce wants the cache position on the lanes (PERF.md
-    # section 5); nothing else of that size may be copied
-    ("kv_attention_decode_paged", 4),
-])
+@pytest.mark.parametrize("op", ["kv_attention_prefill_paged",
+                                "kv_attention_decode_paged",
+                                "kv_attention_verify_paged"])
 def test_paged_pool_is_row_major_at_rest_on_v5e(chip, monkeypatch, op,
-                                                copies_after_gather,
                                                 store):
     """The pool variable [n_pages, page_size, H*Dk] is row-major at
     rest: (a) parameters and results carry the descending layout, (b)
-    the prefill copies no pool, (c) the decode copies none on the way
-    INTO ``gather_pages`` and only the gathered caches after it, (d)
-    each pool's input and output share a buffer. With a 64-wide minor
+    no program holds a copy or transpose of a pool's size, (c) the
+    decode and the verify window reach ``gather_pages`` without one and
+    relay nothing it gathered ([48*1024, 1024], by chance the pool's
+    size): K and V are contracted as the gather leaves them, through a
+    block-diagonal query (kv_attention._decode_contract), (d) each
+    pool's input and output share a buffer. With a 64-wide minor
     dimension ([.., H, Dk]) the same programs transposed every pool in
-    and out: 109 of a 136 ms decode step (PERF.md, PR 25 and PR 28)."""
+    and out, 109 of a 136 ms decode step, and a per-head contraction
+    relaid each gathered cache twice, 29.4 of a 55.8 ms step (PERF.md,
+    PRs 25, 28 and 30). This guard forbids their return."""
     import re
     from paddle_tpu.ops import pallas as pk
     monkeypatch.setattr(pk, "on_tpu", lambda: True)
@@ -318,7 +321,4 @@ def test_paged_pool_is_row_major_at_rest_on_v5e(chip, monkeypatch, op,
             all("tpu_custom_call" in ops[n][3] for n in gathers)
         for n in gathers:                                       # (c)
             assert not _comes_from(ops, n, "copy"), ops[n][3][:200]
-    assert len(copies) <= copies_after_gather, \
-        [ops[n][3][:160] for n in copies]                   # (b), (c)
-    for n in copies:
-        assert _comes_from(ops, n, "gather_pages"), ops[n][3][:200]
+    assert not copies, [ops[n][3][:160] for n in copies]    # (b), (c)

@@ -415,11 +415,14 @@ def test_paged_ops_identical_through_every_gather_tier(op, codec,
 @pytest.mark.parametrize("op", ["decode", "verify"])
 def test_paged_fp32_bit_identical_to_contiguous_op(op):
     """fp32 paged decode == the wave op ``kv_attention_decode`` over the
-    cache the page table describes, BIT for bit on every active row:
-    the paged op reshapes the gathered rows to [B, S, H, Dk] and runs
-    the same per-head contraction over them, so the page indirection
-    adds nothing to the products and sums, and the rows it writes are
-    the same too. The verify window has no wave twin: it is held to a
+    cache the page table describes, BIT for bit on every active row.
+    It holds by construction: both ops hand the same function
+    (``kv_attention._decode_contract``) the same [B, S, H*Dk] rows —
+    the paged op as its gather leaves them, the wave op its
+    [B, S, H, Dk] caches viewed so — hence the same shapes, the same
+    dots and the same bits on any backend. So the page indirection adds
+    nothing to the products and sums, and the rows it writes are the
+    same too. The verify window has no wave twin: it is held to a
     plain float32 statement of the window attention written here
     (rtol 1e-6), the rows it writes compared exactly; its token-level
     identity with sequential paged decode is
@@ -458,6 +461,83 @@ def test_paged_fp32_bit_identical_to_contiguous_op(op):
         np.testing.assert_array_equal(
             _bits(cache(got[pool][0]))[active],
             _bits(want[c][0])[active])
+
+
+def _contract_case(k1, h, d, seed):
+    """Inputs of ``kv_attention._decode_contract``: q [B, K1, H, Dk],
+    k / v [B, S, H*Dk] with huge garbage in every masked row, a causal
+    window mask with per-row lengths, and one slot (row 1) that is
+    inactive: nothing of it is valid."""
+    b, s_len = 3, 40
+    rng = np.random.default_rng(seed)
+    q = rng.standard_normal((b, k1, h, d)).astype(np.float32)
+    k, v = (rng.standard_normal((b, s_len, h * d)).astype(np.float32)
+            for _ in range(2))
+    lens = np.array([17, 0, 31])
+    j = np.arange(s_len)
+    valid = j[None, None, :] < (lens[:, None] + np.arange(k1))[:, :, None]
+    valid[1] = False
+    dead = ~valid.any(axis=1)                            # [B, S]
+    k[dead] = 1e30
+    v[dead] = -1e30
+    return q, k, v, valid
+
+
+@pytest.mark.parametrize("k1, h, d", [(1, 16, 64), (5, 16, 64),
+                                      (1, 4, 128)])
+def test_decode_contract_equals_per_head_contraction(k1, h, d):
+    """The block-diagonal contraction over [B, S, H*Dk] is the per-head
+    attention over [B, S, H, Dk], written here plainly in float32: the
+    same products plus exact zeros, the sums in another order (rtol
+    1e-6). Garbage in masked rows and an inactive slot change nothing
+    a reader sees."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kv_attention as kva
+    q, k, v, valid = _contract_case(k1, h, d, seed=k1 * h + d)
+    got = np.asarray(kva._decode_contract(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(valid), jnp.float32))
+    assert got.shape == (3, k1, h, d) and got.dtype == np.float32
+    k4, v4 = (a.reshape(3, -1, h, d) for a in (k, v))
+    s = jnp.einsum("bihd,bjhd->bhij", q, k4) * d ** -0.5
+    p = jax.nn.softmax(jnp.where(valid[:, None], s, -jnp.inf), axis=-1)
+    want = np.asarray(jnp.einsum("bhij,bjhd->bihd", p, v4))
+    live = valid.any(axis=2)                             # [B, K1]
+    assert live[0].all() and not live[1].any()
+    assert np.all(np.isfinite(got[live]))
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-6,
+                               atol=1e-6)
+
+
+def test_decode_contract_heads_do_not_mix():
+    """A head's context reads its own lanes of K and V and nothing
+    else: rewriting every OTHER head's lanes, with values large enough
+    to swamp any leak, leaves its bits as they were — the zeros of the
+    block-diagonal query and of the pick of a head's own lanes are
+    selected (``where``), not multiplied, so they are exact."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kv_attention as kva
+    k1, h, d = 2, 16, 64
+    q, k, v, valid = _contract_case(k1, h, d, seed=11)
+
+    def run(k, v):
+        return np.asarray(kva._decode_contract(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(valid), jnp.float32))
+    base = run(k, v)
+    keep = 5
+    others = np.ones(h * d, bool)
+    others[keep * d:(keep + 1) * d] = False
+    k2, v2 = k.copy(), v.copy()
+    k2[:, :, others] = 1e4 * (1 + np.arange(others.sum()) % 7)
+    v2[:, :, others] = -3e4
+    moved = run(k2, v2)
+    live = valid.any(axis=2)
+    np.testing.assert_array_equal(_bits(moved[:, :, keep])[live],
+                                  _bits(base[:, :, keep])[live])
+    assert not np.array_equal(moved[:, :, keep - 1][live],
+                              base[:, :, keep - 1][live])
 
 
 def _window_attention_reference(ins, h):
