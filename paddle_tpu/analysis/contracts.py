@@ -37,7 +37,8 @@ Two surfaces:
                             (which would read a stale or freed buffer)
   ctr-geometry-drift        all views' stamped GeometryRecords agree, and
                             each view's feeds/pools are consistent with its
-                            record (page_table width, K+1 window, slot count)
+                            record (page_table width, K+1 window, slot count,
+                            one row of recurrent state per slot)
   ========================  =================================================
 
 CLI: ``tools/proglint.py --contracts`` (default family:
@@ -416,6 +417,18 @@ def rule_geometry_drift(ctx) -> Iterable[Diagnostic]:
                     message=f"view {key!r}: tok window width {k1} != "
                             f"spec_k+1 ({g.window})",
                     var="tok", details={"view": key})
+        # the second kind of per-slot state (a hybrid family's recurrent
+        # and conv state): one row per slot, whichever view declares it
+        for name, vd in v.desc.global_block.vars.items():
+            if g.n_slots and vd.persistable and vd.shape and (
+                    "_kda_state_" in name or "_kda_conv_" in name) \
+                    and int(vd.shape[0]) != g.n_slots:
+                yield Diagnostic(
+                    rule="ctr-geometry-drift", severity=Severity.ERROR,
+                    message=f"view {key!r}: per-slot state {name!r} holds "
+                            f"{vd.shape[0]} slots, the view's n_slots is "
+                            f"{g.n_slots}",
+                    var=name, details={"view": key})
         if g.n_slots and tok is not None and g.mode in (
                 "decode_paged", "decode_verify_paged"):
             s = int(tok[0][0])

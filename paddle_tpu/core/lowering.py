@@ -283,7 +283,13 @@ class CompiledBlock:
 
     def __init__(self, program: ir.ProgramDesc, block_idx: int,
                  feed_names: Sequence[str], fetch_names: Sequence[str],
-                 is_test: bool = False, donate: bool = True, dist=None):
+                 is_test: bool = False, donate: bool = True, dist=None,
+                 feed_transform=None):
+        # ``feed_transform(feeds) -> feeds``, traced INSIDE the
+        # executable before the block reads its feeds: the caller may
+        # then hand over entries the program does not name and have them
+        # folded into ones it does, with no dispatch of their own (the
+        # slot engine's token feed: serving/engine.py). Not under a mesh.
         self._obs_tag = next(CompiledBlock._SEQ)
         _obs_runtime.install_compile_listener()
         # build-time program verification (FLAGS_verify_program or a
@@ -337,6 +343,15 @@ class CompiledBlock:
                 pass
         fn = build_block_fn(program, block_idx, self.sig, is_test=is_test,
                             dist=dist)
+        if feed_transform is not None:
+            if dist is not None and dist.mesh is not None:
+                raise ValueError("feed_transform is not supported under "
+                                 "a mesh (feeds are sharded by name)")
+            block_fn = fn
+
+            def fn(state, consts, feeds, step_seed):
+                return block_fn(state, consts, feed_transform(feeds),
+                                step_seed)
         jit_kwargs = {}
         if donate:
             jit_kwargs["donate_argnums"] = (0,)

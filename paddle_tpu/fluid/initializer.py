@@ -49,6 +49,21 @@ class NormalInitializer(Initializer):
                    "mean": self.loc, "std": self.scale, "seed": self.seed})
 
 
+class HashNormalInitializer(Initializer):
+    """Normal(0, scale) drawn elementwise from the element's index
+    (op ``hash_normal_random``): for models whose start-up would not
+    fit beside the bit buffers of ``NormalInitializer``'s draw."""
+
+    def __init__(self, scale: float = 1.0):
+        self.scale = scale
+
+    def __call__(self, var, block):
+        block.append_op(
+            "hash_normal_random", outputs={"Out": [var]},
+            attrs={"shape": list(var.shape), "dtype": var.dtype,
+                   "std": self.scale})
+
+
 class TruncatedNormalInitializer(Initializer):
     def __init__(self, loc: float = 0.0, scale: float = 1.0, seed: int = 0):
         self.loc, self.scale, self.seed = loc, scale, seed

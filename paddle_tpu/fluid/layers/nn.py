@@ -656,6 +656,175 @@ def _attention_projection_params(helper, d_model, param_attr):
                                     dtype="float32") for a in attrs4]
 
 
+def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
+    """([Wq, Wk, Wv, Wo], {"Wg": [gate]} or {}) of a paged attention
+    layer. ``gqa`` None is the multi-head family's four [M, M] float32
+    matrices and leaves ``attrs`` alone (its programs stay what they
+    were); ``gqa = {"n_kv_head", "head_dim", "gate"}`` declares a
+    grouped-KV layer in x's dtype — Wq / Wg [M, H*D], Wk / Wv
+    [M, n_kv*D], Wo [H*D, M], names ``<base>.wq`` ... ``.wg`` — and sets
+    the attrs the op reads them by."""
+    if gqa is None:
+        return _attention_projection_params(helper, d_model, param_attr), {}
+    import copy
+    n_kv, d = int(gqa["n_kv_head"]), int(gqa["head_dim"])
+    attrs.update(n_kv_head=n_kv, head_dim=d)
+    wide, narrow = int(n_head) * d, n_kv * d
+    shapes = {"wq": [d_model, wide], "wk": [d_model, narrow],
+              "wv": [d_model, narrow], "wo": [wide, d_model]}
+    if gqa.get("gate"):
+        shapes["wg"] = [d_model, wide]
+    ws = {}
+    for tag, shape in shapes.items():
+        a = copy.deepcopy(param_attr)
+        a.name = f"{a.name}.{tag}"
+        ws[tag] = helper.create_parameter(a, shape=shape, dtype=x.dtype)
+    return ([ws[t] for t in ("wq", "wk", "wv", "wo")],
+            {"Wg": [ws["wg"]]} if "wg" in ws else {})
+
+
+def rms_norm(x, epsilon=1e-5, param_attr=None, name=None):
+    """RMSNorm over the last axis (float32 statistics, the result in
+    x's dtype), with a learned gain initialised to 1."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        param_attr, shape=[int(x.shape[-1])], dtype=x.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("rms_norm", inputs={"X": [x], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def dense(x, size, param_attr=None, out_dtype=None, out_name=None,
+          name=None):
+    """x [..., M] @ W [M, size], multiplied in x's dtype with float32
+    accumulation; the result in ``out_dtype`` (x's by default) — a
+    bfloat16 model's logits stay float32 this way. ``out_name`` names
+    the result so that a caller can fetch it."""
+    helper = LayerHelper("dense", name=name)
+    w = helper.create_parameter(param_attr, shape=[int(x.shape[-1]), size],
+                                dtype=x.dtype)
+    dtype = out_dtype or x.dtype
+    if out_name:
+        out = helper.block.create_var(name=out_name, dtype=dtype)
+    else:
+        out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op("dense", inputs={"X": [x], "W": [w]},
+                     outputs={"Out": [out]},
+                     attrs={"out_dtype": out_dtype} if out_dtype else {})
+    return out
+
+
+def _kda_weights(helper, x, d_model, n_head, head_dim, conv_taps, rank,
+                 base, init):
+    """The weights of one KDA layer (ops/kda.py), named ``<base>.<tag>``;
+    ``init`` initialises every matrix. The decay starts as
+    Mamba-2 and Gated DeltaNet start it: A = exp(A_log) spread evenly
+    over [1, 16] across heads, and a dt_bias whose softplus is spread
+    log-evenly over [0.001, 0.1] across channels — so a head forgets
+    within a token or two, or remembers for thousands."""
+    from paddle_tpu.fluid.initializer import NumpyArrayInitializer
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    wide = n_head * head_dim
+    dt0 = np.exp(np.linspace(np.log(1e-3), np.log(0.1), wide))
+    # tag: (the op's slot, shape, the fixed float32 start or None: drawn)
+    table = {
+        "wq": ("Wq", [d_model, wide], None),
+        "wk": ("Wk", [d_model, wide], None),
+        "wv": ("Wv", [d_model, wide], None),
+        "wo": ("Wo", [wide, d_model], None),
+        "conv": ("ConvW", [conv_taps, 3 * wide], None),
+        "a_log": ("ALog", [n_head], np.log(np.linspace(1.0, 16.0, n_head))),
+        "dt_bias": ("DtBias", [wide], dt0 + np.log(-np.expm1(-dt0))),
+        "wa_down": ("WaDown", [d_model, rank], None),
+        "wa_up": ("WaUp", [rank, wide], None),
+        "wbeta": ("WBeta", [d_model, n_head], None),
+        "wg_down": ("WgDown", [d_model, rank], None),
+        "wg_up": ("WgUp", [rank, wide], None),
+        "onorm": ("ONorm", [head_dim], np.ones(head_dim))}
+    out = {}
+    for tag, (slot, shape, fixed) in table.items():
+        attr = ParamAttr(
+            name=f"{base}.{tag}",
+            initializer=init if fixed is None else NumpyArrayInitializer(
+                fixed.astype(np.float32)))
+        out[slot] = [helper.create_parameter(
+            attr, shape=shape,
+            dtype=x.dtype if fixed is None else "float32")]
+    return out
+
+
+def kda(x, state, conv, d_model, n_head, head_dim, base, init, rank,
+        conv_taps=4, epsilon=1e-5, seq_len=None, slot=None, active=None,
+        name=None):
+    """One Kimi Delta Attention layer (ops/kda.py) over the persistable
+    per-slot ``state`` [n_slots, H, D, D] float32 and ``conv``
+    [n_slots, taps-1, 3*H*D], both read and written under their own
+    names (donated). With ``seq_len`` and ``slot`` it is the prefill of
+    ONE request, x [1, T, M], writing slot ``slot``; with ``active`` the
+    decode step of every slot, x [n_slots, 1, M]."""
+    prefill = seq_len is not None
+    op = "kda_prefill" if prefill else "kda_decode"
+    helper = LayerHelper(op, name=name)
+    inputs = _kda_weights(helper, x, d_model, n_head, head_dim, conv_taps,
+                          rank, base, init)
+    inputs.update(X=[x], State=[state], Conv=[conv])
+    if prefill:
+        inputs.update(SeqLen=[seq_len], Slot=[slot])
+    else:
+        inputs.update(Active=[active])
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(op, inputs=inputs,
+                     outputs={"Out": [out], "StateOut": [state],
+                              "ConvOut": [conv]},
+                     attrs={"n_head": int(n_head),
+                            "head_dim": int(head_dim),
+                            "epsilon": float(epsilon)})
+    return out
+
+
+def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
+                    init, held_start=0, n_shared=1, norm_topk=True,
+                    scaling=1.0, valid=None, seq_len=None, counts=None,
+                    name=None):
+    """One expert-parallel member's share of a top-k routed expert layer
+    plus the shared expert (ops/expert_ffn.py): the router is
+    ``n_experts`` wide, the ``n_held`` experts from ``held_start`` are
+    computed here. ``valid`` [n, 1] int or ``seq_len`` [1, 1] says which
+    tokens are real; ``counts`` [2, n_held] int32 (persistable, donated)
+    accumulates the tokens each held expert was given and the calls in
+    which it was given any."""
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    helper = LayerHelper("expert_ffn_held", name=name)
+    shared = n_shared * d_expert
+    shapes = {"router": ("RouterW", [d_model, n_experts]),
+              "w_gate": ("WGate", [n_held, d_model, d_expert]),
+              "w_up": ("WUp", [n_held, d_model, d_expert]),
+              "w_down": ("WDown", [n_held, d_expert, d_model]),
+              "s_gate": ("SGate", [d_model, shared]),
+              "s_up": ("SUp", [d_model, shared]),
+              "s_down": ("SDown", [shared, d_model])}
+    inputs = {"X": [x]}
+    for tag, (slot_name, shape) in shapes.items():
+        inputs[slot_name] = [helper.create_parameter(
+            ParamAttr(name=f"{base}.{tag}", initializer=init),
+            shape=shape, dtype=x.dtype)]
+    outputs = {"Out": [helper.create_variable_for_type_inference(x.dtype)]}
+    if valid is not None:
+        inputs["Valid"] = [valid]
+    if seq_len is not None:
+        inputs["SeqLen"] = [seq_len]
+    if counts is not None:
+        inputs["Counts"], outputs["CountsOut"] = [counts], [counts]
+    helper.append_op("expert_ffn_held", inputs=inputs, outputs=outputs,
+                     attrs={"top_k": int(top_k),
+                            "held_start": int(held_start),
+                            "norm_topk": bool(norm_topk),
+                            "scaling": float(scaling)})
+    return outputs["Out"][0]
+
+
 def kv_attention_prefill(x, d_model, n_head, cache_k, cache_v,
                          param_attr=None, name=None):
     """Causal self-attention over the whole (padded) prompt that ALSO
@@ -709,7 +878,7 @@ def kv_attention_decode(x, pos, seq_len, gen_start, active, d_model,
 
 def kv_attention_prefill_paged(x, rows, d_model, n_head, page_k, page_v,
                                page_ks=None, page_vs=None, codec="none",
-                               param_attr=None, name=None):
+                               param_attr=None, name=None, gqa=None):
     """In-flight-batching prefill (ISSUE 9, 17): causal self-attention
     over the prompt whose K/V rows scatter into the LIVE paged pool
     caches, so a new request joins a running decode without disturbing
@@ -726,10 +895,12 @@ def kv_attention_prefill_paged(x, rows, d_model, n_head, page_k, page_v,
     on write into ``page_ks``/``page_vs`` scale planes
     (ops/kv_attention.py; docs/serving.md 'Paged KV cache')."""
     helper = LayerHelper("kv_attention_prefill_paged", name=name)
-    ws = _attention_projection_params(helper, d_model, param_attr)
+    attrs = {"n_head": int(n_head), "codec": str(codec)}
+    ws, gate = _attention_weights(helper, x, d_model, n_head, param_attr,
+                                  gqa, attrs)
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x], "Wq": [ws[0]], "Wk": [ws[1]],
-              "Wv": [ws[2]], "Wo": [ws[3]],
+              "Wv": [ws[2]], "Wo": [ws[3]], **gate,
               "PageK": [page_k], "PageV": [page_v], "Rows": [rows]}
     outputs = {"Out": [out], "PageKOut": [page_k],
                "PageVOut": [page_v]}
@@ -737,15 +908,14 @@ def kv_attention_prefill_paged(x, rows, d_model, n_head, page_k, page_v,
         inputs["PageKS"], inputs["PageVS"] = [page_ks], [page_vs]
         outputs["PageKSOut"], outputs["PageVSOut"] = [page_ks], [page_vs]
     helper.append_op("kv_attention_prefill_paged",
-                     inputs=inputs, outputs=outputs,
-                     attrs={"n_head": int(n_head), "codec": str(codec)})
+                     inputs=inputs, outputs=outputs, attrs=attrs)
     return out
 
 
 def kv_attention_decode_paged(x, page_table, pos, seq_len, gen_start,
                               active, d_model, n_head, page_k, page_v,
                               page_ks=None, page_vs=None, codec="none",
-                              param_attr=None, name=None):
+                              param_attr=None, name=None, gqa=None):
     """One-token decode over the PAGED KV pool: per-row geometry
     identical to ``kv_attention_decode``, but the cache row for logical
     position j of slot b resolves through the page-table feed
@@ -759,10 +929,12 @@ def kv_attention_decode_paged(x, page_table, pos, seq_len, gen_start,
     in-gather under ``codec='int8'``. x [B, 1, M] -> [B, 1, M]
     (ops/kv_attention.py; docs/serving.md 'Paged KV cache')."""
     helper = LayerHelper("kv_attention_decode_paged", name=name)
-    ws = _attention_projection_params(helper, d_model, param_attr)
+    attrs = {"n_head": int(n_head), "codec": str(codec)}
+    ws, gate = _attention_weights(helper, x, d_model, n_head, param_attr,
+                                  gqa, attrs)
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x], "Wq": [ws[0]], "Wk": [ws[1]],
-              "Wv": [ws[2]], "Wo": [ws[3]],
+              "Wv": [ws[2]], "Wo": [ws[3]], **gate,
               "PageK": [page_k], "PageV": [page_v],
               "PageTable": [page_table], "Pos": [pos],
               "SeqLen": [seq_len], "GenStart": [gen_start],
@@ -773,8 +945,7 @@ def kv_attention_decode_paged(x, page_table, pos, seq_len, gen_start,
         inputs["PageKS"], inputs["PageVS"] = [page_ks], [page_vs]
         outputs["PageKSOut"], outputs["PageVSOut"] = [page_ks], [page_vs]
     helper.append_op("kv_attention_decode_paged",
-                     inputs=inputs, outputs=outputs,
-                     attrs={"n_head": int(n_head), "codec": str(codec)})
+                     inputs=inputs, outputs=outputs, attrs=attrs)
     return out
 
 

@@ -178,6 +178,162 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 
 
 # ---------------------------------------------------------------------------
+# The hybrid sparse block of the slot views (decoder_lm(..., **arch)):
+# RMSNorm, no positions, a mixer per layer of kind "gqa" (gated softmax
+# attention with grouped KV heads, through the paged pool) or "kda" (Kimi
+# Delta Attention, a fixed-size recurrent state per slot), and an expert
+# layer of which this program holds a share. One scope serves the prefill
+# and the decode view: every weight and every state variable is named.
+# ---------------------------------------------------------------------------
+
+_HYBRID_KEYS = {
+    # the period of layer kinds, cycled over n_layer
+    "layer_kinds": None,
+    # "gqa" layers: KV heads, the size of a head, the output gate
+    "n_kv_head": None, "head_dim": None, "gqa_gate": True,
+    # "kda" layers
+    "kda_heads": None, "kda_head_dim": None, "kda_conv_taps": 4,
+    "kda_gate_rank": None,
+    # the expert layer: the router's width, the experts held here (from
+    # held_start), the picks per token, an expert's width
+    "n_routed_experts": None, "n_experts_held": None, "held_start": 0,
+    "n_experts_per_tok": None, "d_expert": None, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 1.0,
+    "rms_eps": 1e-5, "dtype": "float32"}
+
+
+def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
+    """The hybrid block's sizes, checked: every key of ``_HYBRID_KEYS``,
+    the ones whose default is None required. ``kinds`` is the layer
+    kind of each of the ``n_layer`` layers."""
+    unknown = sorted(set(arch) - set(_HYBRID_KEYS))
+    if unknown:
+        raise TypeError(f"decoder_lm: unknown argument(s) {unknown}; the "
+                        f"hybrid block takes {sorted(_HYBRID_KEYS)}")
+    hy = {**_HYBRID_KEYS, **arch}
+    missing = sorted(k for k, v in hy.items() if v is None)
+    if missing:
+        raise ValueError(f"decoder_lm: a hybrid block (layer_kinds given) "
+                         f"needs {missing} too")
+    period = tuple(hy["layer_kinds"])
+    bad = sorted(set(period) - {"gqa", "kda"})
+    if bad or not period:
+        raise ValueError(f"layer_kinds {period}: a layer is 'gqa' or 'kda'")
+    if mode not in ("prefill_paged", "decode_paged"):
+        raise ValueError(
+            f"decoder_lm mode {mode!r} with layer_kinds: the hybrid block "
+            f"is served by the slot views prefill_paged and decode_paged "
+            f"alone (no wave cache; a verify window would have to roll a "
+            f"recurrent state back)")
+    if not 0 < hy["n_experts_held"] <= hy["n_routed_experts"] \
+            - hy["held_start"]:
+        raise ValueError("n_experts_held must lie inside the router's "
+                         "n_routed_experts from held_start")
+    hy["kinds"] = tuple(period[i % len(period)] for i in range(n_layer))
+    return hy
+
+
+def hybrid_weight_std(name: str, shape) -> float:
+    """The standard deviation a hybrid block's weight matrix is drawn
+    with (the program's start-up and the benchmark's drawer use this one
+    rule): 1 for the embedding (nothing scales it and an RMSNorm follows),
+    taps**-0.5 for the depthwise conv (its output keeps its input's
+    variance), Glorot's sqrt(2 / (fan_in + fan_out)) over the last two
+    dimensions otherwise."""
+    if name.endswith("_emb"):
+        return 1.0
+    if name.endswith(".conv"):
+        return float(shape[0]) ** -0.5
+    return (2.0 / (float(shape[-2]) + float(shape[-1]))) ** 0.5
+
+
+def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, n_head, pool_var,
+                 pools, feeds):
+    """Embedding, the layers and the head of a hybrid slot view; returns
+    the float32 logits, flat: [1, V] at the prompt's true end for the
+    prefill, [n_slots, V] for the decode step. The logits variable is
+    named ``<name>_logits`` so that an engine can be asked to fetch it."""
+    prefill = mode == "prefill_paged"
+    dt, eps = hy["dtype"], hy["rms_eps"]
+    n_slots = pools["n_slots"]
+
+    init = _HybridNormal()       # every matrix, by its own name and shape
+
+    def pa(pname, matrix=False):
+        return fluid.ParamAttr(name=f"{name}_{pname}",
+                               initializer=init if matrix else None)
+
+    x = layers.embedding(x_ids, size=[vocab, d_model], dtype=dt,
+                         param_attr=pa("emb", True))
+    for i, kind in enumerate(hy["kinds"]):
+        y = layers.rms_norm(x, eps, pa(f"l{i}_ln1_scale"))
+        if kind == "gqa":
+            d = hy["head_dim"]
+            pshape = pools["shape"] + [hy["n_kv_head"] * d]
+            pk = pool_var(f"{name}_page_k_{i}", pshape, pools["dtype"])
+            pv = pool_var(f"{name}_page_v_{i}", pshape, pools["dtype"])
+            pks = pvs = None
+            if pools["codec"] == "int8":
+                sshape = pools["shape"] + [hy["n_kv_head"]]
+                pks = pool_var(f"{name}_page_ks_{i}", sshape)
+                pvs = pool_var(f"{name}_page_vs_{i}", sshape)
+            gqa = dict(n_kv_head=hy["n_kv_head"], head_dim=d,
+                       gate=hy["gqa_gate"])
+            attr = pa(f"l{i}_attn", True)    # the base of five names
+            if prefill:
+                y = layers.kv_attention_prefill_paged(
+                    y, feeds["page_rows"], d_model, n_head, pk, pv, pks,
+                    pvs, codec=pools["codec"], param_attr=attr, gqa=gqa)
+            else:
+                y = layers.kv_attention_decode_paged(
+                    y, feeds["page_table"], feeds["pos"], feeds["seq_len"],
+                    feeds["gen_start"], feeds["active"], d_model, n_head,
+                    pk, pv, pks, pvs, codec=pools["codec"],
+                    param_attr=attr, gqa=gqa)
+        else:
+            h, d = hy["kda_heads"], hy["kda_head_dim"]
+            state = pool_var(f"{name}_kda_state_{i}", [n_slots, h, d, d])
+            conv = pool_var(f"{name}_kda_conv_{i}",
+                            [n_slots, hy["kda_conv_taps"] - 1, 3 * h * d],
+                            dt)
+            y = layers.kda(
+                y, state, conv, d_model, h, d, f"{name}_l{i}_kda", init,
+                hy["kda_gate_rank"], hy["kda_conv_taps"], eps,
+                **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
+                   if prefill else dict(active=feeds["active"])))
+        x = layers.elementwise_add(x, y)
+        y = layers.rms_norm(x, eps, pa(f"l{i}_ln2_scale"))
+        y = layers.expert_ffn_held(
+            y, d_model, hy["d_expert"], hy["n_routed_experts"],
+            hy["n_experts_held"], hy["n_experts_per_tok"],
+            f"{name}_l{i}_moe", init, hy["held_start"],
+            hy["n_shared_experts"],
+            hy["norm_topk_prob"], hy["routed_scaling_factor"],
+            **(dict(seq_len=feeds["seq_len"]) if prefill else dict(
+                valid=feeds["active"],
+                counts=pool_var(f"{name}_moe_counts_{i}",
+                                [2, hy["n_experts_held"]], "int32"))))
+        x = layers.elementwise_add(x, y)
+    x = layers.reshape(x, shape=[-1, d_model])
+    if prefill:
+        one = layers.fill_constant([1, 1], "int64", 1)
+        x = layers.gather(x, layers.elementwise_sub(feeds["seq_len"], one))
+    x = layers.rms_norm(x, eps, pa("lnf_scale"))
+    return layers.dense(x, vocab, pa("head_w", True),
+                        out_dtype="float32", out_name=f"{name}_logits")
+
+
+class _HybridNormal(fluid.initializer.Initializer):
+    """Normal(0, hybrid_weight_std(name, shape)) for whatever matrix it
+    is asked to initialise, drawn elementwise (``hash_normal_random``: a
+    3.3 B-parameter start-up then needs no memory beside its outputs)."""
+
+    def __call__(self, var, block):
+        fluid.initializer.HashNormalInitializer(
+            hybrid_weight_std(var.name, var.shape))(var, block)
+
+
+# ---------------------------------------------------------------------------
 # Decoder-only LM serving family (paddle_tpu/serving): one set of weights,
 # several program views that share every parameter NAME so a single scope
 # serves them all —
@@ -224,7 +380,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                vocab: int = 64, d_model: int = 32, d_inner: int = 64,
                n_head: int = 2, n_layer: int = 2, name: str = "lm",
                cache_len=None, n_slots=None, page_size=None,
-               n_pages=None, kv_codec=None, spec_k=None):
+               n_pages=None, kv_codec=None, spec_k=None, **arch):
     """Emit the `mode` view ("full" | "prefill" | "decode" |
     "prefill_paged" | "decode_paged" | "decode_verify_paged")
     of the decoder-only LM into the current default programs.
@@ -245,7 +401,16 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     tokens per step, scored together with the last committed token as a
     [n_slots, K+1] window — one fixed-shape executable per (n_slots,
     spec_k), sampling all K+1 window positions on-device so the host's
-    accept rule is a pure comparison."""
+    accept rule is a pure comparison.
+
+    ``arch`` (``layer_kinds=...`` and the sizes :func:`hybrid_arch`
+    lists) turns the block into that of a hybrid sparse model: RMSNorm,
+    no positions, per layer a gated grouped-KV softmax mixer ("gqa",
+    through the same paged ops and pools) or a Kimi Delta Attention
+    mixer ("kda", a fixed-size recurrent state per slot beside the
+    pages), and an expert layer of which this program holds a share.
+    Without it the views are the multi-head ReLU family's, unchanged."""
+    hy = hybrid_arch(arch, mode, n_layer) if arch else None
     # all geometry validation + defaulting lives in ONE record shared
     # with the cross-view family verifier (analysis/contracts.py) —
     # the view consumes the normalized constants instead of re-deriving
@@ -265,8 +430,9 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     main = fluid.default_main_program()
     startup = fluid.default_startup_program()
     main._geometry = geom              # family verifier cross-checks this
-    pe = _const_var(name + "_pos_enc",
-                    position_encoding(cache_len, d_model))
+    if hy is None:
+        pe = _const_var(name + "_pos_enc",
+                        position_encoding(cache_len, d_model))
 
     def attn_pa(i):
         return fluid.ParamAttr(name=f"{name}_l{i}_attn")
@@ -288,11 +454,22 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
         _pool_fills.append((pname, shape, dtype))
         return v
 
+    def fill_pools():
+        # startup pool fills go AFTER every param initializer (rng-salt
+        # stability across modes — see pool_var above)
+        from paddle_tpu.fluid.initializer import ConstantInitializer
+        for pname, shape, fdt in _pool_fills:
+            sv = startup.global_block().create_var(
+                name=pname, shape=shape, dtype=fdt, persistable=True)
+            ConstantInitializer(0.0)(sv, startup.global_block())
+
     def sdata(nm, shape, dtype="int64"):
         # the slot views' feeds are fully static (no batch dimension)
         return layers.data(name=nm, shape=shape, dtype=dtype,
                            append_batch_size=False)
 
+    page_rows = page_table = state_slot = None
+    pos = gen_start = active = sample_step = None
     if mode == "decode":
         tok = layers.data(name="tok", shape=[1, 1], dtype="int64")
         pos = layers.data(name="pos", shape=[1], dtype="int64")
@@ -381,12 +558,32 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
         # sentinel rows skip prefix-shared pages (already resident)
         page_rows = sdata("page_rows", [t, 1])
         feed_specs["page_rows"] = ([t, 1], "int64")
+        if hy is not None and "kda" in hy["kinds"]:
+            # which slot's recurrent state this request's prompt lands
+            # in (>= n_slots: nowhere — the warm-up's dispatch)
+            state_slot = sdata("state_slot", [1, 1])
+            feed_specs["state_slot"] = ([1, 1], "int64")
         x_ids = ids
     else:
         t = prompt_len if mode == "prefill" else cache_len
         ids = layers.data(name="ids", shape=[t, 1], dtype="int64")
         feed_specs = {"ids": ([-1, t, 1], "int64")}
         x_ids = ids
+
+    if hy is not None:
+        logits = _hybrid_body(
+            hy, mode, x_ids, name, vocab, d_model, n_head, pool_var,
+            pools=dict(shape=[n_pages, page_size], dtype=store_dt,
+                       codec=kv_codec, n_slots=int(n_slots)),
+            feeds=dict(seq_len=seq_len, page_rows=page_rows,
+                       state_slot=state_slot, page_table=page_table,
+                       pos=pos, gen_start=gen_start, active=active))
+        fill_pools()
+        if mode == "prefill_paged":
+            sample_step = layers.fill_constant([1, 1], "int64", 0)
+        tok_out = layers.token_sample(logits, temp, top_k, seed_in,
+                                      sample_step)
+        return tok_out, feed_specs
 
     emb = layers.embedding(x_ids, size=[vocab, d_model],
                            param_attr=pa("emb"))
@@ -488,13 +685,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     logits = layers.fc(x, size=vocab, num_flatten_dims=2,
                        param_attr=pa("head_w"), bias_attr=False)
 
-    # startup pool fills go AFTER every param initializer (rng-salt
-    # stability across modes — see pool_var above)
-    from paddle_tpu.fluid.initializer import ConstantInitializer
-    for pname, shape, fdt in _pool_fills:
-        sv = startup.global_block().create_var(
-            name=pname, shape=shape, dtype=fdt, persistable=True)
-        ConstantInitializer(0.0)(sv, startup.global_block())
+    fill_pools()
 
     if mode == "prefill_paged":
         # first generated token, sampled on-device from the logits row
@@ -527,7 +718,7 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
                                                     "full"),
                               prompt_buckets=None, n_slots=None,
                               page_size=None, n_pages=None,
-                              kv_codec=None, spec_k=None):
+                              kv_codec=None, spec_k=None, **arch):
     """The serving program family: {key: (main, startup, feed_specs,
     fetch_name)}. All mains share every parameter name — run ONE startup
     (any of them; their parameter initializers are identical) into a
@@ -540,7 +731,9 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
     the decode slot pool of the paged views; ``page_size``/``n_pages``/
     ``kv_codec`` shape the page pool (ISSUE 17 — see decoder_lm);
     ``spec_k`` sizes the verify window of the ``decode_verify_paged``
-    view (ISSUE 19)."""
+    view (ISSUE 19). ``arch`` (``layer_kinds=...`` and the sizes of
+    :func:`hybrid_arch`) makes the slot views those of a hybrid sparse
+    model; see :func:`decoder_lm`."""
     cache_len = prompt_len + max_new
     buckets = tuple(sorted(set(int(b)
                                for b in (prompt_buckets or (prompt_len,)))))
@@ -551,7 +744,7 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
                d_inner=d_inner, n_head=n_head, n_layer=n_layer,
                name=name, cache_len=cache_len, n_slots=n_slots,
                page_size=page_size, n_pages=n_pages, kv_codec=kv_codec,
-               spec_k=spec_k)
+               spec_k=spec_k, **arch)
     out = {}
 
     def emit(key, mode, p_len):

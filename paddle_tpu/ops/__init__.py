@@ -23,3 +23,5 @@ from paddle_tpu.ops import quant_ops  # noqa: F401
 from paddle_tpu.ops import infra_ops  # noqa: F401
 from paddle_tpu.ops import kv_attention  # noqa: F401
 from paddle_tpu.ops import parallel_ops  # noqa: F401
+from paddle_tpu.ops import kda  # noqa: F401
+from paddle_tpu.ops import expert_ffn  # noqa: F401

@@ -1,0 +1,168 @@
+"""The expert feed-forward layer of a sparse model, as ONE member of an
+expert-parallel group computes it: the router scores every token over
+ALL ``n_experts`` experts and keeps the ``top_k`` best, and this
+program computes the part of the result that the experts it HOLDS
+(``[held_start, held_start + n_held)``) give, plus the shared expert
+that every member computes alike. What the absent experts would add is
+left out — their owners add it (docs/serving.md "Expert layer"); on one
+chip the layer runs without that exchange, and nothing here stands in
+for it.
+
+Dropless, static shapes, two ways to the same sum, chosen from the
+number of tokens in the call (a static shape, never a flag):
+
+- **grouped** (more than ``DENSE_MAX_TOKENS`` tokens: a prefill longer
+  than any the benchmark's cells serve): the ``n_tokens * top_k``
+  assignments are sorted by held expert into a buffer of that many rows (the worst case: no capacity factor, no
+  token dropped), assignments to experts held elsewhere sort to the end
+  and are never computed, and one grouped product per projection
+  (``jax.lax.ragged_dot``: a single Mosaic kernel on the TPU) runs over
+  the held experts;
+- **dense** (a decode step, a prefill of up to 512 tokens): every held
+  expert
+  multiplies EVERY token, and the combine weight — zero where the token
+  did not pick the expert — is applied before the down projection, so
+  the sum over experts is part of that one product. Below the chip's
+  ridge point (197 TFLOP/s over 819 GB/s: 240 multiply-accumulate rows
+  a bf16 weight) multiplying all tokens costs no more time than reading
+  an expert's weights does, nearly every held expert is hit in a step
+  anyway (128 tokens x 8 picks over 320 experts: 96 %), and the step's
+  cost no longer depends on the routing draw: on the chip the grouped
+  products of a 128-token step read 38 % of the HBM roofline and moved
+  with the seed. Above the ridge point the dense way goes on winning
+  for a while, because the grouped product's row tiles are mostly
+  padding at a few tokens an expert: one layer's held experts took
+  1.76 / 1.89 / 3.47 ms dense against 4.41 / 4.71 / 4.93 ms grouped at
+  128 / 256 / 512 tokens, the lines crossing near 700 (PERF.md, PR 31).
+
+``parallel/moe.py`` is the trainer's top-1 layer with capacity drops (op
+``moe_ffn``) and is not this.
+
+An expert is ``W_down (SiLU(W_gate x) * W_up x)``. Router scores and
+the combine weights are float32; products multiply in the storage dtype
+with float32 accumulation.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.registry import first, register_op
+from paddle_tpu.ops.math_ops import dense
+
+F32 = jnp.float32
+
+
+def route(x, router_w, top_k: int, norm_topk: bool, scaling: float):
+    """x [N, M] -> (combine weights [N, top_k] float32, expert ids
+    [N, top_k]): sigmoid scores over every expert, the best ``top_k``,
+    renormalised over the picks when ``norm_topk``."""
+    scores = jax.nn.sigmoid(dense(x, router_w))
+    vals, idx = jax.lax.top_k(scores, top_k)
+    if norm_topk:
+        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    return vals * scaling, idx
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """W_down (SiLU(W_gate x) * W_up x) for x [N, M] -> [N, M] float32."""
+    hidden = jax.nn.silu(dense(x, w_gate)) * dense(x, w_up)
+    return dense(hidden.astype(x.dtype), w_down)
+
+
+# at or under this many tokens a call takes the dense way (module
+# docstring: the largest prompt bucket under the measured crossover of
+# ~700 tokens, so every size a cell serves takes one way)
+DENSE_MAX_TOKENS = 512
+
+
+def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
+                      held_start: int, valid=None):
+    """The routed part of the layer that the held experts give: x [N, M],
+    combine / idx [N, K], w_gate / w_up [E_held, M, F], w_down
+    [E_held, F, M] -> (y [N, M] float32, tokens per held expert
+    [E_held] int32). Tokens with ``valid`` false are routed nowhere."""
+    n, k = idx.shape
+    n_held = w_gate.shape[0]
+    local = idx - held_start
+    held = (local >= 0) & (local < n_held)
+    if valid is not None:
+        held &= valid[:, None]
+    key = jnp.where(held, local, n_held)                     # [N, K]
+    picked = key[:, :, None] == jnp.arange(n_held)           # [N, K, E]
+    sizes = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
+    if n <= DENSE_MAX_TOKENS:
+        # [N, E]: the token's combine weight for the expert, or zero
+        w = jnp.sum(jnp.where(picked, combine[:, :, None], 0.0), axis=1)
+        hidden = jax.nn.silu(_all_tokens(x, w_gate)) \
+            * _all_tokens(x, w_up) * w[:, :, None]           # [N, E, F]
+        y = jax.lax.dot_general(
+            hidden.astype(x.dtype), w_down, (((1, 2), (0, 1)), ((), ())),
+            preferred_element_type=F32)
+        return y, sizes
+    key = key.reshape(-1)                                    # [N*K]
+    order = jnp.argsort(key, stable=True)
+    xs = jnp.take(x, order // k, axis=0)                     # [N*K, M]
+    hidden = jax.nn.silu(_grouped(xs, w_gate, sizes)) \
+        * _grouped(xs, w_up, sizes)
+    ys = _grouped(hidden.astype(x.dtype), w_down, sizes)     # [N*K, M]
+    # back to assignment order; rows past the held groups are zero and
+    # carry the weight 0 besides
+    back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, -1)
+    w = jnp.where(held, combine, 0.0)
+    return jnp.sum(back * w[:, :, None], axis=1), sizes
+
+
+def _all_tokens(x, w):
+    """x [N, M] through every expert's w [E, M, F] -> [N, E, F] float32."""
+    return jax.lax.dot_general(x.astype(w.dtype), w,
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)
+
+
+def _grouped(rows, w, sizes):
+    return jax.lax.ragged_dot(rows.astype(w.dtype), w, sizes,
+                              preferred_element_type=F32)
+
+
+@register_op("expert_ffn_held", no_grad=True,
+             ref="TPU-native serving op: one expert-parallel member's "
+                 "share of a top-k routed expert layer with a shared "
+                 "expert — dropless, static shapes; every held expert "
+                 "over all tokens for a step's few, one grouped product "
+                 "over the held experts for a prefill's many "
+                 "(ops/expert_ffn.py)")
+def _expert_ffn_held(ctx, ins, attrs):
+    """X [B,T,M], RouterW [M,E], WGate/WUp [E_held,M,F], WDown
+    [E_held,F,M], SGate/SUp [M,Fs], SDown [Fs,M] (the shared expert),
+    optional Valid [B*T, 1] int or SeqLen [1,1] int (which tokens are
+    real: an inactive slot's or a padded position's token is routed
+    nowhere), optional Counts [2,E_held] int32 (in place: row 0 the
+    tokens each held expert has been given, row 1 the calls in which it
+    was given any; they wrap, a reader takes differences) -> Out
+    [B,T,M] (+ CountsOut). attrs: top_k, held_start, norm_topk,
+    scaling."""
+    x = first(ins, "X")
+    b, t, m = x.shape
+    x2 = x.reshape(b * t, m)
+    valid = first(ins, "Valid")
+    if valid is not None:
+        valid = jnp.asarray(valid).reshape(-1) > 0
+    elif first(ins, "SeqLen") is not None:
+        valid = jnp.arange(b * t) < jnp.asarray(
+            first(ins, "SeqLen")).reshape(()).astype(jnp.int32)
+    combine, idx = route(x2, first(ins, "RouterW"), int(attrs["top_k"]),
+                         bool(attrs.get("norm_topk", True)),
+                         float(attrs.get("scaling", 1.0)))
+    y, sizes = held_experts_part(
+        x2, combine, idx, first(ins, "WGate"), first(ins, "WUp"),
+        first(ins, "WDown"), int(attrs.get("held_start", 0)), valid)
+    y = y + swiglu(x2, first(ins, "SGate"), first(ins, "SUp"),
+                   first(ins, "SDown"))
+    out = {"Out": [y.astype(x.dtype).reshape(b, t, m)]}
+    counts = first(ins, "Counts")
+    if counts is not None:
+        seen = jnp.stack([sizes, (sizes > 0).astype(jnp.int32)])
+        out["CountsOut"] = [counts + seen.astype(counts.dtype)]
+    return out

@@ -85,6 +85,7 @@ from paddle_tpu.core.registry import first, register_op
 from paddle_tpu.observability import metrics as _metrics
 
 from paddle_tpu.ops import attention_block as _ab
+from paddle_tpu.ops.math_ops import dense
 
 # exporter-catalog family (docs/serving.md "Metric names"; preregistered
 # via exporters._preregister_catalog importing this module). Counts
@@ -107,12 +108,15 @@ def _scores_to_probs(s, mask, dt):
     return p.astype(dt)
 
 
-def _decode_contract(q, k, v, valid, dt):
+def _decode_contract(q, k, v, valid, dt, n_kv=None):
     """The decode-side attention contraction, over a cache that keeps
     the model width on its minor dimension: q [B, K1, H, Dk] (K1 query
     rows per batch row: 1 for a decode step, the window for a verify),
     k / v [B, S, M] with M = H * Dk exactly as ``_paged_gather`` leaves
     them, valid [B, K1, S] bool -> context [B, K1, H, Dk] in ``dt``.
+    With ``n_kv`` grouped KV heads (H / n_kv query heads share one) the
+    cache is [B, S, n_kv * Dk] and row (k1, h) of the block-diagonal
+    query holds q's head h in the lanes of KV head h // (H / n_kv).
 
     The cache is never reshaped to [.., H, Dk]: a 64-wide head is half
     a lane tile, and a per-head contraction of one query row made the
@@ -130,10 +134,17 @@ def _decode_contract(q, k, v, valid, dt):
     precision HIGHEST, which is what an fp32 cache states: a DEFAULT
     MXU pass would round K and V to bf16."""
     b, k1, h, d = q.shape
-    m = h * d
     prec = jax.lax.Precision.HIGHEST if dt == jnp.float32 else None
-    own = jnp.eye(h, dtype=bool)[None, None, :, :, None]  # row h, lanes h'
-    qbd = jnp.where(own, q[:, :, None], 0).reshape(b, k1 * h, m)
+    if n_kv is None or n_kv == h:
+        n_kv = h
+        own = jnp.eye(h, dtype=bool)[None, None, :, :, None]
+        qbd = jnp.where(own, q[:, :, None], 0)     # row h, lanes h'
+    else:
+        own = (jnp.arange(h)[:, None] // (h // n_kv)
+               == jnp.arange(n_kv)[None, :])[None, None, :, :, None]
+        qbd = jnp.where(own, q[:, :, :, None], 0)  # row h, lanes h // G
+    m = n_kv * d
+    qbd = qbd.reshape(b, k1 * h, m)
     s = jax.lax.dot_general(qbd, k, (((2,), (2,)), ((0,), (0,))),
                             precision=prec,
                             preferred_element_type=jnp.float32)
@@ -144,8 +155,52 @@ def _decode_contract(q, k, v, valid, dt):
                             precision=prec,
                             preferred_element_type=jnp.float32)
     # row (k1, h) keeps head h's own lanes: the sum adds exact zeros
-    c = jnp.where(own, c.reshape(b, k1, h, h, d), 0).sum(axis=3)
+    c = jnp.where(own, c.reshape(b, k1, h, n_kv, d), 0).sum(axis=3)
     return c.astype(dt)
+
+
+def _gqa(attrs):
+    """(H, n_kv, Dk) of a grouped-KV, output-gated attention layer, or
+    None: the attrs ``n_kv_head`` / ``head_dim`` are set only by a model
+    that has such layers, so the programs of one that has not carry
+    neither (and stay what they were)."""
+    if "n_kv_head" not in attrs:
+        return None
+    return (int(attrs["n_head"]), int(attrs["n_kv_head"]),
+            int(attrs["head_dim"]))
+
+
+def _gqa_heads(x, w, heads, d):
+    """x [B,T,M] @ w [M, heads*d] -> [B,T,heads,d] in x's dtype."""
+    return dense(x, w, x.dtype).reshape(x.shape[:2] + (heads, d))
+
+
+def _gqa_output(x, c, wg, wo):
+    """y = Wo (c * sigmoid(Wg x)): the context c [B,T,H*D] gated
+    elementwise (float32) before the output projection."""
+    if wg is not None:
+        c = (c.astype(jnp.float32)
+             * jax.nn.sigmoid(dense(x, wg))).astype(x.dtype)
+    return dense(c, wo, x.dtype)
+
+
+def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, h, n_kv, d):
+    """Causal self-attention of a grouped-KV layer over X [B,T,M] with
+    NO positions (the mask alone orders it), plus the K/V projections
+    [B,T,n_kv*d] the caller caches."""
+    b, t, _ = x.shape
+    dt = x.dtype
+    q = _gqa_heads(x, wq, h, d).reshape(b, t, n_kv, h // n_kv, d)
+    k = _gqa_heads(x, wk, n_kv, d)
+    v = _gqa_heads(x, wv, n_kv, d)
+    s = jnp.einsum("btkgd,bskd->bkgts", q, k,
+                   preferred_element_type=jnp.float32) * (float(d) ** -0.5)
+    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    p = _scores_to_probs(s, causal, dt)
+    c = jnp.einsum("bkgts,bskd->btkgd", p, v,
+                   preferred_element_type=jnp.float32).astype(dt)
+    out = _gqa_output(x, c.reshape(b, t, h * d), wg, wo)
+    return out, k.reshape(b, t, -1), v.reshape(b, t, -1)
 
 
 def _causal_prefill(x, wq, wk, wv, wo, h):
@@ -380,15 +435,23 @@ def _kv_attention_prefill_paged(ctx, ins, attrs):
     Rows [B*T, 1] int: flat pool row per prompt position, sentinel
     (>= n_pages*ps) for shared-prefix and skipped positions -> Out
     [B,T,M] + the pools with this prompt's K/V written through the
-    page table. attrs: n_head, codec."""
+    page table. attrs: n_head, codec; with n_kv_head and head_dim the
+    layer has grouped KV heads (Wq/Wo [M, H*D] / [H*D, M], Wk/Wv
+    [M, n_kv*D], pools [n_pages, ps, n_kv*D]), no positions, and an
+    output gate Wg [M, H*D] when given (:func:`_gqa_causal_prefill`)."""
     x = first(ins, "X")
     wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
     h = int(attrs["n_head"])
     codec = str(attrs.get("codec", "none"))
     rows = jnp.asarray(first(ins, "Rows")).reshape(-1).astype(jnp.int32)
     flat_k, flat_v, fks, fvs, n_pages, ps, _ = _paged_pools(ins, codec)
-    out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
-    m = x.shape[2]
+    gqa = _gqa(attrs)
+    if gqa is not None:
+        out, k, v = _gqa_causal_prefill(x, wq, wk, wv, wo,
+                                        first(ins, "Wg"), *gqa)
+    else:
+        out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
+    m = flat_k.shape[1]
     flat_k, fks = _paged_write(flat_k, fks, rows, k.reshape(-1, m))
     flat_v, fvs = _paged_write(flat_v, fvs, rows, v.reshape(-1, m))
     return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
@@ -412,7 +475,11 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     ``_decode_contract`` as they are — no reshape to [.., H, Dk], no
     relayout — and kv_attention_decode hands the same function its
     caches viewed the same way, so fp32 paged decode is bit-identical
-    to kv_attention_decode over the same rows by construction."""
+    to kv_attention_decode over the same rows by construction. With
+    n_kv_head and head_dim (grouped KV heads, optional output gate Wg:
+    see kv_attention_prefill_paged) the gathered caches are
+    [B, S, n_kv*D] and the block-diagonal query carries H / n_kv query
+    heads per KV head."""
     x = first(ins, "X")
     wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
     h = int(attrs["n_head"])
@@ -431,9 +498,18 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
         .astype(jnp.int32)
     active = jnp.asarray(first(ins, "Active")).reshape(-1) > 0
 
-    q = _ab._proj(x, wq, h)                     # [B,1,H,D]
-    k_t = _ab._proj(x, wk, h)
-    v_t = _ab._proj(x, wv, h)
+    gqa = _gqa(attrs)
+    n_kv = None
+    if gqa is not None:
+        h, n_kv, d = gqa
+        q = _gqa_heads(x, wq, h, d)             # [B,1,H,D]
+        k_t = _gqa_heads(x, wk, n_kv, d)        # [B,1,n_kv,D]
+        v_t = _gqa_heads(x, wv, n_kv, d)
+    else:
+        q = _ab._proj(x, wq, h)                     # [B,1,H,D]
+        k_t = _ab._proj(x, wk, h)
+        v_t = _ab._proj(x, wv, h)
+    mk = flat_k.shape[1]                        # the pool's row width
 
     # this step's write row through the page table, sentinel (dropped)
     # for inactive slots — a free slot's pages are bit-identical before
@@ -442,8 +518,8 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     wpage = jnp.take_along_axis(table, (pos // ps)[:, None],
                                 axis=1)[:, 0]
     wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
-    flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, m))
-    flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, m))
+    flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, mk))
+    flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mk))
 
     # gather every slot's logical cache through its table row
     kk = _paged_gather(flat_k, fks, table, ps, dt, ctx.mesh)  # [B,S,M]
@@ -453,10 +529,13 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     valid = (j[None, :] < lens[:, None]) | \
             ((j[None, :] >= gen0[:, None]) &
              (j[None, :] <= pos[:, None]))           # [B,S]
-    c = _decode_contract(q, kk, vv, valid[:, None], dt)
-    out = jax.lax.dot_general(c, wo.reshape(h, -1, m),
-                              (((2, 3), (0, 1)), ((), ())),
-                              preferred_element_type=jnp.float32).astype(dt)
+    c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv)
+    if gqa is not None:
+        out = _gqa_output(x, c.reshape(b, 1, -1), first(ins, "Wg"), wo)
+    else:
+        out = jax.lax.dot_general(
+            c, wo.reshape(h, -1, m), (((2, 3), (0, 1)), ((), ())),
+            preferred_element_type=jnp.float32).astype(dt)
     return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 
 

@@ -48,6 +48,24 @@ def _mul(ctx, ins, attrs):
     return single(out.reshape(out_shape))
 
 
+def dense(x, w, out_dtype=None):
+    """x [..., M] @ w [M, N] in the weight's dtype with float32
+    accumulation; the result in ``out_dtype`` (float32 by default)."""
+    y = jax.lax.dot_general(x.astype(w.dtype), w,
+                            (((x.ndim - 1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return y if out_dtype is None else y.astype(out_dtype)
+
+
+@register_op("dense", no_grad=True,
+             ref="X [..., M] @ W [M, N] in the weight's dtype with "
+                 "float32 accumulation; attr out_dtype (default: X's)")
+def _dense(ctx, ins, attrs):
+    x = first(ins, "X")
+    return single(dense(x, first(ins, "W"),
+                        attrs.get("out_dtype") or x.dtype))
+
+
 @register_op("matmul", ref="operators/matmul_op.cc")
 def _matmul(ctx, ins, attrs):
     x = first(ins, "X")

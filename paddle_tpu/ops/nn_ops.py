@@ -419,6 +419,19 @@ def _layer_norm(ctx, ins, attrs):
     }
 
 
+@register_op("rms_norm", no_grad=True,
+             ref="RMSNorm (Zhang & Sennrich 2019, arXiv:1910.07467) over "
+                 "the last axis: float32 statistics, the result in the "
+                 "input's dtype")
+def _rms_norm(ctx, ins, attrs):
+    x = first(ins, "X")
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                        + attrs.get("epsilon", 1e-5))
+    y = xf * inv * first(ins, "Scale").astype(jnp.float32)
+    return {"Y": [y.astype(x.dtype)]}
+
+
 @register_op("group_norm", ref="operators/group_norm_op.cc")
 def _group_norm(ctx, ins, attrs):
     x = first(ins, "X")              # NCHW

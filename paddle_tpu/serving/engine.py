@@ -41,12 +41,13 @@ steady-state serving performs zero XLA compilations
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import json
 import os
 import pickle
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -307,12 +308,25 @@ class GenerativeModel:
         consts = {n: self.scope.find_var(n) for n in cb.sig.const_names}
         return state, consts, feeds, np.uint32(0)
 
-    def _run(self, cb, aot_key, feeds) -> np.ndarray:
+    # True only inside SlotGenerativeModel._run_unfetched
+    _fetch_later = False
+
+    def _run(self, cb, aot_key, feeds):
         """One dispatch of ``cb`` and the blocking fetch of its first
-        output. While tracing, the host's time in here is named:
-        ``serving.<kind>.args`` (scope lookups, padding), ``.dispatch``
-        (until the async call returns, and the state write-back) and
-        ``.fetch`` (blocked on the device), ``<kind>`` being ``prefill``
+        output (``_launch`` then ``_fetch``) — the one door every
+        dispatch of an engine goes through, so an observer wraps this.
+        The slot engine asks for what ``_launch`` returns instead and
+        fetches later (``_run_unfetched``)."""
+        launched = self._launch(cb, aot_key, feeds)
+        return launched if self._fetch_later else self._fetch(*launched)
+
+    def _launch(self, cb, aot_key, feeds) -> Tuple:
+        """One asynchronous dispatch of ``cb``: the new state is in the
+        scope and the first output still on the device when this
+        returns — ``(output, what _fetch needs beside it)``. While
+        tracing, the host's time is named ``serving.<kind>.args`` (scope
+        lookups, padding) and ``.dispatch`` (until the async call
+        returns, and the state write-back), ``<kind>`` being ``prefill``
         or ``decode`` by ``aot_key[0]`` (a verify step is a decode)."""
         from paddle_tpu.observability import memory as obs_memory
         from paddle_tpu.utils import faults
@@ -370,16 +384,24 @@ class GenerativeModel:
             raise
         for n, v in new_state.items():
             self.scope.set_var(n, v)
-        t2 = time.perf_counter() if trace_on else 0.0
-        out = np.asarray(fetches[0])
+        kind = ("serving.prefill" if aot_key[0].startswith("prefill")
+                else "serving.decode")
         if trace_on:
-            t3 = time.perf_counter()
-            kind = ("serving.prefill" if aot_key[0].startswith("prefill")
-                    else "serving.decode")
             ctx = tctx.current()
             tctx.record_span(kind + ".args", t0, t1, ctx=ctx)
-            tctx.record_span(kind + ".dispatch", t1, t2, ctx=ctx)
-            tctx.record_span(kind + ".fetch", t2, t3, ctx=ctx)
+            tctx.record_span(kind + ".dispatch", t1, time.perf_counter(),
+                             ctx=ctx)
+        return fetches[0], kind, plan
+
+    def _fetch(self, out, kind, plan) -> np.ndarray:
+        """The blocking fetch of a launched dispatch's output (span
+        ``serving.<kind>.fetch``: blocked on the device)."""
+        trace_on = tctx.active()
+        t0 = time.perf_counter() if trace_on else 0.0
+        out = np.asarray(out)
+        if trace_on:
+            tctx.record_span(kind + ".fetch", t0, time.perf_counter(),
+                             ctx=tctx.current())
         if plan is not None:
             out = plan.slice_fetch(out)
         return out
@@ -721,6 +743,30 @@ class ModelDrafter:
         return drafts
 
 
+def _merge_tokens(feeds):
+    """Inside the decode executable: ``tok`` is the host's value where
+    ``tok_use_host`` says so and the previous dispatch's output
+    ``tok_prev`` [n_slots, 1] elsewhere."""
+    import jax.numpy as jnp
+    feeds = dict(feeds)
+    prev, use_host = feeds.pop("tok_prev"), feeds.pop("tok_use_host")
+    tok = feeds["tok"]
+    feeds["tok"] = jnp.where(use_host[:, None, None], tok,
+                             prev[:, :, None].astype(tok.dtype))
+    return feeds
+
+
+class _Flight(NamedTuple):
+    """A decode step that is dispatched and not yet committed."""
+    out: object            # its sampled tokens [n_slots, 1], on the device
+    kind: str              # what ``_fetch`` needs beside them
+    plan: object
+    slots: np.ndarray      # the slots it ran, ascending
+    ran: np.ndarray        # the same as a mask over all slots
+    epoch: np.ndarray      # every slot's admission count at dispatch
+    last: np.ndarray       # per ran slot: its budget's last token
+
+
 class SlotGenerativeModel:
     """In-flight batched decoding over a persistent decode-slot pool
     (ISSUE 9) whose KV cache is PAGED (ISSUE 17): the decode executable
@@ -808,9 +854,13 @@ class SlotGenerativeModel:
             p: CompiledBlock(m.desc, 0, sorted(feeds), [fetch],
                              is_test=True, donate=True, dist=dist)
             for p, (m, _s, feeds, fetch) in pre.items()}
+        # without a mesh the decode executable takes its tokens from the
+        # previous step's output, still on the device, wherever the host
+        # does not know better (``_token_feeds``)
         self._cb_decode = CompiledBlock(
             dec_main.desc, 0, sorted(dec_feeds), [dec_fetch],
-            is_test=True, donate=True, dist=dist)
+            is_test=True, donate=True, dist=dist,
+            feed_transform=None if dist is not None else _merge_tokens)
         # the optional verify view: one fixed-shape [n_slots, K+1]
         # window executable — its presence flips step() to speculative
         # draft→verify→commit (ISSUE 19)
@@ -839,6 +889,7 @@ class SlotGenerativeModel:
             self._m_spec_accepted = smetrics.SPEC_ACCEPTED.labels(
                 model=name)
         self._discover_pool(dec_main, dec_feeds)
+        self._discover_state(dec_main, pre[self.prompt_len][2])
         self._warmed: set = set()
         self._aot: Dict[Tuple, object] = {}
         self._fingerprint = hashlib.sha256(json.dumps(
@@ -861,6 +912,17 @@ class SlotGenerativeModel:
         # committed-token history per slot (prompt + accepted tokens):
         # what the drafter proposes from — host lists, zero extra HBM
         self._hist: List[List[int]] = [[] for _ in range(s)]
+        # decode steps are dispatched and committed apart (``step``):
+        # slots whose LAST step, by token budget, is dispatched ride the
+        # next dispatch masked and leave when theirs is committed; a
+        # slot's admission count tells a step still in flight that the
+        # slot was released since (EOS seen a step late, a cancel) and
+        # its token is nobody's
+        self._closing = np.zeros(s, bool)
+        self._epoch = np.zeros(s, np.int64)
+        # dispatched and uncommitted, oldest first
+        self._flights: collections.deque = collections.deque()
+        self._last_out = None   # newest decode dispatch's tokens, on device
 
     def _discover_pool(self, dec_main, dec_feeds):
         """Size the page pool and the host page-table mirror off the
@@ -891,9 +953,83 @@ class SlotGenerativeModel:
                               self.n_pages, np.int64)
         self._pending_rows: Optional[np.ndarray] = None
 
+    def _discover_state(self, dec_main, pre_feeds):
+        """The second kind of per-slot state (docs/serving.md "Recurrent
+        state"): a hybrid family's ``*_kda_state_*`` / ``*_kda_conv_*``
+        variables, [n_slots, ...] each, fixed-size per slot — so
+        admission stays by pages and free slots. The prefill view writes
+        the slot its ``state_slot`` feed names, the decode view updates
+        every active slot in place. ``*_moe_counts_*`` are the expert
+        layers' device-side counters (``expert_token_counts``)."""
+        gvars = dec_main.desc.global_block.vars
+        self.state_vars = sorted(n for n in gvars
+                                 if "_kda_state_" in n or "_kda_conv_" in n)
+        self._count_vars = sorted(n for n in gvars if "_moe_counts_" in n)
+        self._counts_seen = (0, 0)        # (totals, device values) read
+        self._decode_steps_done = 0
+        # (decode steps counted, a copy of each counter): start-up's
+        # zeros until the dispatcher takes the first snapshot
+        self._counts_snapshot: Tuple = (0, [
+            np.zeros(gvars[n].shape, np.int32) for n in self._count_vars])
+        if bool(self.state_vars) != ("state_slot" in pre_feeds):
+            raise ValueError(
+                f"model {self.name!r}: the decode view carries recurrent "
+                f"state {self.state_vars} and the prefill view "
+                f"{'a' if 'state_slot' in pre_feeds else 'no'} "
+                f"state_slot feed — the views are not one family")
+        smetrics.RECURRENT_STATE_BYTES.labels(model=self.name).set(sum(
+            int(np.prod(gvars[n].shape))
+            * (4 if gvars[n].dtype == "float32" else 2)
+            for n in self.state_vars))
+
+    # decode steps between two snapshots of the expert counters
+    COUNT_SNAPSHOT_STEPS = 32
+
+    def _snapshot_counts(self):
+        """A device-side copy of the expert layers' counters, taken by
+        the dispatcher right after a step's state came back (the
+        variables themselves are donated to the next step, so no other
+        thread may read them): four tiny asynchronous copies every
+        ``COUNT_SNAPSHOT_STEPS`` steps, nothing fetched."""
+        import jax.numpy as jnp
+        self._counts_snapshot = (
+            self._decode_steps_done,
+            [jnp.copy(self.scope.find_var(n)) for n in self._count_vars])
+
+    def expert_token_counts(self, sync: bool = False) -> Dict:
+        """``{"steps": decode steps counted, "counts": [expert layers,
+        2, n_held] totals}`` of the expert layers' device-side counters
+        (row 0: tokens each held expert was given by decode steps; row
+        1: decode steps in which it was given any), as of the last
+        snapshot — at most ``COUNT_SNAPSHOT_STEPS`` steps old, or taken
+        now with ``sync`` (the dispatcher's own thread only) — and
+        ``paddle_moe_expert_tokens_total`` brought up to them. This is
+        what fetches from the device: a scrape or a window's edge calls
+        it, from any thread; the step's path never does."""
+        if sync:
+            self._snapshot_counts()
+        steps, arrays = self._counts_snapshot
+        if not self._count_vars:
+            return {"steps": steps, "counts": None}
+        now = np.stack([np.asarray(a) for a in arrays]).astype(np.int64)
+        # the device counts in int32 and wraps; totals are kept here
+        total, last = self._counts_seen
+        delta = (now - last) % (1 << 32)
+        self._counts_seen = (total + delta, now)
+        for (layer, expert), d in np.ndenumerate(delta[:, 0]):
+            if d:
+                smetrics.MOE_EXPERT_TOKENS.labels(
+                    model=self.name,
+                    layer=self._count_vars[layer].rsplit("_", 1)[1],
+                    expert=str(expert)).inc(int(d))
+        return {"steps": steps, "counts": total + delta}
+
     # -- plumbing (same dispatch/AOT discipline as GenerativeModel) ------
     _args = GenerativeModel._args
     _run = GenerativeModel._run
+    _fetch_later = False
+    _launch = GenerativeModel._launch
+    _fetch = GenerativeModel._fetch
     prompt_bucket_for = GenerativeModel.prompt_bucket_for
 
     def free_count(self) -> int:
@@ -909,11 +1045,12 @@ class SlotGenerativeModel:
         return self.pool.free_count()
 
     def _decode_feeds(self):
-        return {"tok": self._tok[:, None, None],
+        return {**self._token_feeds(),
                 "pos": (self._gen0 + self._gen_count - 1)[:, None],
                 "seq_len": self._seq[:, None],
                 "gen_start": self._gen0[:, None],
-                "active": self._active.astype(np.int64)[:, None],
+                "active": (self._active & ~self._closing
+                           ).astype(np.int64)[:, None],
                 "seed": self._seed[:, None],
                 "sample_step": self._gen_count[:, None],
                 "temperature": self._temp[:, None],
@@ -948,14 +1085,17 @@ class SlotGenerativeModel:
                 "page_table": self._table.copy()}
 
     def _prefill_feeds(self, p_len: int):
+        # the warm-up's feeds: every page row and the state slot are
+        # sentinels, so the dispatch compiles the shapes and writes
+        # nothing — every slot's state stays as start-up left it
         return {"ids": np.zeros((1, p_len, 1), np.int64),
-                **self._admit_feeds(0, p_len),
+                **self._admit_feeds(self.n_slots, p_len),
                 "seq_len": np.ones((1, 1), np.int64),
                 "seed": np.zeros((1, 1), np.int64),
                 "temperature": np.zeros((1, 1), np.float32),
                 "top_k": np.zeros((1, 1), np.int64)}
 
-    def _admit_feeds(self, slot: int, p_len: int):
+    def _admit_feeds(self, slot: int, p_len: int, trace_on: bool = False):
         """Prefill feed: the flat pool row for each prompt position —
         or the drop sentinel for positions whose pages are SHARED with
         the radix tree (their K/V is already resident and bit-identical
@@ -967,7 +1107,21 @@ class SlotGenerativeModel:
         self._pending_rows = None
         if rows is None:
             rows = np.full((p_len, 1), self._row_sentinel, np.int64)
-        return {"page_rows": rows}
+        if not self.state_vars:
+            return {"page_rows": rows}
+        # the recurrent state is per SLOT, not per page: the prefill
+        # recomputes the whole prompt (sentinel rows skip only the page
+        # WRITE), so a prefix-shared admission lands the same state.
+        # All the host does for it is name the slot (span
+        # ``serving.admit.state``: nothing is leased, copied or scrubbed)
+        t0 = time.perf_counter() if trace_on else 0.0
+        feeds = {"page_rows": rows,
+                 "state_slot": np.asarray([[slot]], np.int64)}
+        if trace_on:
+            tctx.record_span("serving.admit.state", t0,
+                             time.perf_counter(), ctx=tctx.current(),
+                             model=self.name, slot=slot)
+        return feeds
 
     def _reserve_capacity(self, slot, prompt, p_len, budget):
         """Admission-time capacity: lease the request's pages (raises
@@ -1028,11 +1182,16 @@ class SlotGenerativeModel:
             self._warmed.add((pk, p))
             if aot_dir and persist:
                 self._persist_one(aot_dir, pk, p)
-        if (dk,) not in self._warmed:
+        fresh = (dk,) not in self._warmed
+        if fresh:
             smetrics.count_compile(self.name, dk)
             compiled += 1
-            self._run(self._cb_decode, (dk,),
-                      self._decode_feeds())
+        # dispatched even when loaded, and twice: the second is fed the
+        # first one's tokens from the device, as every step after it is
+        # (``_token_feeds``)
+        for _ in range(2):
+            self._fetch(*self._dispatch_decode(self._decode_feeds()))
+        if fresh:
             self._warmed.add((dk,))
             if aot_dir and persist:
                 self._persist_one(aot_dir, dk)
@@ -1044,6 +1203,8 @@ class SlotGenerativeModel:
             self._warmed.add((vk,))
             if aot_dir and persist:
                 self._persist_one(aot_dir, vk)
+        if self._count_vars:
+            self._snapshot_counts()        # the copy's own compile, now
         # warmup dispatches touched slot 0's cache rows; no request was
         # live, so just make sure the host mirror says so
         self.reset()
@@ -1149,7 +1310,7 @@ class SlotGenerativeModel:
                 ids[0, :length, 0] = prompt
                 feeds = {
                     "ids": ids,
-                    **self._admit_feeds(slot, p_len),
+                    **self._admit_feeds(slot, p_len, trace_on),
                     "seq_len": np.asarray([[length]], np.int64),
                     "seed": np.asarray([[int(seed)]], np.int64),
                     "temperature": np.asarray([[float(temperature)]],
@@ -1158,7 +1319,16 @@ class SlotGenerativeModel:
                 if trace_on:
                     tctx.record_span("serving.prefill.feeds", t0,
                                      time.perf_counter(), ctx=pctx)
-                tok = self._run(self._cb_prefill[p_len], key, feeds)
+                launched = self._run_unfetched(self._cb_prefill[p_len],
+                                               key, feeds)
+                # the scheduler runs ahead (a step is in flight): queue
+                # the step after it behind the prefill BEFORE waiting
+                # for the first token, so the device goes from the
+                # prefill into a decode step and not into the host's
+                # turn-around; this slot joins the step after that one
+                if 0 < len(self._flights) < 3:
+                    self._launch_step()
+                tok = self._fetch(*launched)
         except BaseException:
             self._release_capacity(slot)
             raise
@@ -1188,7 +1358,8 @@ class SlotGenerativeModel:
             self._m_occupancy.set(self.occupancy())
         return slot, first, done
 
-    def step(self) -> List[Tuple[int, int, Optional[str]]]:
+    def step(self, ahead: bool = False
+             ) -> List[Tuple[int, int, Optional[str]]]:
         """One dispatch over the WHOLE pool (free slots ride along
         masked). Returns (slot, token, done_cause) events in commit
         order; slots that hit EOS or their token budget are released —
@@ -1201,15 +1372,95 @@ class SlotGenerativeModel:
         commits its accepted prefix plus the bonus token — up to K+1
         events per slot per step, bit-identical to what the sequential
         path would have emitted (exact-match acceptance against the
-        on-device samples)."""
-        live = np.flatnonzero(self._active)
-        if live.size == 0:
-            return []
+        on-device samples).
+
+        ``ahead`` (the server's scheduler passes it) queues the NEXT
+        decode step on the device before this one's tokens are fetched:
+        its token feed is this step's output, still on the device, so
+        the host's work on a step's tokens — this commit, the
+        scheduler's, the next dispatch — runs beside a device that is
+        never waiting for it. (Keeping six dispatched was tried and
+        taken back: 2 % fewer tokens a second on the chip, an
+        admission's prefill waiting behind all of them, and no steadier:
+        PERF.md, PR 31.) The events are the same; the scope is then a
+        dispatch or two ahead of them; a slot that ends on EOS or is
+        cancelled has run a step or two more than it needed, whose
+        tokens are dropped (its rows and state are the next admission's
+        to overwrite: everything later is queued behind it). While a step
+        is in flight ``admit`` queues the one after it behind its
+        prefill, before it waits for the first token, so the device
+        goes from a prefill into a decode step and not into the host's
+        turn-around; the admitted slot joins the step after that one. A
+        later call without ``ahead`` commits the oldest step in flight
+        and dispatches nothing. Ignored with a verify view (drafts need
+        the committed history) and under a mesh."""
         if self._cb_verify is not None:
-            return self._step_verify(live)
+            live = np.flatnonzero(self._active)
+            return self._step_verify(live) if live.size else []
         if (self.DECODE,) not in self._warmed:
             smetrics.count_compile(self.name, f"steady_{self.DECODE}")
             self._warmed.add((self.DECODE,))
+        if not self._flights and not self._launch_step():
+            return []
+        if ahead and self.dist is None and len(self._flights) < 2:
+            self._launch_step()
+        return self._commit_step(self._flights.popleft())
+
+    def _token_feeds(self) -> Dict:
+        """The decode step's token feeds. Without a mesh: the host's
+        last tokens, the newest dispatch's output (on the device), and
+        which to take per slot — the host's for every slot that the
+        newest step in flight (dispatched, uncommitted) did not run or
+        no longer owns, all of them when nothing is in flight; the
+        executable merges them (``_merge_tokens``), so no dispatch is
+        spent on it and the step has one signature. Under a mesh only
+        the host's: nothing runs ahead there."""
+        feeds = {"tok": self._tok[:, None, None]}
+        if self.dist is not None:
+            return feeds
+        if self._last_out is None:
+            # before the first dispatch: zeros, placed as an output will
+            # be (a committed array in an uncommitted one's place is
+            # another signature to ``jit``; warmup dispatches twice, so
+            # a real output has taken this one's place either way)
+            import jax
+            like = self.scope.find_var(self._cb_decode.sig.state_names[0])
+            zeros = np.zeros((self.n_slots, 1), np.int32)
+            self._last_out = jax.device_put(
+                zeros, next(iter(like.devices()))
+                if getattr(like, "committed", False) else None)
+        feeds["tok_prev"] = self._last_out
+        if self._flights:
+            after = self._flights[-1]
+            feeds["tok_use_host"] = ~after.ran | (self._epoch != after.epoch)
+        else:
+            feeds["tok_use_host"] = np.ones(self.n_slots, bool)
+        return feeds
+
+    def _run_unfetched(self, cb, aot_key, feeds) -> Tuple:
+        """A dispatch through ``_run`` whose output stays on the device:
+        what ``_fetch`` takes."""
+        self._fetch_later = True
+        try:
+            return self._run(cb, aot_key, feeds)
+        finally:
+            self._fetch_later = False
+
+    def _dispatch_decode(self, feeds) -> Tuple:
+        """The decode step, unfetched; its tokens, still on the device,
+        are the next step's token feed."""
+        launched = self._run_unfetched(self._cb_decode, (self.DECODE,),
+                                       feeds)
+        self._last_out = launched[0]
+        return launched
+
+    def _launch_step(self) -> bool:
+        """Dispatch one decode step, behind those in flight, over the
+        slots that still have a token to make (False when there is
+        none) and advance their counts; nothing is fetched."""
+        ran = self._active & ~self._closing
+        if not ran.any():
+            return False
         # one tracing check per step; timestamps only when on
         trace_on = tctx.active()
         t0 = time.perf_counter() if trace_on else 0.0
@@ -1217,28 +1468,46 @@ class SlotGenerativeModel:
         if trace_on:
             tctx.record_span("serving.decode.feeds", t0,
                              time.perf_counter())
-        out = self._run(self._cb_decode, (self.DECODE,), feeds)
+        out, kind, plan = self._dispatch_decode(feeds)
+        slots = np.flatnonzero(ran)
+        self._gen_count[slots] += 1
+        last = self._gen_count[slots] >= self._budget[slots]
+        self._closing[slots[last]] = True
+        self._decode_steps_done += 1
+        if self._count_vars and \
+                self._decode_steps_done % self.COUNT_SNAPSHOT_STEPS == 0:
+            self._snapshot_counts()
+        self._flights.append(_Flight(out, kind, plan, slots, ran,
+                                     self._epoch.copy(), last))
+        return True
+
+    def _commit_step(self, flight: _Flight
+                     ) -> List[Tuple[int, int, Optional[str]]]:
+        """Fetch a dispatched step's tokens (blocks until the device has
+        run it) and commit them: the events, the releases."""
+        out = self._fetch(flight.out, flight.kind,
+                          flight.plan).reshape(-1)
+        trace_on = tctx.active()
         t0 = time.perf_counter() if trace_on else 0.0
-        out = np.asarray(out).reshape(-1)
         self._m_decode_steps.inc()
-        self._m_tokens.inc(int(live.size))
         events = []
-        for slot in live:
-            slot = int(slot)
+        for slot, last in zip(flight.slots.tolist(), flight.last.tolist()):
+            if self._epoch[slot] != flight.epoch[slot]:
+                continue          # released since the dispatch
             tok = int(out[slot])
             self._tok[slot] = tok
-            self._gen_count[slot] += 1
             self._hist[slot].append(tok)
             self._m_tokens_per_step.observe(1.0)
             eos = self._eos[slot]
             done = None
             if eos is not None and tok == eos:
                 done = "eos"
-            elif self._gen_count[slot] >= self._budget[slot]:
+            elif last:
                 done = "max_new"
             if done:
                 self.release(slot, cause=done)
             events.append((slot, tok, done))
+        self._m_tokens.inc(len(events))
         self._m_occupancy.set(self.occupancy())
         if trace_on:
             tctx.record_span("serving.decode.commit", t0,
@@ -1341,6 +1610,8 @@ class SlotGenerativeModel:
         self.pool.release(slot)
         self._table[slot, :] = self.n_pages
         self._active[slot] = False
+        self._closing[slot] = False
+        self._epoch[slot] += 1
         self._eos[slot] = None
         smetrics.SLOT_EVICTIONS.labels(model=self.name,
                                        cause=cause).inc()
@@ -1351,6 +1622,8 @@ class SlotGenerativeModel:
         self._table[:] = self.n_pages
         self._pending_rows = None
         self._active[:] = False
+        self._closing[:] = False
+        self._flights.clear()
         self._gen_count[:] = 0
         self._eos = [None] * self.n_slots
         self._m_occupancy.set(0.0)

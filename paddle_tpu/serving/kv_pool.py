@@ -44,6 +44,8 @@ internal locking.
 
 from __future__ import annotations
 
+import heapq
+
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from paddle_tpu.serving import metrics as smetrics
@@ -166,32 +168,44 @@ class PagePool:
             self.shared_count())
 
     # -- eviction ---------------------------------------------------------
-    def _evict_one(self, cause: str) -> bool:
-        """Reclaim the LRU refcount-0 LEAF (a refcount-0 node's whole
-        subtree is refcount-0 — any slot holding a child holds the
-        parent — so leaf-first reclaim reaches every cached page)."""
-        victim: Optional[_Node] = None
-        for nd in self._iter_nodes():
-            if nd.refs == 0 and not nd.children:
-                if victim is None or nd.last_use < victim.last_use:
-                    victim = nd
-        if victim is None:
-            return False
-        del victim.parent.children[victim.key]
-        self._free.append(victim.page)
-        self._cached -= 1
-        if self.model:
-            smetrics.KV_PAGE_EVICTIONS.labels(
-                model=self.model, cause=cause).inc()
-        return True
+    def _evict(self, count: int, cause: str) -> int:
+        """Reclaim up to ``count`` pages, the LRU refcount-0 LEAF first
+        (a refcount-0 node's whole subtree is refcount-0 — any slot
+        holding a child holds the parent — so leaf-first reclaim reaches
+        every cached page). ONE walk of the tree whatever ``count``: the
+        leaves go into a heap by last use, and a parent joins it when
+        its last child goes. (A walk per page made an admission into a
+        pool full of cached prompts a 150 ms stall on the chip's host:
+        PERF.md, PR 31.)"""
+        heap = [(nd.last_use, i, nd)
+                for i, nd in enumerate(self._iter_nodes())
+                if nd.refs == 0 and not nd.children]
+        heapq.heapify(heap)
+        tie, done = len(heap), 0
+        evictions = smetrics.KV_PAGE_EVICTIONS.labels(
+            model=self.model, cause=cause) if self.model else None
+        while done < count and heap:
+            _use, _i, victim = heapq.heappop(heap)
+            parent = victim.parent
+            del parent.children[victim.key]
+            self._free.append(victim.page)
+            self._cached -= 1
+            done += 1
+            if evictions is not None:
+                evictions.inc()
+            if parent is not self._root and parent.refs == 0 \
+                    and not parent.children:
+                tie += 1
+                heapq.heappush(heap, (parent.last_use, tie, parent))
+        return done
 
     def _take_pages(self, need: int) -> List[int]:
-        while len(self._free) < need:
-            if not self._evict_one("capacity"):
-                raise PagesExhaustedError(
-                    f"model {self.model!r}: need {need} pages, "
-                    f"{len(self._free)} free and nothing evictable "
-                    f"({self.n_pages} total)")
+        short = need - len(self._free)
+        if short > 0 and self._evict(short, "capacity") < short:
+            raise PagesExhaustedError(
+                f"model {self.model!r}: need {need} pages, "
+                f"{len(self._free)} free and nothing evictable "
+                f"({self.n_pages} total)")
         return [self._free.pop() for _ in range(need)]
 
     # -- lease lifecycle --------------------------------------------------

@@ -154,6 +154,20 @@ KV_PAGE_EVICTIONS = _metrics.counter(
     "capacity (LRU reclaim to satisfy an admission) | reset (engine "
     "reset/warmup scrub)", labelnames=("model", "cause"))
 
+# -- hybrid models: recurrent state and expert layers -------------------
+RECURRENT_STATE_BYTES = _metrics.gauge(
+    "paddle_recurrent_state_bytes",
+    "Bytes of per-slot recurrent and conv state a hybrid model keeps "
+    "beside its KV pages (static: fixed-size per slot, n_slots of them; "
+    "0 for a model with none)", labelnames=("model",))
+MOE_EXPERT_TOKENS = _metrics.counter(
+    "paddle_moe_expert_tokens_total",
+    "Tokens the decode steps routed to each expert this program holds, "
+    "counted on the device and brought here by "
+    "SlotGenerativeModel.expert_token_counts() (off the step's path: "
+    "current as of the last call)",
+    labelnames=("model", "layer", "expert"))
+
 # -- router families (serving/router.py) -------------------------------
 # ``replica`` is the router-assigned slot index ("0".."N-1") — bounded
 # by the pool size, stable across restarts of the replica in that slot.

@@ -593,7 +593,7 @@ class _SlotHostedModel(_HostedModel):
                     continue
                 t_step = time.perf_counter() if trace_on else 0.0
                 try:
-                    events = engine.step()
+                    events = engine.step(ahead=True)
                 except BaseException as e:
                     if self._is_fatal_oom(e):
                         self._fatal_oom(e)  # never returns

@@ -322,3 +322,83 @@ def test_paged_pool_is_row_major_at_rest_on_v5e(chip, monkeypatch, op,
         for n in gathers:                                       # (c)
             assert not _comes_from(ops, n, "copy"), ops[n][3][:200]
     assert not copies, [ops[n][3][:160] for n in copies]    # (b), (c)
+
+
+# ---------------------------------------------------------------------------
+# the hybrid sparse block (PR 31): the whole decode step of
+# solar_open2_250b_ep8_d4 at the cell's sizes, as the chip compiles it
+# ---------------------------------------------------------------------------
+
+def test_hybrid_decode_step_compiles_for_v5e(chip, monkeypatch):
+    """The decode step of the benchmark's hybrid configuration — 128
+    slots, bf16, 4 layers (gqa, kda, kda, kda), 40 of 320 experts — for a
+    described v5e: it fits one chip's 16 GB with room for the gathered
+    caches; it holds the two page gathers and, per expert layer, the
+    dense gate and up products over all tokens (results
+    ``[128,40,1280]``, the shape the benchmark's reader selects) with no
+    copy of the experts' weights; each KDA layer reduces over its state
+    (``f32[128,64,2,128]``, the shape the reader selects) and updates
+    it in one more pass — at most two reads a layer — and no ``copy``
+    or ``transpose`` of a state's or a pool's size is in it;
+    the recurrent state is donated and aliased in place."""
+    import json
+    import os
+    import numpy as np
+    from paddle_tpu import serving
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.ops import pallas as pk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "solar_open2_250b_ep8_d4.json")) as f:
+        cfg = json.load(f)
+    build = cfg["build"]
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    programs = T.build_decoder_lm_programs(
+        name="lm", modes=T.slot_modes(cfg["kv_layout"]),
+        kv_codec=cfg["kv_codec"],
+        **{**build, "prompt_buckets": tuple(build["prompt_buckets"]),
+           "layer_kinds": tuple(build["layer_kinds"])})
+    eng = serving.make_slot_model("lm", programs, init=False)
+    cb = eng._cb_decode
+    gvars = programs["decode_paged"][0].desc.global_block.vars
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+    state = {n: struct(gvars[n].shape, gvars[n].dtype)
+             for n in cb.sig.state_names}
+    consts = {n: struct(gvars[n].shape, gvars[n].dtype)
+              for n in cb.sig.const_names}
+    feeds = {k: struct(v.shape, I32 if v.dtype == np.int64 else v.dtype)
+             for k, v in eng._decode_feeds().items()}
+    compiled = cb.fn.lower(state, consts, feeds,
+                           struct((), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    # the pools and the recurrent state are updated in place
+    state_bytes = 3 * 128 * 64 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes > state_bytes + 2 * 16384 * 16 * 1024 * 2
+    text = compiled.as_text()
+    ops = _hlo_ops(text)
+    entry = text[text.index("ENTRY "):]
+    assert entry.count("gather_pages") >= 2
+    # 128 tokens are under expert_ffn.DENSE_MAX_TOKENS: every held
+    # expert multiplies every token (results [128, 40, 1280], the shape
+    # the benchmark's reader selects) and no grouped product is in it
+    assert not [n for n in ops if n.startswith("ragged-dot")]
+    assert len([line for _o, _c, _a, line in ops.values()
+                if "[128,40,1280]" in line.split(" = ")[1].split(" ")[0]
+                and "fusion(" in line]) >= 8
+    # nor a copy of an expert layer's weights (40 x 4096 x 1280)
+    assert not [line for opcode, count, _a, line in ops.values()
+                if opcode == "copy" and count >= 40 * 4096 * 1280]
+    big = 128 * 64 * 128 * 128          # a state; a pool is half of it
+    assert [line for _o, _c, _a, line in ops.values()
+            if "= f32[128,64,2,128]" in line and "fusion(" in line]
+    # a layer's state is read by its reduction and by its update, not more
+    readers = [n for n, (opcode, _c, args, _l) in ops.items()
+               if opcode == "fusion"
+               and any(ops.get(a, ("", 0))[1] == big for a in args)]
+    assert 3 <= len(readers) <= 6, readers
+    assert not [line for opcode, count, _a, line in ops.values()
+                if opcode in ("copy", "transpose") and count >= big // 2]

@@ -1096,3 +1096,170 @@ def test_spec_shared_prefix_refcount_safety():
     np.testing.assert_array_equal(toks[sb], ref["b"])
     assert m.pool.page_refs(shared_page) == 0    # cached, resident
     m.reset()
+
+
+# ---------------------------------------------------------------------------
+# decode steps dispatched ahead (step(ahead=True): PR 31)
+# ---------------------------------------------------------------------------
+
+_AHEAD_PROMPTS = ([3, 9, 4, 1, 7], [8, 2], [6, 6, 1, 5, 2, 9, 4], [7, 3, 3])
+_AHEAD_BUDGETS = (6, 3, 8, 5)
+
+
+def _drive(m, ahead, eos=None, late=True):
+    """Three requests at once, a fourth admitted into the first slot
+    that frees: every event in order, with the step it came in."""
+    m.reset()
+    for prompt, budget in zip(_AHEAD_PROMPTS[:3], _AHEAD_BUDGETS):
+        m.admit(prompt, max_new=budget, eos_id=eos)
+    events, step = [], 0
+    while m.active_count():
+        step += 1
+        for ev in m.step(ahead=ahead):
+            events.append((step,) + ev)
+        if late and m.free_count() and step < 50:
+            m.admit(_AHEAD_PROMPTS[3], max_new=_AHEAD_BUDGETS[3],
+                    eos_id=eos)
+            late = False
+    assert not m._flights or not m.active_count()
+    assert m.pool.free_count() + m.pool.cached_count() == m.n_pages
+    return events
+
+
+def test_steps_dispatched_ahead_emit_the_same_events():
+    """``step(ahead=True)`` queues the next decode step before this
+    one's tokens are fetched: the events are those of one step at a
+    time, to the token, the cause, the order and the step — budgets
+    that end at different steps."""
+    m = _paged_lm()
+    plain = _drive(m, ahead=False, late=False)
+    assert [e for e in plain if e[3]], "some request ends"
+    assert _drive(m, ahead=True, late=False) == plain
+
+    def streams(events):
+        out = {}
+        for _step, slot, tok, done in events:
+            out.setdefault(slot, []).append((tok, done))
+        return out
+    # a request admitted between two steps joins the step after the one
+    # in flight: a step later, the same stream
+    late = _drive(m, ahead=False)
+    assert len(late) == len(plain) + _AHEAD_BUDGETS[3] - 1
+    assert streams(_drive(m, ahead=True)) == streams(late)
+
+
+def test_a_step_ahead_drops_the_token_after_an_eos():
+    """EOS is seen a step late when steps run ahead: the slot has run
+    one step more than it needed, and that token is nobody's — the
+    events end at the EOS as they do one step at a time."""
+    m = _paged_lm()
+    plain = _drive(m, ahead=False, late=False)
+    # a token some request emits mid-way, as everybody's EOS
+    eos = next(tok for _step, _slot, tok, done in plain[3:] if not done)
+    with_eos = _drive(m, ahead=False, eos=eos, late=False)
+    assert [e for e in with_eos if e[3] == "eos"]
+    assert len(with_eos) < len(plain)
+    assert _drive(m, ahead=True, eos=eos, late=False) == with_eos
+
+
+def test_a_release_drops_the_step_in_flight_for_that_slot():
+    """A cancel between two steps ahead: the step in flight ran the
+    slot, the slot has a new owner when its tokens come back, and the
+    new owner's stream is the one it has alone."""
+    m = _paged_lm()
+    m.reset()
+    alone = m.generate([_AHEAD_PROMPTS[1]], max_new=4)[0]
+    m.reset()
+    a, _t, _d = m.admit(_AHEAD_PROMPTS[0], max_new=8)
+    b, _t, _d = m.admit(_AHEAD_PROMPTS[2], max_new=8)
+    m.step(ahead=True)                   # a second step is in flight
+    assert len(m._flights) == 1 and a in m._flights[0].slots
+    m.release(a)                         # the server's cancel
+    a2, first, _d = m.admit(_AHEAD_PROMPTS[1], max_new=4)
+    assert a2 == a
+    # the admission queued the step after the one in flight behind its
+    # prefill, without the slot it was filling
+    assert len(m._flights) == 2 and not m._flights[1].ran[a]
+    toks = [first]
+    while m._active[a2]:
+        toks += [tok for slot, tok, _d in m.step(ahead=True) if slot == a2]
+    np.testing.assert_array_equal(toks, alone)
+    # a call without ``ahead`` commits what is in flight, dispatches
+    # nothing, and the next one is an ordinary step
+    assert len(m._flights) == 1
+    assert {s for s, _t, _d in m.step()} == {b}
+    assert not m._flights
+    m.reset()
+
+
+def test_the_token_merge_is_part_of_the_decode_executable():
+    """The step's tokens come from the previous dispatch's output where
+    the host does not know better, merged INSIDE the decode executable
+    (``CompiledBlock(feed_transform=...)``): the program and its feeds
+    are what they were (``_fingerprint`` hashes them), the engine hands
+    two entries more, and no dispatch of its own is spent on the merge
+    (the benchmark's readers count program executions a decode step)."""
+    m = _paged_lm()
+    m.reset()
+    program_feeds = set(m._cb_decode.sig.feed_names)
+    handed = set(m._decode_feeds())
+    assert handed - program_feeds == {"tok_prev", "tok_use_host"}
+    assert "tok" in program_feeds
+    # the host's token wins where the mask says so, the device's elsewhere
+    import jax.numpy as jnp
+    merged = seng._merge_tokens({
+        "tok": jnp.asarray([[[1]], [[2]], [[3]]]),
+        "tok_prev": jnp.asarray([[7], [8], [9]]),
+        "tok_use_host": jnp.asarray([True, False, True])})
+    assert set(merged) == {"tok"}
+    np.testing.assert_array_equal(np.asarray(merged["tok"]).reshape(-1),
+                                  [1, 8, 3])
+    # a step fed a stale host token for a slot the step in flight ran
+    # still reads the device's: poison the host mirror between two steps
+    plain = _drive(m, ahead=False, late=False)
+    m.reset()
+    for prompt, budget in zip(_AHEAD_PROMPTS[:3], _AHEAD_BUDGETS):
+        m.admit(prompt, max_new=budget)
+    events, step = [], 0
+    while m.active_count():
+        step += 1
+        events += [(step,) + ev for ev in m.step(ahead=True)]
+        if m._flights:
+            m._tok[m._flights[-1].slots] = 0  # the host's copy is not read
+    assert events == plain
+    m.reset()
+
+
+def test_pool_evicts_many_pages_in_one_walk_lru_leaf_first(monkeypatch):
+    """An admission that needs many pages of a pool full of cached
+    prompts walks the radix tree ONCE (a walk a page was a 150 ms stall
+    of the scheduler on the chip: PERF.md, PR 31), and still reclaims
+    the least recently used refcount-0 LEAF first, a parent only once
+    its last child is gone."""
+    p = kv_pool.PagePool(12, 2, model="kvp_walk")
+    old = [1, 2, 3, 4, 5, 6]                 # a chain of 3 pages
+    mid = [1, 2, 9, 9]                       # shares the first, adds one
+    new = [7, 7, 8, 8, 6, 6]                 # a chain of its own
+    for slot, toks in enumerate((old, mid, new)):
+        p.acquire(slot, toks, span=len(toks) // 2 + 1)
+    for slot in (0, 1, 2):
+        p.release(slot)
+    assert p.cached_count() == 7 and p.free_count() == 5
+    walks = []
+    real = p._iter_nodes
+    monkeypatch.setattr(p, "_iter_nodes",
+                        lambda: walks.append(1) or real())
+    before = len(walks)
+    p.acquire(0, [5, 5], span=5)             # what is free covers it
+    p.release(0)
+    plain = len(walks) - before              # the gauges' own walks
+    assert p.free_count() == 4               # [5, 5] stays cached too
+    before = len(walks)
+    _pages, n_shared = p.acquire(0, [6, 6], span=10)   # 6 to reclaim
+    assert n_shared == 0 and len(walks) - before <= plain // 2 + 1
+    # gone, the oldest leaf first and a parent once its last child is:
+    # old's tail two (used at 1), mid's own page and then the first
+    # page they shared (used at 2), new's tail two (used at 3)
+    assert p.cached_count() == 2             # new's head, and [5, 5]
+    assert p.acquire(1, [5, 5], span=1)[1] == 1
+    p.reset()

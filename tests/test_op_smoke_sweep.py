@@ -231,6 +231,36 @@ spec("token_sample",
      {"Logits": [f(2, 16)], "Temperature": [f(2, 1, lo=0.0, hi=1.0)],
       "TopK": [ints(2, 1, hi=5)], "Seed": [ints(2, 1, hi=100, seed=4)],
       "StepIdx": [ints(2, 1, hi=4, seed=5)]})
+# the hybrid sparse block (ops/kda.py, ops/expert_ffn.py): two slots,
+# two heads of four channels, a conv of four taps, rank-3 gates; two
+# held experts (1 and 2) of a router six wide
+_KDA = {"Wq": [f(8, 8, seed=2)], "Wk": [f(8, 8, seed=3)],
+        "Wv": [f(8, 8, seed=4)], "Wo": [f(8, 8, seed=5)],
+        "ConvW": [f(4, 24, seed=6)], "ALog": [f(2, seed=7)],
+        "DtBias": [f(8, seed=8)], "WaDown": [f(8, 3, seed=9)],
+        "WaUp": [f(3, 8, seed=10)], "WBeta": [f(8, 2, seed=11)],
+        "WgDown": [f(8, 3, seed=12)], "WgUp": [f(3, 8, seed=13)],
+        "ONorm": [pos(4)], "State": [f(2, 2, 4, 4, seed=14)],
+        "Conv": [f(2, 3, 24, seed=15)]}
+spec("kda_decode", {"X": [f(2, 1, 8)], **_KDA,
+                    "Active": [ints(2, 1, hi=2, seed=3)]},
+     {"n_head": 2, "head_dim": 4})
+spec("kda_prefill", {"X": [f(1, 5, 8)], **_KDA,
+                     "SeqLen": [lens(3).reshape(1, 1)],
+                     "Slot": [lens(1).reshape(1, 1)]},
+     {"n_head": 2, "head_dim": 4})
+spec("expert_ffn_held",
+     {"X": [f(1, 4, 8)], "RouterW": [f(8, 6, seed=1)],
+      "WGate": [f(2, 8, 5, seed=2)], "WUp": [f(2, 8, 5, seed=3)],
+      "WDown": [f(2, 5, 8, seed=4)], "SGate": [f(8, 5, seed=5)],
+      "SUp": [f(8, 5, seed=6)], "SDown": [f(5, 8, seed=7)],
+      "SeqLen": [lens(3).reshape(1, 1)]},
+     {"top_k": 2, "held_start": 1})
+spec("rms_norm", {"X": [f(2, 3)], "Scale": [pos(3)]})
+spec("dense", {"X": [f(2, 3)], "W": [f(3, 4, seed=1)]},
+     {"out_dtype": "float32"})
+spec("hash_normal_random", {},
+     {"shape": [2, 3], "dtype": "float32", "std": 0.5})
 spec("batch_norm", {"X": [f(2, 3, 4, 4)], "Scale": [pos(3)],
                     "Bias": [f(3, seed=1)], "Mean": [f(3, seed=2)],
                     "Variance": [pos(3, seed=3)]}, {"is_test": False})
