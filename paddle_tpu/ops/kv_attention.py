@@ -612,12 +612,42 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
 
 
+def _kth_largest(x, k):
+    """x [B,V] float32, k [B] int in [1,V] -> [B] float32: the k-th
+    largest value of each row, EXACTLY what ``-jnp.sort(-x)[:, k-1]``
+    reads, found by selection and not by sorting: every float maps to
+    the uint32 whose unsigned order is the sort's order (-0.0 beside
+    0.0, NaN below everything), and the k-th largest key is built bit
+    by bit, most significant first — a bit stays set where at least k
+    keys reach the candidate. 32 compare-and-count passes over [B,V]
+    whatever k is; a full-vocabulary sort was 2.2-2.8 ms of every
+    decode step on a v5e (PERF.md, PR 32)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    top = jnp.uint32(0x80000000)
+    key = jnp.where(bits < top, bits | top, ~bits)
+    key = jnp.where(x == 0, top, key)
+    key = jnp.where(jnp.isnan(x), jnp.uint32(0), key)
+
+    def one_bit(i, found):
+        cand = found | (top >> i.astype(jnp.uint32))
+        n = jnp.sum(key >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, found)
+
+    found = jax.lax.fori_loop(0, 32, one_bit,
+                              jnp.zeros(x.shape[:1], jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(found >= top, found ^ top, ~found), jnp.float32)
+
+
 @register_op("token_sample", no_grad=True,
              ref="TPU-native serving op: on-device next-token selection "
                  "— greedy argmax or temperature/top-k Gumbel sampling "
                  "keyed ONLY by the per-request seed + token index "
                  "(restart-reproducible; independent of the framework "
-                 "step seed)")
+                 "step seed). A batch whose rows are all greedy runs "
+                 "the argmax alone (a device-side conditional on the "
+                 "op's own inputs); the top-k threshold is an exact "
+                 "selection, never a sort")
 def _token_sample(ctx, ins, attrs):
     """Logits [B,V], Temperature [B,1] float, TopK [B,1] int
     (<=0: no top-k filter; 1: argmax), Seed [B,1] int (per-request),
@@ -630,7 +660,14 @@ def _token_sample(ctx, ins, attrs):
     index) — the same counter-based idiom as the flash kernels'
     hash_keep_mask, so a row's noise is independent of the batch shape
     and of which slot it occupies (vmapped jax.random streams are NOT:
-    they change with the batch)."""
+    they change with the batch).
+
+    The sampled branch runs only where some row of the batch samples
+    (``jax.lax.cond`` on the op's own Temperature and TopK, inside the
+    caller's executable: no flag, no second op), so an all-greedy decode
+    step or prefill pays for one argmax. Where it runs, the top-k set
+    is exact: the threshold is the k-th largest scaled logit
+    (``_kth_largest``) and every logit that TIES it is kept."""
     logits = first(ins, "Logits")
     temp = jnp.asarray(first(ins, "Temperature")).reshape(-1)\
         .astype(jnp.float32)
@@ -642,29 +679,32 @@ def _token_sample(ctx, ins, attrs):
     lg = jnp.asarray(logits).reshape(-1, v).astype(jnp.float32)
 
     greedy = jnp.argmax(lg, axis=-1)
-
-    scaled = lg / jnp.maximum(temp, 1e-6)[:, None]
-    k = jnp.clip(topk, 1, v)
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
-    # ties AT the kth value are all kept (documented; deterministic)
-    keep = (scaled >= kth) | (topk <= 0)[:, None]
-    masked = jnp.where(keep, scaled, -jnp.inf)
-
-    j = jnp.arange(v, dtype=jnp.uint32)[None, :]
-    x = (j * jnp.uint32(0x9E3779B9)
-         ^ seed.astype(jnp.uint32)[:, None] * jnp.uint32(0x85EBCA6B))
-    x = x ^ (stepi.astype(jnp.uint32)[:, None] * jnp.uint32(0x27D4EB2F))
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    # uniform in (0, 1) from the 24 high bits; never exactly 0 or 1
-    u = ((x >> jnp.uint32(8)).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
-    noise = -jnp.log(-jnp.log(u))
-
-    sampled = jnp.argmax(masked + noise, axis=-1)
     use_greedy = (temp <= 0.0) | (topk == 1)
-    out = jnp.where(use_greedy, greedy, sampled).astype(jnp.int64)
-    return {"Out": [out[:, None]]}
+
+    def sample():
+        scaled = lg / jnp.maximum(temp, 1e-6)[:, None]
+        kth = _kth_largest(scaled, jnp.clip(topk, 1, v))[:, None]
+        # ties AT the kth value are all kept (documented; deterministic)
+        keep = (scaled >= kth) | (topk <= 0)[:, None]
+        masked = jnp.where(keep, scaled, -jnp.inf)
+
+        j = jnp.arange(v, dtype=jnp.uint32)[None, :]
+        x = (j * jnp.uint32(0x9E3779B9)
+             ^ seed.astype(jnp.uint32)[:, None] * jnp.uint32(0x85EBCA6B))
+        x = x ^ (stepi.astype(jnp.uint32)[:, None]
+                 * jnp.uint32(0x27D4EB2F))
+        x = x ^ (x >> 16)
+        x = x * jnp.uint32(0x85EBCA6B)
+        x = x ^ (x >> 13)
+        x = x * jnp.uint32(0xC2B2AE35)
+        x = x ^ (x >> 16)
+        # uniform in (0, 1) from the 24 high bits; never exactly 0 or 1
+        u = ((x >> jnp.uint32(8)).astype(jnp.float32) + 0.5) \
+            * (1.0 / (1 << 24))
+        noise = -jnp.log(-jnp.log(u))
+
+        sampled = jnp.argmax(masked + noise, axis=-1)
+        return jnp.where(use_greedy, greedy, sampled)
+
+    out = jax.lax.cond(jnp.any(~use_greedy), sample, lambda: greedy)
+    return {"Out": [out.astype(jnp.int64)[:, None]]}

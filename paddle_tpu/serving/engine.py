@@ -880,6 +880,8 @@ class SlotGenerativeModel:
         self._m_admissions = smetrics.SLOT_ADMISSIONS.labels(model=name)
         self._m_tokens = smetrics.TOKENS_GENERATED.labels(model=name)
         self._m_decode_steps = smetrics.DECODE_STEPS.labels(model=name)
+        self._m_sampling_steps = smetrics.SAMPLING_STEPS.labels(
+            model=name)
         self._m_tokens_per_step = smetrics.TOKENS_PER_STEP.labels(
             model=name)
         self._m_occupancy = smetrics.SLOT_OCCUPANCY.labels(model=name)
@@ -1454,6 +1456,14 @@ class SlotGenerativeModel:
         self._last_out = launched[0]
         return launched
 
+    def _count_sampling_step(self):
+        """Count the step just dispatched if token_sample ran its
+        sampled branch in it: some row fed temperature > 0 with top_k
+        != 1. Released slots feed zeros, so that is a LIVE request; the
+        host's mirror answers, nothing is read from the device."""
+        if ((self._temp > 0.0) & (self._topk != 1)).any():
+            self._m_sampling_steps.inc()
+
     def _launch_step(self) -> bool:
         """Dispatch one decode step, behind those in flight, over the
         slots that still have a token to make (False when there is
@@ -1469,6 +1479,7 @@ class SlotGenerativeModel:
             tctx.record_span("serving.decode.feeds", t0,
                              time.perf_counter())
         out, kind, plan = self._dispatch_decode(feeds)
+        self._count_sampling_step()
         slots = np.flatnonzero(ran)
         self._gen_count[slots] += 1
         last = self._gen_count[slots] >= self._budget[slots]
@@ -1559,6 +1570,7 @@ class SlotGenerativeModel:
             tctx.record_span("serving.decode.feeds", t0,
                              time.perf_counter())
         out = self._run(self._cb_verify, (self.VERIFY,), feeds)
+        self._count_sampling_step()
         t0 = time.perf_counter() if trace_on else 0.0
         out = np.asarray(out).reshape(s, k1)
         self._m_decode_steps.inc()
@@ -1613,6 +1625,10 @@ class SlotGenerativeModel:
         self._closing[slot] = False
         self._epoch[slot] += 1
         self._eos[slot] = None
+        # an idle row feeds greedy: token_sample runs its sampled branch
+        # where ANY row of the batch samples, and has no Active input
+        self._temp[slot] = 0.0
+        self._topk[slot] = 0
         smetrics.SLOT_EVICTIONS.labels(model=self.name,
                                        cause=cause).inc()
         self._m_occupancy.set(self.occupancy())
@@ -1626,6 +1642,8 @@ class SlotGenerativeModel:
         self._flights.clear()
         self._gen_count[:] = 0
         self._eos = [None] * self.n_slots
+        self._temp[:] = 0.0
+        self._topk[:] = 0
         self._m_occupancy.set(0.0)
 
     # -- convenience: drive the pool to completion -----------------------

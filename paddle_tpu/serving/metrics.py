@@ -73,6 +73,13 @@ TOKENS_GENERATED = _metrics.counter(
 DECODE_STEPS = _metrics.counter(
     "paddle_serving_decode_steps_total",
     "Single-token decode executable dispatches", labelnames=("model",))
+SAMPLING_STEPS = _metrics.counter(
+    "paddle_sampling_steps_total",
+    "Slot-engine decode (or verify) steps dispatched with at least one "
+    "live row that samples (temperature > 0 and top_k != 1): the steps "
+    "whose token_sample ran its sampled branch. Over "
+    "paddle_serving_decode_steps_total it is the share of steps that "
+    "paid for more than an argmax", labelnames=("model",))
 PREFILLS = _metrics.counter(
     "paddle_serving_prefills_total",
     "Prefill executable dispatches (one per generation wave, or one "

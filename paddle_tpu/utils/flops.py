@@ -240,8 +240,10 @@ def op_fwd_flops(block, op_type, inputs, outputs, attrs, batch,
         lg = ishape("Logits")
         if lg is None:
             return 0.0
-        # argmax/top-k/gumbel over [B, V]: O(B·V) comparisons; the sort
-        # dominates but stays vector-unit small next to the matmuls
+        # one argmax over [B, V] for an all-greedy batch; where a row
+        # samples, 32 compare-and-count passes (the exact top-k
+        # threshold: no sort), the hash noise and a second argmax — all
+        # O(B·V) vector-unit work, small next to the matmuls
         return float(_prod(lg))
     if op_type in ("dynamic_lstm", "dynamic_lstmp"):
         x = ishape("Input")              # [B, T, 4D] (pre-projected gates)

@@ -260,6 +260,15 @@ def _hlo_ops(text):
     return ops
 
 
+def _count_opcode(text, opcode):
+    """Instructions of ``opcode`` in a module's text, those with a
+    tuple for a result (``while``, ``conditional``, a variadic ``sort``)
+    among them, which ``_hlo_ops`` does not parse."""
+    import re
+    return len(re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = .*?[\]})] "
+                          + re.escape(opcode) + r"\(", text, re.M))
+
+
 def _comes_from(ops, name, name_prefix):
     """Does instruction ``name`` depend, through its pool-sized
     operands, on one whose name starts with ``name_prefix`` (XLA names
@@ -343,6 +352,7 @@ def test_hybrid_decode_step_compiles_for_v5e(chip, monkeypatch):
     the recurrent state is donated and aliased in place."""
     import json
     import os
+    import re
     import numpy as np
     from paddle_tpu import serving
     from paddle_tpu.models import transformer as T
@@ -402,3 +412,53 @@ def test_hybrid_decode_step_compiles_for_v5e(chip, monkeypatch):
     assert 3 <= len(readers) <= 6, readers
     assert not [line for opcode, count, _a, line in ops.values()
                 if opcode in ("copy", "transpose") and count >= big // 2]
+    # token_sample (PR 32): the sampled branch sits under a conditional,
+    # nothing sorts the vocabulary on either side of it (the step's
+    # sorts are the four routers' top 8 of 320), and it brought no
+    # kernel: the benchmark's reader takes every tpu_custom_call of a
+    # decode execution for the page gather, and the step's are the two
+    # page gathers it had
+    assert _count_opcode(text, "conditional") == 1
+    sorts = re.findall(r"= (\(.*?\)|\S+) sort\(", text)
+    assert len(sorts) == 4 and all("[128,320]" in r for r in sorts), sorts
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+# ---------------------------------------------------------------------------
+# token_sample (PR 32): what a greedy batch pays for, as the chip
+# compiles it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows,vocab", [(48, 50257), (128, 24576),
+                                        (1, 50257), (48 * 5, 50257)])
+def test_token_sample_holds_a_conditional_and_no_sort_on_v5e(chip, rows,
+                                                             vocab):
+    """token_sample at the benchmark's decode shapes ([48, 50257] of
+    gpt2_medium_d12, [128, 24576] of the hybrid cut), the batch-1
+    prefill and a verify window of 5: the sampled branch is a
+    device-side ``conditional`` (XLA does not flatten it into a select:
+    an all-greedy batch runs the argmax alone), the top-k threshold's
+    32 passes are a ``while`` inside it, and no ``sort`` and no kernel
+    is anywhere — the full-vocabulary sort was the hottest single op of
+    both gpt2 serve cells, 2.8 ms of a 27 ms decode step (PERF.md, PR
+    32)."""
+    import types
+    from paddle_tpu.core.registry import get_op
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    col = struct((rows, 1), I32)
+    ins = {"Logits": struct((rows, vocab), F32),
+           "Temperature": struct((rows, 1), F32),
+           "TopK": col, "Seed": col, "StepIdx": col}
+    text = jax.jit(lambda i: get_op("token_sample").emit(
+        types.SimpleNamespace(mesh=None), {k: [v] for k, v in i.items()},
+        {})["Out"][0]).lower(ins).compile().as_text()
+    assert _count_opcode(text, "conditional") == 1
+    assert _count_opcode(text, "while") == 1
+    assert _count_opcode(text, "sort") == 0
+    assert "tpu_custom_call" not in text
+    # the loop belongs to the sampled branch, not to the entry
+    entry = text[text.index("ENTRY "):]
+    assert _count_opcode(entry, "while") == 0
+    assert _count_opcode(entry, "conditional") == 1

@@ -610,6 +610,94 @@ def test_on_device_sampling_parity_and_restart_reproducibility():
     assert any((a != b).any() for a, b in zip(s1, s3))
 
 
+@pytest.mark.parametrize("leave", ["max_new", "eos", "cancelled", "reset"])
+def test_a_finished_sampling_slot_feeds_greedy(leave):
+    """token_sample runs its sampled branch where ANY row of the batch
+    samples and has no Active input: a slot whose sampling request left
+    must feed temperature 0 / top_k 0, or one finished sampler would
+    keep every later all-greedy step on the slow branch."""
+    sgm = _shared_slot_lm()
+    sgm.reset()
+    rng = np.random.RandomState(21)
+    prompt = rng.randint(1, 32, (5,))
+    eos = None
+    if leave == "eos":
+        (stream,) = sgm.generate([prompt], max_new=4, temperature=0.8,
+                                 top_k=5, seeds=[77])
+        eos = int(stream[1])
+    slot, _first, done = sgm.admit(prompt, seed=77, temperature=0.8,
+                                   top_k=5, max_new=4, eos_id=eos)
+    assert done is None
+    feeds = sgm._decode_feeds()
+    assert feeds["temperature"][slot, 0] == np.float32(0.8)
+    assert feeds["top_k"][slot, 0] == 5
+    if leave == "cancelled":
+        sgm.release(slot)
+    elif leave == "reset":
+        sgm.reset()
+    else:
+        while sgm.active_count():
+            events = sgm.step()
+        assert events[-1][2] == leave
+    assert sgm.active_count() == 0
+    feeds = sgm._decode_feeds()
+    assert not feeds["temperature"].any() and not feeds["top_k"].any()
+
+
+@pytest.mark.parametrize("ahead", [False, True])
+def test_sampling_steps_counter_counts_the_steps_that_sampled(ahead):
+    """paddle_sampling_steps_total: one per decode step whose feeds
+    carried a sampling row (what token_sample's conditional sees), none
+    for greedy traffic; over paddle_serving_decode_steps_total it is
+    the share of steps that paid for more than an argmax."""
+    sgm = _shared_slot_lm()
+    sgm.reset()
+    sampling = smetrics.SAMPLING_STEPS.labels(model=sgm.name)
+    steps = smetrics.DECODE_STEPS.labels(model=sgm.name)
+    rng = np.random.RandomState(22)
+    prompts = [rng.randint(1, 32, (5,)) for _ in range(3)]
+
+    fed = []                     # per dispatch: did a row sample?
+    real = sgm._dispatch_decode
+
+    def spy(feeds):
+        fed.append(bool(((feeds["temperature"] > 0)
+                         & (feeds["top_k"] != 1)).any()))
+        return real(feeds)
+
+    def drive():
+        n0, s0 = steps.value, sampling.value
+        del fed[:]
+        while sgm.active_count():
+            sgm.step(ahead=ahead)
+        assert steps.value - n0 == len(fed)
+        return sampling.value - s0
+
+    sgm._dispatch_decode = spy
+    try:
+        # greedy traffic, by temperature and by top_k == 1: never
+        sgm.admit(prompts[0], max_new=6)
+        sgm.admit(prompts[1], temperature=0.9, top_k=1, max_new=5)
+        assert drive() == 0 and not any(fed)
+        # one sampler of four tokens (three decode steps) beside a
+        # greedy request of eight (seven)
+        sgm.admit(prompts[0], max_new=8)
+        sgm.admit(prompts[2], seed=5, temperature=0.8, top_k=5,
+                  max_new=4)
+        got = drive()
+        assert got == sum(fed) and len(fed) >= 7
+        if not ahead:
+            assert fed == [True] * 3 + [False] * 4
+        else:
+            # a step dispatched ahead of the sampler's last token still
+            # carried its row; the one after that does not
+            assert fed[:3] == [True] * 3 and 3 <= got <= 4
+            assert not any(fed[4:])
+    finally:
+        del sgm._dispatch_decode
+        sgm.reset()
+
+
 def test_prompt_bucket_ladder_parity_and_cost():
     """Prompt-ladder satellite: a GenerativeModel warmed over a bucket
     ladder generates the same tokens as the single-bucket engine, and
