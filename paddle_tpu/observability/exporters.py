@@ -270,6 +270,9 @@ def _preregister_catalog():
                 # which tier the paged K/V gather was lowered to
                 # (paddle_kv_gather_lowered_total{path})
                 "paddle_tpu.ops.kv_attention",
+                # which way a latent-attention decode layer attends
+                # (paddle_mla_decode_lowered_total{path})
+                "paddle_tpu.ops.mla",
                 "paddle_tpu.distributed.sharded_table"):
         try:
             importlib.import_module(mod)

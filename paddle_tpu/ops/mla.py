@@ -36,15 +36,28 @@ indexer keys, which the scoring reads alone.
   ``index_topk``, the indexer's selection — found by threshold
   (``kv_attention._kth_largest`` and a tie count), never by sorting —
   and scatters latent rows and indexer keys through ``Rows``.
-- ``mla_decode_paged`` writes this step's two rows, scores every LIVE
-  row of every slot against the gathered indexer keys (rows between a
-  prompt's end and its bucket are padding: never scored, never
-  selected), takes the best ``index_topk`` by ``jax.lax.top_k``,
-  gathers those latent rows alone and attends ABSORBED — ``q~_i =
-  W_UK,i^T q_nope,i`` against ``cKV`` directly, ``o_i = W_UV,i sum_s a
-  cKV_s`` — through ``kv_attention._decode_contract`` with one KV head
-  of the plane's width under all H query rows: the same numbers as the
-  expanded way, one gather of one plane.
+- ``mla_decode_paged`` writes this step's two rows and scores every
+  LIVE row of every slot against the gathered indexer keys (rows
+  between a prompt's end and its bucket are padding: never scored,
+  never selected). It attends ABSORBED — ``q~_i = W_UK,i^T q_nope,i``
+  against ``cKV`` directly, ``o_i = W_UV,i sum_s a cKV_s``: one key
+  head of the plane's width under all H query rows, the same numbers as
+  the expanded way — in one of two ways, chosen at lowering from what
+  the op observes (``attends_in_place``; counted in
+  ``paddle_mla_decode_lowered_total{path}``):
+
+  * ``pages`` — on the chip, while the cache is at most
+    ``ATTEND_PAGES_MAX_RATIO`` x index_topk rows long: the selection is a
+    MASK (``select_topk``, the prefill's thresholds: no sort, no
+    indices) and ``ops/pallas/paged_attention.py:attend_pages`` reads
+    each slot's live pages of the latent plane in place under it, a page
+    a DMA into VMEM, the softmax online in the kernel. It costs by the
+    live rows; nothing of the plane is copied to an array. ``Selected``
+    (what a check reads) is read off that mask.
+  * ``rows`` — everywhere else (off the chip, under a mesh, a longer
+    cache): ``jax.lax.top_k``, the chosen rows' pages by comparison with
+    the table, XLA's gather of those index_topk rows alone, and
+    ``kv_attention._decode_contract`` over them.
 
 Precision as the other serving ops: products multiply in the storage
 dtype with float32 accumulation; norms, rotation, indexer scores,
@@ -57,6 +70,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
+from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.ops import kv_attention as _kv
 from paddle_tpu.ops.math_ops import dense
 
@@ -66,6 +80,31 @@ IK_NORM_EPS = 1e-6
 # queries a block of the prefill's attention: the scores of one block
 # are [H, QUERY_BLOCK, T] float32 (0.5 GB at 64 heads and 8192 tokens)
 QUERY_BLOCK = 256
+
+# A decode step attends the latent pages IN PLACE (``attend_pages``: the
+# slot's live pages read whole under the selection's mask) while the
+# cache is at most this many times index_topk rows long, and gathers the
+# index_topk selected rows past it. The kernel costs by a slot's LIVE
+# rows (0.045 + 0.072 ms a thousand rows a slot), the other way by
+# index_topk plus a sort of the whole table (1.1 + 0.021 ms a thousand
+# rows of cache). One layer, 32 slots, index_topk 2048, bf16[640] rows on
+# the v5e, what follows the scores, ms (my chip run, PR 34):
+#   cache 12 288 ( 6 x), 7 140 live rows a slot: in place 0.58, rows 1.37
+#   cache 24 576 (12 x), 14 128 live:             in place 1.04, rows 1.72
+#   cache 32 768 (16 x), 18 991 live:             in place 1.37, rows 1.79
+# The length is known at lowering, the fill is not: at 12 x a cache that
+# is FULL breaks even (1.77 against 1.72) and a fuller one past it loses.
+ATTEND_PAGES_MAX_RATIO = 12
+
+# exporter-catalog family (docs/observability.md). Counts LOWERINGS, as
+# ``paddle_kv_gather_lowered_total`` does: one increment per
+# latent-attention layer each time a decode program is traced, labelled
+# with the way its geometry attends — ``pages`` (the mask and the
+# in-place kernel) or ``rows`` (indices and the row gather).
+MLA_DECODE_LOWERED = _metrics.counter(
+    "paddle_mla_decode_lowered_total",
+    "Latent-attention decode layers lowered, by path (pages|rows)",
+    labelnames=("path",))
 
 _WEIGHTS = ("Wdq", "QNorm", "Wuq", "Wdkv", "KvNorm", "Wuk", "Wuv", "Wo",
             "Wiq", "Wik", "IkScale", "IkBias", "Wiw")
@@ -232,27 +271,67 @@ def expanded_attention(q, c, kr, qi, wi, ki, w, a):
     return o.reshape(t, h * dv)
 
 
+def absorbed_query(q, w, a, dt, width):
+    """q [B, H, dn + dr] float32 with W_UK absorbed: ``[W_UK,i^T
+    q_nope,i ; q_rope,i ; zeros]`` [B, H, width] in ``dt``, what scores a
+    cached latent row directly."""
+    h, dc, dn = a["n_head"], a["kv_lora_rank"], a["qk_nope_head_dim"]
+    qt = jnp.einsum("bhd,chd->bhc", q[..., :dn].astype(dt),
+                    w["Wuk"].reshape(dc, h, dn),
+                    preferred_element_type=F32)
+    pad = width - dc - a["qk_rope_head_dim"]
+    return jnp.concatenate(
+        [qt, q[..., dn:], jnp.zeros(q.shape[:2] + (pad,), F32)],
+        axis=-1).astype(dt)
+
+
+def absorbed_context(u, w, a):
+    """The attended latent u [B, H, W] through W_UV: [B, H * dv] in u's
+    dtype."""
+    h, dc, dv = a["n_head"], a["kv_lora_rank"], a["v_head_dim"]
+    o = jnp.einsum("bhc,chv->bhv", u[..., :dc],
+                   w["Wuv"].reshape(dc, h, dv), preferred_element_type=F32)
+    return o.astype(u.dtype).reshape(u.shape[0], h * dv)
+
+
+def attention_scale(a) -> float:
+    return float(a["qk_nope_head_dim"] + a["qk_rope_head_dim"]) ** -0.5
+
+
 def absorbed_attention(q, rows, valid, w, a):
     """The decode step's way: q [B, H, dn + dr] float32, rows [B, S, W]
     the latent rows to attend ([cKV ; kR ; zeros]), valid [B, S] ->
     context [B, H * dv] in the rows' dtype. W_UK is absorbed into the
     query and W_UV applied to the attended latent, so keys and values
     are the rows as cached."""
-    b, dt = q.shape[0], rows.dtype
-    h, dc, dn, dr, dv = (a["n_head"], a["kv_lora_rank"],
-                         a["qk_nope_head_dim"], a["qk_rope_head_dim"],
-                         a["v_head_dim"])
-    qt = jnp.einsum("bhd,chd->bhc", q[..., :dn].astype(dt),
-                    w["Wuk"].reshape(dc, h, dn),
-                    preferred_element_type=F32)
-    pad = rows.shape[-1] - dc - dr
-    qf = jnp.concatenate([qt, q[..., dn:], jnp.zeros((b, h, pad), F32)],
-                         axis=-1).astype(dt)
+    dt = rows.dtype
+    qf = absorbed_query(q, w, a, dt, rows.shape[-1])
     u = _kv._decode_contract(qf[:, None], rows, rows, valid[:, None], dt,
-                             n_kv=1, scale=float(dn + dr) ** -0.5)
-    o = jnp.einsum("bhc,chv->bhv", u[:, 0, :, :dc],
-                   w["Wuv"].reshape(dc, h, dv), preferred_element_type=F32)
-    return o.astype(dt).reshape(b, h * dv)
+                             n_kv=1, scale=attention_scale(a))
+    return absorbed_context(u[:, 0], w, a)
+
+
+def selected_rows(keep, k):
+    """keep [B, S] bool (at most k a row) -> [B, k] int32: the rows
+    where it holds, ascending, -1 after the last."""
+    s_len = keep.shape[-1]
+    at = jnp.sort(jnp.where(keep, jnp.arange(s_len, dtype=jnp.int32),
+                            s_len), axis=-1)[:, :k]
+    return jnp.where(at < s_len, at, -1)
+
+
+def attends_in_place(flat_c, ps, s_len, topk, mesh=None) -> bool:
+    """Does a decode step over this geometry attend the latent plane's
+    pages in place? Decided from what is being lowered: there is a
+    selection (the cache is longer than index_topk), at most
+    ``ATTEND_PAGES_MAX_RATIO`` times longer, the plane is one
+    ``_paged_gather`` would read a page per DMA (on the chip, no mesh,
+    pages of whole sublane tiles) and its table has a block of whole
+    lane tiles."""
+    from paddle_tpu.ops.pallas import paged_attention as _pk
+    return (topk < s_len <= ATTEND_PAGES_MAX_RATIO * topk
+            and _kv._gather_tier(flat_c, None, ps, mesh) == "pages"
+            and _pk.attend_block_pages(s_len // ps, ps) > 0)
 
 
 def live_rows(j, lens, gen0, pos):
@@ -348,9 +427,27 @@ def _mla_decode_paged(ctx, ins, attrs):
 
     valid = live_rows(jnp.arange(s_len, dtype=jnp.int32), lens, gen0, pos)
     topk = a["index_topk"]
+    in_place = attends_in_place(flat_c, ps, s_len, topk, ctx.mesh)
+    MLA_DECODE_LOWERED.labels(path="pages" if in_place else "rows").inc()
     if s_len > topk:
         keys = _kv._paged_gather(flat_i, None, table, ps, dt, ctx.mesh)
         scores = _slot_scores(qi, wi, keys)
+    if in_place:
+        from paddle_tpu.ops import pallas as _plk
+        from paddle_tpu.ops.pallas import paged_attention as _pk
+        # the selection as a MASK (a row of no live entry would keep
+        # everything: the two thresholds are both -inf there), and the
+        # kernel reads each active slot's live pages whole under it
+        keep = select_topk(scores, valid, topk) & valid
+        sel = selected_rows(keep, topk)
+        u = _pk.attend_pages(
+            absorbed_query(q, w, a, flat_c.dtype, flat_c.shape[-1]),
+            flat_c, table, jnp.where(active, lens, 0), gen0,
+            jnp.where(active, pos, -1), keep, ps, attention_scale(a),
+            value_width=latent_width(a["kv_lora_rank"], 0),
+            interpret=_plk.interpret_mode())
+        o = absorbed_context(u.astype(dt), w, a)
+    elif s_len > topk:
         top, sel = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), topk)
         # attend the chosen rows in the order they lie in the cache
         # (softmax does not care, the gather does: ascending rows are
@@ -372,10 +469,11 @@ def _mla_decode_paged(ctx, ins, attrs):
         rows = jnp.take(flat_c, at.reshape(-1), axis=0, mode="clip")\
             .reshape(b, topk, -1).astype(dt)
         sel = jnp.where(valid, sel, -1)
+        o = absorbed_attention(q, rows, valid, w, a)
     else:
         rows = _kv._paged_gather(flat_c, None, table, ps, dt, ctx.mesh)
         sel = jnp.where(valid, jnp.arange(s_len, dtype=jnp.int32)[None],
                         -1)
-    o = absorbed_attention(q, rows, valid, w, a)
+        o = absorbed_attention(q, rows, valid, w, a)
     out = dense(o, w["Wo"], dt)[:, None]
     return _result(out, flat_c, flat_i, n_pages, ps, Selected=sel)

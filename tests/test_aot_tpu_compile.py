@@ -67,6 +67,19 @@ def _paged_gather_pages(dtype):
             [((49152, 1024), dtype), ((48 * 64,), I32)])
 
 
+def _attend_pages(dtype, ps):
+    # the latent cell: 32 slots of 12 288 rows in pages of a bf16 tile
+    # (or an fp32 tile), 64 heads over rows of 640, 512 lanes of value
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    b, s_len = 32, 12288
+    return (lambda q, pool, t, lens, g0, pos, keep: pa.attend_pages(
+        q, pool, t, lens, g0, pos, keep, page_size=ps, scale=0.0625,
+        value_width=512),
+        [((b, 64, 640), dtype), ((b * s_len, 640), dtype),
+         ((b, s_len // ps), I32), ((b,), I32), ((b,), I32), ((b,), I32),
+         ((b, s_len), jnp.bool_)])
+
+
 def _paged_dequant():
     from paddle_tpu.ops.pallas import paged_attention as pa
     return (lambda p, s, r: pa.gather_rows_dequant(p, s, r, heads=8),
@@ -131,6 +144,8 @@ CASES = {
     "paged_gather-int8-dequant-4096x1024": _paged_dequant,
     "paged_gather_pages-f32-49152x1024": lambda: _paged_gather_pages(F32),
     "paged_gather_pages-bf16-49152x1024": lambda: _paged_gather_pages(BF16),
+    "attend_pages-bf16-ps16-32x12288x640": lambda: _attend_pages(BF16, 16),
+    "attend_pages-f32-ps8-32x12288x640": lambda: _attend_pages(F32, 8),
     "embed_cache-gather-w128": lambda: _cache_gather(128),
     "embed_cache-gather-w256": lambda: _cache_gather(256),
     "embed_cache-scatter-w128": lambda: _cache_scatter(128),
@@ -516,19 +531,37 @@ def _compile_view(chip, programs, key, cb, feeds, monkeypatch):
                        struct((), jnp.uint32)).compile()
 
 
+@pytest.mark.parametrize("path", ["pages", "rows"])
 def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
-                                             monkeypatch):
+                                             monkeypatch, path):
     """32 slots of 12 288 rows, bf16, five ``mla`` layers (one dense, four
     with 16 of 256 experts): the step fits one chip with the 3.02 GB of
     latent and index planes donated and aliased in place; per layer it
-    holds one ``gather_pages`` of the INDEX plane alone, the scoring
+    holds one ``gather_pages`` of the INDEX plane alone and the scoring
     under a result shape of its own (``f32[32,96,128]``: what the
-    benchmark's reader selects), one ``sort`` (``jax.lax.top_k``), and a
-    gather of the selected rows ``bf16[65536,640]`` — no copy of a
-    plane's size, and no gather of the latent plane's whole table."""
+    benchmark's reader selects). The cache is 6 x index_topk long, so
+    the selection is a mask found by threshold and ``attend_pages``
+    reads the latent plane's live pages under it (``pages``, PR 34): a
+    second kernel a layer whose result is the attended latent
+    ``bf16[32,64,640]``, no ``sort`` of a slot's 12 288 scores (nor of
+    the mask's positions: ``Selected`` is not fetched, and gone), no
+    array of the 65 536 selected rows. Past ``ATTEND_PAGES_MAX_RATIO``
+    (``rows``: the same geometry under a smaller ratio) the step is
+    PR 33's: ``jax.lax.top_k``, the chosen rows ordered by position and
+    gathered ``bf16[65536,640]``. Neither way copies or gathers a whole
+    plane."""
+    from paddle_tpu.ops import mla
     eng, programs = glm5_engine
+    if path == "rows":
+        monkeypatch.setattr(mla, "ATTEND_PAGES_MAX_RATIO", 4)
+        jax.clear_caches()          # the view was traced under the other
+    lowered = {p: mla.MLA_DECODE_LOWERED.labels(path=p).value
+               for p in ("pages", "rows")}
     compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
                              eng._decode_feeds(), monkeypatch)
+    for p, was in lowered.items():
+        assert mla.MLA_DECODE_LOWERED.labels(path=p).value - was \
+            == (5 if p == path else 0)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
     planes = 5 * 24576 * 16 * (640 + 128) * 2
@@ -536,19 +569,32 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
     text = compiled.as_text()
     entry = text[text.index("ENTRY "):]
     ops = _hlo_ops(text)
-    assert entry.count("custom_call_target=\"tpu_custom_call\"") == 5
+    in_place = path == "pages"
+    assert entry.count("custom_call_target=\"tpu_custom_call\"") \
+        == (10 if in_place else 5)
     assert len(re.findall(r"= bf16\[393216,128\]\S* custom-call\(", entry)) \
         == 5
+    assert len(re.findall(r"= bf16\[32,64,640\]\S* custom-call\(", entry)) \
+        == (5 if in_place else 0)
     assert len(re.findall(r"= f32\[32,96,128\]\S* fusion\(", entry)) == 5
-    assert len(re.findall(r"= bf16\[65536,640\]\S* fusion\(", entry)) >= 5
-    # the selection's five; the four routers' top-8 sort 256 scores
-    assert len(re.findall(r"= \(f32\[32,12288\]\S*, s32\[32,12288\]\S*\) "
-                          r"sort\(", entry)) == 5
-    # ... and the five orderings of the 2 048 chosen rows by position
-    assert _count_opcode(entry, "sort") == 14
+    rows = len(re.findall(r"= bf16\[65536,640\]\S* fusion\(", entry))
+    # the selection's five full sorts, or none
+    sorts = len(re.findall(r"= \(f32\[32,12288\]\S*, s32\[32,12288\]\S*\) "
+                           r"sort\(", entry))
+    if in_place:
+        assert rows == 0 and "[65536,640]" not in text
+        assert sorts == 0 and not re.findall(r"\[32,12288\][^=]* sort\(",
+                                             text)
+        # the four routers' top-8 over 256 scores are all that sorts
+        assert _count_opcode(entry, "sort") == 4
+    else:
+        assert rows >= 5 and sorts == 5
+        # ... and the five orderings of the 2 048 chosen rows by position
+        assert _count_opcode(entry, "sort") == 14
     plane = 24576 * 16 * 128
     assert not [line for opcode, count, _a, line in ops.values()
-                if opcode in ("copy", "transpose") and count >= plane]
+                if opcode in ("copy", "transpose", "gather")
+                and count >= plane]
     assert not re.findall(r"= bf16\[393216,640\]\S* custom-call\(", entry)
 
 

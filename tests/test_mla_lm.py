@@ -302,3 +302,122 @@ def test_rows_scored_and_selected_are_counted(engine):
         == 3 * pool_rows * 128 * 4
     assert smetrics.INDEX_CACHE_BYTES.labels(model="lm").value \
         == 3 * pool_rows * 16 * 4
+
+
+# --------------------------------- the two ways a decode step attends
+
+@pytest.mark.parametrize("case", ["cuts", "ties", "under_topk"])
+def test_selected_is_where_the_mask_holds(case):
+    """``Selected`` of the in-place path is read off the mask the kernel
+    consumes: the positions where ``keep`` holds, ascending, -1 after —
+    ``jax.lax.top_k``'s set, on tied scores and on slots with fewer live
+    rows than index_topk too."""
+    rng = np.random.RandomState(17)
+    n, s, k = 5, 48, 8
+    scores = rng.randn(n, s).astype(np.float32)
+    last = np.asarray([47, 30, 12, 9, 8])
+    if case == "ties":
+        # + 0.0: top_k orders -0.0 below 0.0, a threshold finds them equal
+        scores = np.round(scores) + 0.0
+    elif case == "under_topk":
+        last = np.asarray([0, 3, 6, 7, -1])        # -1: no live row
+    valid = np.arange(s)[None, :] <= last[:, None]
+    keep = mla.select_topk(jnp.asarray(scores), jnp.asarray(valid), k) \
+        & jnp.asarray(valid)
+    got = np.asarray(mla.selected_rows(keep, k))
+    _, idx = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), k)
+    for row, (g, i, v) in enumerate(zip(got, np.asarray(idx), valid)):
+        want = np.sort(i[v[i]])
+        np.testing.assert_array_equal(g[:len(want)], want)
+        assert (g[len(want):] == -1).all()
+        np.testing.assert_array_equal(np.flatnonzero(np.asarray(keep)[row]),
+                                      want)
+
+
+def _decode_op(rng, s_len, ps, topk, n_pages=None, b=3):
+    """(emit, ins) of one ``mla_decode_paged`` layer over ``b`` slots of
+    ``s_len`` rows in pages of ``ps``: slot 0 long past index_topk, slot
+    1 under it with padding before its bucket, slot 2 inactive."""
+    import types
+    from paddle_tpu.core.registry import get_op
+    a = {**SIZES, "index_topk": topk}
+    mp = s_len // ps
+    n_pages = n_pages or b * mp
+    w = _layer_weights(rng, a)
+    width = mla.latent_width(a["kv_lora_rank"], a["qk_rope_head_dim"])
+    bucket = s_len // 2
+    col = lambda *v: jnp.asarray(v, jnp.int32)[:, None]  # noqa: E731
+    ins = {**w, "X": jnp.asarray(rng.randn(b, 1, 64), jnp.float32),
+           "PageC": jnp.asarray(rng.randn(n_pages, ps, width), jnp.float32),
+           "PageI": jnp.asarray(rng.randn(n_pages, ps, 16), jnp.float32),
+           "PageTable": jnp.asarray(
+               rng.permutation(n_pages)[:b * mp].reshape(b, mp), jnp.int32),
+           "SeqLen": col(bucket - 3, 5, 0), "GenStart": col(bucket, bucket, 0),
+           "Pos": col(bucket + s_len // 3, bucket, -1),
+           "Active": col(1, 1, 0), "Position": col(bucket + s_len // 3 - 3,
+                                                   5, 0)}
+    attrs = {**a, "rope_theta": 1e6, "epsilon": 1e-5}
+
+    def emit(ins):
+        out = get_op("mla_decode_paged").emit(
+            types.SimpleNamespace(mesh=None), {k: [v] for k, v in ins.items()},
+            attrs)
+        return {k: v[0] for k, v in out.items()}
+    return emit, ins
+
+
+def test_in_place_and_gathered_decode_agree(monkeypatch):
+    """One layer's decode step both ways over the same planes — the
+    kernel interpreted, engaged as it would be on the chip — gives the
+    same context and the same ``Selected``."""
+    from paddle_tpu.ops import kv_attention as kv
+    emit, ins = _decode_op(np.random.RandomState(23), s_len=256, ps=8,
+                           topk=64)
+    with jax.default_matmul_precision("highest"):
+        rows = emit(ins)
+        monkeypatch.setattr(
+            kv, "_gather_tier", lambda flat, scales, ps, mesh=None: "pages")
+        monkeypatch.setattr(
+            kv, "_paged_gather", lambda flat, s, table, ps, dt, mesh=None:
+            jnp.take(flat.reshape(-1, ps, flat.shape[-1]), table, axis=0)
+            .reshape(table.shape[0], -1, flat.shape[-1]).astype(dt))
+        pages = emit(ins)
+    live = np.asarray(ins["Active"])[:, 0] > 0
+    np.testing.assert_array_equal(pages["Selected"], rows["Selected"])
+    assert (np.asarray(pages["Selected"])[0] >= 0).all()          # 64 of 211
+    assert (np.asarray(pages["Selected"])[1] >= 0).sum() == 6     # 5 + 1
+    assert np.isfinite(np.asarray(pages["Out"])).all()
+    scale = np.abs(np.asarray(rows["Out"])[live]).max()
+    np.testing.assert_allclose(np.asarray(pages["Out"])[live],
+                               np.asarray(rows["Out"])[live],
+                               atol=TOL * scale)
+    for plane in ("PageCOut", "PageIOut"):
+        np.testing.assert_array_equal(pages[plane], rows[plane])
+
+
+@pytest.mark.parametrize("s_len,path", [
+    (128 * mla.ATTEND_PAGES_MAX_RATIO, "pages"),
+    (128 * mla.ATTEND_PAGES_MAX_RATIO + 128, "rows"),      # past the ratio
+    (64, "rows")])                        # everything attended: no selection
+def test_the_lowering_counter_names_the_path(monkeypatch, s_len, path):
+    """``paddle_mla_decode_lowered_total``: one increment a layer each
+    time a decode program is traced, by what the geometry got — decided
+    from the cache's length over index_topk where the plane is one the
+    chip reads a page per DMA, and ``rows`` everywhere off the chip."""
+    from paddle_tpu.ops import pallas as pk
+    emit, ins = _decode_op(np.random.RandomState(1), s_len=s_len, ps=8,
+                           topk=128)
+    read = lambda: {p: mla.MLA_DECODE_LOWERED.labels(path=p).value  # noqa
+                    for p in ("pages", "rows")}
+    before = read()
+    jax.eval_shape(emit, ins)                       # here: the CPU
+    assert read() == {**before, "rows": before["rows"] + 1}
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    jax.eval_shape(lambda i: emit(i), ins)          # as on the chip
+    after = read()
+    assert after[path] == before[path] + 1 + (path == "rows")
+    assert sum(after.values()) == sum(before.values()) + 2
+    from paddle_tpu.observability import exporters, metrics as obs_metrics
+    exporters._preregister_catalog()
+    assert "paddle_mla_decode_lowered_total" in \
+        obs_metrics.default_registry().snapshot()

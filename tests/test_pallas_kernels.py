@@ -250,3 +250,112 @@ def test_flash_attention_bf16_fwd_bwd_parity():
         b32 = np.asarray(b, np.float32)
         denom = np.abs(b32).max() + 1e-6
         assert np.abs(a32 - b32).max() / denom < 5e-2, name
+
+
+# ------------------------------------------------------------------ ISSUE 34
+# attend_pages: a slot's live pages attended in place under a mask,
+# against the gathered path (the page gather, then _decode_contract)
+
+def _attend_case(case, dtype):
+    """(q, pool, table, lens, gen0, pos, keep) of four slots over a
+    table of 32 pages a slot; a page is one sublane tile of the dtype,
+    a bucket 16 pages, a block 128 rows (so a slot walks several)."""
+    rng = np.random.RandomState(sum(map(ord, case)))
+    ps = 8 if dtype == jnp.float32 else 16
+    b, h, w, mp, n_pages = 4, 8, 256, 32, 160
+    s_len, bucket = mp * ps, 16 * ps
+    pool = rng.randn(n_pages * ps, w).astype(np.float32)
+    q = rng.randn(b, h, w).astype(np.float32)
+    table = rng.permutation(n_pages)[:b * mp].reshape(b, mp)
+    lens = np.asarray([bucket, bucket - 3 * ps - 2, 5, bucket - 1])
+    gen0 = np.full(b, bucket)
+    pos = gen0 + np.asarray([0, 2 * ps + 3, 9 * ps, 15 * ps + ps - 1])
+    j = np.arange(s_len)
+    live = (j[None] < lens[:, None]) | ((j[None] >= gen0[:, None])
+                                        & (j[None] <= pos[:, None]))
+    keep = live & (rng.rand(b, s_len) < 0.4)
+    if case == "masked_row_dominates":
+        # a live row NOT kept whose score is a hundred above the rest:
+        # a kernel that ignores the mask returns that row alone
+        keep[:, 3] = False
+        pool[table[:, 0] * ps + 3] = 10.0 * q[:, 0] \
+            / np.linalg.norm(q[:, 0], axis=-1, keepdims=True)
+    elif case == "fewer_live_than_topk":
+        lens[:], pos[:] = [3, 1, ps, 2], gen0 + [0, 1, 0, ps]
+        keep = (j[None] < lens[:, None]) | ((j[None] >= gen0[:, None])
+                                            & (j[None] <= pos[:, None]))
+    elif case == "inactive_slot":
+        lens[1], pos[1] = 0, -1           # what the engine feeds for one
+        keep[1] = False
+        table[1] = n_pages                # ... and its table: sentinels
+    elif case == "bucket_padding":
+        # rows between the prompt's end and the bucket hold what an
+        # older lease left: huge, and in no extent
+        lens[:] = [1, ps + 1, bucket - ps, 7 * ps]
+        keep &= (j[None] < lens[:, None]) | (j[None] >= bucket)
+        for slot in range(b):
+            dead = np.arange(-(-lens[slot] // ps), bucket // ps)
+            rows = (table[slot, dead, None] * ps + np.arange(ps)).ravel()
+            pool[rows] = 1e30
+    elif case == "part_written_page":
+        # the rows of the last page beyond pos: unwritten, and huge
+        pos[:] = gen0 + [0, 1, ps + 2, 3 * ps - 2]
+        keep &= j[None] <= pos[:, None]
+        for slot in range(b):
+            rows = table[slot, pos[slot] // ps] * ps \
+                + np.arange(pos[slot] % ps + 1, ps)
+            pool[rows] = -1e30
+    elif case == "sentinel_pages":
+        # leased pages only under the live rows, sentinels beyond —
+        # and the leased ones in descending order
+        for slot in range(b):
+            last = pos[slot] // ps + 1
+            table[slot, :last] = np.sort(table[slot, :last])[::-1]
+            table[slot, last:] = n_pages + 7
+    else:
+        assert case == "random_masks"
+    cast = lambda x: jnp.asarray(x, dtype)  # noqa: E731
+    return (cast(q), cast(pool), jnp.asarray(table, jnp.int32),
+            *(jnp.asarray(x, jnp.int32) for x in (lens, gen0, pos)),
+            jnp.asarray(keep), ps)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("case", [
+    "random_masks", "masked_row_dominates", "fewer_live_than_topk",
+    "inactive_slot", "bucket_padding", "part_written_page",
+    "sentinel_pages"])
+def test_attend_pages_matches_the_gathered_path(case, dtype):
+    """The kernel interpreted against ``_paged_gather`` +
+    ``_decode_contract``, the path it replaces: float32 to 1e-5,
+    bfloat16 to the storage's rounding. (The cases also pass under the
+    TPU interpreter — ``pltpu.InterpretParams``: copies land when they
+    are waited for, memory never written reads NaN — which is not used
+    here: its callbacks run JAX operations of their own and deadlocked
+    against the test's under six workers.)"""
+    from paddle_tpu.ops import kv_attention as kv
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    q, pool, table, lens, gen0, pos, keep, ps = _attend_case(case, dtype)
+    scale = 0.5 * q.shape[-1] ** -0.5
+    # the bf16 cases ask for the first lane tile of the result alone
+    vw = 128 if dtype == jnp.bfloat16 else 0
+    got = pa.attend_pages(
+        q, pool, table, lens, gen0, pos, keep, ps, scale, value_width=vw,
+        block_rows=128, interpret=True)
+    rows = kv._paged_gather(pool, None, table, ps, dtype)
+    want = kv._decode_contract(q[:, None], rows, rows, keep[:, None],
+                               dtype, n_kv=1, scale=scale)[:, 0]
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if vw:
+        assert not got[..., vw:].any()
+        got, want = got[..., :vw], want[..., :vw]
+    none = ~np.asarray(keep).any(axis=1)
+    assert np.isfinite(got).all()
+    assert not got[none].any()            # a slot that attends nothing
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    np.testing.assert_allclose(got[~none], want[~none],
+                               atol=tol * np.abs(want[~none]).max())
+    if case == "masked_row_dominates":
+        ignored = np.asarray(pool[table[:, 0] * ps + 3], np.float32)
+        assert np.abs(got - ignored[:, None, :got.shape[-1]]).max() > 1.0
