@@ -24,6 +24,7 @@ import numpy as np
 from paddle_tpu.core import ir
 from paddle_tpu.core import selected_rows as sr
 from paddle_tpu.core.registry import EmitContext, get_op
+from paddle_tpu.observability import device_scopes as _device_scopes
 from paddle_tpu.observability import runtime as _obs_runtime
 
 # ensure all builtin emitters are registered on import
@@ -185,13 +186,17 @@ def emit_op_seq(program: ir.ProgramDesc, block: ir.BlockDesc,
         # else gets an exact densify — a consumer can never observe the
         # difference, only the fast path's cost profile
         attrs = _sharded_attrs(program, op)
-        if any(sr.is_sparse(v) for vals in ins.values() for v in vals) \
-                and op.type not in sr.SPARSE_APPLY_OPS:
-            outs = sr.try_sparse_emit(op.type, ins, attrs)
-            if outs is None:
-                outs = spec.emit(ctx, sr.densify_ins(ins), attrs)
-        else:
-            outs = spec.emit(ctx, ins, attrs)
+        # every lowered op in a scope of its own type: XLA carries it
+        # into each instruction's op_name, which is how the device's
+        # time is read under the program's names (device_scopes.py)
+        with jax.named_scope(_device_scopes.op_scope(op)):
+            if any(sr.is_sparse(v) for vals in ins.values() for v in vals) \
+                    and op.type not in sr.SPARSE_APPLY_OPS:
+                outs = sr.try_sparse_emit(op.type, ins, attrs)
+                if outs is None:
+                    outs = spec.emit(ctx, sr.densify_ins(ins), attrs)
+            else:
+                outs = spec.emit(ctx, ins, attrs)
         for slot, names in op.outputs.items():
             vals = outs.get(slot)
             if vals is None:
@@ -262,6 +267,70 @@ def build_block_fn(program: ir.ProgramDesc, block_idx: int,
         return fetches, new_state
 
     return fn
+
+
+class _Executables:
+    """The jitted fns of one block, the arguments each compiled for and
+    the executable of each once something asked for it. Kept apart from
+    the block, and referring to none of its arrays, so that
+    ``observability.device_scopes`` can hold it while a trace is taken
+    and read the names of a block that is gone by then."""
+
+    def __init__(self):
+        # (iterations, stacked names) -> the jitted fn, and the name
+        # jax.monitoring's compile events give it -> that key
+        self.jitted: Dict[Tuple, Any] = {}
+        self.names: Dict[str, Tuple] = {}
+        # (iterations, stacked names, feed shapes) -> the argument specs
+        # of the NEWEST compile (a second one, for the layouts the
+        # step's own outputs carry, replaces the first), and the
+        # executable once asked for
+        self.ran: Dict[Tuple, Any] = {}
+        self.compiled: Dict[Tuple, Any] = {}
+        _device_scopes.register(self)
+
+    def note(self, fun_name: str, args) -> None:
+        """The compile listener's call (``runtime.dispatching``): a
+        jitted fn called ``fun_name`` compiled inside a dispatch of
+        ``args``. Shapes, dtypes and where each argument was committed
+        are kept, so that a later ``.lower(*specs)`` finds the very
+        executable the dispatch made in JAX's own caches, without a
+        trace, a lowering or a compile. Runs per COMPILE, never per
+        dispatch."""
+        mine = self.names.get(fun_name)
+        if mine is None:
+            return
+
+        def spec(x):
+            aval = jax.typeof(x)
+            committed = isinstance(x, jax.Array) and x.committed
+            return jax.ShapeDtypeStruct(
+                aval.shape, aval.dtype, weak_type=aval.weak_type,
+                sharding=x.sharding if committed else None)
+        self.ran[(*mine, _feed_sig(args[2]))] = \
+            jax.tree_util.tree_map(spec, args)
+
+    def executable(self, iterations: int, snames, args):
+        """THE lower / compile round trip, once per jitted fn and feed
+        shapes."""
+        key = (iterations, snames, _feed_sig(args[2]))
+        exe = self.compiled.get(key)
+        if exe is None:
+            exe = self.compiled[key] = \
+                self.jitted[(iterations, snames)].lower(*args).compile()
+        return exe
+
+    def device_executables(self) -> list:
+        """The executable of every signature the block's jitted fns
+        compiled for: what the block has run."""
+        return [self.executable(iterations, snames, specs)
+                for (iterations, snames, _sig), specs
+                in list(self.ran.items())]
+
+
+def _feed_sig(feeds: Dict[str, Any]):
+    return tuple(sorted((n, tuple(getattr(v, "shape", ()) or ()))
+                        for n, v in feeds.items()))
 
 
 class CompiledBlock:
@@ -382,7 +451,8 @@ class CompiledBlock:
         # same via XLA input_output_aliasing)
         self._step_fn = fn            # un-jitted (dist-wrapped) single step
         self._jit_kwargs = jit_kwargs
-        self.fn = jax.jit(fn, **jit_kwargs)
+        self._exes = _Executables()
+        self.fn = self._jit(fn, 1, False, jit_kwargs)
         # key: (iterations, True | tuple of stacked feed names)
         self._multi_cache: Dict[Tuple[int, Any], Any] = {}
         # device-resident training state: after a dispatch the (sharded)
@@ -394,6 +464,22 @@ class CompiledBlock:
         # witness counter (tests/test_spmd_exec.py).
         self._resident = None   # (scope, scope.version(), state, consts)
         self.gather_state_calls = 0
+
+    def _jit(self, fn, iterations: int, snames, jit_kwargs):
+        """``fn`` (a closure of this block's own) jitted under the
+        block's name, so that XLA calls the module ``jit_<name>``
+        (``device_scopes.module_name``) and the profiler's "XLA Modules"
+        line names the program's blocks. A scan of N steps is another
+        executable than the single step and gets another name, so that
+        an instruction name means one thing under each."""
+        name = _device_scopes.module_name(
+            self.obs_label + (f"_x{iterations}" if iterations > 1 else ""),
+            (op.type for b in self._program_desc.blocks for op in b.ops))
+        fn.__name__ = fn.__qualname__ = name
+        jitted = self._exes.jitted[(iterations, snames)] = jax.jit(
+            fn, **jit_kwargs)
+        self._exes.names[f"jit({name})"] = (iterations, snames)
+        return jitted
 
     def _multi_fn(self, iterations: int, stacked):
         """jitted N-step executable: scans the single-step fn over donated
@@ -417,6 +503,9 @@ class CompiledBlock:
             return cached
         step_fn = self._step_fn
         all_stacked = stacked is True
+        # (not through ``self``: the jitted fn must not keep the block,
+        # and with it the state arrays, alive)
+        created = self.sig.created_persistable
 
         def fn(state, consts, feeds, seed0):
             sf = {n: v for n, v in feeds.items()
@@ -426,13 +515,13 @@ class CompiledBlock:
             # scan carry must have the same structure, so seed the carry
             # with zero placeholders for persistables first CREATED by this
             # block (they're written before read, so the zeros never leak)
-            if self.sig.created_persistable:
+            if created:
                 feeds0 = {**rf, **jax.tree_util.tree_map(
                     lambda x: x[0], sf)}
                 _, out_sd = jax.eval_shape(step_fn, state, consts, feeds0,
                                            seed0)
                 state = dict(state)
-                for n in self.sig.created_persistable:
+                for n in created:
                     if n in out_sd and n not in state:
                         state[n] = jnp.zeros(out_sd[n].shape,
                                              out_sd[n].dtype)
@@ -457,7 +546,7 @@ class CompiledBlock:
                         if (all_stacked or n in snames) else sh)
                     for n, sh in feed_sh.items()}
             jit_kwargs["in_shardings"] = (state_sh, const_sh, feed_sh, repl)
-        jitted = jax.jit(fn, **jit_kwargs)
+        jitted = self._jit(fn, iterations, key[1], jit_kwargs)
         self._multi_cache[key] = jitted
         return jitted
 
@@ -637,43 +726,56 @@ class CompiledBlock:
         fn = self._multi_fn(iterations, stacked)
         if marks is not None:
             marks.append(time.perf_counter())
-        with _obs_runtime.dispatching(self.obs_label):
-            fetches, new_state = fn(state, consts, feeds,
-                                    np.uint32(step_seed0))
+        args = (state, consts, feeds, np.uint32(step_seed0))
+        with _obs_runtime.dispatching(self.obs_label, self._exes.note,
+                                      args):
+            fetches, new_state = fn(*args)
         self._finish_dispatch(scope, new_state, consts)
         return fetches
+
+    def _signature(self, feeds, stacked):
+        """(stacked names, feed shapes) of a dispatch: feed shapes
+        belong in a cache key — jit retraces per shape behind one jitted
+        fn, so a partial tail batch must not be served the full batch's
+        numbers."""
+        snames = (stacked if isinstance(stacked, bool)
+                  else tuple(sorted(stacked)))
+        return snames, _feed_sig(feeds)
+
+    def _compile_thunk(self, scope, feeds, iterations, snames):
+        """A callable that gives the one executable ``analyzed_flops``,
+        ``analyzed_memory``, ``donation_audit`` and
+        ``observability.device_scopes`` all read for this jitted fn and
+        these feed shapes (``_Executables.executable``): none of them
+        lowers or compiles on its own. Call it AFTER a real dispatch."""
+        def compiled():
+            if iterations > 1:
+                self._multi_fn(iterations, snames)
+            state, consts = self._resident_state(scope)
+            return self._exes.executable(
+                iterations, snames, (state, consts, feeds, np.uint32(0)))
+        return compiled
 
     def analyzed_flops(self, scope, feeds: Dict[str, Any],
                        iterations: int = 1, stacked=False):
         """Per-step FLOPs of this executable from XLA's compiled-cost
         analysis (observability MFU numerator), cached per (iterations,
         stacked) jit signature. The lower/compile round trip runs once
-        per signature — call AFTER a real dispatch so jax's executable
-        caches are warm. None when the backend reports no FLOPs (the
-        caller falls back to utils/flops.py's analytic walk)."""
+        per signature (``_compile_thunk``) — call AFTER a real dispatch
+        so jax's executable caches are warm. None when the backend
+        reports no FLOPs (the caller falls back to utils/flops.py's
+        analytic walk)."""
         from paddle_tpu.observability import runtime as obs_runtime
-        snames = (stacked if isinstance(stacked, bool)
-                  else tuple(sorted(stacked)))
-        # feed shapes belong in the key: jit retraces per shape behind
-        # one jitted fn, so a partial tail batch must not serve the full
-        # batch's cached FLOPs
-        feed_sig = tuple(sorted(
-            (n, tuple(getattr(v, "shape", ()) or ()))
-            for n, v in feeds.items()))
+        snames, feed_sig = self._signature(feeds, stacked)
         key = (self._obs_tag, iterations, snames, feed_sig)
         hit, val = obs_runtime.cost_cache_peek(key)
         if hit:
             # resolved signature: skip the scope walk / fn lookup — this
             # runs once per dispatch on the telemetry path
             return val
-        if iterations > 1:
-            fn = self._multi_fn(iterations, stacked)
-        else:
-            fn = self.fn
-        state, consts = self._resident_state(scope)
         return obs_runtime.compiled_flops(
-            fn, state, consts, feeds, np.uint32(0), cache_key=key,
-            per_call_steps=iterations)
+            None, cache_key=key, per_call_steps=iterations,
+            compiled=self._compile_thunk(scope, feeds, iterations, snames))
 
     @property
     def obs_label(self) -> str:
@@ -683,11 +785,6 @@ class CompiledBlock:
         return (getattr(self._program_desc, "_obs_name", None)
                 or f"block{self._obs_tag}")
 
-    def _feed_sig(self, feeds: Dict[str, Any]):
-        return tuple(sorted(
-            (n, tuple(getattr(v, "shape", ()) or ()))
-            for n, v in feeds.items()))
-
     def analyzed_memory(self, scope, feeds: Dict[str, Any],
                         iterations: int = 1, stacked=False):
         """Compiled memory breakdown of this executable (argument/
@@ -695,20 +792,14 @@ class CompiledBlock:
         memory_analysis(), cached per jit signature exactly like
         :meth:`analyzed_flops`. None when the backend reports nothing."""
         from paddle_tpu.observability import memory as obs_memory
-        snames = (stacked if isinstance(stacked, bool)
-                  else tuple(sorted(stacked)))
-        key = ("mem", self._obs_tag, iterations, snames,
-               self._feed_sig(feeds))
+        snames, feed_sig = self._signature(feeds, stacked)
+        key = ("mem", self._obs_tag, iterations, snames, feed_sig)
         hit, val = obs_memory.memory_cache_peek(key)
         if hit:
             return val
-        if iterations > 1:
-            fn = self._multi_fn(iterations, stacked)
-        else:
-            fn = self.fn
-        state, consts = self._resident_state(scope)
         return obs_memory.compiled_memory(
-            fn, state, consts, feeds, np.uint32(0), cache_key=key)
+            None, cache_key=key,
+            compiled=self._compile_thunk(scope, feeds, iterations, snames))
 
     def donation_audit(self, scope, feeds: Dict[str, Any]) -> dict:
         """Verify every mutated state var this block donates actually
@@ -717,19 +808,14 @@ class CompiledBlock:
         signature; counts paddle_donation_violations_total on the first
         resolution. {program, expected, aliased, violations, skipped}."""
         from paddle_tpu.observability import memory as obs_memory
-        key = ("audit", self._obs_tag, self._feed_sig(feeds))
+        key = ("audit", self._obs_tag, _feed_sig(feeds))
         hit, val = obs_memory.memory_cache_peek(key)
         if hit:
             return val
-        state, consts = self._resident_state(scope)
-
-        def lower_text():
-            return self.fn.lower(state, consts, feeds,
-                                 np.uint32(0)).compile().as_text()
-
+        compiled = self._compile_thunk(scope, feeds, 1, False)
         return obs_memory.donation_audit(
-            lower_text, self.sig.state_names, program=self.obs_label,
-            cache_key=key)
+            lambda: compiled().as_text(), self.sig.state_names,
+            program=self.obs_label, cache_key=key)
 
     def _input_shardings(self, dist=None):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -853,8 +939,9 @@ class CompiledBlock:
         state, consts = self._resident_state(scope)
         if marks is not None:
             marks.append(time.perf_counter())
-        with _obs_runtime.dispatching(self.obs_label):
-            fetches, new_state = self.fn(state, consts, feeds,
-                                         np.uint32(step_seed))
+        args = (state, consts, feeds, np.uint32(step_seed))
+        with _obs_runtime.dispatching(self.obs_label, self._exes.note,
+                                      args):
+            fetches, new_state = self.fn(*args)
         self._finish_dispatch(scope, new_state, consts)
         return fetches

@@ -122,12 +122,15 @@ def _cache_put(key: Any, value):
         _MEM_CACHE[key] = value
 
 
-def compiled_memory(jit_fn, *args, cache_key: Any = None
-                    ) -> Optional[dict]:
+def compiled_memory(jit_fn, *args, cache_key: Any = None,
+                    compiled=None) -> Optional[dict]:
     """Memory breakdown of ``jit_fn`` specialized to ``args`` from XLA's
     ``memory_analysis()``: {argument,output,temp,alias,generated_code,
     peak}_bytes. The lower/compile round trip runs once per ``cache_key``
-    (jax's executable caches make it cheap after a real dispatch).
+    (jax's executable caches make it cheap after a real dispatch), or
+    not at all where ``compiled``, a callable that returns the
+    executable, is given in place of ``jit_fn`` and ``args``
+    (``CompiledBlock._compile_thunk``: one kept per signature).
     None when the backend reports nothing."""
     key = cache_key if cache_key is not None else id(jit_fn)
     hit, val = memory_cache_peek(key)
@@ -135,7 +138,9 @@ def compiled_memory(jit_fn, *args, cache_key: Any = None
         return val
     out: Optional[dict] = None
     try:
-        ma = jit_fn.lower(*args).compile().memory_analysis()
+        exe = compiled() if compiled is not None \
+            else jit_fn.lower(*args).compile()
+        ma = exe.memory_analysis()
         if isinstance(ma, (list, tuple)):   # older jax: one per device
             ma = ma[0] if ma else None
         if ma is not None:

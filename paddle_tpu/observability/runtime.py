@@ -174,21 +174,27 @@ def cost_cache_peek(key: Any):
 
 
 def compiled_flops(jit_fn, *args, cache_key: Any = None,
-                   per_call_steps: int = 1) -> Optional[float]:
+                   per_call_steps: int = 1,
+                   compiled=None) -> Optional[float]:
     """Per-step FLOPs of ``jit_fn`` specialized to ``args``, from XLA's
     compiled-cost analysis. ``cache_key`` identifies the jit signature
     (callers pass their executable-cache key); the lower/compile round
     trip runs once per key — jax's internal caches make it cheap when
-    the signature was already compiled by a real call. Returns None when
-    the backend reports no FLOPs (callers fall back to the analytic walk
-    in ``utils/flops.py``)."""
+    the signature was already compiled by a real call. A caller that
+    keeps its executables (``CompiledBlock._compile_thunk``) passes
+    ``compiled``, a callable that returns the one for this key, in place
+    of ``jit_fn`` and ``args``. Returns None when the backend reports no
+    FLOPs (callers fall back to the analytic walk in
+    ``utils/flops.py``)."""
     key = cache_key if cache_key is not None else id(jit_fn)
     with _COST_LOCK:
         if key in _COST_CACHE:
             return _COST_CACHE[key]
     flops: Optional[float] = None
     try:
-        cost = jit_fn.lower(*args).compile().cost_analysis()
+        exe = compiled() if compiled is not None \
+            else jit_fn.lower(*args).compile()
+        cost = exe.cost_analysis()
         if isinstance(cost, (list, tuple)):   # older jax: one per device
             cost = cost[0] if cost else {}
         raw = float(cost.get("flops", 0.0) or 0.0)
@@ -249,22 +255,27 @@ _children: Dict[Any, Any] = {}
 
 
 class dispatching:
-    """``with dispatching(cb.obs_label): jitted(...)`` — names the
-    program for the compile events of this thread. Two attribute stores
-    per dispatch, nothing else; the previous name comes back on exit, so
-    a jit outside any dispatch counts under ``other``."""
+    """``with dispatching(cb.obs_label, note, args): jitted(*args)`` —
+    names the program for the compile events of this thread, and, where
+    a backend compile happens inside, hands ``note(fun_name, args)`` the
+    arguments it compiled for (``CompiledBlock`` keeps their shapes and
+    placement: ``lowering._Executables.note``). A handful of attribute
+    stores per dispatch, nothing else; the previous dispatch comes back
+    on exit, so a jit outside any counts under ``other``."""
 
-    __slots__ = ("program", "_prev")
+    __slots__ = ("program", "note", "args", "_prev")
 
-    def __init__(self, program: str):
+    def __init__(self, program: str, note=None, args=None):
         self.program = program
+        self.note = note
+        self.args = args
 
     def __enter__(self):
-        self._prev = getattr(_compile_local, "program", OTHER_PROGRAM)
-        _compile_local.program = self.program
+        self._prev = getattr(_compile_local, "dispatch", None)
+        _compile_local.dispatch = self
 
     def __exit__(self, *exc):
-        _compile_local.program = self._prev
+        _compile_local.dispatch = self._prev
 
 
 def _own_seconds(stage: str, start: float, end: float) -> float:
@@ -307,7 +318,11 @@ def _on_compile_event(event: str, duration: float, **kw) -> None:
     if stage is None:
         return
     now = time.perf_counter()
-    key = (stage, getattr(_compile_local, "program", OTHER_PROGRAM))
+    dispatch = getattr(_compile_local, "dispatch", None)
+    key = (stage, OTHER_PROGRAM if dispatch is None else dispatch.program)
+    if stage == "backend_compile" and dispatch is not None \
+            and dispatch.note is not None:
+        dispatch.note(kw.get("fun_name"), dispatch.args)
     pair = _children.get(key)
     if pair is None:
         pair = _children[key] = (COMPILE_EVENTS.labels(*key),

@@ -102,6 +102,11 @@ class Tracer:
 
     def start(self):
         self._enabled = True
+        if self is _DEFAULT:
+            # what ran under this trace stays readable by name after
+            # its blocks are gone (device_scopes.scopes())
+            from paddle_tpu.observability import device_scopes
+            device_scopes.hold()
 
     def stop(self):
         self._enabled = False

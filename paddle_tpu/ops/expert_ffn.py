@@ -45,13 +45,20 @@ with float32 accumulation.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
+from paddle_tpu.observability import device_scopes as _device_scopes
 from paddle_tpu.ops.math_ops import dense
 
 F32 = jnp.float32
+# the expert layer's mechanisms under their declared phases: the
+# router and the picks' bookkeeping, the held experts' gate and up
+# products, their down product, the shared expert
+_phase = functools.partial(_device_scopes.phase, "expert_ffn_held")
 
 
 def route(x, router_w, top_k: int, norm_topk: bool, scaling: float,
@@ -92,33 +99,40 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
     [E_held] int32). Tokens with ``valid`` false are routed nowhere."""
     n, k = idx.shape
     n_held = w_gate.shape[0]
-    local = idx - held_start
-    held = (local >= 0) & (local < n_held)
-    if valid is not None:
-        held &= valid[:, None]
-    key = jnp.where(held, local, n_held)                     # [N, K]
-    picked = key[:, :, None] == jnp.arange(n_held)           # [N, K, E]
-    sizes = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
+    with _phase("route"):
+        local = idx - held_start
+        held = (local >= 0) & (local < n_held)
+        if valid is not None:
+            held &= valid[:, None]
+        key = jnp.where(held, local, n_held)                     # [N, K]
+        picked = key[:, :, None] == jnp.arange(n_held)           # [N, K, E]
+        sizes = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
     if n <= DENSE_MAX_TOKENS:
-        # [N, E]: the token's combine weight for the expert, or zero
-        w = jnp.sum(jnp.where(picked, combine[:, :, None], 0.0), axis=1)
-        hidden = jax.nn.silu(_all_tokens(x, w_gate)) \
-            * _all_tokens(x, w_up) * w[:, :, None]           # [N, E, F]
-        y = jax.lax.dot_general(
-            hidden.astype(x.dtype), w_down, (((1, 2), (0, 1)), ((), ())),
-            preferred_element_type=F32)
+        with _phase("route"):
+            # [N, E]: the token's combine weight for the expert, or zero
+            w = jnp.sum(jnp.where(picked, combine[:, :, None], 0.0), axis=1)
+        with _phase("up"):
+            hidden = jax.nn.silu(_all_tokens(x, w_gate)) \
+                * _all_tokens(x, w_up) * w[:, :, None]           # [N, E, F]
+        with _phase("down"):
+            y = jax.lax.dot_general(
+                hidden.astype(x.dtype), w_down,
+                (((1, 2), (0, 1)), ((), ())), preferred_element_type=F32)
         return y, sizes
-    key = key.reshape(-1)                                    # [N*K]
-    order = jnp.argsort(key, stable=True)
-    xs = jnp.take(x, order // k, axis=0)                     # [N*K, M]
-    hidden = jax.nn.silu(_grouped(xs, w_gate, sizes)) \
-        * _grouped(xs, w_up, sizes)
-    ys = _grouped(hidden.astype(x.dtype), w_down, sizes)     # [N*K, M]
-    # back to assignment order; rows past the held groups are zero and
-    # carry the weight 0 besides
-    back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, -1)
-    w = jnp.where(held, combine, 0.0)
-    return jnp.sum(back * w[:, :, None], axis=1), sizes
+    with _phase("route"):
+        key = key.reshape(-1)                                    # [N*K]
+        order = jnp.argsort(key, stable=True)
+        xs = jnp.take(x, order // k, axis=0)                     # [N*K, M]
+    with _phase("up"):
+        hidden = jax.nn.silu(_grouped(xs, w_gate, sizes)) \
+            * _grouped(xs, w_up, sizes)
+    with _phase("down"):
+        ys = _grouped(hidden.astype(x.dtype), w_down, sizes)     # [N*K, M]
+        # back to assignment order; rows past the held groups are zero
+        # and carry the weight 0 besides
+        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, -1)
+        w = jnp.where(held, combine, 0.0)
+        return jnp.sum(back * w[:, :, None], axis=1), sizes
 
 
 def _all_tokens(x, w):
@@ -161,15 +175,18 @@ def _expert_ffn_held(ctx, ins, attrs):
         valid = jnp.arange(b * t) < jnp.asarray(
             first(ins, "SeqLen")).reshape(()).astype(jnp.int32)
     bias = first(ins, "RouterBias")
-    combine, idx = route(x2, first(ins, "RouterW"), int(attrs["top_k"]),
-                         bool(attrs.get("norm_topk", True)),
-                         float(attrs.get("scaling", 1.0)),
-                         *(() if bias is None else (bias,)))
+    with _phase("route"):
+        combine, idx = route(
+            x2, first(ins, "RouterW"), int(attrs["top_k"]),
+            bool(attrs.get("norm_topk", True)),
+            float(attrs.get("scaling", 1.0)),
+            *(() if bias is None else (bias,)))
     y, sizes = held_experts_part(
         x2, combine, idx, first(ins, "WGate"), first(ins, "WUp"),
         first(ins, "WDown"), int(attrs.get("held_start", 0)), valid)
-    y = y + swiglu(x2, first(ins, "SGate"), first(ins, "SUp"),
-                   first(ins, "SDown"))
+    with _phase("shared"):
+        y = y + swiglu(x2, first(ins, "SGate"), first(ins, "SUp"),
+                       first(ins, "SDown"))
     out = {"Out": [y.astype(x.dtype).reshape(b, t, m)]}
     counts = first(ins, "Counts")
     if counts is not None:

@@ -37,14 +37,18 @@ HIGHEST would cost more MXU passes than the state costs HBM time.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
+from paddle_tpu.observability import device_scopes as _device_scopes
 from paddle_tpu.ops.math_ops import dense
 
 F32 = jnp.float32
 L2_EPS = 1e-6
+_phase = functools.partial(_device_scopes.phase, "kda_decode")
 
 _WEIGHTS = ("Wq", "Wk", "Wv", "Wo", "ConvW", "ALog", "DtBias", "WaDown",
             "WaUp", "WBeta", "WgDown", "WgUp", "ONorm")
@@ -174,13 +178,18 @@ def _kda_decode(ctx, ins, attrs):
     active = jnp.asarray(first(ins, "Active")).reshape(-1) > 0
 
     u, g, beta, gate = _token_terms(x[:, 0], w, h, d)
-    window = jnp.concatenate([conv, u[:, None].astype(conv.dtype)], axis=1)
-    c = jnp.sum(w["ConvW"].astype(F32)[None] * window.astype(F32), axis=1)
-    q, k, v = _qkv(c, h, d)
-    s_new, o = _delta_step(state, q, k, v, g, beta.reshape(b, h))
+    with _phase("conv"):
+        window = jnp.concatenate([conv, u[:, None].astype(conv.dtype)],
+                                 axis=1)
+        c = jnp.sum(w["ConvW"].astype(F32)[None] * window.astype(F32),
+                    axis=1)
+        q, k, v = _qkv(c, h, d)
+    with _phase("state"):
+        s_new, o = _delta_step(state, q, k, v, g, beta.reshape(b, h))
     y = _output(o, gate, w, eps, dt)
-    return {"Out": [y[:, None]],
-            "StateOut": [jnp.where(active[:, None, None, None], s_new,
-                                   state)],
-            "ConvOut": [jnp.where(active[:, None, None], window[:, 1:],
-                                  conv)]}
+    with _phase("state"):
+        state_out = jnp.where(active[:, None, None, None], s_new, state)
+    with _phase("conv"):
+        conv_out = jnp.where(active[:, None, None], window[:, 1:], conv)
+    return {"Out": [y[:, None]], "StateOut": [state_out],
+            "ConvOut": [conv_out]}

@@ -78,10 +78,13 @@ of the decode executable is position-free by construction.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
+from paddle_tpu.observability import device_scopes as _device_scopes
 from paddle_tpu.observability import metrics as _metrics
 
 from paddle_tpu.ops import attention_block as _ab
@@ -518,21 +521,26 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     # for inactive slots — a free slot's pages are bit-identical before
     # and after the step, same contract as kv_attention_decode's gated
     # one-hot write
-    wpage = jnp.take_along_axis(table, (pos // ps)[:, None],
-                                axis=1)[:, 0]
-    wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
-    flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, mk))
-    flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mk))
+    phase = functools.partial(_device_scopes.phase,
+                              "kv_attention_decode_paged")
+    with phase("write"):
+        wpage = jnp.take_along_axis(table, (pos // ps)[:, None],
+                                    axis=1)[:, 0]
+        wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
+        flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, mk))
+        flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mk))
 
     # gather every slot's logical cache through its table row
-    kk = _paged_gather(flat_k, fks, table, ps, dt, ctx.mesh)  # [B,S,M]
-    vv = _paged_gather(flat_v, fvs, table, ps, dt, ctx.mesh)
+    with phase("gather"):
+        kk = _paged_gather(flat_k, fks, table, ps, dt, ctx.mesh)  # [B,S,M]
+        vv = _paged_gather(flat_v, fvs, table, ps, dt, ctx.mesh)
 
-    j = jnp.arange(s_len, dtype=jnp.int32)
-    valid = (j[None, :] < lens[:, None]) | \
-            ((j[None, :] >= gen0[:, None]) &
-             (j[None, :] <= pos[:, None]))           # [B,S]
-    c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv)
+    with phase("attend"):
+        j = jnp.arange(s_len, dtype=jnp.int32)
+        valid = (j[None, :] < lens[:, None]) | \
+                ((j[None, :] >= gen0[:, None]) &
+                 (j[None, :] <= pos[:, None]))           # [B,S]
+        c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv)
     if gqa is not None:
         out = _gqa_output(x, c.reshape(b, 1, -1), first(ins, "Wg"), wo)
     else:
@@ -592,23 +600,29 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     # window position i writes logical position pos + i; resolve each
     # through the page table, sentinel for inactive rows, positions at
     # or past win_len, and positions past the table span
-    i = jnp.arange(k1, dtype=jnp.int32)
-    wp = pos[:, None] + i[None, :]                          # [B,K1]
-    wpage = jnp.take_along_axis(table, jnp.clip(wp // ps, 0, mp - 1),
-                                axis=1)
-    ok = active[:, None] & (i[None, :] < wlen[:, None]) & (wp < s_len)
-    wrow = jnp.where(ok, wpage * ps + wp % ps, rtot).reshape(-1)
-    flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(-1, m))
-    flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(-1, m))
+    phase = functools.partial(_device_scopes.phase,
+                              "kv_attention_verify_paged")
+    with phase("write"):
+        i = jnp.arange(k1, dtype=jnp.int32)
+        wp = pos[:, None] + i[None, :]                          # [B,K1]
+        wpage = jnp.take_along_axis(table, jnp.clip(wp // ps, 0, mp - 1),
+                                    axis=1)
+        ok = active[:, None] & (i[None, :] < wlen[:, None]) & (wp < s_len)
+        wrow = jnp.where(ok, wpage * ps + wp % ps, rtot).reshape(-1)
+        flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(-1, m))
+        flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(-1, m))
 
-    kk = _paged_gather(flat_k, fks, table, ps, dt, ctx.mesh)  # [B,S,M]
-    vv = _paged_gather(flat_v, fvs, table, ps, dt, ctx.mesh)
+    with phase("gather"):
+        kk = _paged_gather(flat_k, fks, table, ps, dt, ctx.mesh)  # [B,S,M]
+        vv = _paged_gather(flat_v, fvs, table, ps, dt, ctx.mesh)
 
-    j = jnp.arange(s_len, dtype=jnp.int32)
-    valid = (j[None, None, :] < lens[:, None, None]) | \
-            ((j[None, None, :] >= gen0[:, None, None]) &
-             (j[None, None, :] <= (pos[:, None] + i[None, :])[:, :, None]))
-    c = _decode_contract(q, kk, vv, valid, dt)        # [B,K1,S] mask
+    with phase("attend"):
+        j = jnp.arange(s_len, dtype=jnp.int32)
+        valid = (j[None, None, :] < lens[:, None, None]) | \
+                ((j[None, None, :] >= gen0[:, None, None]) &
+                 (j[None, None, :]
+                  <= (pos[:, None] + i[None, :])[:, :, None]))
+        c = _decode_contract(q, kk, vv, valid, dt)        # [B,K1,S] mask
     out = jax.lax.dot_general(c, wo.reshape(h, -1, m),
                               (((2, 3), (0, 1)), ((), ())),
                               preferred_element_type=jnp.float32).astype(dt)

@@ -66,10 +66,13 @@ softmax float32.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
+from paddle_tpu.observability import device_scopes as _device_scopes
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.ops import kv_attention as _kv
 from paddle_tpu.ops.math_ops import dense
@@ -105,6 +108,8 @@ MLA_DECODE_LOWERED = _metrics.counter(
     "paddle_mla_decode_lowered_total",
     "Latent-attention decode layers lowered, by path (pages|rows)",
     labelnames=("path",))
+
+_phase = functools.partial(_device_scopes.phase, "mla_decode_paged")
 
 _WEIGHTS = ("Wdq", "QNorm", "Wuq", "Wdkv", "KvNorm", "Wuk", "Wuv", "Wo",
             "Wiq", "Wik", "IkScale", "IkBias", "Wiw")
@@ -417,63 +422,78 @@ def _mla_decode_paged(ctx, ins, attrs):
     pos, lens, gen0 = vec("Pos"), vec("SeqLen"), vec("GenStart")
     active = vec("Active") > 0
 
-    q, row, ki, qi, wi = token_terms(
-        x[:, 0], w, vec("Position"), a, float(attrs["rope_theta"]),
-        float(attrs.get("epsilon", 1e-5)))
-    wpage = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
-    wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
-    flat_c = flat_c.at[wrow].set(row.astype(flat_c.dtype), mode="drop")
-    flat_i = flat_i.at[wrow].set(ki.astype(flat_i.dtype), mode="drop")
+    # the op's four mechanisms, each under its declared phase
+    # (observability/device_scopes.py)
+    with _phase("project"):
+        q, row, ki, qi, wi = token_terms(
+            x[:, 0], w, vec("Position"), a, float(attrs["rope_theta"]),
+            float(attrs.get("epsilon", 1e-5)))
+        wpage = jnp.take_along_axis(table, (pos // ps)[:, None],
+                                    axis=1)[:, 0]
+        wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
+        flat_c = flat_c.at[wrow].set(row.astype(flat_c.dtype), mode="drop")
+    with _phase("index"):
+        flat_i = flat_i.at[wrow].set(ki.astype(flat_i.dtype), mode="drop")
 
     valid = live_rows(jnp.arange(s_len, dtype=jnp.int32), lens, gen0, pos)
     topk = a["index_topk"]
     in_place = attends_in_place(flat_c, ps, s_len, topk, ctx.mesh)
     MLA_DECODE_LOWERED.labels(path="pages" if in_place else "rows").inc()
     if s_len > topk:
-        keys = _kv._paged_gather(flat_i, None, table, ps, dt, ctx.mesh)
-        scores = _slot_scores(qi, wi, keys)
+        with _phase("index"):
+            keys = _kv._paged_gather(flat_i, None, table, ps, dt, ctx.mesh)
+            scores = _slot_scores(qi, wi, keys)
     if in_place:
         from paddle_tpu.ops import pallas as _plk
         from paddle_tpu.ops.pallas import paged_attention as _pk
         # the selection as a MASK (a row of no live entry would keep
         # everything: the two thresholds are both -inf there), and the
         # kernel reads each active slot's live pages whole under it
-        keep = select_topk(scores, valid, topk) & valid
-        sel = selected_rows(keep, topk)
-        u = _pk.attend_pages(
-            absorbed_query(q, w, a, flat_c.dtype, flat_c.shape[-1]),
-            flat_c, table, jnp.where(active, lens, 0), gen0,
-            jnp.where(active, pos, -1), keep, ps, attention_scale(a),
-            value_width=latent_width(a["kv_lora_rank"], 0),
-            interpret=_plk.interpret_mode())
-        o = absorbed_context(u.astype(dt), w, a)
+        with _phase("select"):
+            keep = select_topk(scores, valid, topk) & valid
+            sel = selected_rows(keep, topk)
+        with _phase("attend"):
+            u = _pk.attend_pages(
+                absorbed_query(q, w, a, flat_c.dtype, flat_c.shape[-1]),
+                flat_c, table, jnp.where(active, lens, 0), gen0,
+                jnp.where(active, pos, -1), keep, ps, attention_scale(a),
+                value_width=latent_width(a["kv_lora_rank"], 0),
+                interpret=_plk.interpret_mode())
+            o = absorbed_context(u.astype(dt), w, a)
     elif s_len > topk:
-        top, sel = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), topk)
-        # attend the chosen rows in the order they lie in the cache
-        # (softmax does not care, the gather does: ascending rows are
-        # neighbours in a page more often than rows in score order);
-        # what is not live sorts last
-        sel = jnp.sort(jnp.where(top > -jnp.inf, sel, s_len), axis=-1)
-        valid = sel < s_len
-        # the page of each selected row, by comparing against the
-        # table's columns: a gather of 65536 scalars took XLA 0.67 ms a
-        # layer on the v5e, this reduction is a fifth of it (PR 33)
-        page = jnp.sum(jnp.where(
-            (sel // ps)[:, :, None] == jnp.arange(table.shape[1]),
-            table[:, None, :], 0), axis=-1)
-        # where fewer rows are live (an empty slot: none) the gather
-        # still reads index_topk rows, masked after: distinct ones — the
-        # same row 2048 times a slot made the gather twice as slow
-        spare = jnp.arange(b * topk, dtype=jnp.int32).reshape(b, topk)
-        at = jnp.where(valid, page * ps + sel % ps, spare % rtot)
-        rows = jnp.take(flat_c, at.reshape(-1), axis=0, mode="clip")\
-            .reshape(b, topk, -1).astype(dt)
-        sel = jnp.where(valid, sel, -1)
-        o = absorbed_attention(q, rows, valid, w, a)
+        with _phase("select"):
+            top, sel = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf),
+                                     topk)
+            # attend the chosen rows in the order they lie in the cache
+            # (softmax does not care, the gather does: ascending rows
+            # are neighbours in a page more often than rows in score
+            # order); what is not live sorts last
+            sel = jnp.sort(jnp.where(top > -jnp.inf, sel, s_len), axis=-1)
+            valid = sel < s_len
+            # the page of each selected row, by comparing against the
+            # table's columns: a gather of 65536 scalars took XLA 0.67
+            # ms a layer on the v5e, this reduction is a fifth of it
+            # (PR 33)
+            page = jnp.sum(jnp.where(
+                (sel // ps)[:, :, None] == jnp.arange(table.shape[1]),
+                table[:, None, :], 0), axis=-1)
+        with _phase("attend"):
+            # where fewer rows are live (an empty slot: none) the gather
+            # still reads index_topk rows, masked after: distinct ones —
+            # the same row 2048 times a slot made the gather twice as
+            # slow
+            spare = jnp.arange(b * topk, dtype=jnp.int32).reshape(b, topk)
+            at = jnp.where(valid, page * ps + sel % ps, spare % rtot)
+            rows = jnp.take(flat_c, at.reshape(-1), axis=0, mode="clip")\
+                .reshape(b, topk, -1).astype(dt)
+            sel = jnp.where(valid, sel, -1)
+            o = absorbed_attention(q, rows, valid, w, a)
     else:
-        rows = _kv._paged_gather(flat_c, None, table, ps, dt, ctx.mesh)
-        sel = jnp.where(valid, jnp.arange(s_len, dtype=jnp.int32)[None],
-                        -1)
-        o = absorbed_attention(q, rows, valid, w, a)
-    out = dense(o, w["Wo"], dt)[:, None]
+        with _phase("attend"):
+            rows = _kv._paged_gather(flat_c, None, table, ps, dt, ctx.mesh)
+            sel = jnp.where(valid,
+                            jnp.arange(s_len, dtype=jnp.int32)[None], -1)
+            o = absorbed_attention(q, rows, valid, w, a)
+    with _phase("project"):
+        out = dense(o, w["Wo"], dt)[:, None]
     return _result(out, flat_c, flat_i, n_pages, ps, Selected=sel)

@@ -51,6 +51,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from paddle_tpu.observability import device_scopes
 from paddle_tpu.observability import runtime as obs_runtime
 from paddle_tpu.observability import trace_context as tctx
 from paddle_tpu.serving import bucketing, kv_pool
@@ -119,6 +120,20 @@ def load_executable(path: str):
         warnings.warn(f"AOT executable {path} did not load: "
                       f"{type(e).__name__}: {e}", stacklevel=2)
         return None
+
+
+class _Loaded:
+    """An engine's executables loaded ahead of time (its ``_aot``
+    table, shared): they dispatch in place of the blocks' own, so where
+    an engine has them ``observability.device_scopes`` reads its
+    programs' names from them. Holds nothing else of the engine."""
+
+    def __init__(self, table: dict):
+        self._table = table
+        device_scopes.register(self)
+
+    def device_executables(self) -> list:
+        return list(self._table.values())
 
 
 class ServedModel:
@@ -297,6 +312,7 @@ class GenerativeModel:
                 is_test=True, donate=False, dist=dist)
         self._warmed: set = set()   # ("prefill", bucket, P) | ("decode", bucket)
         self._aot: Dict[Tuple, object] = {}
+        self._aot_names = _Loaded(self._aot)
         self._fingerprint = hashlib.sha256(json.dumps(
             [pre[p][0].desc.to_dict() for p in self.prompt_buckets]
             + [dec_main.desc.to_dict()],
@@ -352,7 +368,8 @@ class GenerativeModel:
             faults.inject("serving.dispatch")
             aot = self._aot.get(aot_key)
             # compile events of this thread count under the block's name
-            with obs_runtime.dispatching(cb.obs_label):
+            with obs_runtime.dispatching(cb.obs_label, cb._exes.note,
+                                         args):
                 if aot is not None:
                     try:
                         fetches, new_state = aot(*args)
@@ -894,6 +911,7 @@ class SlotGenerativeModel:
         self._discover_state(dec_main, pre[self.prompt_len][2])
         self._warmed: set = set()
         self._aot: Dict[Tuple, object] = {}
+        self._aot_names = _Loaded(self._aot)
         self._fingerprint = hashlib.sha256(json.dumps(
             [pre[p][0].desc.to_dict() for p in self.prompt_buckets]
             + [dec_main.desc.to_dict()]
