@@ -273,6 +273,9 @@ def _preregister_catalog():
                 # which way a latent-attention decode layer attends
                 # (paddle_mla_decode_lowered_total{path})
                 "paddle_tpu.ops.mla",
+                # which tier advances a KDA decode layer's state
+                # (paddle_kda_decode_lowered_total{path})
+                "paddle_tpu.ops.kda",
                 "paddle_tpu.distributed.sharded_table"):
         try:
             importlib.import_module(mod)

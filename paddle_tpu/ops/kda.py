@@ -29,10 +29,22 @@ persistable and donated (updated in place).
 
 Precision: projections multiply in the storage dtype with float32
 accumulation; conv, norms, decay, beta, gates and the whole recurrence
-are float32. The state update is written as multiply-and-reduce on the
-VPU (two reads and one write of the state a step), not as D x D matrix
-products: a [2, D] x [D, D] product per (slot, head) at precision
-HIGHEST would cost more MXU passes than the state costs HBM time.
+are float32. The state update is multiply-and-reduce on the VPU, not
+D x D matrix products: a [2, D] x [D, D] product per (slot, head) at
+precision HIGHEST would cost more MXU passes than the state costs HBM
+time.
+
+``kda_decode``'s update has two tiers of one algorithm, chosen at
+lowering by what the code can observe (``_state_tier``;
+``paddle_kda_decode_lowered_total{path}`` counts which):
+
+- ``kernel``: ``ops/pallas/kda_state.py`` — a block of heads of one slot
+  in VMEM, ONE read and one write of the state a step, in place. On the
+  chip, off a mesh, for a float32 state whose [D, D] tile is whole lane
+  tiles.
+- ``refer``: ``_delta_step`` as XLA fuses it — a reduction over the old
+  state, then an elementwise pass that reads it again: two reads and
+  one write. Everywhere else, and always ``kda_prefill``'s loop body.
 """
 
 from __future__ import annotations
@@ -44,11 +56,24 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
 from paddle_tpu.observability import device_scopes as _device_scopes
+from paddle_tpu.observability import metrics as _metrics
+from paddle_tpu.ops import pallas as _plk
 from paddle_tpu.ops.math_ops import dense
+from paddle_tpu.ops.pallas import kda_state as _ks
 
 F32 = jnp.float32
 L2_EPS = 1e-6
 _phase = functools.partial(_device_scopes.phase, "kda_decode")
+
+# exporter-catalog family (docs/observability.md). Counts LOWERINGS, as
+# ``paddle_kv_gather_lowered_total`` does: one increment per KDA layer
+# each time a decode program is traced, labelled with the tier that
+# advances its state — ``kernel`` (one read of the state a step) or
+# ``refer`` (``_delta_step``: two).
+KDA_DECODE_LOWERED = _metrics.counter(
+    "paddle_kda_decode_lowered_total",
+    "KDA decode layers lowered, by the state update's tier (kernel|refer)",
+    labelnames=("path",))
 
 _WEIGHTS = ("Wq", "Wk", "Wv", "Wo", "ConvW", "ALog", "DtBias", "WaDown",
             "WaUp", "WBeta", "WgDown", "WgUp", "ONorm")
@@ -96,6 +121,19 @@ def _delta_step(s, q, k, v, g, beta):
     s_new = alpha[..., :, None] * s + bk[..., :, None] * dv[..., None, :]
     o = red[..., 1, :] + jnp.sum(q * bk, axis=-1, keepdims=True) * dv
     return s_new, o
+
+
+def _state_tier(state, mesh=None) -> str:
+    """``kernel`` where the Pallas update runs — on a TPU, off a mesh,
+    a float32 state of whole lane tiles — or is forced onto the
+    interpreter (``pallas.forced_interpret``, the tests' way in);
+    ``refer`` otherwise."""
+    _, h, d, _ = state.shape
+    if not _ks.supported(h, d, state.dtype):
+        return "refer"
+    if _plk.kernel_enabled(128, d, mesh=mesh) or _plk.forced_interpret():
+        return "kernel"
+    return "refer"
 
 
 def _output(o, gate, w, eps, dt):
@@ -184,11 +222,19 @@ def _kda_decode(ctx, ins, attrs):
         c = jnp.sum(w["ConvW"].astype(F32)[None] * window.astype(F32),
                     axis=1)
         q, k, v = _qkv(c, h, d)
+    beta = beta.reshape(b, h)
+    tier = _state_tier(state, ctx.mesh)
+    KDA_DECODE_LOWERED.labels(path=tier).inc()
     with _phase("state"):
-        s_new, o = _delta_step(state, q, k, v, g, beta.reshape(b, h))
+        if tier == "kernel":
+            state_out, o = _ks.kda_state_update(
+                state, q, k, v, g, beta, active,
+                interpret=_plk.interpret_mode())
+        else:
+            s_new, o = _delta_step(state, q, k, v, g, beta)
+            state_out = jnp.where(active[:, None, None, None], s_new,
+                                  state)
     y = _output(o, gate, w, eps, dt)
-    with _phase("state"):
-        state_out = jnp.where(active[:, None, None, None], s_new, state)
     with _phase("conv"):
         conv_out = jnp.where(active[:, None, None], window[:, 1:], conv)
     return {"Out": [y[:, None]], "StateOut": [state_out],

@@ -642,9 +642,7 @@ def _fused_linear_ce(ctx, ins, attrs):
     v = w.shape[1]
     use_kernel = (pk.kernel_enabled(128, d, mesh=ctx.mesh)
                   and fce.supported(n, d, v)) \
-        or (pk.interpret_mode()
-            and __import__("os").environ.get(
-                "PADDLE_TPU_FORCE_PALLAS", "0") == "1")
+        or pk.forced_interpret()
     if use_kernel:
         loss = fce.fused_linear_ce(x, w, label.reshape(-1), eps, ignore,
                                    pk.interpret_mode())

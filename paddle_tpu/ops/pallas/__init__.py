@@ -42,6 +42,14 @@ def interpret_mode() -> bool:
     return not on_tpu()
 
 
+def forced_interpret() -> bool:
+    """The tests' way into a kernel inside a whole program on the CPU:
+    with PADDLE_TPU_FORCE_PALLAS=1 an emitter that has no TPU runs its
+    kernel on the interpreter. Never true on a chip."""
+    return (interpret_mode()
+            and os.environ.get("PADDLE_TPU_FORCE_PALLAS", "0") == "1")
+
+
 def kernel_enabled(min_align: int = 128, *dims, mesh=None) -> bool:
     """Pallas path is worth it only when the lane dims align to hardware
     tiles; otherwise the refer (jnp) tier wins.

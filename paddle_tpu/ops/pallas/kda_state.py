@@ -1,0 +1,161 @@
+"""Kimi Delta Attention's decode-step state update (Pallas TPU): every
+slot's and head's recurrent state ``S [D, D]`` float32 advanced by one
+token with ONE read and ONE write of the state, in place.
+
+``ops/kda.py:_delta_step`` (the refer tier, and ``kda_prefill``'s loop
+body) is the algebra; XLA makes two fusions of it — a reduction over the
+old state, then an elementwise pass that reads the state again — so the
+state crosses HBM three times a step where the algorithm needs two. Here
+a block of heads of one slot is one VMEM tile: the two reductions over
+the key-channel axis, ``dv = v - u``, the rank-one update and ``o`` all
+read the tile once, and the new state goes back to the buffer it came
+from (``input_output_aliases``).
+
+Layout. A state tile has the key channel ``i`` on the sublanes and the
+value channel ``j`` on the lanes, so what the update scales ROWS by
+(``alpha_i``, ``k_i alpha_i``, ``q_i alpha_i``, ``beta k_i``) must be
+broadcast along lanes. An operand ``[.., D, 1]`` would pad 128-fold in
+HBM; q, k and g come in as ``[heads, D]`` tiles (D on the lanes, as they
+are computed), the kernel makes the four vectors of them and transposes
+the ``[4 * heads, D]`` tile once a grid step on the XLU: column ``c`` of
+the transposed tile is vector ``c // heads`` of head ``c % heads``, a
+static lane slice. What is indexed by ``j`` (``v``) stays a row; a
+head's scalars (``beta``, ``q . beta k``) are read from SMEM. All
+products and sums are float32 on the VPU (a [2, D] x [D, D] product at
+precision HIGHEST would cost more MXU passes than the tile costs HBM
+time), and all of them hide under the tile's DMA: the kernel takes what
+a copy of the state through VMEM takes (PERF.md section 6, PR 36)."""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+F32 = jnp.float32
+_LANES = 128
+# a grid step's block of the state: 32 heads of [128, 128] (whose 4 x 32
+# vectors fill the [128, 128] tile the XLU transposes) are 2 MB in and
+# 2 MB out, 8 MB double-buffered
+BLOCK_BYTES = 2 << 20
+# heads of a block the kernel's Python writes out; the block is a loop
+# over groups of them. Every head written out is MLIR to build EACH time
+# a decode program is lowered, warm compile cache or not: all 32 cost the
+# hybrid cell 5 s of a 21 s set-up (0.27 s a lowering of the kernel on
+# the chip's host, about a dozen of them); 4 cost 0.05 s a lowering. One
+# head a turn of the loop leaves its arithmetic in the open (the kernel
+# alone at the cell's shape, ms: 32 written out 1.699, 8 1.702, 4 1.704,
+# 2 1.717, 1 1.954: my chip run, PR 36).
+UNROLL = 4
+
+
+def head_block(n_head: int, head_dim: int) -> int:
+    """Heads a grid step: the largest count whose state block is at most
+    ``BLOCK_BYTES``, divides ``n_head`` and keeps the vectors' [heads, D]
+    tiles whole sublane tiles (0: no such block, the refer tier runs)."""
+    most = min(n_head, BLOCK_BYTES // (4 * head_dim * head_dim))
+    for hb in range(most, 0, -1):
+        if n_head % hb == 0 and hb % 8 == 0:
+            return hb
+    return 0
+
+
+def supported(n_head: int, head_dim: int, dtype) -> bool:
+    """What the kernel is written for: a float32 state whose [D, D] tile
+    is whole lane tiles, heads that block."""
+    return (dtype == F32 and head_dim % _LANES == 0
+            and head_block(n_head, head_dim) > 0)
+
+
+def _advance(s, alpha, ka, qa, bk, v, qbk):
+    """``ops/kda.py:_delta_step`` on one head's tile s [D, D]: alpha,
+    ka = k * alpha, qa = q * alpha, bk = beta * k are [D, 1] (a key
+    channel a row), v is [1, D], qbk = q . bk the head's scalar ->
+    (s_new, o [1, D])."""
+    u = jnp.sum(ka * s, axis=0, keepdims=True)
+    red_q = jnp.sum(qa * s, axis=0, keepdims=True)
+    dv = v - u
+    return alpha * s + bk * dv, red_q + qbk * dv
+
+
+def _kda_state_kernel(active_ref, beta_ref, qbk_ref, s_ref, q_ref, k_ref,
+                      v_ref, g_ref, so_ref, o_ref, *, hb, d, n_head, unroll):
+    slot = pl.program_id(0)
+    live = active_ref[slot] > 0
+    first = slot * n_head + pl.program_id(1) * hb
+    k = k_ref[0]
+    alpha = jnp.exp(g_ref[0])                               # [hb, D]
+    # four vectors of hb heads, padded to a square the XLU transposes:
+    # t[i, n * hb + h] = vector n of head h at key channel i
+    cols = jnp.concatenate([alpha, k * alpha, q_ref[0] * alpha, k], axis=0)
+    side = -(-max(4 * hb, d) // _LANES) * _LANES
+    cols = jnp.pad(cols, ((0, side - 4 * hb), (0, side - d)))
+
+    def group(gi, t):
+        """``unroll`` heads written out (a lane slice must be static);
+        then the next group's columns rotate to the front."""
+        for h in range(unroll):
+            head = gi * unroll + h
+            a_col, ka, qa, k_col = (t[:, n * hb + h:n * hb + h + 1]
+                                    for n in range(4))      # [D, 1]
+            s = s_ref[0, head]
+            s_new, o = _advance(
+                s, a_col, ka, qa, beta_ref[first + head] * k_col,
+                v_ref[0, pl.ds(head, 1), :], qbk_ref[first + head])
+            so_ref[0, head] = jnp.where(live, s_new, s)
+            o_ref[0, pl.ds(head, 1), :] = o
+        return pltpu.roll(t, side - unroll, axis=1)
+    jax.lax.fori_loop(0, hb // unroll, group, cols.T[:d])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "unroll", "interpret"))
+def kda_state_update(state, q, k, v, g, beta, active, heads: int = 0,
+                     unroll: int = UNROLL, interpret=False):
+    """One step of the gated delta rule for every slot and head: state
+    [B, H, D, D] float32 (aliased to the result: in place where the
+    caller donates it), q, k, v, g [B, H, D] float32, beta [B, H],
+    active [B] (a slot with 0 keeps its state bit for bit; its ``o`` is
+    not meaningful) -> (new state, o [B, H, D]) — the state FIRST: the
+    device trace names a kernel by the first shape of its result, and
+    the benchmark's reader of the state's time selects on it. ``heads``
+    a grid step (0: :func:`head_block`).
+
+    Beside the state the kernel reads q, k, v, g and writes o, 2 % of
+    the state's bytes; what a head scales by as a whole (``beta`` and
+    ``q . beta k``, [B, H]) rides in SMEM."""
+    b, h, d, _ = state.shape
+    hb = heads or head_block(h, d)
+    if not hb or h % hb:
+        raise ValueError(f"no block of {heads or 'whole tiles of'} heads "
+                         f"divides {h}")
+    unroll = min(unroll, hb)
+    if hb % unroll:
+        raise ValueError(f"{unroll} heads written out do not divide a "
+                         f"block of {hb}")
+    qbk = jnp.sum(q * (beta[..., None] * k), axis=-1)
+    tile = pl.BlockSpec((1, hb, d, d), lambda i, j, *_: (i, j, 0, 0))
+    vec = pl.BlockSpec((1, hb, d), lambda i, j, *_: (i, j, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,          # active, beta, q . beta k: SMEM
+        grid=(b, h // hb),
+        in_specs=[tile, vec, vec, vec, vec],
+        out_specs=[tile, vec],
+    )
+    return pl.pallas_call(
+        functools.partial(_kda_state_kernel, hb=hb, d=d, n_head=h,
+                          unroll=unroll),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, F32),
+                   jax.ShapeDtypeStruct((b, h, d), F32)],
+        # operands 0-2 are the prefetched scalars, 3 the state
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        name="kda_state_update",
+        interpret=interpret,
+    )(active.astype(jnp.int32).reshape(b), beta.astype(F32).reshape(b * h),
+      qbk.reshape(b * h), state, q, k, v, g)

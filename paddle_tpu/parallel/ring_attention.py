@@ -37,10 +37,8 @@ def _use_flash_blocks(tq, tk, d):
     """Route the per-shard block compute through the Pallas flash kernel
     when it can tile (TPU + lane-aligned head dim), or when forced for
     interpret-mode testing."""
-    import os
     from paddle_tpu.ops import pallas as pk
-    if (os.environ.get("PADDLE_TPU_FORCE_PALLAS", "0") == "1"
-            and pk.interpret_mode()):
+    if pk.forced_interpret():
         # test-only override: interpret mode has no tiling constraints;
         # on real TPU the alignment gate below always applies
         return tq % 8 == 0 and tk % 8 == 0
