@@ -474,7 +474,8 @@ class CompiledBlock:
         an instruction name means one thing under each."""
         name = _device_scopes.module_name(
             self.obs_label + (f"_x{iterations}" if iterations > 1 else ""),
-            (op.type for b in self._program_desc.blocks for op in b.ops))
+            (key for b in self._program_desc.blocks for op in b.ops
+             for key in _device_scopes.scope_keys(op)))
         fn.__name__ = fn.__qualname__ = name
         jitted = self._exes.jitted[(iterations, snames)] = jax.jit(
             fn, **jit_kwargs)

@@ -657,13 +657,15 @@ def _attention_projection_params(helper, d_model, param_attr):
 
 
 def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
-    """([Wq, Wk, Wv, Wo], {"Wg": [gate]} or {}) of a paged attention
-    layer. ``gqa`` None is the multi-head family's four [M, M] float32
+    """([Wq, Wk, Wv, Wo], the layer's further inputs: {"Wg": [gate]},
+    {"QNorm": .., "KNorm": ..} or {}) of a paged attention layer.
+    ``gqa`` None is the multi-head family's four [M, M] float32
     matrices and leaves ``attrs`` alone (its programs stay what they
-    were); ``gqa = {"n_kv_head", "head_dim", "gate"}`` declares a
-    grouped-KV layer in x's dtype — Wq / Wg [M, H*D], Wk / Wv
-    [M, n_kv*D], Wo [H*D, M], names ``<base>.wq`` ... ``.wg`` — and sets
-    the attrs the op reads them by."""
+    were); ``gqa = {"n_kv_head", "head_dim", "gate"}`` (and where the
+    layer has them ``qk_norm`` with ``rms_eps``, ``rope_theta``,
+    ``window``) declares a grouped-KV layer in x's dtype — Wq / Wg
+    [M, H*D], Wk / Wv [M, n_kv*D], Wo [H*D, M], names ``<base>.wq`` ...
+    ``.wg`` — and sets the attrs the op reads them by."""
     if gqa is None:
         return _attention_projection_params(helper, d_model, param_attr), {}
     import copy
@@ -679,8 +681,32 @@ def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
         a = copy.deepcopy(param_attr)
         a.name = f"{a.name}.{tag}"
         ws[tag] = helper.create_parameter(a, shape=shape, dtype=x.dtype)
-    return ([ws[t] for t in ("wq", "wk", "wv", "wo")],
-            {"Wg": [ws["wg"]]} if "wg" in ws else {})
+    extra = {"Wg": [ws["wg"]]} if "wg" in ws else {}
+    # what only some grouped-KV layers have, attrs and inputs alike set
+    # only where the layer has it: a norm of each q and k head (gains
+    # ``.q_norm`` / ``.k_norm`` [D]), rotary positions, a window
+    if gqa.get("qk_norm"):
+        from paddle_tpu.fluid.param_attr import ParamAttr
+        attrs.update(qk_norm=True, rms_eps=float(gqa["rms_eps"]))
+        for slot, tag in (("QNorm", "q_norm"), ("KNorm", "k_norm")):
+            extra[slot] = [helper.create_parameter(
+                ParamAttr(name=f"{param_attr.name}.{tag}"), shape=[d],
+                dtype=x.dtype, default_initializer=ConstantInitializer(1.0))]
+    if gqa.get("rope_theta"):
+        attrs["rope_theta"] = float(gqa["rope_theta"])
+    if gqa.get("window"):
+        attrs["window"] = int(gqa["window"])
+    return [ws[t] for t in ("wq", "wk", "wv", "wo")], extra
+
+
+def _attended_output(helper, gqa) -> dict:
+    """A window layer's ``Attended`` output, named ``<weights'
+    base>_attended`` so that a check can fetch it (per query the lowest
+    key position attended and how many)."""
+    if not gqa or not gqa.get("window"):
+        return {}
+    return {"Attended": [helper.block.create_var(
+        name=gqa["attended_name"], dtype="int32")]}
 
 
 def rms_norm(x, epsilon=1e-5, param_attr=None, name=None):
@@ -988,7 +1014,7 @@ def kv_attention_prefill_paged(x, rows, d_model, n_head, page_k, page_v,
               "Wv": [ws[2]], "Wo": [ws[3]], **gate,
               "PageK": [page_k], "PageV": [page_v], "Rows": [rows]}
     outputs = {"Out": [out], "PageKOut": [page_k],
-               "PageVOut": [page_v]}
+               "PageVOut": [page_v], **_attended_output(helper, gqa)}
     if codec == "int8":
         inputs["PageKS"], inputs["PageVS"] = [page_ks], [page_vs]
         outputs["PageKSOut"], outputs["PageVSOut"] = [page_ks], [page_vs]
@@ -1025,7 +1051,7 @@ def kv_attention_decode_paged(x, page_table, pos, seq_len, gen_start,
               "SeqLen": [seq_len], "GenStart": [gen_start],
               "Active": [active]}
     outputs = {"Out": [out], "PageKOut": [page_k],
-               "PageVOut": [page_v]}
+               "PageVOut": [page_v], **_attended_output(helper, gqa)}
     if codec == "int8":
         inputs["PageKS"], inputs["PageVS"] = [page_ks], [page_vs]
         outputs["PageKSOut"], outputs["PageVSOut"] = [page_ks], [page_vs]

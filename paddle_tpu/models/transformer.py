@@ -16,6 +16,7 @@ import numpy as np
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.initializer import NumpyArrayInitializer
+from paddle_tpu.ops.kv_attention import window_ring
 
 
 def _const_var(name, value):
@@ -193,8 +194,15 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 _HYBRID_KEYS = {
     # the period of layer kinds, cycled over n_layer
     "layer_kinds": None,
-    # "gqa" layers: KV heads, the size of a head, the output gate
+    # "gqa" layers: KV heads, the size of a head, the output gate, a
+    # norm of every q and k head. "swa" layers are "gqa" layers that
+    # attend the last ``window`` positions alone, with rotary positions
+    # (``rope_theta``), cached in a page group of their own
     "n_kv_head": None, "head_dim": None, "gqa_gate": True,
+    "qk_norm": False, "window": None,
+    # a norm AFTER each sub-layer too (x + Norm(f(Norm(x)))), and a
+    # factor on the embedding
+    "post_norms": False, "embed_scale": 1.0,
     # "kda" layers
     "kda_heads": None, "kda_head_dim": None, "kda_conv_taps": 4,
     "kda_gate_rank": None,
@@ -216,6 +224,7 @@ _HYBRID_KEYS = {
 # the sizes only layers of one kind read: required where the kind occurs
 _KIND_KEYS = {
     "gqa": ("n_kv_head", "head_dim"),
+    "swa": ("n_kv_head", "head_dim", "window", "rope_theta"),
     "kda": ("kda_heads", "kda_head_dim", "kda_gate_rank"),
     "mla": ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
             "qk_rope_head_dim", "v_head_dim", "rope_theta",
@@ -237,8 +246,8 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
     if bad or not period:
         raise ValueError(f"layer_kinds {period}: a layer is one of "
                          f"{sorted(_KIND_KEYS)}")
-    unused = {k for kind, keys in _KIND_KEYS.items() if kind not in period
-              for k in keys}
+    unused = {k for keys in _KIND_KEYS.values() for k in keys} \
+        - {k for kind in period for k in _KIND_KEYS[kind]}
     missing = sorted(k for k, v in hy.items()
                      if v is None and k not in unused)
     if missing:
@@ -293,28 +302,42 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
 
     x = layers.embedding(x_ids, size=[vocab, d_model], dtype=dt,
                          param_attr=pa("emb", True))
+    if hy["embed_scale"] != 1.0:
+        x = layers.scale(x, scale=float(hy["embed_scale"]))
     for i, kind in enumerate(hy["kinds"]):
         y = layers.rms_norm(x, eps, pa(f"l{i}_ln1_scale"))
-        if kind == "gqa":
+        if kind in ("gqa", "swa"):
+            # a window layer's pools are its group's (fewer pages,
+            # another table: serving/kv_pool.py "Window group")
+            swa = kind == "swa"
             d = hy["head_dim"]
-            pshape = pools["shape"] + [hy["n_kv_head"] * d]
-            pk = pool_var(f"{name}_page_k_{i}", pshape, pools["dtype"])
-            pv = pool_var(f"{name}_page_v_{i}", pshape, pools["dtype"])
+            tag = "page_w" if swa else "page_"
+            shape = [pools["window_pages"] if swa else pools["shape"][0],
+                     pools["shape"][1]]
+            pshape = shape + [hy["n_kv_head"] * d]
+            pk = pool_var(f"{name}_{tag}k_{i}", pshape, pools["dtype"])
+            pv = pool_var(f"{name}_{tag}v_{i}", pshape, pools["dtype"])
             pks = pvs = None
             if pools["codec"] == "int8":
-                sshape = pools["shape"] + [hy["n_kv_head"]]
-                pks = pool_var(f"{name}_page_ks_{i}", sshape)
-                pvs = pool_var(f"{name}_page_vs_{i}", sshape)
+                sshape = shape + [hy["n_kv_head"]]
+                pks = pool_var(f"{name}_{tag}ks_{i}", sshape)
+                pvs = pool_var(f"{name}_{tag}vs_{i}", sshape)
             gqa = dict(n_kv_head=hy["n_kv_head"], head_dim=d,
-                       gate=hy["gqa_gate"])
-            attr = pa(f"l{i}_attn", True)    # the base of five names
+                       gate=hy["gqa_gate"], qk_norm=hy["qk_norm"],
+                       rms_eps=eps)
+            if swa:
+                gqa.update(window=hy["window"], rope_theta=hy["rope_theta"],
+                           attended_name=f"{name}_l{i}_attn_attended")
+            attr = pa(f"l{i}_attn", True)    # the base of its names
             if prefill:
                 y = layers.kv_attention_prefill_paged(
-                    y, feeds["page_rows"], d_model, n_head, pk, pv, pks,
-                    pvs, codec=pools["codec"], param_attr=attr, gqa=gqa)
+                    y, feeds["page_rows_w" if swa else "page_rows"],
+                    d_model, n_head, pk, pv, pks, pvs,
+                    codec=pools["codec"], param_attr=attr, gqa=gqa)
             else:
                 y = layers.kv_attention_decode_paged(
-                    y, feeds["page_table"], feeds["pos"], feeds["seq_len"],
+                    y, feeds["page_table_w" if swa else "page_table"],
+                    feeds["pos"], feeds["seq_len"],
                     feeds["gen_start"], feeds["active"], d_model, n_head,
                     pk, pv, pks, pvs, codec=pools["codec"],
                     param_attr=attr, gqa=gqa)
@@ -348,6 +371,8 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
                 hy["kda_gate_rank"], hy["kda_conv_taps"], eps,
                 **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
                    if prefill else dict(active=feeds["active"])))
+        if hy["post_norms"]:
+            y = layers.rms_norm(y, eps, pa(f"l{i}_ln1_post_scale"))
         x = layers.elementwise_add(x, y)
         y = layers.rms_norm(x, eps, pa(f"l{i}_ln2_scale"))
         if i < hy["first_k_dense"]:
@@ -365,6 +390,8 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
                     counts=pool_var(f"{name}_moe_counts_{i}",
                                     [2, hy["n_experts_held"]], "int32"))),
                 router_bias=hy["router_bias"])
+        if hy["post_norms"]:
+            y = layers.rms_norm(y, eps, pa(f"l{i}_ln2_post_scale"))
         x = layers.elementwise_add(x, y)
     x = layers.reshape(x, shape=[-1, d_model])
     if prefill:
@@ -525,6 +552,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                            append_batch_size=False)
 
     page_rows = page_table = state_slot = position = None
+    page_rows_w = page_table_w = None
     pos = gen_start = active = sample_step = None
     if mode == "decode":
         tok = layers.data(name="tok", shape=[1, 1], dtype="int64")
@@ -569,6 +597,11 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             # a ROW of the slot, and generated rows start at the bucket
             position = sdata("position", [S, 1])
             feed_specs["position"] = ([S, 1], "int64")
+        if hy is not None and "swa" in hy["kinds"]:
+            # the window group's table: a slot's RING of pages
+            ring = window_ring(hy["window"], page_size)
+            page_table_w = sdata("page_table_w", [S, ring])
+            feed_specs["page_table_w"] = ([S, ring], "int64")
         x_ids, t = tok, 1
     elif mode == "decode_verify_paged":
         S = int(n_slots)
@@ -619,6 +652,11 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
         # sentinel rows skip prefix-shared pages (already resident)
         page_rows = sdata("page_rows", [t, 1])
         feed_specs["page_rows"] = ([t, 1], "int64")
+        if hy is not None and "swa" in hy["kinds"]:
+            # the window group's row per prompt position: the sentinel
+            # for every position behind the first decode step's window
+            page_rows_w = sdata("page_rows_w", [t, 1])
+            feed_specs["page_rows_w"] = ([t, 1], "int64")
         if hy is not None and "kda" in hy["kinds"]:
             # which slot's recurrent state this request's prompt lands
             # in (>= n_slots: nowhere — the warm-up's dispatch)
@@ -635,11 +673,16 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
         logits = _hybrid_body(
             hy, mode, x_ids, name, vocab, d_model, d_inner, n_head, pool_var,
             pools=dict(shape=[n_pages, page_size], dtype=store_dt,
-                       codec=kv_codec, n_slots=int(n_slots)),
+                       codec=kv_codec, n_slots=int(n_slots),
+                       # every slot's ring, whatever the context
+                       window_pages=int(n_slots) * window_ring(
+                           hy["window"], page_size)
+                       if "swa" in hy["kinds"] else 0),
             feeds=dict(seq_len=seq_len, page_rows=page_rows,
                        state_slot=state_slot, page_table=page_table,
                        pos=pos, gen_start=gen_start, active=active,
-                       position=position))
+                       position=position, page_rows_w=page_rows_w,
+                       page_table_w=page_table_w))
         fill_pools()
         if mode == "prefill_paged":
             sample_step = layers.fill_constant([1, 1], "int64", 0)

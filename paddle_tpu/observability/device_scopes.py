@@ -22,6 +22,7 @@ time goes to the op that owns the root.
 
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 import time
@@ -43,6 +44,14 @@ PHASES = {
     "kv_attention_verify_paged": ("write", "gather", "attend"),
     "kda_decode": ("conv", "state"),
     "expert_ffn_held": ("route", "up", "down", "shared"),
+    # a VARIANT of an op (``<op type>/<variant>``): the scope an op
+    # lowers under, below its own, where an attribute makes it another
+    # mechanism — a layer that attends a window. A row of its own, and
+    # in a module's digest only where an op of the block is the variant
+    # (``scope_keys``), so the modules of programs without one keep
+    # their names
+    "kv_attention_decode_paged/window": ("write", "gather", "attend"),
+    "kv_attention_prefill_paged/window": (),
 }
 GRAD = "grad"
 
@@ -57,6 +66,26 @@ def phase(op_type: str, name: str):
             f"that the module names, and with them the compile-cache "
             f"keys, change")
     return jax.named_scope(name)
+
+
+def variant(op_type: str, name: str, on: bool = True):
+    """``with variant("kv_attention_decode_paged", "window"):`` — the
+    named scope of a declared variant of an op, below the op's own
+    (nothing where ``on`` is false)."""
+    if not on:
+        return contextlib.nullcontext()
+    if f"{op_type}/{name}" not in PHASES:
+        raise ValueError(f"{name!r} is not a declared variant of "
+                         f"{op_type!r}: add it to device_scopes.PHASES")
+    return jax.named_scope(name)
+
+
+def scope_keys(op) -> Tuple[str, ...]:
+    """The rows of ``PHASES`` that ``op`` lowers under: its type, and
+    the variant its attributes make it."""
+    if op.attrs.get("window") and f"{op.type}/window" in PHASES:
+        return op.type, f"{op.type}/window"
+    return (op.type,)
 
 
 def op_scope(op) -> str:
@@ -160,7 +189,7 @@ def program_scope(op_name: str) -> str:
     from paddle_tpu.core.registry import OPS
     for name in op_name.split(";"):
         parts = name.split("/")
-        out, phases = [], ()
+        out, phases, op_type = [], (), ""
         i, last = 0, len(parts) - 1
         while i < last:                  # the last one is the primitive
             part = parts[i]
@@ -171,7 +200,10 @@ def program_scope(op_name: str) -> str:
                 out.append(part)
             elif part in OPS or part == GRAD:
                 out.append(part)
-                phases = PHASES.get(part, ())
+                op_type, phases = part, PHASES.get(part, ())
+            elif out and f"{op_type}/{part}" in PHASES:
+                out.append(part)
+                phases = PHASES[f"{op_type}/{part}"]
             i += 1
         if out:
             return "/".join(out)

@@ -128,11 +128,14 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
             * _grouped(xs, w_up, sizes)
     with _phase("down"):
         ys = _grouped(hidden.astype(x.dtype), w_down, sizes)     # [N*K, M]
-        # back to assignment order; rows past the held groups are zero
-        # and carry the weight 0 besides
+        # back to assignment order; rows past the held groups are never
+        # computed: on the chip the grouped product leaves them as it
+        # found them (NaN now and then, which a weight of 0 does not
+        # silence: a padded position's NaN reached the next layer's
+        # keys; PERF.md, PR 37), so they are selected out, not weighed
         back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, -1)
-        w = jnp.where(held, combine, 0.0)
-        return jnp.sum(back * w[:, :, None], axis=1), sizes
+        back = jnp.where(held[:, :, None], back * combine[:, :, None], 0.0)
+        return jnp.sum(back, axis=1), sizes
 
 
 def _all_tokens(x, w):

@@ -190,23 +190,141 @@ def _gqa_output(x, c, wg, wo):
     return dense(c, wo, x.dtype)
 
 
-def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, h, n_kv, d):
-    """Causal self-attention of a grouped-KV layer over X [B,T,M] with
-    NO positions (the mask alone orders it), plus the K/V projections
-    [B,T,n_kv*d] the caller caches."""
+def rope_half(x, pos, theta: float):
+    """x [B, T, heads, D] float32 rotated at positions pos [B, T], the
+    rotate-half convention: (x[i], x[i + D/2]) turns by pos *
+    theta^(-2i/D) — all D dimensions, no scaling."""
+    d = x.shape[-1]
+    inv = float(theta) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[:, :, None, None] * inv    # [B,T,1,D/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _rms(x, scale, eps):
+    """x * scale / rms(x) over the last axis, float32."""
+    inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return x * inv * scale.astype(jnp.float32)
+
+
+def _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa, positions, grouped=False):
+    """(q [B,T,H,D], k, v [B,T,n_kv,D]) of a grouped-KV layer in x's
+    dtype; with ``grouped`` q is [B,T,n_kv,G,D]. With attr ``qk_norm``
+    every head of q and k is RMS-normalised over its D values (gains
+    ``QNorm`` / ``KNorm`` [D], eps ``rms_eps``) and with attr
+    ``rope_theta`` rotated at ``positions()`` [B,T] (the tokens' TRUE
+    positions), in that order and in float32: what is cached is the
+    normalised, rotated key. Without either the projections are what
+    they were, op for op (q grouped before k is projected: a plain
+    layer's programs keep their compile-cache keys)."""
+    h, n_kv, d = gqa
+    qshape = x.shape[:2] + ((n_kv, h // n_kv, d) if grouped else (h, d))
+    qk_norm, theta = bool(attrs.get("qk_norm")), attrs.get("rope_theta")
+    if not qk_norm and not theta:
+        return (_gqa_heads(x, wq, h, d).reshape(qshape),
+                _gqa_heads(x, wk, n_kv, d), _gqa_heads(x, wv, n_kv, d))
+    eps = float(attrs.get("rms_eps", 1e-5))
+    out = []
+    for w, heads, gain in ((wq, h, "QNorm"), (wk, n_kv, "KNorm")):
+        y = dense(x, w).reshape(x.shape[:2] + (heads, d))
+        if qk_norm:
+            y = _rms(y, first(ins, gain), eps)
+        if theta:
+            y = rope_half(y, positions(), theta)
+        out.append(y.astype(x.dtype))
+    return out[0].reshape(qshape), out[1], _gqa_heads(x, wv, n_kv, d)
+
+
+def _softmax_rows(s):
+    """softmax over the last axis with the row maximum behind an
+    optimization barrier: fused with the subtraction, XLA's TPU
+    pipeline turned ``max`` over 8192 keys into a ``reduce-window`` of
+    16383 taps for EVERY score — 47 ms a block of 256 queries, 7.5 of a
+    prefill's 8.2 s (PERF.md, PR 33). Every row has its own key, so no
+    row is all -inf."""
+    m = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
+    e = jnp.exp(s - m)
+    return e / jnp.sum(e, axis=-1, keepdims=True)
+
+
+# queries a block of a long grouped-KV prefill: a prompt bucket longer
+# than this attends in blocks (the scores of a whole 16384-token prompt
+# are [32, 16384, 16384] float32, 34 GB; of a block over all its keys
+# 1.1 GB, over a window's 0.17 GB), a shorter one at once, as before
+GQA_QUERY_BLOCK = 512
+
+
+def _attended(keep, first_col=0):
+    """keep [Q, S] bool -> [Q, 2] int32: the lowest key each query
+    attends (columns count from ``first_col``) and how many."""
+    return jnp.stack([first_col + jnp.argmax(keep, axis=-1),
+                      jnp.sum(keep, axis=-1)], axis=-1).astype(jnp.int32)
+
+
+def _gqa_attend(q, k, v, window=None):
+    """Causal attention of q [B,T,n_kv,G,D] over k, v [B,T,n_kv,D] ->
+    ([B,T,n_kv,G,D] in q's dtype, with a window what each query
+    attends [T, 2]: ``_attended``). With ``window`` query t sees the
+    keys s with 0 <= t - s < window: a band, and a block of queries
+    reads only the keys its band can reach."""
+    b, t, n_kv, g, d = q.shape
+    dt = q.dtype
+    scale = float(d) ** -0.5
+    blk = GQA_QUERY_BLOCK
+    if t <= blk:
+        s = jnp.einsum("btkgd,bskd->bkgts", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+        if window is not None:
+            keep &= jnp.arange(t)[:, None] - jnp.arange(t)[None, :] < window
+        p = _scores_to_probs(s, keep, dt)
+        c = jnp.einsum("bkgts,bskd->btkgd", p, v,
+                       preferred_element_type=jnp.float32).astype(dt)
+        return c, (None if window is None else _attended(keep))
+    if t % blk:
+        raise ValueError(f"a prompt bucket of {t} is not a whole number "
+                         f"of {blk}-query blocks")
+    span = t if window is None else min(t, window + blk)
+
+    def block(t0):
+        k0 = jnp.clip(t0 + blk - span, 0, t - span)
+        cut = lambda z, at, n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            z, at, n, axis=1)
+        ahead = (t0 + jnp.arange(blk))[:, None] \
+            - (k0 + jnp.arange(span))[None, :]
+        keep = ahead >= 0
+        if window is not None:
+            keep &= ahead < window
+        s = jnp.einsum("btkgd,bskd->bkgts", cut(q, t0, blk),
+                       cut(k, k0, span),
+                       preferred_element_type=jnp.float32) * scale
+        p = _softmax_rows(jnp.where(keep, s, -jnp.inf)).astype(dt)
+        c = jnp.einsum("bkgts,bskd->btkgd", p, cut(v, k0, span),
+                       preferred_element_type=jnp.float32).astype(dt)
+        return c, _attended(keep, k0)
+
+    o, seen = jax.lax.map(block, jnp.arange(0, t, blk))  # [T/blk,B,blk,..]
+    return (jnp.moveaxis(o, 0, 1).reshape(q.shape),
+            None if window is None else seen.reshape(t, 2))
+
+
+def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, ins, attrs, gqa):
+    """Causal self-attention of a grouped-KV layer over X [B,T,M], plus
+    the K/V projections [B,T,n_kv*d] the caller caches and, of a window
+    layer, what each query attended (``_attended``). Without
+    positions the mask alone orders it; a layer's attrs may give its
+    heads a norm and rotary positions (:func:`_gqa_qkv`: a prompt's
+    rows ARE its positions) and its queries a ``window``."""
+    h, n_kv, d = gqa
     b, t, _ = x.shape
-    dt = x.dtype
-    q = _gqa_heads(x, wq, h, d).reshape(b, t, n_kv, h // n_kv, d)
-    k = _gqa_heads(x, wk, n_kv, d)
-    v = _gqa_heads(x, wv, n_kv, d)
-    s = jnp.einsum("btkgd,bskd->bkgts", q, k,
-                   preferred_element_type=jnp.float32) * (float(d) ** -0.5)
-    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-    p = _scores_to_probs(s, causal, dt)
-    c = jnp.einsum("bkgts,bskd->btkgd", p, v,
-                   preferred_element_type=jnp.float32).astype(dt)
+    q, k, v = _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa,
+                       lambda: jnp.broadcast_to(jnp.arange(t), (b, t)),
+                       grouped=True)
+    window = attrs.get("window")
+    c, seen = _gqa_attend(q, k, v, int(window) if window else None)
     out = _gqa_output(x, c.reshape(b, t, h * d), wg, wo)
-    return out, k.reshape(b, t, -1), v.reshape(b, t, -1)
+    return out, k.reshape(b, t, -1), v.reshape(b, t, -1), seen
 
 
 def _causal_prefill(x, wq, wk, wv, wo, h):
@@ -417,11 +535,16 @@ def _paged_write(flat, fscale, rows, vals):
             fscale.at[rows].set(scale, mode="drop"))
 
 
-def _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps):
-    """The paged ops' outputs, pools back in their declared shapes."""
+def _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps, seen=None):
+    """The paged ops' outputs, pools back in their declared shapes;
+    ``Attended`` (a window layer's: per query the lowest key position it
+    attended and how many, for a check to read — dead code in an
+    executable that does not fetch it)."""
     res = {"Out": [out],
            "PageKOut": [flat_k.reshape(n_pages, ps, -1)],
            "PageVOut": [flat_v.reshape(n_pages, ps, -1)]}
+    if seen is not None:
+        res["Attended"] = [seen]
     if fks is not None:
         res["PageKSOut"] = [fks.reshape(n_pages, ps, -1)]
         res["PageVSOut"] = [fvs.reshape(n_pages, ps, -1)]
@@ -452,15 +575,71 @@ def _kv_attention_prefill_paged(ctx, ins, attrs):
     rows = jnp.asarray(first(ins, "Rows")).reshape(-1).astype(jnp.int32)
     flat_k, flat_v, fks, fvs, n_pages, ps, _ = _paged_pools(ins, codec)
     gqa = _gqa(attrs)
+    seen = None
     if gqa is not None:
-        out, k, v = _gqa_causal_prefill(x, wq, wk, wv, wo,
-                                        first(ins, "Wg"), *gqa)
+        # a window layer lowers under a scope of its own below the op's
+        with _device_scopes.variant("kv_attention_prefill_paged",
+                                    "window", bool(attrs.get("window"))):
+            out, k, v, seen = _gqa_causal_prefill(
+                x, wq, wk, wv, wo, first(ins, "Wg"), ins, attrs, gqa)
     else:
         out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
     m = flat_k.shape[1]
     flat_k, fks = _paged_write(flat_k, fks, rows, k.reshape(-1, m))
     flat_v, fvs = _paged_write(flat_v, fvs, rows, v.reshape(-1, m))
-    return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps)
+    return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps, seen)
+
+
+def window_ring(window: int, page_size: int) -> int:
+    """Pages that ``window`` consecutive positions can touch: the length
+    of a slot's ring in the page pool's window group (what the decode
+    view's ``page_table_w`` feed is wide, what ``PagePool`` leases at
+    most)."""
+    return -(-int(window) // int(page_size)) + 1
+
+
+def _window_decode(q, k_t, v_t, table, true_pos, active, window, n_kv,
+                   mesh, pools):
+    """A window layer's decode step over its group's pools: ``table``
+    [B, ring] is the slot's RING of pages, entry ``e`` holding the
+    logical page ``lp`` of true positions with ``lp % ring == e``
+    (serving/kv_pool.py "Window group"). Writes this token's rows at
+    its true position, gathers the ring's pages — O(window) rows a slot
+    whatever the context — and attends the keys ``j`` with ``0 <=
+    true_pos - j < window``: entry e holds page ``cur - (cur - e) %
+    ring`` of the slot (``cur`` the page being written), so a gathered
+    row's position is known without a feed, and a page the host has
+    returned (sentinel) or not yet written lies outside the window.
+    Returns (context [B,1,H,D], what each slot attended [B,2]:
+    ``_attended``, the pools)."""
+    flat_k, flat_v, fks, fvs, ps, rtot = pools
+    b, ring = table.shape
+    dt, mk = q.dtype, flat_k.shape[1]
+    phase = functools.partial(_device_scopes.phase,
+                              "kv_attention_decode_paged/window")
+    cur = true_pos // ps
+    with phase("write"):
+        wpage = jnp.take_along_axis(table, (cur % ring)[:, None],
+                                    axis=1)[:, 0]
+        wrow = jnp.where(active, wpage * ps + true_pos % ps, rtot)
+        flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, mk))
+        flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mk))
+    with phase("gather"):
+        kk = _paged_gather(flat_k, fks, table, ps, dt, mesh)
+        vv = _paged_gather(flat_v, fvs, table, ps, dt, mesh)
+    with phase("attend"):
+        e = jnp.arange(ring, dtype=jnp.int32)[None, :]
+        page = cur[:, None] - (cur[:, None] - e) % ring          # [B,ring]
+        j = (page[:, :, None] * ps
+             + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
+             ).reshape(b, ring * ps)
+        ahead = true_pos[:, None] - j
+        valid = (j >= 0) & (ahead >= 0) & (ahead < window)
+        c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv)
+        seen = jnp.stack(
+            [jnp.min(jnp.where(valid, j, jnp.iinfo(jnp.int32).max), -1),
+             jnp.sum(valid, -1)], axis=-1).astype(jnp.int32)
+    return c, seen, flat_k, flat_v, fks, fvs
 
 
 @register_op("kv_attention_decode_paged", no_grad=True,
@@ -508,9 +687,21 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     n_kv = None
     if gqa is not None:
         h, n_kv, d = gqa
-        q = _gqa_heads(x, wq, h, d)             # [B,1,H,D]
-        k_t = _gqa_heads(x, wk, n_kv, d)        # [B,1,n_kv,D]
-        v_t = _gqa_heads(x, wv, n_kv, d)
+        # each token's TRUE position: generated rows start at the bucket
+        true_pos = lambda: lens + pos - gen0                 # noqa: E731
+        # q [B,1,H,D], k_t / v_t [B,1,n_kv,D]
+        q, k_t, v_t = _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa,
+                               lambda: true_pos()[:, None])
+        if attrs.get("window"):
+            with _device_scopes.variant("kv_attention_decode_paged",
+                                        "window"):
+                c, seen, flat_k, flat_v, fks, fvs = _window_decode(
+                    q, k_t, v_t, table, true_pos(), active,
+                    int(attrs["window"]), n_kv, ctx.mesh,
+                    (flat_k, flat_v, fks, fvs, ps, rtot))
+            out = _gqa_output(x, c.reshape(b, 1, -1), first(ins, "Wg"), wo)
+            return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages,
+                                 ps, seen)
     else:
         q = _ab._proj(x, wq, h)                     # [B,1,H,D]
         k_t = _ab._proj(x, wk, h)

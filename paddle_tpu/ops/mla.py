@@ -140,9 +140,7 @@ def _rope_part(x, pos, theta, lo, hi):
                             x[..., hi:]], axis=-1)
 
 
-def _rms(x, scale, eps):
-    inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return x * inv * scale.astype(F32)
+_rms = _kv._rms
 
 
 def _sizes(attrs):
@@ -225,16 +223,7 @@ def select_topk(scores, valid, k):
     return above | (behind >= _kv._kth_largest(behind, room)[:, None])
 
 
-def _softmax_rows(s):
-    """softmax over the last axis with the row maximum behind an
-    optimization barrier: fused with the subtraction, XLA's TPU
-    pipeline turned ``max`` over 8192 keys into a ``reduce-window`` of
-    16383 taps for EVERY score — 47 ms a block of 256 queries, 7.5 of a
-    prefill's 8.2 s (PERF.md, PR 33). Every row has its own key, so no
-    row is all -inf."""
-    m = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
-    e = jnp.exp(s - m)
-    return e / jnp.sum(e, axis=-1, keepdims=True)
+_softmax_rows = _kv._softmax_rows
 
 
 def expanded_attention(q, c, kr, qi, wi, ki, w, a):

@@ -46,6 +46,7 @@ import hashlib
 import json
 import os
 import pickle
+import re
 import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -949,15 +950,35 @@ class SlotGenerativeModel:
         decode program's pool vars and page-table feed."""
         # any plane of the pool: K (every family with softmax layers)
         # or, for a family whose attention is latent, its latent plane
-        pool_vars = [v for n, v in dec_main.desc.global_block.vars.items()
-                     if n.endswith(("_page_k_0", "_page_c_0"))]
-        if not pool_vars:
+        gvars = dec_main.desc.global_block.vars
+        pool_vars = [v for n, v in gvars.items()
+                     if re.search(r"_page_[kc]_\d+$", n)]
+        # the window group's pools (layers that attend the last
+        # ``window`` positions alone: another page-id space, a ring of
+        # pages a slot behind a table of its own)
+        window_vars = [v for n, v in gvars.items()
+                       if re.search(r"_page_wk_\d+$", n)]
+        if not pool_vars and not window_vars:
             raise ValueError(
                 f"model {self.name!r}: decode_paged program has no "
-                f"*_page_k_* or *_page_c_* pool vars")
-        self.n_pages = int(pool_vars[0].shape[0])
-        self.page_size = int(pool_vars[0].shape[1])
+                f"*_page_k_*, *_page_c_* or *_page_wk_* pool vars")
         self.max_pages = int(dec_feeds["page_table"][0][1])
+        any_pool = (pool_vars or window_vars)[0]
+        self.page_size = int(any_pool.shape[1])
+        # a family of window layers alone has no full-group pool on the
+        # device: its pages are bookkeeping (every slot at full length)
+        self.n_pages = int(pool_vars[0].shape[0]) if pool_vars \
+            else self.n_slots * self.max_pages
+        self.window = self.window_ring = self.n_window_pages = 0
+        self._window_layers = len(window_vars)
+        if window_vars:
+            self.n_window_pages = int(window_vars[0].shape[0])
+            self.window_ring = int(dec_feeds["page_table_w"][0][1])
+            self.window = next(
+                int(op.attrs["window"])
+                for op in dec_main.desc.global_block.ops
+                if op.type == "kv_attention_decode_paged"
+                and op.attrs.get("window"))
         self.cache_len = self.max_pages * self.page_size
         self.max_new = self.cache_len - self.prompt_len
         if self.n_pages < self.max_pages:
@@ -965,8 +986,9 @@ class SlotGenerativeModel:
                 f"model {self.name!r}: pool of {self.n_pages} pages "
                 f"cannot hold one worst-case request ({self.max_pages} "
                 f"pages) — admission could never succeed")
-        self.pool = kv_pool.PagePool(self.n_pages, self.page_size,
-                                     model=self.name)
+        self.pool = kv_pool.PagePool(
+            self.n_pages, self.page_size, model=self.name,
+            window_pages=self.n_window_pages, window=self.window)
         # row-write sentinel: one past the flat pool -> scatter drops it
         self._row_sentinel = self.n_pages * self.page_size
         # host page-table mirror; n_pages is the TABLE sentinel (gather
@@ -974,6 +996,12 @@ class SlotGenerativeModel:
         self._table = np.full((self.n_slots, self.max_pages),
                               self.n_pages, np.int64)
         self._pending_rows: Optional[np.ndarray] = None
+        # the same for the window group: a slot's ring of pages
+        self._table_w = np.full((self.n_slots, self.window_ring),
+                                self.n_window_pages, np.int64)
+        self._pending_rows_w: Optional[np.ndarray] = None
+        self._m_window_rows = smetrics.KV_WINDOW_ROWS_ATTENDED.labels(
+            model=self.name) if window_vars else None
 
     def _discover_state(self, dec_main, pre_feeds):
         """The second kind of per-slot state (docs/serving.md "Recurrent
@@ -1092,9 +1120,12 @@ class SlotGenerativeModel:
     def _decode_feeds(self):
         # a family with rotary positions is fed each token's TRUE
         # position beside its row (generated rows start at the bucket)
-        position = {"position": (self._seq + self._gen_count - 1)[:, None]
-                    } if self._dsa_layers else {}
-        return {**self._token_feeds(), **position,
+        extra = {"position": (self._seq + self._gen_count - 1)[:, None]
+                 } if self._dsa_layers else {}
+        if self.window:
+            self._recycle_window_pages()
+            extra["page_table_w"] = self._table_w.copy()
+        return {**self._token_feeds(), **extra,
                 "pos": (self._gen0 + self._gen_count - 1)[:, None],
                 "seq_len": self._seq[:, None],
                 "gen_start": self._gen0[:, None],
@@ -1105,6 +1136,32 @@ class SlotGenerativeModel:
                 "temperature": self._temp[:, None],
                 "top_k": self._topk[:, None],
                 "page_table": self._table.copy()}
+
+    def _recycle_window_pages(self):
+        """Before a decode step's feeds: every slot whose token is the
+        first of a page of TRUE positions returns the window group's
+        pages that now lie behind its window and takes the page it is
+        about to write (``PagePool.window_advance``; idempotent, so a
+        reader of the next step's feeds may come first). Span
+        ``serving.decode.recycle``, inside ``serving.decode.feeds``."""
+        true_pos = self._seq + self._gen_count - 1
+        enters = np.flatnonzero(self._active & ~self._closing
+                                & (true_pos % self.page_size == 0))
+        if not enters.size:
+            return
+        trace_on = tctx.active()
+        t0 = time.perf_counter() if trace_on else 0.0
+        for slot in enters.tolist():
+            if self.pool.window_advance(slot, int(true_pos[slot])):
+                self._window_table_row(slot)
+        if trace_on:
+            tctx.record_span("serving.decode.recycle", t0,
+                             time.perf_counter())
+
+    def _window_table_row(self, slot: int):
+        ring = np.asarray(self.pool.window_lease(slot).ring, np.int64)
+        self._table_w[slot] = np.where(ring >= 0, ring,
+                                       self.n_window_pages)
 
     def _verify_feeds(self, tok_w=None, win_len=None):
         """The verify dispatch's fixed-shape feeds. The sampling feeds
@@ -1156,6 +1213,14 @@ class SlotGenerativeModel:
         self._pending_rows = None
         if rows is None:
             rows = np.full((p_len, 1), self._row_sentinel, np.int64)
+        if self.window:
+            rows_w = self._pending_rows_w
+            self._pending_rows_w = None
+            if rows_w is None:
+                rows_w = np.full(
+                    (p_len, 1), self.n_window_pages * self.page_size,
+                    np.int64)
+            return {"page_rows": rows, "page_rows_w": rows_w}
         if not self.state_vars:
             return {"page_rows": rows}
         # the recurrent state is per SLOT, not per page: the prefill
@@ -1184,14 +1249,17 @@ class SlotGenerativeModel:
         span = self.pool.span_for(p_len + budget, draft_window=0)
         try:
             pages, n_shared = self.pool.acquire(
-                slot, [int(t) for t in prompt], span)
+                slot, [int(t) for t in prompt], span,
+                total_len=len(prompt) + budget if self.window else None)
         except kv_pool.PagesExhaustedError as e:
             raise SlotExhaustedError(
                 f"model {self.name!r}: page pool cannot cover a "
                 f"{span}-page admission (free_pages="
                 f"{self.pool.free_count()}, evictable_cached="
                 f"{self.pool.cached_count()}, pages_total="
-                f"{self.n_pages}, free_slots={self.free_count()}, "
+                f"{self.n_pages}, free_window_pages="
+                f"{self.pool.window_free_count()}, free_slots="
+                f"{self.free_count()}, "
                 f"active_slots={self.active_count()})") from e
         ps = self.page_size
         idx = np.arange(p_len)
@@ -1200,6 +1268,18 @@ class SlotGenerativeModel:
         self._pending_rows = rows[:, None]
         self._table[slot, :] = self.n_pages
         self._table[slot, :span] = pages
+        if self.window:
+            # the window group holds the prompt's LAST pages alone: rows
+            # are by true position (a prompt's rows are its positions),
+            # and whatever lies before the lease's first page, or in the
+            # bucket's padding, is written nowhere
+            self._window_table_row(slot)
+            lease = self.pool.window_lease(slot)
+            held = (idx // ps >= lease.lo) & (idx < len(prompt))
+            rows_w = self._table_w[slot][(idx // ps) % self.window_ring] \
+                * ps + idx % ps
+            rows_w[~held] = self.n_window_pages * ps
+            self._pending_rows_w = rows_w[:, None]
 
     def _release_capacity(self, slot):
         """A prefill dispatch died after acquire: abort the lease (the
@@ -1209,7 +1289,8 @@ class SlotGenerativeModel:
         admission can't inherit them."""
         self.pool.abort(slot)
         self._table[slot, :] = self.n_pages
-        self._pending_rows = None
+        self._table_w[slot, :] = self.n_window_pages
+        self._pending_rows = self._pending_rows_w = None
 
     # -- warmup / AOT ----------------------------------------------------
     def warmup(self, aot_dir: Optional[str] = None,
@@ -1533,6 +1614,12 @@ class SlotGenerativeModel:
             self._m_dsa_scored.inc(int(live.sum()) * self._dsa_layers)
             self._m_dsa_selected.inc(int(np.minimum(
                 live, self._dsa_topk).sum()) * self._dsa_layers)
+        if self.window:
+            # what the window layers attend this step: each running
+            # slot's live rows, at most a window's
+            live = self._seq[slots] + self._gen_count[slots]
+            self._m_window_rows.inc(int(np.minimum(
+                live, self.window).sum()) * self._window_layers)
         self._gen_count[slots] += 1
         last = self._gen_count[slots] >= self._budget[slots]
         self._closing[slots[last]] = True
@@ -1673,6 +1760,7 @@ class SlotGenerativeModel:
             return
         self.pool.release(slot)
         self._table[slot, :] = self.n_pages
+        self._table_w[slot, :] = self.n_window_pages
         self._active[slot] = False
         self._closing[slot] = False
         self._epoch[slot] += 1
@@ -1688,7 +1776,8 @@ class SlotGenerativeModel:
     def reset(self):
         self.pool.reset()
         self._table[:] = self.n_pages
-        self._pending_rows = None
+        self._table_w[:] = self.n_window_pages
+        self._pending_rows = self._pending_rows_w = None
         self._active[:] = False
         self._closing[:] = False
         self._flights.clear()

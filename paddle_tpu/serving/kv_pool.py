@@ -38,6 +38,25 @@ through it. Three cooperating structures:
   (``paddle_kv_page_evictions_total{cause="capacity"}``);
   :meth:`PagePool.reset` drops the whole cache (``cause="reset"``).
 
+- **Window group** — a model whose layers are of two kinds, some
+  attending the whole context and some only the last ``window``
+  positions, keeps the two kinds in pools of their own (another device
+  array a layer, another page-id space) and leases them apart: the FULL
+  group as above (span, radix sharing), the WINDOW group a ring of at
+  most ``ceil(window / page_size) + 1`` pages a slot, addressed by TRUE
+  position (``ring[(position // page_size) % len(ring)]``). Admission
+  takes the pages of the prompt's last ``window`` positions and of what
+  the budget will write, up to the ring's length, and is refused when
+  EITHER group lacks pages; a long prompt's pages behind its window are
+  never taken. When a decoding slot enters a page its ring has no room
+  for, :meth:`PagePool.window_advance` first returns the pages that lie
+  behind every future query's window
+  (``paddle_kv_window_pages_released_total``) and then takes one: a
+  take after admission always follows a release, so it cannot fail. The
+  window group takes no part in prefix sharing (its rows are written by
+  every admission: a prefix-shared admission recomputes the prompt
+  anyway).
+
 Thread discipline matches the engine: one dispatcher at a time — no
 internal locking.
 """
@@ -48,6 +67,7 @@ import heapq
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from paddle_tpu.ops.kv_attention import window_ring
 from paddle_tpu.serving import metrics as smetrics
 
 
@@ -84,19 +104,48 @@ class _SlotLease:
         self.n_shared = n_shared  # leading pages found in the tree
 
 
+class _WindowLease:
+    """A slot's share of the window group: ``ring[e]`` is the page that
+    holds logical page ``lp`` (``lp % len(ring) == e``) for ``lo <= lp <
+    hi``, -1 elsewhere."""
+
+    __slots__ = ("ring", "lo", "hi")
+
+    def __init__(self, ring, lo, hi):
+        self.ring = ring
+        self.lo = lo              # lowest logical page still held
+        self.hi = hi              # one past the highest page held
+
+    def held(self) -> int:
+        return self.hi - self.lo
+
+
 class PagePool:
     """Free-list page allocator + prompt-prefix radix tree for one
     serving model's paged KV pool. Page ids index the device pools'
     leading axis; the engine turns a lease into the slot's page-table
-    row and the prefill's write-row vector."""
+    row and the prefill's write-row vector. ``window_pages`` pages of a
+    second group, for layers that attend the last ``window`` positions
+    alone (module docstring, "Window group")."""
 
-    def __init__(self, n_pages: int, page_size: int, model: str = ""):
+    def __init__(self, n_pages: int, page_size: int, model: str = "",
+                 window_pages: int = 0, window: int = 0):
         if n_pages < 1 or page_size < 1:
             raise ValueError(f"bad pool geometry: n_pages={n_pages}, "
                              f"page_size={page_size}")
         self.n_pages = int(n_pages)
         self.page_size = int(page_size)
         self.model = model
+        self.window = int(window)
+        self.window_pages = int(window_pages)
+        self.window_ring = window_ring(window, page_size) \
+            if window_pages else 0
+        if 0 < self.window_pages < self.window_ring:
+            raise ValueError(
+                f"bad pool geometry: {window_pages} window pages cannot "
+                f"hold one slot's ring of {self.window_ring}")
+        self._wfree: List[int] = list(range(self.window_pages))[::-1]
+        self._wslots: Dict[int, _WindowLease] = {}
         self._free: List[int] = list(range(self.n_pages))[::-1]
         self._root = _Node(None, -1, None)
         self._slots: Dict[int, _SlotLease] = {}
@@ -145,11 +194,18 @@ class PagePool:
         return -(-(int(total_len) + int(draft_window)) // self.page_size)
 
     def stats(self) -> dict:
-        return {"pages_total": self.n_pages,
-                "pages_free": self.free_count(),
-                "pages_cached": self._cached,
-                "pages_shared": self.shared_count(),
-                "slots": len(self._slots)}
+        out = {"pages_total": self.n_pages,
+               "pages_free": self.free_count(),
+               "pages_cached": self._cached,
+               "pages_shared": self.shared_count(),
+               "slots": len(self._slots)}
+        if self.window_pages:
+            out.update(window_pages_total=self.window_pages,
+                       window_pages_free=len(self._wfree))
+        return out
+
+    def window_free_count(self) -> int:
+        return len(self._wfree)
 
     def _iter_nodes(self):
         stack = list(self._root.children.values())
@@ -161,11 +217,29 @@ class PagePool:
     def _publish(self):
         if not self.model:
             return
-        smetrics.KV_PAGES_TOTAL.labels(model=self.model).set(self.n_pages)
-        smetrics.KV_PAGES_FREE.labels(model=self.model).set(
-            self.free_count())
+        # the unlabelled pair counts every group's pages; a pool with a
+        # window group publishes each group beside it
+        smetrics.KV_PAGES_TOTAL.labels(model=self.model).set(
+            self.n_pages + self.window_pages)
+        self._publish_free()
+        if self.window_pages:
+            for group, total in (("full", self.n_pages),
+                                 ("window", self.window_pages)):
+                smetrics.KV_GROUP_PAGES_TOTAL.labels(
+                    model=self.model, group=group).set(total)
         smetrics.KV_PREFIX_SHARED_PAGES.labels(model=self.model).set(
             self.shared_count())
+
+    def _publish_free(self):
+        """The free gauges alone (no walk of the tree: a decoding slot
+        that recycles a window page calls this between admissions)."""
+        smetrics.KV_PAGES_FREE.labels(model=self.model).set(
+            self.free_count() + len(self._wfree))
+        if self.window_pages:
+            for group, free in (("full", self.free_count()),
+                                ("window", len(self._wfree))):
+                smetrics.KV_GROUP_PAGES_FREE.labels(
+                    model=self.model, group=group).set(free)
 
     # -- eviction ---------------------------------------------------------
     def _evict(self, count: int, cause: str) -> int:
@@ -209,9 +283,13 @@ class PagePool:
         return [self._free.pop() for _ in range(need)]
 
     # -- lease lifecycle --------------------------------------------------
-    def acquire(self, slot: int, tokens: Sequence[int],
-                span: int) -> Tuple[List[int], int]:
-        """Lease ``span`` pages to ``slot`` for a prompt of ``tokens``:
+    def acquire(self, slot: int, tokens: Sequence[int], span: int,
+                total_len: Optional[int] = None
+                ) -> Tuple[List[int], int]:
+        """Lease ``span`` pages to ``slot`` for a prompt of ``tokens``
+        (and, in a pool with a window group, the ring that ``total_len``
+        = prompt + budget positions need: refused, and nothing taken,
+        when either group lacks pages):
         walk the radix tree along the FULL prompt pages, share every
         node found (refcount++), allocate private pages for the rest,
         and insert the new full prompt pages so later requests share
@@ -225,6 +303,12 @@ class PagePool:
         full = min(len(tokens) // self.page_size, int(span))
         if span < 1:
             raise ValueError(f"span {span} < 1")
+        wlo, whi = self._window_span(len(tokens), total_len)
+        if whi - wlo > len(self._wfree):
+            raise PagesExhaustedError(
+                f"model {self.model!r}: admission needs {whi - wlo} "
+                f"window pages, {len(self._wfree)} free of "
+                f"{self.window_pages}")
         # 1) longest shared prefix of full prompt pages
         chain: List[_Node] = []
         cur = self._root
@@ -285,8 +369,78 @@ class PagePool:
         tail = private[k:]
         pages = [nd.page for nd in nodes] + tail
         self._slots[slot] = _SlotLease(pages, nodes, tail, n_shared)
+        if self.window_pages:
+            ring = [-1] * self.window_ring
+            for lp in range(wlo, whi):
+                ring[lp % self.window_ring] = self._wfree.pop()
+            self._wslots[slot] = _WindowLease(ring, wlo, whi)
         self._publish()
         return pages, n_shared
+
+    # -- the window group -------------------------------------------------
+    def _window_span(self, prompt_len: int, total_len: Optional[int]
+                     ) -> Tuple[int, int]:
+        """The logical pages [lo, hi) of the window group an admission
+        takes: from the page of the first position the FIRST decode step
+        still sees (``prompt_len - window + 1``) to the page of the last
+        position ever written, at most a ring's length."""
+        if not self.window_pages:
+            return 0, 0
+        if total_len is None:
+            raise ValueError("a pool with a window group leases by "
+                             "total_len (prompt + budget positions)")
+        lo = max(0, int(prompt_len) - self.window + 1) // self.page_size
+        hi = (max(int(total_len), int(prompt_len)) - 1) \
+            // self.page_size + 1
+        return lo, min(hi, lo + self.window_ring)
+
+    def window_lease(self, slot: int) -> Optional[_WindowLease]:
+        return self._wslots.get(slot)
+
+    def window_advance(self, slot: int, position: int) -> bool:
+        """``slot`` is about to write ``position`` (a TRUE position) and
+        attend the ``window`` positions up to it: return to the group
+        the pages whose rows all lie behind that window (and so behind
+        every later one), then make sure the page of ``position`` is
+        held. True when the slot's ring changed. Idempotent: a second
+        call for the same position finds nothing to return and the page
+        held. A page is only taken where the ring was full, right after
+        one was returned, so this cannot run out of pages."""
+        lease = self._wslots.get(slot)
+        if lease is None:
+            return False
+        ring, n = lease.ring, self.window_ring
+        lp = int(position) // self.page_size
+        if lp < lease.hi:
+            return False
+        dead = max(0, int(position) - self.window + 1) // self.page_size
+        released = 0
+        while lease.lo < min(dead, lease.hi):
+            e = lease.lo % n
+            self._wfree.append(ring[e])
+            ring[e] = -1
+            lease.lo += 1
+            released += 1
+        lease.lo = max(lease.lo, dead)
+        lease.hi = max(lease.hi, lease.lo)
+        while lease.hi <= lp:
+            if not self._wfree:
+                raise PagesExhaustedError(
+                    f"model {self.model!r}: no window page for slot "
+                    f"{slot} at position {position}")
+            ring[lease.hi % n] = self._wfree.pop()
+            lease.hi += 1
+        if self.model:
+            if released:
+                smetrics.KV_WINDOW_PAGES_RELEASED.labels(
+                    model=self.model).inc(released)
+            self._publish_free()
+        return True
+
+    def _window_release(self, slot: int):
+        lease = self._wslots.pop(slot, None)
+        if lease is not None:
+            self._wfree.extend(p for p in lease.ring if p >= 0)
 
     def release(self, slot: int):
         """Return ``slot``'s lease: tail pages go straight to the free
@@ -297,6 +451,7 @@ class PagePool:
         lease = self._slots.pop(slot, None)
         if lease is None:
             return
+        self._window_release(slot)
         self._clock += 1
         for nd in reversed(lease.nodes):
             nd.refs -= 1
@@ -316,6 +471,7 @@ class PagePool:
         lease = self._slots.pop(slot, None)
         if lease is None:
             return
+        self._window_release(slot)
         inserted = set(lease.nodes[lease.n_shared:])
         self._clock += 1
         for nd in reversed(lease.nodes):      # deepest-first: children
@@ -339,6 +495,8 @@ class PagePool:
         the device pools are about to be scrubbed or reused, so cached
         pages would alias stale K/V)."""
         self._slots.clear()
+        self._wslots.clear()
+        self._wfree = list(range(self.window_pages))[::-1]
         n = sum(1 for _ in self._iter_nodes())
         if n and self.model:
             smetrics.KV_PAGE_EVICTIONS.labels(

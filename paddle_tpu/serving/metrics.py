@@ -150,6 +150,28 @@ KV_PAGES_FREE = _metrics.gauge(
     "Pages on the free list right now (admission takes "
     "span - shared_prefix_pages of these; cached prefix pages are NOT "
     "free — they evict on demand)", labelnames=("model",))
+# a pool with a window group (serving/kv_pool.py "Window group")
+# publishes each group beside the pair above, which counts both
+KV_GROUP_PAGES_TOTAL = _metrics.gauge(
+    "paddle_kv_group_pages_total",
+    "Pages of one layer group of the model's KV page pool (group: "
+    "full | window; only for a pool that has a window group)",
+    labelnames=("model", "group"))
+KV_GROUP_PAGES_FREE = _metrics.gauge(
+    "paddle_kv_group_pages_free",
+    "Pages of one layer group on its free list right now",
+    labelnames=("model", "group"))
+KV_WINDOW_PAGES_RELEASED = _metrics.counter(
+    "paddle_kv_window_pages_released_total",
+    "Window-group pages a LIVE request returned because every row of "
+    "theirs lies behind its window (a page taken in their place is "
+    "not counted)", labelnames=("model",))
+KV_WINDOW_ROWS_ATTENDED = _metrics.counter(
+    "paddle_kv_window_rows_attended_total",
+    "Cache rows the window layers' decode steps attended: per step, "
+    "slot and window layer min(the slot's positions, the window), "
+    "counted on the host from the slots' own lengths",
+    labelnames=("model",))
 KV_PREFIX_SHARED_PAGES = _metrics.gauge(
     "paddle_kv_prefix_shared_pages",
     "Pages physically referenced by >= 2 in-flight slots via the "

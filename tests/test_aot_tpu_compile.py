@@ -633,3 +633,80 @@ def test_latent_prefill_compiles_for_v5e(chip, glm5_engine, monkeypatch):
     # selection over [256, 8192] scores a block is by threshold, no sort
     assert _count_opcode(text, "sort") == 12
     assert not re.findall(r"\[256,8192\][^=]* sort\(", text)
+
+
+# ---------------------------------------------------------------------------
+# window and full attention layers in one page pool (PR 37): the decode
+# step and the largest prefill of trinity_mini_26b_d5 at the cell's sizes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def trinity_engine():
+    import json
+    import os
+    from paddle_tpu import serving
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.ops import pallas as pk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "trinity_mini_26b_d5.json")) as f:
+        cfg = json.load(f)
+    build = cfg["build"]
+    was, pk.on_tpu = pk.on_tpu, lambda: True
+    try:
+        programs = T.build_decoder_lm_programs(
+            name="lm", modes=T.slot_modes(cfg["kv_layout"]),
+            kv_codec=cfg["kv_codec"],
+            **{**build, "prompt_buckets": tuple(build["prompt_buckets"]),
+               "layer_kinds": tuple(build["layer_kinds"])})
+        yield serving.make_slot_model("lm", programs, init=False), programs
+    finally:
+        pk.on_tpu = was
+
+
+def test_window_decode_step_compiles_for_v5e(chip, trinity_engine,
+                                             monkeypatch):
+    """32 slots, bf16, four window layers and one full layer, all 128
+    experts a layer: the step fits one chip with both page groups (1.34
+    GB the full layer's 20 480 rows a slot, 0.54 GB the four window
+    layers' 2 064) donated and aliased in place. A window layer gathers
+    its ring alone (``bf16[66048,512]``: 32 slots x 129 pages x 16
+    rows), the full layer its whole table (``bf16[655360,512]``), and
+    neither group is copied or transposed."""
+    eng, programs = trinity_engine
+    assert (eng.window, eng.window_ring, eng.n_window_pages) \
+        == (2048, 129, 32 * 129)
+    compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
+                             eng._decode_feeds(), monkeypatch)
+    mem = compiled.memory_analysis()
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 10e9 < peak < 15e9
+    pages = (40960 + 4 * 4128) * 16 * 512 * 2 * 2
+    assert mem.alias_size_in_bytes >= pages
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    assert len(re.findall(r"= bf16\[66048,512\]\S* custom-call\(", entry)) \
+        == 8
+    assert len(re.findall(r"= bf16\[655360,512\]\S* custom-call\(", entry)) \
+        == 2
+    plane = 4128 * 16 * 512
+    assert not [line for opcode, count, _a, line in _hlo_ops(text).values()
+                if opcode in ("copy", "transpose", "gather")
+                and count >= plane]
+    # the module's name carries the window variant's row
+    assert text.startswith("HloModule jit_lm_decode_paged_s367a,")
+
+
+def test_window_prefill_compiles_for_v5e(chip, trinity_engine, monkeypatch):
+    """The 16 384-token prefill beside the weights and both page groups:
+    under 15 GB (attention in blocks of 512 queries: the whole prompt's
+    scores would be 34 GB), with no window reduction in it
+    (``kv_attention._softmax_rows``)."""
+    eng, programs = trinity_engine
+    compiled = _compile_view(chip, programs, "prefill_paged@16384",
+                             eng._cb_prefill[16384],
+                             eng._prefill_feeds(16384), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    assert "reduce-window" not in compiled.as_text()

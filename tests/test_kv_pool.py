@@ -240,8 +240,152 @@ def test_kv_gauges_preregistered_in_exporter_catalog():
     snap = obs_metrics.default_registry().snapshot()
     for fam in ("paddle_kv_pages_total", "paddle_kv_pages_free",
                 "paddle_kv_prefix_shared_pages",
-                "paddle_kv_page_evictions_total"):
+                "paddle_kv_page_evictions_total",
+                "paddle_kv_group_pages_total", "paddle_kv_group_pages_free",
+                "paddle_kv_window_pages_released_total",
+                "paddle_kv_window_rows_attended_total"):
         assert fam in snap, fam
+
+
+# ---------------------------------------------------------------------------
+# the window group (PR 37): a ring of pages a slot, beside the full group
+# ---------------------------------------------------------------------------
+
+def _window_pool(window_pages=9, model=""):
+    # pages of 4 rows, a window of 8 positions: a ring of 3 pages
+    return kv_pool.PagePool(16, 4, model=model, window_pages=window_pages,
+                            window=8)
+
+
+def _window_invariants(p):
+    """No page of the window group both free and leased, none leased
+    twice, none lost."""
+    leased = [pg for lease in p._wslots.values()
+              for pg in lease.ring if pg >= 0]
+    assert len(leased) == len(set(leased))
+    assert not set(leased) & set(p._wfree)
+    assert len(leased) + len(p._wfree) == p.window_pages
+    for lease in p._wslots.values():
+        assert lease.held() == sum(pg >= 0 for pg in lease.ring) \
+            <= p.window_ring
+
+
+def test_window_ring_geometry():
+    assert kv_pool.window_ring(2048, 16) == 129
+    assert kv_pool.window_ring(8, 4) == 3
+    assert kv_pool.window_ring(9, 4) == 4
+    assert _window_pool().window_ring == 3
+    with pytest.raises(ValueError, match="ring"):
+        _window_pool(window_pages=2)
+    with pytest.raises(ValueError, match="total_len"):
+        _window_pool().acquire(0, [1, 2, 3], 2)
+
+
+@pytest.mark.parametrize("prompt_len,total,lo,held", [
+    (3, 5, 0, 2),        # two pages cover all it will ever write
+    (3, 40, 0, 3),       # a ring's worth, however long it runs
+    (8, 9, 0, 3),        # the prompt fills the window
+    (21, 23, 3, 3),      # a long prompt: pages 0-2 are never taken
+    (21, 22, 3, 3)])
+def test_window_admission_takes_the_prompts_last_pages(prompt_len, total,
+                                                       lo, held):
+    p = _window_pool()
+    p.acquire(0, list(range(prompt_len)), -(-total // 4), total_len=total)
+    lease = p.window_lease(0)
+    assert (lease.lo, lease.held()) == (lo, held)
+    # the ring's entry of a logical page is page % ring
+    for lp in range(lease.lo, lease.hi):
+        assert lease.ring[lp % 3] >= 0
+    _window_invariants(p)
+    p.release(0)
+    assert p.window_free_count() == 9 and p.window_lease(0) is None
+
+
+def test_window_release_behind_the_window_is_idempotent():
+    """A decoding slot returns the pages behind its window when it
+    enters a page its ring has no room for, and takes one; a second
+    call for the same position changes nothing; a position inside a
+    held page changes nothing."""
+    name = "kvp_window"
+    p = _window_pool(model=name)
+    counter = smetrics.KV_WINDOW_PAGES_RELEASED.labels(model=name)
+    p.acquire(0, [1, 2, 3], 10, total_len=40)
+    assert p.window_lease(0).ring.count(-1) == 0
+    free = p.window_free_count()
+    for position in range(3, 12):             # pages 0-2: all held
+        assert not p.window_advance(0, position)
+    assert (counter.value, p.window_free_count()) == (0, free)
+    # position 12 opens page 3; the window [5, 12] leaves page 0 behind
+    assert p.window_advance(0, 12)
+    assert (counter.value, p.window_free_count()) == (1, free)
+    lease = p.window_lease(0)
+    assert (lease.lo, lease.hi) == (1, 4)
+    ring = list(lease.ring)
+    assert not p.window_advance(0, 12)        # again: nothing to do
+    assert not p.window_advance(0, 13)
+    assert (counter.value, list(lease.ring)) == (1, ring)
+    _window_invariants(p)
+    for position in range(14, 40):
+        p.window_advance(0, position)
+        _window_invariants(p)
+        assert p.window_lease(0).held() == 3
+    assert counter.value == 7                 # pages 0-6, one each
+    assert not p.window_advance(5, 12)        # a slot with no lease
+
+
+def test_window_admission_is_refused_when_either_group_lacks_pages():
+    p = _window_pool(window_pages=4)
+    p.acquire(0, [1, 2, 3], 4, total_len=40)       # 3 of 4 window pages
+    before = p.stats()
+    with pytest.raises(kv_pool.PagesExhaustedError, match="window"):
+        p.acquire(1, [4, 5, 6], 2, total_len=8)    # needs 2, 1 free
+    assert p.stats() == before and p.lease(1) is None
+    with pytest.raises(kv_pool.PagesExhaustedError, match="private"):
+        p.acquire(1, [4, 5, 6], 14, total_len=4)   # 12 of 16 full pages
+    assert p.stats() == before and p.window_lease(1) is None
+    p.acquire(1, [4, 5, 6], 2, total_len=4)        # one window page: fits
+    _window_invariants(p)
+
+
+@pytest.mark.parametrize("end", ["release", "abort", "reset"])
+def test_window_cancel_and_finish_return_both_groups(end):
+    name = "kvp_window_" + end
+    p = _window_pool(model=name)
+    p.acquire(0, [1, 2, 3, 4, 5], 4, total_len=14)
+    p.acquire(1, [1, 2, 3, 4, 9], 3, total_len=12)
+    p.window_advance(0, 12)
+    gauges = lambda group: (                               # noqa: E731
+        smetrics.KV_GROUP_PAGES_TOTAL.labels(model=name, group=group).value,
+        smetrics.KV_GROUP_PAGES_FREE.labels(model=name, group=group).value)
+    assert gauges("window") == (9, 3) and gauges("full")[0] == 16
+    # the unlabelled pair counts both groups
+    assert smetrics.KV_PAGES_TOTAL.labels(model=name).value == 25
+    assert smetrics.KV_PAGES_FREE.labels(model=name).value \
+        == p.free_count() + 3
+    if end == "reset":
+        p.reset()
+    else:
+        getattr(p, end)(0)
+        getattr(p, end)(1)
+    assert gauges("window") == (9, 9)
+    assert p.available_count() == 16 and not p._wslots
+    _window_invariants(p)
+
+
+def test_window_group_leaves_the_full_groups_sharing_as_it_was():
+    """The radix tree shares the full group's prompt pages exactly as a
+    pool without a window group does; the window group's are private."""
+    plain, both = kv_pool.PagePool(16, 4), _window_pool()
+    for slot, tokens in enumerate(([1, 2, 3, 4, 5, 6, 7, 8, 9],
+                                   [1, 2, 3, 4, 5, 6, 7, 8, 7],
+                                   [1, 2, 3, 4, 0])):
+        want = plain.acquire(slot, tokens, 4)
+        assert both.acquire(slot, tokens, 4, total_len=16) == want
+    assert both.shared_count() == plain.shared_count() == 2
+    rings = [pg for s in range(3) for pg in both.window_lease(s).ring
+             if pg >= 0]
+    assert len(rings) == len(set(rings)) == 9
+    _window_invariants(both)
 
 
 # ---------------------------------------------------------------------------
