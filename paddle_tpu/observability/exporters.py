@@ -276,6 +276,9 @@ def _preregister_catalog():
                 # which tier advances a KDA decode layer's state
                 # (paddle_kda_decode_lowered_total{path})
                 "paddle_tpu.ops.kda",
+                # what runs a fused attention block's core
+                # (paddle_attention_block_lowered_total{path, d_head})
+                "paddle_tpu.ops.nn_ops",
                 "paddle_tpu.distributed.sharded_table"):
         try:
             importlib.import_module(mod)

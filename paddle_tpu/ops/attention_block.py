@@ -208,3 +208,56 @@ def _zero_seed_cot(seed):
 
 
 attention_block.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+def flash_block(x_q, x_kv, wq, wk, wv, wo, seed, n_head, causal, dropout_p,
+                bq, interpret):
+    """``attention_block``'s contract with the core — scores, mask,
+    softmax, dropout, ``p . V`` and their backward — in the Pallas
+    kernels of ``ops/pallas/flash_pairs.py`` (heads of 64, two a lane
+    tile, ``Tq == Tk`` in one key block). q, k, v and o stay
+    ``[B, T, M]``, the projections' output viewed flat, so the four
+    projections and their backward are plain matrix products; no
+    ``[B, H, Tq, Tk]`` tensor and no ``[B, H, T, D]`` relayout reaches
+    HBM. The backward takes o from its own kernel, not from the
+    forward's (``flash_pairs``' docstring)."""
+    return _flash_fwd(x_q, x_kv, wq, wk, wv, wo, seed, n_head, causal,
+                      dropout_p, bq, interpret)[0]
+
+
+def _mm(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32
+                               ).astype(a.dtype)
+
+
+def _flash_fwd(x_q, x_kv, wq, wk, wv, wo, seed, n_head, causal, dropout_p,
+               bq, interpret):
+    from paddle_tpu.ops.pallas.flash_pairs import pairs_forward
+    q, k, v = (_mm(x, w, ((2,), (0,)))
+               for x, w in ((x_q, wq), (x_kv, wk), (x_kv, wv)))
+    o = pairs_forward(q, k, v, seed, n_head, causal, dropout_p, bq,
+                      interpret)
+    return _mm(o, wo, ((2,), (0,))), (x_q, x_kv, wq, wk, wv, wo, seed,
+                                      q, k, v)
+
+
+def _flash_bwd(n_head, causal, dropout_p, bq, interpret, res, dout):
+    from paddle_tpu.ops.pallas.flash_pairs import pairs_backward
+    x_q, x_kv, wq, wk, wv, wo, seed, q, k, v = res
+    do = _mm(dout, wo, ((2,), (1,)))
+    dq, dk, dv, o = pairs_backward(q, k, v, do, seed, n_head, causal,
+                                   dropout_p, bq, interpret)
+
+    def dx(g, w):                       # [B,T,M] . w^T
+        return _mm(g, w, ((2,), (1,)))
+
+    def dw(x, g):                       # over (b, t)
+        return _mm(x, g, ((0, 1), (0, 1)))
+
+    return (dx(dq, wq), dx(dk, wk) + dx(dv, wv), dw(x_q, dq), dw(x_kv, dk),
+            dw(x_kv, dv), dw(o, dout), _zero_seed_cot(seed))
+
+
+flash_block.defvjp(_flash_fwd, _flash_bwd)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op, single
+from paddle_tpu.observability import metrics as _metrics
 
 
 # ---------------------------------------------------------------------------
@@ -764,6 +765,43 @@ def _pad(ctx, ins, attrs):
 # Ulysses, parallel/ring_attention.py — the long-context capability)
 # ---------------------------------------------------------------------------
 
+# exporter-catalog family (docs/observability.md). Counts LOWERINGS, as
+# ``paddle_kda_decode_lowered_total`` does: one increment per attention
+# block each time a program that holds it is traced, labelled with what
+# runs its core — ``flash`` (a Pallas kernel; no [B,H,Tq,Tk] tensor in
+# HBM) or ``composed`` (ops/attention_block.py) — and the head size.
+# Only the op's own emission into a program counts: not the shape
+# inference of program build (it sees no mesh), not the backward's
+# re-trace of the forward (it takes the same path). The sequence-parallel
+# branch (ring / Ulysses) is not counted.
+_ATTENTION_BLOCK_LOWERED = _metrics.counter(
+    "paddle_attention_block_lowered_total",
+    "fused_attention_block ops lowered, by what runs the attention core "
+    "(flash|composed) and head size",
+    labelnames=("path", "d_head"))
+
+
+def _attention_kernel_blocks(t_q, t_k, m, n_head, causal, mesh):
+    """(bq, bk) when a flash kernel runs the block's core, else None.
+    What the kernels need decides: a chip and no mesh of several devices
+    (or, for the tests, ``pallas.forced_interpret``); heads of whole
+    lane tiles for ``flash_attention``, heads of 64 in whole pairs with
+    Tq == Tk for ``flash_pairs``; a shape for which
+    ``flash_engage`` names blocks that divide it."""
+    from paddle_tpu.ops import pallas as pk
+    d = m // n_head
+    if d == pk.flash_pairs.D_HEAD:
+        eng = pk.flash_engage(t_q, t_k, d, causal)
+        if (eng and pk.flash_pairs.supported(t_q, t_k, m, n_head, *eng)
+                and (pk.kernel_enabled(mesh=mesh)
+                     or pk.forced_interpret())):
+            return eng
+        return None
+    if pk.kernel_enabled(128, d, mesh=mesh):
+        return pk.flash_engage(t_q, t_k, d, causal)
+    return None
+
+
 @register_op("fused_attention_block",
              ref="composed: mul+transpose+matmul+softmax ops; TPU-native "
                  "fused projection+attention block (zero-relayout VJP, "
@@ -822,37 +860,48 @@ def _fused_attention_block(ctx, ins, attrs):
                          ).astype(o.dtype)
         return single(_amp_out(out, attrs) if amp else out)
 
-    # Flash routing is BENCHMARK-DERIVED (pk.flash_engage reads the
-    # committed AUTOTUNE table from tools/flash_autotune.py): flash owns
-    # the region from T>=512 (model-verified: transformer_big 73.2k ->
-    # 77.1k tok/s at T=512/d=128) and all long-context shapes (O(T·D)
-    # HBM instead of O(T²)); below the crossover the fused block's
-    # relayout-free dots keep the row.
+    # The choice between kernel and composed block is made here, from
+    # what the emitter sees (d, Tq, Tk, causal, the mesh): flash_engage
+    # reads the committed table (tools/flash_autotune.py; model rows
+    # override region sweeps). Measured in the cell train_big_1chip
+    # (transformer_big, [16, 512] a chip, dropout 0.3, one v5e):
+    # 8 heads of 128 -> flash_attention, 73.2k -> 77.1k tok/s (2026-08,
+    # not the published model); the PUBLISHED 16 heads of 64 ->
+    # flash_pairs, 54.0k -> 76.9k tok/s (2026-09-30, PR 41; flash_attention
+    # behind a [B,H,T,D] relayout read 55.8k and was not kept).
+    # Below T=512 and under a mesh of several devices the composed
+    # block's relayout-free dots keep the row.
     h = n_head
     m = x_q.shape[-1]
     d = m // h
     from paddle_tpu.ops import pallas as pk
-    if pk.kernel_enabled(128, d, mesh=mesh):
-        eng = pk.flash_engage(t_q, t_k, d, causal)
-        if eng:
-            bq, bk = eng
-            def proj_bhtd(x, w):
-                y = jax.lax.dot_general(x, w.reshape(m, h, d),
-                                        (((2,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32
-                                        ).astype(x.dtype)
-                return y.transpose(0, 2, 1, 3)
-            q = proj_bhtd(x_q, wq)
-            k = proj_bhtd(x_kv, wk)
-            v = proj_bhtd(x_kv, wv)
-            o = pk.flash_attention(q, k, v, causal, float(d) ** -0.5,
-                                   bq, bk, False, dropout_p,
-                                   seed if dropout_p > 0 else None)
-            o = o.transpose(0, 2, 1, 3).reshape(x_q.shape[0], t_q, m)
-            out = jnp.matmul(o, wo.astype(o.dtype),
-                             preferred_element_type=jnp.float32
-                             ).astype(o.dtype)
-            return single(_amp_out(out, attrs) if amp else out)
+    eng = _attention_kernel_blocks(t_q, t_k, m, h, causal, mesh)
+    if ctx.op is not None and ctx.step_base_key is not None:
+        _ATTENTION_BLOCK_LOWERED.labels(
+            path="flash" if eng else "composed", d_head=str(d)).inc()
+    if eng and d == pk.flash_pairs.D_HEAD:
+        out = ab.flash_block(x_q, x_kv, wq, wk, wv, wo, seed, h, causal,
+                             dropout_p, eng[0], pk.interpret_mode())
+        return single(_amp_out(out, attrs) if amp else out)
+    if eng:
+        bq, bk = eng
+        def proj_bhtd(x, w):
+            y = jax.lax.dot_general(x, w.reshape(m, h, d),
+                                    (((2,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32
+                                    ).astype(x.dtype)
+            return y.transpose(0, 2, 1, 3)
+        q = proj_bhtd(x_q, wq)
+        k = proj_bhtd(x_kv, wk)
+        v = proj_bhtd(x_kv, wv)
+        o = pk.flash_attention(q, k, v, causal, float(d) ** -0.5,
+                               bq, bk, False, dropout_p,
+                               seed if dropout_p > 0 else None)
+        o = o.transpose(0, 2, 1, 3).reshape(x_q.shape[0], t_q, m)
+        out = jnp.matmul(o, wo.astype(o.dtype),
+                         preferred_element_type=jnp.float32
+                         ).astype(o.dtype)
+        return single(_amp_out(out, attrs) if amp else out)
 
     out = ab.attention_block(x_q, x_kv, wq, wk, wv, wo, seed,
                              n_head, causal, dropout_p)

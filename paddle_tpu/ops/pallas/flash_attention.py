@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 _NEG = -1e30
@@ -50,6 +51,29 @@ def _masked_scores(q, k, scale, causal, qb, j, bq, bk, q_off):
     return s
 
 
+_C_Q = np.uint32(0x9E3779B9)
+_C_K = np.uint32(0x85EBCA6B)
+
+
+def _seed_mix(seed, bh):
+    return (jnp.asarray(seed).astype(jnp.uint32)
+            + jnp.asarray(bh).astype(jnp.uint32) * jnp.uint32(0x27D4EB2F))
+
+
+def _finalize(x):
+    """murmur3's 32-bit finalizer."""
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x85EBCA6B)
+    x = x ^ (x >> 13)
+    x = x * jnp.uint32(0xC2B2AE35)
+    return x ^ (x >> 16)
+
+
+def keep_threshold(dropout_p):
+    """A hashed coordinate is kept iff its 32 bits reach this."""
+    return min(int(dropout_p * 2.0 ** 32), 2 ** 32 - 1)
+
+
 def hash_keep_mask(seed, bh, qpos, kpos, dropout_p):
     """Attention-weight dropout keep mask, upscale_in_train convention:
     keep/(1-p) as float32. Counter-based: a murmur3-finalizer mix of
@@ -58,17 +82,11 @@ def hash_keep_mask(seed, bh, qpos, kpos, dropout_p):
     kernels (TPU and interpret mode both) and in the jnp fallback paths,
     and the backward kernels regenerate the forward's mask bit-exactly
     from the same coordinates (reference semantics: dropout on the
-    softmax weights, dist_transformer.py:1044)."""
-    x = (qpos.astype(jnp.uint32) * jnp.uint32(0x9E3779B9)
-         ^ kpos.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B))
-    x = x ^ (jnp.asarray(seed).astype(jnp.uint32)
-             + jnp.asarray(bh).astype(jnp.uint32) * jnp.uint32(0x27D4EB2F))
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    thresh = jnp.uint32(min(int(dropout_p * 2.0 ** 32), 2 ** 32 - 1))
+    softmax weights, dist_transformer.py:1044). ``flash_pairs`` builds
+    the same bits from the same pieces."""
+    x = (qpos.astype(jnp.uint32) * _C_Q ^ kpos.astype(jnp.uint32) * _C_K)
+    x = _finalize(x ^ _seed_mix(seed, bh))
+    thresh = jnp.uint32(keep_threshold(dropout_p))
     keep = (x >= thresh).astype(jnp.float32)
     return keep * (1.0 / (1.0 - dropout_p))
 
@@ -234,10 +252,20 @@ def pick_blocks(tq, tk):
 # MODEL row exists, its A/B overrides the region sweep (isolated
 # regions mispredict block choice under real co-residency; entries
 # marked source="model-ab" in the table). Model-level verification of
-# the T=512 crossover: transformer_big moved 73.2k -> 77.1k tok/s
-# (42.8 -> 45.1% MFU) when this table routed it to flash; r04 had
-# measured the OPPOSITE with the then-kernels — which is exactly why
-# the rule must be a measured table, not a hand threshold.
+# the T=512 crossover, by head size, both on transformer_big at
+# [16, 512] tokens a chip, dropout 0.3, one v5e:
+#   8 heads of 128 (NOT the published model; 2026-08): 73.2k -> 77.1k
+#     tok/s (42.8 -> 45.1% MFU) when this table routed it to flash; r04
+#     had measured the OPPOSITE with the then-kernels — which is exactly
+#     why the rule must be a measured table, not a hand threshold.
+#   16 heads of 64 (the published widths; cell train_big_1chip,
+#     2026-09-30, PR 41): composed 54.0k -> 76.9k tok/s (36.9 -> 52.6%
+#     MFU) through flash_pairs; THIS file's kernel behind a [B,H,T,D]
+#     relayout read 55.8k there (+3 %) and was not kept for heads of 64.
+#     The d=64 rows at T=512 are that reading; their blocks are
+#     flash_pairs' (bq, and bk = the whole key axis). No other d=64 row
+#     is kept: the region sweep's were of this file's kernel behind a
+#     [B,H,T,D] relayout, which heads of 64 no longer take.
 
 
 def _autotune_table():
@@ -270,7 +298,9 @@ def flash_engage(tq, tk, d, causal):
     Below T=512 the region wins in AUTOTUNE are within the
     bthd<->bhtd boundary-transpose cost the composed path pays at the
     model level (the r4 fused block won T=256 by +1.5 MFU), so the
-    crossover is T>=512 where the model-level A/B confirmed it. Shapes
+    crossover is T>=512 where the model-level A/B confirmed it (heads
+    of 128 in 2026-08, heads of 64 through ``flash_pairs`` in PR 41:
+    the comment above ``_autotune_table``). Shapes
     beyond the table (T>2048, uneven tq/tk) fall back to the long-
     context heuristic blocks that won the T=4096..16384 sweep."""
     def _valid(blocks):
@@ -543,8 +573,7 @@ def _vjp_bwd(causal, scale, bq, bk, interpret, dropout_p, res, g):
 def _zero_seed_cot(seed):
     if seed is None:
         return None
-    import numpy as _np
-    return _np.zeros(jnp.shape(seed), dtype=jax.dtypes.float0)
+    return np.zeros(jnp.shape(seed), dtype=jax.dtypes.float0)
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)

@@ -117,6 +117,22 @@ def _flash(t, d):
             [((b, 8, t, d), BF16)] * 3)
 
 
+def _flash_pairs(dropout_p):
+    """Forward and backward kernels of the trainer's d = 64 path at the
+    cell's shape and the table's blocks: 16 heads of 64 as
+    [16, 512, 1024], causal (the mask's operations beside the rest)."""
+    from paddle_tpu.ops.pallas import flash_engage, flash_pairs
+    bq, bk = flash_engage(512, 512, 64, True)
+    assert flash_pairs.supported(512, 512, 1024, 16, bq, bk)
+
+    def both(q, k, v, g, seed):
+        return (flash_pairs.pairs_forward(q, k, v, seed, 16, True,
+                                          dropout_p, bq, False),
+                flash_pairs.pairs_backward(q, k, v, g, seed, 16, True,
+                                           dropout_p, bq, False))
+    return both, [((16, 512, 1024), BF16)] * 4 + [((1,), I32)]
+
+
 def _kda_state():
     # the hybrid cell: 128 slots x 64 heads of [128, 128] float32
     from paddle_tpu.ops.pallas import kda_state as ks
@@ -164,6 +180,8 @@ CASES = {
     "embed_pool-w256": lambda: _embed_pool(256),
     "flash-fwd+bwd-T512-d128": lambda: _flash(512, 128),
     "flash-fwd+bwd-T2048-d64": lambda: _flash(2048, 64),
+    "flash_pairs-fwd+bwd-T512-d64": lambda: _flash_pairs(0.0),
+    "flash_pairs-fwd+bwd-T512-d64-dropout": lambda: _flash_pairs(0.3),
     "fused_ce-fwd+bwd-8192x512x32000": _fused_ce,
     "fused_lstm-fwd+bwd-100x64x512": _fused_lstm,
     "kda_state-f32-128x64x128x128": _kda_state,
@@ -175,52 +193,111 @@ def test_kernel_compiles_for_v5e(chip, case):
     fn, shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
             for shape, dtype in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if case.startswith("flash_pairs"):      # forward AND backward kernel
+        assert _count_opcode(text, "custom-call") == 2
 
 
-def test_mesh_step_compiles_with_kernels_gated(v5e, chip, monkeypatch):
-    """XLA refuses to partition a Mosaic call ("wrap the call in a
-    shard_map"): a step lowered under a mesh must take the refer tier
-    where a lone chip takes the flash kernel (T=512, d_head=128 engages
-    it). ``on_tpu`` is steered here because trace-time gates ask
-    ``jax.default_backend()``, which is the CPU in this process."""
+def _attention_step_text(n_head, dist, chip, monkeypatch, compiled=True):
+    """The text of a one-block step (T = 512, d_model 256, mean of a
+    causal ``fused_multi_head_attention``) lowered for a described v5e:
+    on ``chip`` alone, or under ``dist``'s mesh. ``on_tpu`` is steered
+    because trace-time gates ask ``jax.default_backend()``, which is the
+    CPU in this process."""
     import numpy as np
-    from jax.sharding import Mesh
     import paddle_tpu.fluid as fluid
     from paddle_tpu.core.lowering import CompiledBlock
     from paddle_tpu.ops import pallas as pk
-    from paddle_tpu.parallel import DistributeConfig
 
     main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         x = fluid.layers.data(name="x", shape=[512, 256], dtype="float32")
         loss = fluid.layers.mean(fluid.layers.fused_multi_head_attention(
-            x, x, d_model=256, n_head=2, causal=True))
+            x, x, d_model=256, n_head=n_head, causal=True))
     scope = fluid.Scope()
     fluid.Executor(fluid.TPUPlace()).run(startup, scope=scope)
     monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    cb = CompiledBlock(main.desc, 0, ["x"], [loss.name], dist=dist)
+    # under a mesh the jit's own in_shardings place the arguments
+    where = {} if dist is not None else {"sharding": chip}
 
-    def compiled_text(dist):
-        cb = CompiledBlock(main.desc, 0, ["x"], [loss.name], dist=dist)
-        # under a mesh the jit's own in_shardings place the arguments
-        where = {} if dist is not None else {"sharding": chip}
+    def struct(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, **where)
+    state, consts = cb._gather_state(scope)
+    lowered = cb.fn.lower(
+        jax.tree_util.tree_map(struct, state),
+        jax.tree_util.tree_map(struct, consts),
+        {"x": jax.ShapeDtypeStruct((8, 512, 256), F32, **where)},
+        jax.ShapeDtypeStruct((), jnp.uint32, **where))
+    return lowered.compile().as_text() if compiled else lowered.as_text()
 
-        def struct(a):
-            return jax.ShapeDtypeStruct(np.shape(a), a.dtype, **where)
-        state, consts = cb._gather_state(scope)
-        return cb.fn.lower(
-            jax.tree_util.tree_map(struct, state),
-            jax.tree_util.tree_map(struct, consts),
-            {"x": jax.ShapeDtypeStruct((8, 512, 256), F32, **where)},
-            jax.ShapeDtypeStruct((), jnp.uint32, **where)
-        ).compile().as_text()
 
-    assert "tpu_custom_call" in compiled_text(None)
+@pytest.mark.parametrize("d_head", [128, 64])
+def test_mesh_step_compiles_with_kernels_gated(v5e, chip, monkeypatch,
+                                               d_head):
+    """XLA refuses to partition a Mosaic call ("wrap the call in a
+    shard_map"): a step lowered under a mesh must take the composed
+    block where a lone chip takes a flash kernel (T = 512 engages it at
+    heads of 128 and, two a lane tile, of 64), and the counter says
+    which."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from paddle_tpu.ops import nn_ops
+    from paddle_tpu.parallel import DistributeConfig
+
+    def lowered(path):
+        return nn_ops._ATTENTION_BLOCK_LOWERED.labels(
+            path=path, d_head=str(d_head)).value
+
+    was = lowered("flash"), lowered("composed")
+    text = _attention_step_text(256 // d_head, None, chip, monkeypatch)
+    assert "tpu_custom_call" in text
+    assert (lowered("flash"), lowered("composed")) == (was[0] + 1, was[1])
     mesh = Mesh(np.asarray(v5e), ("dp",))
-    text = compiled_text(DistributeConfig(mesh=mesh, data_axis="dp"))
+    text = _attention_step_text(
+        256 // d_head, DistributeConfig(mesh=mesh, data_axis="dp"), chip,
+        monkeypatch)
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text          # the mean over the dp-split batch
+    assert (lowered("flash"), lowered("composed")) == (was[0] + 1,
+                                                       was[1] + 1)
+
+
+def _scrubbed_sha(text):
+    """sha256 of a program's text without what names the checkout or the
+    process: a kernel's serialized body (``backend_config``, which
+    carries source paths), source locations in a jaxpr, the module's
+    name (blocks are numbered as a process builds them)."""
+    import hashlib
+    text = re.sub(r"module @\w+", "module", text)
+    text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', "", text)
+    text = re.sub(r" at [^\s]+:\d+", "", text)
+    text = re.sub(r"/[\w/.\-]+\.py(:\d+)?", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def test_head_size_128_lowers_as_before_the_pair_kernels(chip, monkeypatch):
+    """PR 41 gave heads of 64 kernels of their own; heads of 128 keep
+    ``flash_attention`` op for op. Held as recorded at the parent
+    commit: the step's StableHLO around the kernels, and the jaxpr of
+    forward + backward — the kernels' bodies — with and without dropout
+    (the hash helpers were regrouped, their operations were not)."""
+    from paddle_tpu.ops.pallas import flash_attention
+    step = _attention_step_text(2, None, chip, monkeypatch, compiled=False)
+    assert step.count("tpu_custom_call") == 1
+    assert _scrubbed_sha(step) == "c6d7980dd64b4698"
+    seed = jnp.asarray([3], I32)
+    shape = jax.ShapeDtypeStruct((2, 2, 512, 128), BF16)
+    for dropout_p, want in ((0.0, "2ff688339b6830b6"),
+                            (0.3, "565373baa98ec1ae")):
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, True, None, 512, 512, False, dropout_p,
+                seed if dropout_p > 0 else None).astype(F32))
+        jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+            shape, shape, shape))
+        assert _scrubbed_sha(jaxpr) == want, dropout_p
 
 
 # ---------------------------------------------------------------------------

@@ -174,3 +174,120 @@ def test_transformer_model_fused_matches_unfused():
     assert abs(results[True][0] - results[False][0]) < 0.6, results
     assert results[True][-1] < results[True][0]
     assert results[False][-1] < results[False][0]
+
+
+# ---------------------------------------------------------------------------
+# heads of 64: the flash kernels of ops/pallas/flash_pairs.py (two heads a
+# lane tile) under the same contract, interpreted on the CPU
+# ---------------------------------------------------------------------------
+
+def _pair_inputs(b=2, t=256, h=2, cross=False):
+    m = 64 * h
+    x_q = jnp.asarray(_rand((b, t, m), 40))
+    x_kv = jnp.asarray(_rand((b, t, m), 41)) if cross else x_q
+    ws = [jnp.asarray(_rand((m, m), 50 + i) * m ** -0.5) for i in range(4)]
+    return x_q, x_kv, ws, h
+
+
+PAIR_CASES = [(False, False, 0.0), (True, False, 0.0), (False, True, 0.0),
+              (False, False, 0.3), (True, False, 0.3), (False, True, 0.3)]
+
+
+@pytest.mark.parametrize("causal,cross,dropout_p", PAIR_CASES)
+def test_flash_block_matches_composed_block(causal, cross, dropout_p):
+    """d = 64 through the pair kernels against the composed block: the
+    forward and EVERY gradient (x_q, x_kv, Wq, Wk, Wv, Wo), causal / not /
+    cross with Tq == Tk, two query blocks, dropout from the
+    same seed (the gradients agree only if all three masks do)."""
+    from paddle_tpu.ops.attention_block import flash_block
+    x_q, x_kv, ws, h = _pair_inputs(cross=cross)
+    seed = jnp.asarray([4321], jnp.int32)
+    tangent = jnp.asarray(_rand(x_q.shape, 60))
+
+    def f_flash(x_q, x_kv, *ws):
+        return jnp.sum(tangent * flash_block(
+            x_q, x_kv, *ws, seed, h, causal, dropout_p, 128, True))
+
+    def f_composed(x_q, x_kv, *ws):
+        return jnp.sum(tangent * attention_block(
+            x_q, x_kv, *ws, seed, h, causal, dropout_p))
+
+    got = flash_block(x_q, x_kv, *ws, seed, h, causal, dropout_p, 128,
+                      True)
+    want = attention_block(x_q, x_kv, *ws, seed, h, causal, dropout_p)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    if cross:
+        args, nums = (x_q, x_kv, *ws), tuple(range(6))
+        g_flash = jax.grad(f_flash, argnums=nums)(*args)
+        g_composed = jax.grad(f_composed, argnums=nums)(*args)
+    else:       # self-attention: x_q IS x_kv, one gradient for both roles
+        g_flash = jax.grad(lambda x, *ws: f_flash(x, x, *ws),
+                           argnums=tuple(range(5)))(x_q, *ws)
+        g_composed = jax.grad(lambda x, *ws: f_composed(x, x, *ws),
+                              argnums=tuple(range(5)))(x_q, *ws)
+    for i, (a, bb) in enumerate(zip(g_flash, g_composed)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
+                                   rtol=3e-4, atol=3e-4,
+                                   err_msg=f"grad arg {i}")
+
+
+def _lowered(path, d_head):
+    from paddle_tpu.ops import nn_ops
+    return nn_ops._ATTENTION_BLOCK_LOWERED.labels(
+        path=path, d_head=str(d_head)).value
+
+
+def _run_block_op(monkeypatch, t_q, t_k, d_model, n_head, forced=True):
+    """One ``fused_attention_block`` through the executor on the CPU,
+    kernels on the interpreter where the gate lets them; returns the
+    output and how much each path of the counter grew."""
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1" if forced else "0")
+    d_head = d_model // n_head
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 3
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        xq = fluid.layers.data(name="xq", shape=[t_q, d_model],
+                               dtype="float32")
+        xkv = fluid.layers.data(name="xkv", shape=[t_k, d_model],
+                                dtype="float32")
+        out = layers.fused_multi_head_attention(xq, xkv, d_model, n_head)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    was = _lowered("flash", d_head), _lowered("composed", d_head)
+    got = exe.run(main, feed={"xq": _rand((1, t_q, d_model), 70),
+                              "xkv": _rand((1, t_k, d_model), 71)},
+                  fetch_list=[out])[0]
+    return got, (_lowered("flash", d_head) - was[0],
+                 _lowered("composed", d_head) - was[1])
+
+
+def test_op_takes_the_pair_kernels_at_heads_of_64(monkeypatch):
+    """T = 512, two heads of 64: the emitter routes to the kernels
+    (forced onto the interpreter here), says so in the counter, and the
+    result is the composed block's."""
+    got, grew = _run_block_op(monkeypatch, 512, 512, 128, 2)
+    assert grew == (1, 0)
+    want, grew = _run_block_op(monkeypatch, 512, 512, 128, 2, forced=False)
+    assert grew == (0, 1)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("t_q,t_k,d_model,n_head,why", [
+    (512, 512, 192, 3, "an odd number of heads leaves half a lane tile"),
+    (640, 640, 128, 2, "T is not a multiple of the table's blocks"),
+    (512, 1024, 128, 2, "Tq != Tk under 2 048"),
+    (256, 256, 128, 2, "below the T = 512 crossover"),
+    (512, 512, 64, 2, "heads of 32"),
+])
+def test_op_refuses_odd_shapes_and_says_composed(monkeypatch, t_q, t_k,
+                                                 d_model, n_head, why):
+    _, grew = _run_block_op(monkeypatch, t_q, t_k, d_model, n_head)
+    assert grew == (0, 1), why
+
+
+def test_counter_is_in_the_exporter_catalog():
+    from paddle_tpu.observability import exporters, metrics as obs_metrics
+    exporters._preregister_catalog()
+    assert "paddle_attention_block_lowered_total" in \
+        obs_metrics.default_registry().snapshot()

@@ -227,3 +227,70 @@ def test_attention_op_train_vs_test_dropout():
     o3 = exe.run(test_prog, feed={"q": qv}, fetch_list=[out])[0]
     o4 = exe.run(test_prog, feed={"q": qv}, fetch_list=[out])[0]
     np.testing.assert_allclose(o3, o4, rtol=1e-6)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_pair_kernel_mask_is_hash_keep_mask_bit_for_bit(causal):
+    """``flash_pairs`` keeps exactly the coordinates ``hash_keep_mask(seed,
+    b * H + h, qpos, kpos)`` keeps, for BOTH heads of every pair and the
+    last batch row, in the forward kernel and in the backward's second
+    making of o. With q = 0 every visible key weighs the same, and a v
+    that is one-hot in (key, channel) shows each key's keep bit in its
+    own output channel: 64 keys a run."""
+    from paddle_tpu.ops.pallas.flash_pairs import (pairs_backward,
+                                                   pairs_forward)
+    b, t, h, p = 3, 256, 4, 0.3
+    m = 64 * h
+    seed = jnp.array([20260930], jnp.int32)
+    q = jnp.zeros((b, t, m), jnp.float32)
+    kept = np.zeros((2, b, h, t, t), bool)
+    for r in range(t // 64):
+        v = np.zeros((b, t, h, 64), np.float32)
+        v[:, np.arange(64) + 64 * r, :, np.arange(64)] = 1.0
+        v = jnp.asarray(v.reshape(b, t, m))
+        outs = (pairs_forward(q, q, v, seed, h, causal, p, 128, True),
+                pairs_backward(q, q, v, q, seed, h, causal, p, 128,
+                               True)[3])
+        for which, o in enumerate(outs):
+            kept[which, ..., 64 * r:64 * (r + 1)] = np.asarray(
+                o).reshape(b, t, h, 64).transpose(0, 2, 1, 3) > 0
+    want = np.asarray(hash_keep_mask(
+        seed[0], jnp.arange(b * h).reshape(b, h, 1, 1),
+        jnp.arange(t)[None, None, :, None],
+        jnp.arange(t)[None, None, None, :], p)) > 0
+    if causal:
+        want &= np.tril(np.ones((t, t), bool))
+    assert want.any() and not want.all()
+    np.testing.assert_array_equal(kept[0], want)
+    np.testing.assert_array_equal(kept[1], want)
+    assert not np.array_equal(want[-1, 0], want[-1, 1])     # heads differ
+    assert not np.array_equal(want[-1, -1], want[0, -1])    # rows differ
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_pair_kernel_dropout_gradients_match_reference(causal):
+    """The one backward kernel makes the forward's mask again: its dq,
+    dk, dv equal the autodiff of the composed reference and its o the
+    forward kernel's, two query blocks, heads as lanes 0:64 and 64:128
+    of one tile."""
+    from paddle_tpu.ops.pallas.flash_pairs import (pairs_backward,
+                                                   pairs_forward)
+    b, h, t = 2, 2, 256
+    q4, k4, v4 = _qkv(b=b, h=h, tq=t, tk=t, d=64, seed=3)
+    g4 = _qkv(b=b, h=h, tq=t, tk=t, d=64, seed=4)[0]
+    seed = jnp.array([21], jnp.int32)
+
+    def flat(x):                                    # [B,H,T,D] -> [B,T,M]
+        return x.transpose(0, 2, 1, 3).reshape(b, t, h * 64)
+
+    want_o, vjp = jax.vjp(
+        lambda q, k, v: _reference(q, k, v, causal, 0.3, 21), q4, k4, v4)
+    got = pairs_backward(flat(q4), flat(k4), flat(v4), flat(g4), seed, h,
+                         causal, 0.3, 128, True)
+    fwd_o = pairs_forward(flat(q4), flat(k4), flat(v4), seed, h, causal,
+                          0.3, 128, True)
+    for a, bb in zip(got, vjp(g4) + (want_o,)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(flat(bb)),
+                                   rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(fwd_o), np.asarray(got[3]),
+                               rtol=1e-6, atol=1e-6)

@@ -245,7 +245,7 @@ def test_flash_engage_reads_unified_table():
     fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
     # the migrated winners (previously the in-code AUTOTUNE dict)
     assert fa.flash_engage(512, 512, 128, True) == (512, 512)
-    assert fa.flash_engage(512, 512, 64, False) == (256, 512)
+    assert fa.flash_engage(512, 512, 64, False) == (512, 512)
     assert fa.flash_engage(1024, 1024, 128, False) == (512, 1024)
     assert fa.flash_engage(2048, 2048, 128, True) == (512, 512)
     # model-A/B tie below the crossover: fused block keeps the row
