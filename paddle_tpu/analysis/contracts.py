@@ -419,9 +419,13 @@ def rule_geometry_drift(ctx) -> Iterable[Diagnostic]:
                     var="tok", details={"view": key})
         # the second kind of per-slot state (a hybrid family's recurrent
         # and conv state): one row per slot, whichever view declares it
+        from paddle_tpu.core.registry import slot_state_vars
+        declared = {n for slots in slot_state_vars(
+            v.desc.global_block).values() for names in slots.values()
+            for n in names}
         for name, vd in v.desc.global_block.vars.items():
-            if g.n_slots and vd.persistable and vd.shape and (
-                    "_kda_state_" in name or "_kda_conv_" in name) \
+            if g.n_slots and vd.persistable and vd.shape \
+                    and name in declared \
                     and int(vd.shape[0]) != g.n_slots:
                 yield Diagnostic(
                     rule="ctr-geometry-drift", severity=Severity.ERROR,

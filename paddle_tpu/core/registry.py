@@ -92,19 +92,26 @@ class OpSpec:
     nondiff_inputs: tuple = ()
     # docstring-level reference citation
     ref: str = ""
+    # per-slot recurrent state of a serving op: (the kind of state, the
+    # output slots that carry it) — what the slot engine finds a model's
+    # fixed-size state by (serving/engine.py:_discover_state), whatever
+    # mixer keeps it and whatever its variables are called
+    slot_state: Optional[tuple] = None
 
 
 OPS: Dict[str, OpSpec] = {}
 
 
-def register_op(op_type: str, *, no_grad: bool = False, ref: str = ""):
+def register_op(op_type: str, *, no_grad: bool = False, ref: str = "",
+                slot_state: Optional[tuple] = None):
     """Register an emitter for `op_type` (capability parity with
     REGISTER_OPERATOR / REGISTER_OP_CUDA_KERNEL, op_registry.h:197,237)."""
 
     def deco(fn: Callable) -> Callable:
         if op_type in OPS:
             raise ValueError(f"op {op_type!r} registered twice")
-        OPS[op_type] = OpSpec(type=op_type, emit=fn, no_grad=no_grad, ref=ref)
+        OPS[op_type] = OpSpec(type=op_type, emit=fn, no_grad=no_grad, ref=ref,
+                              slot_state=slot_state)
         return fn
 
     return deco
@@ -122,6 +129,24 @@ def get_op(op_type: str) -> OpSpec:
 
 def has_op(op_type: str) -> bool:
     return op_type in OPS
+
+
+def slot_state_vars(block) -> Dict[str, Dict[str, List[str]]]:
+    """{kind of state: {output slot: its variables' names, sorted}} of
+    the per-slot recurrent state that the ops of ``block`` (a BlockDesc)
+    carry: the outputs an op's registration declares as ``slot_state``
+    (``StateOut``: the recurrence's own state; ``ConvOut``: the conv
+    window's last rows)."""
+    found: Dict[str, Dict[str, set]] = {}
+    for op in block.ops:
+        spec = OPS.get(op.type)
+        if spec is not None and spec.slot_state:
+            kind, slots = spec.slot_state
+            for slot in slots:
+                found.setdefault(kind, {}).setdefault(slot, set()).update(
+                    op.output(slot))
+    return {kind: {slot: sorted(names) for slot, names in slots.items()}
+            for kind, slots in sorted(found.items())}
 
 
 # -- helpers for emitters ---------------------------------------------------

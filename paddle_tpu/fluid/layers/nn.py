@@ -663,7 +663,8 @@ def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
     matrices and leaves ``attrs`` alone (its programs stay what they
     were); ``gqa = {"n_kv_head", "head_dim", "gate"}`` (and where the
     layer has them ``qk_norm`` with ``rms_eps``, ``rope_theta``,
-    ``window``) declares a grouped-KV layer in x's dtype — Wq / Wg
+    ``window``, ``attn_scale``: what multiplies the scores in place of
+    ``head_dim ** -0.5``) declares a grouped-KV layer in x's dtype — Wq / Wg
     [M, H*D], Wk / Wv [M, n_kv*D], Wo [H*D, M], names ``<base>.wq`` ...
     ``.wg`` — and sets the attrs the op reads them by."""
     if gqa is None:
@@ -696,6 +697,8 @@ def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
         attrs["rope_theta"] = float(gqa["rope_theta"])
     if gqa.get("window"):
         attrs["window"] = int(gqa["window"])
+    if gqa.get("attn_scale"):
+        attrs["attn_scale"] = float(gqa["attn_scale"])
     return [ws[t] for t in ("wq", "wk", "wv", "wo")], extra
 
 
@@ -723,22 +726,31 @@ def rms_norm(x, epsilon=1e-5, param_attr=None, name=None):
 
 
 def dense(x, size, param_attr=None, out_dtype=None, out_name=None,
-          name=None):
+          name=None, weight=None, scale=None):
     """x [..., M] @ W [M, size], multiplied in x's dtype with float32
     accumulation; the result in ``out_dtype`` (x's by default) — a
     bfloat16 model's logits stay float32 this way. ``out_name`` names
-    the result so that a caller can fetch it."""
+    the result so that a caller can fetch it. ``weight`` is a variable
+    [size, M] that exists (a TIED head: the embedding's table, read as
+    it lies and contracted over its columns, no transposed copy) in
+    place of a parameter of the layer's own; ``scale`` multiplies the
+    float32 result (a tied, scaled head: logits / logits_scaling)."""
     helper = LayerHelper("dense", name=name)
-    w = helper.create_parameter(param_attr, shape=[int(x.shape[-1]), size],
-                                dtype=x.dtype)
+    attrs = {"out_dtype": out_dtype} if out_dtype else {}
+    if weight is not None:
+        w, attrs["transpose_w"] = weight, True
+    else:
+        w = helper.create_parameter(
+            param_attr, shape=[int(x.shape[-1]), size], dtype=x.dtype)
+    if scale is not None:
+        attrs["scale"] = float(scale)
     dtype = out_dtype or x.dtype
     if out_name:
         out = helper.block.create_var(name=out_name, dtype=dtype)
     else:
         out = helper.create_variable_for_type_inference(dtype)
     helper.append_op("dense", inputs={"X": [x], "W": [w]},
-                     outputs={"Out": [out]},
-                     attrs={"out_dtype": out_dtype} if out_dtype else {})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -810,10 +822,69 @@ def kda(x, state, conv, d_model, n_head, head_dim, base, init, rank,
     return out
 
 
+def ssd(x, state, conv, d_model, sizes, base, init, epsilon=1e-5,
+        seq_len=None, slot=None, active=None, name=None):
+    """One Mamba-2 (SSD) mixer layer (ops/ssd.py) over the persistable
+    per-slot ``state`` [n_slots, N, H*P] float32 and ``conv``
+    [n_slots, taps-1, H*P + 2*G*N], both read and written under their
+    own names (donated). ``sizes``: ssd_heads (H), ssd_head_dim (P),
+    ssd_d_state (N), ssd_groups (G), ssd_conv_taps, ssd_chunk. With
+    ``seq_len`` and ``slot`` it is the prefill of ONE request, x
+    [1, T, M], writing slot ``slot``; with ``active`` the decode step of
+    every slot, x [n_slots, 1, M]. Weights ``<base>.<tag>``; the decay
+    starts as Mamba-2 starts it: A = exp(A_log) spread evenly over
+    [1, 16] across the heads and a dt_bias whose softplus is spread
+    log-evenly over [0.001, 0.1] across them; D = 1."""
+    from paddle_tpu.fluid.initializer import NumpyArrayInitializer
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    prefill = seq_len is not None
+    op = "ssd_prefill" if prefill else "ssd_decode"
+    helper = LayerHelper(op, name=name)
+    h, p = int(sizes["ssd_heads"]), int(sizes["ssd_head_dim"])
+    n, g = int(sizes["ssd_d_state"]), int(sizes["ssd_groups"])
+    taps = int(sizes["ssd_conv_taps"])
+    inner, wide = h * p, h * p + 2 * g * n
+    dt0 = np.exp(np.linspace(np.log(1e-3), np.log(0.1), h))
+    # tag: (the op's slot, shape, the fixed float32 start or None: drawn)
+    table = {
+        "w_in": ("WIn", [d_model, inner + wide + h], None),
+        "w_out": ("WOut", [inner, d_model], None),
+        "conv": ("ConvW", [taps, wide], None),
+        "conv_bias": ("ConvB", [1, wide], None),
+        "a_log": ("ALog", [h], np.log(np.linspace(1.0, 16.0, h))),
+        "dt_bias": ("DtBias", [h], dt0 + np.log(-np.expm1(-dt0))),
+        "d": ("D", [h], np.ones(h)),
+        "norm": ("Norm", [inner], np.ones(inner))}
+    inputs = {}
+    for tag, (slot_name, shape, fixed) in table.items():
+        attr = ParamAttr(
+            name=f"{base}.{tag}",
+            initializer=init if fixed is None else NumpyArrayInitializer(
+                fixed.astype(np.float32)))
+        inputs[slot_name] = [helper.create_parameter(
+            attr, shape=shape,
+            dtype=x.dtype if fixed is None else "float32")]
+    inputs.update(X=[x], State=[state], Conv=[conv])
+    if prefill:
+        inputs.update(SeqLen=[seq_len], Slot=[slot])
+    else:
+        inputs.update(Active=[active])
+    attrs = {"n_head": h, "head_dim": p, "d_state": n, "n_groups": g,
+             "epsilon": float(epsilon)}
+    if prefill:
+        attrs["chunk"] = int(sizes["ssd_chunk"])
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(op, inputs=inputs,
+                     outputs={"Out": [out], "StateOut": [state],
+                              "ConvOut": [conv]}, attrs=attrs)
+    return out
+
+
 def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
                     init, held_start=0, n_shared=1, norm_topk=True,
                     scaling=1.0, valid=None, seq_len=None, counts=None,
-                    name=None, router_bias=False):
+                    name=None, router_bias=False, d_shared=None,
+                    scoring="sigmoid"):
     """One expert-parallel member's share of a top-k routed expert layer
     plus the shared expert (ops/expert_ffn.py): the router is
     ``n_experts`` wide, the ``n_held`` experts from ``held_start`` are
@@ -822,10 +893,14 @@ def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
     accumulates the tokens each held expert was given and the calls in
     which it was given any. ``router_bias`` declares the router's
     correction bias [1, n_experts] float32 (``<base>.router_bias``):
-    experts are then picked by score + bias and weighed by score."""
+    experts are then picked by score + bias and weighed by score.
+    ``d_shared`` is the shared expert's own width (``n_shared *
+    d_expert`` when None); ``scoring`` the router's: ``"sigmoid"``
+    scores over every expert, or ``"softmax_topk"`` — the best ``top_k``
+    by logit, weighed by a softmax over those logits alone."""
     from paddle_tpu.fluid.param_attr import ParamAttr
     helper = LayerHelper("expert_ffn_held", name=name)
-    shared = n_shared * d_expert
+    shared = n_shared * d_expert if d_shared is None else int(d_shared)
     shapes = {"router": ("RouterW", [d_model, n_experts]),
               "w_gate": ("WGate", [n_held, d_model, d_expert]),
               "w_up": ("WUp", [n_held, d_model, d_expert]),
@@ -849,11 +924,14 @@ def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
         inputs["SeqLen"] = [seq_len]
     if counts is not None:
         inputs["Counts"], outputs["CountsOut"] = [counts], [counts]
+    attrs = {"top_k": int(top_k), "held_start": int(held_start),
+             "norm_topk": bool(norm_topk), "scaling": float(scaling)}
+    if scoring != "sigmoid":
+        # set only where it differs: the sigmoid routers' programs stay
+        # what they were
+        attrs["scoring"] = str(scoring)
     helper.append_op("expert_ffn_held", inputs=inputs, outputs=outputs,
-                     attrs={"top_k": int(top_k),
-                            "held_start": int(held_start),
-                            "norm_topk": bool(norm_topk),
-                            "scaling": float(scaling)})
+                     attrs=attrs)
     return outputs["Out"][0]
 
 

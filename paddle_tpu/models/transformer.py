@@ -180,15 +180,18 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 
 # ---------------------------------------------------------------------------
 # The hybrid sparse block of the slot views (decoder_lm(..., **arch)):
-# RMSNorm, a mixer per layer of kind "gqa" (gated softmax attention with
-# grouped KV heads and no positions, through the paged pool), "kda" (Kimi
-# Delta Attention, a fixed-size recurrent state per slot) or "mla" (latent
-# attention with rotary positions and the DSA indexer's sparse selection:
-# a latent plane and an indexer-key plane in the paged pool), and an
-# expert layer of which this program holds a share — or, in the first
-# ``first_k_dense`` layers, a dense SwiGLU layer of width ``d_inner``. One
-# scope serves the prefill and the decode view: every weight and every
-# state variable is named.
+# RMSNorm, a mixer per layer of kind "gqa" (softmax attention with
+# grouped KV heads and no positions, through the paged pool; an output
+# gate where ``gqa_gate``), "swa" (a "gqa" layer over a sliding window,
+# with rotary positions, in a page group of its own), "kda" (Kimi Delta
+# Attention, a fixed-size recurrent state per slot), "ssd" (Mamba-2's
+# state-space dual: another fixed-size state per slot, a chunked scan as
+# its prefill) or "mla" (latent attention with rotary positions and the
+# DSA indexer's sparse selection: a latent plane and an indexer-key plane
+# in the paged pool), and an expert layer of which this program holds a
+# share — or, in the first ``first_k_dense`` layers, a dense SwiGLU layer
+# of width ``d_inner``. One scope serves the prefill and the decode view:
+# every weight and every state variable is named.
 # ---------------------------------------------------------------------------
 
 _HYBRID_KEYS = {
@@ -200,12 +203,22 @@ _HYBRID_KEYS = {
     # (``rope_theta``), cached in a page group of their own
     "n_kv_head": None, "head_dim": None, "gqa_gate": True,
     "qk_norm": False, "window": None,
+    # what multiplies the attention scores ("gqa" layers) in place of
+    # head_dim ** -0.5
+    "attn_scale": None,
     # a norm AFTER each sub-layer too (x + Norm(f(Norm(x)))), and a
-    # factor on the embedding
-    "post_norms": False, "embed_scale": 1.0,
+    # factor on the embedding, on each sub-layer's result before it
+    # joins the residual (x + r f(Norm(x))) and under the logits
+    # (logits / logits_scale); a head that is the embedding's own table
+    "post_norms": False, "embed_scale": 1.0, "residual_scale": 1.0,
+    "logits_scale": 1.0, "tie_embeddings": False,
     # "kda" layers
     "kda_heads": None, "kda_head_dim": None, "kda_conv_taps": 4,
     "kda_gate_rank": None,
+    # "ssd" layers: heads, a head's channels, the state's size, the
+    # groups that share B and C, the conv's taps, the prefill's chunk
+    "ssd_heads": None, "ssd_head_dim": None, "ssd_d_state": None,
+    "ssd_groups": 1, "ssd_conv_taps": 4, "ssd_chunk": None,
     # "mla" layers: the query's and the cache's latent widths, a head's
     # parts, the rotation's base, the indexer's heads and its top-k
     "q_lora_rank": None, "kv_lora_rank": None, "qk_nope_head_dim": None,
@@ -219,6 +232,10 @@ _HYBRID_KEYS = {
     "n_routed_experts": None, "n_experts_held": None, "held_start": 0,
     "n_experts_per_tok": None, "d_expert": None, "n_shared_experts": 1,
     "norm_topk_prob": True, "routed_scaling_factor": 1.0,
+    # the shared expert's own width (n_shared_experts * d_expert when
+    # None) and how the router scores: "sigmoid" over every expert, or
+    # "softmax_topk" (the best by logit, a softmax over the picks)
+    "d_shared": None, "scoring": "sigmoid",
     "rms_eps": 1e-5, "dtype": "float32"}
 
 # the sizes only layers of one kind read: required where the kind occurs
@@ -226,9 +243,14 @@ _KIND_KEYS = {
     "gqa": ("n_kv_head", "head_dim"),
     "swa": ("n_kv_head", "head_dim", "window", "rope_theta"),
     "kda": ("kda_heads", "kda_head_dim", "kda_gate_rank"),
+    "ssd": ("ssd_heads", "ssd_head_dim", "ssd_d_state", "ssd_chunk"),
     "mla": ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
             "qk_rope_head_dim", "v_head_dim", "rope_theta",
             "index_n_heads", "index_head_dim", "index_topk")}
+
+
+# sizes whose None is a value (the op's own default), not an omission
+_OPTIONAL = ("attn_scale", "d_shared")
 
 
 def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
@@ -249,7 +271,8 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
     unused = {k for keys in _KIND_KEYS.values() for k in keys} \
         - {k for kind in period for k in _KIND_KEYS[kind]}
     missing = sorted(k for k, v in hy.items()
-                     if v is None and k not in unused)
+                     if v is None and k not in unused
+                     and k not in _OPTIONAL)
     if missing:
         raise ValueError(f"decoder_lm: a hybrid block (layer_kinds given) "
                          f"needs {missing} too")
@@ -259,6 +282,9 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
             f"is served by the slot views prefill_paged and decode_paged "
             f"alone (no wave cache; a verify window would have to roll a "
             f"recurrent state back)")
+    if hy["attn_scale"] is not None and "swa" in period:
+        raise ValueError("attn_scale is read by 'gqa' layers alone: a "
+                         "window layer scales by head_dim ** -0.5")
     if not 0 < hy["n_experts_held"] <= hy["n_routed_experts"] \
             - hy["held_start"]:
         raise ValueError("n_experts_held must lie inside the router's "
@@ -272,13 +298,15 @@ def hybrid_weight_std(name: str, shape) -> float:
     with (the program's start-up and the benchmark's drawer use this one
     rule): 1 for the embedding (nothing scales it and an RMSNorm follows),
     taps**-0.5 for the depthwise conv (its output keeps its input's
-    variance), 0.01 for a router's correction bias (small beside the
+    variance), 0.1 for a conv's bias, 0.01 for a router's correction bias (small beside the
     scores' spread, so that picking and weighing differ), Glorot's sqrt(2 / (fan_in + fan_out)) over the last two
     dimensions otherwise."""
     if name.endswith("_emb"):
         return 1.0
     if name.endswith(".conv"):
         return float(shape[0]) ** -0.5
+    if name.endswith(".conv_bias"):
+        return 0.1
     if name.endswith(".router_bias"):
         return 0.01
     return (2.0 / (float(shape[-2]) + float(shape[-1]))) ** 0.5
@@ -304,6 +332,13 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
                          param_attr=pa("emb", True))
     if hy["embed_scale"] != 1.0:
         x = layers.scale(x, scale=float(hy["embed_scale"]))
+
+    def join(x, y):
+        """x + residual_scale * y."""
+        if hy["residual_scale"] != 1.0:
+            y = layers.scale(y, scale=float(hy["residual_scale"]))
+        return layers.elementwise_add(x, y)
+
     for i, kind in enumerate(hy["kinds"]):
         y = layers.rms_norm(x, eps, pa(f"l{i}_ln1_scale"))
         if kind in ("gqa", "swa"):
@@ -324,7 +359,7 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
                 pvs = pool_var(f"{name}_{tag}vs_{i}", sshape)
             gqa = dict(n_kv_head=hy["n_kv_head"], head_dim=d,
                        gate=hy["gqa_gate"], qk_norm=hy["qk_norm"],
-                       rms_eps=eps)
+                       rms_eps=eps, attn_scale=hy["attn_scale"])
             if swa:
                 gqa.update(window=hy["window"], rope_theta=hy["rope_theta"],
                            attended_name=f"{name}_l{i}_attn_attended")
@@ -360,6 +395,20 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
                         "page_table", "pos", "seq_len", "gen_start",
                         "active", "position")],
                     selected_name=f"{name}_l{i}_mla_selected")))
+        elif kind == "ssd":
+            sizes = {k: hy[k] for k in _HYBRID_KEYS if k.startswith("ssd_")}
+            inner = hy["ssd_heads"] * hy["ssd_head_dim"]
+            state = pool_var(f"{name}_ssd_state_{i}",
+                             [n_slots, hy["ssd_d_state"], inner])
+            conv = pool_var(
+                f"{name}_ssd_conv_{i}",
+                [n_slots, hy["ssd_conv_taps"] - 1,
+                 inner + 2 * hy["ssd_groups"] * hy["ssd_d_state"]], dt)
+            y = layers.ssd(
+                y, state, conv, d_model, sizes, f"{name}_l{i}_ssd", init,
+                eps,
+                **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
+                   if prefill else dict(active=feeds["active"])))
         else:
             h, d = hy["kda_heads"], hy["kda_head_dim"]
             state = pool_var(f"{name}_kda_state_{i}", [n_slots, h, d, d])
@@ -373,7 +422,7 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
                    if prefill else dict(active=feeds["active"])))
         if hy["post_norms"]:
             y = layers.rms_norm(y, eps, pa(f"l{i}_ln1_post_scale"))
-        x = layers.elementwise_add(x, y)
+        x = join(x, y)
         y = layers.rms_norm(x, eps, pa(f"l{i}_ln2_scale"))
         if i < hy["first_k_dense"]:
             y = layers.swiglu_ffn(y, d_model, d_inner, f"{name}_l{i}_ffn",
@@ -389,17 +438,27 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
                     valid=feeds["active"],
                     counts=pool_var(f"{name}_moe_counts_{i}",
                                     [2, hy["n_experts_held"]], "int32"))),
-                router_bias=hy["router_bias"])
+                router_bias=hy["router_bias"], d_shared=hy["d_shared"],
+                scoring=hy["scoring"])
         if hy["post_norms"]:
             y = layers.rms_norm(y, eps, pa(f"l{i}_ln2_post_scale"))
-        x = layers.elementwise_add(x, y)
+        x = join(x, y)
     x = layers.reshape(x, shape=[-1, d_model])
     if prefill:
         one = layers.fill_constant([1, 1], "int64", 1)
         x = layers.gather(x, layers.elementwise_sub(feeds["seq_len"], one))
     x = layers.rms_norm(x, eps, pa("lnf_scale"))
-    return layers.dense(x, vocab, pa("head_w", True),
-                        out_dtype="float32", out_name=f"{name}_logits")
+    head = {}
+    if hy["tie_embeddings"]:
+        # ONE table, read by the embedding and by the logits
+        head["weight"] = fluid.default_main_program().global_block().var(
+            f"{name}_emb")
+    if hy["logits_scale"] != 1.0:
+        head["scale"] = 1.0 / float(hy["logits_scale"])
+    return layers.dense(x, vocab,
+                        None if hy["tie_embeddings"] else pa("head_w", True),
+                        out_dtype="float32", out_name=f"{name}_logits",
+                        **head)
 
 
 class _HybridNormal(fluid.initializer.Initializer):
@@ -484,9 +543,11 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
 
     ``arch`` (``layer_kinds=...`` and the sizes :func:`hybrid_arch`
     lists) turns the block into that of a hybrid sparse model: RMSNorm,
-    per layer a gated grouped-KV softmax mixer with no positions ("gqa",
-    through the same paged ops and pools), a Kimi Delta Attention mixer
-    ("kda", a fixed-size recurrent state per slot beside the pages) or
+    per layer a grouped-KV softmax mixer with no positions ("gqa",
+    through the same paged ops and pools; "swa" over a sliding window),
+    a Kimi Delta Attention mixer ("kda", a fixed-size recurrent state
+    per slot beside the pages), a Mamba-2 state-space mixer ("ssd",
+    another fixed-size state, prefilled by a chunked scan) or
     latent attention with rotary positions and the DSA indexer's sparse
     selection ("mla": a latent plane and an indexer-key plane in the
     pool, and a ``position`` feed of the decode view), and an expert
@@ -657,7 +718,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             # for every position behind the first decode step's window
             page_rows_w = sdata("page_rows_w", [t, 1])
             feed_specs["page_rows_w"] = ([t, 1], "int64")
-        if hy is not None and "kda" in hy["kinds"]:
+        if hy is not None and {"kda", "ssd"} & set(hy["kinds"]):
             # which slot's recurrent state this request's prompt lands
             # in (>= n_slots: nowhere — the warm-up's dispatch)
             state_slot = sdata("state_slot", [1, 1])

@@ -43,6 +43,8 @@ PHASES = {
     "kv_attention_decode_paged": ("write", "gather", "attend"),
     "kv_attention_verify_paged": ("write", "gather", "attend"),
     "kda_decode": ("conv", "state"),
+    "ssd_decode": ("conv", "state"),
+    "ssd_prefill": ("conv", "scan"),
     "expert_ffn_held": ("route", "up", "down", "shared"),
     # a VARIANT of an op (``<op type>/<variant>``): the scope an op
     # lowers under, below its own, where an attribute makes it another

@@ -1,6 +1,8 @@
 """The expert feed-forward layer of a sparse model, as ONE member of an
 expert-parallel group computes it: the router scores every token over
-ALL ``n_experts`` experts and keeps the ``top_k`` best, and this
+ALL ``n_experts`` experts and keeps the ``top_k`` best (by a sigmoid of
+every logit, or by the logits themselves with a softmax over the picks:
+:func:`route`), and this
 program computes the part of the result that the experts it HOLDS
 (``[held_start, held_start + n_held)``) give, plus the shared expert
 that every member computes alike. What the absent experts would add is
@@ -62,12 +64,21 @@ _phase = functools.partial(_device_scopes.phase, "expert_ffn_held")
 
 
 def route(x, router_w, top_k: int, norm_topk: bool, scaling: float,
-          bias=None):
+          bias=None, scoring: str = "sigmoid"):
     """x [N, M] -> (combine weights [N, top_k] float32, expert ids
     [N, top_k]): sigmoid scores over every expert, the best ``top_k``,
     renormalised over the picks when ``norm_topk``. With a correction
     ``bias`` [1, E] (DeepSeek-V3's ``noaux_tc``) the picks are the best
-    by ``score + bias`` and the weights the picks' scores, without it."""
+    by ``score + bias`` and the weights the picks' scores, without it.
+    ``scoring="softmax_topk"`` (Granite's ``GraniteMoeTopKGating``): the
+    best ``top_k`` by LOGIT, weighed by a softmax over those logits
+    alone (no bias, nothing to renormalise)."""
+    if scoring == "softmax_topk":
+        vals, idx = jax.lax.top_k(dense(x, router_w), top_k)
+        return jax.nn.softmax(vals, axis=-1) * scaling, idx
+    if scoring != "sigmoid":
+        raise ValueError(f"a router scores by 'sigmoid' or 'softmax_topk', "
+                         f"not {scoring!r}")
     scores = jax.nn.sigmoid(dense(x, router_w))
     if bias is None:
         vals, idx = jax.lax.top_k(scores, top_k)
@@ -167,7 +178,7 @@ def _expert_ffn_held(ctx, ins, attrs):
     tokens each held expert has been given, row 1 the calls in which it
     was given any; they wrap, a reader takes differences) -> Out
     [B,T,M] (+ CountsOut). attrs: top_k, held_start, norm_topk,
-    scaling."""
+    scaling, scoring (:func:`route`; sigmoid when absent)."""
     x = first(ins, "X")
     b, t, m = x.shape
     x2 = x.reshape(b * t, m)
@@ -183,7 +194,8 @@ def _expert_ffn_held(ctx, ins, attrs):
             x2, first(ins, "RouterW"), int(attrs["top_k"]),
             bool(attrs.get("norm_topk", True)),
             float(attrs.get("scaling", 1.0)),
-            *(() if bias is None else (bias,)))
+            *(() if bias is None else (bias,)),
+            **({"scoring": attrs["scoring"]} if "scoring" in attrs else {}))
     y, sizes = held_experts_part(
         x2, combine, idx, first(ins, "WGate"), first(ins, "WUp"),
         first(ins, "WDown"), int(attrs.get("held_start", 0)), valid)

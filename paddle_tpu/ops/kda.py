@@ -150,6 +150,7 @@ def _weights(ins):
 
 
 @register_op("kda_prefill", no_grad=True,
+             slot_state=("kda", ("StateOut", "ConvOut")),
              ref="TPU-native serving op: Kimi Delta Attention "
                  "(arXiv:2510.26692) over one request's prompt, the "
                  "exact recurrence to its true length, writing the "
@@ -199,6 +200,7 @@ def _kda_prefill(ctx, ins, attrs):
 
 
 @register_op("kda_decode", no_grad=True,
+             slot_state=("kda", ("StateOut", "ConvOut")),
              ref="TPU-native serving op: one Kimi Delta Attention step "
                  "for every decode slot, the recurrent and conv state "
                  "updated in place, inactive slots untouched "

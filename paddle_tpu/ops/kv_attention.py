@@ -262,15 +262,16 @@ def _attended(keep, first_col=0):
                       jnp.sum(keep, axis=-1)], axis=-1).astype(jnp.int32)
 
 
-def _gqa_attend(q, k, v, window=None):
+def _gqa_attend(q, k, v, window=None, scale=None):
     """Causal attention of q [B,T,n_kv,G,D] over k, v [B,T,n_kv,D] ->
     ([B,T,n_kv,G,D] in q's dtype, with a window what each query
     attends [T, 2]: ``_attended``). With ``window`` query t sees the
     keys s with 0 <= t - s < window: a band, and a block of queries
-    reads only the keys its band can reach."""
+    reads only the keys its band can reach. ``scale`` multiplies the
+    scores (D ** -0.5 when None)."""
     b, t, n_kv, g, d = q.shape
     dt = q.dtype
-    scale = float(d) ** -0.5
+    scale = float(d) ** -0.5 if scale is None else float(scale)
     blk = GQA_QUERY_BLOCK
     if t <= blk:
         s = jnp.einsum("btkgd,bskd->bkgts", q, k,
@@ -322,7 +323,8 @@ def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, ins, attrs, gqa):
                        lambda: jnp.broadcast_to(jnp.arange(t), (b, t)),
                        grouped=True)
     window = attrs.get("window")
-    c, seen = _gqa_attend(q, k, v, int(window) if window else None)
+    c, seen = _gqa_attend(q, k, v, int(window) if window else None,
+                          attrs.get("attn_scale"))
     out = _gqa_output(x, c.reshape(b, t, h * d), wg, wo)
     return out, k.reshape(b, t, -1), v.reshape(b, t, -1), seen
 
@@ -731,7 +733,8 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
         valid = (j[None, :] < lens[:, None]) | \
                 ((j[None, :] >= gen0[:, None]) &
                  (j[None, :] <= pos[:, None]))           # [B,S]
-        c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv)
+        c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv,
+                             attrs.get("attn_scale"))
     if gqa is not None:
         out = _gqa_output(x, c.reshape(b, 1, -1), first(ins, "Wg"), wo)
     else:

@@ -48,22 +48,30 @@ def _mul(ctx, ins, attrs):
     return single(out.reshape(out_shape))
 
 
-def dense(x, w, out_dtype=None):
-    """x [..., M] @ w [M, N] in the weight's dtype with float32
+def dense(x, w, out_dtype=None, transpose_w=False):
+    """x [..., M] @ w [M, N] (``transpose_w``: w [N, M], contracted over
+    its columns as it lies) in the weight's dtype with float32
     accumulation; the result in ``out_dtype`` (float32 by default)."""
-    y = jax.lax.dot_general(x.astype(w.dtype), w,
-                            (((x.ndim - 1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    y = jax.lax.dot_general(
+        x.astype(w.dtype), w,
+        (((x.ndim - 1,), (1 if transpose_w else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     return y if out_dtype is None else y.astype(out_dtype)
 
 
 @register_op("dense", no_grad=True,
              ref="X [..., M] @ W [M, N] in the weight's dtype with "
-                 "float32 accumulation; attr out_dtype (default: X's)")
+                 "float32 accumulation; attr out_dtype (default: X's); "
+                 "with attr transpose_w W is [N, M], contracted over its "
+                 "columns as it lies (a tied head); attr scale multiplies "
+                 "the float32 result")
 def _dense(ctx, ins, attrs):
     x = first(ins, "X")
-    return single(dense(x, first(ins, "W"),
-                        attrs.get("out_dtype") or x.dtype))
+    y = dense(x, first(ins, "W"),
+              transpose_w=bool(attrs.get("transpose_w")))
+    if attrs.get("scale") is not None:
+        y = y * float(attrs["scale"])
+    return single(y.astype(attrs.get("out_dtype") or x.dtype))
 
 
 @register_op("matmul", ref="operators/matmul_op.cc")

@@ -1005,15 +1005,23 @@ class SlotGenerativeModel:
 
     def _discover_state(self, dec_main, pre_feeds):
         """The second kind of per-slot state (docs/serving.md "Recurrent
-        state"): a hybrid family's ``*_kda_state_*`` / ``*_kda_conv_*``
-        variables, [n_slots, ...] each, fixed-size per slot — so
-        admission stays by pages and free slots. The prefill view writes
-        the slot its ``state_slot`` feed names, the decode view updates
-        every active slot in place. ``*_moe_counts_*`` are the expert
-        layers' device-side counters (``expert_token_counts``)."""
+        state"): the variables a hybrid family's mixers DECLARE as
+        per-slot state (``register_op(..., slot_state=(kind, slots))``:
+        a ``kda`` layer's delta-rule state and conv window, an ``ssd``
+        layer's state-space state and conv window), [n_slots, ...] each,
+        fixed-size per slot — so admission stays by pages and free
+        slots. The prefill view writes the slot its ``state_slot`` feed
+        names, the decode view updates every active slot in place.
+        ``*_moe_counts_*`` are the expert layers' device-side counters
+        (``expert_token_counts``)."""
+        from paddle_tpu.core.registry import slot_state_vars
         gvars = dec_main.desc.global_block.vars
-        self.state_vars = sorted(n for n in gvars
-                                 if "_kda_state_" in n or "_kda_conv_" in n)
+        self.state_kinds = {
+            kind: sorted(n for names in slots.values() for n in names)
+            for kind, slots in slot_state_vars(
+                dec_main.desc.global_block).items()}
+        self.state_vars = sorted(
+            n for names in self.state_kinds.values() for n in names)
         self._count_vars = sorted(n for n in gvars if "_moe_counts_" in n)
         self._counts_seen = (0, 0)        # (totals, device values) read
         self._decode_steps_done = 0
@@ -1033,8 +1041,9 @@ class SlotGenerativeModel:
                        * (4 if gvars[n].dtype == "float32" else 2)
                        for n in names)
 
-        smetrics.RECURRENT_STATE_BYTES.labels(model=self.name).set(
-            nbytes(self.state_vars))
+        for kind, names in self.state_kinds.items():
+            smetrics.RECURRENT_STATE_BYTES.labels(
+                model=self.name, kind=kind).set(nbytes(names))
         # the third kind (docs/serving.md "Latent pages and the indexer's
         # cache"): a latent-attention layer's two planes, paged like K
         # and V and leased with the slot's pages through the one table
@@ -1054,6 +1063,27 @@ class SlotGenerativeModel:
             model=self.name)
         self._m_dsa_selected = smetrics.DSA_ROWS_SELECTED.labels(
             model=self.name)
+        # a state-space layer's prefill scans whole chunks up to the
+        # prompt's true length: counted on the host at admission, from
+        # the length and the chunk the op was built with
+        ssd_ops = [op for op in dec_main.desc.global_block.ops
+                   if op.type == "ssd_decode"]
+        self._ssd_layers = len(ssd_ops)
+        self._ssd_chunks: Dict[int, int] = {}       # by prompt bucket
+        self._m_ssd_tokens = smetrics.SSD_TOKENS_SCANNED.labels(
+            model=self.name)
+        self._m_ssd_rows = smetrics.SSD_CHUNK_ROWS.labels(model=self.name)
+
+    def _ssd_chunk(self, p_len: int) -> int:
+        """Rows a turn of the chunked scan of the ``p_len`` prefill view
+        (``ops/ssd.py``: the op's ``chunk``, at most the bucket)."""
+        if p_len not in self._ssd_chunks:
+            op = next(
+                op for op in
+                self._cb_prefill[p_len]._program_desc.global_block.ops
+                if op.type == "ssd_prefill")
+            self._ssd_chunks[p_len] = min(int(op.attrs["chunk"]), p_len)
+        return self._ssd_chunks[p_len]
 
     # decode steps between two snapshots of the expert counters
     COUNT_SNAPSHOT_STEPS = 32
@@ -1465,6 +1495,11 @@ class SlotGenerativeModel:
         self._m_prefills.inc()
         self._m_admissions.inc()
         self._m_tokens.inc()
+        if self._ssd_layers:
+            chunk = self._ssd_chunk(p_len)
+            self._m_ssd_tokens.inc(length * self._ssd_layers)
+            self._m_ssd_rows.inc(-(-length // chunk) * chunk
+                                 * self._ssd_layers)
         first = int(np.asarray(tok).reshape(-1)[0])
         self._active[slot] = True
         self._tok[slot] = first

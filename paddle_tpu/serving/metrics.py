@@ -187,8 +187,19 @@ KV_PAGE_EVICTIONS = _metrics.counter(
 RECURRENT_STATE_BYTES = _metrics.gauge(
     "paddle_recurrent_state_bytes",
     "Bytes of per-slot recurrent and conv state a hybrid model keeps "
-    "beside its KV pages (static: fixed-size per slot, n_slots of them; "
-    "0 for a model with none)", labelnames=("model",))
+    "beside its KV pages, by the kind of mixer that keeps it (kda|ssd; "
+    "static: fixed-size per slot, n_slots of them; no sample for a "
+    "model with none)", labelnames=("model", "kind"))
+SSD_TOKENS_SCANNED = _metrics.counter(
+    "paddle_ssd_tokens_scanned_total",
+    "True prompt tokens through ssd_prefill's scan, summed over the "
+    "model's SSD layers (counted on the host at admission)",
+    labelnames=("model",))
+SSD_CHUNK_ROWS = _metrics.counter(
+    "paddle_ssd_chunk_rows_total",
+    "Rows ssd_prefill's chunked scan computed: the whole chunks a "
+    "prompt's true length fills, summed over the model's SSD layers",
+    labelnames=("model",))
 LATENT_CACHE_BYTES = _metrics.gauge(
     "paddle_latent_cache_bytes",
     "Bytes of the latent-attention layers' latent planes in the page "

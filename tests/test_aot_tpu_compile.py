@@ -787,3 +787,90 @@ def test_window_prefill_compiles_for_v5e(chip, trinity_engine, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
     assert "reduce-window" not in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# state-space layers beside one attention layer (PR 42): the decode step
+# and the 2048-token prefill of granite4_h_small_ep4_d10 at the cell's sizes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def granite_engine():
+    import json
+    import os
+    from paddle_tpu import serving
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.ops import pallas as pk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "granite4_h_small_ep4_d10.json")) as f:
+        cfg = json.load(f)
+    build = cfg["build"]
+    was, pk.on_tpu = pk.on_tpu, lambda: True
+    try:
+        programs = T.build_decoder_lm_programs(
+            name="lm", modes=T.slot_modes(cfg["kv_layout"]),
+            kv_codec=cfg["kv_codec"],
+            **{**build, "prompt_buckets": tuple(build["prompt_buckets"]),
+               "layer_kinds": tuple(build["layer_kinds"])})
+        yield serving.make_slot_model("lm", programs, init=False), programs
+    finally:
+        pk.on_tpu = was
+
+
+def test_ssd_decode_step_compiles_for_v5e(chip, granite_engine, monkeypatch):
+    """128 slots, bf16, nine SSD layers and one attention layer, 18 of 72
+    experts: the step's arguments are the 12.28 GB the configuration's
+    file counts and it fits one chip with its temporaries; each SSD
+    layer's state (``f32[128,128,8192]``, 537 MB) is touched by ONE
+    instruction — a fusion with two results, the new state and ``y
+    f32[128,8192]``: one read and one write of the state — and no
+    ``copy`` or ``transpose`` of its size is in the step; the state and
+    the pages are donated and aliased in place. 128 tokens take the
+    experts' dense way."""
+    eng, programs = granite_engine
+    compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
+                             eng._decode_feeds(), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert 12.2e9 < mem.argument_size_in_bytes < 12.35e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    state = 9 * 128 * 128 * 8192 * 4
+    assert mem.alias_size_in_bytes >= state + 2 * 22528 * 16 * 1024 * 2
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    ops = _hlo_ops(text)
+    touching = [line for line in entry.splitlines()[1:]
+                if "f32[128,128,8192]" in line.split(" = ", 1)[-1]
+                and not re.search(r"\b(parameter|get-tuple-element|bitcast|"
+                                  r"tuple)\(", line)]
+    assert len(touching) == 9, touching[:3]
+    assert all(re.search(r"= \(f32\[128,128,8192\]\S*, f32\[128,8192\]\S*\) "
+                         r"fusion\(", line) for line in touching)
+    assert not [n for n in ops if n.startswith("ragged-dot")]
+    assert entry.count("gather_pages") >= 2
+    # the module's name carries ``ssd_decode``'s row of the phases
+    assert text.startswith("HloModule jit_lm_decode_paged_s8ff8,")
+
+
+def test_ssd_prefill_compiles_for_v5e(chip, granite_engine, monkeypatch):
+    """The 2048-token prefill beside the weights, the state and the
+    pages: under 14.5 GB (the bound under which the configuration keeps
+    128 slots); the scan is a ``while`` over the chunks a prompt fills;
+    the slot's state lands by an in-place update of the donated variable
+    (no copy of a state's size); 2048 tokens take the experts' grouped
+    way (three ``ragged-dot`` an expert layer)."""
+    eng, programs = granite_engine
+    compiled = _compile_view(chip, programs, "prefill_paged@2048",
+                             eng._cb_prefill[2048],
+                             eng._prefill_feeds(2048), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    assert mem.alias_size_in_bytes >= 9 * 128 * 128 * 8192 * 4
+    text = compiled.as_text()
+    ops = _hlo_ops(text)
+    assert _count_opcode(text, "while") >= 9
+    assert not [line for opcode, count, _a, line in ops.values()
+                if opcode in ("copy", "transpose")
+                and count >= 128 * 128 * 8192]
+    assert text.startswith("HloModule jit_lm_prefill_paged_2048_s0b8b,")

@@ -249,6 +249,20 @@ spec("kda_prefill", {"X": [f(1, 5, 8)], **_KDA,
                      "SeqLen": [lens(3).reshape(1, 1)],
                      "Slot": [lens(1).reshape(1, 1)]},
      {"n_head": 2, "head_dim": 4})
+# the state-space mixer (ops/ssd.py): two slots, two heads of four
+# channels, a state of eight, one group, a conv of four taps with bias
+_SSD = {"WIn": [f(8, 34, seed=2)], "WOut": [f(8, 8, seed=3)],
+        "ConvW": [f(4, 24, seed=4)], "ConvB": [f(1, 24, seed=5)],
+        "ALog": [f(2, seed=6)], "DtBias": [f(2, seed=7)], "D": [pos(2)],
+        "Norm": [pos(8)], "State": [f(2, 8, 8, seed=8)],
+        "Conv": [f(2, 3, 24, seed=9)]}
+_SSD_ATTRS = {"n_head": 2, "head_dim": 4, "d_state": 8, "n_groups": 1}
+spec("ssd_decode", {"X": [f(2, 1, 8)], **_SSD,
+                    "Active": [ints(2, 1, hi=2, seed=3)]}, _SSD_ATTRS)
+spec("ssd_prefill", {"X": [f(1, 6, 8)], **_SSD,
+                     "SeqLen": [lens(4).reshape(1, 1)],
+                     "Slot": [lens(1).reshape(1, 1)]},
+     {**_SSD_ATTRS, "chunk": 3})
 spec("expert_ffn_held",
      {"X": [f(1, 4, 8)], "RouterW": [f(8, 6, seed=1)],
       "WGate": [f(2, 8, 5, seed=2)], "WUp": [f(2, 8, 5, seed=3)],
