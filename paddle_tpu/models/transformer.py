@@ -339,6 +339,20 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
             y = layers.scale(y, scale=float(hy["residual_scale"]))
         return layers.elementwise_add(x, y)
 
+    def grouped_counts(i):
+        """A prefill of more than ``DENSE_MAX_TOKENS`` tokens takes the
+        expert layer's grouped way and counts the rows it held, in a
+        counter of the prefill views' own (the engine runs the decode
+        view's startup, which knows nothing of it, and makes the
+        counter itself: ``SlotGenerativeModel._grouped_counters``). A
+        view of up to that many tokens has none, and its program is
+        what it was."""
+        from paddle_tpu.ops.expert_ffn import DENSE_MAX_TOKENS
+        if not prefill or pools["prompt_len"] <= DENSE_MAX_TOKENS:
+            return {}
+        return {"counts": pool_var(f"{name}_moe_grouped_{i}",
+                                   [2, hy["n_experts_held"]], "int32")}
+
     for i, kind in enumerate(hy["kinds"]):
         y = layers.rms_norm(x, eps, pa(f"l{i}_ln1_scale"))
         if kind in ("gqa", "swa"):
@@ -428,16 +442,20 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
             y = layers.swiglu_ffn(y, d_model, d_inner, f"{name}_l{i}_ffn",
                                   init)
         else:
+            if prefill:
+                told = dict(seq_len=feeds["seq_len"], **grouped_counts(i))
+            else:
+                told = dict(valid=feeds["active"],
+                            counts=pool_var(f"{name}_moe_counts_{i}",
+                                            [2, hy["n_experts_held"]],
+                                            "int32"))
             y = layers.expert_ffn_held(
                 y, d_model, hy["d_expert"], hy["n_routed_experts"],
                 hy["n_experts_held"], hy["n_experts_per_tok"],
                 f"{name}_l{i}_moe", init, hy["held_start"],
                 hy["n_shared_experts"],
                 hy["norm_topk_prob"], hy["routed_scaling_factor"],
-                **(dict(seq_len=feeds["seq_len"]) if prefill else dict(
-                    valid=feeds["active"],
-                    counts=pool_var(f"{name}_moe_counts_{i}",
-                                    [2, hy["n_experts_held"]], "int32"))),
+                **told,
                 router_bias=hy["router_bias"], d_shared=hy["d_shared"],
                 scoring=hy["scoring"])
         if hy["post_norms"]:
@@ -735,6 +753,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             hy, mode, x_ids, name, vocab, d_model, d_inner, n_head, pool_var,
             pools=dict(shape=[n_pages, page_size], dtype=store_dt,
                        codec=kv_codec, n_slots=int(n_slots),
+                       prompt_len=int(prompt_len),
                        # every slot's ring, whatever the context
                        window_pages=int(n_slots) * window_ring(
                            hy["window"], page_size)

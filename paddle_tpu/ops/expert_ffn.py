@@ -13,13 +13,27 @@ for it.
 Dropless, static shapes, two ways to the same sum, chosen from the
 number of tokens in the call (a static shape, never a flag):
 
-- **grouped** (more than ``DENSE_MAX_TOKENS`` tokens: a prefill longer
-  than any the benchmark's cells serve): the ``n_tokens * top_k``
-  assignments are sorted by held expert into a buffer of that many rows (the worst case: no capacity factor, no
-  token dropped), assignments to experts held elsewhere sort to the end
-  and are never computed, and one grouped product per projection
-  (``jax.lax.ragged_dot``: a single Mosaic kernel on the TPU) runs over
-  the held experts;
+- **grouped** (more than ``DENSE_MAX_TOKENS`` tokens: Granite's
+  prefills of 1 024 and 2 048 tokens, inside its cell's window; GLM-5's
+  and Trinity's while their cells prime): the ``n_tokens * top_k``
+  assignments are sorted by held expert, held ones first, and every
+  buffer holds the HELD rows alone — :func:`grouped_rows` of them, from
+  the shapes the op sees (a quarter more than the held experts' even
+  share of the assignments: 6 400 of 20 480 rows at 18 of 72 experts;
+  all of them where the member holds every expert), never a capacity
+  factor: a draw that holds more takes another turn of a loop whose trip
+  count the device decides, and nothing is dropped. What moves is what
+  is computed: the held rows' inputs are gathered into the buffer, one
+  grouped product per projection (``jax.lax.ragged_dot``: a single
+  Mosaic kernel on the TPU) runs over it, and each token then reads its
+  picks' result rows where they lie and sums them weighed, in one pass
+  (until PR 44 every buffer was ``[n_tokens * top_k, ·]`` and the result
+  went back through a permute, a select, a reshape and a sum of that
+  size: 46 of a 2 048-token prefill's 124 ms, for rows three quarters
+  of which nobody computed). A pick held elsewhere, a padded token's or
+  another turn's is SELECTED out, not weighed by zero: the grouped
+  product leaves the rows past its groups as it found them, NaN on the
+  chip now and then;
 - **dense** (a decode step, a prefill of up to 512 tokens): every held
   expert
   multiplies EVERY token, and the combine weight — zero where the token
@@ -102,12 +116,28 @@ def swiglu(x, w_gate, w_up, w_down):
 DENSE_MAX_TOKENS = 512
 
 
+def grouped_rows(n: int, k: int, n_held: int, n_experts: int) -> int:
+    """Rows of the grouped way's buffers for ``n`` tokens of ``k`` picks
+    over a router ``n_experts`` wide of which ``n_held`` are held here:
+    a quarter more than the held experts' even share of the ``n * k``
+    assignments, in whole tiles of 256, at most all of them (a member
+    that holds every expert). A draw that holds more takes another turn
+    of :func:`held_experts_part`'s loop; nothing is dropped."""
+    if n_held >= n_experts:
+        return n * k
+    share = -(-n * k * n_held * 5 // (n_experts * 4))
+    return min(n * k, -(-share // 256) * 256)
+
+
 def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
-                      held_start: int, valid=None):
+                      held_start: int, valid=None, n_experts=None):
     """The routed part of the layer that the held experts give: x [N, M],
     combine / idx [N, K], w_gate / w_up [E_held, M, F], w_down
     [E_held, F, M] -> (y [N, M] float32, tokens per held expert
-    [E_held] int32). Tokens with ``valid`` false are routed nowhere."""
+    [E_held] int32). Tokens with ``valid`` false are routed nowhere.
+    ``n_experts`` is the router's width (the grouped way sizes its
+    buffers by the held share, :func:`grouped_rows`; every assignment's
+    worth when None)."""
     n, k = idx.shape
     n_held = w_gate.shape[0]
     with _phase("route"):
@@ -130,23 +160,64 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
                 hidden.astype(x.dtype), w_down,
                 (((1, 2), (0, 1)), ((), ())), preferred_element_type=F32)
         return y, sizes
+    rows = grouped_rows(n, k, n_held, n_held if n_experts is None
+                        else n_experts)
     with _phase("route"):
-        key = key.reshape(-1)                                    # [N*K]
-        order = jnp.argsort(key, stable=True)
-        xs = jnp.take(x, order // k, axis=0)                     # [N*K, M]
-    with _phase("up"):
-        hidden = jax.nn.silu(_grouped(xs, w_gate, sizes)) \
-            * _grouped(xs, w_up, sizes)
-    with _phase("down"):
-        ys = _grouped(hidden.astype(x.dtype), w_down, sizes)     # [N*K, M]
-        # back to assignment order; rows past the held groups are never
-        # computed: on the chip the grouped product leaves them as it
-        # found them (NaN now and then, which a weight of 0 does not
-        # silence: a padded position's NaN reached the next layer's
-        # keys; PERF.md, PR 37), so they are selected out, not weighed
-        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, -1)
-        back = jnp.where(held[:, :, None], back * combine[:, :, None], 0.0)
-        return jnp.sum(back, axis=1), sizes
+        # held assignments first, by expert (an assignment held elsewhere
+        # carries the largest key): ``order`` is the assignment at each
+        # sorted position, ``at`` the position of each assignment
+        order = jnp.argsort(key.reshape(-1), stable=True)        # [N*K]
+        at = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+        turns = -(-n * k // rows)
+        token = jnp.pad((order // k).astype(jnp.int32),
+                        (0, turns * rows - n * k))
+        # where each held expert's rows end (a masked sum, not a
+        # cumsum: that lowers to a window reduction, which the long
+        # prefills' compile tests keep out of their modules)
+        e = jnp.arange(n_held)
+        ends = jnp.sum(jnp.where(e[:, None] <= e[None, :],
+                                 sizes[:, None], 0), axis=0)
+        starts = ends - sizes
+
+    def turn(i, y):
+        """Sorted positions [i * rows, (i + 1) * rows): the held rows
+        among them through the three products, and their weighed sum
+        added to each token's row of ``y``."""
+        lo = i * rows
+        with _phase("route"):
+            xs = jnp.take(x, jax.lax.dynamic_slice(token, (lo,), (rows,)),
+                          axis=0, mode="clip")                   # [R, M]
+            # each held expert's rows inside this turn
+            cut = jnp.clip(ends, lo, lo + rows) \
+                - jnp.clip(starts, lo, lo + rows)
+        with _phase("up"):
+            hidden = jax.nn.silu(_grouped(xs, w_gate, cut)) \
+                * _grouped(xs, w_up, cut)
+        with _phase("down"):
+            ys = _grouped(hidden.astype(x.dtype), w_down, cut)   # [R, M]
+            # back to token order in one pass: a token reads its picks'
+            # rows where they are. Rows past the turn's groups are never
+            # computed: on the chip the grouped product leaves them as
+            # it found them (NaN now and then, which a weight of 0 does
+            # not silence: a padded position's NaN reached the next
+            # layer's keys; PERF.md, PR 37), so a pick held elsewhere,
+            # a padded token's or another turn's is selected out, not
+            # weighed
+            here = held & (at >= lo) & (at < lo + rows)
+            row = jnp.clip(at - lo, 0, rows - 1)
+            for j in range(k):
+                y = y + jnp.where(
+                    here[:, j, None],
+                    jnp.take(ys, row[:, j], axis=0, mode="clip")
+                    * combine[:, j, None], 0.0)
+        return y
+
+    if turns == 1:
+        return turn(0, 0.0), sizes
+    # as many turns as the draw's held assignments fill: one, unless
+    # the router sends this member far more than its even share
+    return jax.lax.fori_loop(0, -(-ends[-1] // rows), turn,
+                             jnp.zeros((n, x.shape[1]), F32)), sizes
 
 
 def _all_tokens(x, w):
@@ -198,7 +269,8 @@ def _expert_ffn_held(ctx, ins, attrs):
             **({"scoring": attrs["scoring"]} if "scoring" in attrs else {}))
     y, sizes = held_experts_part(
         x2, combine, idx, first(ins, "WGate"), first(ins, "WUp"),
-        first(ins, "WDown"), int(attrs.get("held_start", 0)), valid)
+        first(ins, "WDown"), int(attrs.get("held_start", 0)), valid,
+        first(ins, "RouterW").shape[1])
     with _phase("shared"):
         y = y + swiglu(x2, first(ins, "SGate"), first(ins, "SUp"),
                        first(ins, "SDown"))

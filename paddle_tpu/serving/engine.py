@@ -1029,6 +1029,30 @@ class SlotGenerativeModel:
         # zeros until the dispatcher takes the first snapshot
         self._counts_snapshot: Tuple = (0, [
             np.zeros(gvars[n].shape, np.int32) for n in self._count_vars])
+        # the prefill views' own counters: the rows the expert layers'
+        # grouped way held (``*_moe_grouped_*``, in the views of the
+        # buckets that take that way: layers the decode view counts
+        # too, so they ride its snapshots; made by
+        # ``_grouped_counters``), beside the rows it was given — bucket
+        # tokens x top_k a layer, known here — per prefill
+        self._grouped_vars: List[str] = []
+        self._grouped_given: Dict[int, int] = {}    # by prompt bucket
+        for p_len, cb in self._cb_prefill.items():
+            ops = [op for op in cb._program_desc.global_block.ops
+                   if op.type == "expert_ffn_held" and op.inputs.get(
+                       "Counts")]
+            self._grouped_vars += [op.inputs["Counts"][0] for op in ops]
+            self._grouped_given[p_len] = sum(
+                p_len * int(op.attrs["top_k"]) for op in ops)
+        self._grouped_vars = sorted(set(self._grouped_vars))
+        self._grouped_given_done = 0
+        self._grouped_seen = (0, 0)   # (given, device values) brought
+        # (rows given, a copy of each counter) as of the same instant
+        self._grouped_snapshot: Tuple = (0, [])
+        self._m_grouped = {
+            rows: smetrics.MOE_GROUPED_ROWS.labels(model=self.name,
+                                                   rows=rows)
+            for rows in ("given", "held")} if self._grouped_vars else {}
         if bool(self.state_vars) != ("state_slot" in pre_feeds):
             raise ValueError(
                 f"model {self.name!r}: the decode view carries recurrent "
@@ -1093,11 +1117,16 @@ class SlotGenerativeModel:
         the dispatcher right after a step's state came back (the
         variables themselves are donated to the next step, so no other
         thread may read them): four tiny asynchronous copies every
-        ``COUNT_SNAPSHOT_STEPS`` steps, nothing fetched."""
+        ``COUNT_SNAPSHOT_STEPS`` steps, nothing fetched. The prefill
+        views' grouped-rows counters go with them, beside the rows the
+        prefills dispatched so far were given."""
         import jax.numpy as jnp
         self._counts_snapshot = (
             self._decode_steps_done,
             [jnp.copy(self.scope.find_var(n)) for n in self._count_vars])
+        self._grouped_snapshot = (
+            self._grouped_given_done,
+            [jnp.copy(self.scope.find_var(n)) for n in self._grouped_vars])
 
     def expert_token_counts(self, sync: bool = False) -> Dict:
         """``{"steps": decode steps counted, "counts": [expert layers,
@@ -1106,11 +1135,13 @@ class SlotGenerativeModel:
         1: decode steps in which it was given any), as of the last
         snapshot — at most ``COUNT_SNAPSHOT_STEPS`` steps old, or taken
         now with ``sync`` (the dispatcher's own thread only) — and
-        ``paddle_moe_expert_tokens_total`` brought up to them. This is
+        ``paddle_moe_expert_tokens_total`` brought up to them (and
+        ``paddle_moe_grouped_rows_total``, the prefills' own). This is
         what fetches from the device: a scrape or a window's edge calls
         it, from any thread; the step's path never does."""
         if sync:
             self._snapshot_counts()
+        self._count_grouped_rows()
         steps, arrays = self._counts_snapshot
         if not self._count_vars:
             return {"steps": steps, "counts": None}
@@ -1126,6 +1157,37 @@ class SlotGenerativeModel:
                     layer=self._count_vars[layer].rsplit("_", 1)[1],
                     expert=str(expert)).inc(int(d))
         return {"steps": steps, "counts": total + delta}
+
+    def _grouped_counters(self):
+        """The prefill views' grouped-rows counters, zeroed, placed as
+        the decode view's counter of the same layer is. Made HERE,
+        before the first prefill runs, and not by the startup: the
+        engine runs the decode view's, and a small buffer allocated
+        among its pools moves every pool behind it — Trinity's full
+        layer's page gather read 0.74 ms a step slower for four
+        counters of 1 KB filled between its pools (PERF.md, PR 44)."""
+        import jax
+        for name in self._grouped_vars:
+            if self.scope.find_var(name) is None:
+                like = self.scope.find_var(
+                    name.replace("_moe_grouped_", "_moe_counts_"))
+                self.scope.set_var(name, jax.device_put(
+                    np.zeros(like.shape, like.dtype), like.sharding))
+
+    def _count_grouped_rows(self):
+        """``paddle_moe_grouped_rows_total`` brought up to the last
+        snapshot: the rows the prefills' grouped way was given (counted
+        here, per dispatch) and held (counted on the device), both as
+        of the instant the dispatcher took the snapshot."""
+        given, arrays = self._grouped_snapshot
+        if not arrays:
+            return
+        now = np.stack([np.asarray(a)[0] for a in arrays]).astype(np.int64)
+        brought, last = self._grouped_seen
+        # the device counts in int32 and wraps
+        self._m_grouped["held"].inc(int(((now - last) % (1 << 32)).sum()))
+        self._m_grouped["given"].inc(given - brought)
+        self._grouped_seen = (given, now)
 
     # -- plumbing (same dispatch/AOT discipline as GenerativeModel) ------
     _args = GenerativeModel._args
@@ -1331,6 +1393,7 @@ class SlotGenerativeModel:
         loaded = compiled = 0
         if aot_dir:
             loaded += self.load_compiled(aot_dir)
+        self._grouped_counters()
         pk, dk = self.PREFILL, self.DECODE
         for p in self.prompt_buckets:
             if (pk, p) in self._warmed:
@@ -1339,6 +1402,7 @@ class SlotGenerativeModel:
             compiled += 1
             self._run(self._cb_prefill[p], (pk, p),
                       self._prefill_feeds(p))
+            self._grouped_given_done += self._grouped_given[p]
             self._warmed.add((pk, p))
             if aot_dir and persist:
                 self._persist_one(aot_dir, pk, p)
@@ -1481,6 +1545,7 @@ class SlotGenerativeModel:
                                      time.perf_counter(), ctx=pctx)
                 launched = self._run_unfetched(self._cb_prefill[p_len],
                                                key, feeds)
+                self._grouped_given_done += self._grouped_given[p_len]
                 # the scheduler runs ahead (a step is in flight): queue
                 # the step after it behind the prefill BEFORE waiting
                 # for the first token, so the device goes from the

@@ -229,6 +229,17 @@ MOE_EXPERT_TOKENS = _metrics.counter(
     "current as of the last call)",
     labelnames=("model", "layer", "expert"))
 
+MOE_GROUPED_ROWS = _metrics.counter(
+    "paddle_moe_grouped_rows_total",
+    "Assignment rows of the expert layers' grouped way (a prefill of "
+    "more than ops/expert_ffn.py:DENSE_MAX_TOKENS tokens), summed over "
+    "the expert layers and the prefills: rows=given is bucket tokens x "
+    "top_k (the worst case, what every buffer was once sized to), "
+    "rows=held what this program's experts were routed and computed "
+    "(counted on the device; a padded position is routed nowhere). "
+    "Brought here with paddle_moe_expert_tokens_total, both rows as of "
+    "the same snapshot", labelnames=("model", "rows"))
+
 # -- router families (serving/router.py) -------------------------------
 # ``replica`` is the router-assigned slot index ("0".."N-1") — bounded
 # by the pool size, stable across restarts of the replica in that slot.

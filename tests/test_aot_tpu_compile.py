@@ -874,3 +874,85 @@ def test_ssd_prefill_compiles_for_v5e(chip, granite_engine, monkeypatch):
                 if opcode in ("copy", "transpose")
                 and count >= 128 * 128 * 8192]
     assert text.startswith("HloModule jit_lm_prefill_paged_2048_s0b8b,")
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's two ways (PR 44): the dense way's text is the
+# parent's, the grouped way's optimised module holds no buffer of the
+# worst case
+# ---------------------------------------------------------------------------
+
+def _expert_layer(chip, tokens, n_experts, n_held, top_k, m, f, attrs, told):
+    """``expert_ffn_held`` alone, lowered for a described v5e: X
+    [1, tokens, m] over a router ``n_experts`` wide, ``n_held`` experts
+    of width ``f`` and a shared one of 2 f; ``told(struct)`` gives the
+    optional inputs."""
+    from paddle_tpu.ops import expert_ffn
+
+    def s(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    ins = {"X": s((1, tokens, m)), "RouterW": s((m, n_experts)),
+           "WGate": s((n_held, m, f)), "WUp": s((n_held, m, f)),
+           "WDown": s((n_held, f, m)), "SGate": s((m, 2 * f)),
+           "SUp": s((m, 2 * f)), "SDown": s((2 * f, m)), **told(s)}
+
+    def layer(ins):
+        out = expert_ffn._expert_ffn_held(
+            None, {k: [v] for k, v in ins.items()},
+            {"top_k": top_k, **attrs})
+        return {k: v[0] for k, v in out.items()}
+    return jax.jit(layer).lower(ins)
+
+
+# (tokens, router, held, picks, d_model, d_expert, attributes, optional
+# inputs) -> the text's hash as recorded at the parent commit c5e5c19
+DENSE_WAY_AT_THE_PARENT = {
+    "granite_step": (
+        (128, 72, 18, 10, 4096, 768,
+         {"scoring": "softmax_topk", "held_start": 0},
+         lambda s: {"Valid": s((128, 1), I32), "Counts": s((2, 18), I32)}),
+        "5138a4510503f09b"),
+    "solar_prefill_512": (
+        (512, 320, 40, 8, 4096, 1280, {"norm_topk": True, "scaling": 1.0},
+         lambda s: {"SeqLen": s((1, 1), I32)}),
+        "cfc40f81379cddfd"),
+    "glm5_step": (
+        (32, 256, 16, 8, 6144, 2048, {"norm_topk": True, "scaling": 2.5},
+         lambda s: {"Valid": s((32, 1), I32), "Counts": s((2, 16), I32),
+                    "RouterBias": s((1, 256), F32)}),
+        "830f75c81b33a4e1"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DENSE_WAY_AT_THE_PARENT))
+def test_the_dense_way_lowers_as_at_the_parent(chip, case):
+    """Up to ``DENSE_MAX_TOKENS`` tokens the op lowers op for op as
+    before the grouped way changed: a step of Granite's and of GLM-5's
+    view, Solar's largest prefill — the text's hash without source
+    locations, as recorded in the parent's checkout by this function."""
+    from paddle_tpu.ops import expert_ffn
+    args, want = DENSE_WAY_AT_THE_PARENT[case]
+    assert args[0] <= expert_ffn.DENSE_MAX_TOKENS == 512
+    assert _scrubbed_sha(_expert_layer(chip, *args).as_text()) == want
+
+
+def test_the_grouped_way_holds_no_worst_case_buffer_on_v5e(chip):
+    """Granite's 2 048-token prefill, 18 of 72 experts held, 10 picks a
+    token: the optimised module holds the three grouped products over a
+    buffer of the HELD share (6 400 rows: a quarter of the 20 480
+    assignments and a quarter more), inside a loop whose turns the
+    draw's held rows decide, and nothing of the worst case's size — no
+    ``[N*K, M]`` value in float32 or in the storage dtype, no
+    ``[N, K, M]`` value."""
+    from paddle_tpu.ops import expert_ffn
+    assert expert_ffn.grouped_rows(2048, 10, 18, 72) == 6400
+    text = _expert_layer(
+        chip, 2048, 72, 18, 10, 4096, 768,
+        {"scoring": "softmax_topk", "held_start": 0},
+        lambda s: {"SeqLen": s((1, 1), I32)}).compile().as_text()
+    assert text.count("ragged-dot-none") >= 3
+    assert "f32[6400,4096]" in text and "bf16[6400,4096]" in text
+    assert _count_opcode(text, "while") == 1
+    for shape in ("[20480,4096]", "[20480,768]", "[2048,10,4096]",
+                  "[10,2048,4096]"):
+        assert shape not in text, shape

@@ -261,6 +261,107 @@ def test_dropless_under_a_skewed_router(way):
     assert int(sizes[1]) == 32 and int(sizes.sum()) <= 32 * 4
 
 
+def _poisoned(monkeypatch):
+    """What the chip does now and then (PERF.md, PR 37): the grouped
+    product leaves the rows past its groups as it found them — NaN, here
+    always. A combine that WEIGHS such a row, by 0 even, returns NaN."""
+    plain = expert_ffn._grouped
+
+    def grouped(rows, w, sizes):
+        out = plain(rows, w, sizes)
+        past = np.arange(rows.shape[0])[:, None] >= sizes.sum()
+        return jax.numpy.where(past, np.nan, out)
+    monkeypatch.setattr(expert_ffn, "_grouped", grouped)
+
+
+# (router 16 wide, 4 picks a token, 192 tokens: 768 assignments)
+# case -> (experts held, what the router is made to do, padded tail)
+GROUPED_CASES = {
+    "a_quarter_held": (4, None, 0),
+    "every_pick_held": (16, None, 0),
+    "no_pick_held": (4, "elsewhere", 0),
+    "every_token_on_one_held_expert": (4, "one", 0),
+    "a_padded_tail": (4, None, 70),
+    "every_pick_of_every_token_on_a_quarter": (4, "all_four", 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_the_grouped_way_gives_the_dense_ways_sum(case, monkeypatch):
+    """Whatever the draw, the grouped way — buffers sized to the held
+    share (256 rows for 4 of 16 experts), as many turns as the held
+    assignments fill — returns the dense way's sum and the same counts,
+    with the rows no product computed poisoned: a pick held elsewhere, a
+    padded token's, or another turn's contributes a selected zero."""
+    n_held, skew, padded = GROUPED_CASES[case]
+    n, k, n_experts = 192, 4, 16
+    rng = np.random.RandomState(3)
+    w = _expert_weights(rng, n_experts)
+    x = np.abs(rng.randn(n, 64)).astype(np.float32)
+    if skew == "elsewhere":            # x > 0: experts 8-11 win everywhere
+        w["router"][:, 8:12] += 1.0
+    elif skew == "one":                # expert 1 is every token's first
+        w["router"][:, 1] += 1.0
+    elif skew == "all_four":           # the four held are every token's
+        w["router"][:, :4] += 1.0
+    valid = jax.numpy.arange(n) < n - padded if padded else None
+    combine, idx = expert_ffn.route(jax.numpy.asarray(x), w["router"], k,
+                                    True, 1.0)
+
+    def part():
+        return expert_ffn.held_experts_part(
+            jax.numpy.asarray(x), combine, idx, w["w_gate"][:n_held],
+            w["w_up"][:n_held], w["w_down"][:n_held], 0, valid, n_experts)
+    with jax.default_matmul_precision("highest"):
+        dense, dense_sizes = part()
+        monkeypatch.setattr(expert_ffn, "DENSE_MAX_TOKENS", 0)
+        _poisoned(monkeypatch)
+        grouped, sizes = part()
+    rows = expert_ffn.grouped_rows(n, k, n_held, n_experts)
+    held = int(sizes.sum())
+    assert rows == (n * k if n_held == n_experts else 256)
+    assert {"every_pick_held": held == n * k, "no_pick_held": held == 0,
+            "every_token_on_one_held_expert": int(sizes[1]) == n,
+            "every_pick_of_every_token_on_a_quarter":
+                held == n * k and -(-held // rows) == 3}.get(case, held > 0)
+    if padded:
+        assert held < (n - padded) * k and not np.asarray(grouped)[-padded:].any()
+    np.testing.assert_array_equal(np.asarray(sizes), np.asarray(dense_sizes))
+    assert np.isfinite(np.asarray(grouped)).all()
+    np.testing.assert_allclose(
+        np.asarray(grouped), np.asarray(dense),
+        atol=TOL * max(np.abs(np.asarray(dense)).max(), 1e-3))
+
+
+def test_the_grouped_rows_counter_reads_all_held_where_all_are(monkeypatch):
+    """``paddle_moe_grouped_rows_total`` through the engine: a member
+    that holds every expert, prompts that fill their buckets — every row
+    the grouped way was given it held (100 %); with a quarter of the
+    experts held, a share between none and all. Both rows come from ONE
+    snapshot (``expert_token_counts``), the decode steps' counters
+    beside them untouched by the prefills."""
+    from paddle_tpu.serving import metrics as sm
+    monkeypatch.setattr(expert_ffn, "DENSE_MAX_TOKENS", 0)
+    rng = np.random.RandomState(5)
+
+    def rows(engine):
+        engine.expert_token_counts(sync=True)
+        return np.asarray([sm.MOE_GROUPED_ROWS.labels(
+            model=engine.name, rows=r).value for r in ("given", "held")])
+
+    for n_held, full in ((16, True), (4, False)):
+        engine = make_engine(n_experts_held=n_held)
+        assert len(engine._grouped_vars) == BUILD["n_layer"]
+        before = rows(engine)
+        steps0 = engine.expert_token_counts()["counts"].sum()
+        for length in (8, 16, 16):
+            engine.admit(rng.randint(1, 96, length), max_new=2)
+        given, held = rows(engine) - before
+        assert given == (8 + 16 + 16) * 4 * BUILD["n_layer"]
+        assert held == given if full else 0 < held < given
+        assert engine.expert_token_counts()["counts"].sum() == steps0
+
+
 # ------------------------------------------------------------ the engine
 
 def _states(engine, slot):
