@@ -210,9 +210,10 @@ def _zero_seed_cot(seed):
 attention_block.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
 def flash_block(x_q, x_kv, wq, wk, wv, wo, seed, n_head, causal, dropout_p,
-                bq, interpret):
+                bq, interpret, mesh=None, data_axis=None):
     """``attention_block``'s contract with the core — scores, mask,
     softmax, dropout, ``p . V`` and their backward — in the Pallas
     kernels of ``ops/pallas/flash_pairs.py`` (heads of 64, two a lane
@@ -221,9 +222,16 @@ def flash_block(x_q, x_kv, wq, wk, wv, wo, seed, n_head, causal, dropout_p,
     projections and their backward are plain matrix products; no
     ``[B, H, Tq, Tk]`` tensor and no ``[B, H, T, D]`` relayout reaches
     HBM. The backward takes o from its own kernel, not from the
-    forward's (``flash_pairs``' docstring)."""
+    forward's (``flash_pairs``' docstring).
+
+    ``mesh`` / ``data_axis``: under a mesh of several devices, all along
+    ``data_axis``, which divides the batch, the two kernels — and
+    nothing else — run inside a ``shard_map`` over it (``_on_shards``):
+    XLA cannot partition a Mosaic call, and partitions the projections
+    and their gradients around the manual region as it does for the
+    composed block."""
     return _flash_fwd(x_q, x_kv, wq, wk, wv, wo, seed, n_head, causal,
-                      dropout_p, bq, interpret)[0]
+                      dropout_p, bq, interpret, mesh, data_axis)[0]
 
 
 def _mm(a, b, dims):
@@ -232,23 +240,57 @@ def _mm(a, b, dims):
                                ).astype(a.dtype)
 
 
+def _on_shards(kernel, mesh, data_axis, n_head):
+    """``kernel(seed, *rows)`` — a ``flash_pairs`` call closed over its
+    static arguments, every array ``[B, T, M]`` in and out — on each
+    device's rows of the batch. The kernels' programs are batch-parallel,
+    so nothing crosses the axis. The dropout bits stay the GLOBAL row's:
+    a kernel hashes ``seed + (b * H + h) * 0x27D4EB2F`` in uint32
+    (``flash_attention._seed_mix``) with ``b`` its program id, the local
+    row, so a shard whose first row is ``b0`` is handed
+    ``seed + b0 * H * 0x27D4EB2F`` and draws what the unmapped call draws
+    on the whole batch (and the composed block under GSPMD). The results
+    of a ``pallas_call`` carry no replication annotation: ``check_vma``
+    is off, as in ``ring_attention.sp_attention``."""
+    if mesh is None or mesh.size == 1:
+        return kernel
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.ops.pallas.flash_attention import _seed_mix
+
+    def shard(seed, rows):
+        b0 = jax.lax.axis_index(data_axis) * rows[0].shape[0]
+        seed = jax.lax.bitcast_convert_type(
+            _seed_mix(seed, b0 * n_head), jnp.int32)
+        return kernel(seed, *rows)
+
+    spec = P(data_axis, None, None)     # a prefix: every array in and out
+    mapped = jax.shard_map(shard, mesh=mesh, in_specs=(P(None), spec),
+                           out_specs=spec, check_vma=False)
+    return lambda seed, *rows: mapped(seed, rows)
+
+
 def _flash_fwd(x_q, x_kv, wq, wk, wv, wo, seed, n_head, causal, dropout_p,
-               bq, interpret):
+               bq, interpret, mesh, data_axis):
     from paddle_tpu.ops.pallas.flash_pairs import pairs_forward
     q, k, v = (_mm(x, w, ((2,), (0,)))
                for x, w in ((x_q, wq), (x_kv, wk), (x_kv, wv)))
-    o = pairs_forward(q, k, v, seed, n_head, causal, dropout_p, bq,
-                      interpret)
+    o = _on_shards(
+        lambda seed, q, k, v: pairs_forward(
+            q, k, v, seed, n_head, causal, dropout_p, bq, interpret),
+        mesh, data_axis, n_head)(seed, q, k, v)
     return _mm(o, wo, ((2,), (0,))), (x_q, x_kv, wq, wk, wv, wo, seed,
                                       q, k, v)
 
 
-def _flash_bwd(n_head, causal, dropout_p, bq, interpret, res, dout):
+def _flash_bwd(n_head, causal, dropout_p, bq, interpret, mesh, data_axis,
+               res, dout):
     from paddle_tpu.ops.pallas.flash_pairs import pairs_backward
     x_q, x_kv, wq, wk, wv, wo, seed, q, k, v = res
     do = _mm(dout, wo, ((2,), (1,)))
-    dq, dk, dv, o = pairs_backward(q, k, v, do, seed, n_head, causal,
-                                   dropout_p, bq, interpret)
+    dq, dk, dv, o = _on_shards(
+        lambda seed, q, k, v, do: pairs_backward(
+            q, k, v, do, seed, n_head, causal, dropout_p, bq, interpret),
+        mesh, data_axis, n_head)(seed, q, k, v, do)
 
     def dx(g, w):                       # [B,T,M] . w^T
         return _mm(g, w, ((2,), (1,)))

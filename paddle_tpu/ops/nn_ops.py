@@ -781,20 +781,30 @@ _ATTENTION_BLOCK_LOWERED = _metrics.counter(
     labelnames=("path", "d_head"))
 
 
-def _attention_kernel_blocks(t_q, t_k, m, n_head, causal, mesh):
+def _attention_kernel_blocks(t_q, t_k, m, n_head, causal, mesh, data_axis,
+                             batch):
     """(bq, bk) when a flash kernel runs the block's core, else None.
-    What the kernels need decides: a chip and no mesh of several devices
-    (or, for the tests, ``pallas.forced_interpret``); heads of whole
-    lane tiles for ``flash_attention``, heads of 64 in whole pairs with
-    Tq == Tk for ``flash_pairs``; a shape for which
-    ``flash_engage`` names blocks that divide it."""
+    What the kernels need decides: a chip (or, for the tests,
+    ``pallas.forced_interpret``); heads of whole lane tiles for
+    ``flash_attention``, heads of 64 in whole pairs with Tq == Tk for
+    ``flash_pairs``; a shape for which ``flash_engage`` names blocks
+    that divide it. Under a mesh of several devices only ``flash_pairs``
+    runs, mapped over the data axis (``attention_block.flash_block``),
+    and only where that axis is the whole mesh — every other axis of
+    size 1 — and divides the batch: a model axis shards M, which the
+    kernels' pairs of heads do not follow."""
     from paddle_tpu.ops import pallas as pk
     d = m // n_head
     if d == pk.flash_pairs.D_HEAD:
+        if (mesh is not None and mesh.size > 1
+                and not (data_axis in mesh.axis_names
+                         and mesh.shape[data_axis] == mesh.size
+                         and batch % mesh.size == 0)):
+            return None
         eng = pk.flash_engage(t_q, t_k, d, causal)
+        # no mesh to refuse: a mapped call sees one chip's rows
         if (eng and pk.flash_pairs.supported(t_q, t_k, m, n_head, *eng)
-                and (pk.kernel_enabled(mesh=mesh)
-                     or pk.forced_interpret())):
+                and (pk.kernel_enabled() or pk.forced_interpret())):
             return eng
         return None
     if pk.kernel_enabled(128, d, mesh=mesh):
@@ -869,19 +879,26 @@ def _fused_attention_block(ctx, ins, attrs):
     # not the published model); the PUBLISHED 16 heads of 64 ->
     # flash_pairs, 54.0k -> 76.9k tok/s (2026-09-30, PR 41; flash_attention
     # behind a [B,H,T,D] relayout read 55.8k and was not kept).
-    # Below T=512 and under a mesh of several devices the composed
-    # block's relayout-free dots keep the row.
+    # Under a mesh of several devices XLA cannot partition a Mosaic
+    # call: flash_pairs runs mapped over the data axis when that axis is
+    # the whole mesh and divides the batch (train_big_dp4, PR 45; the
+    # masks and the all-reduces are the composed block's), every other
+    # mesh keeps the composed block, which XLA partitions. Below T=512
+    # the composed block's relayout-free dots keep the row.
     h = n_head
     m = x_q.shape[-1]
     d = m // h
     from paddle_tpu.ops import pallas as pk
-    eng = _attention_kernel_blocks(t_q, t_k, m, h, causal, mesh)
+    data_axis = getattr(ctx.dist, "data_axis", None)
+    eng = _attention_kernel_blocks(t_q, t_k, m, h, causal, mesh, data_axis,
+                                   x_q.shape[0])
     if ctx.op is not None and ctx.step_base_key is not None:
         _ATTENTION_BLOCK_LOWERED.labels(
             path="flash" if eng else "composed", d_head=str(d)).inc()
     if eng and d == pk.flash_pairs.D_HEAD:
         out = ab.flash_block(x_q, x_kv, wq, wk, wv, wo, seed, h, causal,
-                             dropout_p, eng[0], pk.interpret_mode())
+                             dropout_p, eng[0], pk.interpret_mode(),
+                             mesh, data_axis)
         return single(_amp_out(out, attrs) if amp else out)
     if eng:
         bq, bk = eng

@@ -59,8 +59,11 @@ def kernel_enabled(min_align: int = 128, *dims, mesh=None) -> bool:
     automatically partitioned. Please wrap the call in a shard_map"), so
     under a mesh of more than one device an emitter's kernel is off and
     the refer tier — which XLA does partition — runs. Kernels already
-    inside a shard_map region (parallel/ring_attention.py) see per-shard
-    arrays and pass no mesh."""
+    inside a shard_map region see per-shard arrays and pass no mesh:
+    parallel/ring_attention.py's flash shards, and the ONE emitter that
+    maps its kernel itself — ``fused_attention_block`` at heads of 64
+    (``nn_ops._attention_kernel_blocks``: ``flash_pairs`` over a mesh
+    that is the data axis alone, ``attention_block.flash_block``)."""
     if kernels_disabled():
         return False
     if not on_tpu():

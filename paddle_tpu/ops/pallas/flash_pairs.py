@@ -29,7 +29,13 @@ and XLA drops it. ``p`` never reaches HBM.
 
 Dropout is ``flash_attention.hash_keep_mask``'s bits at
 ``(seed, b * H + h, qpos, kpos)`` with ``h = 2 * pair + half``; the
-upscale ``1 / (1 - p)`` multiplies float32 results, not probabilities."""
+upscale ``1 / (1 - p)`` multiplies float32 results, not probabilities.
+``b`` is the program id: the row of the batch the call was GIVEN. Mapped
+over a data-parallel mesh (``attention_block._on_shards``) a call sees a
+shard's rows, and its caller hands it the seed advanced by the shard's
+first global row — the seed and ``b * H + h`` enter the hash as one
+uint32 sum — so ``b`` there is the GLOBAL row and the bits are the whole
+batch's; these kernels do not know."""
 
 from __future__ import annotations
 

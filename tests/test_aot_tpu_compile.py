@@ -199,12 +199,14 @@ def test_kernel_compiles_for_v5e(chip, case):
         assert _count_opcode(text, "custom-call") == 2
 
 
-def _attention_step_text(n_head, dist, chip, monkeypatch, compiled=True):
+def _attention_step_text(n_head, dist, chip, monkeypatch, compiled=True,
+                         train=False):
     """The text of a one-block step (T = 512, d_model 256, mean of a
-    causal ``fused_multi_head_attention``) lowered for a described v5e:
-    on ``chip`` alone, or under ``dist``'s mesh. ``on_tpu`` is steered
-    because trace-time gates ask ``jax.default_backend()``, which is the
-    CPU in this process."""
+    causal ``fused_multi_head_attention``; with ``train`` its backward
+    and an SGD update too) lowered for a described v5e: on ``chip``
+    alone, or under ``dist``'s mesh. ``on_tpu`` is steered because
+    trace-time gates ask ``jax.default_backend()``, which is the CPU in
+    this process."""
     import numpy as np
     import paddle_tpu.fluid as fluid
     from paddle_tpu.core.lowering import CompiledBlock
@@ -215,6 +217,8 @@ def _attention_step_text(n_head, dist, chip, monkeypatch, compiled=True):
         x = fluid.layers.data(name="x", shape=[512, 256], dtype="float32")
         loss = fluid.layers.mean(fluid.layers.fused_multi_head_attention(
             x, x, d_model=256, n_head=n_head, causal=True))
+        if train:
+            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
     scope = fluid.Scope()
     fluid.Executor(fluid.TPUPlace()).run(startup, scope=scope)
     monkeypatch.setattr(pk, "on_tpu", lambda: True)
@@ -237,10 +241,15 @@ def _attention_step_text(n_head, dist, chip, monkeypatch, compiled=True):
 def test_mesh_step_compiles_with_kernels_gated(v5e, chip, monkeypatch,
                                                d_head):
     """XLA refuses to partition a Mosaic call ("wrap the call in a
-    shard_map"): a step lowered under a mesh must take the composed
-    block where a lone chip takes a flash kernel (T = 512 engages it at
-    heads of 128 and, two a lane tile, of 64), and the counter says
-    which."""
+    shard_map"). At heads of 128 a step lowered under a mesh therefore
+    takes the composed block where a lone chip takes ``flash_attention``
+    (T = 512 engages it). At heads of 64 the emitter does wrap the pair
+    kernels (PR 45): under a ``dp`` mesh the compiled step holds exactly
+    two Mosaic calls a block — forward and backward; the ``__vjp__``
+    op's re-traced forward is dropped inside the manual region as it is
+    off a mesh — beside the gradients' all-reduce, both under the op's
+    scope (the backward's under ``grad/``). The counter says which in
+    both."""
     import numpy as np
     from jax.sharding import Mesh
     from paddle_tpu.ops import nn_ops
@@ -250,18 +259,28 @@ def test_mesh_step_compiles_with_kernels_gated(v5e, chip, monkeypatch,
         return nn_ops._ATTENTION_BLOCK_LOWERED.labels(
             path=path, d_head=str(d_head)).value
 
+    def calls(text):
+        return text.count('custom_call_target="tpu_custom_call"')
+
+    mapped = d_head == 64
     was = lowered("flash"), lowered("composed")
-    text = _attention_step_text(256 // d_head, None, chip, monkeypatch)
-    assert "tpu_custom_call" in text
+    text = _attention_step_text(256 // d_head, None, chip, monkeypatch,
+                                train=mapped)
+    assert calls(text) == (2 if mapped else 1)
     assert (lowered("flash"), lowered("composed")) == (was[0] + 1, was[1])
     mesh = Mesh(np.asarray(v5e), ("dp",))
     text = _attention_step_text(
         256 // d_head, DistributeConfig(mesh=mesh, data_axis="dp"), chip,
-        monkeypatch)
-    assert "tpu_custom_call" not in text
-    assert "all-reduce" in text          # the mean over the dp-split batch
-    assert (lowered("flash"), lowered("composed")) == (was[0] + 1,
-                                                       was[1] + 1)
+        monkeypatch, train=mapped)
+    assert calls(text) == (2 if mapped else 0)
+    assert "all-reduce" in text     # the mean (and dW) over the dp split
+    assert (lowered("flash"), lowered("composed")) == (
+        was[0] + 1 + mapped, was[1] + 1 - mapped)
+    # a manual region must not lose the scope the trace's readers go by
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]*)"', text)
+    assert all("fused_attention_block" in n for n in names), names
+    assert sum("grad/" in n for n in names) == mapped, names
 
 
 def _scrubbed_sha(text):
@@ -275,6 +294,20 @@ def _scrubbed_sha(text):
     text = re.sub(r" at [^\s]+:\d+", "", text)
     text = re.sub(r"/[\w/.\-]+\.py(:\d+)?", "", text)
     return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("train,want", [(False, "1a1e6cc5ff39e3c8"),
+                                        (True, "9ace96be8a2d6254")])
+def test_one_chip_step_at_heads_of_64_lowers_as_before_the_wrap(
+        chip, monkeypatch, train, want):
+    """PR 45 maps the pair kernels over a dp mesh; off a mesh the block
+    is the parent's op for op: the step's StableHLO around the kernels
+    (forward alone, and with backward and update), held as recorded at
+    the parent commit."""
+    step = _attention_step_text(4, None, chip, monkeypatch, compiled=False,
+                                train=train)
+    assert step.count("tpu_custom_call") == 1 + train
+    assert _scrubbed_sha(step) == want
 
 
 def test_head_size_128_lowers_as_before_the_pair_kernels(chip, monkeypatch):

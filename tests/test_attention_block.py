@@ -232,16 +232,99 @@ def test_flash_block_matches_composed_block(causal, cross, dropout_p):
                                    err_msg=f"grad arg {i}")
 
 
+def _dist(shape):
+    from paddle_tpu.parallel.mesh import DistributeConfig, make_mesh
+    n = int(np.prod(list(shape.values())))
+    return DistributeConfig(mesh=make_mesh(shape, jax.devices()[:n]),
+                            data_axis="dp", model_axis="tp")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dropout_p", [0.0, 0.3])
+def test_pair_kernels_on_shards_are_the_whole_batch_bit_for_bit(
+        causal, dropout_p):
+    """The two Mosaic calls mapped over a dp mesh of 4 (one row a
+    device) give what they give on the whole batch, every bit: the
+    shard's seed carries its first GLOBAL row, so the keep masks are the
+    unmapped call's (and the composed block's under GSPMD), not four
+    copies of row 0's."""
+    from paddle_tpu.ops.attention_block import _on_shards
+    from paddle_tpu.ops.pallas.flash_pairs import (pairs_backward,
+                                                   pairs_forward)
+    h, t = 2, 256
+    q, k, v, do = (jnp.asarray(_rand((4, t, 64 * h), 80 + i))
+                   for i in range(4))
+    seed = jnp.asarray([2 ** 31 - 5], jnp.int32)    # the mix wraps
+
+    def fwd(seed, q, k, v):
+        return pairs_forward(q, k, v, seed, h, causal, dropout_p, 128, True)
+
+    def bwd(seed, q, k, v, do):
+        return pairs_backward(q, k, v, do, seed, h, causal, dropout_p, 128,
+                              True)
+
+    for kernel, args in ((fwd, (q, k, v)), (bwd, (q, k, v, do))):
+        want = jax.tree_util.tree_leaves(kernel(seed, *args))
+        got = jax.tree_util.tree_leaves(jax.jit(_on_shards(
+            kernel, _dist({"dp": 4}).mesh, "dp", h))(seed, *args))
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if dropout_p > 0:       # and the rows do differ: no mask is shared
+        o = np.asarray(fwd(seed, q[:1].repeat(4, 0), k[:1].repeat(4, 0),
+                           v[:1].repeat(4, 0)))
+        assert not np.array_equal(o[0], o[1])
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dropout_p", [0.0, 0.3])
+def test_mapped_flash_block_equals_unmapped(causal, dropout_p):
+    """``flash_block`` under a ("dp",) mesh of 4 against ``flash_block``
+    on the whole batch: the output and both dx — made row by row — bit
+    for bit, dropout or not; the four dW are sums over (b, t) that the
+    mesh makes as four partial sums and an all-reduce, so they agree to
+    float32 rounding (a mask drawn from the local row would be off by
+    the whole gradient)."""
+    from paddle_tpu.ops.attention_block import flash_block
+    x_q, x_kv, ws, h = _pair_inputs(b=4, cross=True)
+    seed = jnp.asarray([4321], jnp.int32)
+    tangent = jnp.asarray(_rand(x_q.shape, 60))
+
+    def run(mesh, axis):
+        def f(x_q, x_kv, *ws):
+            out = flash_block(x_q, x_kv, *ws, seed, h, causal, dropout_p,
+                              128, True, mesh, axis)
+            return jnp.sum(tangent * out), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            f, argnums=tuple(range(6)), has_aux=True))(x_q, x_kv, *ws)
+        return out, grads
+
+    want, g_want = run(None, None)
+    got, g_got = run(_dist({"dp": 4}).mesh, "dp")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for i in (0, 1):
+        np.testing.assert_array_equal(np.asarray(g_got[i]),
+                                      np.asarray(g_want[i]),
+                                      err_msg=f"grad arg {i}")
+    for i in range(2, 6):
+        np.testing.assert_allclose(np.asarray(g_got[i]),
+                                   np.asarray(g_want[i]),
+                                   rtol=1e-4, atol=1e-4,
+                                   err_msg=f"grad arg {i}")
+
+
 def _lowered(path, d_head):
     from paddle_tpu.ops import nn_ops
     return nn_ops._ATTENTION_BLOCK_LOWERED.labels(
         path=path, d_head=str(d_head)).value
 
 
-def _run_block_op(monkeypatch, t_q, t_k, d_model, n_head, forced=True):
+def _run_block_op(monkeypatch, t_q, t_k, d_model, n_head, forced=True,
+                  dist=None, batch=1):
     """One ``fused_attention_block`` through the executor on the CPU,
-    kernels on the interpreter where the gate lets them; returns the
-    output and how much each path of the counter grew."""
+    kernels on the interpreter where the gate lets them, under
+    ``dist``'s mesh if given; returns the output and how much each path
+    of the counter grew."""
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1" if forced else "0")
     d_head = d_model // n_head
     main, startup = fluid.Program(), fluid.Program()
@@ -255,8 +338,10 @@ def _run_block_op(monkeypatch, t_q, t_k, d_model, n_head, forced=True):
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup)
     was = _lowered("flash", d_head), _lowered("composed", d_head)
-    got = exe.run(main, feed={"xq": _rand((1, t_q, d_model), 70),
-                              "xkv": _rand((1, t_k, d_model), 71)},
+    prog = main if dist is None else \
+        fluid.CompiledProgram(main).with_sharding(dist)
+    got = exe.run(prog, feed={"xq": _rand((batch, t_q, d_model), 70),
+                              "xkv": _rand((batch, t_k, d_model), 71)},
                   fetch_list=[out])[0]
     return got, (_lowered("flash", d_head) - was[0],
                  _lowered("composed", d_head) - was[1])
@@ -284,6 +369,46 @@ def test_op_refuses_odd_shapes_and_says_composed(monkeypatch, t_q, t_k,
                                                  d_model, n_head, why):
     _, grew = _run_block_op(monkeypatch, t_q, t_k, d_model, n_head)
     assert grew == (0, 1), why
+
+
+def test_op_maps_the_pair_kernels_over_a_dp_mesh(monkeypatch):
+    """Under a mesh that is the data axis alone the emitter keeps the
+    kernels, mapped over the axis, and counts ``flash``; the result is
+    the unsharded program's."""
+    got, grew = _run_block_op(monkeypatch, 512, 512, 128, 2,
+                              dist=_dist({"dp": 4}), batch=4)
+    assert grew == (1, 0)
+    want, grew = _run_block_op(monkeypatch, 512, 512, 128, 2, batch=4)
+    assert grew == (1, 0)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,why", [
+    ({"dp": 2, "tp": 2}, "a second axis of size 2 shards M"),
+    ({"tp": 4}, "no data axis in the mesh"),
+])
+def test_op_under_other_meshes_says_composed(monkeypatch, shape, why):
+    _, grew = _run_block_op(monkeypatch, 512, 512, 128, 2,
+                            dist=_dist(shape), batch=4)
+    assert grew == (0, 1), why
+
+
+def test_rule_refuses_a_batch_the_data_axis_does_not_divide(monkeypatch):
+    """The executor pads a feed to the data axis, so the rule is asked
+    directly: 6 rows over dp = 4 stay composed, 8 are mapped; a mesh of
+    one device is no mesh."""
+    from paddle_tpu.ops import nn_ops
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    mesh = _dist({"dp": 4}).mesh
+
+    def blocks(mesh, batch):
+        return nn_ops._attention_kernel_blocks(512, 512, 128, 2, True, mesh,
+                                               "dp", batch)
+    want = blocks(None, 6)
+    assert want is not None
+    assert blocks(mesh, 6) is None
+    assert blocks(mesh, 8) == want
+    assert blocks(_dist({"dp": 1}).mesh, 6) == want
 
 
 def test_counter_is_in_the_exporter_catalog():
