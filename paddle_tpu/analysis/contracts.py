@@ -1,8 +1,8 @@
 """Cross-view program contracts: one scope, many executables, one truth.
 
-The decoder_lm serving family emits 6+ program views (full, prefill@P,
-decode, prefill_paged@P, decode_paged, decode_verify_paged) that all
-dispatch against ONE scope — the weights, KV caches and page pools are
+The decoder_lm serving family emits several program views (full,
+prefill_paged@P, decode_paged, decode_verify_paged) that all
+dispatch against ONE scope — the weights and page pools are
 shared state. Nothing in the per-program verifier can see the
 hazards that live BETWEEN views: a persistable whose shape/dtype drifts
 across builders, a startup whose rng-salted initializers slid to
@@ -54,8 +54,8 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 from paddle_tpu.analysis.diagnostics import Diagnostic, Severity
 from paddle_tpu.analysis.rules import register_rule
 
-DECODER_LM_MODES = ("full", "prefill", "decode", "prefill_paged",
-                    "decode_paged", "decode_verify_paged")
+DECODER_LM_MODES = ("full", "prefill_paged", "decode_paged",
+                    "decode_verify_paged")
 
 _KV_CODECS = ("none", "bf16", "int8")
 _STORE_DTYPES = {"none": "float32", "bf16": "bfloat16", "int8": "int8"}
@@ -134,8 +134,12 @@ def validate_geometry(mode: str, prompt_len: int, max_new: int,
     slot at full length) and kv_codec (FLAGS_kv_cache_codec)."""
     _count("geometry")
     if mode not in DECODER_LM_MODES:
+        gone = (" — the contiguous-cache views 'prefill' and 'decode' "
+                "were removed at PR 46: the slot views prefill_paged and "
+                "decode_paged serve (transformer.slot_modes()), 'full' is "
+                "the greedy oracle") if mode in ("prefill", "decode") else ""
         raise ValueError(f"decoder_lm mode {mode!r} not in "
-                         f"{DECODER_LM_MODES}")
+                         f"{DECODER_LM_MODES}{gone}")
     if mode.endswith("_paged") and not n_slots:
         raise ValueError(f"mode {mode!r} needs n_slots")
     prompt_len = int(prompt_len)
@@ -218,8 +222,8 @@ class FamilyContext:
         seen_ids = set()
         for key, (main, startup, feed_specs, fetch_name) in \
                 family.items():
-            if id(main) in seen_ids:       # bucket aliases ("prefill" ->
-                continue                   # "prefill@P_max")
+            if id(main) in seen_ids:       # bucket aliases ("prefill_paged"
+                continue                   # -> "prefill_paged@P_max")
             seen_ids.add(id(main))
             desc = main.desc if hasattr(main, "desc") else main
             sdesc = (startup.desc if hasattr(startup, "desc")

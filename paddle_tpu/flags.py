@@ -221,9 +221,8 @@ define("grad_allreduce_codec", str, "none",
 define("kv_cache_codec", str, "none",
        "Storage codec for the slot server's paged KV pool "
        "(serving/engine.py, serving/kv_pool.py; docs/serving.md 'Paged "
-       "KV cache'): 'none' stores fp32 (decode bit-exact against the "
-       "wave op kv_attention_decode over the same rows), 'bf16' "
-       "truncates to 2 bytes/elem, 'int8' stores int8 codes + one fp32 "
+       "KV cache'): 'none' stores fp32 (rows read back exactly as "
+       "written), 'bf16' truncates to 2 bytes/elem, 'int8' stores int8 codes + one fp32 "
        "scale per (position, head) row — the per-row-scale discipline "
        "of FLAGS_embed_exchange_codec applied at rest. Quantize on "
        "page write, dequantize in the attention gather.")

@@ -12,7 +12,6 @@ from paddle_tpu.fluid.layers.nn import (  # noqa: F401
     clip, conv2d, conv2d_transpose,
     cos_sim, crf_decoding, cross_entropy, dropout, embedding, expand, fc,
     fused_linear_cross_entropy, fused_multi_head_attention,
-    kv_attention_prefill, kv_attention_decode,
     kv_attention_prefill_paged, kv_attention_decode_paged,
     kv_attention_verify_paged, rms_norm, dense, kda, ssd, expert_ffn_held,
     swiglu_ffn, mla,

@@ -1014,57 +1014,6 @@ def mla(x, page_c, page_i, d_model, sizes, base, init, rope_theta,
     return out
 
 
-def kv_attention_prefill(x, d_model, n_head, cache_k, cache_v,
-                         param_attr=None, name=None):
-    """Causal self-attention over the whole (padded) prompt that ALSO
-    populates the serving KV cache: ``cache_k``/``cache_v`` are
-    persistable [B, S, H, D] vars this op writes (S from the var shape;
-    CompiledBlock carries them into the serving scope, where the decode
-    program reads them). x [B, T, M] -> [B, T, M]. Numerics identical to
-    fused_multi_head_attention(causal=True) without dropout — a
-    prefill+decode transcript matches the full-forward graph
-    (ops/kv_attention.py; docs/serving.md)."""
-    helper = LayerHelper("kv_attention_prefill", name=name)
-    ws = _attention_projection_params(helper, d_model, param_attr)
-    out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op("kv_attention_prefill",
-                     inputs={"X": [x], "Wq": [ws[0]], "Wk": [ws[1]],
-                             "Wv": [ws[2]], "Wo": [ws[3]]},
-                     outputs={"Out": [out], "CacheK": [cache_k],
-                              "CacheV": [cache_v]},
-                     attrs={"n_head": int(n_head),
-                            "cache_len": int(cache_k.shape[1])})
-    return out
-
-
-def kv_attention_decode(x, pos, seq_len, gen_start, active, d_model,
-                        n_head, cache_k, cache_v, param_attr=None,
-                        name=None):
-    """One-token decode step over the static-shape KV cache with fully
-    per-row geometry: writes each active row's k/v at its own ``pos``
-    (in-place — the caches are read and written under the same names, so
-    they are donated state) and attends over the per-row mask
-    {j < seq_len} ∪ {gen_start <= j <= pos}; rows with ``active`` == 0
-    (free decode slots) leave their cache row untouched. x [B, 1, M],
-    pos/seq_len/gen_start/active [B, 1] int -> [B, 1, M]. The same
-    executable serves every decode position and every join/leave mix —
-    zero steady-state compiles (ops/kv_attention.py; docs/serving.md)."""
-    helper = LayerHelper("kv_attention_decode", name=name)
-    ws = _attention_projection_params(helper, d_model, param_attr)
-    out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op("kv_attention_decode",
-                     inputs={"X": [x], "Wq": [ws[0]], "Wk": [ws[1]],
-                             "Wv": [ws[2]], "Wo": [ws[3]],
-                             "CacheK": [cache_k], "CacheV": [cache_v],
-                             "Pos": [pos], "SeqLen": [seq_len],
-                             "GenStart": [gen_start],
-                             "Active": [active]},
-                     outputs={"Out": [out], "CacheKOut": [cache_k],
-                              "CacheVOut": [cache_v]},
-                     attrs={"n_head": int(n_head)})
-    return out
-
-
 def kv_attention_prefill_paged(x, rows, d_model, n_head, page_k, page_v,
                                page_ks=None, page_vs=None, codec="none",
                                param_attr=None, name=None, gqa=None):
@@ -1105,8 +1054,11 @@ def kv_attention_decode_paged(x, page_table, pos, seq_len, gen_start,
                               active, d_model, n_head, page_k, page_v,
                               page_ks=None, page_vs=None, codec="none",
                               param_attr=None, name=None, gqa=None):
-    """One-token decode over the PAGED KV pool: per-row geometry
-    identical to ``kv_attention_decode``, but the cache row for logical
+    """One-token decode over the PAGED KV pool with fully per-row
+    geometry (``pos`` each row's cache write index, ``gen_start`` where
+    its generated region begins, ``seq_len`` its true prompt length,
+    ``active`` == 0 a free slot that flows through untouched): the
+    cache row for logical
     position j of slot b resolves through the page-table feed
     (``page_table`` [B, max_pages] int — a STATIC-shape feed, so every
     join/leave/page mix dispatches the same executable, zero

@@ -280,8 +280,8 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
         raise ValueError(
             f"decoder_lm mode {mode!r} with layer_kinds: the hybrid block "
             f"is served by the slot views prefill_paged and decode_paged "
-            f"alone (no wave cache; a verify window would have to roll a "
-            f"recurrent state back)")
+            f"alone (a verify window would have to roll a recurrent state "
+            f"back)")
     if hy["attn_scale"] is not None and "swa" in period:
         raise ValueError("attn_scale is read by 'gqa' layers alone: a "
                          "window layer scales by head_dim ** -0.5")
@@ -496,16 +496,6 @@ class _HybridNormal(fluid.initializer.Initializer):
 #   "full"         — logits over the whole sequence via causal fused
 #                    attention: the full-forward-per-token baseline (and
 #                    the parity oracle).
-#   "prefill"      — same causal forward over a prompt bucket, PLUS the
-#                    layers.kv_attention_prefill cache side effect:
-#                    per-layer persistable [B, S, H, D] K/V caches land
-#                    in the scope. With a prompt bucket LADDER one
-#                    prefill view exists per bucket length (all writing
-#                    the same cache_len caches), so mixed-length traffic
-#                    doesn't pay worst-case prefill.
-#   "decode"       — ONE token per call with per-row geometry
-#                    (pos/seq_len/gen_start/active), O(1) per token
-#                    instead of a fresh full forward.
 #   "prefill_paged" — the in-flight-batching prefill: ONE request
 #                    (batch 1) whose K/V rows are scattered into the
 #                    per-layer [n_pages, page_size, H*D] page pools
@@ -537,21 +527,19 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                n_head: int = 2, n_layer: int = 2, name: str = "lm",
                cache_len=None, n_slots=None, page_size=None,
                n_pages=None, kv_codec=None, spec_k=None, **arch):
-    """Emit the `mode` view ("full" | "prefill" | "decode" |
-    "prefill_paged" | "decode_paged" | "decode_verify_paged")
-    of the decoder-only LM into the current default programs.
-    ``cache_len`` decouples the cache size from this view's prompt
-    bucket (ladder prefills at P < P_max still write full-size caches);
-    the paged modes need ``n_slots``. The paged views (ISSUE 17) keep
-    K/V in [n_pages, page_size, H*D] page pools behind a per-slot
-    page-table feed — ``page_size`` must divide cache_len (the decode
-    gather then covers exactly cache_len logical rows: fp32 paged
-    decode is bit-identical to the wave op ``kv_attention_decode``);
-    ``n_pages`` defaults to every slot at full length
+    """Emit the `mode` view ("full" | "prefill_paged" | "decode_paged"
+    | "decode_verify_paged") of the decoder-only LM into the current
+    default programs. ``cache_len`` decouples the cache size from this
+    view's prompt bucket (ladder prefills at P < P_max address the same
+    full-length slots); the paged modes need ``n_slots``. The paged
+    views (ISSUE 17) keep K/V in [n_pages, page_size, H*D] page pools
+    behind a per-slot page-table feed — ``page_size`` must divide
+    cache_len (the decode gather then covers exactly cache_len logical
+    rows); ``n_pages`` defaults to every slot at full length
     (n_slots * cache_len / page_size); ``kv_codec`` defaults to
     FLAGS_kv_cache_codec ('none' | 'bf16' | 'int8' storage). Returns
-    (output_var, feed_specs) — logits for full/prefill/decode, the
-    on-device-sampled next token for the paged views.
+    (output_var, feed_specs) — logits for full, the on-device-sampled
+    next token for the paged views.
 
     The verify views (ISSUE 19) take ``spec_k`` (default 4): K drafted
     tokens per step, scored together with the last committed token as a
@@ -633,20 +621,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     page_rows = page_table = state_slot = position = None
     page_rows_w = page_table_w = None
     pos = gen_start = active = sample_step = None
-    if mode == "decode":
-        tok = layers.data(name="tok", shape=[1, 1], dtype="int64")
-        pos = layers.data(name="pos", shape=[1], dtype="int64")
-        seq_len = layers.data(name="seq_len", shape=[1], dtype="int64")
-        gen_start = layers.data(name="gen_start", shape=[1],
-                                dtype="int64")
-        active = layers.data(name="active", shape=[1], dtype="int64")
-        feed_specs = {"tok": ([-1, 1, 1], "int64"),
-                      "pos": ([-1, 1], "int64"),
-                      "seq_len": ([-1, 1], "int64"),
-                      "gen_start": ([-1, 1], "int64"),
-                      "active": ([-1, 1], "int64")}
-        x_ids, t = tok, 1
-    elif mode == "decode_paged":
+    if mode == "decode_paged":
         S = int(n_slots)
         tok = sdata("tok", [S, 1, 1])
         pos = sdata("pos", [S, 1])
@@ -743,7 +718,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             feed_specs["state_slot"] = ([1, 1], "int64")
         x_ids = ids
     else:
-        t = prompt_len if mode == "prefill" else cache_len
+        t = cache_len
         ids = layers.data(name="ids", shape=[t, 1], dtype="int64")
         feed_specs = {"ids": ([-1, t, 1], "int64")}
         x_ids = ids
@@ -773,10 +748,10 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     emb = layers.embedding(x_ids, size=[vocab, d_model],
                            param_attr=pa("emb"))
     x = layers.scale(emb, scale=d_model ** 0.5)
-    if mode in ("decode", "decode_paged"):
+    if mode == "decode_paged":
         # semantic position of this token for row b is
         # seq_len[b] + generated-so-far = seq_len + (pos - gen_start)
-        # (prompts are right-padded to their bucket; the cache SLOT is
+        # (prompts are right-padded to their bucket; the cache ROW is
         # storage only, the mask orders attention)
         gen = layers.elementwise_sub(pos, gen_start)
         pos_ids = layers.elementwise_add(seq_len, gen)
@@ -809,7 +784,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             attn = layers.fused_multi_head_attention(
                 attn_in, attn_in, d_model, n_head, causal=True,
                 param_attr=attn_pa(i))
-        elif mode.endswith("_paged"):
+        else:
             # the whole model width on the minor dimension: row-major
             # at rest on the TPU, a page contiguous (ops/kv_attention
             # .py:_paged_pools has why [.., n_head, d_k] was not)
@@ -835,23 +810,6 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
                     attn_in, page_table, pos, seq_len, gen_start,
                     active, d_model, n_head, pk, pv, pks, pvs,
                     codec=kv_codec, param_attr=attn_pa(i))
-        else:
-            ck = main.global_block().create_var(
-                name=f"{name}_cache_k_{i}",
-                shape=[-1, cache_len, n_head, d_k], dtype="float32",
-                persistable=True, stop_gradient=True)
-            cv = main.global_block().create_var(
-                name=f"{name}_cache_v_{i}",
-                shape=[-1, cache_len, n_head, d_k], dtype="float32",
-                persistable=True, stop_gradient=True)
-            if mode == "prefill":
-                attn = layers.kv_attention_prefill(
-                    attn_in, d_model, n_head, ck, cv,
-                    param_attr=attn_pa(i))
-            else:
-                attn = layers.kv_attention_decode(
-                    attn_in, pos, seq_len, gen_start, active, d_model,
-                    n_head, ck, cv, param_attr=attn_pa(i))
         x = layers.elementwise_add(x, attn)
         ffn_in = layers.layer_norm(x, begin_norm_axis=2,
                                    param_attr=pa(f"l{i}_ln2_scale"),
@@ -899,8 +857,7 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
                               vocab: int = 64, d_model: int = 32,
                               d_inner: int = 64, n_head: int = 2,
                               n_layer: int = 2, name: str = "lm",
-                              seed: int = 7, modes=("prefill", "decode",
-                                                    "full"),
+                              seed: int = 7, modes=("full",),
                               prompt_buckets=None, n_slots=None,
                               page_size=None, n_pages=None,
                               kv_codec=None, spec_k=None, **arch):
@@ -909,9 +866,10 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
     (any of them; their parameter initializers are identical) into a
     scope and it serves every view alike.
 
+    ``modes`` defaults to the one view that needs no slot geometry, the
+    ``full`` oracle; the slot server's are :func:`slot_modes`.
     ``prompt_buckets`` (ascending lengths, largest == prompt_len) emits
-    one prefill view PER bucket — keys ``prefill@P`` (and
-    ``prefill_paged@P`` when the paged modes are requested), with the
+    one prefill view PER bucket — keys ``prefill_paged@P``, with the
     bare mode name aliased to the largest bucket. ``n_slots`` sizes
     the decode slot pool of the paged views; ``page_size``/``n_pages``/
     ``kv_codec`` shape the page pool (ISSUE 17 — see decoder_lm);
@@ -942,7 +900,7 @@ def build_decoder_lm_programs(prompt_len: int = 16, max_new: int = 16,
         out[key] = (main, startup, feed_specs, outv.name)
 
     for mode in modes:
-        if mode in ("prefill", "prefill_paged"):
+        if mode == "prefill_paged":
             for p in buckets:
                 emit(f"{mode}@{p}", mode, p)
             out[mode] = out[f"{mode}@{buckets[-1]}"]
@@ -970,7 +928,7 @@ def slot_modes(layout="paged", spec=False):
 
 def contracts_lint_family():
     """``proglint --contracts`` default target: the full decoder_lm
-    serving family (every mode, bucketed prefills, wave + paged + verify
+    serving family (every mode, bucketed prefills, full + paged + verify
     views) at lint-sized dims — the cross-view contract verifier
     (analysis/contracts.py) runs over what this returns."""
     from paddle_tpu.analysis.contracts import DECODER_LM_MODES
@@ -978,18 +936,6 @@ def contracts_lint_family():
         prompt_len=8, max_new=8, vocab=32, d_model=16, d_inner=32,
         n_head=2, n_layer=2, prompt_buckets=(4, 8), n_slots=4, spec_k=3,
         modes=DECODER_LM_MODES)
-
-
-def serve_lint_prefill():
-    """proglint --module entry (tools/test_runner.py pre-test gate):
-    builds the prefill serving program into the default programs."""
-    decoder_lm("prefill")
-
-
-def serve_lint_decode():
-    """proglint --module entry: the single-token KV-cache decode
-    program (per-row pos/seq_len/gen_start/active geometry)."""
-    decoder_lm("decode")
 
 
 def serve_lint_prefill_paged():

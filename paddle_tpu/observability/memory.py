@@ -310,11 +310,11 @@ _WATERMARK_HIST: deque = deque(maxlen=256)
 _watermark_peak = 0
 _CENSUS_LOCK = threading.Lock()
 
-# matches the wave engine's caches (_cache_k_0), the slot engine's page
-# pools (_page_k_0), and the paged codec's scale planes (_page_ks_0 /
-# _page_vs_0), a latent-attention layer's latent and indexer-key planes
-# (_page_c_0 / _page_i_0) — all kv_cache family
-_KV_RE = re.compile(r"_(cache|page)_((k|v)s?|c|i)_\d+$")
+# matches the slot engine's page pools (_page_k_0), the paged codec's
+# scale planes (_page_ks_0 / _page_vs_0), a latent-attention layer's
+# latent and indexer-key planes (_page_c_0 / _page_i_0) — all kv_cache
+# family
+_KV_RE = re.compile(r"_page_((k|v)s?|c|i)_\d+$")
 # optimizer accumulators are '<param>_<kind>_N' (fluid/optimizer.py
 # _add_accumulator); the kinds below are every _add_accumulator call site
 _ACC_RE = re.compile(

@@ -7,20 +7,13 @@ Inference-only ops (no VJP — serving programs are is_test), all spelled
 with the same numerics as ``ops/attention_block.py`` (fp32 MXU
 accumulation via preferred_element_type, softmax in fp32, probabilities
 applied in the storage dtype) so a prefill+decode transcript matches the
-full-forward graph token for token. The decode-side ops (wave decode,
-paged decode, paged verify) share ONE contraction over the cache,
+full-forward graph token for token. The decode-side ops (paged decode,
+paged verify) share ONE contraction over the cache,
 :func:`_decode_contract`: it consumes K and V as ``[B, S, H*Dk]`` — the
 model width on the minor dimension, as the paged gather leaves them and
 never re-laid — through a block-diagonal query, and with fp32 compute
 its two dots run at precision HIGHEST (an fp32 cache is multiplied in
 fp32, not rounded to bf16 by a default MXU pass):
-
-- ``kv_attention_prefill`` — causal self-attention over the whole
-  (padded) prompt in one shot, PLUS the cache side effect: the K/V
-  projections land in ``[B, S, H, D]`` cache tensors (``S = cache_len``,
-  zero beyond the prompt). The caches are program outputs bound to
-  PERSISTABLE vars, so ``CompiledBlock`` carries them into the serving
-  scope (created_persistable) where the decode program finds them.
 
 - ``kv_attention_prefill_paged`` — the in-flight-batching prefill: same
   causal attention, but the K/V rows are scattered into the shared
@@ -30,17 +23,15 @@ fp32, not rounded to bf16 by a default MXU pass):
   never leaks its previous occupant's keys: the decode mask admits only
   rows this request wrote.
 
-- ``kv_attention_decode`` — ONE new token per ROW per call, with fully
-  per-row geometry: ``Pos [B,1]`` is each row's cache write index,
+- ``kv_attention_decode_paged`` — ONE new token per ROW per call, with
+  fully per-row geometry: ``Pos [B,1]`` is each row's cache write index,
   ``GenStart [B,1]`` is where its generated region begins (the prompt
   bucket it was prefilled at), ``SeqLen [B,1]`` its true prompt length,
   and ``Active [B,1]`` gates the cache write — an inactive (free) slot
-  flows through the batch untouched. Every decode step of every mix of
-  in-flight requests runs the SAME static-shape executable: zero
-  steady-state compiles. (The wave-per-batch path is the special case
-  Pos = GenStart + step, Active = 1.) ``kv_attention_decode_paged`` is
-  the slot server's form: the same geometry with each row's cache read
-  and written through a ``[n_slots, max_pages]`` page table.
+  flows through the batch untouched; each row's cache is read and
+  written through a ``[n_slots, max_pages]`` page table. Every decode
+  step of every mix of in-flight requests runs the SAME static-shape
+  executable: zero steady-state compiles.
 
 - ``kv_attention_verify_paged`` — the speculative-decoding verify step
   (ISSUE 19): score a ``[B, K+1]`` token window per row in ONE causal dispatch. Window position 0 is the
@@ -351,78 +342,6 @@ def _causal_prefill(x, wq, wk, wv, wo, h):
     return out, k, v
 
 
-@register_op("kv_attention_prefill", no_grad=True,
-             ref="TPU-native serving op: causal attention + KV-cache "
-                 "population (decode counterpart of "
-                 "fused_attention_block; numerics per "
-                 "ops/attention_block.py)")
-def _kv_attention_prefill(ctx, ins, attrs):
-    """X [B,T,M], Wq/Wk/Wv/Wo [M,M] -> Out [B,T,M] (causal self-attn),
-    CacheK/CacheV [B,S,H,Dk] with [:, :T] = the K/V projections.
-    attrs: n_head, cache_len (S >= T)."""
-    x = first(ins, "X")
-    wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
-    h = int(attrs["n_head"])
-    cache_len = int(attrs["cache_len"])
-    t = x.shape[1]
-    dt = x.dtype
-    out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
-    pad = [(0, 0), (0, cache_len - t), (0, 0), (0, 0)]
-    cache_k = jnp.pad(k.astype(dt), pad)
-    cache_v = jnp.pad(v.astype(dt), pad)
-    return {"Out": [out], "CacheK": [cache_k], "CacheV": [cache_v]}
-
-
-@register_op("kv_attention_decode", no_grad=True,
-             ref="TPU-native serving op: one-token decode step over a "
-                 "static-shape KV cache with per-row position/active "
-                 "masking (in-flight batching; O(cache_len) cost, "
-                 "position-free executable)")
-def _kv_attention_decode(ctx, ins, attrs):
-    """X [B,1,M], Wq..Wo [M,M], CacheK/CacheV [B,S,H,Dk],
-    Pos [B,1] int (this token's cache write index, per row),
-    SeqLen [B,1] int (true prompt lengths),
-    GenStart [B,1] int (first generated slot — the prompt bucket the
-    row was prefilled at), Active [B,1] int (0 = free slot: the cache
-    row is left untouched and the output row is meaningless).
-    attrs: n_head. Writes k/v at ``Pos`` where active and attends over
-    {j < seq_len} ∪ {gen_start <= j <= pos}."""
-    x = first(ins, "X")
-    wq, wk, wv, wo = (first(ins, n) for n in ("Wq", "Wk", "Wv", "Wo"))
-    cache_k, cache_v = first(ins, "CacheK"), first(ins, "CacheV")
-    h = int(attrs["n_head"])
-    b, _, m = x.shape
-    s_len = cache_k.shape[1]
-    dt = x.dtype
-
-    pos = jnp.asarray(first(ins, "Pos")).reshape(-1).astype(jnp.int32)
-    lens = jnp.asarray(first(ins, "SeqLen")).reshape(-1).astype(jnp.int32)
-    gen0 = jnp.asarray(first(ins, "GenStart")).reshape(-1)\
-        .astype(jnp.int32)
-    active = jnp.asarray(first(ins, "Active")).reshape(-1) > 0
-
-    q = _ab._proj(x, wq, h)                     # [B,1,H,D]
-    k_t = _ab._proj(x, wk, h).astype(cache_k.dtype)
-    v_t = _ab._proj(x, wv, h).astype(cache_v.dtype)
-
-    j = jnp.arange(s_len, dtype=jnp.int32)
-    # per-row one-hot write at pos, gated by active — a free slot's
-    # cache row is bit-identical before and after the step
-    write = (j[None, :] == pos[:, None]) & active[:, None]      # [B,S]
-    cache_k = jnp.where(write[:, :, None, None], k_t, cache_k)
-    cache_v = jnp.where(write[:, :, None, None], v_t, cache_v)
-
-    valid = (j[None, :] < lens[:, None]) | \
-            ((j[None, :] >= gen0[:, None]) &
-             (j[None, :] <= pos[:, None]))           # [B,S]
-    c = _decode_contract(q, cache_k.reshape(b, s_len, m),
-                         cache_v.reshape(b, s_len, m), valid[:, None], dt)
-    out = jax.lax.dot_general(c, wo.reshape(h, -1, m),
-                              (((2, 3), (0, 1)), ((), ())),
-                              preferred_element_type=jnp.float32).astype(dt)
-    return {"Out": [out], "CacheKOut": [cache_k], "CacheVOut": [cache_v]}
-
-
 def _kv_quant(rows):
     """rows [..., H, D] fp32 -> (int8 codes, fp32 scales [..., H]):
     symmetric per-(position, head) scaling — the per-row-scale wire
@@ -654,15 +573,13 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     """X [B,1,M], Wq..Wo [M,M], PageK/PageV [n_pages, ps, H * Dk]
     (+ PageKS/PageVS when codec=int8), PageTable [B, MP] int (flat page
     id per logical page; sentinel n_pages past the slot's span),
-    Pos/SeqLen/GenStart/Active [B,1] int — geometry identical to
-    kv_attention_decode; the cache row for logical position j lives at
+    Pos/SeqLen/GenStart/Active [B,1] int (the module docstring's
+    per-row geometry); the cache row for logical position j lives at
     flat row table[b, j//ps]*ps + j%ps. attrs: n_head, codec. The mask
     {j < seq_len} ∪ {gen_start <= j <= pos} zeroes sentinel/garbage
     rows EXACTLY. The gathered [B, S, H*Dk] caches go to
     ``_decode_contract`` as they are — no reshape to [.., H, Dk], no
-    relayout — and kv_attention_decode hands the same function its
-    caches viewed the same way, so fp32 paged decode is bit-identical
-    to kv_attention_decode over the same rows by construction. With
+    relayout. With
     n_kv_head and head_dim (grouped KV heads, optional output gate Wg:
     see kv_attention_prefill_paged) the gathered caches are
     [B, S, n_kv*D] and the block-diagonal query carries H / n_kv query
@@ -712,8 +629,7 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
 
     # this step's write row through the page table, sentinel (dropped)
     # for inactive slots — a free slot's pages are bit-identical before
-    # and after the step, same contract as kv_attention_decode's gated
-    # one-hot write
+    # and after the step
     phase = functools.partial(_device_scopes.phase,
                               "kv_attention_decode_paged")
     with phase("write"):
@@ -755,7 +671,7 @@ def _kv_attention_verify_paged(ctx, ins, attrs):
     [M,M], PageK/PageV [n_pages, ps, H * Dk] (+ PageKS/PageVS when
     codec=int8), PageTable [B, MP] int, Pos [B,1] int (logical cache
     row of window position 0 — the row's committed frontier),
-    SeqLen/GenStart/Active [B,1] as in kv_attention_decode, WinLen
+    SeqLen/GenStart/Active [B,1] as in kv_attention_decode_paged, WinLen
     [B,1] int (valid window positions, 1..K1; 1 degenerates to plain
     decode). The cache row for logical position j is flat row
     table[b, j//ps]*ps + j%ps. attrs: n_head, codec.

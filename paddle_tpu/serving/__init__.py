@@ -5,8 +5,9 @@ A server process hosts N models, each as a set of AOT-compiled
 shape-bucket executables warmed at startup; requests coalesce through a
 bounded admission queue into continuously-formed batches that land on
 compiled buckets via pad-and-slice; the transformer family serves
-autoregressive traffic through a prefill + KV-cache decode program pair
-(O(1) per token, zero steady-state compiles). The client wraps the
+autoregressive traffic through the paged slot engine (a prefill per
+admission, one fixed-shape decode step over every slot: O(1) per token,
+zero steady-state compiles). The client wraps the
 distributed/resilience.py kit (RetryPolicy + CircuitBreaker) and every
 stage exports through observability/ (scrape endpoint included).
 
@@ -16,7 +17,7 @@ Public surface::
     policy = serving.BucketPolicy.pow2(8)
     server = serving.ModelServer()
     server.add_model(serving.ServedModel("clf", model_dir, policy))
-    server.add_model(serving.GenerativeModel("lm", programs, policy))
+    server.add_model(serving.make_slot_model("lm", programs))
     endpoint = server.serve()
     client = serving.ServingClient(endpoint)
     outs = client.infer("clf", {"x": batch})

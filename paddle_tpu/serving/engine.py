@@ -1,6 +1,6 @@
 """Serving engines: the per-model execution layer under the server.
 
-Three engine kinds, one discipline — every runtime dispatch lands on a
+Two engine kinds, one discipline — every runtime dispatch lands on a
 shape signature that was WARMED (compiled or AOT-loaded) at startup, so
 steady-state serving performs zero XLA compilations
 (``serving.metrics.forbid_compiles`` turns the contract into an error;
@@ -13,21 +13,12 @@ steady-state serving performs zero XLA compilations
   persistence satellite), requests padded to the nearest bucket and
   sliced back (serving/bucketing.py).
 
-- :class:`GenerativeModel` — the transformer-family KV-cache decode
-  path: a prefill program (causal forward over the prompt bucket that
-  populates per-layer [B, S, H, D] caches in the model scope) plus a
-  single-token decode program whose static shapes make every decode
-  step the SAME executable (ops/kv_attention.py). Autoregressive
-  serving becomes prefill + O(1)-per-token decode instead of a fresh
-  full forward per token; ``analyzed_flops`` of the decode executable
-  is independent of the decode position by construction.
-
 - :class:`SlotGenerativeModel` — in-flight batched decoding (ISSUE 9)
   over a PAGED KV pool (ISSUE 17): the decode executable is ONE
   fixed-shape ``[n_slots]``-row program; requests JOIN a free slot
   mid-flight (prefill scatters their cache rows in) and LEAVE on
   EOS/max-tokens/cancel, so the device stays saturated with whatever
-  work exists right now — no wave barrier, with on-device
+  work exists right now — no batch barrier, with on-device
   temperature/top-k sampling per slot. Slots address their cache
   through a per-slot page table into one shared ``[n_pages, page_size,
   H*D]`` pool, admission is gated by FREE PAGES for the request's span
@@ -36,7 +27,11 @@ steady-state serving performs zero XLA compilations
   prefix pages through a refcounted radix tree
   (``serving/kv_pool.py``). The page table is a fixed-shape
   ``[n_slots, max_pages]`` feed, so join/leave churn never re-lowers.
-  ``make_slot_model`` builds it.
+  ``make_slot_model`` builds it. Autoregressive serving is a prefill
+  per admission + O(1)-per-token decode steps instead of a fresh full
+  forward per token; its base class :class:`GenerativeModel` holds the
+  scope and the dispatch door and, over a family's ``full`` view, is
+  the greedy oracle that full forward is kept for.
 """
 
 from __future__ import annotations
@@ -65,8 +60,8 @@ class PromptTooLongError(ValueError):
     bucket (carried over the wire as kind='bad_request')."""
 
 
-# -- AOT executable persistence (shared by GenerativeModel; the
-# predictor has the same discipline inline) -------------------------------
+# -- AOT executable persistence (the slot engine's; the predictor has
+# the same discipline inline) ---------------------------------------------
 
 def _sha256_file(path: str) -> str:
     h = hashlib.sha256()
@@ -95,9 +90,12 @@ def save_executable(path: str, lowered) -> bool:
         return False
 
 
-def load_executable(path: str):
-    """Deserialize an executable saved by :func:`save_executable`; None
-    on any mismatch/corruption (caller falls back to the compile path).
+def load_executable(path: str, devices):
+    """Deserialize an executable saved by :func:`save_executable`, to
+    run on ``devices`` (the ones it was compiled for: left to its
+    default the loader hands it every local device, and each call then
+    fails on a host with several); None on any mismatch/corruption
+    (caller falls back to the compile path).
     SECURITY: pickle — the directory must be a trusted model dir, same
     trust level as the model program itself (see predictor.py)."""
     if not os.path.exists(path):
@@ -115,7 +113,8 @@ def load_executable(path: str):
         from jax.experimental import serialize_executable as se
         with open(path, "rb") as f:
             payload = pickle.load(f)
-        return se.deserialize_and_load(*payload)
+        return se.deserialize_and_load(*payload,
+                                       execution_devices=list(devices))
     except Exception as e:
         import warnings
         warnings.warn(f"AOT executable {path} did not load: "
@@ -241,83 +240,52 @@ class ServedModel:
 
 
 class GenerativeModel:
-    """Prefill + KV-cache decode serving for the decoder-LM family
-    (wave-per-batch: the whole coalesced batch decodes to completion —
-    the control arm the slot scheduler is measured against).
+    """What every engine of the decoder-LM family is: one scope over the
+    family's weights, one door for every dispatch (``_run``: ``_launch``
+    then ``_fetch``) and the table of executables loaded ahead of time
+    — and, where the family carries a ``full`` view, the greedy oracle
+    the slot engine is held to (``full_forward_generate``: tests,
+    chip_smoke.py, ``ModelDrafter``). :class:`SlotGenerativeModel`, the
+    engine a server hosts, inherits from it; built alone over
+    ``build_decoder_lm_programs(..., modes=("full",))`` it is the oracle
+    and serves nothing. (The name is the one the benchmark's tests patch
+    ``_run`` on; renaming it is a ``benchmark`` PR's.)"""
 
-    Built from the program family of
-    ``models.transformer.build_decoder_lm_programs`` (any model whose
-    programs share the same feed contract works): each ``prefill@P``
-    view consumes ``ids [B, P, 1]`` (a LADDER of prompt buckets — mixed
-    lengths pad to the nearest bucket instead of worst-case) and creates
-    the per-layer caches in the model scope; ``decode`` consumes
-    ``tok [B, 1, 1]`` plus the per-row ``pos / seq_len / gen_start /
-    active`` geometry and reads+writes the caches (donated state — the
-    cache update is in-place in HBM). Greedy decoding; one scope per
-    model, waves serialized by the server's batcher."""
+    # the view whose start-up fills the scope: every view's draws the
+    # same weights, a slot view's zeroes its pools too
+    STARTUP = "full"
 
     def __init__(self, name: str, programs: Dict,
                  policy: Optional[bucketing.BucketPolicy] = None,
                  scope=None, init: bool = True, dist=None):
         import paddle_tpu.fluid as fluid
         from paddle_tpu.core.lowering import CompiledBlock
+        from paddle_tpu.observability import memory as obs_memory
         self.name = name
         # optional SPMD serving: a DistributeConfig lowers every view
         # through the one-dispatch mesh path of core/lowering.py — the
-        # params and KV caches live sharded over the mesh and each
+        # params and KV pools live sharded over the mesh and each
         # prefill/decode is a single jit call (docs/serving.md "Serving
         # over a mesh"). None (default) keeps single-device serving.
         self.dist = dist
         self.policy = policy or bucketing.BucketPolicy()
         self.scope = scope or fluid.Scope()
-        # prompt-length bucket ladder: every "prefill@P" view (the bare
-        # "prefill" key aliases the largest bucket)
-        pre = {}
-        for key, val in programs.items():
-            if key == "prefill" or key.startswith("prefill@"):
-                pre[int(val[2]["ids"][0][1])] = val
-        if not pre:
-            raise ValueError("programs must contain a 'prefill' view")
-        self.prompt_buckets = tuple(sorted(pre))
-        self.prompt_len = self.prompt_buckets[-1]
-        pre_main, pre_start, _, _ = pre[self.prompt_len]
-        dec_main, dec_start, dec_feeds, dec_fetch = programs["decode"]
         if init:
-            exe = fluid.Executor(fluid.TPUPlace())
-            exe.run(pre_start, scope=self.scope)
-        # HBM observability: name the programs for the memory gauges
-        # and register the model scope with the census walk
-        from paddle_tpu.observability import memory as obs_memory
-        for p, (m, _s, _f, _o) in pre.items():
-            m.desc._obs_name = f"{name}.prefill@{p}"
-        dec_main.desc._obs_name = f"{name}.decode"
+            fluid.Executor(fluid.TPUPlace()).run(
+                programs[self.STARTUP][1], scope=self.scope)
+        # HBM observability: register the scope with the census walk
         obs_memory.note_scope(self.scope)
-        self._cb_prefill = {
-            p: CompiledBlock(m.desc, 0, sorted(feeds), [fetch],
-                             is_test=True, donate=False, dist=dist)
-            for p, (m, _s, feeds, fetch) in pre.items()}
-        self._cb_decode = CompiledBlock(
-            dec_main.desc, 0, sorted(dec_feeds), [dec_fetch],
-            is_test=True, donate=True, dist=dist)
-        # max_new from the cache length the decode block declares
-        cache_vars = [v for n, v in dec_main.desc.global_block.vars.items()
-                      if n.endswith("_cache_k_0")]
-        self.cache_len = int(cache_vars[0].shape[1]) if cache_vars else 0
-        self.max_new = (self.cache_len - self.prompt_len
-                        if cache_vars else 0)
         self._full = None
         if "full" in programs:
             full_main, _, full_feeds, full_fetch = programs["full"]
             self._full = CompiledBlock(
                 full_main.desc, 0, sorted(full_feeds), [full_fetch],
                 is_test=True, donate=False, dist=dist)
-        self._warmed: set = set()   # ("prefill", bucket, P) | ("decode", bucket)
+            # the oracle's sequence: the view's own feed, ids [-1, T, 1]
+            self._full_len = int(full_feeds["ids"][0][1])
+        self._warmed: set = set()   # the executables' keys, once warm
         self._aot: Dict[Tuple, object] = {}
         self._aot_names = _Loaded(self._aot)
-        self._fingerprint = hashlib.sha256(json.dumps(
-            [pre[p][0].desc.to_dict() for p in self.prompt_buckets]
-            + [dec_main.desc.to_dict()],
-            sort_keys=True, default=str).encode()).hexdigest()
 
     # -- plumbing --------------------------------------------------------
     def _args(self, cb, feeds):
@@ -342,26 +310,13 @@ class GenerativeModel:
         scope and the first output still on the device when this
         returns — ``(output, what _fetch needs beside it)``. While
         tracing, the host's time is named ``serving.<kind>.args`` (scope
-        lookups, padding) and ``.dispatch`` (until the async call
+        lookups) and ``.dispatch`` (until the async call
         returns, and the state write-back), ``<kind>`` being ``prefill``
         or ``decode`` by ``aot_key[0]`` (a verify step is a decode)."""
         from paddle_tpu.observability import memory as obs_memory
         from paddle_tpu.utils import faults
         trace_on = tctx.active()
         t0 = time.perf_counter() if trace_on else 0.0
-        plan = None
-        dist = getattr(self, "dist", None)
-        if dist is not None and getattr(dist, "mesh", None) is not None:
-            ax = dist.data_axis
-            if ax and ax in dist.mesh.axis_names:
-                # a wave batch not divisible by the data axis pads to
-                # the next multiple and slices the padded rows back off
-                # the fetch — the executor's pad-and-slice discipline
-                # (utils/padding.py). Slot engines have a fixed
-                # [n_slots] geometry: size n_slots divisible by the
-                # data axis and this is a no-op.
-                feeds, plan = _padding.pad_feeds_to_multiple(
-                    feeds, int(dist.mesh.shape[ax]))
         args = self._args(cb, feeds)
         t1 = time.perf_counter() if trace_on else 0.0
         try:
@@ -409,9 +364,9 @@ class GenerativeModel:
             tctx.record_span(kind + ".args", t0, t1, ctx=ctx)
             tctx.record_span(kind + ".dispatch", t1, time.perf_counter(),
                              ctx=ctx)
-        return fetches[0], kind, plan
+        return fetches[0], kind
 
-    def _fetch(self, out, kind, plan) -> np.ndarray:
+    def _fetch(self, out, kind) -> np.ndarray:
         """The blocking fetch of a launched dispatch's output (span
         ``serving.<kind>.fetch``: blocked on the device)."""
         trace_on = tctx.active()
@@ -420,231 +375,44 @@ class GenerativeModel:
         if trace_on:
             tctx.record_span(kind + ".fetch", t0, time.perf_counter(),
                              ctx=tctx.current())
-        if plan is not None:
-            out = plan.slice_fetch(out)
         return out
 
-    def _dispatch(self, kind: str, bucket: int, feeds,
-                  p_len: Optional[int] = None) -> np.ndarray:
-        if kind == "prefill":
-            p = p_len or self.prompt_len
-            out = self._run(self._cb_prefill[p],
-                            ("prefill", bucket, p), feeds)
-            # the prefill just (re)created the per-layer caches in the
-            # scope — refresh the exact KV-bytes gauge (once per wave,
-            # not per decoded token)
-            from paddle_tpu.observability import memory as obs_memory
-            obs_memory.kv_pool_bytes(self.scope, self.name)
-            return out
-        return self._run(self._cb_decode, ("decode", bucket), feeds)
-
-    def prompt_bucket_for(self, length: int) -> int:
-        """Smallest prompt bucket >= length (the prompt-ladder analogue
-        of BucketPolicy.bucket_for)."""
-        for p in self.prompt_buckets:
-            if length <= p:
-                return p
-        raise PromptTooLongError(
-            f"prompt of length {length} exceeds the prompt bucket "
-            f"{self.prompt_len}")
-
-    def _prefill_feeds(self, bucket: int, p_len: Optional[int] = None):
-        p = p_len or self.prompt_len
-        return {"ids": np.zeros((bucket, p, 1), np.int64)}
-
-    def _decode_feeds(self, bucket: int, step: int = 0,
-                      p_len: Optional[int] = None):
-        p = p_len or self.prompt_len
-        return {"tok": np.zeros((bucket, 1, 1), np.int64),
-                "pos": np.full((bucket, 1), p + step, np.int64),
-                "seq_len": np.full((bucket, 1), p, np.int64),
-                "gen_start": np.full((bucket, 1), p, np.int64),
-                "active": np.ones((bucket, 1), np.int64)}
-
-    # -- warmup / AOT ----------------------------------------------------
-    def warmup(self, aot_dir: Optional[str] = None,
-               persist: bool = True) -> Dict[str, int]:
-        """Compile-or-load every (prefill bucket × batch bucket) plus
-        decode per batch bucket. With ``aot_dir``, serialized
-        executables are loaded when present and written after a compile,
-        so a restarted server skips the compiler entirely."""
-        loaded = compiled = 0
-        if aot_dir:
-            loaded += self.load_compiled(aot_dir)
-        for bucket in self.policy.batch_buckets:
-            for p in self.prompt_buckets:
-                if ("prefill", bucket, p) in self._warmed:
-                    continue
-                smetrics.count_compile(self.name, "prefill")
-                compiled += 1
-                self._dispatch("prefill", bucket,
-                               self._prefill_feeds(bucket, p), p_len=p)
-                self._warmed.add(("prefill", bucket, p))
-                if aot_dir and persist:
-                    self._persist_one(aot_dir, "prefill", bucket, p)
-            if ("decode", bucket) not in self._warmed:
-                smetrics.count_compile(self.name, "decode")
-                compiled += 1
-                # the decode dispatch reads the cache state vars — run a
-                # prefill at this bucket first so they exist in the
-                # scope at the right shape even when the prefill
-                # executable was AOT-loaded (no dispatch)
-                self._dispatch("prefill", bucket,
-                               self._prefill_feeds(bucket))
-                self._dispatch("decode", bucket,
-                               self._decode_feeds(bucket))
-                self._warmed.add(("decode", bucket))
-                if aot_dir and persist:
-                    self._persist_one(aot_dir, "decode", bucket)
-        return {"loaded": loaded, "compiled": compiled}
-
-    def _aot_path(self, dirname: str, kind: str, bucket: int,
-                  p_len: Optional[int] = None) -> str:
-        tag = f"{kind}_b{bucket}" + (f"_p{p_len}" if p_len else "")
-        return os.path.join(
-            dirname, f"__kv_{tag}.{self._fingerprint[:12]}.pax")
-
-    def _persist_one(self, dirname: str, kind: str, bucket: int,
-                     p_len: Optional[int] = None):
-        if kind == "prefill":
-            cb = self._cb_prefill[p_len or self.prompt_len]
-            feeds = self._prefill_feeds(bucket, p_len)
-        else:
-            cb = self._cb_decode
-            feeds = self._decode_feeds(bucket)
-        lowered = cb.fn.lower(*self._args(cb, feeds))
-        save_executable(self._aot_path(dirname, kind, bucket, p_len),
-                        lowered)
-
-    def load_compiled(self, dirname: str) -> int:
-        """Load every persisted executable matching this program
-        fingerprint; returns how many now serve without a compile. The
-        fingerprint hashes the program descs VERBATIM — including
-        generated intermediate var names, which restart identically in a
-        fresh process (the server-restart scenario this serves) but
-        shift if the programs are REbuilt inside one process; a mismatch
-        is safe, it just recompiles."""
-        n = 0
-        for bucket in self.policy.batch_buckets:
-            for p in self.prompt_buckets:
-                exe = load_executable(
-                    self._aot_path(dirname, "prefill", bucket, p))
-                if exe is not None:
-                    self._aot[("prefill", bucket, p)] = exe
-                    self._warmed.add(("prefill", bucket, p))
-                    n += 1
-            exe = load_executable(self._aot_path(dirname, "decode",
-                                                 bucket))
-            if exe is not None:
-                self._aot[("decode", bucket)] = exe
-                self._warmed.add(("decode", bucket))
-                n += 1
-        return n
-
-    # -- generation ------------------------------------------------------
-    def generate(self, prompts: Sequence[np.ndarray],
-                 max_new: Optional[int] = None) -> List[np.ndarray]:
-        """Greedy-decode ``max_new`` tokens for each prompt (1-D int
-        arrays of length <= prompt bucket). One prefill (at the nearest
-        prompt bucket of the wave's longest prompt) + max_new decode
-        steps per wave, all on warmed static-shape executables."""
-        max_new = self.max_new if max_new is None else int(max_new)
-        if max_new > self.max_new:
-            raise ValueError(f"max_new {max_new} exceeds the cache "
-                             f"budget {self.max_new}")
-        n = len(prompts)
-        lens = np.array([len(p) for p in prompts], np.int64)
-        too_long = lens > self.prompt_len
-        if too_long.any():
-            raise PromptTooLongError(
-                f"{int(too_long.sum())} prompt(s) exceed the prompt "
-                f"bucket {self.prompt_len}")
-        p_len = self.prompt_bucket_for(int(lens.max()) if n else 1)
-        bucket = self.policy.bucket_for(n)
-        for key, kind in ((("prefill", bucket, p_len), "prefill"),
-                          (("decode", bucket), "decode")):
-            if key not in self._warmed:
-                smetrics.count_compile(self.name, f"steady_{kind}")
-                self._warmed.add(key)
-        ids = np.zeros((bucket, p_len), np.int64)
-        for i, p in enumerate(prompts):
-            ids[i, :len(p)] = np.asarray(p, np.int64)
-        blens = _padding.pad_rows(lens[:, None], bucket)
-
-        with tctx.span(f"serving.prefill@{p_len}", model=self.name,
-                       rows=bucket):
-            logits = self._dispatch("prefill", bucket,
-                                    {"ids": ids[:, :, None]},
-                                    p_len=p_len)
-        smetrics.PREFILLS.labels(model=self.name).inc()
-        tok = logits[np.arange(bucket), blens[:, 0] - 1].argmax(-1)
-        out = [tok.astype(np.int64)]
-        gen_start = np.full((bucket, 1), p_len, np.int64)
-        active = np.ones((bucket, 1), np.int64)
-        for s in range(max_new - 1):
-            lg = self._dispatch(
-                "decode", bucket,
-                {"tok": out[-1][:, None, None],
-                 "pos": np.full((bucket, 1), p_len + s, np.int64),
-                 "seq_len": blens, "gen_start": gen_start,
-                 "active": active})
-            smetrics.DECODE_STEPS.labels(model=self.name).inc()
-            out.append(lg[:, 0].argmax(-1).astype(np.int64))
-        smetrics.TOKENS_GENERATED.labels(model=self.name).inc(
-            int(n * max_new))
-        toks = np.stack(out, axis=1)       # [bucket, max_new]
-        return [toks[i] for i in range(n)]
-
-    # -- baseline (bench/parity) ----------------------------------------
+    # -- the greedy oracle -----------------------------------------------
     def full_forward_generate(self, prompts: Sequence[np.ndarray],
-                              max_new: Optional[int] = None
-                              ) -> List[np.ndarray]:
-        """The O(T)-per-token baseline: a fresh full causal forward for
+                              max_new: int) -> List[np.ndarray]:
+        """The O(T)-per-token reference: a fresh full causal forward for
         every emitted token (requires the "full" program): the greedy
-        oracle the tests and chip_smoke.py compare the KV-cache paths
+        oracle the tests and chip_smoke.py compare the slot engine
         against, on the exact same weights."""
         if self._full is None:
             raise RuntimeError("no 'full' program was provided")
-        max_new = self.max_new if max_new is None else int(max_new)
         n = len(prompts)
         lens = np.array([len(p) for p in prompts], np.int64)
         bucket = self.policy.bucket_for(n)
-        t_total = self.prompt_len + self.max_new
-        seq = np.zeros((bucket, t_total), np.int64)
+        seq = np.zeros((bucket, self._full_len), np.int64)
         for i, p in enumerate(prompts):
             seq[i, :len(p)] = np.asarray(p, np.int64)
         blens = _padding.pad_rows(lens[:, None], bucket)[:, 0]
         out = []
-        for s in range(max_new):
+        for s in range(int(max_new)):
             f, _ = self._full.fn(*self._args(
                 self._full, {"ids": seq[:, :, None]}))
             logits = np.asarray(f[0])
             tok = logits[np.arange(bucket), blens - 1 + s].argmax(-1)
             out.append(tok.astype(np.int64))
             # append each row's token right after its current end
-            # (blens + s <= prompt_len + max_new - 1 = t_total - 1)
+            # (a prompt and its budget fit the view: blens + s < T)
             seq[np.arange(bucket), blens + s] = out[-1]
         toks = np.stack(out, axis=1)
         return [toks[i] for i in range(n)]
-
-    def decode_flops(self, bucket: Optional[int] = None,
-                     step: int = 0):
-        """``analyzed_flops`` of the decode executable — independent of
-        the decode position by construction (static shapes; the
-        acceptance criterion's witness). Runs one prefill first so the
-        scope's cache state matches the probed bucket."""
-        bucket = bucket or self.policy.batch_buckets[0]
-        self._dispatch("prefill", bucket, self._prefill_feeds(bucket))
-        return self._cb_decode.analyzed_flops(
-            self.scope, self._decode_feeds(bucket, step))
 
     def full_forward_flops(self, bucket: Optional[int] = None):
         if self._full is None:
             return None
         bucket = bucket or self.policy.batch_buckets[0]
-        t_total = self.prompt_len + self.max_new
         return self._full.analyzed_flops(
-            self.scope, {"ids": np.zeros((bucket, t_total, 1), np.int64)})
+            self.scope,
+            {"ids": np.zeros((bucket, self._full_len, 1), np.int64)})
 
 
 class SlotExhaustedError(RuntimeError):
@@ -732,7 +500,7 @@ class ModelDrafter:
     SEPARATE (smaller) decoder-LM sharing the engine family's program-
     view machinery — its ``full`` view is re-dispatched K times per
     proposal. Pass a :class:`GenerativeModel` built over the draft
-    weights. Useful when histories don't self-repeat (NgramDrafter's
+    weights' ``full`` view. Useful when histories don't self-repeat (NgramDrafter's
     blind spot); the acceptance rule upstream is unchanged, so a bad
     draft model costs only acceptance length, never correctness."""
 
@@ -744,7 +512,7 @@ class ModelDrafter:
 
     def propose(self, tokens, k: int):
         m = self.model
-        t_total = m.prompt_len + m.max_new
+        t_total = m._full_len
         # greedy continuation needs room for k drafts after the context
         ctx = list(tokens)[-(t_total - k):] if k < t_total else []
         if k <= 0 or not ctx:
@@ -778,14 +546,13 @@ class _Flight(NamedTuple):
     """A decode step that is dispatched and not yet committed."""
     out: object            # its sampled tokens [n_slots, 1], on the device
     kind: str              # what ``_fetch`` needs beside them
-    plan: object
     slots: np.ndarray      # the slots it ran, ascending
     ran: np.ndarray        # the same as a mask over all slots
     epoch: np.ndarray      # every slot's admission count at dispatch
     last: np.ndarray       # per ran slot: its budget's last token
 
 
-class SlotGenerativeModel:
+class SlotGenerativeModel(GenerativeModel):
     """In-flight batched decoding over a persistent decode-slot pool
     (ISSUE 9) whose KV cache is PAGED (ISSUE 17): the decode executable
     is ONE fixed-shape ``[n_slots]``-row program where each slot carries
@@ -798,7 +565,7 @@ class SlotGenerativeModel:
     (``admit`` prefills the prompt at the nearest prompt bucket and
     scatters its cache rows into the slot's pages via
     ``kv_attention_prefill_paged``) and LEAVE on EOS/max-tokens
-    (``step`` reports the leave and frees the slot) — no wave barrier,
+    (``step`` reports the leave and frees the slot) — no batch barrier,
     zero steady-state compiles (the page table is a fixed-shape feed:
     join/leave churn re-dispatches, never re-lowers).
 
@@ -830,13 +597,13 @@ class SlotGenerativeModel:
     PREFILL = "prefill_paged"
     DECODE = "decode_paged"
     VERIFY = "decode_verify_paged"
+    # any slot start-up: params + zero-filled pools. The DECODE view's,
+    # and nothing allocated between its pools (``_grouped_counters``)
+    STARTUP = DECODE
 
     def __init__(self, name: str, programs: Dict, scope=None,
                  init: bool = True, dist=None, drafter=None):
-        import paddle_tpu.fluid as fluid
         from paddle_tpu.core.lowering import CompiledBlock
-        self.name = name
-        self.dist = dist          # same contract as GenerativeModel.dist
         pk, dk = self.PREFILL, self.DECODE
         pre = {}
         for key, val in programs.items():
@@ -849,23 +616,20 @@ class SlotGenerativeModel:
                 f"n_slots=...)); got {sorted(programs)}")
         self.prompt_buckets = tuple(sorted(pre))
         self.prompt_len = self.prompt_buckets[-1]
-        dec_main, dec_start, dec_feeds, dec_fetch = programs[dk]
+        dec_main, _, dec_feeds, dec_fetch = programs[dk]
         self.n_slots = int(dec_feeds["tok"][0][0])
         ver = programs.get(self.VERIFY)
-        # server compatibility: max prompts one request may carry
-        self.policy = bucketing.BucketPolicy((self.n_slots,))
-        self.scope = scope or fluid.Scope()
-        if init:
-            exe = fluid.Executor(fluid.TPUPlace())
-            # any slot startup: params + zero-filled pool caches
-            exe.run(dec_start, scope=self.scope)
-        # HBM observability: program labels, census scope, and (the pool
-        # exists right after startup) the exact KV-pool bytes gauge
+        # the scope and its start-up; the policy is for the server: the
+        # most prompts one request may carry
+        super().__init__(name, programs,
+                         bucketing.BucketPolicy((self.n_slots,)),
+                         scope=scope, init=init, dist=dist)
+        # HBM observability: program labels and (the pool exists right
+        # after startup) the exact KV-pool bytes gauge
         from paddle_tpu.observability import memory as obs_memory
         for p, (m, _s, _f, _o) in pre.items():
             m.desc._obs_name = f"{name}.{pk}@{p}"
         dec_main.desc._obs_name = f"{name}.{dk}"
-        obs_memory.note_scope(self.scope)
         if init:
             obs_memory.kv_pool_bytes(self.scope, name)
         self._cb_prefill = {
@@ -910,9 +674,6 @@ class SlotGenerativeModel:
                 model=name)
         self._discover_pool(dec_main, dec_feeds)
         self._discover_state(dec_main, pre[self.prompt_len][2])
-        self._warmed: set = set()
-        self._aot: Dict[Tuple, object] = {}
-        self._aot_names = _Loaded(self._aot)
         self._fingerprint = hashlib.sha256(json.dumps(
             [pre[p][0].desc.to_dict() for p in self.prompt_buckets]
             + [dec_main.desc.to_dict()]
@@ -1189,13 +950,15 @@ class SlotGenerativeModel:
         self._m_grouped["given"].inc(given - brought)
         self._grouped_seen = (given, now)
 
-    # -- plumbing (same dispatch/AOT discipline as GenerativeModel) ------
-    _args = GenerativeModel._args
-    _run = GenerativeModel._run
-    _fetch_later = False
-    _launch = GenerativeModel._launch
-    _fetch = GenerativeModel._fetch
-    prompt_bucket_for = GenerativeModel.prompt_bucket_for
+    def prompt_bucket_for(self, length: int) -> int:
+        """Smallest prompt bucket >= length (the prompt-ladder analogue
+        of BucketPolicy.bucket_for)."""
+        for p in self.prompt_buckets:
+            if length <= p:
+                return p
+        raise PromptTooLongError(
+            f"prompt of length {length} exceeds the prompt bucket "
+            f"{self.prompt_len}")
 
     def free_count(self) -> int:
         return int((~self._active).sum())
@@ -1454,24 +1217,28 @@ class SlotGenerativeModel:
         save_executable(self._aot_path(dirname, kind, p_len), lowered)
 
     def load_compiled(self, dirname: str) -> int:
+        """Load every persisted executable matching this program
+        fingerprint; returns how many now serve without a compile. The
+        fingerprint hashes the program descs VERBATIM — including
+        generated intermediate var names, which restart identically in a
+        fresh process (the server-restart scenario this serves) but
+        shift if the programs are REbuilt inside one process; a mismatch
+        is safe, it just recompiles."""
+        # the devices the executables were compiled for: the mesh's, or
+        # the one the start-up put the scope's arrays on
+        mesh = self.dist.mesh if self.dist is not None else None
+        devices = mesh.devices.flat if mesh is not None else \
+            self.scope.find_var(
+                self._cb_decode.sig.state_names[0]).devices()
+        keys = [(self.PREFILL, p) for p in self.prompt_buckets] \
+            + [(self.DECODE,)] \
+            + ([(self.VERIFY,)] if self._cb_verify is not None else [])
         n = 0
-        pk, dk = self.PREFILL, self.DECODE
-        for p in self.prompt_buckets:
-            exe = load_executable(self._aot_path(dirname, pk, p))
+        for key in keys:
+            exe = load_executable(self._aot_path(dirname, *key), devices)
             if exe is not None:
-                self._aot[(pk, p)] = exe
-                self._warmed.add((pk, p))
-                n += 1
-        exe = load_executable(self._aot_path(dirname, dk))
-        if exe is not None:
-            self._aot[(dk,)] = exe
-            self._warmed.add((dk,))
-            n += 1
-        if self._cb_verify is not None:
-            exe = load_executable(self._aot_path(dirname, self.VERIFY))
-            if exe is not None:
-                self._aot[(self.VERIFY,)] = exe
-                self._warmed.add((self.VERIFY,))
+                self._aot[key] = exe
+                self._warmed.add(key)
                 n += 1
         return n
 
@@ -1706,7 +1473,7 @@ class SlotGenerativeModel:
         if trace_on:
             tctx.record_span("serving.decode.feeds", t0,
                              time.perf_counter())
-        out, kind, plan = self._dispatch_decode(feeds)
+        out, kind = self._dispatch_decode(feeds)
         self._count_sampling_step()
         slots = np.flatnonzero(ran)
         if self._dsa_layers:
@@ -1727,7 +1494,7 @@ class SlotGenerativeModel:
         if self._count_vars and \
                 self._decode_steps_done % self.COUNT_SNAPSHOT_STEPS == 0:
             self._snapshot_counts()
-        self._flights.append(_Flight(out, kind, plan, slots, ran,
+        self._flights.append(_Flight(out, kind, slots, ran,
                                      self._epoch.copy(), last))
         return True
 
@@ -1735,8 +1502,7 @@ class SlotGenerativeModel:
                      ) -> List[Tuple[int, int, Optional[str]]]:
         """Fetch a dispatched step's tokens (blocks until the device has
         run it) and commit them: the events, the releases."""
-        out = self._fetch(flight.out, flight.kind,
-                          flight.plan).reshape(-1)
+        out = self._fetch(flight.out, flight.kind).reshape(-1)
         trace_on = tctx.active()
         t0 = time.perf_counter() if trace_on else 0.0
         self._m_decode_steps.inc()

@@ -44,7 +44,7 @@ QUEUE_DEPTH = _metrics.gauge(
 QUEUE_WAIT = _metrics.histogram(
     "paddle_serving_queue_wait_seconds",
     "Admission-to-dispatch wait (enqueue until the batcher coalesces "
-    "the request into a wave, or the slot scheduler pops it for "
+    "the request into a batch, or the slot scheduler pops it for "
     "admission) — the queueing-delay component the depth gauge cannot "
     "show",
     labelnames=("model",))
@@ -82,13 +82,12 @@ SAMPLING_STEPS = _metrics.counter(
     "paid for more than an argmax", labelnames=("model",))
 PREFILLS = _metrics.counter(
     "paddle_serving_prefills_total",
-    "Prefill executable dispatches (one per generation wave, or one "
-    "per slot admission on the in-flight path)", labelnames=("model",))
+    "Prefill executable dispatches (one per slot admission)",
+    labelnames=("model",))
 TTFT = _metrics.histogram(
     "paddle_serving_ttft_seconds",
     "Time to first token: submit to the first generated token of a "
-    "request. On the slot scheduler this is bounded by queue wait + one "
-    "prefill; on the wave path it includes the whole wave",
+    "request: bounded by queue wait + one prefill",
     labelnames=("model",))
 INTER_TOKEN = _metrics.histogram(
     "paddle_serving_inter_token_latency_seconds",

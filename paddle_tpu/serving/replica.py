@@ -64,20 +64,17 @@ def build_engine(model_spec: dict):
     if kind == "decoder_lm":
         from paddle_tpu.models import transformer as T
         params = dict(model_spec.get("params") or {})
-        if model_spec.get("slots", True):
-            # page geometry the spec leaves out comes from
-            # analysis.contracts.validate_geometry: page_size 4,
-            # n_pages = every slot at full length
-            params.setdefault("modes", T.slot_modes("paged"))
-            params.setdefault("n_slots", 2)
-            return engine.make_slot_model(
-                name, T.build_decoder_lm_programs(name=name, **params))
-        params.setdefault("modes", ("prefill", "decode"))
-        programs = T.build_decoder_lm_programs(name=name, **params)
-        buckets = model_spec.get("buckets") or (1, 2)
-        return engine.GenerativeModel(
-            name, programs,
-            bucketing.BucketPolicy(tuple(int(b) for b in buckets)))
+        if not model_spec.get("slots", True):
+            raise ValueError(
+                f"replica spec of model {name!r} says \"slots\": false: "
+                f"a decoder_lm is served by the slot engine alone")
+        # page geometry the spec leaves out comes from
+        # analysis.contracts.validate_geometry: page_size 4,
+        # n_pages = every slot at full length
+        params.setdefault("modes", T.slot_modes("paged"))
+        params.setdefault("n_slots", 2)
+        return engine.make_slot_model(
+            name, T.build_decoder_lm_programs(name=name, **params))
     raise ValueError(f"unknown model kind {kind!r} in replica spec")
 
 

@@ -14,11 +14,11 @@ Request lifecycle (docs/serving.md):
            -> per-request latency observed, futures fulfilled
 
 A hosted :class:`~paddle_tpu.serving.engine.SlotGenerativeModel` gets
-the IN-FLIGHT scheduler instead of the wave batcher: a single loop that
+the IN-FLIGHT scheduler instead of the batcher: a single loop that
 admits queued prompts into free decode slots (one prefill each), steps
 the whole pool by one token per iteration, observes TTFT/inter-token
 latencies, and reaps slots on EOS/max-tokens/cancel — a request joins a
-RUNNING decode instead of waiting for the current wave to drain
+RUNNING decode instead of waiting for a batch to drain
 (ISSUE 9). ``cancel`` (in-process or over the wire) frees a request's
 slots within one decode step; the RPC handler cancels a generation
 whose client hung up mid-stream.
@@ -61,8 +61,8 @@ from paddle_tpu.observability import trace_context as tctx
 from paddle_tpu.observability import tracing as _tracing
 from paddle_tpu.serving import bucketing
 from paddle_tpu.serving import metrics as smetrics
-from paddle_tpu.serving.engine import (GenerativeModel, PromptTooLongError,
-                                       ServedModel, SlotExhaustedError,
+from paddle_tpu.serving.engine import (PromptTooLongError, ServedModel,
+                                       SlotExhaustedError,
                                        SlotGenerativeModel)
 from paddle_tpu.utils import faults
 
@@ -183,7 +183,7 @@ class _HostedModel:
 
     def cancel(self, request_id: str) -> bool:
         """Cancellation is only meaningful on the in-flight scheduler
-        (_SlotHostedModel); the wave batcher runs requests to
+        (_SlotHostedModel); the batcher runs requests to
         completion."""
         return False
 
@@ -313,10 +313,7 @@ class _HostedModel:
             if not wave:
                 continue
             try:
-                if wave[0].kind == "infer":
-                    self._run_infer_wave(wave)
-                else:
-                    self._run_generate_wave(wave)
+                self._run_infer_wave(wave)
             except BaseException as e:   # engine error: fail the wave
                 if self._is_fatal_oom(e):
                     self._fatal_oom(e)   # never returns
@@ -340,29 +337,6 @@ class _HostedModel:
             part = [o[row0:row0 + r.rows] if np.ndim(o) >= 1 else o
                     for o in outs]
             row0 += r.rows
-            self._settle(r, result=part)
-
-    def _run_generate_wave(self, wave: List[_Request]):
-        prompts: List[np.ndarray] = []
-        for r in wave:
-            prompts.extend(r.prompts)
-        rows = len(prompts)
-        bucket = self.engine.policy.bucket_for(rows)
-        smetrics.BATCH_OCCUPANCY.labels(model=self.name).set(
-            min(1.0, rows / bucket))
-        smetrics.BATCHES.labels(model=self.name).inc()
-        smetrics.REQUESTS_APPLIED.labels(model=self.name).inc(len(wave))
-        max_new = max(r.max_new for r in wave)
-        toks = self.engine.generate(prompts, max_new=max_new)
-        # the wave yields no token before it drains: TTFT == settle time
-        # (the honest control-arm number for the slot scheduler)
-        now = time.perf_counter()
-        i = 0
-        for r in wave:
-            smetrics.TTFT.labels(model=self.name).observe(
-                now - r.t_enqueue)
-            part = [t[:r.max_new] for t in toks[i:i + len(r.prompts)]]
-            i += len(r.prompts)
             self._settle(r, result=part)
 
     # -- settlement ------------------------------------------------------
@@ -730,7 +704,7 @@ class ModelServer:
     def add_model(self, engine, max_queue_depth: Optional[int] = None,
                   linger_s: Optional[float] = None,
                   warmup: bool = True, aot_dir: Optional[str] = None):
-        """Host a :class:`ServedModel` or :class:`GenerativeModel`.
+        """Host a :class:`ServedModel` or :class:`SlotGenerativeModel`.
         Warmup runs HERE (cold start pays the compiles or AOT loads;
         steady state pays none)."""
         name = engine.name
@@ -790,32 +764,26 @@ class ModelServer:
                         temperature: float = 0.0, top_k: int = 0,
                         seed: Optional[int] = None,
                         eos_id: Optional[int] = None) -> _Future:
-        """Queue a generation. Sampling knobs ride on the request
-        (honored by slot-scheduled models; the wave batcher is greedy
-        and rejects non-greedy submits): ``temperature <= 0`` or
+        """Queue a generation on a slot-scheduled model (any other
+        kind is refused). Sampling knobs ride on the request:
+        ``temperature <= 0`` or
         ``top_k == 1`` is exact greedy; ``seed`` makes a sampled stream
         reproducible across retries AND server restarts (prompt i uses
         seed + i); ``eos_id`` ends a stream early, freeing its slot."""
         m = self.model(model)
+        if not isinstance(m, _SlotHostedModel):
+            raise ValueError(
+                f"model {model!r} ({type(m.engine).__name__}) does not "
+                f"generate; host a SlotGenerativeModel "
+                f"(serving.make_slot_model)")
         prompts = [np.asarray(p, np.int64).reshape(-1) for p in prompts]
         if len(prompts) > m.max_rows:
             raise RequestShedError(
                 f"{len(prompts)} prompts exceed the largest bucket "
                 f"{m.max_rows}; split the request")
-        max_allowed = getattr(m.engine, "max_new", None)
-        if max_allowed is not None and max_new > max_allowed:
+        if max_new > m.engine.max_new:
             raise ValueError(f"max_new {max_new} exceeds the model's "
-                             f"cache budget {max_allowed}")
-        sampled = float(temperature) > 0.0 and int(top_k) != 1
-        if (sampled or eos_id is not None or seed is not None) \
-                and not isinstance(m, _SlotHostedModel):
-            # reject rather than silently ignore: the wave batcher
-            # decodes every request to its full budget with no EOS
-            # reaping and no sampling state
-            raise ValueError(
-                f"model {model!r} is wave-scheduled (greedy, no "
-                f"eos/seed); host a SlotGenerativeModel for on-device "
-                f"sampling and EOS early-leave")
+                             f"cache budget {m.engine.max_new}")
         req = _Request("generate", request_id or uuid.uuid4().hex,
                        len(prompts), prompts=prompts,
                        max_new=int(max_new), signature="generate",

@@ -198,28 +198,6 @@ def op_fwd_flops(block, op_type, inputs, outputs, attrs, batch,
         if attrs.get("causal"):
             dots *= 0.5
         return proj + dots
-    if op_type == "kv_attention_prefill":
-        # projections (4 × [B,T,M]·[M,M]) + causal attention dots
-        x = ishape("X")
-        if x is None:
-            return 0.0
-        b, t, m = x[-3], x[-2], x[-1]
-        h = int(attrs.get("n_head", 1))
-        d = m // max(h, 1)
-        return 2.0 * b * m * m * 4.0 * t + 2.0 * b * h * t * t * d
-    if op_type == "kv_attention_decode":
-        # one token per row: projections (4 × [B,1,M]·[M,M]) + dots over
-        # the STATIC cache length — independent of the decode position
-        # AND of which rows are active (the flat-decode-cost acceptance
-        # criterion)
-        x, ck = ishape("X"), ishape("CacheK")
-        if x is None or ck is None:
-            return 0.0
-        b, m = x[-3], x[-1]
-        s = ck[-3]
-        h = int(attrs.get("n_head", 1))
-        d = m // max(h, 1)
-        return 2.0 * b * m * m * 4.0 + 2.0 * b * h * s * d * 2.0
     if op_type == "kv_attention_verify_paged":
         # draft-verify window: K+1 tokens per row through the decode
         # math — projections (4 × [B,K1,M]·[M,M]) + dots of every window

@@ -665,20 +665,20 @@ def _decoder_family(modes):
 
 def test_contracts_full_family_green():
     """The contract the CI gate (proglint --contracts) enforces: the
-    whole decoder_lm family — wave, paged and verify views over
+    whole decoder_lm family — full, paged and verify views over
     every prompt bucket — passes every cross-view rule."""
     from paddle_tpu.analysis.contracts import DECODER_LM_MODES
     from paddle_tpu.models import transformer
     fam = transformer.contracts_lint_family()
-    # six modes; the two prefills fan out to a view per bucket + alias
-    assert len(DECODER_LM_MODES) == 6 and len(fam) == 10
+    # four modes; the prefill fans out to a view per bucket + alias
+    assert len(DECODER_LM_MODES) == 4 and len(fam) == 6
     diags = analysis.verify_family(fam)
     assert diags == [], [d.format() for d in diags]
 
 
 def test_contract_view_var_drift():
-    fam = _decoder_family(("prefill", "decode"))
-    fam["decode"][0].desc.global_block.vars["lm_emb"].shape = [33, 16]
+    fam = _decoder_family(("prefill_paged", "decode_paged"))
+    fam["decode_paged"][0].desc.global_block.vars["lm_emb"].shape = [33, 16]
     diags = analysis.verify_family(fam)
     assert [(d.rule, d.var) for d in diags] == \
         [("ctr-view-var-drift", "lm_emb")]
@@ -687,11 +687,11 @@ def test_contract_view_var_drift():
 
 
 def test_contract_salt_misalignment():
-    fam = _decoder_family(("prefill", "decode"))
+    fam = _decoder_family(("prefill_paged", "decode_paged"))
     # shift every rng initializer of ONE view by one startup op index —
     # per-index salting means the views would initialize different
     # weights for the "shared" parameters
-    ops = fam["decode"][1].desc.global_block.ops
+    ops = fam["decode_paged"][1].desc.global_block.ops
     ops.insert(0, ops.pop())
     diags = analysis.verify_family(fam)
     assert diags and {d.rule for d in diags} == {"ctr-salt-misalignment"}
@@ -699,27 +699,30 @@ def test_contract_salt_misalignment():
 
 
 def test_contract_stale_donation_read():
-    fam = _decoder_family(("prefill", "decode"))
-    # prefill demotes a KV cache that the decode view mutates in place:
+    fam = _decoder_family(("prefill_paged", "decode_paged"))
+    # prefill demotes a page pool that the decode view mutates in place:
     # prefill would then read a local temp, never the donated buffer
-    fam["prefill"][0].desc.global_block.vars[
-        "lm_cache_k_0"].persistable = False
+    fam["prefill_paged"][0].desc.global_block.vars[
+        "lm_page_k_0"].persistable = False
     diags = analysis.verify_family(fam)
     assert [d.rule for d in diags] == ["ctr-stale-donation-read"]
     d = diags[0]
-    assert d.var == "lm_cache_k_0"
+    assert d.var == "lm_page_k_0"
     assert d.details["as"] == "a non-persistable temp"
-    assert d.details["offending_view"].startswith("prefill")
+    assert d.details["offending_view"].startswith("prefill_paged")
 
 
 def test_contract_geometry_drift():
     import dataclasses
-    fam = _decoder_family(("prefill", "decode"))
-    m = fam["decode"][0]
+    fam = _decoder_family(("prefill_paged", "decode_paged"))
+    m = fam["decode_paged"][0]
     m._geometry = dataclasses.replace(m._geometry, cache_len=32)
     diags = analysis.verify_family(fam)
+    # the views disagree, and the drifted view's own page table no
+    # longer spans its cache
     assert [(d.rule, d.var) for d in diags] == \
-        [("ctr-geometry-drift", "cache_len")]
+        [("ctr-geometry-drift", "cache_len"),
+         ("ctr-geometry-drift", "page_table")]
 
 
 def test_validate_geometry_record():

@@ -38,13 +38,12 @@ _LM_PARAMS = {"prompt_len": 8, "max_new": 8, "vocab": 32, "d_model": 16,
               "d_inner": 32, "n_head": 2, "n_layer": 2}
 
 
-def _wave_spec(max_queue_depth=64, buckets=(1, 2), env=None):
-    """The wave-path tiny decoder LM (slots=false selects
-    GenerativeModel — the engine with the ``serving.dispatch`` chaos
+def _lm_spec(max_queue_depth=64, n_slots=2, env=None):
+    """The slot replica's tiny decoder LM (every dispatch of the slot
+    engine goes through ``_launch``: the ``serving.dispatch`` chaos
     site the OOM injection needs)."""
     spec = {"model": {"kind": "decoder_lm", "name": "lm",
-                      "slots": False, "buckets": list(buckets),
-                      "params": dict(_LM_PARAMS)},
+                      "params": dict(_LM_PARAMS, n_slots=int(n_slots))},
             "max_queue_depth": int(max_queue_depth)}
     if env:
         spec["env"] = dict(env)
@@ -120,7 +119,7 @@ def test_load_spike_static_sheds_autoscaled_serves_clean(tmp_path):
     from paddle_tpu.serving.router import Router
 
     # -- arm 1: static-2, shallow queues --------------------------------
-    shallow = _wave_spec(max_queue_depth=1)
+    shallow = _lm_spec(max_queue_depth=1)
     router = Router(spec=shallow, replicas=2,
                     workdir=str(tmp_path / "static"),
                     breaker_reset_s=0.5)
@@ -146,7 +145,7 @@ def test_load_spike_static_sheds_autoscaled_serves_clean(tmp_path):
          f"shed ({static_shed_ratio:.1%}) — not a spike")
 
     # -- arm 2: the SAME spike, autoscaled ------------------------------
-    deep = _wave_spec(max_queue_depth=512)
+    deep = _lm_spec(max_queue_depth=512)
     router = Router(spec=deep, replicas=2,
                     workdir=str(tmp_path / "scaled"),
                     breaker_reset_s=0.5)
@@ -207,7 +206,7 @@ def test_load_spike_static_sheds_autoscaled_serves_clean(tmp_path):
 
 def test_replica_oom_replaced_with_fallback_not_restart_looped(tmp_path):
     """OOM under load: the 10th ``serving.dispatch`` in slot 0's
-    process (6 warmup dispatches + mid-wave under load) raises an
+    process (3 warmup dispatches, then under load) raises an
     injected MemoryError. The replica memdumps and dies WITHOUT
     acking; the supervisor classifies cause="oom" from the witness
     file and respawns the slot ONCE with the registered smaller
@@ -216,11 +215,11 @@ def test_replica_oom_replaced_with_fallback_not_restart_looped(tmp_path):
     from paddle_tpu.serving import metrics as smetrics
     from paddle_tpu.serving.router import Router
 
-    faulty = _wave_spec(env={
+    faulty = _lm_spec(env={
         "FLAGS_fault_plan":
             "serving.dispatch:raise@10:exc=MemoryError"})
-    clean = _wave_spec()
-    fallback = _wave_spec(buckets=(1,))    # the smaller-footprint config
+    clean = _lm_spec()
+    fallback = _lm_spec(n_slots=1)    # the smaller-footprint config
     router = Router(specs=[faulty, clean],
                     workdir=str(tmp_path), breaker_reset_s=0.5,
                     oom_fallback=fallback)

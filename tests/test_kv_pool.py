@@ -557,21 +557,16 @@ def test_paged_ops_identical_through_every_gather_tier(op, codec,
 
 
 @pytest.mark.parametrize("op", ["decode", "verify"])
-def test_paged_fp32_bit_identical_to_contiguous_op(op):
-    """fp32 paged decode == the wave op ``kv_attention_decode`` over the
-    cache the page table describes, BIT for bit on every active row.
-    It holds by construction: both ops hand the same function
-    (``kv_attention._decode_contract``) the same [B, S, H*Dk] rows —
-    the paged op as its gather leaves them, the wave op its
-    [B, S, H, Dk] caches viewed so — hence the same shapes, the same
-    dots and the same bits on any backend. So the page indirection adds
-    nothing to the products and sums, and the rows it writes are the
-    same too. The verify window has no wave twin: it is held to a
-    plain float32 statement of the window attention written here
-    (rtol 1e-6), the rows it writes compared exactly; its token-level
-    identity with sequential paged decode is
-    tests/test_spec_decode.py::test_spec_greedy_bit_identical_zero_
-    recompiles."""
+def test_paged_fp32_ops_match_the_plain_window_attention(op):
+    """fp32 paged decode and verify == a plain float32 statement of the
+    window attention written here (``_window_attention_reference``; a
+    decode step is a window of one) over the cache the page table
+    describes, on every row the host reads (rtol 1e-6: the same products
+    and exact zeros, the sums in another order), and the rows they write
+    are the reference's BIT for bit — the page indirection adds nothing
+    to what lands in the pool. Token-level identity of the verify window
+    with sequential paged decode is tests/test_spec_decode.py::
+    test_spec_greedy_bit_identical_zero_recompiles."""
     import types
     import jax.numpy as jnp
     from paddle_tpu.core.registry import get_op
@@ -590,17 +585,14 @@ def test_paged_fp32_bit_identical_to_contiguous_op(op):
             if not k.startswith("Page")}
     cins["CacheK"] = [cache(ins["PageK"][0])]
     cins["CacheV"] = [cache(ins["PageV"][0])]
+    cins.setdefault("WinLen", [jnp.ones_like(ins["Pos"][0])])
     active = np.asarray(ins["Active"][0]).reshape(-1) > 0
     assert active.any() and not active.all()
-    if op == "decode":
-        want = get_op("kv_attention_decode").emit(ctx, cins, {"n_head": h})
-        np.testing.assert_array_equal(_bits(got["Out"][0])[active],
-                                      _bits(want["Out"][0])[active])
-    else:
-        want = _window_attention_reference(cins, h)
-        np.testing.assert_allclose(np.asarray(got["Out"][0])[want["seen"]],
-                                   np.asarray(want["Out"][0])[want["seen"]],
-                                   rtol=1e-6, atol=1e-6)
+    want = _window_attention_reference(cins, h)
+    assert want["seen"].any(axis=1).tolist() == active.tolist()
+    np.testing.assert_allclose(np.asarray(got["Out"][0])[want["seen"]],
+                               np.asarray(want["Out"][0])[want["seen"]],
+                               rtol=1e-6, atol=1e-6)
     for pool, c in (("PageKOut", "CacheKOut"), ("PageVOut", "CacheVOut")):
         np.testing.assert_array_equal(
             _bits(cache(got[pool][0]))[active],
@@ -685,8 +677,8 @@ def test_decode_contract_heads_do_not_mix():
 
 
 def _window_attention_reference(ins, h):
-    """The verify window in plain float32 ``jax.numpy`` over [B, S, H, Dk]
-    caches: window position i of row b writes its k/v at cache row
+    """The verify window (a decode step: a window of one) in plain float32
+    ``jax.numpy`` over [B, S, H, Dk] caches: window position i of row b writes its k/v at cache row
     pos + i (where active, i < win_len and the row exists) and attends
     over {j < seq_len} ∪ {gen_start <= j <= pos + i}. ``seen`` marks the
     [B, K1] outputs the host reads (active rows, i < win_len)."""
@@ -783,6 +775,9 @@ def test_make_slot_model_factory_and_geometry():
     assert type(m) is seng.SlotGenerativeModel
     assert not seng.SlotGenerativeModel.__subclasses__()
     assert not hasattr(seng, "PagedSlotGenerativeModel")
+    # one engine: the plumbing is inherited, not borrowed
+    assert seng.SlotGenerativeModel.__mro__[1] is seng.GenerativeModel
+    assert "_launch" not in vars(seng.SlotGenerativeModel)
     assert (m.n_pages, m.page_size, m.max_pages) == (16, 4, 4)
     assert m.cache_len == 16 and m.n_slots == 4
     assert m.free_pages() == 16
@@ -826,22 +821,46 @@ def test_paged_slot_layout_helper():
 
 
 def test_make_slot_model_refuses_a_family_without_paged_views():
-    """The wave family (prefill/decode/full) is not a slot family: the
-    constructor says which views it wants."""
+    """The oracle's family (the ``full`` view alone, the builder's
+    default) is not a slot family: the constructor says which views it
+    wants."""
+    full_only = T.build_decoder_lm_programs(**_LM_CFG)
+    assert sorted(full_only) == ["full"]
     with pytest.raises(ValueError, match="prefill_paged.*decode_paged"):
-        seng.make_slot_model(
-            "lm_wave_only", T.build_decoder_lm_programs(**_LM_CFG),
-            init=False)
+        seng.make_slot_model("lm_full_only", full_only, init=False)
     progs = T.build_decoder_lm_programs(
         **_LM_CFG, modes=("decode_paged",), n_slots=2)
     with pytest.raises(ValueError, match="prefill_paged"):
         seng.make_slot_model("lm_no_prefill", progs, init=False)
 
 
+@pytest.mark.parametrize("mode", ["prefill", "decode"])
+def test_the_contiguous_cache_views_are_refused_by_name(mode):
+    """The two views of the batch-at-a-time engine (removed at PR 46)
+    are refused with where they went; four modes are left."""
+    from paddle_tpu.analysis.contracts import DECODER_LM_MODES
+    assert DECODER_LM_MODES == ("full",) + T.slot_modes(spec=True)
+    with pytest.raises(ValueError, match="removed at PR 46.*slot_modes"):
+        T.build_decoder_lm_programs(**_LM_CFG, modes=(mode,))
+    from paddle_tpu.core.registry import get_op
+    with pytest.raises(KeyError, match="no emitter registered"):
+        get_op(f"kv_attention_{mode}")
+
+
+def test_replica_refuses_a_spec_without_slots():
+    """``"slots": false`` asked for the batch-at-a-time engine: refused
+    by name (a spec comes from outside the program)."""
+    from paddle_tpu.serving import replica
+    with pytest.raises(ValueError, match="slots.*slot engine alone"):
+        replica.build_engine(
+            {"kind": "decoder_lm", "name": "lm_replica_noslots",
+             "slots": False, "params": dict(_LM_CFG)})
+
+
 def test_replica_decoder_lm_spec_builds_the_paged_engine():
     """A replica's ``decoder_lm`` spec with ``slots: true`` builds the
     paged slot engine at validate_geometry's defaults (page_size 4,
-    every slot at full length) and serves the wave oracle's tokens."""
+    every slot at full length) and serves the oracle's tokens."""
     from paddle_tpu.serving import replica
     eng = replica.build_engine(
         {"kind": "decoder_lm", "name": "lm_replica_paged", "slots": True,

@@ -173,7 +173,8 @@ def test_classify_known_names():
     obs_memory.register_buffer_family("emb_table_rows", "embed_cache")
     assert obs_memory.classify("lm_page_k_0") == "kv_cache"
     assert obs_memory.classify("lm_page_vs_1") == "kv_cache"
-    assert obs_memory.classify("lm_cache_v_1") == "kv_cache"
+    assert obs_memory.classify("lm_page_c_1") == "kv_cache"
+    assert obs_memory.classify("lm_cache_v_1") == "other"
     assert obs_memory.classify("fc_0.w_0_moment1_0") == "optimizer_moment"
     assert obs_memory.classify("fc_0.w_0_velocity_0") == "optimizer_moment"
     assert obs_memory.classify("fc_0.w_0@GRAD") == "activation"

@@ -209,24 +209,8 @@ spec("fused_attention_block",
       "Wq": [f(8, 8, seed=2)], "Wk": [f(8, 8, seed=3)],
       "Wv": [f(8, 8, seed=4)], "Wo": [f(8, 8, seed=5)]},
      {"n_head": 2, "causal": True})
-# serving KV-cache family (ops/kv_attention.py): prefill populates a
-# [B, S, H, D] cache, decode writes one token per ACTIVE row at its
-# per-row pos, token_sample picks next tokens on-device (the paged
-# forms are CONTEXT_OPS above)
-spec("kv_attention_prefill",
-     {"X": [f(2, 4, 8)],
-      "Wq": [f(8, 8, seed=2)], "Wk": [f(8, 8, seed=3)],
-      "Wv": [f(8, 8, seed=4)], "Wo": [f(8, 8, seed=5)]},
-     {"n_head": 2, "cache_len": 6})
-spec("kv_attention_decode",
-     {"X": [f(2, 1, 8)],
-      "Wq": [f(8, 8, seed=2)], "Wk": [f(8, 8, seed=3)],
-      "Wv": [f(8, 8, seed=4)], "Wo": [f(8, 8, seed=5)],
-      "CacheK": [f(2, 6, 2, 4, seed=6)], "CacheV": [f(2, 6, 2, 4, seed=7)],
-      "Pos": [ints(2, 1, hi=6, seed=1)], "SeqLen": [ints(2, 1, hi=4)],
-      "GenStart": [ints(2, 1, hi=4, seed=2)],
-      "Active": [ints(2, 1, hi=2, seed=3)]},
-     {"n_head": 2})
+# serving KV-cache family (ops/kv_attention.py): token_sample picks next
+# tokens on-device (the paged attention ops are CONTEXT_OPS above)
 spec("token_sample",
      {"Logits": [f(2, 16)], "Temperature": [f(2, 1, lo=0.0, hi=1.0)],
       "TopK": [ints(2, 1, hi=5)], "Seed": [ints(2, 1, hi=100, seed=4)],

@@ -1,6 +1,6 @@
 """Serving-stack tests (ISSUE 8, docs/serving.md): bucket policy +
 pad-and-slice, bucketed AOT warmup with the zero-steady-state-compile
-contract ENFORCED, the KV-cache decode path's parity with the
+contract ENFORCED, the slot engine's parity with the
 full-forward oracle and its flat per-token cost, continuous batching /
 admission control / idempotency on the server, and the metrics surface
 through the scrape endpoint. The @slow load test drives the RPC front
@@ -48,35 +48,28 @@ _LM_CFG = dict(prompt_len=8, max_new=8, vocab=32, d_model=16,
 _LM_CACHE = {}
 
 
-def _shared_lm():
-    """One warmed GenerativeModel shared by the KV tests (explicit
-    Programs + a private scope, so the fresh-programs fixture can't
-    touch it) — each warmup costs several jit compiles on CPU."""
-    gm = _LM_CACHE.get("gm")
-    if gm is None:
-        gm = serving.GenerativeModel(
-            "lm_shared", T.build_decoder_lm_programs(**_LM_CFG),
-            serving.BucketPolicy((2, 4)))
-        gm.warmup()
-        _LM_CACHE["gm"] = gm
-    return gm
-
-
 def _shared_slot_lm():
-    """One warmed SlotGenerativeModel over the SAME config + seed as
-    :func:`_shared_lm` (identical weights), with a prompt bucket ladder
-    — the in-flight engine the parity tests drive against the wave
-    oracle."""
+    """One warmed SlotGenerativeModel shared by the slot tests (explicit
+    Programs + a private scope, so the fresh-programs fixture can't
+    touch it — each warmup costs several jit compiles on CPU), with a
+    prompt bucket ladder; its family carries the ``full`` view, so the
+    greedy oracle (``full_forward_generate``) reads the same scope."""
     sgm = _LM_CACHE.get("sgm")
     if sgm is None:
         sgm = serving.make_slot_model(
             "lm_slot_shared",
             T.build_decoder_lm_programs(
                 **_LM_CFG, prompt_buckets=(4, 8),
-                modes=T.slot_modes(), n_slots=4))
-        sgm.warmup()
+                modes=("full",) + T.slot_modes(), n_slots=4))
+        _LM_CACHE["sgm_warmup"] = sgm.warmup()
         _LM_CACHE["sgm"] = sgm
     return sgm
+
+
+def _oracle(sgm, prompts, budgets):
+    """Per-request greedy oracle tokens off the engine's own weights."""
+    return [sgm.full_forward_generate([p], max_new=m)[0]
+            for p, m in zip(prompts, budgets)]
 
 
 def _counter_value(family, **labels):
@@ -272,96 +265,118 @@ def test_predictor_multi_signature_aot(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_kv_decode_matches_full_forward_oracle():
-    """Greedy prefill+decode transcript == greedy full-forward-per-token
-    transcript over the same weights (full-length prompts, so the two
-    paths see identical sequences)."""
-    gm = _shared_lm()
+    """Greedy prefill+decode transcript of the slot engine == greedy
+    full-forward-per-token transcript over the same weights (full-length
+    prompts, so the two paths see identical sequences)."""
+    sgm = _shared_slot_lm()
+    sgm.reset()
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, 32, (8,)) for _ in range(4)]
-    kv = gm.generate(prompts, max_new=8)
-    ref = gm.full_forward_generate(prompts, max_new=8)
+    kv = sgm.generate(prompts, max_new=8)
+    ref = sgm.full_forward_generate(prompts, max_new=8)
     for a, b in zip(kv, ref):
         np.testing.assert_array_equal(a, b)
 
 
 def test_kv_decode_bucket_invariance():
     """Short prompts padded into a LARGER prompt bucket generate the
-    same tokens — the per-row seq_len mask keeps pad slots out of
-    attention and the positional encoding uses semantic positions."""
+    same tokens — the per-row seq_len mask keeps pad rows out of
+    attention and the positional encoding uses semantic positions: the
+    ladder engine lands them on bucket 4, a single-bucket engine over
+    the same weights (same cfg + seed) on bucket 8."""
     rng = np.random.RandomState(4)
-    raw = [rng.randint(1, 32, (l,)) for l in (3, 5, 4)]
-
-    def run(prompt_len):
-        if prompt_len == _LM_CFG["prompt_len"]:
-            gm = _shared_lm()       # same cfg + seed -> same weights
-        else:
-            cfg = dict(_LM_CFG, prompt_len=prompt_len)
-            gm = serving.GenerativeModel(
-                f"lm_bucket{prompt_len}",
-                T.build_decoder_lm_programs(**cfg),
-                serving.BucketPolicy((4,)))
-            gm.warmup()
-        return np.stack(gm.generate(raw, max_new=6))
-
-    np.testing.assert_array_equal(run(5), run(8))
+    raw = [rng.randint(1, 32, (l,)) for l in (3, 4, 2)]
+    ladder = _shared_slot_lm()
+    ladder.reset()
+    assert {ladder.prompt_bucket_for(len(p)) for p in raw} == {4}
+    single = serving.make_slot_model(
+        "lm_bucket8", T.build_decoder_lm_programs(
+            **_LM_CFG, modes=T.slot_modes(), n_slots=4))
+    single.warmup()
+    assert single.prompt_buckets == (8,)
+    np.testing.assert_array_equal(
+        np.stack(ladder.generate(raw, max_new=6)),
+        np.stack(single.generate(raw, max_new=6)))
 
 
 def test_decode_cost_flat_in_position():
     """analyzed_flops of the decode executable is independent of how
     many tokens were already emitted (static shapes — the SAME
-    executable serves step 0 and step 63), and the decode step is >=5x
-    cheaper than one full forward at the serving sequence length."""
-    gm = _shared_lm()
-    f0 = gm.decode_flops(bucket=2, step=0)
-    f_late = gm.decode_flops(bucket=2, step=7)
+    executable serves the first step and the last), and the decode step
+    is >=5x cheaper than one full forward at the serving sequence
+    length over as many rows."""
+    sgm = _shared_slot_lm()
+    sgm.reset()
+    rng = np.random.RandomState(8)
+    for _ in range(sgm.n_slots):
+        sgm.admit(rng.randint(1, 32, (8,)), max_new=8)
+    f0 = sgm._cb_decode.analyzed_flops(sgm.scope, sgm._decode_feeds())
+    for _ in range(6):
+        sgm.step()
+    assert sgm.active_count() == sgm.n_slots
+    f_late = sgm._cb_decode.analyzed_flops(sgm.scope, sgm._decode_feeds())
+    sgm.reset()
     assert f0 is not None
     assert f0 == f_late          # position-free by construction
-    full = gm.full_forward_flops(2)
+    full = sgm.full_forward_flops(sgm.n_slots)
     assert full is not None
     assert full / f0 >= 5.0, (full, f0)
 
 
 def test_generate_rejects_overlong_prompt_and_budget():
-    gm = _shared_lm()
+    sgm = _shared_slot_lm()
+    sgm.reset()
     with pytest.raises(serving.PromptTooLongError):
-        gm.generate([np.arange(1, 12)], max_new=2)   # 11 > bucket 8
+        sgm.generate([np.arange(1, 12)], max_new=2)   # 11 > bucket 8
     with pytest.raises(ValueError):
-        gm.generate([np.arange(1, 5)], max_new=99)   # > cache budget
+        sgm.generate([np.arange(1, 5)], max_new=99)   # > cache budget
+    # a refused admission holds nothing
+    assert sgm.active_count() == 0
+    assert sgm.free_pages() == sgm.n_pages
 
 
-def test_generative_aot_roundtrip(tmp_path):
-    """warmup(aot_dir) persists the (prefill, decode) executables; a
-    second engine over the same programs loads them — zero compiles —
-    and generates the identical transcript."""
-    progs = T.build_decoder_lm_programs(**_LM_CFG)
+@pytest.mark.parametrize("spec", [False, True])
+def test_generative_aot_roundtrip(tmp_path, spec):
+    """warmup(aot_dir) persists every view's executable (the prefill
+    ladder, the decode step, the verify step where the family has one);
+    a second engine over the same programs loads them FOR ITS OWN
+    DEVICE — zero compiles, no fallback to the compile path under the
+    suite's eight host devices — and generates the identical
+    transcript."""
+    progs = T.build_decoder_lm_programs(
+        **_LM_CFG, prompt_buckets=(4, 8), modes=T.slot_modes(spec=spec),
+        n_slots=2, **({"spec_k": 2} if spec else {}))
+    n_views = 4 if spec else 3
     d = str(tmp_path)
-    gm = serving.GenerativeModel("lm_aot_a", progs,
-                                 serving.BucketPolicy((2,)))
-    r1 = gm.warmup(aot_dir=d)
-    if r1["compiled"] and not os.listdir(d):
-        pytest.skip("executable serialization unsupported here")
-    prompts = [np.arange(1, 7), np.arange(3, 9)]
-    ref = gm.generate(prompts, max_new=5)
+    a = serving.make_slot_model("lm_aot_a", progs)
+    assert a.warmup(aot_dir=d) == {"loaded": 0, "compiled": n_views}
+    assert len([f for f in os.listdir(d) if f.endswith(".pax")]) == n_views
+    prompts = [np.arange(1, 7), np.arange(3, 6)]
+    ref = a.generate(prompts, max_new=5)
 
-    gm2 = serving.GenerativeModel("lm_aot_b", progs,
-                                  serving.BucketPolicy((2,)))
-    r2 = gm2.warmup(aot_dir=d)
-    assert r2 == {"loaded": 2, "compiled": 0}
+    b = serving.make_slot_model("lm_aot_b", progs)
+    fallback = smetrics.AOT_FALLBACK.labels(model="lm_aot_b",
+                                            cause="backend_error")
+    before = fallback.value
+    assert b.warmup(aot_dir=d) == {"loaded": n_views, "compiled": 0}
     with serving.forbid_compiles():
-        out = gm2.generate(prompts, max_new=5)
-    for a, b in zip(ref, out):
-        np.testing.assert_array_equal(a, b)
+        out = b.generate(prompts, max_new=5)
+    for x, y in zip(ref, out):
+        np.testing.assert_array_equal(x, y)
+    assert fallback.value == before
+    assert len(b._aot) == n_views        # none was dropped for the jit's
 
 
 def test_generative_steady_state_zero_compiles():
-    gm = _shared_lm()
+    sgm = _shared_slot_lm()
+    sgm.reset()
     rng = np.random.RandomState(5)
     before = sum(c.value for c in
                  smetrics.COMPILATIONS.children().values())
     with serving.forbid_compiles():
-        for n in (1, 2, 3, 4, 2):
-            gm.generate([rng.randint(1, 32, (6,)) for _ in range(n)],
-                        max_new=4)
+        for n in (1, 2, 3, 4, 6):          # 6 > n_slots: two queue
+            sgm.generate([rng.randint(1, 32, (int(rng.randint(2, 9)),))
+                          for _ in range(n)], max_new=4)
     after = sum(c.value for c in
                 smetrics.COMPILATIONS.children().values())
     assert after == before
@@ -463,23 +478,29 @@ def test_serving_metrics_on_scrape_endpoint(tmp_path):
 def test_rpc_roundtrip(tmp_path):
     d = _clf_model_dir(tmp_path)
     sm = serving.ServedModel("clf_rpc", d, serving.BucketPolicy((2,)))
-    gm = _shared_lm()
+    sgm = _shared_slot_lm()
     server = serving.ModelServer()
     server.add_model(sm)
-    server.add_model(gm)
+    server.add_model(sgm)        # already warmed: warmup() resets only
     endpoint = server.serve()
     client = serving.ServingClient(endpoint)
     try:
         assert client.ping()
-        assert client.models() == ["clf_rpc", "lm_shared"]
+        assert client.models() == ["clf_rpc", "lm_slot_shared"]
         rng = np.random.RandomState(7)
         x = rng.rand(2, 8).astype(np.float32)
         (out,) = client.infer("clf_rpc", {"x": x})
         (ref,) = sm.infer({"x": x})
         np.testing.assert_allclose(out, ref, rtol=1e-6)
-        toks = client.generate("lm_shared", [list(range(1, 7))],
+        toks = client.generate("lm_slot_shared", [list(range(1, 7))],
                                max_new=4)
         assert toks[0].shape == (4,)
+        np.testing.assert_array_equal(
+            toks[0], sgm.full_forward_generate(
+                [np.arange(1, 7)], max_new=4)[0])
+        # a model that does not generate refuses by name
+        with pytest.raises(ValueError, match="does not generate"):
+            server.submit_generate("clf_rpc", [[1, 2, 3]], max_new=2)
         # typed rejection crosses the wire
         with pytest.raises(serving.ModelNotFoundError):
             client.infer("missing", {"x": x})
@@ -498,16 +519,15 @@ def test_slot_scheduler_greedy_parity_random_arrivals():
     """ACCEPTANCE: tokens produced by the slot scheduler under a
     randomized join/leave interleaving (random arrival order, random
     admission counts, mixed budgets and prompt lengths across the
-    prompt-bucket ladder) equal per-request sequential generate()
-    output — and the whole churn runs under forbid_compiles."""
-    gm, sgm = _shared_lm(), _shared_slot_lm()
+    prompt-bucket ladder) equal the per-request full-forward oracle
+    — and the whole churn runs under forbid_compiles."""
+    sgm = _shared_slot_lm()
     rng = np.random.RandomState(11)
     n_req = 10
     prompts = [rng.randint(1, 32, (int(rng.randint(3, 9)),))
                for _ in range(n_req)]
     budgets = [int(rng.randint(2, 9)) for _ in range(n_req)]
-    oracle = [gm.generate([p], max_new=m)[0]
-              for p, m in zip(prompts, budgets)]
+    oracle = _oracle(sgm, prompts, budgets)
 
     order = list(rng.permutation(n_req))       # randomized arrivals
     collected, results, slot2idx = {}, {}, {}
@@ -545,15 +565,14 @@ def test_slot_server_concurrent_join_leave_parity():
     with mixed budgets (plus one EOS early-leave) each come back equal
     to the sequential oracle, with ZERO compiles through the whole
     join/leave churn."""
-    gm, sgm = _shared_lm(), _shared_slot_lm()
+    sgm = _shared_slot_lm()
     server = serving.ModelServer()
     server.add_model(sgm)        # already warmed: warmup() is a no-op
     rng = np.random.RandomState(12)
     prompts = [rng.randint(1, 32, (int(rng.randint(3, 9)),))
                for _ in range(8)]
     budgets = [int(rng.randint(2, 9)) for _ in range(8)]
-    oracle = [gm.generate([p], max_new=m)[0]
-              for p, m in zip(prompts, budgets)]
+    oracle = _oracle(sgm, prompts, budgets)
     try:
         with serving.forbid_compiles():
             futs = []
@@ -580,13 +599,14 @@ def test_slot_server_concurrent_join_leave_parity():
 
 def test_on_device_sampling_parity_and_restart_reproducibility():
     """Sampling satellite: temperature=0 and top_k=1 both bit-match the
-    greedy wave oracle; a seeded sampled stream replays identically on a
+    greedy oracle; a seeded sampled stream replays identically on a
     FRESH engine over freshly built programs (the server-restart
     scenario); different seeds diverge."""
-    gm, sgm = _shared_lm(), _shared_slot_lm()
+    sgm = _shared_slot_lm()
+    sgm.reset()
     rng = np.random.RandomState(13)
     prompts = [rng.randint(1, 32, (6,)) for _ in range(3)]
-    greedy = [gm.generate([p], max_new=8)[0] for p in prompts]
+    greedy = sgm.full_forward_generate(prompts, max_new=8)
     for kwargs in (dict(temperature=0.0),
                    dict(temperature=0.9, top_k=1)):
         got = sgm.generate(prompts, max_new=8, **kwargs)
@@ -699,30 +719,29 @@ def test_sampling_steps_counter_counts_the_steps_that_sampled(ahead):
 
 
 def test_prompt_bucket_ladder_parity_and_cost():
-    """Prompt-ladder satellite: a GenerativeModel warmed over a bucket
-    ladder generates the same tokens as the single-bucket engine, and
-    short prompts prefill on the SMALL bucket's executable (strictly
-    fewer flops than worst-case prefill)."""
-    gm = _shared_lm()
-    gml = serving.GenerativeModel(
-        "lm_ladder",
-        T.build_decoder_lm_programs(**_LM_CFG, prompt_buckets=(4, 8)),
-        serving.BucketPolicy((2,)))
-    r = gml.warmup()
-    assert r["compiled"] == 3          # prefill@4, prefill@8, decode
+    """Prompt-ladder satellite: an engine warmed over a bucket ladder
+    compiled one prefill per bucket beside the decode step, generates
+    the oracle's tokens whichever bucket a prompt lands on, and short
+    prompts prefill on the SMALL bucket's executable (strictly fewer
+    flops than worst-case prefill)."""
+    sgm = _shared_slot_lm()
+    sgm.reset()
+    # prefill_paged@4, prefill_paged@8, decode_paged
+    assert _LM_CACHE["sgm_warmup"] == {"loaded": 0, "compiled": 3}
     rng = np.random.RandomState(14)
-    short = [rng.randint(1, 32, (3,)), rng.randint(1, 32, (4,))]
-    ref = gm.generate(short, max_new=6)
+    mixed = [rng.randint(1, 32, (3,)), rng.randint(1, 32, (4,)),
+             rng.randint(1, 32, (7,))]
+    assert [sgm.prompt_bucket_for(len(p)) for p in mixed] == [4, 4, 8]
+    ref = sgm.full_forward_generate(mixed, max_new=6)
     with serving.forbid_compiles():
-        out = gml.generate(short, max_new=6)
+        out = sgm.generate(mixed, max_new=6)
     for a, b in zip(out, ref):
         np.testing.assert_array_equal(a, b)
-    f4 = gml._cb_prefill[4].analyzed_flops(
-        gml.scope, gml._prefill_feeds(2, 4))
-    f8 = gml._cb_prefill[8].analyzed_flops(
-        gml.scope, gml._prefill_feeds(2, 8))
-    if f4 and f8:
-        assert f4 < f8
+    f4 = sgm._cb_prefill[4].analyzed_flops(sgm.scope,
+                                           sgm._prefill_feeds(4))
+    f8 = sgm._cb_prefill[8].analyzed_flops(sgm.scope,
+                                           sgm._prefill_feeds(8))
+    assert f4 and f8 and f4 < f8
 
 
 def test_slot_metrics_on_scrape_endpoint():
@@ -827,20 +846,21 @@ def test_load_mixed_shapes_and_decode_speedup(tmp_path):
     # with a conservative floor keeps CI deterministic)
     progs = T.build_decoder_lm_programs(
         prompt_len=32, max_new=32, vocab=128, d_model=64, d_inner=256,
-        n_head=4, n_layer=2)
-    gm = serving.GenerativeModel("lm_speed", progs,
-                                 serving.BucketPolicy((4,)))
-    gm.warmup()
+        n_head=4, n_layer=2, modes=("full",) + T.slot_modes(), n_slots=4)
+    sgm = serving.make_slot_model("lm_speed", progs)
+    sgm.warmup()
     rng = np.random.RandomState(9)
     prompts = [rng.randint(1, 128, (32,)) for _ in range(4)]
-    gm.full_forward_generate(prompts, max_new=2)   # warm baseline jit
+    sgm.full_forward_generate(prompts, max_new=2)   # warm baseline jit
     t0 = time.perf_counter()
-    ref = gm.full_forward_generate(prompts, max_new=32)
+    ref = sgm.full_forward_generate(prompts, max_new=32)
     base_s = time.perf_counter() - t0
     with serving.forbid_compiles():
         t0 = time.perf_counter()
-        kv = gm.generate(prompts, max_new=32)
+        kv = sgm.generate(prompts, max_new=32)
         kv_s = time.perf_counter() - t0
     for a, b in zip(kv, ref):
         np.testing.assert_array_equal(a, b)
-    assert base_s / kv_s >= 3.0, (base_s, kv_s)
+    # four batch-1 prefills and 31 four-row steps against 32 forwards
+    # of [4, 64]: ~3x here
+    assert base_s / kv_s >= 2.0, (base_s, kv_s)

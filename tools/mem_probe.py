@@ -120,29 +120,6 @@ def probe_model(name, batch=DEFAULT_BATCH):
     return row
 
 
-def probe_serving_decode():
-    """Donation audit of the serving KV-cache decode executable at a
-    tiny decoder_lm config — the acceptance gate's 'transformer decode'
-    program."""
-    from paddle_tpu.models.transformer import build_decoder_lm_programs
-    import proglint
-
-    progs = build_decoder_lm_programs(
-        prompt_len=8, max_new=8, vocab=64, d_model=32, d_inner=64,
-        n_head=2, n_layer=2, modes=("decode",))
-    main, startup, feed_specs, _fetch = progs["decode"]
-    audit = proglint._memory_audit("decoder_lm.decode", main, startup,
-                                   sorted(feed_specs))
-    return {
-        "program": "decoder_lm.decode",
-        "expected": len(audit.get("expected") or []),
-        "aliased": len(audit.get("aliased") or []),
-        "violations": audit.get("violations") or [],
-        "skipped": audit.get("skipped") or [],
-        **({"error": audit["error"]} if audit.get("error") else {}),
-    }
-
-
 def probe_serving_decode_paged():
     """Donation audit + census classification of the PAGED decode
     executable (ISSUE 17): the shared ``*_page_k/v_*`` pools must keep
@@ -203,7 +180,7 @@ def main(argv=None):
     failures = 0
     doc = {"metric": "compiled peak-HBM vs static estimator (zoo, "
                      "default configs)",
-           "batch_size": args.batch_size, "models": {}, "serving": None,
+           "batch_size": args.batch_size, "models": {},
            "serving_paged": None}
     probed = {}
     for name in names:
@@ -229,20 +206,6 @@ def main(argv=None):
               f"[{row['estimate']['total_low']}, "
               f"{row['estimate']['total_high']}] B, "
               f"{bad} donation violation(s) ({row['probe_s']}s)")
-
-    try:
-        doc["serving"] = probe_serving_decode()
-        sbad = doc["serving"]["violations"] or doc["serving"].get("error")
-        if sbad:
-            failures += 1
-        print(f"[{'FAIL' if sbad else 'ok'}] decoder_lm.decode: "
-              f"{doc['serving']['aliased']}/{doc['serving']['expected']} "
-              f"state buffers aliased, "
-              f"{len(doc['serving']['violations'])} violation(s)")
-    except Exception as e:
-        doc["serving"] = {"error": str(e)[:200]}
-        failures += 1
-        print(f"[FAIL] decoder_lm.decode: {e}")
 
     try:
         doc["serving_paged"] = probe_serving_decode_paged()

@@ -34,8 +34,8 @@ import sys
 # builds; the full zoo is covered by tests/test_analysis.py)
 LINT_MODELS = ("mnist", "smallnet")
 
-# the serving programs (prefill + KV-cache decode, wave AND paged
-# slot-pool views) are linted in is-test mode via `proglint --all`, which
+# the serving programs (the full view and the paged slot-pool views)
+# are linted in is-test mode via `proglint --all`, which
 # auto-discovers every serve_lint_* entry of models/transformer — a new
 # serving view only needs a serve_lint_ function to join the gate, not
 # an edit here (ISSUE 8/9; docs/serving.md)
@@ -138,16 +138,7 @@ def run_lint_gate(root: str, timeout: int, ci: bool = False) -> int:
              "--smoke"], cwd=root, timeout=timeout, env=env)
         if r.returncode:
             return r.returncode
-        print("test_runner: lint gate — proglint --memory over the "
-              "serving decode program")
-        r = subprocess.run(
-            [sys.executable, os.path.join(root, "tools", "proglint.py"),
-             "--memory", "--is-test", "--module",
-             "paddle_tpu.models.transformer:serve_lint_decode"],
-            cwd=root, timeout=timeout, env=env)
-        if r.returncode:
-            return r.returncode
-        # same donation audit over the PAGED decode program — the shared
+        # the donation audit over the PAGED decode program — the shared
         # page pool (and the int8 scale planes, when configured) must
         # keep aliasing in input_output_alias across the page-table
         # gather/scatter rewrite (ISSUE 17; docs/serving.md "Paged KV
