@@ -471,11 +471,14 @@ class CompiledBlock:
         (``device_scopes.module_name``) and the profiler's "XLA Modules"
         line names the program's blocks. A scan of N steps is another
         executable than the single step and gets another name, so that
-        an instruction name means one thing under each."""
+        an instruction name means one thing under each: ``_x<N>`` at the
+        END of it, behind the digest of a block that holds phased ops
+        (the trace readers' step pattern is ``jit_\\w+_x\\d+``)."""
         name = _device_scopes.module_name(
-            self.obs_label + (f"_x{iterations}" if iterations > 1 else ""),
+            self.obs_label,
             (key for b in self._program_desc.blocks for op in b.ops
-             for key in _device_scopes.scope_keys(op)))
+             for key in _device_scopes.scope_keys(op))) \
+            + (f"_x{iterations}" if iterations > 1 else "")
         fn.__name__ = fn.__qualname__ = name
         jitted = self._exes.jitted[(iterations, snames)] = jax.jit(
             fn, **jit_kwargs)

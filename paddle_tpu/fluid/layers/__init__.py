@@ -14,7 +14,7 @@ from paddle_tpu.fluid.layers.nn import (  # noqa: F401
     fused_linear_cross_entropy, fused_multi_head_attention,
     kv_attention_prefill_paged, kv_attention_decode_paged,
     kv_attention_verify_paged, rms_norm, dense, kda, ssd, expert_ffn_held,
-    swiglu_ffn, mla,
+    swiglu_ffn, mla, mla_full, router_bias_update,
     token_sample,
     gather, hsigmoid, huber_loss, l2_normalize, label_smooth, layer_norm,
     linear_chain_crf, log, matmul, mean, mul, nce, one_hot, pool2d,

@@ -884,7 +884,7 @@ def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
                     init, held_start=0, n_shared=1, norm_topk=True,
                     scaling=1.0, valid=None, seq_len=None, counts=None,
                     name=None, router_bias=False, d_shared=None,
-                    scoring="sigmoid"):
+                    scoring="sigmoid", load=False):
     """One expert-parallel member's share of a top-k routed expert layer
     plus the shared expert (ops/expert_ffn.py): the router is
     ``n_experts`` wide, the ``n_held`` experts from ``held_start`` are
@@ -897,7 +897,11 @@ def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
     ``d_shared`` is the shared expert's own width (``n_shared *
     d_expert`` when None); ``scoring`` the router's: ``"sigmoid"``
     scores over every expert, or ``"softmax_topk"`` — the best ``top_k``
-    by logit, weighed by a softmax over those logits alone."""
+    by logit, weighed by a softmax over those logits alone. With
+    ``load`` (a trainer's layer) the result is (out, the step's picks
+    per expert over the whole router [n_experts] int32) and the
+    correction bias is no trainable parameter: no gradient reaches it,
+    ``router_bias_update`` is its whole update."""
     from paddle_tpu.fluid.param_attr import ParamAttr
     helper = LayerHelper("expert_ffn_held", name=name)
     shared = n_shared * d_expert if d_shared is None else int(d_shared)
@@ -914,9 +918,12 @@ def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
             ParamAttr(name=f"{base}.{tag}", initializer=init),
             shape=shape, dtype=x.dtype)]
     if router_bias:
-        inputs["RouterBias"] = [helper.create_parameter(
-            ParamAttr(name=f"{base}.router_bias", initializer=init),
-            shape=[1, n_experts], dtype="float32")]
+        bias = helper.create_parameter(
+            ParamAttr(name=f"{base}.router_bias", initializer=init,
+                      trainable=not load),
+            shape=[1, n_experts], dtype="float32")
+        bias.stop_gradient = bool(load)
+        inputs["RouterBias"] = [bias]
     outputs = {"Out": [helper.create_variable_for_type_inference(x.dtype)]}
     if valid is not None:
         inputs["Valid"] = [valid]
@@ -930,9 +937,42 @@ def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
         # set only where it differs: the sigmoid routers' programs stay
         # what they were
         attrs["scoring"] = str(scoring)
+    if load:
+        attrs["load"] = True
+        outputs["Load"] = [helper.create_variable_for_type_inference("int32")]
+        outputs["Load"][0].stop_gradient = True
     helper.append_op("expert_ffn_held", inputs=inputs, outputs=outputs,
                      attrs=attrs)
+    if load:
+        return outputs["Out"][0], outputs["Load"][0]
     return outputs["Out"][0]
+
+
+def router_bias_update(bias, load, gamma, total_name=None, name=None):
+    """After a step, move a router's correction ``bias`` [1, E] (in
+    place) by ``gamma`` toward the experts that the step's ``load`` [E]
+    gave fewer picks than the mean (ops/expert_ffn.py:
+    ``router_bias_update``). With ``total_name`` a persistable [E] int32
+    of that name accumulates the loads (what a load metric reads), and
+    is returned."""
+    from paddle_tpu.fluid.initializer import ConstantInitializer
+    helper = LayerHelper("router_bias_update", name=name)
+    inputs = {"Bias": [bias], "Load": [load]}
+    outputs = {"BiasOut": [bias]}
+    total = None
+    if total_name:
+        shape = [int(bias.shape[-1])]
+        total = helper.main_program.global_block().create_var(
+            name=total_name, shape=shape, dtype="int32", persistable=True,
+            stop_gradient=True)
+        startup = helper.startup_program.global_block()
+        ConstantInitializer(0)(startup.create_var(
+            name=total_name, shape=shape, dtype="int32", persistable=True),
+            startup)
+        inputs["LoadTotal"], outputs["LoadTotalOut"] = [total], [total]
+    helper.append_op("router_bias_update", inputs=inputs, outputs=outputs,
+                     attrs={"gamma": float(gamma)})
+    return total
 
 
 def swiglu_ffn(x, d_model, d_inner, base, init, name=None):
@@ -954,6 +994,60 @@ def swiglu_ffn(x, d_model, d_inner, base, init, name=None):
     return out
 
 
+def _mla_weights(helper, x, d_model, sizes, base, init, indexer):
+    """{op slot: [parameter]} of one latent attention layer, named
+    ``<base>.<tag>`` (``init`` draws every matrix; gains start at 1):
+    the eight of the attention itself and, with ``indexer``, the DSA
+    indexer's five."""
+    from paddle_tpu.fluid.initializer import ConstantInitializer
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    h, ql, dc = sizes["n_head"], sizes["q_lora_rank"], sizes["kv_lora_rank"]
+    dn, dr, dv = (sizes["qk_nope_head_dim"], sizes["qk_rope_head_dim"],
+                  sizes["v_head_dim"])
+    # tag: (the op's slot, shape, a fixed start or None: drawn)
+    table = {
+        "wdq": ("Wdq", [d_model, ql], None),
+        "q_norm": ("QNorm", [ql], 1.0),
+        "wuq": ("Wuq", [ql, h * (dn + dr)], None),
+        "wdkv": ("Wdkv", [d_model, dc + dr], None),
+        "kv_norm": ("KvNorm", [dc], 1.0),
+        "wuk": ("Wuk", [dc, h * dn], None),
+        "wuv": ("Wuv", [dc, h * dv], None),
+        "wo": ("Wo", [h * dv, d_model], None)}
+    if indexer:
+        j, di = sizes["index_n_heads"], sizes["index_head_dim"]
+        table.update({
+            "wiq": ("Wiq", [ql, j * di], None),
+            "wik": ("Wik", [d_model, di], None),
+            "ik_scale": ("IkScale", [di], 1.0),
+            "ik_bias": ("IkBias", [di], 0.0),
+            "wiw": ("Wiw", [d_model, j], None)})
+    return {slot: [helper.create_parameter(
+        ParamAttr(name=f"{base}.{tag}",
+                  initializer=init if fixed is None
+                  else ConstantInitializer(fixed)),
+        shape=shape, dtype=x.dtype)]
+        for tag, (slot, shape, fixed) in table.items()}
+
+
+def mla_full(x, d_model, sizes, base, init, rope_theta, epsilon=1e-5,
+             name=None):
+    """One multi-head latent attention layer WITHOUT an indexer over
+    whole sequences x [B, T, M], causal (ops/mla.py: ``mla_full``; it
+    has a gradient). ``sizes``: n_head, q_lora_rank, kv_lora_rank,
+    qk_nope_head_dim, qk_rope_head_dim, v_head_dim. Its weights carry
+    the names :func:`mla` gives the same matrices."""
+    helper = LayerHelper("mla_full", name=name)
+    inputs = {"X": [x], **_mla_weights(helper, x, d_model, sizes, base,
+                                       init, indexer=False)}
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("mla_full", inputs=inputs, outputs={"Out": [out]},
+                     attrs={**{k: int(v) for k, v in sizes.items()},
+                            "rope_theta": float(rope_theta),
+                            "epsilon": float(epsilon)})
+    return out
+
+
 def mla(x, page_c, page_i, d_model, sizes, base, init, rope_theta,
         epsilon=1e-5, rows=None, decode=None, selected_name=None,
         name=None):
@@ -967,36 +1061,11 @@ def mla(x, page_c, page_i, d_model, sizes, base, init, rope_theta,
     [1, T, M]; with ``decode`` (page_table, pos, seq_len, gen_start,
     active, position) the step of every slot, x [n_slots, 1, M], whose
     attended rows land in a variable named ``selected_name``."""
-    from paddle_tpu.fluid.initializer import ConstantInitializer
-    from paddle_tpu.fluid.param_attr import ParamAttr
     op = "mla_prefill_paged" if rows is not None else "mla_decode_paged"
     helper = LayerHelper(op, name=name)
-    h, ql, dc = sizes["n_head"], sizes["q_lora_rank"], sizes["kv_lora_rank"]
-    dn, dr, dv = (sizes["qk_nope_head_dim"], sizes["qk_rope_head_dim"],
-                  sizes["v_head_dim"])
-    j, di = sizes["index_n_heads"], sizes["index_head_dim"]
-    # tag: (the op's slot, shape, a fixed start or None: drawn)
-    table = {
-        "wdq": ("Wdq", [d_model, ql], None),
-        "q_norm": ("QNorm", [ql], 1.0),
-        "wuq": ("Wuq", [ql, h * (dn + dr)], None),
-        "wdkv": ("Wdkv", [d_model, dc + dr], None),
-        "kv_norm": ("KvNorm", [dc], 1.0),
-        "wuk": ("Wuk", [dc, h * dn], None),
-        "wuv": ("Wuv", [dc, h * dv], None),
-        "wo": ("Wo", [h * dv, d_model], None),
-        "wiq": ("Wiq", [ql, j * di], None),
-        "wik": ("Wik", [d_model, di], None),
-        "ik_scale": ("IkScale", [di], 1.0),
-        "ik_bias": ("IkBias", [di], 0.0),
-        "wiw": ("Wiw", [d_model, j], None)}
-    inputs = {"X": [x], "PageC": [page_c], "PageI": [page_i]}
-    for tag, (slot, shape, fixed) in table.items():
-        inputs[slot] = [helper.create_parameter(
-            ParamAttr(name=f"{base}.{tag}",
-                      initializer=init if fixed is None
-                      else ConstantInitializer(fixed)),
-            shape=shape, dtype=x.dtype)]
+    inputs = {"X": [x], "PageC": [page_c], "PageI": [page_i],
+              **_mla_weights(helper, x, d_model, sizes, base, init,
+                             indexer=True)}
     out = helper.create_variable_for_type_inference(x.dtype)
     outputs = {"Out": [out], "PageCOut": [page_c], "PageIOut": [page_i]}
     if rows is not None:

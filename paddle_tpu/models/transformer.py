@@ -16,6 +16,7 @@ import numpy as np
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.initializer import NumpyArrayInitializer
+from paddle_tpu.observability import device_scopes as _device_scopes
 from paddle_tpu.ops.kv_attention import window_ring
 
 
@@ -247,6 +248,9 @@ _KIND_KEYS = {
     "mla": ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
             "qk_rope_head_dim", "v_head_dim", "rope_theta",
             "index_n_heads", "index_head_dim", "index_topk")}
+# the DSA indexer's sizes: all three None is a latent layer WITHOUT an
+# indexer (no index plane, no selection: every earlier position attended)
+_INDEXER_KEYS = ("index_n_heads", "index_head_dim", "index_topk")
 
 
 # sizes whose None is a value (the op's own default), not an omission
@@ -270,18 +274,40 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
                          f"{sorted(_KIND_KEYS)}")
     unused = {k for keys in _KIND_KEYS.values() for k in keys} \
         - {k for kind in period for k in _KIND_KEYS[kind]}
+    no_indexer = hy["index_topk"] is None
     missing = sorted(k for k, v in hy.items()
                      if v is None and k not in unused
-                     and k not in _OPTIONAL)
+                     and k not in _OPTIONAL
+                     and not (no_indexer and k in _INDEXER_KEYS))
     if missing:
         raise ValueError(f"decoder_lm: a hybrid block (layer_kinds given) "
                          f"needs {missing} too")
-    if mode not in ("prefill_paged", "decode_paged"):
+    if mode == "full":
+        # whole sequences in, logits out: no pool, no page table. The
+        # view a trainer differentiates and the oracle runs
+        other = sorted(set(period) - {"mla"})
+        if other:
+            raise ValueError(
+                f"decoder_lm mode 'full' with layer kinds {other}: they "
+                f"are served by the slot views prefill_paged and "
+                f"decode_paged alone; of the hybrid block's kinds only "
+                f"'mla' has a full view (ops/mla.py:mla_full)")
+        if not no_indexer:
+            raise ValueError(
+                "decoder_lm mode 'full' with index_topk: the full view "
+                "of a latent layer attends every earlier position; a "
+                "layer with the DSA indexer has none yet")
+    elif mode not in ("prefill_paged", "decode_paged"):
         raise ValueError(
             f"decoder_lm mode {mode!r} with layer_kinds: the hybrid block "
             f"is served by the slot views prefill_paged and decode_paged "
             f"alone (a verify window would have to roll a recurrent state "
             f"back)")
+    elif no_indexer and "mla" in period:
+        raise ValueError(
+            f"decoder_lm mode {mode!r} with 'mla' layers and no "
+            f"index_topk: the paged latent ops select through the DSA "
+            f"indexer; a latent layer without one has the full view alone")
     if hy["attn_scale"] is not None and "swa" in period:
         raise ValueError("attn_scale is read by 'gqa' layers alone: a "
                          "window layer scales by head_dim ** -0.5")
@@ -312,26 +338,68 @@ def hybrid_weight_std(name: str, shape) -> float:
     return (2.0 / (float(shape[-2]) + float(shape[-1]))) ** 0.5
 
 
-def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
-                 pool_var, pools, feeds):
-    """Embedding, the layers and the head of a hybrid slot view; returns
-    the float32 logits, flat: [1, V] at the prompt's true end for the
-    prefill, [n_slots, V] for the decode step. The logits variable is
-    named ``<name>_logits`` so that an engine can be asked to fetch it."""
-    prefill = mode == "prefill_paged"
+class _HybridBlock:
+    """What the hybrid block's views share: the embedding, one layer,
+    the final norm and the head, under the names one scope serves them
+    all by. ``loads`` (a trainer's list) collects, per expert layer with
+    a correction bias, (the bias parameter, the layer's ``Load``
+    output): what ``router_bias_update`` reads after the step."""
+
+    def __init__(self, hy, mode, name, vocab, d_model, d_inner, n_head,
+                 pool_var=None, pools=None, feeds=None, loads=None):
+        self.hy, self.mode, self.name, self.vocab = hy, mode, name, vocab
+        self.d_model, self.d_inner, self.n_head = d_model, d_inner, n_head
+        self.pool_var, self.pools, self.feeds = pool_var, pools, feeds
+        self.loads = loads
+        self.init = _HybridNormal()    # every matrix, by name and shape
+
+    def pa(self, pname, matrix=False):
+        return fluid.ParamAttr(name=f"{self.name}_{pname}",
+                               initializer=self.init if matrix else None)
+
+    def embed(self, ids):
+        hy = self.hy
+        x = layers.embedding(ids, size=[self.vocab, self.d_model],
+                             dtype=hy["dtype"],
+                             param_attr=self.pa("emb", True))
+        if hy["embed_scale"] != 1.0:
+            x = layers.scale(x, scale=float(hy["embed_scale"]))
+        return x
+
+    def logits(self, x, out_name=None):
+        """The final norm and the head over flat rows x [N, M]: float32
+        logits [N, V], in a variable named ``out_name`` where an engine
+        is to fetch them."""
+        hy = self.hy
+        x = layers.rms_norm(x, hy["rms_eps"], self.pa("lnf_scale"))
+        head = {}
+        if hy["tie_embeddings"]:
+            # ONE table, read by the embedding and by the logits
+            head["weight"] = fluid.default_main_program().global_block()\
+                .var(f"{self.name}_emb")
+        if hy["logits_scale"] != 1.0:
+            head["scale"] = 1.0 / float(hy["logits_scale"])
+        return layers.dense(
+            x, self.vocab,
+            None if hy["tie_embeddings"] else self.pa("head_w", True),
+            out_dtype="float32", out_name=out_name, **head)
+
+    def layer(self, x, i, kind, tag=None, dense_ffn=False):
+        """Layer ``i`` of ``kind`` over x: mixer and feed-forward, each
+        behind its norm and joined to the residual. Its weights are
+        named ``<name>_<tag>_...`` (``l<i>`` unless ``tag`` is given: a
+        module beside the stack, the trainer's MTP layer)."""
+        return _hybrid_layer(self, x, i, kind, tag or f"l{i}", dense_ffn)
+
+
+def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
+    hy, mode, name = blk.hy, blk.mode, blk.name
+    d_model, d_inner, n_head = blk.d_model, blk.d_inner, blk.n_head
+    pool_var, pools, feeds, init, pa = (blk.pool_var, blk.pools, blk.feeds,
+                                        blk.init, blk.pa)
+    prefill, full = mode == "prefill_paged", mode == "full"
     dt, eps = hy["dtype"], hy["rms_eps"]
-    n_slots = pools["n_slots"]
-
-    init = _HybridNormal()       # every matrix, by its own name and shape
-
-    def pa(pname, matrix=False):
-        return fluid.ParamAttr(name=f"{name}_{pname}",
-                               initializer=init if matrix else None)
-
-    x = layers.embedding(x_ids, size=[vocab, d_model], dtype=dt,
-                         param_attr=pa("emb", True))
-    if hy["embed_scale"] != 1.0:
-        x = layers.scale(x, scale=float(hy["embed_scale"]))
+    n_slots = None if full else pools["n_slots"]
 
     def join(x, y):
         """x + residual_scale * y."""
@@ -353,130 +421,146 @@ def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
         return {"counts": pool_var(f"{name}_moe_grouped_{i}",
                                    [2, hy["n_experts_held"]], "int32")}
 
+    y = layers.rms_norm(x, eps, pa(f"{tag}_ln1_scale"))
+    if full:
+        sizes = {k: hy[k] for k in _KIND_KEYS["mla"]
+                 if k != "rope_theta" and k not in _INDEXER_KEYS}
+        sizes["n_head"] = n_head
+        y = layers.mla_full(y, d_model, sizes, f"{name}_{tag}_mla", init,
+                            hy["rope_theta"], eps)
+    elif kind in ("gqa", "swa"):
+        # a window layer's pools are its group's (fewer pages,
+        # another table: serving/kv_pool.py "Window group")
+        swa = kind == "swa"
+        d = hy["head_dim"]
+        plane = "page_w" if swa else "page_"
+        shape = [pools["window_pages"] if swa else pools["shape"][0],
+                 pools["shape"][1]]
+        pshape = shape + [hy["n_kv_head"] * d]
+        pk = pool_var(f"{name}_{plane}k_{i}", pshape, pools["dtype"])
+        pv = pool_var(f"{name}_{plane}v_{i}", pshape, pools["dtype"])
+        pks = pvs = None
+        if pools["codec"] == "int8":
+            sshape = shape + [hy["n_kv_head"]]
+            pks = pool_var(f"{name}_{plane}ks_{i}", sshape)
+            pvs = pool_var(f"{name}_{plane}vs_{i}", sshape)
+        gqa = dict(n_kv_head=hy["n_kv_head"], head_dim=d,
+                   gate=hy["gqa_gate"], qk_norm=hy["qk_norm"],
+                   rms_eps=eps, attn_scale=hy["attn_scale"])
+        if swa:
+            gqa.update(window=hy["window"], rope_theta=hy["rope_theta"],
+                       attended_name=f"{name}_l{i}_attn_attended")
+        attr = pa(f"l{i}_attn", True)    # the base of its names
+        if prefill:
+            y = layers.kv_attention_prefill_paged(
+                y, feeds["page_rows_w" if swa else "page_rows"],
+                d_model, n_head, pk, pv, pks, pvs,
+                codec=pools["codec"], param_attr=attr, gqa=gqa)
+        else:
+            y = layers.kv_attention_decode_paged(
+                y, feeds["page_table_w" if swa else "page_table"],
+                feeds["pos"], feeds["seq_len"],
+                feeds["gen_start"], feeds["active"], d_model, n_head,
+                pk, pv, pks, pvs, codec=pools["codec"],
+                param_attr=attr, gqa=gqa)
+    elif kind == "mla":
+        from paddle_tpu.ops.mla import latent_width
+        sizes = {k: hy[k] for k in _KIND_KEYS["mla"]
+                 if k != "rope_theta"}
+        sizes["n_head"] = n_head
+        wide = latent_width(hy["kv_lora_rank"], hy["qk_rope_head_dim"])
+        pc = pool_var(f"{name}_page_c_{i}", pools["shape"] + [wide],
+                      pools["dtype"])
+        pi = pool_var(f"{name}_page_i_{i}",
+                      pools["shape"] + [hy["index_head_dim"]],
+                      pools["dtype"])
+        y = layers.mla(
+            y, pc, pi, d_model, sizes, f"{name}_l{i}_mla", init,
+            hy["rope_theta"], eps,
+            **(dict(rows=feeds["page_rows"]) if prefill else dict(
+                decode=[feeds[k] for k in (
+                    "page_table", "pos", "seq_len", "gen_start",
+                    "active", "position")],
+                selected_name=f"{name}_l{i}_mla_selected")))
+    elif kind == "ssd":
+        sizes = {k: hy[k] for k in _HYBRID_KEYS if k.startswith("ssd_")}
+        inner = hy["ssd_heads"] * hy["ssd_head_dim"]
+        state = pool_var(f"{name}_ssd_state_{i}",
+                         [n_slots, hy["ssd_d_state"], inner])
+        conv = pool_var(
+            f"{name}_ssd_conv_{i}",
+            [n_slots, hy["ssd_conv_taps"] - 1,
+             inner + 2 * hy["ssd_groups"] * hy["ssd_d_state"]], dt)
+        y = layers.ssd(
+            y, state, conv, d_model, sizes, f"{name}_l{i}_ssd", init,
+            eps,
+            **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
+               if prefill else dict(active=feeds["active"])))
+    else:
+        h, d = hy["kda_heads"], hy["kda_head_dim"]
+        state = pool_var(f"{name}_kda_state_{i}", [n_slots, h, d, d])
+        conv = pool_var(f"{name}_kda_conv_{i}",
+                        [n_slots, hy["kda_conv_taps"] - 1, 3 * h * d],
+                        dt)
+        y = layers.kda(
+            y, state, conv, d_model, h, d, f"{name}_l{i}_kda", init,
+            hy["kda_gate_rank"], hy["kda_conv_taps"], eps,
+            **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
+               if prefill else dict(active=feeds["active"])))
+    if hy["post_norms"]:
+        y = layers.rms_norm(y, eps, pa(f"{tag}_ln1_post_scale"))
+    x = join(x, y)
+    y = layers.rms_norm(x, eps, pa(f"{tag}_ln2_scale"))
+    if dense_ffn:
+        y = layers.swiglu_ffn(y, d_model, d_inner, f"{name}_{tag}_ffn",
+                              init)
+    else:
+        if full:
+            # every token is real; a trainer asks for the step's load
+            told = dict(load=blk.loads is not None
+                        and hy["router_bias"])
+        elif prefill:
+            told = dict(seq_len=feeds["seq_len"], **grouped_counts(i))
+        else:
+            told = dict(valid=feeds["active"],
+                        counts=pool_var(f"{name}_moe_counts_{i}",
+                                        [2, hy["n_experts_held"]],
+                                        "int32"))
+        y = layers.expert_ffn_held(
+            y, d_model, hy["d_expert"], hy["n_routed_experts"],
+            hy["n_experts_held"], hy["n_experts_per_tok"],
+            f"{name}_{tag}_moe", init, hy["held_start"],
+            hy["n_shared_experts"],
+            hy["norm_topk_prob"], hy["routed_scaling_factor"],
+            **told,
+            router_bias=hy["router_bias"], d_shared=hy["d_shared"],
+            scoring=hy["scoring"])
+        if isinstance(y, tuple):
+            y, load = y
+            blk.loads.append((f"{name}_{tag}_moe.router_bias", load))
+    if hy["post_norms"]:
+        y = layers.rms_norm(y, eps, pa(f"{tag}_ln2_post_scale"))
+    x = join(x, y)
+    return x
+
+
+def _hybrid_body(hy, mode, x_ids, name, vocab, d_model, d_inner, n_head,
+                 pool_var, pools, feeds):
+    """Embedding, the layers and the head of a hybrid view; returns the
+    float32 logits, flat: [1, V] at the prompt's true end for the
+    prefill, [n_slots, V] for the decode step, [B * T, V] for the full
+    view. The logits variable is named ``<name>_logits`` so that an
+    engine can be asked to fetch it."""
+    blk = _HybridBlock(hy, mode, name, vocab, d_model, d_inner, n_head,
+                       pool_var, pools, feeds)
+    x = blk.embed(x_ids)
     for i, kind in enumerate(hy["kinds"]):
-        y = layers.rms_norm(x, eps, pa(f"l{i}_ln1_scale"))
-        if kind in ("gqa", "swa"):
-            # a window layer's pools are its group's (fewer pages,
-            # another table: serving/kv_pool.py "Window group")
-            swa = kind == "swa"
-            d = hy["head_dim"]
-            tag = "page_w" if swa else "page_"
-            shape = [pools["window_pages"] if swa else pools["shape"][0],
-                     pools["shape"][1]]
-            pshape = shape + [hy["n_kv_head"] * d]
-            pk = pool_var(f"{name}_{tag}k_{i}", pshape, pools["dtype"])
-            pv = pool_var(f"{name}_{tag}v_{i}", pshape, pools["dtype"])
-            pks = pvs = None
-            if pools["codec"] == "int8":
-                sshape = shape + [hy["n_kv_head"]]
-                pks = pool_var(f"{name}_{tag}ks_{i}", sshape)
-                pvs = pool_var(f"{name}_{tag}vs_{i}", sshape)
-            gqa = dict(n_kv_head=hy["n_kv_head"], head_dim=d,
-                       gate=hy["gqa_gate"], qk_norm=hy["qk_norm"],
-                       rms_eps=eps, attn_scale=hy["attn_scale"])
-            if swa:
-                gqa.update(window=hy["window"], rope_theta=hy["rope_theta"],
-                           attended_name=f"{name}_l{i}_attn_attended")
-            attr = pa(f"l{i}_attn", True)    # the base of its names
-            if prefill:
-                y = layers.kv_attention_prefill_paged(
-                    y, feeds["page_rows_w" if swa else "page_rows"],
-                    d_model, n_head, pk, pv, pks, pvs,
-                    codec=pools["codec"], param_attr=attr, gqa=gqa)
-            else:
-                y = layers.kv_attention_decode_paged(
-                    y, feeds["page_table_w" if swa else "page_table"],
-                    feeds["pos"], feeds["seq_len"],
-                    feeds["gen_start"], feeds["active"], d_model, n_head,
-                    pk, pv, pks, pvs, codec=pools["codec"],
-                    param_attr=attr, gqa=gqa)
-        elif kind == "mla":
-            from paddle_tpu.ops.mla import latent_width
-            sizes = {k: hy[k] for k in _KIND_KEYS["mla"]
-                     if k != "rope_theta"}
-            sizes["n_head"] = n_head
-            wide = latent_width(hy["kv_lora_rank"], hy["qk_rope_head_dim"])
-            pc = pool_var(f"{name}_page_c_{i}", pools["shape"] + [wide],
-                          pools["dtype"])
-            pi = pool_var(f"{name}_page_i_{i}",
-                          pools["shape"] + [hy["index_head_dim"]],
-                          pools["dtype"])
-            y = layers.mla(
-                y, pc, pi, d_model, sizes, f"{name}_l{i}_mla", init,
-                hy["rope_theta"], eps,
-                **(dict(rows=feeds["page_rows"]) if prefill else dict(
-                    decode=[feeds[k] for k in (
-                        "page_table", "pos", "seq_len", "gen_start",
-                        "active", "position")],
-                    selected_name=f"{name}_l{i}_mla_selected")))
-        elif kind == "ssd":
-            sizes = {k: hy[k] for k in _HYBRID_KEYS if k.startswith("ssd_")}
-            inner = hy["ssd_heads"] * hy["ssd_head_dim"]
-            state = pool_var(f"{name}_ssd_state_{i}",
-                             [n_slots, hy["ssd_d_state"], inner])
-            conv = pool_var(
-                f"{name}_ssd_conv_{i}",
-                [n_slots, hy["ssd_conv_taps"] - 1,
-                 inner + 2 * hy["ssd_groups"] * hy["ssd_d_state"]], dt)
-            y = layers.ssd(
-                y, state, conv, d_model, sizes, f"{name}_l{i}_ssd", init,
-                eps,
-                **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
-                   if prefill else dict(active=feeds["active"])))
-        else:
-            h, d = hy["kda_heads"], hy["kda_head_dim"]
-            state = pool_var(f"{name}_kda_state_{i}", [n_slots, h, d, d])
-            conv = pool_var(f"{name}_kda_conv_{i}",
-                            [n_slots, hy["kda_conv_taps"] - 1, 3 * h * d],
-                            dt)
-            y = layers.kda(
-                y, state, conv, d_model, h, d, f"{name}_l{i}_kda", init,
-                hy["kda_gate_rank"], hy["kda_conv_taps"], eps,
-                **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
-                   if prefill else dict(active=feeds["active"])))
-        if hy["post_norms"]:
-            y = layers.rms_norm(y, eps, pa(f"l{i}_ln1_post_scale"))
-        x = join(x, y)
-        y = layers.rms_norm(x, eps, pa(f"l{i}_ln2_scale"))
-        if i < hy["first_k_dense"]:
-            y = layers.swiglu_ffn(y, d_model, d_inner, f"{name}_l{i}_ffn",
-                                  init)
-        else:
-            if prefill:
-                told = dict(seq_len=feeds["seq_len"], **grouped_counts(i))
-            else:
-                told = dict(valid=feeds["active"],
-                            counts=pool_var(f"{name}_moe_counts_{i}",
-                                            [2, hy["n_experts_held"]],
-                                            "int32"))
-            y = layers.expert_ffn_held(
-                y, d_model, hy["d_expert"], hy["n_routed_experts"],
-                hy["n_experts_held"], hy["n_experts_per_tok"],
-                f"{name}_l{i}_moe", init, hy["held_start"],
-                hy["n_shared_experts"],
-                hy["norm_topk_prob"], hy["routed_scaling_factor"],
-                **told,
-                router_bias=hy["router_bias"], d_shared=hy["d_shared"],
-                scoring=hy["scoring"])
-        if hy["post_norms"]:
-            y = layers.rms_norm(y, eps, pa(f"l{i}_ln2_post_scale"))
-        x = join(x, y)
+        x = blk.layer(x, i, kind, dense_ffn=i < hy["first_k_dense"])
     x = layers.reshape(x, shape=[-1, d_model])
-    if prefill:
+    if mode == "prefill_paged":
         one = layers.fill_constant([1, 1], "int64", 1)
         x = layers.gather(x, layers.elementwise_sub(feeds["seq_len"], one))
-    x = layers.rms_norm(x, eps, pa("lnf_scale"))
-    head = {}
-    if hy["tie_embeddings"]:
-        # ONE table, read by the embedding and by the logits
-        head["weight"] = fluid.default_main_program().global_block().var(
-            f"{name}_emb")
-    if hy["logits_scale"] != 1.0:
-        head["scale"] = 1.0 / float(hy["logits_scale"])
-    return layers.dense(x, vocab,
-                        None if hy["tie_embeddings"] else pa("head_w", True),
-                        out_dtype="float32", out_name=f"{name}_logits",
-                        **head)
+    return blk.logits(x, out_name=f"{name}_logits")
 
 
 class _HybridNormal(fluid.initializer.Initializer):
@@ -723,6 +807,10 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
         feed_specs = {"ids": ([-1, t, 1], "int64")}
         x_ids = ids
 
+    if hy is not None and mode == "full":
+        logits = _hybrid_body(hy, mode, x_ids, name, vocab, d_model,
+                              d_inner, n_head, None, None, None)
+        return layers.reshape(logits, shape=[-1, t, vocab]), feed_specs
     if hy is not None:
         logits = _hybrid_body(
             hy, mode, x_ids, name, vocab, d_model, d_inner, n_head, pool_var,
@@ -959,6 +1047,83 @@ def serve_lint_verify_paged():
     window writes resolved through the page-table feed, beyond-lease
     rows dropped via sentinel (ISSUE 19)."""
     decoder_lm("decode_verify_paged", n_slots=4)
+
+
+def build_lm(seq_len: int, vocab: int, d_model: int, d_inner: int,
+             n_head: int, n_layer: int, name: str = "lm",
+             mtp_layers: int = 0, mtp_weight: float = 0.3,
+             bias_update_gamma: float = 1e-3, lr: float = 2.2e-4,
+             beta1: float = 0.9, beta2: float = 0.95,
+             epsilon: float = 1e-8, **arch):
+    """The hybrid block's TRAINING graph, beside :func:`build` (the
+    encoder-decoder's): ``decoder_lm``'s ``full`` view of ``n_layer``
+    layers (``arch``: :func:`hybrid_arch`'s sizes) over ``ids``
+    [B, seq_len, 1], next-token cross-entropy against ``lbl_ids``, and
+    with ``mtp_layers`` 1 the multi-token-prediction module of
+    DeepSeek-V3 (arXiv:2412.19437, section 2.2) in the loss:
+
+        h'_i = W_eh [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)]
+        one more layer (the period's kind, an expert layer), the final
+        norm, the head and the table SHARED with the main model, target
+        t_{i+2} (``lbl2_ids``), over the positions i < seq_len (the last
+        one's next token is a label, not an input: it is computed for
+        the shapes' sake and left out of the mean)
+
+    loss = mean CE + ``mtp_weight`` x mean MTP CE. Adam on every
+    parameter but the routers' correction biases, which no gradient
+    reaches: after the optimizer's ops ``router_bias_update`` moves each
+    by ``bias_update_gamma`` against the step's load. The MTP module's
+    ops carry the device scope ``mtp``. Returns (loss, the per-layer
+    accumulated loads' variable names, feed_specs)."""
+    if mtp_layers not in (0, 1):
+        raise ValueError("build_lm: mtp_layers is 0 or 1 (config.json's "
+                         "num_nextn_predict_layers)")
+    hy = hybrid_arch(arch, "full", n_layer)
+    t = int(seq_len)
+    ids = layers.data(name="ids", shape=[t, 1], dtype="int64")
+    lbl = layers.data(name="lbl_ids", shape=[t, 1], dtype="int64")
+    feed_specs = {"ids": ([-1, t, 1], "int64"),
+                  "lbl_ids": ([-1, t, 1], "int64")}
+    main = fluid.default_main_program()
+    block = main.global_block()
+    loads = []
+    blk = _HybridBlock(hy, "full", name, vocab, d_model, d_inner, n_head,
+                       loads=loads)
+
+    def ce(x, labels):
+        """Per-position cross-entropy [B * T, 1] of the shared head."""
+        return layers.softmax_with_cross_entropy(
+            blk.logits(layers.reshape(x, shape=[-1, d_model])),
+            layers.reshape(labels, shape=[-1, 1]))
+
+    x = blk.embed(ids)
+    for i, kind in enumerate(hy["kinds"]):
+        x = blk.layer(x, i, kind, dense_ffn=i < hy["first_k_dense"])
+    loss = layers.mean(ce(x, lbl))
+    if mtp_layers:
+        lbl2 = layers.data(name="lbl2_ids", shape=[t, 1], dtype="int64")
+        feed_specs["lbl2_ids"] = ([-1, t, 1], "int64")
+        first_mtp_op = len(block.ops)
+        eps = hy["rms_eps"]
+        both = layers.concat(
+            [layers.rms_norm(blk.embed(lbl), eps, blk.pa("mtp0_enorm")),
+             layers.rms_norm(x, eps, blk.pa("mtp0_hnorm"))], axis=2)
+        h = layers.dense(both, d_model, blk.pa("mtp0_eh_proj", True))
+        h = blk.layer(h, n_layer, hy["kinds"][-1], tag="mtp0")
+        per = layers.reshape(ce(h, lbl2), shape=[-1, t])
+        mtp_loss = layers.mean(layers.slice(per, axes=[1], starts=[0],
+                                            ends=[t - 1]))
+        for op in block.ops[first_mtp_op:]:
+            op.desc.attrs[_device_scopes.LAYER_SCOPE_ATTR] = "mtp"
+        loss = layers.elementwise_add(
+            loss, layers.scale(mtp_loss, scale=float(mtp_weight)))
+    fluid.optimizer.Adam(learning_rate=lr, beta1=beta1, beta2=beta2,
+                         epsilon=epsilon).minimize(loss)
+    totals = [layers.router_bias_update(
+        block.var(bias_name), load, float(bias_update_gamma),
+        total_name=bias_name.replace(".router_bias", ".load"))
+        for bias_name, load in loads]
+    return loss, totals, feed_specs
 
 
 def build(is_train: bool = True, src_vocab: int = 32000,

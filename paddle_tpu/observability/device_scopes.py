@@ -46,6 +46,7 @@ PHASES = {
     "ssd_decode": ("conv", "state"),
     "ssd_prefill": ("conv", "scan"),
     "expert_ffn_held": ("route", "up", "down", "shared"),
+    "mla_full": ("project", "attend"),
     # a VARIANT of an op (``<op type>/<variant>``): the scope an op
     # lowers under, below its own, where an attribute makes it another
     # mechanism — a layer that attends a window. A row of its own, and
@@ -56,6 +57,12 @@ PHASES = {
     "kv_attention_prefill_paged/window": (),
 }
 GRAD = "grad"
+# A module beside a model's stack (the trainer's multi-token-prediction
+# module) lowers every one of its ops one component deeper:
+# ``<LAYER_SCOPES member>/<op type>``, ``grad/<member>/<op type>`` for
+# its backward — the op attribute ``LAYER_SCOPE_ATTR`` names the member.
+LAYER_SCOPES = ("mtp",)
+LAYER_SCOPE_ATTR = "__layer_scope__"
 
 
 def phase(op_type: str, name: str):
@@ -93,8 +100,11 @@ def scope_keys(op) -> Tuple[str, ...]:
 def op_scope(op) -> str:
     """The scope ``emit_op_seq`` lowers ``op`` in."""
     if op.type == "__vjp__":
-        return f"{GRAD}/{(op.attrs.get('fwd_op') or {}).get('type')}"
-    return op.type
+        fwd = op.attrs.get("fwd_op") or {}
+        layer = (fwd.get("attrs") or {}).get(LAYER_SCOPE_ATTR)
+        return "/".join(p for p in (GRAD, layer, fwd.get("type")) if p)
+    layer = op.attrs.get(LAYER_SCOPE_ATTR)
+    return f"{layer}/{op.type}" if layer else op.type
 
 
 def module_name(label: str, op_types: Iterable[str]) -> str:
@@ -200,7 +210,7 @@ def program_scope(op_name: str) -> str:
                 continue
             if part in phases:
                 out.append(part)
-            elif part in OPS or part == GRAD:
+            elif part in OPS or part == GRAD or part in LAYER_SCOPES:
                 out.append(part)
                 op_type, phases = part, PHASES.get(part, ())
             elif out and f"{op_type}/{part}" in PHASES:

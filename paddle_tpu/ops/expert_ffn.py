@@ -68,7 +68,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
 from paddle_tpu.observability import device_scopes as _device_scopes
-from paddle_tpu.ops.math_ops import dense
+from paddle_tpu.ops.math_ops import amp_dtypes, dense
 
 F32 = jnp.float32
 # the expert layer's mechanisms under their declared phases: the
@@ -140,6 +140,12 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
     worth when None)."""
     n, k = idx.shape
     n_held = w_gate.shape[0]
+    if n <= DENSE_MAX_TOKENS:
+        # the products multiply in x's dtype (a no-op but for float32
+        # master weights under the mixed-precision rewrite; the grouped
+        # way casts its own, so that their gradient comes back float32)
+        w_gate, w_up, w_down = (w.astype(x.dtype)
+                                for w in (w_gate, w_up, w_down))
     with _phase("route"):
         local = idx - held_start
         held = (local >= 0) & (local < n_held)
@@ -162,22 +168,48 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
         return y, sizes
     rows = grouped_rows(n, k, n_held, n_held if n_experts is None
                         else n_experts)
+    return _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes,
+                        rows), sizes
+
+
+def _plan(key, sizes, rows):
+    """The grouped way's bookkeeping, from each assignment's ``key``
+    [N, K] (its held expert, or E_held for one held elsewhere) and the
+    held experts' ``sizes``: ``order`` [N*K] the assignment at each
+    sorted position (held ones first, by expert), ``at`` [N, K] the
+    position of each assignment, ``token`` the token of each sorted
+    position (padded to whole turns of ``rows``), and where each held
+    expert's rows start and end."""
+    n, k = key.shape
+    n_held = sizes.shape[0]
+    order = jnp.argsort(key.reshape(-1), stable=True)            # [N*K]
+    at = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+    turns = -(-n * k // rows)
+    token = jnp.pad((order // k).astype(jnp.int32),
+                    (0, turns * rows - n * k))
+    # where each held expert's rows end (a masked sum, not a
+    # cumsum: that lowers to a window reduction, which the long
+    # prefills' compile tests keep out of their modules)
+    e = jnp.arange(n_held)
+    ends = jnp.sum(jnp.where(e[:, None] <= e[None, :],
+                             sizes[:, None], 0), axis=0)
+    return order, at, token, ends - sizes, ends
+
+
+def _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes, rows):
+    """The grouped way (module docstring): y [N, M] float32 from the
+    held assignments alone, ``rows`` sorted positions a turn. The
+    weights multiply in x's dtype (float32 master weights under the
+    mixed-precision rewrite are cast here, so that their gradient comes
+    back float32)."""
+    n, k = key.shape
+    w_gate, w_up, w_down = (w.astype(x.dtype) for w in (w_gate, w_up,
+                                                        w_down))
     with _phase("route"):
         # held assignments first, by expert (an assignment held elsewhere
-        # carries the largest key): ``order`` is the assignment at each
-        # sorted position, ``at`` the position of each assignment
-        order = jnp.argsort(key.reshape(-1), stable=True)        # [N*K]
-        at = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+        # carries the largest key)
+        _, at, token, starts, ends = _plan(key, sizes, rows)
         turns = -(-n * k // rows)
-        token = jnp.pad((order // k).astype(jnp.int32),
-                        (0, turns * rows - n * k))
-        # where each held expert's rows end (a masked sum, not a
-        # cumsum: that lowers to a window reduction, which the long
-        # prefills' compile tests keep out of their modules)
-        e = jnp.arange(n_held)
-        ends = jnp.sum(jnp.where(e[:, None] <= e[None, :],
-                                 sizes[:, None], 0), axis=0)
-        starts = ends - sizes
 
     def turn(i, y):
         """Sorted positions [i * rows, (i + 1) * rows): the held rows
@@ -213,11 +245,101 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
         return y
 
     if turns == 1:
-        return turn(0, 0.0), sizes
+        return turn(0, 0.0)
     # as many turns as the draw's held assignments fill: one, unless
     # the router sends this member far more than its even share
     return jax.lax.fori_loop(0, -(-ends[-1] // rows), turn,
-                             jnp.zeros((n, x.shape[1]), F32)), sizes
+                             jnp.zeros((n, x.shape[1]), F32))
+
+
+def _grouped_way_fwd(x, combine, w_gate, w_up, w_down, held, key, sizes,
+                     rows):
+    y = _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes,
+                     rows)
+    return y, (x, combine, w_gate, w_up, w_down, held, key, sizes)
+
+
+def _grouped_way_bwd(rows, res, dy):
+    """The grouped way's backward, grouped too: per turn the held rows'
+    products are made again from their inputs (nothing of a turn is
+    kept), their cotangents go through the transposed grouped products
+    (``jax.lax.ragged_dot_general``: over the experts' own rows, never
+    every expert times every token), and what the forward gathered is
+    gathered back — a sorted row reads ITS token's cotangent, a token
+    reads ITS picks' rows; no scatter. Weight gradients accumulate in
+    float32 over the turns (as many as the forward took)."""
+    x, combine, w_gate, w_up, w_down, held, key, sizes = res
+    n, k = key.shape
+    cdt = x.dtype
+    wg, wu, wd = (w.astype(cdt) for w in (w_gate, w_up, w_down))
+    with _phase("route"):
+        order, at, token, starts, ends = _plan(key, sizes, rows)
+        # each sorted position's combine weight
+        weight = jnp.pad(combine.reshape(-1)[order],
+                         (0, token.shape[0] - n * k))
+    dy = dy.astype(F32)
+
+    def turn(i, carry):
+        dx, dcomb, dwg, dwu, dwd = carry
+        lo = i * rows
+        with _phase("route"):
+            tok = jax.lax.dynamic_slice(token, (lo,), (rows,))
+            xs = jnp.take(x, tok, axis=0, mode="clip")
+            cut = jnp.clip(ends, lo, lo + rows) \
+                - jnp.clip(starts, lo, lo + rows)
+            # the rows the turn's groups cover: the others were never
+            # computed and hold whatever lay there
+            live = (jnp.arange(rows) < jnp.clip(ends[-1] - lo, 0, rows)
+                    )[:, None]
+            dys = jnp.take(dy, tok, axis=0, mode="clip")         # [R, M]
+            d_ys = jnp.where(
+                live, dys * jax.lax.dynamic_slice(weight, (lo,),
+                                                  (rows,))[:, None],
+                0.0).astype(cdt)
+        with _phase("up"):
+            g, u = _grouped(xs, wg, cut), _grouped(xs, wu, cut)
+            sg = jax.nn.sigmoid(g)
+            act = g * sg
+            hidden = (act * u).astype(cdt)
+        with _phase("down"):
+            ys = _grouped(hidden, wd, cut)
+            d_weight = jnp.where(live[:, 0], jnp.sum(ys * dys, axis=-1), 0.0)
+            dh = _grouped_into(d_ys, wd, cut)                    # [R, F]
+            dwd = dwd + _grouped_outer(hidden, d_ys, cut)
+        with _phase("up"):
+            dg = jnp.where(live, dh * u * (sg * (1.0 + g * (1.0 - sg))),
+                           0.0).astype(cdt)
+            du = jnp.where(live, dh * act, 0.0).astype(cdt)
+            dwg = dwg + _grouped_outer(xs, dg, cut)
+            dwu = dwu + _grouped_outer(xs, du, cut)
+            d_xs = _grouped_into(dg, wg, cut) + _grouped_into(du, wu, cut)
+        with _phase("route"):
+            here = held & (at >= lo) & (at < lo + rows)
+            row = jnp.clip(at - lo, 0, rows - 1)
+            for j in range(k):
+                dx = dx + jnp.where(
+                    here[:, j, None],
+                    jnp.take(d_xs, row[:, j], axis=0, mode="clip"), 0.0)
+            dcomb = dcomb + jnp.where(
+                here, jnp.take(d_weight, row.reshape(-1), axis=0,
+                               mode="clip").reshape(n, k), 0.0)
+        return dx, dcomb, dwg, dwu, dwd
+
+    zero = (jnp.zeros(x.shape, F32), jnp.zeros(combine.shape, F32),
+            jnp.zeros(w_gate.shape, F32), jnp.zeros(w_up.shape, F32),
+            jnp.zeros(w_down.shape, F32))
+    if -(-n * k // rows) == 1:
+        out = turn(0, zero)
+    else:
+        out = jax.lax.fori_loop(0, -(-ends[-1] // rows), turn, zero)
+    dx, dcomb, dwg, dwu, dwd = out
+    return (dx.astype(x.dtype), dcomb.astype(combine.dtype),
+            dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype),
+            dwd.astype(w_down.dtype), None, None, None)
+
+
+_grouped_way = jax.custom_vjp(_grouped_way, nondiff_argnums=(8,))
+_grouped_way.defvjp(_grouped_way_fwd, _grouped_way_bwd)
 
 
 def _all_tokens(x, w):
@@ -232,13 +354,34 @@ def _grouped(rows, w, sizes):
                               preferred_element_type=F32)
 
 
-@register_op("expert_ffn_held", no_grad=True,
-             ref="TPU-native serving op: one expert-parallel member's "
-                 "share of a top-k routed expert layer with a shared "
-                 "expert — dropless, static shapes; every held expert "
-                 "over all tokens for a step's few, one grouped product "
-                 "over the held experts for a prefill's many "
-                 "(ops/expert_ffn.py)")
+def _grouped_into(d_out, w, sizes):
+    """The grouped product's transpose to its rows: d_out [R, B] against
+    each row's expert's w [E, A, B] -> [R, A] float32."""
+    return jax.lax.ragged_dot_general(
+        d_out.astype(w.dtype), w, sizes, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(([1], [2]), ([], [])),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[0]),
+        preferred_element_type=F32)
+
+
+def _grouped_outer(rows, d_out, sizes):
+    """The grouped product's transpose to its weights: each expert's
+    rows [R, A] against their cotangents [R, B] -> [E, A, B] float32."""
+    return jax.lax.ragged_dot_general(
+        rows, d_out.astype(rows.dtype), sizes,
+        jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(([0], [0]), ([], [])),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+        preferred_element_type=F32)
+
+
+@register_op("expert_ffn_held",
+             ref="one expert-parallel member's share of a top-k routed "
+                 "expert layer with a shared expert — dropless, static "
+                 "shapes; every held expert over all tokens for a "
+                 "step's few, one grouped product over the held experts "
+                 "for a prefill's or a training step's many, its "
+                 "backward grouped too (ops/expert_ffn.py)")
 def _expert_ffn_held(ctx, ins, attrs):
     """X [B,T,M], RouterW [M,E], WGate/WUp [E_held,M,F], WDown
     [E_held,F,M], SGate/SUp [M,Fs], SDown [Fs,M] (the shared expert),
@@ -248,11 +391,16 @@ def _expert_ffn_held(ctx, ins, attrs):
     nowhere), optional Counts [2,E_held] int32 (in place: row 0 the
     tokens each held expert has been given, row 1 the calls in which it
     was given any; they wrap, a reader takes differences) -> Out
-    [B,T,M] (+ CountsOut). attrs: top_k, held_start, norm_topk,
-    scaling, scoring (:func:`route`; sigmoid when absent)."""
+    [B,T,M] (+ CountsOut; + Load [E] int32 where the op declares it:
+    the real tokens' picks over ALL the router's experts, what
+    ``router_bias_update`` balances). attrs: top_k, held_start,
+    norm_topk, scaling, scoring (:func:`route`; sigmoid when absent).
+    Under the mixed-precision tags the products multiply in bfloat16
+    over float32 master weights; the router scores in float32."""
     x = first(ins, "X")
     b, t, m = x.shape
-    x2 = x.reshape(b * t, m)
+    dt, out_dt = amp_dtypes(x, attrs)
+    x2 = x.reshape(b * t, m).astype(dt)
     valid = first(ins, "Valid")
     if valid is not None:
         valid = jnp.asarray(valid).reshape(-1) > 0
@@ -272,9 +420,16 @@ def _expert_ffn_held(ctx, ins, attrs):
         first(ins, "WDown"), int(attrs.get("held_start", 0)), valid,
         first(ins, "RouterW").shape[1])
     with _phase("shared"):
-        y = y + swiglu(x2, first(ins, "SGate"), first(ins, "SUp"),
-                       first(ins, "SDown"))
-    out = {"Out": [y.astype(x.dtype).reshape(b, t, m)]}
+        y = y + swiglu(x2, *(first(ins, n).astype(dt)
+                             for n in ("SGate", "SUp", "SDown")))
+    out = {"Out": [y.astype(out_dt).reshape(b, t, m)]}
+    if attrs.get("load"):
+        with _phase("route"):
+            picks = idx[:, :, None] == jnp.arange(
+                first(ins, "RouterW").shape[1])
+            if valid is not None:
+                picks &= valid[:, None, None]
+            out["Load"] = [jnp.sum(picks, axis=(0, 1), dtype=jnp.int32)]
     counts = first(ins, "Counts")
     if counts is not None:
         seen = jnp.stack([sizes, (sizes > 0).astype(jnp.int32)])
@@ -282,13 +437,40 @@ def _expert_ffn_held(ctx, ins, attrs):
     return out
 
 
-@register_op("swiglu_ffn", no_grad=True,
+@register_op("router_bias_update", no_grad=True,
+             ref="the auxiliary-loss-free balancing of DeepSeek-V3 "
+                 "(arXiv:2412.19437, section 2.1.2; config.json's "
+                 "topk_method noaux_tc): after a step a router's "
+                 "correction bias moves by gamma toward the experts the "
+                 "step gave fewer tokens than the mean "
+                 "(ops/expert_ffn.py)")
+def _router_bias_update(ctx, ins, attrs):
+    """Bias [1,E] float32 (in place), Load [E] int (the step's picks per
+    expert, ``expert_ffn_held``'s), optional LoadTotal [E] int32 (in
+    place: the picks so far, what a load metric reads; wraps) ->
+    BiasOut = Bias + gamma * sign(mean(Load) - Load) (+ LoadTotalOut).
+    No gradient reaches the bias: this is its whole update."""
+    bias = first(ins, "Bias")
+    load = jnp.asarray(first(ins, "Load")).astype(F32)
+    step = float(attrs["gamma"]) * jnp.sign(jnp.mean(load) - load)
+    out = {"BiasOut": [bias + step.reshape(bias.shape).astype(bias.dtype)]}
+    total = first(ins, "LoadTotal")
+    if total is not None:
+        out["LoadTotalOut"] = [total + load.astype(total.dtype)]
+    return out
+
+
+@register_op("swiglu_ffn",
              ref="a dense SwiGLU feed-forward layer, W_down (SiLU(W_gate "
                  "x) * W_up x): products in the storage dtype with "
                  "float32 accumulation (ops/expert_ffn.py:swiglu)")
 def _swiglu_ffn(ctx, ins, attrs):
-    """X [B,T,M], WGate / WUp [M,F], WDown [F,M] -> Out [B,T,M]."""
+    """X [B,T,M], WGate / WUp [M,F], WDown [F,M] -> Out [B,T,M]
+    (bfloat16 products over float32 master weights under the
+    mixed-precision tags)."""
     x = first(ins, "X")
-    y = swiglu(x.reshape(-1, x.shape[-1]), first(ins, "WGate"),
-               first(ins, "WUp"), first(ins, "WDown"))
-    return {"Out": [y.astype(x.dtype).reshape(x.shape)]}
+    dt, out_dt = amp_dtypes(x, attrs)
+    y = swiglu(x.reshape(-1, x.shape[-1]).astype(dt),
+               *(first(ins, n).astype(dt)
+                 for n in ("WGate", "WUp", "WDown")))
+    return {"Out": [y.astype(out_dt).reshape(x.shape)]}

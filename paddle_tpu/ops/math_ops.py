@@ -59,19 +59,33 @@ def dense(x, w, out_dtype=None, transpose_w=False):
     return y if out_dtype is None else y.astype(out_dtype)
 
 
-@register_op("dense", no_grad=True,
+def amp_dtypes(x, attrs):
+    """(the dtype an op's products multiply in, the dtype of its
+    result) under the mixed-precision rewrite's tags
+    (contrib/mixed_precision.py): bfloat16 products over float32 master
+    weights where ``__amp_bf16__`` is set, a bfloat16 result where
+    ``__amp_keep_bf16__`` is; x's own dtype, twice, without them."""
+    if not attrs.get("__amp_bf16__"):
+        return x.dtype, x.dtype
+    return jnp.bfloat16, (jnp.bfloat16 if attrs.get("__amp_keep_bf16__")
+                          else x.dtype)
+
+
+@register_op("dense",
              ref="X [..., M] @ W [M, N] in the weight's dtype with "
                  "float32 accumulation; attr out_dtype (default: X's); "
                  "with attr transpose_w W is [N, M], contracted over its "
                  "columns as it lies (a tied head); attr scale multiplies "
                  "the float32 result")
 def _dense(ctx, ins, attrs):
-    x = first(ins, "X")
-    y = dense(x, first(ins, "W"),
-              transpose_w=bool(attrs.get("transpose_w")))
+    x, w = first(ins, "X"), first(ins, "W")
+    dt, out_dt = amp_dtypes(x, attrs)
+    if attrs.get("__amp_bf16__"):
+        w = w.astype(dt)
+    y = dense(x, w, transpose_w=bool(attrs.get("transpose_w")))
     if attrs.get("scale") is not None:
         y = y * float(attrs["scale"])
-    return single(y.astype(attrs.get("out_dtype") or x.dtype))
+    return single(y.astype(attrs.get("out_dtype") or out_dt))
 
 
 @register_op("matmul", ref="operators/matmul_op.cc")

@@ -75,7 +75,7 @@ from paddle_tpu.core.registry import first, register_op
 from paddle_tpu.observability import device_scopes as _device_scopes
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.ops import kv_attention as _kv
-from paddle_tpu.ops.math_ops import dense
+from paddle_tpu.ops.math_ops import amp_dtypes, dense
 
 F32 = jnp.float32
 LANES = 128
@@ -110,6 +110,7 @@ MLA_DECODE_LOWERED = _metrics.counter(
     labelnames=("path",))
 
 _phase = functools.partial(_device_scopes.phase, "mla_decode_paged")
+_full_phase = functools.partial(_device_scopes.phase, "mla_full")
 
 _WEIGHTS = ("Wdq", "QNorm", "Wuq", "Wdkv", "KvNorm", "Wuk", "Wuv", "Wo",
             "Wiq", "Wik", "IkScale", "IkBias", "Wiw")
@@ -144,7 +145,9 @@ _rms = _kv._rms
 
 
 def _sizes(attrs):
-    return {k: int(attrs[k]) for k in (
+    """The layer's sizes; the indexer's three are None where the layer
+    has none (``mla_full`` of a model without DSA)."""
+    return {k: None if attrs.get(k) is None else int(attrs[k]) for k in (
         "n_head", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
         "v_head_dim", "index_n_heads", "index_head_dim", "index_topk")}
 
@@ -154,11 +157,11 @@ def token_terms(x, w, pos, a, theta, eps):
     q [N, H, dn + dr] float32 (the rope part rotated), its latent row
     [N, W] ([cKV ; kR ; zeros]) and indexer key [N, di] in x's dtype,
     the indexer's queries [N, J, di] (x's dtype) and head weights
-    [N, J] float32."""
+    [N, J] float32 — None, all three of the indexer's, for a layer
+    without one (``index_topk`` None)."""
     n, dt = x.shape[0], x.dtype
     h, dc, dn, dr = (a["n_head"], a["kv_lora_rank"], a["qk_nope_head_dim"],
                      a["qk_rope_head_dim"])
-    j, di = a["index_n_heads"], a["index_head_dim"]
     cq = _rms(dense(x, w["Wdq"]), w["QNorm"], eps).astype(dt)
     q = _rope_part(dense(cq, w["Wuq"]).reshape(n, h, dn + dr), pos,
                    theta, dn, dn + dr)
@@ -167,6 +170,9 @@ def token_terms(x, w, pos, a, theta, eps):
     kr = rope(ckv[:, dc:], pos, theta)
     pad = latent_width(dc, dr) - dc - dr
     row = jnp.concatenate([c, kr, jnp.zeros((n, pad), F32)], axis=-1)
+    if a["index_topk"] is None:
+        return q, row.astype(dt), None, None, None
+    j, di = a["index_n_heads"], a["index_head_dim"]
     qi = _rope_part(dense(cq, w["Wiq"]).reshape(n, j, di), pos, theta, 0,
                     dr)
     ki = dense(x, w["Wik"])
@@ -226,6 +232,32 @@ def select_topk(scores, valid, k):
 _softmax_rows = _kv._softmax_rows
 
 
+def query_blocks(q, k, v, scale, blk, select=None):
+    """Causal softmax attention of one sequence, a block of ``blk``
+    queries at a time: q [T, H, d] float32, k [T, H, d], v [T, H, dv] ->
+    (block, the blocks' first rows): ``block(t0)`` is the context
+    [blk, H, dv] in v's dtype of the queries t0 .. t0 + blk. Query t
+    attends the positions s <= t, and of those what ``select(t0, keep)``
+    keeps. The one composed path beside the kernels: the prefill maps
+    it, the trainer's fallback maps it recomputed."""
+    t, dt = v.shape[0], v.dtype
+    s_idx = jnp.arange(t)
+
+    def block(t0):
+        rows = t0 + jnp.arange(blk)
+        keep = s_idx[None, :] <= rows[:, None]
+        if select is not None:
+            keep = select(t0, keep)
+        qb = jax.lax.dynamic_slice_in_dim(q, t0, blk).astype(dt)
+        s = jnp.einsum("qhd,shd->hqs", qb, k,
+                       preferred_element_type=F32) * scale
+        p = _softmax_rows(jnp.where(keep[None], s, -jnp.inf)).astype(dt)
+        return jnp.einsum("hqs,shd->qhd", p, v,
+                          preferred_element_type=F32).astype(dt)
+
+    return block, jnp.arange(0, t, blk)
+
+
 def expanded_attention(q, c, kr, qi, wi, ki, w, a):
     """The prefill's way over one sequence: q [T, H, dn + dr] float32, c
     [T, dc] and kr [T, dr] (the latent rows' parts), the indexer's qi
@@ -244,25 +276,14 @@ def expanded_attention(q, c, kr, qi, wi, ki, w, a):
     if t % blk:
         raise ValueError(f"a prompt bucket of {t} is not a whole number "
                          f"of {blk}-query blocks")
-    scale = float(dn + dr) ** -0.5
-    s_idx = jnp.arange(t)
 
-    def block(t0):
-        rows = t0 + jnp.arange(blk)
-        keep = s_idx[None, :] <= rows[:, None]
-        if t > topk:
-            cut = lambda z: jax.lax.dynamic_slice_in_dim(z, t0, blk)  # noqa
-            keep = select_topk(index_scores(cut(qi), cut(wi), ki), keep,
-                               topk)
-        qb = jax.lax.dynamic_slice_in_dim(q, t0, blk).astype(dt)
-        s = jnp.einsum("qhd,shd->hqs", qb, k,
-                       preferred_element_type=F32) * scale
-        p = _softmax_rows(jnp.where(keep[None], s, -jnp.inf)).astype(dt)
-        return jnp.einsum("hqs,shd->qhd", p, v,
-                          preferred_element_type=F32).astype(dt)
+    def select(t0, keep):
+        cut = lambda z: jax.lax.dynamic_slice_in_dim(z, t0, blk)  # noqa
+        return select_topk(index_scores(cut(qi), cut(wi), ki), keep, topk)
 
-    o = jax.lax.map(block, jnp.arange(0, t, blk))
-    return o.reshape(t, h * dv)
+    block, starts = query_blocks(q, k, v, attention_scale(a), blk,
+                                 select if t > topk else None)
+    return jax.lax.map(block, starts).reshape(t, h * dv)
 
 
 def absorbed_query(q, w, a, dt, width):
@@ -486,3 +507,77 @@ def _mla_decode_paged(ctx, ins, attrs):
     with _phase("project"):
         out = dense(o, w["Wo"], dt)[:, None]
     return _result(out, flat_c, flat_i, n_pages, ps, Selected=sel)
+
+
+def causal_attention(q, k_nope, kr, v, scale, mesh=None):
+    """Causal softmax attention of whole sequences, expanded: q
+    [B, T, H, dn + dr], k_nope [B, T, H, dn], the rotated key kr
+    [B, T, dr] that all heads share, v [B, T, H, dv] -> [B, T, H * dv]
+    in v's dtype. Differentiable. On the chip (and where a test forces
+    the interpreter) the flash kernels of ``ops/pallas/flash_attention``
+    at query/key heads of dn + dr and value heads of dv: no [T, T] array
+    reaches HBM, forward or backward. Elsewhere query blocks of
+    ``QUERY_BLOCK`` rows, each recomputed in the backward."""
+    from paddle_tpu.ops import pallas as _plk
+    b, t, h, _ = q.shape
+    dt = v.dtype
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(kr[:, :, None, :],
+                                  (b, t, h, kr.shape[-1]))], axis=-1)
+    blocks = _plk.pick_blocks(t, t)
+    if None not in blocks and (_plk.kernel_enabled(128, t, mesh=mesh)
+                               or _plk.forced_interpret()):
+        heads_first = lambda z: jnp.swapaxes(z, 1, 2)          # noqa: E731
+        o = _plk.flash_attention(
+            heads_first(q.astype(dt)), heads_first(k), heads_first(v),
+            True, scale, blocks[0], blocks[1], _plk.interpret_mode())
+        return heads_first(o).reshape(b, t, -1)
+    blk = QUERY_BLOCK if t % QUERY_BLOCK == 0 else t
+
+    def one(q1, k1, v1):
+        block, starts = query_blocks(q1, k1, v1, scale, blk)
+        return jax.lax.map(jax.checkpoint(block), starts).reshape(t, -1)
+
+    return jax.vmap(one)(q, k, v)
+
+
+@register_op("mla_full",
+             ref="multi-head latent attention (arXiv:2405.04434) over "
+                 "whole sequences, causal and expanded, with a gradient: "
+                 "the trainer's view of the latent layer, and the "
+                 "oracle's (ops/mla.py)")
+def _mla_full(ctx, ins, attrs):
+    """X [B,T,M] and the layer's weights (none of the indexer's: a
+    layer whose ``index_topk`` is None) -> Out [B,T,M]. Position t of a
+    sequence is token t. Under the mixed-precision tags the products
+    multiply in bfloat16 over float32 master weights; norms, rotation
+    and softmax are float32 either way. attrs: the sizes of ``_sizes``,
+    rope_theta, epsilon."""
+    x = first(ins, "X")
+    a = _sizes(attrs)
+    if a["index_topk"] is not None:
+        raise ValueError("mla_full attends every earlier position: a "
+                         "layer with an indexer (index_topk) has no "
+                         "full view yet")
+    dt, out_dt = amp_dtypes(x, attrs)
+    w = {n: first(ins, n) for n in _WEIGHTS if first(ins, n) is not None}
+    w = {n: v.astype(dt) if v.ndim == 2 else v for n, v in w.items()}
+    b, t, m = x.shape
+    h, dc, dn, dr, dv = (a["n_head"], a["kv_lora_rank"],
+                         a["qk_nope_head_dim"], a["qk_rope_head_dim"],
+                         a["v_head_dim"])
+    with _full_phase("project"):
+        q, row, _, _, _ = token_terms(
+            x.reshape(b * t, m).astype(dt), w, jnp.tile(jnp.arange(t), b),
+            a, float(attrs["rope_theta"]),
+            float(attrs.get("epsilon", 1e-5)))
+        c = row[:, :dc]
+        k_nope = dense(c, w["Wuk"], dt).reshape(b, t, h, dn)
+        v = dense(c, w["Wuv"], dt).reshape(b, t, h, dv)
+    with _full_phase("attend"):
+        o = causal_attention(q.reshape(b, t, h, dn + dr), k_nope,
+                             row[:, dc:dc + dr].reshape(b, t, dr), v,
+                             attention_scale(a), ctx.mesh)
+    with _full_phase("project"):
+        out = dense(o.reshape(b * t, h * dv), w["Wo"], out_dt)
+    return {"Out": [out.reshape(b, t, m)]}

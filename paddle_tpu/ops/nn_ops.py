@@ -420,7 +420,7 @@ def _layer_norm(ctx, ins, attrs):
     }
 
 
-@register_op("rms_norm", no_grad=True,
+@register_op("rms_norm",
              ref="RMSNorm (Zhang & Sennrich 2019, arXiv:1910.07467) over "
                  "the last axis: float32 statistics, the result in the "
                  "input's dtype")

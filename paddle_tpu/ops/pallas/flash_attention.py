@@ -195,10 +195,10 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
     if dropout_p <= 0:
         seed = None
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     q4 = q.reshape(b * h, tq, d)
     k4 = k.reshape(b * h, tk, d)
-    v4 = v.reshape(b * h, tk, d)
+    v4 = v.reshape(b * h, tk, dv)
     nk = tk // bk
     grid = (b * h, tq // bq, nk)
     kern = functools.partial(_fwd_kernel, bq=bq, bk=bk, nk=nk, causal=causal,
@@ -207,7 +207,7 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
     out, lse = pl.pallas_call(
         kern,
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, tq, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -216,20 +216,20 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
             [
                 pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
                 pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
+                pl.BlockSpec((1, bk, dv), lambda bh, i, j: (bh, j, 0)),
             ],
             [
-                pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
                 pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
             ],
             [
                 pltpu.VMEM((bq, 1), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
             ],
             seed),
     )(*_seed_args(seed), q4, k4, v4)
-    return out.reshape(b, h, tq, d), lse.reshape(b, h, tq)
+    return out.reshape(b, h, tq, dv), lse.reshape(b, h, tq)
 
 
 def pick_blocks(tq, tk):
@@ -350,7 +350,9 @@ def flash_engage(tq, tk, d, causal):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, scale=None, bq=128, bk=128,
                     interpret=False, dropout_p=0.0, seed=None):
-    """q [B,H,Tq,D], k/v [B,H,Tk,D] → [B,H,Tq,D]. Tq % bq == Tk % bk == 0.
+    """q [B,H,Tq,D], k [B,H,Tk,D], v [B,H,Tk,Dv] → [B,H,Tq,Dv] (value
+    heads of another size than the query/key heads': latent attention's
+    192 / 128). Tq % bq == Tk % bk == 0.
     dropout_p applies attention-weight dropout (upscale_in_train) with a
     keep mask derived from `seed` (int32 scalar, traced ok) + tile
     coordinates — identical in fwd and bwd kernels."""
@@ -491,12 +493,12 @@ def _flash_bwd_impl(causal, scale, bq, bk, interpret, res, g, glse,
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     nq, nk = tq // bq, tk // bk
     q4 = q.reshape(b * h, tq, d)
     k4 = k.reshape(b * h, tk, d)
-    v4 = v.reshape(b * h, tk, d)
-    g4 = g.reshape(b * h, tq, d)
+    v4 = v.reshape(b * h, tk, dv)
+    g4 = g.reshape(b * h, tq, dv)
     lse4 = lse.reshape(b * h, tq, 1)
     delta4 = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32),
                      axis=-1).reshape(b * h, tq, 1)
@@ -519,8 +521,8 @@ def _flash_bwd_impl(causal, scale, bq, bk, interpret, res, g, glse,
             [
                 pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
                 pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-                pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                pl.BlockSpec((1, bk, dv), lambda bh, i, j: (bh, j, 0)),
+                pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
                 pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
                 pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
             ] + glse_in[1],
@@ -531,13 +533,13 @@ def _flash_bwd_impl(causal, scale, bq, bk, interpret, res, g, glse,
 
     glse_in_kv = ([glse4], [pl.BlockSpec((1, bq, 1),
                                          lambda bh, j, i: (bh, i, 0))])         if has_glse else ([], [])
-    dk, dv = pl.pallas_call(
+    dk, dval = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, nq=nq, causal=causal,
                           scale=scale, q_off=q_off, has_glse=has_glse,
                           dropout_p=dp_eff),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, tk, dv), v.dtype),
         ],
         interpret=interpret,
         **_grid_spec(
@@ -545,22 +547,22 @@ def _flash_bwd_impl(causal, scale, bq, bk, interpret, res, g, glse,
             [
                 pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0)),
                 pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-                pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0)),
+                pl.BlockSpec((1, bk, dv), lambda bh, j, i: (bh, j, 0)),
+                pl.BlockSpec((1, bq, dv), lambda bh, j, i: (bh, i, 0)),
                 pl.BlockSpec((1, bq, 1), lambda bh, j, i: (bh, i, 0)),
                 pl.BlockSpec((1, bq, 1), lambda bh, j, i: (bh, i, 0)),
             ] + glse_in_kv[1],
             [
                 pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
+                pl.BlockSpec((1, bk, dv), lambda bh, j, i: (bh, j, 0)),
             ],
             [pltpu.VMEM((bk, d), jnp.float32),
-             pltpu.VMEM((bk, d), jnp.float32)],
+             pltpu.VMEM((bk, dv), jnp.float32)],
             seed),
     )(*_seed_args(seed), q4, k4, v4, g4, lse4, delta4, *glse_in_kv[0])
 
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dv.reshape(b, h, tk, d))
+            dval.reshape(b, h, tk, dv))
 
 
 def _vjp_bwd(causal, scale, bq, bk, interpret, dropout_p, res, g):

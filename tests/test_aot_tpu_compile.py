@@ -117,6 +117,21 @@ def _flash(t, d):
             [((b, 8, t, d), BF16)] * 3)
 
 
+def _flash_latent():
+    """fwd + bwd at latent attention's head sizes — query / key heads of
+    192 (128 + 64 rotated), value heads of 128 — over the trainer's
+    8 192-token sequence of 32 heads, the blocks ``causal_attention``
+    picks (ISSUE 47)."""
+    from paddle_tpu.ops.pallas import flash_attention, pick_blocks
+    bq, bk = pick_blocks(8192, 8192)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, 192 ** -0.5, bq, bk)
+                       .astype(F32))
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [((1, 32, 8192, 192), BF16)] * 2 + [((1, 32, 8192, 128), BF16)])
+
+
 def _flash_pairs(dropout_p):
     """Forward and backward kernels of the trainer's d = 64 path at the
     cell's shape and the table's blocks: 16 heads of 64 as
@@ -180,6 +195,7 @@ CASES = {
     "embed_pool-w256": lambda: _embed_pool(256),
     "flash-fwd+bwd-T512-d128": lambda: _flash(512, 128),
     "flash-fwd+bwd-T2048-d64": lambda: _flash(2048, 64),
+    "flash-fwd+bwd-T8192-dqk192-dv128": _flash_latent,
     "flash_pairs-fwd+bwd-T512-d64": lambda: _flash_pairs(0.0),
     "flash_pairs-fwd+bwd-T512-d64-dropout": lambda: _flash_pairs(0.3),
     "fused_ce-fwd+bwd-8192x512x32000": _fused_ce,
@@ -989,3 +1005,90 @@ def test_the_grouped_way_holds_no_worst_case_buffer_on_v5e(chip):
     for shape in ("[20480,4096]", "[20480,768]", "[2048,10,4096]",
                   "[10,2048,4096]"):
         assert shape not in text, shape
+
+
+def test_the_grouped_ways_backward_is_grouped_on_v5e(chip):
+    """A training step's expert layer at JoyAI-LLM-Flash's share (8 192
+    tokens, 8 picks over a router of 256, 16 experts of 768 held, float32
+    master weights under bfloat16 activations): forward and backward
+    hold grouped products alone — three forward, and in the backward the
+    three made again, their four transposes to the rows and three to the
+    weights — over the HELD share's 5 120 rows; nothing of every expert
+    times every token (``[8192,16,768]``), nothing of the worst case's
+    size (65 536 assignments), and one loop each way whose turns the
+    draw decides."""
+    from paddle_tpu.ops import expert_ffn
+    n, e, held, k, m, f = 8192, 256, 16, 8, 2048, 768
+    assert expert_ffn.grouped_rows(n, k, held, e) == 5120
+
+    def s(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(x, router, bias, wg, wu, wd, g):
+        def part(x, wg, wu, wd):
+            combine, idx = expert_ffn.route(x, router, k, True, 2.5, bias)
+            return expert_ffn.held_experts_part(x, combine, idx, wg, wu, wd,
+                                                0, None, e)[0]
+        y, pull = jax.vjp(part, x, wg, wu, wd)
+        return (y,) + pull(g)
+    text = jax.jit(step).lower(
+        s((n, m)), s((m, e), F32), s((1, e), F32), s((held, m, f), F32),
+        s((held, m, f), F32), s((held, f, m), F32),
+        s((n, m), F32)).compile().as_text()
+    assert text.count("ragged-dot-none") >= 13
+    assert "[5120,2048]" in text and "[5120,768]" in text
+    assert _count_opcode(text, "while") == 2
+    for shape in ("[8192,16,768]", "[16,8192,768]", "[65536,2048]",
+                  "[65536,768]", "[8192,8,2048]", "[8,8192,2048]"):
+        assert shape not in text, shape
+
+
+def test_joyai_train_step_compiles_for_v5e(chip, monkeypatch):
+    """The trainer's whole step of ``train_joyai_seq8k_1chip`` (build_lm
+    at the configuration's sizes: five layers and the MTP module, one
+    8 192-token sequence, the pass pipeline, the mixed-precision rewrite
+    and the configuration's recomputation) for a described v5e: it fits
+    the chip beside its 8.17 GB of float32 state, holds the flash
+    kernels of every layer forward and backward and the grouped
+    products, and no ``[.., T, T]`` array."""
+    import json
+    import os
+    import numpy as np
+    from paddle_tpu.core.lowering import CompiledBlock
+    from paddle_tpu.ops import pallas as pk
+    from chipbench.runners import train_lm
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "joyai_llm_flash_ep16_d6.json")) as f:
+        cfg = json.load(f)
+    t = cfg["build"]["seq_len"]
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    main, _startup, loss, _totals = train_lm.build_program(cfg, 1)
+    feeds = ["ids", "lbl_ids", "lbl2_ids"]
+    cb = CompiledBlock(main.desc, 0, feeds, [loss.name])
+    gvars = main.desc.global_block.vars
+
+    def struct(n):
+        v = gvars[n]
+        return jax.ShapeDtypeStruct(tuple(v.shape), jnp.dtype(v.dtype),
+                                    sharding=chip)
+    state = {n: struct(n) for n in cb.sig.state_names}
+    assert 8.1e9 < sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                       for s in state.values()) < 8.2e9
+    compiled = cb.fn.lower(
+        state, {n: struct(n) for n in cb.sig.const_names},
+        {n: jax.ShapeDtypeStruct((1, t, 1), jnp.int64, sharding=chip)
+         for n in feeds},
+        jax.ShapeDtypeStruct((), jnp.uint32, sharding=chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    # six layers: the flash forward, its recomputation, dq and dkv
+    attend = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                        r'op_name="([^"]*)"', text)
+    assert sum("mla_full" in n for n in attend) >= 24
+    assert any("grad/mtp/mla_full" in n for n in attend)
+    assert text.count("ragged-dot-none") >= 5 * 16
+    for m in re.finditer(r"(?:f32|bf16)\[([\d,]+)\]", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        assert dims.count(t) < 2, m.group(0)

@@ -256,6 +256,19 @@ spec("expert_ffn_held",
      {"top_k": 2, "held_start": 1})
 spec("swiglu_ffn", {"X": [f(1, 4, 8)], "WGate": [f(8, 5, seed=1)],
                     "WUp": [f(8, 5, seed=2)], "WDown": [f(5, 8, seed=3)]})
+spec("router_bias_update",
+     {"Bias": [f(1, 6)], "Load": [ints(6, hi=9, seed=2)],
+      "LoadTotal": [ints(6, hi=9, seed=3)]}, {"gamma": 0.001})
+# latent attention WITHOUT an indexer over whole sequences, the
+# trainer's view (ops/mla.py: mla_full): two heads (nope 4, rope 4, v 4)
+# over a latent of 8, two sequences of six tokens
+spec("mla_full",
+     {"X": [f(2, 6, 8)], "Wdq": [f(8, 6, seed=1)], "QNorm": [pos(6)],
+      "Wuq": [f(6, 16, seed=2)], "Wdkv": [f(8, 12, seed=3)],
+      "KvNorm": [pos(8)], "Wuk": [f(8, 8, seed=4)],
+      "Wuv": [f(8, 8, seed=5)], "Wo": [f(8, 8, seed=6)]},
+     {"n_head": 2, "kv_lora_rank": 8, "qk_nope_head_dim": 4,
+      "qk_rope_head_dim": 4, "v_head_dim": 4, "rope_theta": 10000.0})
 # latent attention with the DSA indexer (ops/mla.py): two heads (nope 4,
 # rope 4, v 4) over a latent of 8, an indexer of two heads of 8 that
 # keeps 4 rows; a pool of 6 pages of 2 rows, the latent row padded to
