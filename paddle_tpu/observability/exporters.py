@@ -279,6 +279,9 @@ def _preregister_catalog():
                 # what runs a fused attention block's core
                 # (paddle_attention_block_lowered_total{path, d_head})
                 "paddle_tpu.ops.nn_ops",
+                # what a causal flash kernel's grid visits, computes and
+                # masks (paddle_flash_causal_blocks_total{kernel, kind})
+                "paddle_tpu.ops.pallas.flash_attention",
                 "paddle_tpu.distributed.sharded_table"):
         try:
             importlib.import_module(mod)

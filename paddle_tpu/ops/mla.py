@@ -524,7 +524,7 @@ def causal_attention(q, k_nope, kr, v, scale, mesh=None):
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(kr[:, :, None, :],
                                   (b, t, h, kr.shape[-1]))], axis=-1)
-    blocks = _plk.pick_blocks(t, t)
+    blocks = _plk.causal_blocks(t, q.shape[-1], v.shape[-1])
     if None not in blocks and (_plk.kernel_enabled(128, t, mesh=mesh)
                                or _plk.forced_interpret()):
         heads_first = lambda z: jnp.swapaxes(z, 1, 2)          # noqa: E731

@@ -74,7 +74,8 @@ def kernel_enabled(min_align: int = 128, *dims, mesh=None) -> bool:
 
 
 from paddle_tpu.ops.pallas.flash_attention import (  # noqa: E402,F401
-    flash_attention, flash_attention_lse, flash_engage, pick_blocks)
+    causal_blocks, flash_attention, flash_attention_lse, flash_engage,
+    pick_blocks)
 from paddle_tpu.ops.pallas import flash_pairs  # noqa: E402,F401
 from paddle_tpu.ops.pallas.fused_ce import fused_linear_ce  # noqa: E402,F401
 from paddle_tpu.ops.pallas.fused_rnn import (fused_gru_train,  # noqa: E402,F401

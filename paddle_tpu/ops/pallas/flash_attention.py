@@ -1,19 +1,35 @@
-"""Flash attention forward kernel (Pallas TPU).
+"""Flash attention, forward and backward (Pallas TPU): three kernels.
 
-Replaces the materialized [B, H, Tq, Tk] score tensor of the refer path
-(parallel/ring_attention.py full_attention) with online-softmax tiling:
-each grid step owns one [BQ, D] query block in VMEM, streams [BK, D]
-key/value blocks, and keeps running (max, denom, acc) statistics — the
-standard flash recurrence. HBM traffic drops from O(Tq*Tk) to
-O(Tq*D + Tk*D) per head, which is the difference between HBM-bound and
-MXU-bound attention at long sequence length (the whole point of ring
-attention's per-shard compute too — this kernel is the per-shard inner
-loop of paddle_tpu.parallel.ring_attention when shapes align).
+Forward: the [B, H, Tq, Tk] score tensor that the composed attention
+(parallel/ring_attention.py ``full_attention``, the jnp tier the tests
+compare against) writes to HBM never exists. A grid step owns one
+[BQ, D] query block in VMEM, streams [BK, D] key/value blocks, and keeps
+running (max, denom, acc) statistics — the standard flash recurrence.
+HBM traffic drops from O(Tq*Tk) to O(Tq*D + Tk*D) per head. The callers:
+``ops/mla.py:causal_attention`` (latent attention's heads of 192 / 128,
+the trained cell), ``ops/nn_ops.py:fused_attention_block`` (heads of
+128 where ``flash_engage`` names blocks) and ring attention's per-shard
+inner loop (``flash_attention_lse``).
 
 Backward: jax.custom_vjp over blockwise Pallas kernels. Residuals are
 (q, k, v, o, lse) — O(T*D) — and the bwd recomputes scores tile-by-tile in
 two kernels (dQ over k-blocks; dK/dV over q-blocks, the flash-attention-2
-schedule), so training peak memory is O(T*D) end to end."""
+schedule), so training peak memory is O(T*D) end to end.
+
+The causal schedule (PR 48). A causal call whose every query sees a key
+(``tq <= tk``) runs its grid over the VISIBLE (query block, key block)
+pairs alone: the grid is (heads, pairs) and two int32 tables in SMEM
+(scalar prefetch) give a step its blocks, so no step is spent and no
+tile is fetched above the diagonal (``causal_schedule`` is the
+arithmetic, ``paddle_flash_causal_blocks_total`` what a lowering did
+with its shape). Every visited tile takes ``_masked_scores``' mask: a
+second, mask-free body for the tiles wholly under the diagonal was
+measured on the chip and not kept — the vector units have the slack
+(0.2 ms of a 494 ms step) and two bodies a kernel are twice the code
+to trace, lower and load at every set-up. A non-causal call, and a
+causal one with ``tq > tk`` (queries that see no key: rows of zeros, as
+before), keep the dense (heads, Tq/bq, Tk/bk) grid and lower to the
+text they always had."""
 
 from __future__ import annotations
 
@@ -24,7 +40,22 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from paddle_tpu.observability import metrics as _metrics
+
 _NEG = -1e30
+
+# exporter-catalog family (docs/observability.md). Counts LOWERINGS, as
+# ``paddle_mla_decode_lowered_total`` does: each time a causal call of
+# one of the three kernels is traced, the grid steps it will run
+# (``visited``) and those that compute a tile (``computed``), over all
+# heads of the call. Under the causal schedule visited == computed; a
+# shape that fell back to the dense grid (tq > tk) reads visited >
+# computed.
+CAUSAL_BLOCKS = _metrics.counter(
+    "paddle_flash_causal_blocks_total",
+    "Causal flash-attention tiles by lowering: grid steps visited, "
+    "tiles computed (kernel=fwd|dq|dkv)",
+    labelnames=("kernel", "kind"))
 
 
 def _block_visible(causal, kb, bk, q_last):
@@ -32,6 +63,46 @@ def _block_visible(causal, kb, bk, q_last):
     if not causal:
         return True
     return (kb * bk) < q_last
+
+
+def _visible_tiles(tq, tk, bq, bk):
+    """The (query block, key block) tiles of a causal [tq, tk] call that
+    hold a key at or under some query, query-major."""
+    q_off = tk - tq
+    return [(i, j) for i in range(tq // bq) for j in range(tk // bk)
+            if _block_visible(True, j, bk, q_off + (i + 1) * bq)]
+
+
+def causal_schedule(tq, tk, bq, bk, key_major=False):
+    """The causal grid of a [tq, tk] call in [bq, bk] tiles, in visit
+    order: (query blocks, key blocks) as int32 numpy arrays over the
+    visible tiles (``_block_visible``, with the diagonal shifted by
+    ``q_off = tk - tq``). Query-major (forward, dQ: a query block's key
+    blocks are 0..its last) or key-major (dK/dV: a key block's query
+    blocks are its first..the last). None where some query block sees
+    no key (tq > tk): its output would never be written, so such a call
+    keeps the dense grid."""
+    if tq > tk:
+        return None
+    tiles = _visible_tiles(tq, tk, bq, bk)
+    if key_major:
+        tiles.sort(key=lambda t: (t[1], t[0]))
+    qi, kj = (np.asarray(x, np.int32) for x in zip(*tiles))
+    return qi, kj
+
+
+def _count_causal(kernel, heads, tq, tk, bq, bk):
+    """-> the (outer, inner) tables of the schedule for this kernel, or
+    None for the dense grid; counts what the lowering will do."""
+    sched = causal_schedule(tq, tk, bq, bk, key_major=kernel == "dkv")
+    computed = len(_visible_tiles(tq, tk, bq, bk))
+    visited = computed if sched else (tq // bq) * (tk // bk)
+    for kind, n in (("visited", visited), ("computed", computed)):
+        CAUSAL_BLOCKS.labels(kernel=kernel, kind=kind).inc(heads * n)
+    if sched is None:
+        return None
+    qi, kj = sched
+    return (kj, qi) if kernel == "dkv" else (qi, kj)
 
 
 def _masked_scores(q, k, scale, causal, qb, j, bq, bk, q_off):
@@ -100,27 +171,59 @@ def _block_keep_mask(seed, bh, qb, j, bq, bk, q_off, dropout_p):
     return hash_keep_mask(seed, bh, qpos, kpos, dropout_p)
 
 
-def _fwd_kernel(*args, bq, bk, nk, causal, scale, q_off, dropout_p):
-    """Grid (BH, Tq/bq, Tk/bk): the innermost k dimension streams [bk, D]
+def _step_ids(args, paired):
+    """(batch*head, outer block, inner block) of this grid step, and the
+    kernel's other refs: the dense grid's own ids, or under the causal
+    schedule the step's row of the two tables in SMEM."""
+    if not paired:
+        return pl.program_id(0), pl.program_id(1), pl.program_id(2), args
+    outer_ref, inner_ref, *args = args
+    p = pl.program_id(1)
+    return pl.program_id(0), outer_ref[p], inner_ref[p], args
+
+
+def _causal_tiles(tile, paired, causal, qb, kb, bq, bk, q_off):
+    """Run ``tile`` where the step's place against the diagonal asks.
+    Dense grid: skip a tile wholly above it. Causal schedule: every
+    step is a visible tile."""
+    if paired:
+        tile()
+    else:
+        pl.when(_block_visible(causal, kb, bk, q_off + (qb + 1) * bq))(tile)
+
+
+def _last_key_block(paired, qb, j, nk, bq, bk, q_off):
+    """Key block j closes query block qb's row: the last one there is,
+    or under the causal schedule the last one the block sees (its key
+    blocks are 0..that one)."""
+    last = j == nk - 1
+    if not paired:
+        return last
+    return jnp.logical_or(last, jnp.logical_not(
+        _block_visible(True, j + 1, bk, q_off + (qb + 1) * bq)))
+
+
+def _fwd_kernel(*args, bq, bk, nk, causal, paired, scale, q_off,
+                dropout_p):
+    """Dense grid (BH, Tq/bq, Tk/bk), or under the causal schedule
+    (BH, visible tiles, query-major): the innermost steps stream [bk, D]
     key/value tiles from HBM while (m, l, acc) persist in VMEM scratch —
     TPU grid steps run sequentially, so the scratch carries the online-
-    softmax state across k blocks; VMEM use is O(bq*d + bk*d), independent
-    of sequence length.
+    softmax state across a query block's key blocks; VMEM use is
+    O(bq*d + bk*d), independent of sequence length.
 
     dropout_p > 0 applies attention-weight dropout (upscale_in_train):
     the keep mask multiplies the numerator accumulator only — the
     denominator stays the full softmax sum, matching the composed
     softmax→dropout→matmul graph the reference trains
     (dist_transformer.py:1044). The seed rides scalar prefetch."""
+    bh, qb, j, args = _step_ids(args, paired)
     if dropout_p > 0:
         seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, \
             m_scr, l_scr, acc_scr = args
     else:
         seed_ref = None
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = args
-    bh = pl.program_id(0)
-    qb = pl.program_id(1)
-    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _():
@@ -128,11 +231,7 @@ def _fwd_kernel(*args, bq, bk, nk, causal, scale, q_off, dropout_p):
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: key blocks wholly above the diagonal contribute nothing
-    visible = _block_visible(causal, j, bk, q_off + (qb + 1) * bq)
-
-    @pl.when(visible)
-    def _():
+    def tile():
         q = q_ref[0]                                      # [BQ, D]
         k = k_ref[0]                                      # [BK, D]
         v = v_ref[0]
@@ -151,35 +250,50 @@ def _fwd_kernel(*args, bq, bk, nk, causal, scale, q_off, dropout_p):
         acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
             pv.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    @pl.when(j == nk - 1)
+    # causal: key blocks wholly above the diagonal contribute nothing
+    _causal_tiles(tile, paired, causal, qb, j, bq, bk, q_off)
+
+    @pl.when(_last_key_block(paired, qb, j, nk, bq, bk, q_off))
     def _():
         safe_l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
         lse_ref[0] = m_scr[:] + jnp.log(safe_l)           # [BQ, 1]
 
 
-def _grid_spec(grid, in_specs, out_specs, scratch_shapes, seed):
-    """pallas_call kwargs: plain grid without dropout, scalar-prefetch
-    grid (seed in SMEM, index maps gain the leading scalar ref) with."""
+def _grid_spec(grid, in_specs, out_specs, scratch_shapes, seed, pairs=None):
+    """pallas_call kwargs. The index maps are written over (bh, outer
+    block, inner block). Plain dense grid with neither tables nor seed;
+    else a scalar-prefetch grid whose leading operands, in SMEM, are the
+    causal schedule's two tables (``pairs``: the grid becomes (bh,
+    visible tiles) and a step's blocks are its row of the tables, so
+    what lies above the diagonal is neither visited nor fetched) and
+    then the dropout seed."""
     from jax.experimental.pallas import tpu as pltpu
-    if seed is None:
+    n_scalars = len(pairs or ()) + (seed is not None)
+    if not n_scalars:
         return dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
                     scratch_shapes=scratch_shapes)
+    if pairs is not None:
+        grid = (grid[0], len(pairs[0]))
 
     def lift(spec):
         im = spec.index_map
 
         def index_map(*args):
-            # with num_scalar_prefetch=1 the scalar ref arrives as the
-            # TRAILING argument after the grid indices — drop it
-            return im(*args[:-1])
+            # the scalar refs arrive as the TRAILING arguments after the
+            # grid indices
+            ids, refs = args[:-n_scalars], args[-n_scalars:]
+            if pairs is not None:
+                bh, step = ids
+                ids = (bh, refs[0][step], refs[1][step])
+            return im(*ids)
         return pl.BlockSpec(spec.block_shape, index_map)
 
     in_specs = [lift(s) for s in in_specs]
     out_specs = (lift(out_specs) if isinstance(out_specs, pl.BlockSpec)
                  else [lift(s) for s in out_specs])
     return dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+        num_scalar_prefetch=n_scalars, grid=grid, in_specs=in_specs,
         out_specs=out_specs, scratch_shapes=scratch_shapes))
 
 
@@ -201,7 +315,9 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
     v4 = v.reshape(b * h, tk, dv)
     nk = tk // bk
     grid = (b * h, tq // bq, nk)
+    pairs = _count_causal("fwd", b * h, tq, tk, bq, bk) if causal else None
     kern = functools.partial(_fwd_kernel, bq=bq, bk=bk, nk=nk, causal=causal,
+                             paired=pairs is not None,
                              scale=scale, q_off=tk - tq,
                              dropout_p=dropout_p if seed is not None else 0.0)
     out, lse = pl.pallas_call(
@@ -227,17 +343,50 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
                 pltpu.VMEM((bq, 1), jnp.float32),
                 pltpu.VMEM((bq, dv), jnp.float32),
             ],
-            seed),
-    )(*_seed_args(seed), q4, k4, v4)
+            seed, pairs),
+    )(*(pairs or ()), *_seed_args(seed), q4, k4, v4)
     return out.reshape(b, h, tq, dv), lse.reshape(b, h, tq)
 
 
 def pick_blocks(tq, tk):
-    """Largest hardware-friendly block sizes dividing the sequence lengths
-    (bq=512/bk=1024 won the on-chip sweep at T=4096..16384)."""
+    """Largest hardware-friendly block sizes dividing the sequence lengths:
+    bq=512/bk=1024 won the on-chip sweep at T=4096..16384 — a sweep of
+    2026-08 at heads of 128 on the dense grid, non-latent. Where a call
+    was measured since, the committed table overrides it
+    (``causal_blocks``: latent attention's heads of 192 / 128 take
+    1024 x 1024 under the causal schedule)."""
     bq = next((s for s in (512, 256, 128) if tq % s == 0), None)
     bk = next((s for s in (1024, 512, 256, 128) if tk % s == 0), None)
     return bq, bk
+
+
+def causal_blocks(t, d, dv):
+    """(bq, bk) of a causal call over whole sequences of ``t`` tokens at
+    query / key heads of ``d`` and value heads of ``dv``: the committed
+    table's entry where a whole-model A/B set one, else ``pick_blocks``.
+    The one entry (PR 48, one v5e, ``train_joyai_seq8k_1chip`` — 32
+    heads of 192 / 128 over 8 192 tokens, the causal schedule): 512 x
+    1024 a step of 494.4 ms and 15 488-15 552 tokens/s, 1024 x 1024
+    **485.4 ms and 15 747-15 817** (the dense grid at 512 x 1024: 547.4
+    and 14 127-14 192). Alone, a layer's four calls (forward twice, dQ,
+    dK/dV), ms: 1024 x 1024 **36.1**, 512 x 1024 37.7, 512 x 2048 40.1
+    and 2048 x 512 48.6 (both only over the default VMEM limit), 256 x
+    2048 41.9, 256 x 1024 42.1, 512 x 512 45.2, 1024 x 512 46.6, 256 x
+    512 51.0, 2048 x 256 58.9, 512 x 256 64.8, 1024 x 256 67.6, 256 x
+    256 76.5. Square tiles multiply 36/32 of the causal half where
+    512 x 1024 multiply 72/64 — the same: it is the fewer, larger steps
+    that pay, and keys in tiles under 512 cost most."""
+    try:
+        from paddle_tpu.passes import autotune as at
+        entry = at.lookup("flash_attention", {"T": int(t), "d": int(d),
+                                              "dv": int(dv), "causal": 1})
+    except Exception:
+        entry = None
+    if entry is not None and entry.get("impl") == "flash":
+        bq, bk = int(entry["bq"]), int(entry["bk"])
+        if t % bq == 0 and t % bk == 0:
+            return bq, bk
+    return pick_blocks(t, t)
 
 
 # Benchmark-derived kernel selection (round-4 VERDICT #4 — the
@@ -266,29 +415,10 @@ def pick_blocks(tq, tk):
 #     flash_pairs' (bq, and bk = the whole key axis). No other d=64 row
 #     is kept: the region sweep's were of this file's kernel behind a
 #     [B,H,T,D] relayout, which heads of 64 no longer take.
-
-
-def _autotune_table():
-    """{(T, d, causal): (bq, bk) | None} from the committed unified
-    table — the same lookup path every tuned region uses. An absent or
-    unreadable table yields {} and flash_engage falls back to the
-    long-context heuristics (pick_blocks)."""
-    try:
-        from paddle_tpu.passes import autotune as at
-        out = {}
-        for key, entry in at.load_table().get("entries", {}).items():
-            if not key.startswith("flash_attention|"):
-                continue
-            params = dict(kv.split("=", 1) for kv in key.split("|")[1:])
-            k = (int(params["T"]), int(params["d"]),
-                 bool(int(params["causal"])))
-            if entry.get("impl") == "flash":
-                out[k] = (int(entry["bq"]), int(entry["bk"]))
-            else:
-                out[k] = None          # XLA composition won the region
-        return out
-    except Exception:
-        return {}
+#   32 heads of 192 / 128, causal, T=8192 (latent attention expanded;
+#     cell train_joyai_seq8k_1chip, 2026-10-01, PR 48): the row with
+#     ``dv`` in its key, read by ``causal_blocks`` (its docstring has
+#     the readings).
 
 
 def flash_engage(tq, tk, d, causal):
@@ -300,7 +430,7 @@ def flash_engage(tq, tk, d, causal):
     model level (the r4 fused block won T=256 by +1.5 MFU), so the
     crossover is T>=512 where the model-level A/B confirmed it (heads
     of 128 in 2026-08, heads of 64 through ``flash_pairs`` in PR 41:
-    the comment above ``_autotune_table``). Shapes
+    the comment above). Shapes
     beyond the table (T>2048, uneven tq/tk) fall back to the long-
     context heuristic blocks that won the T=4096..16384 sweep."""
     def _valid(blocks):
@@ -371,15 +501,17 @@ def _vjp_fwd(q, k, v, causal, scale, bq, bk, interpret, dropout_p, seed):
     return out, (q, k, v, out, lse, seed)
 
 
-def _dq_kernel(*args, bq, bk, nk, causal, scale, q_off, has_glse,
+def _dq_kernel(*args, bq, bk, nk, causal, paired, scale, q_off, has_glse,
                dropout_p):
-    """Grid (BH, Tq/bq, Tk/bk): accumulate dQ for one q block across k
+    """Grid (BH, Tq/bq, Tk/bk), or the causal schedule's (BH, visible
+    tiles, query-major): accumulate dQ for one q block across k
     blocks; ds = p * (mask·(dO·Vᵀ) − delta + dLSE) — the dLSE term carries
     the cotangent of the exposed log-sum-exp (∂lse/∂s_ij = p_ij), used by
     ring attention's block-merge; zero for plain attention. The dropout
     keep mask regenerates bit-exactly from the tile coordinates (only the
     dp term is masked: out = Σ_k w_k·m_k·v_k gives ds_j = w_j(m_j·dp_j −
     g·out), and delta = g·out already absorbs the mask)."""
+    bh, qb, j, args = _step_ids(args, paired)
     if dropout_p > 0:
         seed_ref, *args = args
     else:
@@ -391,18 +523,12 @@ def _dq_kernel(*args, bq, bk, nk, causal, scale, q_off, has_glse,
         glse_ref = None
         q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, \
             dq_ref, dq_scr = args
-    bh = pl.program_id(0)
-    qb = pl.program_id(1)
-    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    visible = _block_visible(causal, j, bk, q_off + (qb + 1) * bq)
-
-    @pl.when(visible)
-    def _():
+    def tile():
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -420,15 +546,19 @@ def _dq_kernel(*args, bq, bk, nk, causal, scale, q_off, has_glse,
             ds.astype(k.dtype), k,
             preferred_element_type=jnp.float32) * scale
 
-    @pl.when(j == nk - 1)
+    _causal_tiles(tile, paired, causal, qb, j, bq, bk, q_off)
+
+    @pl.when(_last_key_block(paired, qb, j, nk, bq, bk, q_off))
     def _():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*args, bq, bk, nq, causal, scale, q_off, has_glse,
+def _dkv_kernel(*args, bq, bk, nq, causal, paired, scale, q_off, has_glse,
                 dropout_p):
-    """Grid (BH, Tk/bk, Tq/bq): accumulate dK/dV for one k block across q
+    """Grid (BH, Tk/bk, Tq/bq), or the causal schedule's (BH, visible
+    tiles, key-major): accumulate dK/dV for one k block across q
     blocks; dV = (p·mask)ᵀ·dO, dK = scale · dsᵀ·Q."""
+    bh, kb, i, args = _step_ids(args, paired)
     if dropout_p > 0:
         seed_ref, *args = args
     else:
@@ -440,20 +570,17 @@ def _dkv_kernel(*args, bq, bk, nq, causal, scale, q_off, has_glse,
         glse_ref = None
         q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, \
             dk_ref, dv_ref, dk_scr, dv_scr = args
-    bh = pl.program_id(0)
-    kb = pl.program_id(1)
-    i = pl.program_id(2)
+    first = i == 0
+    if paired:          # a key block's query blocks are its first..nq-1
+        first = jnp.logical_or(first, jnp.logical_not(
+            _block_visible(True, kb, bk, q_off + i * bq)))
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    # q block i sees this k block iff its LAST query reaches it
-    visible = _block_visible(causal, kb, bk, q_off + (i + 1) * bq)
-
-    @pl.when(visible)
-    def _():
+    def tile():
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -477,6 +604,9 @@ def _dkv_kernel(*args, bq, bk, nq, causal, scale, q_off, has_glse,
         dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # scale·dsᵀ·Q
+
+    # q block i sees this k block iff its LAST query reaches it
+    _causal_tiles(tile, paired, causal, i, kb, bq, bk, q_off)
 
     @pl.when(i == nq - 1)
     def _():
@@ -507,11 +637,16 @@ def _flash_bwd_impl(causal, scale, bq, bk, interpret, res, g, glse,
              if has_glse else None)
     q_off = tk - tq
     dp_eff = dropout_p if seed is not None else 0.0
+    q_pairs = k_pairs = None
+    if causal:
+        q_pairs = _count_causal("dq", b * h, tq, tk, bq, bk)
+        k_pairs = _count_causal("dkv", b * h, tq, tk, bq, bk)
     glse_in = ([glse4], [pl.BlockSpec((1, bq, 1),
                                       lambda bh, i, j: (bh, i, 0))])         if has_glse else ([], [])
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, bq=bq, bk=bk, nk=nk, causal=causal,
+                          paired=q_pairs is not None,
                           scale=scale, q_off=q_off, has_glse=has_glse,
                           dropout_p=dp_eff),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
@@ -528,13 +663,15 @@ def _flash_bwd_impl(causal, scale, bq, bk, interpret, res, g, glse,
             ] + glse_in[1],
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
             [pltpu.VMEM((bq, d), jnp.float32)],
-            seed),
-    )(*_seed_args(seed), q4, k4, v4, g4, lse4, delta4, *glse_in[0])
+            seed, q_pairs),
+    )(*(q_pairs or ()), *_seed_args(seed), q4, k4, v4, g4, lse4, delta4,
+      *glse_in[0])
 
     glse_in_kv = ([glse4], [pl.BlockSpec((1, bq, 1),
                                          lambda bh, j, i: (bh, i, 0))])         if has_glse else ([], [])
     dk, dval = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, nq=nq, causal=causal,
+                          paired=k_pairs is not None,
                           scale=scale, q_off=q_off, has_glse=has_glse,
                           dropout_p=dp_eff),
         out_shape=[
@@ -558,8 +695,9 @@ def _flash_bwd_impl(causal, scale, bq, bk, interpret, res, g, glse,
             ],
             [pltpu.VMEM((bk, d), jnp.float32),
              pltpu.VMEM((bk, dv), jnp.float32)],
-            seed),
-    )(*_seed_args(seed), q4, k4, v4, g4, lse4, delta4, *glse_in_kv[0])
+            seed, k_pairs),
+    )(*(k_pairs or ()), *_seed_args(seed), q4, k4, v4, g4, lse4, delta4,
+      *glse_in_kv[0])
 
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
             dval.reshape(b, h, tk, dv))

@@ -121,9 +121,9 @@ def _flash_latent():
     """fwd + bwd at latent attention's head sizes — query / key heads of
     192 (128 + 64 rotated), value heads of 128 — over the trainer's
     8 192-token sequence of 32 heads, the blocks ``causal_attention``
-    picks (ISSUE 47)."""
-    from paddle_tpu.ops.pallas import flash_attention, pick_blocks
-    bq, bk = pick_blocks(8192, 8192)
+    picks (ISSUE 47; 1024 x 1024 under the causal schedule, PR 48)."""
+    from paddle_tpu.ops.pallas import causal_blocks, flash_attention
+    bq, bk = causal_blocks(8192, 192, 128)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, True, 192 ** -0.5, bq, bk)
@@ -328,25 +328,31 @@ def test_one_chip_step_at_heads_of_64_lowers_as_before_the_wrap(
 
 def test_head_size_128_lowers_as_before_the_pair_kernels(chip, monkeypatch):
     """PR 41 gave heads of 64 kernels of their own; heads of 128 keep
-    ``flash_attention`` op for op. Held as recorded at the parent
-    commit: the step's StableHLO around the kernels, and the jaxpr of
-    forward + backward — the kernels' bodies — with and without dropout
-    (the hash helpers were regrouped, their operations were not)."""
+    ``flash_attention`` op for op: the step's StableHLO around the
+    kernels, and the jaxpr of forward + backward — the kernels' bodies —
+    with and without dropout. NON-causal they are held as the parent of
+    PR 48 traced them (PR 41 regrouped the hash helpers, not their
+    operations; PR 48 left the dense grid's text alone). Causal they are
+    PR 48's:
+    the grid over the visible tiles (here one), two tables in SMEM
+    ahead of the seed."""
     from paddle_tpu.ops.pallas import flash_attention
     step = _attention_step_text(2, None, chip, monkeypatch, compiled=False)
     assert step.count("tpu_custom_call") == 1
-    assert _scrubbed_sha(step) == "c6d7980dd64b4698"
+    assert _scrubbed_sha(step) == "a68d8bc779c38f5b"
     seed = jnp.asarray([3], I32)
     shape = jax.ShapeDtypeStruct((2, 2, 512, 128), BF16)
-    for dropout_p, want in ((0.0, "2ff688339b6830b6"),
-                            (0.3, "565373baa98ec1ae")):
+    for causal, dropout_p, want in ((False, 0.0, "fc8dea230564f74b"),
+                                    (False, 0.3, "59ab0c2570d02d80"),
+                                    (True, 0.0, "8dcd63c272872fe7"),
+                                    (True, 0.3, "f5c7dd0636be26f6")):
         def loss(q, k, v):
             return jnp.sum(flash_attention(
-                q, k, v, True, None, 512, 512, False, dropout_p,
+                q, k, v, causal, None, 512, 512, False, dropout_p,
                 seed if dropout_p > 0 else None).astype(F32))
         jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
             shape, shape, shape))
-        assert _scrubbed_sha(jaxpr) == want, dropout_p
+        assert _scrubbed_sha(jaxpr) == want, (causal, dropout_p)
 
 
 # ---------------------------------------------------------------------------

@@ -290,19 +290,40 @@ def test_flash_kernels_at_heads_of_192_and_128_forward_and_backward():
             np.testing.assert_allclose(got, exp, rtol=2e-4, atol=2e-4)
 
 
-def test_the_trainer_through_the_interpreted_kernels(monkeypatch):
+# 128 tokens: one tile a head (``pick_blocks`` takes the whole sequence);
+# 384: three query blocks by three key blocks of 128 — the causal
+# schedule's six visible tiles of nine
+@pytest.mark.parametrize("seq_len", [128, 384])
+def test_the_trainer_through_the_interpreted_kernels(monkeypatch, seq_len):
     """The whole op with its kernels forced (interpreted): the same loss
-    and gradients as the composed attention's."""
-    build, main, loss, _t, scope, exe = trainer(128, n_layer=2)
+    and gradients as the composed attention's, and the kernels' grids
+    run over the visible tiles alone
+    (``paddle_flash_causal_blocks_total``)."""
+    import sys
+    from paddle_tpu.ops import pallas as pk
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    build, main, loss, _t, scope, exe = trainer(seq_len, n_layer=2)
     feed = sample(build, batch=1)
     fetch = [loss.name, "lm_l1_mla.wuq@GRAD", "lm_l1_mla.wdkv@GRAD"]
     want = [np.asarray(o) for o in
             exe.run(main, feed=feed, scope=scope, fetch_list=fetch)]
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
-    build, main, loss, _t, scope, exe = trainer(128, n_layer=2)
+    read = lambda: {(kern, kind): fa.CAUSAL_BLOCKS.labels(  # noqa: E731
+        kernel=kern, kind=kind).value for kern in ("fwd", "dq", "dkv")
+        for kind in ("visited", "computed")}
+    before = read()
+    build, main, loss, _t, scope, exe = trainer(seq_len, n_layer=2)
     got = exe.run(main, feed=feed, scope=scope, fetch_list=fetch)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), b, rtol=2e-4, atol=1e-6)
+    grew = {k: n - before[k] for k, n in read().items()}
+    bq, bk = pk.pick_blocks(seq_len, seq_len)
+    assert (seq_len // bq, seq_len // bk) == {128: (1, 1),
+                                              384: (3, 3)}[seq_len]
+    tiles = {128: 1, 384: 6}[seq_len]
+    for kern in ("fwd", "dq", "dkv"):
+        assert grew[kern, "visited"] == grew[kern, "computed"] > 0
+        assert grew[kern, "visited"] % (build["n_head"] * tiles) == 0
 
 
 def test_the_full_view_is_refused_by_name_where_it_does_not_exist():

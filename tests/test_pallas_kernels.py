@@ -252,6 +252,197 @@ def test_flash_attention_bf16_fwd_bwd_parity():
         assert np.abs(a32 - b32).max() / denom < 5e-2, name
 
 
+# ------------------------------------------------------------------ ISSUE 48
+# the causal schedule: the grid runs over the visible tiles alone
+
+def _flash_module():
+    import sys
+    import paddle_tpu.ops.pallas  # noqa: F401  (the name is the function's)
+    return sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+
+
+def _composed(q, k, v, scale, dropout_p, seed):
+    """Causal softmax -> hashed dropout -> product, plain jnp, with the
+    diagonal shifted by tk - tq; -> (context, log-sum-exp)."""
+    fa = _flash_module()
+    b, h, tq, _ = q.shape
+    tk = k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    qpos = (tk - tq) + jnp.arange(tq)
+    s = jnp.where(qpos[:, None] >= jnp.arange(tk)[None, :], s, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)
+    if dropout_p > 0:
+        w = w * fa.hash_keep_mask(
+            seed, jnp.arange(b * h).reshape(b, h, 1, 1),
+            qpos[None, None, :, None], jnp.arange(tk)[None, None, None, :],
+            dropout_p)
+    return (jnp.einsum("bhqk,bhkd->bhqd", w, v),
+            jax.scipy.special.logsumexp(s, axis=-1))
+
+
+# (tq, tk) -> blocks with bq < bk, bq == bk, bq > bk; with tq < tk the
+# diagonal starts 24 keys in, inside a key block of 16
+_CAUSAL_SHAPES = {(48, 48): ((8, 16), (16, 16), (16, 8)),
+                  (24, 48): ((8, 16), (8, 8), (24, 8))}
+_CAUSAL_GRID = [(tq, tk, bq, bk) for (tq, tk), blocks
+                in _CAUSAL_SHAPES.items() for bq, bk in blocks]
+
+
+@pytest.mark.parametrize("with_lse", [False, True],
+                         ids=["flash_attention", "flash_attention_lse"])
+@pytest.mark.parametrize("dropout_p", [0.0, 0.3])
+@pytest.mark.parametrize("d,dv", [(192, 128), (128, 128)])
+@pytest.mark.parametrize("tq,tk,bq,bk", _CAUSAL_GRID)
+def test_causal_schedule_forward_and_gradients(tq, tk, bq, bk, d, dv,
+                                               dropout_p, with_lse):
+    """Forward and all three gradients of the causal kernels
+    (interpreted) against the composed attention: query blocks smaller
+    than, equal to and larger than the key blocks, the diagonal shifted
+    by tk - tq, both head sizes, dropout's keep mask from the tile's own
+    coordinates, and the log-sum-exp's cotangent."""
+    fa = _flash_module()
+    rng = np.random.RandomState(tq + bq * 7 + bk)
+    q, k = (jnp.asarray(rng.randn(1, 2, t, d), jnp.float32)
+            for t in (tq, tk))
+    v = jnp.asarray(rng.randn(1, 2, tk, dv), jnp.float32)
+    g = jnp.asarray(rng.randn(1, 2, tq, dv), jnp.float32)
+    glse = jnp.asarray(rng.randn(1, 2, tq), jnp.float32)
+    seed = jnp.asarray([11], jnp.int32)
+    scale = d ** -0.5
+
+    def kernels(q, k, v):
+        args = (q, k, v, True, scale, bq, bk, True, dropout_p,
+                seed if dropout_p > 0 else None)
+        if with_lse:
+            return fa.flash_attention_lse(*args)
+        return fa.flash_attention(*args)
+
+    def composed(q, k, v):
+        out = _composed(q, k, v, scale, dropout_p, 11)
+        return out if with_lse else out[0]
+
+    with jax.default_matmul_precision("highest"):
+        got, pull = jax.vjp(kernels, q, k, v)
+        want, want_pull = jax.vjp(composed, q, k, v)
+        cot = (g, glse) if with_lse else g
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+        for a, b in zip(pull(cot), want_pull(cot)):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("key_major", [False, True],
+                         ids=["query_major", "key_major"])
+@pytest.mark.parametrize("tq,tk,bq,bk", _CAUSAL_GRID + [
+    (8192, 8192, 512, 1024), (8192, 8192, 512, 512), (2048, 8192, 256, 1024),
+    (4096, 4096, 1024, 256)])
+def test_causal_schedule_visits_the_visible_tiles(tq, tk, bq, bk, key_major):
+    """The schedule's tiles ARE the tiles with a key at or under some
+    query, each once, and the order is what the kernels' first / last
+    step rests on: a query block's key blocks are 0..its last, a key
+    block's query blocks its first..the last."""
+    fa = _flash_module()
+    qi, kj = fa.causal_schedule(tq, tk, bq, bk, key_major)
+    under = (np.arange(tq)[:, None] + (tk - tq) >= np.arange(tk)[None, :]) \
+        .reshape(tq // bq, bq, tk // bk, bk)
+    assert sorted(zip(qi.tolist(), kj.tolist())) == \
+        [tuple(t) for t in np.argwhere(under.any(axis=(1, 3))).tolist()]
+    outer, inner = (kj, qi) if key_major else (qi, kj)
+    assert (np.diff(outer) >= 0).all()
+    for o in np.unique(outer):
+        run = inner[outer == o]
+        np.testing.assert_array_equal(
+            run, np.arange(run[0], run[0] + len(run)))
+        assert run[-1] == tq // bq - 1 if key_major else run[0] == 0
+
+
+def test_causal_schedule_is_refused_where_a_query_block_sees_no_key():
+    """tq > tk: the first queries see no key; the call keeps the dense
+    grid (rows of zeros, as before) and the counter says so."""
+    fa = _flash_module()
+    assert fa.causal_schedule(48, 24, 8, 8) is None
+    read = lambda: {k: fa.CAUSAL_BLOCKS.labels(kernel="fwd", kind=k).value  # noqa
+                    for k in ("visited", "computed")}
+    before = read()
+    q = jnp.ones((1, 1, 48, 8), jnp.float32)
+    out = fa.flash_attention(q, q[:, :, :24], q[:, :, :24], True, None,
+                             8, 8, True)
+    np.testing.assert_array_equal(out[0, 0, :24], 0.0)
+    np.testing.assert_allclose(out[0, 0, 24:], 1.0, rtol=1e-6)
+    after = read()
+    # 6 x 3 tiles visited, the 6 under the shifted diagonal computed
+    assert {k: after[k] - before[k] for k in after} == {
+        "visited": 18, "computed": 6}
+
+
+def test_causal_blocks_counter_on_the_trained_cells_shape():
+    """``paddle_flash_causal_blocks_total`` at the trained latent cell's
+    call (32 heads of 192 / 128 over 8 192 tokens, the blocks
+    ``causal_attention`` picks): every visited tile computes — 36 a
+    head at 1024 x 1024, where the dense grid at 512 x 1024 visited
+    128 to compute 72."""
+    from paddle_tpu.ops import pallas as pk
+    fa = _flash_module()
+    read = lambda: {(kern, kind): fa.CAUSAL_BLOCKS.labels(  # noqa: E731
+        kernel=kern, kind=kind).value
+        for kern in ("fwd", "dq", "dkv")
+        for kind in ("visited", "computed")}
+    bq, bk = pk.causal_blocks(8192, 192, 128)
+    qk = jax.ShapeDtypeStruct((1, 32, 8192, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
+    before = read()
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, True, 192 ** -0.5, bq, bk).astype(jnp.float32)),
+        argnums=(0, 1, 2)), qk, qk, v)
+    grew = {k: n - before[k] for k, n in read().items()}
+    assert (bq, bk) == (1024, 1024)
+    for kern in ("fwd", "dq", "dkv"):
+        # the gradient's trace runs the forward once more (custom_vjp)
+        assert grew[kern, "visited"] / (32 * 36) in (1, 2), grew
+        assert grew[kern, "computed"] == grew[kern, "visited"]
+    from paddle_tpu.observability import exporters, metrics as obs_metrics
+    exporters._preregister_catalog()
+    assert "paddle_flash_causal_blocks_total" in \
+        obs_metrics.default_registry().snapshot()
+
+
+def _jaxpr_sha(fn, *shapes):
+    """sha256 of a jaxpr's text — the kernels' bodies and grids —
+    without what names the checkout (source locations)."""
+    import hashlib
+    import re
+    text = re.sub(r" at [^\s]+:\d+", "", str(jax.make_jaxpr(fn)(*shapes)))
+    text = re.sub(r"/[\w/.\-]+\.py(:\d+)?", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("causal,tq,tk,dropout_p,want", [
+    (False, 1024, 2048, 0.0, "6d5280df856fddbf"),
+    (False, 1024, 2048, 0.3, "1747e32308e7ea21"),
+    (True, 2048, 1024, 0.0, "3c901130ddd1f123"),
+    (True, 2048, 1024, 0.3, "229d86e6195f529c")])
+def test_the_dense_grid_lowers_as_before_the_causal_schedule(
+        causal, tq, tk, dropout_p, want):
+    """A non-causal call, and a causal one with tq > tk, trace to the
+    jaxpr they traced to at PR 47 (forward, dQ and dK/dV with the
+    log-sum-exp's cotangent, heads of 192 / 128, blocks 256 x 512):
+    held as recorded at the parent commit."""
+    fa = _flash_module()
+    seed = jnp.asarray([3], jnp.int32)
+
+    def loss(q, k, v):
+        o, lse = fa.flash_attention_lse(
+            q, k, v, causal, None, 256, 512, False, dropout_p,
+            seed if dropout_p > 0 else None)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse * lse)
+
+    shapes = [jax.ShapeDtypeStruct((2, 2, t, d), jnp.bfloat16)
+              for t, d in ((tq, 192), (tk, 192), (tk, 128))]
+    assert _jaxpr_sha(jax.grad(loss, argnums=(0, 1, 2)), *shapes) == want
+
+
 # ------------------------------------------------------------------ ISSUE 34
 # attend_pages: a slot's live pages attended in place under a mask,
 # against the gathered path (the page gather, then _decode_contract)

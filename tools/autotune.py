@@ -13,9 +13,11 @@ Kinds:
 - ``flash_attention``: fwd + full dq/dk/dv bwd of the attention region
   at each (T, d_head, causal) across the Pallas kernel's (bq, bk) grid
   vs the XLA fused-dot composition (the sweep tools/flash_autotune.py
-  shipped, now writing the unified format). Where a full-model A/B
-  exists, re-commit it with ``source="model-ab"`` — model rows override
-  region sweeps (docs/performance.md).
+  shipped, now writing the unified format), and of latent attention's
+  expanded heads (192 / 128, causal, one 8 192-token sequence of 32).
+  Where a full-model A/B exists, re-commit it with
+  ``source="model-ab"`` — model rows override region sweeps
+  (docs/performance.md).
 - ``pass_pipeline``: full-model A/B of IR-pass candidate sets through
   ``bench.py --model M --passes ...`` subprocesses (fresh backend per
   candidate); the winning set is committed per (model, batch bucket)
@@ -54,11 +56,21 @@ def _device_name() -> str:
 
 # ------------------------------------------------------------------ flash
 
+# (T, d, dv, heads): the attention regions of the fused block (value
+# heads as wide as the query / key heads'), and latent attention
+# expanded as the trained cell runs it — causal only, one sequence, and
+# no XLA composition beside it (its [32, 8192, 8192] float32 scores do
+# not fit the chip)
+FLASH_REGIONS = [(T, d, d, 8) for T in (256, 512, 1024, 2048)
+                 for d in (64, 128)] + [(8192, 192, 128, 32)]
+
+
 def sweep_flash(table, tokens=8192):
     """(bq, bk) grid vs the XLA composition, committed per
-    (T, d, causal) — the tools/flash_autotune.py sweep in the unified
-    format. Timing goes through autotune.measure_ms so the measurement
-    counter records every sample (and CI's forbid guard would trip)."""
+    (T, d, causal) — and per dv where the value heads are of another
+    size — in the unified format. Timing goes through
+    autotune.measure_ms so the measurement counter records every sample
+    (and CI's forbid guard would trip)."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -83,67 +95,69 @@ def sweep_flash(table, tokens=8192):
                 argnums=(0, 1, 2))(*a)))
 
     rng = np.random.RandomState(0)
-    for T in (256, 512, 1024, 2048):
-        for d in (64, 128):
-            h, b = 8, max(1, tokens // T)
-            q, k, v = (jnp.asarray(rng.randn(b, h, T, d), np.float32)
-                       .astype(jnp.bfloat16) * 0.3 for _ in range(3))
-            scale = float(d) ** -0.5
-            for causal in (False, True):
-                xla_ms = at.measure_ms(
-                    grad_fn(lambda q, k, v, c=causal:
-                            xla_attention(q, k, v, c, scale)), q, k, v)
-                best = None
-                for bq in (128, 256, 512):
-                    if T % bq:
+    for T, d, dv, h in FLASH_REGIONS:
+        latent = dv != d
+        b = max(1, tokens // T)
+        q, k, v = (jnp.asarray(rng.randn(b, h, T, w), np.float32)
+                   .astype(jnp.bfloat16) * 0.3 for w in (d, d, dv))
+        scale = float(d) ** -0.5
+        for causal in ((True,) if latent else (False, True)):
+            xla_ms = None if latent else at.measure_ms(
+                grad_fn(lambda q, k, v, c=causal:
+                        xla_attention(q, k, v, c, scale)), q, k, v)
+            best = None
+            for bq in (128, 256, 512, 1024):
+                if T % bq or (bq == 1024 and not latent):
+                    continue
+                for bk in (128, 256, 512, 1024):
+                    if T % bk:
                         continue
-                    for bk in (128, 256, 512, 1024):
-                        if T % bk:
-                            continue
-                        try:
-                            ms = at.measure_ms(
-                                grad_fn(lambda q, k, v, c=causal,
-                                        bq=bq, bk=bk:
-                                        pk.flash_attention(
-                                            q, k, v, c, scale, bq, bk)),
-                                q, k, v)
-                        except Exception as e:   # over-VMEM config etc.
-                            print(json.dumps(
-                                {"T": T, "d": d, "causal": causal,
-                                 "bq": bq, "bk": bk,
-                                 "error": str(e)[:80]}), flush=True)
-                            continue
-                        print(json.dumps(
-                            {"T": T, "d": d, "causal": causal,
-                             "bq": bq, "bk": bk,
-                             "flash_ms": round(ms, 3),
-                             "xla_ms": round(xla_ms, 3)}), flush=True)
-                        if best is None or ms < best[0]:
-                            best = (ms, bq, bk)
-                if best is None:
-                    continue
-                params = at.flash_params(T, d, causal)
-                existing = table.get("entries", {}).get(
-                    at.fingerprint("flash_attention", params))
-                if existing and existing.get("source") == "model-ab":
-                    # model rows OVERRIDE region sweeps (the round-5
-                    # precedence rule: region-optimal blocks measured
-                    # slower at the model level) — a region re-sweep
-                    # must never clobber a model-verified winner
+                    row = {"T": T, "d": d, "causal": causal,
+                           "bq": bq, "bk": bk, **({"dv": dv} if latent
+                                                  else {})}
+                    try:
+                        ms = at.measure_ms(
+                            grad_fn(lambda q, k, v, c=causal,
+                                    bq=bq, bk=bk:
+                                    pk.flash_attention(
+                                        q, k, v, c, scale, bq, bk)),
+                            q, k, v)
+                    except Exception as e:   # over-VMEM config etc.
+                        print(json.dumps({**row, "error": str(e)[:80]}),
+                              flush=True)
+                        continue
                     print(json.dumps(
-                        {"T": T, "d": d, "causal": causal,
-                         "kept": "model-ab entry", **existing}),
+                        {**row, "flash_ms": round(ms, 3),
+                         "xla_ms": xla_ms and round(xla_ms, 3)}),
                         flush=True)
-                    continue
-                flash_wins = best[0] < xla_ms
-                entry = {"source": "region-sweep",
-                         "flash_ms": round(best[0], 3),
-                         "xla_ms": round(xla_ms, 3)}
-                if flash_wins:
-                    entry.update(impl="flash", bq=best[1], bk=best[2])
-                else:
-                    entry["impl"] = "xla"
-                at.record(table, "flash_attention", params, entry)
+                    if best is None or ms < best[0]:
+                        best = (ms, bq, bk)
+            if best is None:
+                continue
+            params = at.flash_params(T, d, causal)
+            if latent:      # the key ``causal_blocks`` reads: T exact
+                params = {"T": T, "d": d, "dv": dv, "causal": 1}
+            existing = table.get("entries", {}).get(
+                at.fingerprint("flash_attention", params))
+            if existing and existing.get("source") == "model-ab":
+                # model rows OVERRIDE region sweeps (the round-5
+                # precedence rule: region-optimal blocks measured
+                # slower at the model level) — a region re-sweep
+                # must never clobber a model-verified winner
+                print(json.dumps(
+                    {"T": T, "d": d, "causal": causal,
+                     "kept": "model-ab entry", **existing}),
+                    flush=True)
+                continue
+            entry = {"source": "region-sweep",
+                     "flash_ms": round(best[0], 3)}
+            if xla_ms is not None:
+                entry["xla_ms"] = round(xla_ms, 3)
+            if xla_ms is None or best[0] < xla_ms:
+                entry.update(impl="flash", bq=best[1], bk=best[2])
+            else:
+                entry["impl"] = "xla"
+            at.record(table, "flash_attention", params, entry)
     return table
 
 
