@@ -157,6 +157,11 @@ def emit_op_seq(program: ir.ProgramDesc, block: ir.BlockDesc,
     This is the single trace-time interpreter loop; control-flow emitters
     call back into it for their sub-blocks (replacing the reference's
     per-iteration child-scope interpretation, while_op.cc:64-70)."""
+    from paddle_tpu.ops import grad_ops          # ops import core
+    # a forward op tagged for recomputation and its `__vjp__` come from
+    # one trace: what the backward keeps is what the forward op computed
+    with_backward = grad_ops.recomputed_pairs(block, indices)
+    linked: Dict[int, Any] = {}
     for i in indices:
         op = block.ops[i]
         spec = get_op(op.type)
@@ -171,7 +176,7 @@ def emit_op_seq(program: ir.ProgramDesc, block: ir.BlockDesc,
         ctx = EmitContext(base_key=base_key, step_base_key=step_base,
                           op_index=block.idx * 100_000 + op_salt,
                           is_test=is_test,
-                          program=program, dist=dist, op=op)
+                          program=program, dist=dist, op=op, linked=linked)
         ins = {}
         for slot, names in op.inputs.items():
             try:
@@ -195,6 +200,9 @@ def emit_op_seq(program: ir.ProgramDesc, block: ir.BlockDesc,
                 outs = sr.try_sparse_emit(op.type, ins, attrs)
                 if outs is None:
                     outs = spec.emit(ctx, sr.densify_ins(ins), attrs)
+            elif i in with_backward:
+                outs = grad_ops.emit_with_backward(ctx, op, with_backward[i],
+                                                   ins, attrs)
             else:
                 outs = spec.emit(ctx, ins, attrs)
         for slot, names in op.outputs.items():

@@ -70,6 +70,10 @@ class EmitContext:
     # emitter calls) — lets emitters read their own var NAMES, e.g. the
     # sparse-apply telemetry site needs the Param name
     op: Any = None
+    # shared by the ops of one lowered sequence (lowering.emit_op_seq): a
+    # recomputed forward op emitted under its backward's jax.vjp leaves
+    # the transpose here for its `__vjp__` op (ops/grad_ops.py)
+    linked: Any = None
 
     def key(self, salt: int = 0):
         return jax.random.fold_in(

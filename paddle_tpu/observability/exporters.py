@@ -282,6 +282,9 @@ def _preregister_catalog():
                 # what a causal flash kernel's grid visits, computes and
                 # masks (paddle_flash_causal_blocks_total{kernel, kind})
                 "paddle_tpu.ops.pallas.flash_attention",
+                # what a recomputed op's backward keeps beside its inputs
+                # (paddle_recompute_kept_values_total{op}, _bytes_total)
+                "paddle_tpu.ops.grad_ops",
                 "paddle_tpu.distributed.sharded_table"):
         try:
             importlib.import_module(mod)

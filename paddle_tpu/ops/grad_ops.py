@@ -6,11 +6,24 @@ from python backward.py:394 through core.get_grad_op_desc). TPU-native
 re-design: a single `__vjp__` op whose emitter re-traces the forward
 emitter under `jax.vjp` — every op's backward rule is derived automatically
 and XLA's CSE merges the re-traced forward with the original, so there is no
-duplicate compute in the compiled executable.
+duplicate compute in the compiled executable. (A Mosaic call is the
+exception: its serialized body carries the trace's call stack and two
+traces never compare equal — a kernel's backward takes (inputs,
+cotangent) alone so that the re-traced forward call is dead code, as
+ops/attention_block.py does, or the op is tagged for recomputation.)
+
+An op tagged `__remat__` (contrib/recompute.py) is not re-traced: the
+lowering loop emits the forward op itself under the backward's
+``jax.vjp(jax.checkpoint(..., policy=KEPT))`` (``emit_with_backward``)
+and the `__vjp__` op calls the transpose that trace left. The backward
+keeps the op's inputs and the values a kernel has named, and those are
+the forward op's own.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -19,6 +32,7 @@ import jax.numpy as jnp
 from paddle_tpu.core import ir
 from paddle_tpu.core import selected_rows as sr
 from paddle_tpu.core.registry import EmitContext, get_op, register_op
+from paddle_tpu.observability import metrics as _metrics
 
 
 def _slot_layout(slots: Dict[str, List[str]]) -> List[Tuple[str, int]]:
@@ -105,23 +119,143 @@ def _unflatten(vals: List[Any], layout) -> Dict[str, List[Any]]:
     return d
 
 
+# exporter-catalog families (docs/observability.md). Count LOWERINGS:
+# each time a recomputed op's backward is traced into a program, the
+# values its checkpoint keeps beside the op's inputs — what a kernel of
+# the op has named (contrib/recompute.py:KEPT) — and their bytes, by the
+# forward op's type. Read off the vjp's residuals at trace time: no host
+# work in a step. An op in which nothing is named counts 0.
+KEPT_VALUES = _metrics.counter(
+    "paddle_recompute_kept_values_total",
+    "Named values a recomputed op's backward keeps beside its inputs, "
+    "by lowering", labelnames=("op",))
+KEPT_BYTES = _metrics.counter(
+    "paddle_recompute_kept_bytes_total",
+    "Bytes of the named values a recomputed op's backward keeps, by "
+    "lowering", labelnames=("op",))
+
+
+def _count_kept(op_type, vjp_fn, flat_in):
+    """A checkpoint's residuals are its function's inputs and what its
+    policy saved: the latter are the vjp's array leaves that are none of
+    the op's inputs (a schedule table is a numpy constant, no array of
+    the trace)."""
+    given = {id(v) for v in flat_in}
+    kept = [r for r in jax.tree_util.tree_leaves(vjp_fn)
+            if isinstance(r, jax.Array) and id(r) not in given]
+    KEPT_VALUES.labels(op=op_type).inc(len(kept))
+    KEPT_BYTES.labels(op=op_type).inc(
+        sum(r.size * r.dtype.itemsize for r in kept))
+
+
+def _traced_vjp(fwd_ctx: EmitContext, fwd_op, flat_in, diff_idx):
+    """The forward op traced under ``jax.vjp`` in its differentiable
+    inputs -> (outs, float_out, primals, vjp_fn): every declared output
+    flat (what ``emit_with_backward`` hands on as the forward op's), the
+    flat positions of those that carry cotangents, their values, and the
+    transpose over them."""
+    spec = get_op(fwd_op.type)
+    in_layout = _slot_layout(fwd_op.inputs)
+    out_layout = _slot_layout(fwd_op.outputs)
+    diff_vals = tuple(flat_in[i] for i in diff_idx)
+
+    def forward_flat(diff_vals, ctx=fwd_ctx):
+        vals = list(flat_in)
+        for i, v in zip(diff_idx, diff_vals):
+            vals[i] = v
+        outs = spec.emit(ctx, _unflatten(vals, in_layout), fwd_op.attrs)
+        return tuple(_flatten(outs, out_layout))
+
+    # determine which declared outputs are float (can carry cotangents);
+    # a probe, not the op's emission (no `op`: lowering counters pass)
+    out_avals = jax.eval_shape(
+        functools.partial(forward_flat,
+                          ctx=dataclasses.replace(fwd_ctx, op=None)),
+        diff_vals)
+    float_out = [k for k, a in enumerate(out_avals)
+                 if jnp.issubdtype(a.dtype, jnp.inexact)]
+
+    def forward_float_only(diff_vals):
+        outs = forward_flat(diff_vals)
+        return tuple(outs[k] for k in float_out), outs
+
+    remat = bool(fwd_op.attrs.get("__remat__"))
+    if remat:
+        # contrib.recompute: the backward keeps this op's INPUTS and the
+        # values a kernel of it has named (contrib/recompute.py:KEPT —
+        # dear to remake, no larger than the inputs: flash attention's
+        # output and log-sum-exp) and re-runs the rest of the forward
+        # (jax.checkpoint) — trades FLOPs for activation memory (e.g.
+        # projections, the broadcast key, attention probs [B,H,T,T]
+        # never persist between fwd and bwd). An op in which nothing is
+        # named keeps its inputs alone.
+        from paddle_tpu.contrib.recompute import KEPT
+        forward_float_only = jax.checkpoint(
+            forward_float_only,
+            policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+
+    primals, vjp_fn, outs = jax.vjp(forward_float_only, diff_vals,
+                                    has_aux=True)
+    if remat and fwd_ctx.step_base_key is not None:
+        _count_kept(fwd_op.type, vjp_fn, flat_in)
+    return outs, float_out, primals, vjp_fn
+
+
+def recomputed_pairs(block: ir.BlockDesc, indices) -> Dict[int, Any]:
+    """{position of a forward op tagged for recomputation: its `__vjp__`
+    op} over the ops at `indices` — paired by the snapshot's identity
+    (``ir_pass.vjp_snapshot_key``) and the same inputs, the forward op
+    first. The lowering loop emits such a pair from ONE trace
+    (``emit_with_backward``)."""
+    tagged = {}
+    for i in indices:
+        op = block.ops[i]
+        if op.type != "__vjp__" and op.attrs.get("__remat__"):
+            tagged[i] = op
+    if not tagged:
+        return {}
+    from paddle_tpu.fluid.ir_pass import vjp_snapshot_key
+    at = {vjp_snapshot_key(op.type, op.outputs): i
+          for i, op in tagged.items()}
+    pairs = {}
+    for i in indices:
+        op = block.ops[i]
+        if op.type != "__vjp__":
+            continue
+        snap = op.attrs.get("fwd_op", {})
+        j = at.get(vjp_snapshot_key(snap.get("type"), snap.get("outputs")))
+        if (j is not None and j < i
+                and snap.get("attrs", {}).get("__remat__")
+                and op.inputs.get("FwdIn") == _flatten(
+                    tagged[j].inputs, _slot_layout(tagged[j].inputs))):
+            pairs[j] = op
+    return pairs
+
+
+def emit_with_backward(ctx: EmitContext, op, vjp_op, ins, attrs):
+    """Emit forward `op` under the ``jax.vjp`` of its `__vjp__` op and
+    leave the transpose in ``ctx.linked`` for it: the op's outputs and
+    its backward's residuals come from one trace, so what the checkpoint
+    keeps IS what the forward op computed. (Two traces of one Mosaic call
+    do not merge: the serialized kernel carries each trace's call stack,
+    and XLA's CSE compares it byte for byte.)"""
+    fwd_op = ir.OpDesc(type=op.type, inputs=op.inputs, outputs=op.outputs,
+                       attrs=attrs)
+    flat_in = _flatten(ins, _slot_layout(op.inputs))
+    diff_idx = [i for i, m in enumerate(vjp_op.attrs["in_grad_mask"]) if m]
+    outs, *traced = _traced_vjp(ctx, fwd_op, flat_in, diff_idx)
+    ctx.linked[id(vjp_op)] = traced
+    return _unflatten(outs, _slot_layout(op.outputs))
+
+
 @register_op("__vjp__", no_grad=True, ref="framework/grad_op_desc_maker.h (capability)")
 def _vjp_emit(ctx: EmitContext, ins, attrs):
     fwd_op = ir.OpDesc.from_dict(attrs["fwd_op"])
-    spec = get_op(fwd_op.type)
     in_layout = _slot_layout(fwd_op.inputs)
     out_layout = _slot_layout(fwd_op.outputs)
     flat_in = ins.get("FwdIn", [])
     diff_mask = attrs["in_grad_mask"]      # per flat fwd input
     og_mask = attrs["out_grad_mask"]       # per flat fwd output: grad provided?
-    # propagate dist: the backward re-trace must partition exactly like the
-    # forward (e.g. ring attention stays sequence-parallel in its vjp)
-    fwd_ctx = EmitContext(base_key=ctx.base_key,
-                          step_base_key=ctx.step_base_key,
-                          op_index=attrs["fwd_op_index"],
-                          is_test=ctx.is_test,
-                          program=ctx.program, dist=ctx.dist)
-
     diff_idx = [i for i, m in enumerate(diff_mask) if m]
 
     def flat_pos(layout, slot):
@@ -149,31 +283,21 @@ def _vjp_emit(ctx: EmitContext, ins, attrs):
             if wgrad is not None:
                 return {"InGrad": [wgrad]}
 
-    def forward_flat(diff_vals):
-        vals = list(flat_in)
-        for i, v in zip(diff_idx, diff_vals):
-            vals[i] = v
-        outs = spec.emit(fwd_ctx, _unflatten(vals, in_layout), fwd_op.attrs)
-        return tuple(_flatten(outs, out_layout))
-
-    # determine which declared outputs are float (can carry cotangents)
-    out_avals = jax.eval_shape(forward_flat, tuple(flat_in[i] for i in diff_idx))
-    float_out = [k for k, a in enumerate(out_avals)
-                 if jnp.issubdtype(a.dtype, jnp.inexact)]
-
-    def forward_float_only(diff_vals):
-        outs = forward_flat(diff_vals)
-        return tuple(outs[k] for k in float_out)
-
-    if fwd_op.attrs.get("__remat__"):
-        # contrib.recompute: save only this op's INPUTS as residuals and
-        # re-run the forward inside the backward (jax.checkpoint) — trades
-        # FLOPs for activation memory (e.g. attention probs [B,H,T,T]
-        # never persist between fwd and bwd)
-        forward_float_only = jax.checkpoint(forward_float_only)
-
-    primals, vjp_fn = jax.vjp(forward_float_only,
-                              tuple(flat_in[i] for i in diff_idx))
+    # a recomputed op's forward was emitted under this vjp already
+    # (emit_with_backward); every other op is re-traced here, and XLA's
+    # CSE merges the copy with the forward op
+    traced = ctx.linked.pop(id(ctx.op), None) if ctx.linked else None
+    if traced is None:
+        # propagate dist: the backward re-trace must partition exactly
+        # like the forward (e.g. ring attention stays sequence-parallel
+        # in its vjp)
+        fwd_ctx = EmitContext(base_key=ctx.base_key,
+                              step_base_key=ctx.step_base_key,
+                              op_index=attrs["fwd_op_index"],
+                              is_test=ctx.is_test,
+                              program=ctx.program, dist=ctx.dist)
+        _, *traced = _traced_vjp(fwd_ctx, fwd_op, flat_in, diff_idx)
+    float_out, primals, vjp_fn = traced
     ograds = ins.get("OutGrad", [])
     og_by_flat: Dict[int, Any] = {}
     j = 0

@@ -312,6 +312,34 @@ def _scrubbed_sha(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
+def _kernel_shas(fn, *shapes):
+    """sha256 of each ``pallas_call`` of a trace, in order: its body,
+    grid and block mappings, operands and results, without what names
+    the checkout (``tests/test_pallas_kernels.py`` has its twin)."""
+    import hashlib
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+    out = []
+    for e in walk(jax.make_jaxpr(fn)(*shapes).jaxpr):
+        if e.primitive.name != "pallas_call":
+            continue
+        text = (f"{e.params['jaxpr']}\n{e.params['grid_mapping']}\n"
+                f"{[v.aval for v in e.invars]}\n"
+                f"{[v.aval for v in e.outvars]}")
+        text = re.sub(r" at [^\s]+:\d+", "", text)
+        text = re.sub(r"/[\w/.\-]+\.py(:\d+)?", "", text)
+        text = re.sub(r"0x[0-9a-f]+", "", text)
+        out.append(hashlib.sha256(text.encode()).hexdigest()[:12])
+    return out
+
+
 @pytest.mark.parametrize("train,want", [(False, "1a1e6cc5ff39e3c8"),
                                         (True, "9ace96be8a2d6254")])
 def test_one_chip_step_at_heads_of_64_lowers_as_before_the_wrap(
@@ -329,8 +357,10 @@ def test_one_chip_step_at_heads_of_64_lowers_as_before_the_wrap(
 def test_head_size_128_lowers_as_before_the_pair_kernels(chip, monkeypatch):
     """PR 41 gave heads of 64 kernels of their own; heads of 128 keep
     ``flash_attention`` op for op: the step's StableHLO around the
-    kernels, and the jaxpr of forward + backward — the kernels' bodies —
-    with and without dropout. NON-causal they are held as the parent of
+    kernels, and the three kernels of forward + backward — their bodies,
+    grids and operands (``_kernel_shas``; until PR 50 the jaxpr's whole
+    text, which a ``name`` equation renumbers: the hashes are the new
+    helper's reading of PR 48's commit) — with and without dropout. NON-causal they are held as the parent of
     PR 48 traced them (PR 41 regrouped the hash helpers, not their
     operations; PR 48 left the dense grid's text alone). Causal they are
     PR 48's:
@@ -342,17 +372,18 @@ def test_head_size_128_lowers_as_before_the_pair_kernels(chip, monkeypatch):
     assert _scrubbed_sha(step) == "a68d8bc779c38f5b"
     seed = jnp.asarray([3], I32)
     shape = jax.ShapeDtypeStruct((2, 2, 512, 128), BF16)
-    for causal, dropout_p, want in ((False, 0.0, "fc8dea230564f74b"),
-                                    (False, 0.3, "59ab0c2570d02d80"),
-                                    (True, 0.0, "8dcd63c272872fe7"),
-                                    (True, 0.3, "f5c7dd0636be26f6")):
+    for causal, dropout_p, want in (
+            (False, 0.0, "13f4cc280efa 7db3bfe61acb 450d78b31f6e"),
+            (False, 0.3, "c004b8a681df 9cede643060d bdff9fdc6e2c"),
+            (True, 0.0, "9f053599830c 5de0e7a0d043 41a798012b0b"),
+            (True, 0.3, "54ec76aa14dc 03619f19e166 278b3dbfe92b")):
         def loss(q, k, v):
             return jnp.sum(flash_attention(
                 q, k, v, causal, None, 512, 512, False, dropout_p,
                 seed if dropout_p > 0 else None).astype(F32))
-        jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
-            shape, shape, shape))
-        assert _scrubbed_sha(jaxpr) == want, (causal, dropout_p)
+        assert " ".join(_kernel_shas(
+            jax.grad(loss, argnums=(0, 1, 2)), shape, shape, shape)) \
+            == want, (causal, dropout_p)
 
 
 # ---------------------------------------------------------------------------
@@ -1055,8 +1086,8 @@ def test_joyai_train_step_compiles_for_v5e(chip, monkeypatch):
     8 192-token sequence, the pass pipeline, the mixed-precision rewrite
     and the configuration's recomputation) for a described v5e: it fits
     the chip beside its 8.17 GB of float32 state, holds the flash
-    kernels of every layer forward and backward and the grouped
-    products, and no ``[.., T, T]`` array."""
+    kernels of every layer — the forward once, the backward's two — and
+    the grouped products, and no ``[.., T, T]`` array."""
     import json
     import os
     import numpy as np
@@ -1081,19 +1112,38 @@ def test_joyai_train_step_compiles_for_v5e(chip, monkeypatch):
     state = {n: struct(n) for n in cb.sig.state_names}
     assert 8.1e9 < sum(int(np.prod(s.shape)) * s.dtype.itemsize
                        for s in state.values()) < 8.2e9
+    from paddle_tpu.ops import grad_ops
+
+    def kept():
+        return {op: (grad_ops.KEPT_VALUES.labels(op=op).value,
+                     grad_ops.KEPT_BYTES.labels(op=op).value)
+                for op in cfg["recompute"]}
+    before = kept()
     compiled = cb.fn.lower(
         state, {n: struct(n) for n in cb.sig.const_names},
         {n: jax.ShapeDtypeStruct((1, t, 1), jnp.int64, sharding=chip)
          for n in feeds},
         jax.ShapeDtypeStruct((), jnp.uint32, sharding=chip)).compile()
+    # what the three recomputed op types keep beside their inputs: the
+    # six latent layers their bf16[1,32,8192,128] out and f32[1,32,8192]
+    # lse, the experts and the dense layer nothing
+    assert {op: tuple(a - b for a, b in zip(kept()[op], before[op]))
+            for op in before} == {
+        "mla_full": (12, 6 * (32 * t * 128 * 2 + 32 * t * 4)),
+        "expert_ffn_held": (0, 0), "swiglu_ffn": (0, 0)}
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
     text = compiled.as_text()
-    # six layers: the flash forward, its recomputation, dq and dkv
-    attend = re.findall(r'custom_call_target="tpu_custom_call".*?'
-                        r'op_name="([^"]*)"', text)
-    assert sum("mla_full" in n for n in attend) >= 24
-    assert any("grad/mtp/mla_full" in n for n in attend)
+    # six layers: the flash forward ONCE — the recomputed op keeps its
+    # out and lse (PR 50; four calls a layer before) — dq and dkv
+    attend = [m.groups() for m in map(re.compile(
+        r'= (.*?) custom-call\(.*custom_call_target="tpu_custom_call"'
+        r'.*?op_name="([^"]*mla_full[^"]*)"').search, text.splitlines())
+        if m]
+    forward = [n for shape, n in attend if "f32[32,8192,1]" in shape]
+    assert len(forward) == 6 and not any("grad/" in n for n in forward)
+    assert len(attend) == 18
+    assert sum("grad/mtp/mla_full" in n for _, n in attend) == 2
     assert text.count("ragged-dot-none") >= 5 * 16
     for m in re.finditer(r"(?:f32|bf16)\[([\d,]+)\]", text):
         dims = [int(d) for d in m.group(1).split(",")]

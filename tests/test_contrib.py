@@ -489,6 +489,115 @@ def test_recompute_rewrite_gradient_parity():
     np.testing.assert_array_equal(w0, w1)
 
 
+def _ffn_trainer(remat):
+    """x -> swiglu_ffn -> mean, SGD; the FFN tagged for recomputation."""
+    from paddle_tpu.contrib.recompute import rewrite_program_recompute
+    from paddle_tpu.fluid.initializer import NormalInitializer
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 3
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = layers.data(name="x", shape=[16, 32], dtype="float32")
+        h = layers.swiglu_ffn(layers.fc(x, size=32, num_flatten_dims=2),
+                              32, 64, "ffn", NormalInitializer(0.0, 0.1))
+        loss = layers.mean(h * h)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        if remat:
+            assert rewrite_program_recompute(main, ("swiglu_ffn",)) == 2
+    return main, startup, loss
+
+
+def _lowered_text(main, loss):
+    import re
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.lowering import CompiledBlock
+    cb = CompiledBlock(main.desc, 0, ["x"], [loss.name])
+    gvars = main.desc.global_block.vars
+
+    def struct(n):
+        return jax.ShapeDtypeStruct(tuple(gvars[n].shape),
+                                    jnp.dtype(gvars[n].dtype))
+    text = cb.fn.lower(
+        {n: struct(n) for n in cb.sig.state_names},
+        {n: struct(n) for n in cb.sig.const_names},
+        {"x": jax.ShapeDtypeStruct((2, 16, 32), jnp.float32)},
+        jax.ShapeDtypeStruct((), jnp.uint32)).as_text()
+    # without locations and the module's name (a process-wide count)
+    return re.sub(r"loc\(.*?\)|@jit_block\w+", "", text)
+
+
+def test_recompute_without_a_named_value_lowers_as_the_bare_checkpoint(
+        monkeypatch):
+    """A tagged op in which no kernel names a value (``swiglu_ffn``)
+    saves nothing: its step lowers to the same text under the policy of
+    ``contrib/recompute.py:KEPT`` as with ``KEPT`` emptied — the bare
+    checkpoint — and to another than the untagged program's."""
+    from paddle_tpu.contrib import recompute
+    tagged = _lowered_text(*_ffn_trainer(True)[::2])
+    monkeypatch.setattr(recompute, "KEPT", ())
+    assert _lowered_text(*_ffn_trainer(True)[::2]) == tagged
+    assert "optimization_barrier" in tagged
+    assert "optimization_barrier" not in _lowered_text(
+        *_ffn_trainer(False)[::2])
+
+
+def test_recomputed_ops_are_lowered_with_their_backward():
+    """``grad_ops.recomputed_pairs``: a tagged forward op is paired with
+    its `__vjp__` by the snapshot's identity and the same inputs, the
+    forward op first; an untagged op, a snapshot without the tag, other
+    inputs or a `__vjp__` alone pair with nothing (the `__vjp__` then
+    re-traces its forward, as every untagged op's does)."""
+    from paddle_tpu.ops import grad_ops
+    main, _startup, _loss = _ffn_trainer(True)
+    block = main.desc.global_block
+    every = range(len(block.ops))
+    (at, vjp), = grad_ops.recomputed_pairs(block, every).items()
+    assert block.ops[at].type == "swiglu_ffn"
+    assert vjp.type == "__vjp__" \
+        and vjp.attrs["fwd_op"]["type"] == "swiglu_ffn"
+    later = block.ops.index(vjp)
+    assert at < later
+    # the `__vjp__` without its forward op, and the reverse
+    assert grad_ops.recomputed_pairs(block, range(at + 1, len(block.ops))) \
+        == {}
+    assert grad_ops.recomputed_pairs(block, range(later)) == {}
+    # a snapshot that lost the tag, or whose inputs are another op's
+    del vjp.attrs["fwd_op"]["attrs"]["__remat__"]
+    assert grad_ops.recomputed_pairs(block, every) == {}
+    vjp.attrs["fwd_op"]["attrs"]["__remat__"] = True
+    vjp.inputs["FwdIn"] = list(reversed(vjp.inputs["FwdIn"]))
+    assert grad_ops.recomputed_pairs(block, every) == {}
+    assert grad_ops.recomputed_pairs(_ffn_trainer(False)[0].desc.global_block,
+                                     every) == {}
+
+
+def test_a_recomputed_ops_vjp_alone_re_traces_its_forward(monkeypatch):
+    """The gradient of a tagged op is the untagged program's whether the
+    pair is lowered from one trace (the executor's step) or the
+    `__vjp__` runs without its forward op in the sequence (fetching a
+    gradient from a scope the forward already ran in is not a program
+    the executor builds, so the pairing is switched off instead)."""
+    from paddle_tpu.ops import grad_ops
+
+    def grads(remat):
+        main, startup, loss = _ffn_trainer(remat)
+        scope = fluid.Scope()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup, scope=scope)
+        xv = np.random.RandomState(4).rand(2, 16, 32).astype(np.float32)
+        return [np.asarray(o) for o in exe.run(
+            main, feed={"x": xv}, scope=scope,
+            fetch_list=[loss.name, "ffn.w_gate@GRAD", "ffn.w_down@GRAD"])]
+
+    want = grads(False)
+    for a, b in zip(grads(True), want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+    monkeypatch.setattr(grad_ops, "recomputed_pairs",
+                        lambda block, indices: {})
+    for a, b in zip(grads(True), want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
 def test_memory_usage_estimator():
     """contrib memory_usage (reference: contrib/memory_usage_calc.py) —
     parameters + persistables + an activation band, batch dim resolved."""

@@ -392,3 +392,131 @@ def test_the_full_view_is_the_familys_oracle():
                               ref.Prec()) @ p["head_w"]
         seq.append(int(jnp.argmax(logits)))
     assert list(got) == seq[len(prompt):]
+
+
+# ------------------------------------------------------------------ ISSUE 50
+# a recomputed op keeps what its kernel named: the flash forward's
+# output and log-sum-exp (contrib/recompute.py:KEPT), so the backward
+# holds no forward kernel; forward op and `__vjp__` come from one trace
+
+def _step_jaxpr(main, loss, feed):
+    """The whole step's jaxpr, as the executor lowers it."""
+    from paddle_tpu.core.lowering import CompiledBlock
+    cb = CompiledBlock(main.desc, 0, list(feed), [loss.name])
+    gvars = main.desc.global_block.vars
+
+    def struct(n):
+        return jax.ShapeDtypeStruct(tuple(gvars[n].shape),
+                                    jnp.dtype(gvars[n].dtype))
+    return jax.make_jaxpr(cb.fn)(
+        {n: struct(n) for n in cb.sig.state_names},
+        {n: struct(n) for n in cb.sig.const_names},
+        {n: jax.ShapeDtypeStruct(v.shape, v.dtype) for n, v in feed.items()},
+        jax.ShapeDtypeStruct((), jnp.uint32)).jaxpr
+
+
+def _forward_kernels(jaxpr):
+    """The flash FORWARD calls of a jaxpr, nested ones too: the
+    ``pallas_call`` equations whose results are (out, lse)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and len(eqn.outvars) == 2 \
+                and eqn.outvars[1].aval.shape[-1] == 1:
+            n += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _forward_kernels(sub)
+    return n
+
+
+def _kept(op_type):
+    from paddle_tpu.ops import grad_ops
+    return (grad_ops.KEPT_VALUES.labels(op=op_type).value,
+            grad_ops.KEPT_BYTES.labels(op=op_type).value)
+
+
+def test_a_recomputed_latent_layers_gradients_are_the_untagged_programs(
+        monkeypatch):
+    """``mla_full`` tagged for recomputation, its kernels forced
+    (interpreted): the loss and EVERY parameter's gradient equal the
+    untagged program's bit for bit — what the backward keeps are the
+    forward kernel's own ``out`` and ``lse``."""
+    from paddle_tpu.contrib.recompute import rewrite_program_recompute
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+
+    def run(tags):
+        build, main, loss, _t, scope, exe = trainer(128, n_layer=2)
+        if tags:
+            # two main layers and the MTP module's, forward op + snapshot
+            assert rewrite_program_recompute(main, tags) == 6
+        fetch = [loss.name] + sorted(
+            n for n in main.desc.global_block.vars
+            if n.startswith("lm_") and n.endswith("@GRAD"))
+        return fetch, [np.asarray(o) for o in exe.run(
+            main, feed=sample(build, batch=1), scope=scope,
+            fetch_list=fetch)]
+
+    names, want = run(())
+    _, got = run(("mla_full",))
+    assert len(names) > 40
+    for n, a, b in zip(names, got, want):
+        np.testing.assert_array_equal(a, b, err_msg=n)
+
+
+@pytest.mark.parametrize("kept,per_layer", [(True, 1), (False, 2)])
+def test_a_recomputed_latent_layer_runs_its_forward_kernel_once(
+        monkeypatch, kept, per_layer):
+    """The step's jaxpr holds ONE forward ``pallas_call`` a recomputed
+    latent layer — the forward op's, whose ``out`` and ``lse`` the
+    checkpoint keeps — where the bare checkpoint's (nothing named:
+    ``KEPT`` emptied) holds two, the second inside the backward's
+    recomputation. Counted in the jaxpr: the forward op and its
+    ``__vjp__`` are ONE trace, so no merge is left to the compiler."""
+    from paddle_tpu.contrib import recompute
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    if not kept:
+        monkeypatch.setattr(recompute, "KEPT", ())
+    build, main, loss, _t, _scope, _exe = trainer(128, n_layer=2)
+    recompute.rewrite_program_recompute(main, ("mla_full",))
+    before = _kept("mla_full")
+    jaxpr = _step_jaxpr(main, loss, sample(build, batch=1))
+    assert _forward_kernels(jaxpr) == 3 * per_layer
+    values, nbytes = (a - b for a, b in zip(_kept("mla_full"), before))
+    # four heads of 16 over 128 tokens in float32, and their row sums
+    assert (values, nbytes) == (
+        (6, 3 * (4 * 128 * 16 * 4 + 4 * 128 * 4)) if kept else (0, 0))
+
+
+def test_an_untagged_latent_layers_backward_re_traces_its_forward(
+        monkeypatch):
+    """Without the tag nothing changes: the `__vjp__` op re-traces the
+    forward (two forward calls a layer in the jaxpr, the second for the
+    compiler to merge or drop) and the counter of kept values stands."""
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    build, main, loss, _t, _scope, _exe = trainer(128, n_layer=2)
+    before = _kept("mla_full")
+    jaxpr = _step_jaxpr(main, loss, sample(build, batch=1))
+    assert _forward_kernels(jaxpr) == 6
+    assert _kept("mla_full") == before
+
+
+@pytest.mark.parametrize("op_type", ["swiglu_ffn", "expert_ffn_held"])
+def test_an_op_in_which_nothing_is_named_keeps_its_inputs_alone(op_type):
+    """``paddle_recompute_kept_values_total`` / ``_bytes_total`` read 0
+    for a recomputed op without a named value, each time it is lowered,
+    and are in the exporters' catalog."""
+    from paddle_tpu.contrib.recompute import rewrite_program_recompute
+    from paddle_tpu.observability import exporters, metrics as obs_metrics
+    build, main, loss, _t, scope, exe = trainer(64, n_layer=2)
+    assert rewrite_program_recompute(main, (op_type,)) >= 2
+    before = _kept(op_type)
+    exe.run(main, feed=sample(build, batch=1), scope=scope,
+            fetch_list=[loss.name])
+    assert _kept(op_type) == before
+    # the family exists for the op all the same: a scrape shows the zero
+    exporters._preregister_catalog()
+    snap = obs_metrics.default_registry().snapshot()
+    assert "paddle_recompute_kept_values_total" in snap
+    assert "paddle_recompute_kept_bytes_total" in snap

@@ -408,27 +408,62 @@ def test_causal_blocks_counter_on_the_trained_cells_shape():
         obs_metrics.default_registry().snapshot()
 
 
-def _jaxpr_sha(fn, *shapes):
-    """sha256 of a jaxpr's text — the kernels' bodies and grids —
-    without what names the checkout (source locations)."""
+def _subjaxprs(eqn):
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (list, tuple)) else [v]):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold,
+    in order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _subjaxprs(eqn):
+            yield from _walk(sub)
+
+
+def _kernel_shas(fn, *shapes):
+    """sha256 of each ``pallas_call`` of a trace, in order — its body,
+    grid and block mappings, operands and results — without what names
+    the checkout (source locations). What lies between the kernels is
+    not hashed: a ``name`` equation (``flash_attention._named``, PR 50)
+    renumbers every variable after it."""
     import hashlib
     import re
-    text = re.sub(r" at [^\s]+:\d+", "", str(jax.make_jaxpr(fn)(*shapes)))
-    text = re.sub(r"/[\w/.\-]+\.py(:\d+)?", "", text)
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+    out = []
+    for e in _walk(jax.make_jaxpr(fn)(*shapes).jaxpr):
+        if e.primitive.name != "pallas_call":
+            continue
+        text = (f"{e.params['jaxpr']}\n{e.params['grid_mapping']}\n"
+                f"{[v.aval for v in e.invars]}\n"
+                f"{[v.aval for v in e.outvars]}")
+        text = re.sub(r" at [^\s]+:\d+", "", text)
+        text = re.sub(r"/[\w/.\-]+\.py(:\d+)?", "", text)
+        text = re.sub(r"0x[0-9a-f]+", "", text)
+        out.append(hashlib.sha256(text.encode()).hexdigest()[:12])
+    return out
 
 
 @pytest.mark.parametrize("causal,tq,tk,dropout_p,want", [
-    (False, 1024, 2048, 0.0, "6d5280df856fddbf"),
-    (False, 1024, 2048, 0.3, "1747e32308e7ea21"),
-    (True, 2048, 1024, 0.0, "3c901130ddd1f123"),
-    (True, 2048, 1024, 0.3, "229d86e6195f529c")])
+    (False, 1024, 2048, 0.0,
+     ("5cf6130b0852", "065e6d5740aa", "b87572983d6d")),
+    (False, 1024, 2048, 0.3,
+     ("a44ba2f3bb55", "6b1d74578900", "1b9c6340f05b")),
+    (True, 2048, 1024, 0.0,
+     ("7d9f52425bbc", "d6c9b4fec16b", "e64ccc1828ad")),
+    (True, 2048, 1024, 0.3,
+     ("dc50dd2389ed", "531a5a53da34", "299bfa2ad5b2"))])
 def test_the_dense_grid_lowers_as_before_the_causal_schedule(
         causal, tq, tk, dropout_p, want):
     """A non-causal call, and a causal one with tq > tk, trace to the
-    jaxpr they traced to at PR 47 (forward, dQ and dK/dV with the
-    log-sum-exp's cotangent, heads of 192 / 128, blocks 256 x 512):
-    held as recorded at the parent commit."""
+    kernels they traced to at PR 47 (forward, dQ and dK/dV with the
+    log-sum-exp's cotangent, heads of 192 / 128, blocks 256 x 512): held
+    as ``_kernel_shas`` reads PR 48's commit (the hashes that stood here
+    before PR 50 were of the jaxpr's whole text, which a ``name``
+    equation renumbers)."""
     fa = _flash_module()
     seed = jnp.asarray([3], jnp.int32)
 
@@ -440,7 +475,74 @@ def test_the_dense_grid_lowers_as_before_the_causal_schedule(
 
     shapes = [jax.ShapeDtypeStruct((2, 2, t, d), jnp.bfloat16)
               for t, d in ((tq, 192), (tk, 192), (tk, 128))]
-    assert _jaxpr_sha(jax.grad(loss, argnums=(0, 1, 2)), *shapes) == want
+    assert tuple(_kernel_shas(jax.grad(loss, argnums=(0, 1, 2)),
+                              *shapes)) == want
+
+
+# ------------------------------------------------------------------ ISSUE 50
+# the forward rules name ``out`` and ``lse``: a checkpoint whose policy
+# saves contrib/recompute.py:KEPT keeps the pair and its backward holds
+# no forward kernel; anywhere else a name is an identity
+
+@pytest.mark.parametrize("with_lse", [False, True],
+                         ids=["flash_attention", "flash_attention_lse"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_a_checkpoint_that_saves_the_named_pair_runs_the_forward_once(
+        with_lse, causal):
+    """Both interfaces (ring attention's ``flash_attention_lse`` with
+    the log-sum-exp's cotangent too) under ``jax.checkpoint`` with the
+    recomputed ops' policy: the three gradients equal the plain call's
+    bit for bit, and the gradient's jaxpr holds the forward
+    ``pallas_call`` once where the bare checkpoint's holds it twice."""
+    from paddle_tpu.contrib.recompute import KEPT
+    fa = _flash_module()
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(1, 2, 256, d).astype(np.float32))
+               for d in (24, 24, 16))
+
+    def attend(q, k, v):
+        if with_lse:
+            o, lse = fa.flash_attention_lse(q * 1.5, k, v, causal, None,
+                                            128, 128, True)
+            return jnp.sum(o * o) + jnp.sum(jnp.sin(lse))
+        return jnp.sum(fa.flash_attention(q * 1.5, k, v, causal, None,
+                                          128, 128, True) ** 2)
+
+    policy = jax.checkpoint_policies.save_only_these_names(*KEPT)
+    kept = jax.grad(jax.checkpoint(attend, policy=policy), (0, 1, 2))
+    bare = jax.grad(jax.checkpoint(attend), (0, 1, 2))
+    want = jax.grad(attend, (0, 1, 2))(q, k, v)
+    for a, b in zip(kept(q, k, v), want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def is_forward(e):
+        return (e.primitive.name == "pallas_call" and len(e.outvars) == 2
+                and e.outvars[1].aval.shape[-1] == 1)
+
+    def forwards(fn):
+        return sum(map(is_forward,
+                       _walk(jax.make_jaxpr(fn)(q, k, v).jaxpr)))
+
+    assert (forwards(kept), forwards(bare)) == (1, 2)
+    # everything else IS recomputed: the backward's checkpoint scales
+    # the query again and runs the two backward kernels, no forward one
+    (again,) = [e for e in jax.make_jaxpr(kept)(q, k, v).jaxpr.eqns
+                if e.primitive.name.startswith(("remat", "checkpoint"))]
+    inside = list(_walk(again.params["jaxpr"]))
+    assert any(e.primitive.name == "mul" for e in inside)
+    assert sum(e.primitive.name == "pallas_call" for e in inside) == 2
+    assert not any(map(is_forward, inside))
+
+
+def test_a_name_outside_a_policy_lowers_to_nothing():
+    """The forward rules' names leave no trace in a program that saves
+    nothing by name: the gradient's lowered text holds no ``name``."""
+    fa = _flash_module()
+    q = jax.ShapeDtypeStruct((1, 2, 256, 16), jnp.float32)
+    text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, True, None, 128, 128, True)), (0, 1, 2))).lower(
+            q, q, q).as_text()
+    assert "flash_out" not in text and "flash_lse" not in text
 
 
 # ------------------------------------------------------------------ ISSUE 34
