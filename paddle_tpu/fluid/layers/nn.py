@@ -880,13 +880,46 @@ def ssd(x, state, conv, d_model, sizes, base, init, epsilon=1e-5,
     return out
 
 
+def shortconv(x, conv, d_model, taps, base, init, seq_len=None, slot=None,
+              active=None, name=None):
+    """One gated short convolution layer of the LFM2 family
+    (ops/shortconv.py) over the persistable per-slot ``conv``
+    [n_slots, taps-1, M], read and written under its own name (donated):
+    the last rows of ``B * x``. With ``seq_len`` and ``slot`` it is the
+    prefill of ONE request, x [1, T, M], writing slot ``slot``; with
+    ``active`` the decode step of every slot, x [n_slots, 1, M]. Weights
+    ``<base>.w_in`` [M, 3M] (B, C, x in that order), ``.conv`` [taps, M]
+    (depthwise, no bias), ``.w_out`` [M, M], all drawn by ``init``."""
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    prefill = seq_len is not None
+    op = "shortconv_prefill" if prefill else "shortconv_decode"
+    helper = LayerHelper(op, name=name)
+    shapes = {"w_in": ("WIn", [d_model, 3 * d_model]),
+              "conv": ("ConvW", [int(taps), d_model]),
+              "w_out": ("WOut", [d_model, d_model])}
+    inputs = {"X": [x], "Conv": [conv]}
+    for tag, (slot_name, shape) in shapes.items():
+        inputs[slot_name] = [helper.create_parameter(
+            ParamAttr(name=f"{base}.{tag}", initializer=init),
+            shape=shape, dtype=x.dtype)]
+    if prefill:
+        inputs.update(SeqLen=[seq_len], Slot=[slot])
+    else:
+        inputs.update(Active=[active])
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(op, inputs=inputs,
+                     outputs={"Out": [out], "ConvOut": [conv]})
+    return out
+
+
 def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
                     init, held_start=0, n_shared=1, norm_topk=True,
                     scaling=1.0, valid=None, seq_len=None, counts=None,
                     name=None, router_bias=False, d_shared=None,
                     scoring="sigmoid", load=False):
     """One expert-parallel member's share of a top-k routed expert layer
-    plus the shared expert (ops/expert_ffn.py): the router is
+    plus the shared expert, where the model has one (``n_shared`` 0: no
+    shared expert, no parameters of one) (ops/expert_ffn.py): the router is
     ``n_experts`` wide, the ``n_held`` experts from ``held_start`` are
     computed here. ``valid`` [n, 1] int or ``seq_len`` [1, 1] says which
     tokens are real; ``counts`` [2, n_held] int32 (persistable, donated)
@@ -908,10 +941,12 @@ def expert_ffn_held(x, d_model, d_expert, n_experts, n_held, top_k, base,
     shapes = {"router": ("RouterW", [d_model, n_experts]),
               "w_gate": ("WGate", [n_held, d_model, d_expert]),
               "w_up": ("WUp", [n_held, d_model, d_expert]),
-              "w_down": ("WDown", [n_held, d_expert, d_model]),
-              "s_gate": ("SGate", [d_model, shared]),
-              "s_up": ("SUp", [d_model, shared]),
-              "s_down": ("SDown", [shared, d_model])}
+              "w_down": ("WDown", [n_held, d_expert, d_model])}
+    if shared:
+        # a layer without a shared expert has no such parameters
+        shapes.update({"s_gate": ("SGate", [d_model, shared]),
+                       "s_up": ("SUp", [d_model, shared]),
+                       "s_down": ("SDown", [shared, d_model])})
     inputs = {"X": [x]}
     for tag, (slot_name, shape) in shapes.items():
         inputs[slot_name] = [helper.create_parameter(

@@ -182,12 +182,15 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 # ---------------------------------------------------------------------------
 # The hybrid sparse block of the slot views (decoder_lm(..., **arch)):
 # RMSNorm, a mixer per layer of kind "gqa" (softmax attention with
-# grouped KV heads and no positions, through the paged pool; an output
-# gate where ``gqa_gate``), "swa" (a "gqa" layer over a sliding window,
-# with rotary positions, in a page group of its own), "kda" (Kimi Delta
+# grouped KV heads, through the paged pool; no positions unless
+# ``gqa_rope_theta``; an output gate where ``gqa_gate``), "swa" (a
+# "gqa" layer over a sliding window, with rotary positions, in a page
+# group of its own), "kda" (Kimi Delta
 # Attention, a fixed-size recurrent state per slot), "ssd" (Mamba-2's
 # state-space dual: another fixed-size state per slot, a chunked scan as
-# its prefill) or "mla" (latent attention with rotary positions and the
+# its prefill), "conv" (the LFM2 family's gated short convolution: a
+# third fixed-size state per slot, the last rows of one elementwise
+# product) or "mla" (latent attention with rotary positions and the
 # DSA indexer's sparse selection: a latent plane and an indexer-key plane
 # in the paged pool), and an expert layer of which this program holds a
 # share — or, in the first ``first_k_dense`` layers, a dense SwiGLU layer
@@ -204,6 +207,9 @@ _HYBRID_KEYS = {
     # (``rope_theta``), cached in a page group of their own
     "n_kv_head": None, "head_dim": None, "gqa_gate": True,
     "qk_norm": False, "window": None,
+    # rotary positions on the "gqa" layers themselves (rotate-half, all
+    # of a head's dimensions, this base; None: no positions)
+    "gqa_rope_theta": None,
     # what multiplies the attention scores ("gqa" layers) in place of
     # head_dim ** -0.5
     "attn_scale": None,
@@ -220,6 +226,8 @@ _HYBRID_KEYS = {
     # groups that share B and C, the conv's taps, the prefill's chunk
     "ssd_heads": None, "ssd_head_dim": None, "ssd_d_state": None,
     "ssd_groups": 1, "ssd_conv_taps": 4, "ssd_chunk": None,
+    # "conv" layers: the depthwise conv's taps (the cache keeps taps - 1)
+    "conv_taps": None,
     # "mla" layers: the query's and the cache's latent widths, a head's
     # parts, the rotation's base, the indexer's heads and its top-k
     "q_lora_rank": None, "kv_lora_rank": None, "qk_nope_head_dim": None,
@@ -245,6 +253,7 @@ _KIND_KEYS = {
     "swa": ("n_kv_head", "head_dim", "window", "rope_theta"),
     "kda": ("kda_heads", "kda_head_dim", "kda_gate_rank"),
     "ssd": ("ssd_heads", "ssd_head_dim", "ssd_d_state", "ssd_chunk"),
+    "conv": ("conv_taps",),
     "mla": ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
             "qk_rope_head_dim", "v_head_dim", "rope_theta",
             "index_n_heads", "index_head_dim", "index_topk")}
@@ -254,7 +263,7 @@ _INDEXER_KEYS = ("index_n_heads", "index_head_dim", "index_topk")
 
 
 # sizes whose None is a value (the op's own default), not an omission
-_OPTIONAL = ("attn_scale", "d_shared")
+_OPTIONAL = ("attn_scale", "d_shared", "gqa_rope_theta")
 
 
 def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
@@ -450,6 +459,8 @@ def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
         if swa:
             gqa.update(window=hy["window"], rope_theta=hy["rope_theta"],
                        attended_name=f"{name}_l{i}_attn_attended")
+        elif hy["gqa_rope_theta"]:
+            gqa.update(rope_theta=hy["gqa_rope_theta"])
         attr = pa(f"l{i}_attn", True)    # the base of its names
         if prefill:
             y = layers.kv_attention_prefill_paged(
@@ -494,6 +505,13 @@ def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
         y = layers.ssd(
             y, state, conv, d_model, sizes, f"{name}_l{i}_ssd", init,
             eps,
+            **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
+               if prefill else dict(active=feeds["active"])))
+    elif kind == "conv":
+        conv = pool_var(f"{name}_conv_state_{i}",
+                        [n_slots, hy["conv_taps"] - 1, d_model], dt)
+        y = layers.shortconv(
+            y, conv, d_model, hy["conv_taps"], f"{name}_l{i}_conv", init,
             **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
                if prefill else dict(active=feeds["active"])))
     else:
@@ -633,11 +651,13 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
 
     ``arch`` (``layer_kinds=...`` and the sizes :func:`hybrid_arch`
     lists) turns the block into that of a hybrid sparse model: RMSNorm,
-    per layer a grouped-KV softmax mixer with no positions ("gqa",
-    through the same paged ops and pools; "swa" over a sliding window),
+    per layer a grouped-KV softmax mixer ("gqa", through the same paged
+    ops and pools, without positions unless ``gqa_rope_theta``; "swa"
+    over a sliding window),
     a Kimi Delta Attention mixer ("kda", a fixed-size recurrent state
     per slot beside the pages), a Mamba-2 state-space mixer ("ssd",
-    another fixed-size state, prefilled by a chunked scan) or
+    another fixed-size state, prefilled by a chunked scan), a gated
+    short convolution ("conv", a window of its last rows per slot) or
     latent attention with rotary positions and the DSA indexer's sparse
     selection ("mla": a latent plane and an indexer-key plane in the
     pool, and a ``position`` feed of the decode view), and an expert
@@ -795,7 +815,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             # for every position behind the first decode step's window
             page_rows_w = sdata("page_rows_w", [t, 1])
             feed_specs["page_rows_w"] = ([t, 1], "int64")
-        if hy is not None and {"kda", "ssd"} & set(hy["kinds"]):
+        if hy is not None and {"kda", "ssd", "conv"} & set(hy["kinds"]):
             # which slot's recurrent state this request's prompt lands
             # in (>= n_slots: nowhere — the warm-up's dispatch)
             state_slot = sdata("state_slot", [1, 1])

@@ -45,6 +45,8 @@ PHASES = {
     "kda_decode": ("conv", "state"),
     "ssd_decode": ("conv", "state"),
     "ssd_prefill": ("conv", "scan"),
+    "shortconv_decode": ("project", "conv", "out"),
+    "shortconv_prefill": ("project", "conv", "out"),
     "expert_ffn_held": ("route", "up", "down", "shared"),
     "mla_full": ("project", "attend"),
     # a VARIANT of an op (``<op type>/<variant>``): the scope an op

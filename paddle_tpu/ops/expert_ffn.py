@@ -5,7 +5,8 @@ every logit, or by the logits themselves with a softmax over the picks:
 :func:`route`), and this
 program computes the part of the result that the experts it HOLDS
 (``[held_start, held_start + n_held)``) give, plus the shared expert
-that every member computes alike. What the absent experts would add is
+that every member computes alike (where the model has one: a layer
+declared without ``SGate`` adds none). What the absent experts would add is
 left out — their owners add it (docs/serving.md "Expert layer"); on one
 chip the layer runs without that exchange, and nothing here stands in
 for it.
@@ -384,7 +385,8 @@ def _grouped_outer(rows, d_out, sizes):
                  "backward grouped too (ops/expert_ffn.py)")
 def _expert_ffn_held(ctx, ins, attrs):
     """X [B,T,M], RouterW [M,E], WGate/WUp [E_held,M,F], WDown
-    [E_held,F,M], SGate/SUp [M,Fs], SDown [Fs,M] (the shared expert),
+    [E_held,F,M], optional SGate/SUp [M,Fs], SDown [Fs,M] (the shared
+    expert; a layer without one declares none and lowers no such branch),
     optional RouterBias [1,E] float32 (the picks are by score + bias:
     :func:`route`), optional Valid [B*T, 1] int or SeqLen [1,1] int (which tokens are
     real: an inactive slot's or a padded position's token is routed
@@ -393,7 +395,10 @@ def _expert_ffn_held(ctx, ins, attrs):
     was given any; they wrap, a reader takes differences) -> Out
     [B,T,M] (+ CountsOut; + Load [E] int32 where the op declares it:
     the real tokens' picks over ALL the router's experts, what
-    ``router_bias_update`` balances). attrs: top_k, held_start,
+    ``router_bias_update`` balances; + Picks [B*T, top_k] int32 where
+    the op declares that output: every token's picked experts — no
+    builder declares it, a reader of routing decisions adds it to its
+    own copy of a program). attrs: top_k, held_start,
     norm_topk, scaling, scoring (:func:`route`; sigmoid when absent).
     Under the mixed-precision tags the products multiply in bfloat16
     over float32 master weights; the router scores in float32."""
@@ -419,9 +424,10 @@ def _expert_ffn_held(ctx, ins, attrs):
         x2, combine, idx, first(ins, "WGate"), first(ins, "WUp"),
         first(ins, "WDown"), int(attrs.get("held_start", 0)), valid,
         first(ins, "RouterW").shape[1])
-    with _phase("shared"):
-        y = y + swiglu(x2, *(first(ins, n).astype(dt)
-                             for n in ("SGate", "SUp", "SDown")))
+    if first(ins, "SGate") is not None:
+        with _phase("shared"):
+            y = y + swiglu(x2, *(first(ins, n).astype(dt)
+                                 for n in ("SGate", "SUp", "SDown")))
     out = {"Out": [y.astype(out_dt).reshape(b, t, m)]}
     if attrs.get("load"):
         with _phase("route"):
@@ -430,6 +436,9 @@ def _expert_ffn_held(ctx, ins, attrs):
             if valid is not None:
                 picks &= valid[:, None, None]
             out["Load"] = [jnp.sum(picks, axis=(0, 1), dtype=jnp.int32)]
+    op = getattr(ctx, "op", None)      # a bare call has no description
+    if op is not None and "Picks" in op.outputs:
+        out["Picks"] = [idx.astype(jnp.int32)]
     counts = first(ins, "Counts")
     if counts is not None:
         seen = jnp.stack([sizes, (sizes > 0).astype(jnp.int32)])

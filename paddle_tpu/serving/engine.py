@@ -769,7 +769,8 @@ class SlotGenerativeModel(GenerativeModel):
         state"): the variables a hybrid family's mixers DECLARE as
         per-slot state (``register_op(..., slot_state=(kind, slots))``:
         a ``kda`` layer's delta-rule state and conv window, an ``ssd``
-        layer's state-space state and conv window), [n_slots, ...] each,
+        layer's state-space state and conv window, a ``shortconv``
+        layer's window alone), [n_slots, ...] each,
         fixed-size per slot — so admission stays by pages and free
         slots. The prefill view writes the slot its ``state_slot`` feed
         names, the decode view updates every active slot in place.
@@ -858,6 +859,15 @@ class SlotGenerativeModel(GenerativeModel):
         self._m_ssd_tokens = smetrics.SSD_TOKENS_SCANNED.labels(
             model=self.name)
         self._m_ssd_rows = smetrics.SSD_CHUNK_ROWS.labels(model=self.name)
+        # a gated short convolution convolves a prompt's true tokens and
+        # a step's running slots: counted on the host, in every such layer
+        self._conv_layers = sum(
+            op.type == "shortconv_decode"
+            for op in dec_main.desc.global_block.ops)
+        self._m_conv_tokens = {
+            view: smetrics.SHORTCONV_TOKENS.labels(model=self.name,
+                                                   view=view)
+            for view in ("prefill", "decode")} if self._conv_layers else {}
 
     def _ssd_chunk(self, p_len: int) -> int:
         """Rows a turn of the chunked scan of the ``p_len`` prefill view
@@ -1089,7 +1099,8 @@ class SlotGenerativeModel(GenerativeModel):
         if trace_on:
             tctx.record_span("serving.admit.state", t0,
                              time.perf_counter(), ctx=tctx.current(),
-                             model=self.name, slot=slot)
+                             model=self.name, slot=slot,
+                             kinds=",".join(self.state_kinds))
         return feeds
 
     def _reserve_capacity(self, slot, prompt, p_len, budget):
@@ -1332,6 +1343,8 @@ class SlotGenerativeModel(GenerativeModel):
             self._m_ssd_tokens.inc(length * self._ssd_layers)
             self._m_ssd_rows.inc(-(-length // chunk) * chunk
                                  * self._ssd_layers)
+        if self._conv_layers:
+            self._m_conv_tokens["prefill"].inc(length * self._conv_layers)
         first = int(np.asarray(tok).reshape(-1)[0])
         self._active[slot] = True
         self._tok[slot] = first
@@ -1476,6 +1489,9 @@ class SlotGenerativeModel(GenerativeModel):
         out, kind = self._dispatch_decode(feeds)
         self._count_sampling_step()
         slots = np.flatnonzero(ran)
+        if self._conv_layers:
+            self._m_conv_tokens["decode"].inc(
+                len(slots) * self._conv_layers)
         if self._dsa_layers:
             live = self._seq[slots] + self._gen_count[slots]
             self._m_dsa_scored.inc(int(live.sum()) * self._dsa_layers)

@@ -186,7 +186,8 @@ KV_PAGE_EVICTIONS = _metrics.counter(
 RECURRENT_STATE_BYTES = _metrics.gauge(
     "paddle_recurrent_state_bytes",
     "Bytes of per-slot recurrent and conv state a hybrid model keeps "
-    "beside its KV pages, by the kind of mixer that keeps it (kda|ssd; "
+    "beside its KV pages, by the kind of mixer that keeps it "
+    "(kda|ssd|shortconv; "
     "static: fixed-size per slot, n_slots of them; no sample for a "
     "model with none)", labelnames=("model", "kind"))
 SSD_TOKENS_SCANNED = _metrics.counter(
@@ -199,6 +200,12 @@ SSD_CHUNK_ROWS = _metrics.counter(
     "Rows ssd_prefill's chunked scan computed: the whole chunks a "
     "prompt's true length fills, summed over the model's SSD layers",
     labelnames=("model",))
+SHORTCONV_TOKENS = _metrics.counter(
+    "paddle_shortconv_tokens_total",
+    "True tokens through the gated short convolutions, summed over the "
+    "model's conv layers, by view: a prompt's length at its prefill, the "
+    "slots a decode step ran (counted on the host)",
+    labelnames=("model", "view"))
 LATENT_CACHE_BYTES = _metrics.gauge(
     "paddle_latent_cache_bytes",
     "Bytes of the latent-attention layers' latent planes in the page "

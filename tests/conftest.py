@@ -82,6 +82,8 @@ STEP_MODULES_SINCE_PR37 = {
     # PR 47: the digest (``mla_full``'s and ``expert_ffn_held``'s rows)
     # BEFORE the scan's ``_x<N>``, which the step pattern ends with
     "train_joyai_seq8k_1chip": "jit_block3_s5b8b_x2",
+    # PR 51: the digest carries ``shortconv_decode``'s row of the phases
+    "serve_lfm2_extract_closed": "jit_lm_decode_paged_se045",
 }
 
 
@@ -90,10 +92,18 @@ STEP_MODULES_SINCE_PR37 = {
 # that adds metrics may neither edit that module nor put an entry anywhere
 # but at the list's end. That one module is shown the list as PR 44 left
 # it: cut behind PR 44's last entry, whatever was appended since (no
-# table to extend a cell at a time). Its other assertions, and every
-# other module, read the list whole; a ``benchmark`` PR drops the pin
-# (PERF.md section 7 (42)).
+# table to extend a cell at a time). It also pins its two metrics'
+# ``workloads`` to PR 44's one cell, and a later cell whose prefills take
+# the grouped way belongs on ``experts_ms_per_prefill``'s list (PR 51):
+# the module is shown THOSE TWO metrics' lists without the cells added
+# behind PR 44's last, and every other metric's list as it is. So two
+# of its assertions no longer see the real file — that the two metrics
+# end the list, and that each lists one cell — and the rest of
+# ``test_the_metric_is_declared_for_the_cell_alone`` (``moves``, unit,
+# source, layer, the reader's file) does; every other module reads the
+# file whole. A ``benchmark`` PR drops the pin (PERF.md section 7 (42)).
 LAST_METRIC_OF_PR44 = "moe_grouped_held_rows_pct.prefill"
+LAST_CELL_OF_PR44 = "serve_granite_sessions_closed"
 
 
 @pytest.fixture(autouse=True)
@@ -108,6 +118,10 @@ def _per_layer_list_as_pr44_left_it(request, monkeypatch):
         bench = real()
         names = [m["name"] for m in bench["per_layer"]]
         del bench["per_layer"][names.index(LAST_METRIC_OF_PR44) + 1:]
+        cells = [w["name"] for w in bench["workloads"]]
+        later = set(cells[cells.index(LAST_CELL_OF_PR44) + 1:])
+        for m in bench["per_layer"][-2:]:
+            m["workloads"] = [w for w in m["workloads"] if w not in later]
         return bench
     monkeypatch.setattr(harness, "load_benchmark", load)
 

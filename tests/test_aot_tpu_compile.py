@@ -963,6 +963,78 @@ def test_ssd_prefill_compiles_for_v5e(chip, granite_engine, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# gated short convolutions beside rotary attention layers (PR 51): the
+# decode step and the 4096-token prefill of lfm2_8b_a1b_d12 at the cell's
+# sizes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def lfm2_engine():
+    import json
+    import os
+    from paddle_tpu import serving
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.ops import pallas as pk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "lfm2_8b_a1b_d12.json")) as f:
+        cfg = json.load(f)
+    build = cfg["build"]
+    was, pk.on_tpu = pk.on_tpu, lambda: True
+    try:
+        programs = T.build_decoder_lm_programs(
+            name="lm", modes=T.slot_modes(cfg["kv_layout"]),
+            kv_codec=cfg["kv_codec"],
+            **{**build, "prompt_buckets": tuple(build["prompt_buckets"]),
+               "layer_kinds": tuple(build["layer_kinds"])})
+        yield serving.make_slot_model("lm", programs, init=False), programs
+    finally:
+        pk.on_tpu = was
+
+
+def test_shortconv_decode_step_compiles_for_v5e(chip, lfm2_engine,
+                                                monkeypatch):
+    """64 slots, bf16, nine conv layers and three rotary attention
+    layers, all 32 experts: the step's arguments are the 9.55 GB the
+    configuration's file counts (7.86 of weights, 1.69 of pages, 4.7 MB
+    of conv windows) and it fits one chip with its temporaries; the
+    pages are donated and aliased in place; 64 tokens take the experts'
+    dense way; three layers gather K and V."""
+    eng, programs = lfm2_engine
+    compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
+                             eng._decode_feeds(), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert 9.5e9 < mem.argument_size_in_bytes < 9.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
+    assert mem.alias_size_in_bytes >= 2 * 3 * 17152 * 16 * 512 * 2
+    text = compiled.as_text()
+    assert not [n for n in _hlo_ops(text) if n.startswith("ragged-dot")]
+    assert text[text.index("ENTRY "):].count("gather_pages") >= 6
+    # the module's name carries ``shortconv_decode``'s row of the phases
+    assert text.startswith("HloModule jit_lm_decode_paged_se045,")
+
+
+def test_shortconv_prefill_compiles_for_v5e(chip, lfm2_engine, monkeypatch):
+    """The 4096-token prefill beside the weights and the pages: under
+    10.5 GB; 4096 tokens take the experts' grouped way over buffers of
+    every assignment (the member holds every expert: three ``ragged-dot``
+    an expert layer, no loop of turns); the attention's softmax over
+    4096 keys holds no window reduction."""
+    eng, programs = lfm2_engine
+    compiled = _compile_view(chip, programs, "prefill_paged@4096",
+                             eng._cb_prefill[4096],
+                             eng._prefill_feeds(4096), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
+    text = compiled.as_text()
+    assert "reduce-window" not in text
+    assert text.count("ragged_dot_tiling") == 30
+    assert _count_opcode(text, "while") >= 3     # the attention's blocks
+    assert text.startswith("HloModule jit_lm_prefill_paged_4096_sfa9e,")
+
+
+# ---------------------------------------------------------------------------
 # the expert layer's two ways (PR 44): the dense way's text is the
 # parent's, the grouped way's optimised module holds no buffer of the
 # worst case

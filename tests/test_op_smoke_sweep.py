@@ -247,6 +247,15 @@ spec("ssd_prefill", {"X": [f(1, 6, 8)], **_SSD,
                      "SeqLen": [lens(4).reshape(1, 1)],
                      "Slot": [lens(1).reshape(1, 1)]},
      {**_SSD_ATTRS, "chunk": 3})
+# the gated short convolution (ops/shortconv.py): two slots, eight
+# channels, three taps
+_SHORTCONV = {"WIn": [f(8, 24, seed=2)], "ConvW": [f(3, 8, seed=3)],
+              "WOut": [f(8, 8, seed=4)], "Conv": [f(2, 2, 8, seed=5)]}
+spec("shortconv_decode", {"X": [f(2, 1, 8)], **_SHORTCONV,
+                          "Active": [ints(2, 1, hi=2, seed=3)]})
+spec("shortconv_prefill", {"X": [f(1, 6, 8)], **_SHORTCONV,
+                           "SeqLen": [lens(4).reshape(1, 1)],
+                           "Slot": [lens(1).reshape(1, 1)]})
 spec("expert_ffn_held",
      {"X": [f(1, 4, 8)], "RouterW": [f(8, 6, seed=1)],
       "WGate": [f(2, 8, 5, seed=2)], "WUp": [f(2, 8, 5, seed=3)],
