@@ -268,7 +268,9 @@ def _preregister_catalog():
                 # (docs/performance.md 'Sharded embedding tables')
                 "paddle_tpu.ops.embed_cache",
                 # which tier the paged K/V gather was lowered to
-                # (paddle_kv_gather_lowered_total{path})
+                # (paddle_kv_gather_lowered_total{path}) and what attends
+                # a grouped-KV prefill
+                # (paddle_gqa_prefill_attend_lowered_total{path})
                 "paddle_tpu.ops.kv_attention",
                 # which way a latent-attention decode layer attends
                 # (paddle_mla_decode_lowered_total{path})

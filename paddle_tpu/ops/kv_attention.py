@@ -94,6 +94,17 @@ KV_GATHER_LOWERED = _metrics.counter(
     labelnames=("path",))
 
 
+# same catalog, same discipline: one increment each time
+# ``_gqa_attend`` is traced, labelled with the implementation its shapes
+# chose (``_gqa_attend_tier``; the device trace shows the kernel as a
+# ``tpu_custom_call`` ``[B * H, T, D]`` under ``kv_attention_prefill_paged``)
+GQA_PREFILL_ATTEND_LOWERED = _metrics.counter(
+    "paddle_gqa_prefill_attend_lowered_total",
+    "Grouped-KV prefill attentions lowered, by implementation "
+    "(flash|blocked|whole)",
+    labelnames=("path",))
+
+
 def _scores_to_probs(s, mask, dt):
     """fp32 scaled+masked scores -> storage-dtype probabilities, the
     shared softmax spelling (mirrors attention_block._fwd_impl)."""
@@ -253,18 +264,46 @@ def _attended(keep, first_col=0):
                       jnp.sum(keep, axis=-1)], axis=-1).astype(jnp.int32)
 
 
-def _gqa_attend(q, k, v, window=None, scale=None):
+def _gqa_attend_tier(t, d, window, mesh=None):
+    """Which implementation attends a grouped-KV prefill of ``t`` rows
+    at heads of ``d``, decided from what is being lowered and never
+    from a flag (as ``_gather_tier``): ``("whole", None)`` for a bucket
+    of at most ``GQA_QUERY_BLOCK`` rows (one square of scores),
+    ``("flash", (bq, bk))`` for a longer bucket of a FULL layer where
+    the causal flash forward kernel may run (a TPU, no mesh of more
+    than one device and heads of whole half lane tiles —
+    ``kernel_enabled`` — or the tests' interpreter) and its blocks
+    divide the bucket, ``("blocked", None)`` otherwise:
+    a window layer (the band, and the ``Attended`` output that only the
+    composition makes), the CPU, a mesh."""
+    if t <= GQA_QUERY_BLOCK:
+        return "whole", None
+    if window is None:
+        from paddle_tpu.ops import pallas as _plk
+        blocks = _plk.causal_blocks(t, d, d)
+        if None not in blocks and (
+                _plk.kernel_enabled(64, t, d, mesh=mesh)
+                or _plk.forced_interpret()):
+            return "flash", blocks
+    return "blocked", None
+
+
+def _gqa_attend(q, k, v, window=None, scale=None, mesh=None):
     """Causal attention of q [B,T,n_kv,G,D] over k, v [B,T,n_kv,D] ->
     ([B,T,n_kv,G,D] in q's dtype, with a window what each query
     attends [T, 2]: ``_attended``). With ``window`` query t sees the
     keys s with 0 <= t - s < window: a band, and a block of queries
     reads only the keys its band can reach. ``scale`` multiplies the
-    scores (D ** -0.5 when None)."""
+    scores (D ** -0.5 when None). One algorithm, the implementation
+    chosen by shape (:func:`_gqa_attend_tier`, under ``mesh``): float32
+    scores, maximum, denominator and accumulator, the probabilities in
+    q's dtype for ``p . V``, whichever runs."""
     b, t, n_kv, g, d = q.shape
     dt = q.dtype
     scale = float(d) ** -0.5 if scale is None else float(scale)
-    blk = GQA_QUERY_BLOCK
-    if t <= blk:
+    tier, blocks = _gqa_attend_tier(t, d, window, mesh)
+    GQA_PREFILL_ATTEND_LOWERED.labels(path=tier).inc()
+    if tier == "whole":
         s = jnp.einsum("btkgd,bskd->bkgts", q, k,
                        preferred_element_type=jnp.float32) * scale
         keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
@@ -274,6 +313,17 @@ def _gqa_attend(q, k, v, window=None, scale=None):
         c = jnp.einsum("bkgts,bskd->btkgd", p, v,
                        preferred_element_type=jnp.float32).astype(dt)
         return c, (None if window is None else _attended(keep))
+    if tier == "flash":
+        # the kernel's key / value index maps read key head h // G: the
+        # keys stay [B, n_kv, T, D] in HBM, never broadcast to H heads
+        from paddle_tpu.ops import pallas as _plk
+        heads_first = lambda z: jnp.swapaxes(z, 1, 2)          # noqa: E731
+        o = _plk.flash_attention(
+            heads_first(q.reshape(b, t, n_kv * g, d)), heads_first(k),
+            heads_first(v), True, scale, blocks[0], blocks[1],
+            _plk.interpret_mode())
+        return heads_first(o).reshape(q.shape), None
+    blk = GQA_QUERY_BLOCK
     if t % blk:
         raise ValueError(f"a prompt bucket of {t} is not a whole number "
                          f"of {blk}-query blocks")
@@ -301,7 +351,7 @@ def _gqa_attend(q, k, v, window=None, scale=None):
             None if window is None else seen.reshape(t, 2))
 
 
-def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, ins, attrs, gqa):
+def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, ins, attrs, gqa, mesh=None):
     """Causal self-attention of a grouped-KV layer over X [B,T,M], plus
     the K/V projections [B,T,n_kv*d] the caller caches and, of a window
     layer, what each query attended (``_attended``). Without
@@ -315,7 +365,7 @@ def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, ins, attrs, gqa):
                        grouped=True)
     window = attrs.get("window")
     c, seen = _gqa_attend(q, k, v, int(window) if window else None,
-                          attrs.get("attn_scale"))
+                          attrs.get("attn_scale"), mesh)
     out = _gqa_output(x, c.reshape(b, t, h * d), wg, wo)
     return out, k.reshape(b, t, -1), v.reshape(b, t, -1), seen
 
@@ -502,7 +552,8 @@ def _kv_attention_prefill_paged(ctx, ins, attrs):
         with _device_scopes.variant("kv_attention_prefill_paged",
                                     "window", bool(attrs.get("window"))):
             out, k, v, seen = _gqa_causal_prefill(
-                x, wq, wk, wv, wo, first(ins, "Wg"), ins, attrs, gqa)
+                x, wq, wk, wv, wo, first(ins, "Wg"), ins, attrs, gqa,
+                ctx.mesh)
     else:
         out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
     m = flat_k.shape[1]

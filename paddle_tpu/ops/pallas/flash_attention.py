@@ -8,8 +8,20 @@ running (max, denom, acc) statistics — the standard flash recurrence.
 HBM traffic drops from O(Tq*Tk) to O(Tq*D + Tk*D) per head. The callers:
 ``ops/mla.py:causal_attention`` (latent attention's heads of 192 / 128,
 the trained cell), ``ops/nn_ops.py:fused_attention_block`` (heads of
-128 where ``flash_engage`` names blocks) and ring attention's per-shard
-inner loop (``flash_attention_lse``).
+128 where ``flash_engage`` names blocks), ring attention's per-shard
+inner loop (``flash_attention_lse``) and — the forward alone, PR 52 —
+``ops/kv_attention.py:_gqa_attend``: the serving prefill of a full
+grouped-KV layer over a bucket longer than 512 rows (LFM2's 32 query
+heads of 64 over 8 key heads, Granite's and Trinity's heads of 128).
+
+Grouped key heads (forward only). ``k`` and ``v`` may hold ``n_kv``
+heads for ``H = n_kv * G`` query heads: the key / value ``BlockSpec``
+index maps (``_kv_index_map``, dense grid and causal schedule alike)
+read row ``bh // G`` of the [B * n_kv, Tk, D] keys for query row ``bh``
+of [B * H, Tq, D], so the keys are never broadcast to H heads in HBM.
+A group of one keeps the maps, and the lowered text, it always had.
+The backward kernels have no such maps: both ``custom_vjp`` entries
+REFUSE a gradient through a grouped call (``_ungrouped``).
 
 Backward: jax.custom_vjp over blockwise Pallas kernels. Residuals are
 (q, k, v, o, lse) — O(T*D) — and the bwd recomputes scores tile-by-tile in
@@ -322,16 +334,46 @@ def _named(out, lse):
     return checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
 
 
+def _kv_index_map(h, n_kv):
+    """The key / value ``BlockSpec`` index map of the forward kernel
+    over (batch * query head, query block, key block). With grouped key
+    heads (``h = n_kv * G`` query heads, the serving prefill's) query
+    row ``bh`` of the [B * h, ...] view reads row ``bh // G`` of the
+    [B * n_kv, ...] keys: batch row ``bh // h``, key head ``(bh % h) //
+    G``. A group of one keeps the map, and so the lowered text, it
+    always had."""
+    if h % n_kv:
+        raise ValueError(f"{h} query heads are not a whole number of "
+                         f"groups over {n_kv} key heads")
+    g = h // n_kv
+    if g == 1:
+        return lambda bh, i, j: (bh, j, 0)
+    return lambda bh, i, j: (bh // g, j, 0)
+
+
+def _ungrouped(q, k):
+    """The backward kernels have no grouped index maps (the grouped
+    callers are the serving ops, ``no_grad``): a gradient through a
+    grouped call is refused here, in the forward rule, and never taken
+    with the wrong keys."""
+    if k.shape[1] != q.shape[1]:
+        raise ValueError(
+            f"flash attention over grouped key heads ({q.shape[1]} query, "
+            f"{k.shape[1]} key) is forward-only: no backward kernel "
+            "reads them")
+
+
 def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
                dropout_p=0.0, seed=None):
     from jax.experimental.pallas import tpu as pltpu
     if dropout_p <= 0:
         seed = None
     b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    n_kv, tk, dv = k.shape[1], k.shape[2], v.shape[3]
     q4 = q.reshape(b * h, tq, d)
-    k4 = k.reshape(b * h, tk, d)
-    v4 = v.reshape(b * h, tk, dv)
+    k4 = k.reshape(b * n_kv, tk, d)
+    v4 = v.reshape(b * n_kv, tk, dv)
+    kv_block = _kv_index_map(h, n_kv)
     nk = tk // bk
     grid = (b * h, tq // bq, nk)
     pairs = _count_causal("fwd", b * h, tq, tk, bq, bk) if causal else None
@@ -350,8 +392,8 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
             grid,
             [
                 pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-                pl.BlockSpec((1, bk, dv), lambda bh, i, j: (bh, j, 0)),
+                pl.BlockSpec((1, bk, d), kv_block),
+                pl.BlockSpec((1, bk, dv), kv_block),
             ],
             [
                 pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
@@ -395,7 +437,25 @@ def causal_blocks(t, d, dv):
     512 51.0, 2048 x 256 58.9, 512 x 256 64.8, 1024 x 256 67.6, 256 x
     256 76.5. Square tiles multiply 36/32 of the causal half where
     512 x 1024 multiply 72/64 — the same: it is the fewer, larger steps
-    that pay, and keys in tiles under 512 cost most."""
+    that pay, and keys in tiles under 512 cost most.
+
+    The grouped forward's rows (PR 52, one v5e; the serving prefill,
+    ``kv_attention._gqa_attend``), 1024 x 1024 everywhere from 2 048
+    tokens. 32 query / 8 key heads of 64 (``serve_lfm2_extract_closed``,
+    traced in the cell, a prefill's three calls): 4 096 tokens **3.901
+    ms** against 4.155 at 512 x 1024, 2 048 tokens **1.214** against
+    1.282; a call alone at 4 096, ms: 1024 x 1024 **1.417** (48.5
+    TFLOP/s of the causal half: both products half-fill the MXU at a
+    contraction and a result width of 64), 512 x 1024 1.489, 1024 x
+    2048 1.726, 256 x 1024 1.749, 512 x 2048 1.761, 512 x 512 2.144,
+    256 x 512 2.417, 1024 x 512 2.587, 128 x 512 3.468, 256 x 256
+    4.330 (2048 x 1024 and over: past the VMEM limit). 32 query / 4
+    key heads of 128 alone (Trinity's full layer; Granite's 8 key heads
+    read the same), 1024 x 1024 against 512 x 1024, ms: 2 048 tokens
+    0.452 | 0.486, 4 096 1.358 | 1.483, 8 192 4.674 | 5.082, 16 384
+    **17.21 | 18.75** (128 TFLOP/s). At 1 024 tokens (Granite's short
+    bucket: no row, ``pick_blocks``) 512 x 1024 0.238, 256 x 1024
+    0.234, 1024 x 1024 0.246."""
     try:
         from paddle_tpu.passes import autotune as at
         entry = at.lookup("flash_attention", {"T": int(t), "d": int(d),
@@ -502,7 +562,8 @@ def flash_attention(q, k, v, causal=False, scale=None, bq=128, bk=128,
                     interpret=False, dropout_p=0.0, seed=None):
     """q [B,H,Tq,D], k [B,H,Tk,D], v [B,H,Tk,Dv] → [B,H,Tq,Dv] (value
     heads of another size than the query/key heads': latent attention's
-    192 / 128). Tq % bq == Tk % bk == 0.
+    192 / 128). Tq % bq == Tk % bk == 0. Forward only, k and v may hold
+    n_kv heads with H = n_kv * G (the module docstring).
     dropout_p applies attention-weight dropout (upscale_in_train) with a
     keep mask derived from `seed` (int32 scalar, traced ok) + tile
     coordinates — identical in fwd and bwd kernels."""
@@ -514,6 +575,7 @@ def flash_attention(q, k, v, causal=False, scale=None, bq=128, bk=128,
 
 
 def _vjp_fwd(q, k, v, causal, scale, bq, bk, interpret, dropout_p, seed):
+    _ungrouped(q, k)
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     out, lse = _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,
@@ -756,6 +818,7 @@ def flash_attention_lse(q, k, v, causal=False, scale=None, bq=128, bk=128,
 
 def _lse_vjp_fwd(q, k, v, causal, scale, bq, bk, interpret, dropout_p,
                  seed):
+    _ungrouped(q, k)
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     out, lse = _flash_fwd(q, k, v, causal, scale, bq, bk, interpret,

@@ -693,6 +693,15 @@ def glm5_engine():
         pk.on_tpu = was
 
 
+def _flash_forward_calls(text, result):
+    """The Mosaic calls of a compiled prefill whose first result has the
+    shape ``result`` ([B * H, T, D]: the grouped-KV prefill's flash
+    forward, kv_attention._gqa_attend)."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and f"= ({result}" in line]
+
+
 def _compile_view(chip, programs, key, cb, feeds, monkeypatch):
     import numpy as np
     from paddle_tpu.ops import pallas as pk
@@ -863,8 +872,9 @@ def test_window_decode_step_compiles_for_v5e(chip, trinity_engine,
 
 def test_window_prefill_compiles_for_v5e(chip, trinity_engine, monkeypatch):
     """The 16 384-token prefill beside the weights and both page groups:
-    under 15 GB (attention in blocks of 512 queries: the whole prompt's
-    scores would be 34 GB), with no window reduction in it
+    under 15 GB (the window layers attend in blocks of 512 queries
+    over their band, the full layer through the flash kernel: the whole
+    prompt's scores would be 34 GB), with no window reduction in it
     (``kv_attention._softmax_rows``)."""
     eng, programs = trinity_engine
     compiled = _compile_view(chip, programs, "prefill_paged@16384",
@@ -872,7 +882,13 @@ def test_window_prefill_compiles_for_v5e(chip, trinity_engine, monkeypatch):
                              eng._prefill_feeds(16384), monkeypatch)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
-    assert "reduce-window" not in compiled.as_text()
+    text = compiled.as_text()
+    assert "reduce-window" not in text
+    # the one full layer takes the flash forward (ISSUE 52), the four
+    # window layers keep their loop of query blocks over the band
+    assert len(_flash_forward_calls(text, "bf16[32,16384,128]")) == 1
+    assert "f32[4,8,512,16384]" not in text
+    assert _count_opcode(text, "while") >= 4
 
 
 # ---------------------------------------------------------------------------
@@ -959,6 +975,7 @@ def test_ssd_prefill_compiles_for_v5e(chip, granite_engine, monkeypatch):
     assert not [line for opcode, count, _a, line in ops.values()
                 if opcode in ("copy", "transpose")
                 and count >= 128 * 128 * 8192]
+    assert len(_flash_forward_calls(text, "bf16[32,2048,128]")) == 1
     assert text.startswith("HloModule jit_lm_prefill_paged_2048_s0b8b,")
 
 
@@ -1019,8 +1036,10 @@ def test_shortconv_prefill_compiles_for_v5e(chip, lfm2_engine, monkeypatch):
     """The 4096-token prefill beside the weights and the pages: under
     10.5 GB; 4096 tokens take the experts' grouped way over buffers of
     every assignment (the member holds every expert: three ``ragged-dot``
-    an expert layer, no loop of turns); the attention's softmax over
-    4096 keys holds no window reduction."""
+    an expert layer, no loop of turns); each of the three attention
+    layers attends through ONE causal flash forward kernel over 32
+    query heads of 64 (ISSUE 52: 8 key heads, read by the index maps) —
+    no loop of query blocks, no float32 block of scores."""
     eng, programs = lfm2_engine
     compiled = _compile_view(chip, programs, "prefill_paged@4096",
                              eng._cb_prefill[4096],
@@ -1030,7 +1049,8 @@ def test_shortconv_prefill_compiles_for_v5e(chip, lfm2_engine, monkeypatch):
     text = compiled.as_text()
     assert "reduce-window" not in text
     assert text.count("ragged_dot_tiling") == 30
-    assert _count_opcode(text, "while") >= 3     # the attention's blocks
+    assert len(_flash_forward_calls(text, "bf16[32,4096,64]")) == 3
+    assert "f32[8,4,512,4096]" not in text       # a block's scores
     assert text.startswith("HloModule jit_lm_prefill_paged_4096_sfa9e,")
 
 

@@ -117,6 +117,42 @@ def test_prefill_then_decode_matches_the_full_forward(engine, prompt_len):
         assert worst(engine, prompt_len, forced=False)[0] <= TOL
 
 
+@pytest.fixture(scope="module")
+def flash_engine():
+    """Buckets of 128 and 256 rows with the prefill's flash kernel
+    forced on (interpreted; ``GQA_QUERY_BLOCK`` lowered under the
+    buckets, tiles of 64 x 128 so that a bucket is several): the
+    programs are traced inside ``warmup()``, under the patches."""
+    from paddle_tpu.ops import kv_attention as kv
+    from paddle_tpu.ops import pallas as pk
+    build = {**BUILD, "prompt_buckets": [128, 256], "prompt_len": 256}
+    before = kv.GQA_PREFILL_ATTEND_LOWERED.labels(path="flash").value
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+        patch.setattr(kv, "GQA_QUERY_BLOCK", 64)
+        patch.setattr(pk, "causal_blocks", lambda t, d, dv: (64, 128))
+        engine = make_engine(prompt_buckets=build["prompt_buckets"],
+                             prompt_len=build["prompt_len"])
+    lowered = kv.GQA_PREFILL_ATTEND_LOWERED.labels(path="flash").value
+    return engine, build, lowered - before
+
+
+@pytest.mark.parametrize("prompt_len", [70, 129, 256])
+def test_the_prefill_through_the_flash_kernel_matches_the_full_forward(
+        flash_engine, prompt_len):
+    """ISSUE 52: the prefill view attending through the causal flash
+    forward kernel (grouped key heads, rotated and normalised q and k,
+    the bucket's padded rows attended as before) against one full causal
+    forward with no cache, to the float32 tolerance of every other
+    comparison here; every lowering of a bucket's program counted its
+    two attention layers under ``flash``."""
+    engine, build, lowered = flash_engine
+    assert lowered and lowered % (2 * len(build["prompt_buckets"])) == 0
+    err, margin = worst(engine, prompt_len, max_new=4, build=build)
+    assert err <= TOL
+    assert margin == 0.0
+
+
 @pytest.mark.parametrize("prompt_len", [1, 2, 11])
 def test_the_window_after_a_prefill_is_the_prompts_true_end(engine,
                                                             prompt_len):

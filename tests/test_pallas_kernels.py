@@ -545,6 +545,192 @@ def test_a_name_outside_a_policy_lowers_to_nothing():
     assert "flash_out" not in text and "flash_lse" not in text
 
 
+# ------------------------------------------------------------------ ISSUE 52
+# grouped key heads: the forward's key / value index maps read head
+# bh // G, and the serving prefill (kv_attention._gqa_attend) takes the
+# kernel by shape
+
+def _grouped_case(g, d, t, dtype=jnp.float32, n_kv=2, b=2):
+    rng = np.random.RandomState(g * 1000 + d + t)
+    q = jnp.asarray(rng.randn(b, t, n_kv, g, d), dtype)
+    k, v = (jnp.asarray(rng.randn(b, t, n_kv, d), dtype) for _ in "kv")
+    return q, k, v
+
+
+def _grouped_reference(q, k, v, scale):
+    """float32, every key head written out for its G query heads."""
+    q, k, v = (np.asarray(z, np.float64) for z in (q, k, v))
+    t = q.shape[1]
+    s = np.einsum("btkgd,bskd->bkgts", q, k) * scale
+    s = np.where(np.arange(t)[:, None] >= np.arange(t)[None, :], s, -np.inf)
+    p = np.exp(s - s.max(axis=-1, keepdims=True))
+    p /= p.sum(axis=-1, keepdims=True)
+    return np.einsum("bkgts,bskd->btkgd", p, v)
+
+
+@pytest.mark.parametrize("scale", [None, 0.3], ids=["scale_d", "scale_0.3"])
+@pytest.mark.parametrize("t,bq,bk", [(256, 128, 128), (512, 128, 256),
+                                     (512, 256, 128)])
+@pytest.mark.parametrize("g,d", [(4, 64), (4, 128), (8, 128)])
+def test_grouped_forward_matches_the_composition_and_a_reference(
+        g, d, t, bq, bk, scale, monkeypatch):
+    """The causal forward kernel (interpreted) over 2 key heads for 2 G
+    query heads, two batch rows, against ``_gqa_attend``'s blocked
+    composition and against a float64 reference that broadcasts the
+    keys: blocks of 128-256 over 256-512 rows, ``bq != bk`` among them,
+    the op's scale and the default."""
+    from paddle_tpu.ops import kv_attention as kv
+    fa = _flash_module()
+    q, k, v = _grouped_case(g, d, t)
+    b, _t, n_kv = k.shape[:3]
+    monkeypatch.setattr(kv, "GQA_QUERY_BLOCK", 128)
+    heads_first = lambda z: jnp.swapaxes(z, 1, 2)              # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        got = heads_first(fa.flash_attention(
+            heads_first(q.reshape(b, t, n_kv * g, d)), heads_first(k),
+            heads_first(v), True, scale, bq, bk, True)).reshape(q.shape)
+        blocked, _ = kv._gqa_attend(q, k, v, scale=scale)
+    want = _grouped_reference(q, k, v, d ** -0.5 if scale is None else scale)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got, blocked, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("g,d", [(4, 64), (8, 128)])
+def test_grouped_forward_rounds_the_probabilities_as_the_composition(
+        g, d, monkeypatch):
+    """In bfloat16 both ways hold float32 scores and statistics and
+    round the probabilities to bfloat16 for ``p . V``: they agree to
+    that rounding (2 ** -7 of the largest value), through
+    ``_gqa_attend`` itself with the kernel forced on."""
+    from paddle_tpu.ops import kv_attention as kv
+    q, k, v = _grouped_case(g, d, 256, jnp.bfloat16)
+    monkeypatch.setattr(kv, "GQA_QUERY_BLOCK", 128)
+    blocked, _ = kv._gqa_attend(q, k, v)
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    assert kv._gqa_attend_tier(256, d, None)[0] == "flash"
+    got, seen = kv._gqa_attend(q, k, v)
+    assert seen is None and got.dtype == jnp.bfloat16
+    want = _grouped_reference(*(z.astype(jnp.float32) for z in (q, k, v)),
+                              d ** -0.5)
+    for other in (np.asarray(blocked, np.float32), want):
+        np.testing.assert_allclose(np.asarray(got, np.float32), other,
+                                   atol=2.0 ** -7 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("entry", ["flash_attention", "flash_attention_lse"])
+def test_a_gradient_through_grouped_key_heads_is_refused(entry):
+    """The backward kernels have no grouped index maps: both
+    ``custom_vjp`` entries refuse in their forward rule; the plain
+    forward of each runs."""
+    fa = _flash_module()
+    q = jnp.ones((1, 4, 16, 8), jnp.float32)
+    kv_ = jnp.ones((1, 2, 16, 8), jnp.float32)
+    call = lambda q, k, v: getattr(fa, entry)(                 # noqa: E731
+        q, k, v, True, None, 8, 8, True)
+    out = jax.tree_util.tree_leaves(call(q, kv_, kv_))[0]
+    np.testing.assert_allclose(out, 1.0, rtol=1e-6)
+    with pytest.raises(ValueError, match="forward-only"):
+        jax.grad(lambda q: jnp.sum(jax.tree_util.tree_leaves(
+            call(q, kv_, kv_))[0]))(q)
+    with pytest.raises(ValueError, match="whole number of groups"):
+        call(q, jnp.ones((1, 3, 16, 8), jnp.float32),
+             jnp.ones((1, 3, 16, 8), jnp.float32))
+
+
+def test_a_group_of_one_keeps_the_maps_it_had_and_groups_change_two():
+    """The grouped call's kernel is the ungrouped call's but for the
+    key and value block mappings (``bh // G``): same body, same query
+    and output maps."""
+    fa = _flash_module()
+
+    def mappings(n_kv):
+        fn = lambda q, k, v: fa.flash_attention(               # noqa: E731
+            q, k, v, True, None, 128, 128)
+        q = jax.ShapeDtypeStruct((2, 8, 256, 64), jnp.bfloat16)
+        kv_ = jax.ShapeDtypeStruct((2, n_kv, 256, 64), jnp.bfloat16)
+        (e,) = [e for e in _walk(jax.make_jaxpr(fn)(q, kv_, kv_).jaxpr)
+                if e.primitive.name == "pallas_call"]
+        return ([str(m.index_map_jaxpr)
+                 for m in e.params["grid_mapping"].block_mappings],
+                str(e.params["jaxpr"]))
+    one, body_one = mappings(8)
+    four, body_four = mappings(2)
+    assert body_one == body_four
+    assert [a == b for a, b in zip(one, four)] == [
+        True, False, False, True, True]
+    assert "div" in four[1] and "div" not in one[1]
+
+
+def _attend_lowered():
+    from paddle_tpu.ops import kv_attention as kv
+    return {p: kv.GQA_PREFILL_ATTEND_LOWERED.labels(path=p).value
+            for p in ("flash", "blocked", "whole")}
+
+
+@pytest.mark.parametrize("forced,t,d,window,want", [
+    ("1", 1024, 64, None, "flash"),        # a long full layer
+    ("1", 2048, 128, None, "flash"),
+    ("1", 1024, 64, 256, "blocked"),       # a window layer keeps the band
+    ("0", 1024, 64, None, "blocked"),      # no TPU, nothing forced
+    ("1", 512, 64, None, "whole"),         # one square of scores
+    ("1", 512, 64, 256, "whole"),
+    ("0", 256, 128, None, "whole")])
+def test_gqa_attend_chooses_by_shape_and_the_counter_says_which(
+        forced, t, d, window, want, monkeypatch):
+    """``paddle_gqa_prefill_attend_lowered_total{path}`` grows by one a
+    lowering of ``_gqa_attend``, under the label of the implementation
+    its shapes chose; the flash path counts its causal tiles too."""
+    from paddle_tpu.ops import kv_attention as kv
+    fa = _flash_module()
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", forced)
+    q = jax.ShapeDtypeStruct((1, t, 2, 4, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, t, 2, d), jnp.bfloat16)
+    tiles = lambda: fa.CAUSAL_BLOCKS.labels(                   # noqa: E731
+        kernel="fwd", kind="computed").value
+    before, tiles_before = _attend_lowered(), tiles()
+    out, seen = jax.eval_shape(
+        lambda q, k, v: kv._gqa_attend(q, k, v, window), q, k, k)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert (seen is None) == (window is None)
+    grew = {p: n - before[p] for p, n in _attend_lowered().items()}
+    assert grew == {p: float(p == want) for p in grew}
+    bq, bk = fa.causal_blocks(t, d, d)
+    assert tiles() - tiles_before == (
+        8 * len(fa._visible_tiles(t, t, bq, bk)) if want == "flash" else 0)
+
+
+@pytest.mark.parametrize("devices,t,d,want,want_blocks", [
+    (1, 4096, 64, "flash", (1024, 1024)),  # LFM2's rows of the table
+    (1, 2048, 64, "flash", (1024, 1024)),
+    (1, 16384, 128, "flash", (1024, 1024)),    # Trinity's full layer
+    (1, 1024, 128, "flash", (512, 1024)),  # Granite's short bucket
+    (4, 4096, 64, "blocked", None),        # XLA cannot partition Mosaic
+    (1, 4096, 16, "blocked", None),        # heads under half a lane tile
+    (1, 512, 64, "whole", None)])
+def test_gqa_attend_tier_on_a_chip_and_under_a_mesh(devices, t, d, want,
+                                                    want_blocks,
+                                                    monkeypatch):
+    """On a TPU (steered: the rule asks ``on_tpu``) the kernel is taken
+    off a mesh at aligned shapes only, at the committed table's blocks
+    (``causal_blocks``) or ``pick_blocks``'s; under a mesh of more than
+    one device the composition, which XLA partitions, stays."""
+    from jax.sharding import Mesh
+    from paddle_tpu.ops import kv_attention as kv
+    from paddle_tpu.ops import pallas as pk
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(jax.devices()[:devices]), ("dp",))
+    tier, blocks = kv._gqa_attend_tier(t, d, None, mesh)
+    assert (tier, blocks) == (want, want_blocks)
+    assert kv._gqa_attend_tier(t, d, 2048, mesh)[0] != "flash"
+
+
+def test_the_attend_counter_is_in_the_exporters_catalog():
+    from paddle_tpu.observability import exporters, metrics as obs_metrics
+    exporters._preregister_catalog()
+    assert "paddle_gqa_prefill_attend_lowered_total" in \
+        obs_metrics.default_registry().snapshot()
+
+
 # ------------------------------------------------------------------ ISSUE 34
 # attend_pages: a slot's live pages attended in place under a mask,
 # against the gathered path (the page gather, then _decode_contract)
