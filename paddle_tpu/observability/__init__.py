@@ -33,7 +33,11 @@ watchable:
   witness (``FLAGS_lock_witness``): ``ObservedLock`` validates the
   global lock DAG per acquisition, counting inversions and dumping
   both offending stacks through the flight recorder — the dynamic twin
-  of the static ``ccy-lock-order-cycle`` lint.
+  of the static ``ccy-lock-order-cycle`` lint;
+- :mod:`~paddle_tpu.observability.pause_watch` — one thread that names
+  the host's pauses (span ``host.pause``,
+  ``paddle_host_pause*_total{cause}``); it exists only while a tracer,
+  a span sink, step telemetry or an exporter listens.
 
 Everything is off by default; with no observability flag set the hot
 path pays one flag lookup per executor dispatch. Metric catalog and
@@ -51,6 +55,7 @@ from paddle_tpu.observability import spool  # noqa: F401
 from paddle_tpu.observability import flight_recorder  # noqa: F401
 from paddle_tpu.observability import lock_witness  # noqa: F401
 from paddle_tpu.observability import memory  # noqa: F401
+from paddle_tpu.observability import pause_watch  # noqa: F401
 from paddle_tpu.observability.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, counter, default_registry,
     gauge, histogram)
@@ -67,11 +72,13 @@ def enable():
     flag-free path tests and bench use)."""
     global _force_enabled
     _force_enabled = True
+    pause_watch.hold("telemetry")
 
 
 def disable():
     global _force_enabled
     _force_enabled = False
+    pause_watch.release("telemetry")
 
 
 def enabled() -> bool:

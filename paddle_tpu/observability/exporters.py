@@ -34,6 +34,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from paddle_tpu.observability import metrics
+from paddle_tpu.observability import pause_watch
 
 STEP_LOG_NAME = "steps.jsonl"
 PROM_NAME = "metrics.prom"
@@ -240,6 +241,9 @@ def _preregister_catalog():
     snapshot of ANY observed run) holds without those paths firing."""
     import importlib
     for mod in ("paddle_tpu.observability.runtime",
+                # the pause watch's two (paddle_host_pauses_total,
+                # paddle_host_pause_seconds_total{cause})
+                "paddle_tpu.observability.pause_watch",
                 # HBM memory families (paddle_hbm_*, paddle_donation_*,
                 # paddle_oom_*): compiled breakdowns, census gauges,
                 # donation violations, OOM events
@@ -370,7 +374,10 @@ def ensure_started() -> bool:
                 warnings.warn(f"metrics scrape endpoint disabled: "
                               f"cannot bind port {port}: {e!r}")
         _started_from_flags = True
-        return _dumper is not None or _server is not None
+        if _dumper is not None or _server is not None:
+            pause_watch.hold("exporters")
+            return True
+        return False
 
 
 def active_dumper() -> Optional[MetricsDumper]:
@@ -400,6 +407,7 @@ def shutdown():
             _server.stop()
             _server = None
         _started_from_flags = False
+    pause_watch.release("exporters")
 
 
 @atexit.register

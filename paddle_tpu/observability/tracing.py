@@ -47,6 +47,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 from paddle_tpu.observability import metrics as _metrics
+from paddle_tpu.observability import pause_watch as _pause_watch
 
 DROPPED_SPANS = _metrics.counter(
     "paddle_trace_dropped_spans_total",
@@ -107,9 +108,13 @@ class Tracer:
             # its blocks are gone (device_scopes.scopes())
             from paddle_tpu.observability import device_scopes
             device_scopes.hold()
+            # and the host's pauses get a name while it runs
+            _pause_watch.hold("tracer")
 
     def stop(self):
         self._enabled = False
+        if self is _DEFAULT:
+            _pause_watch.release("tracer")
 
     def active(self) -> bool:
         """True when spans are being captured (ring enabled or any sink
@@ -124,11 +129,16 @@ class Tracer:
         with self._lock:
             if sink not in self._sinks:
                 self._sinks.append(sink)
+        if self is _DEFAULT:
+            _pause_watch.hold("sinks")
 
     def remove_sink(self, sink: Callable[[Span], None]):
         with self._lock:
             if sink in self._sinks:
                 self._sinks.remove(sink)
+            last = not self._sinks
+        if last and self is _DEFAULT:
+            _pause_watch.release("sinks")
 
     def reset(self):
         with self._lock:

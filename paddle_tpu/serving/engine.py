@@ -284,6 +284,12 @@ class GenerativeModel:
             # the oracle's sequence: the view's own feed, ids [-1, T, 1]
             self._full_len = int(full_feeds["ids"][0][1])
         self._warmed: set = set()   # the executables' keys, once warm
+        # the newest dispatch's first output, still on the device, and
+        # the dispatches that found it ready: ``_launch``
+        self._prev_output = None
+        self._m_starved = {
+            view: smetrics.DISPATCH_STARVED.labels(model=name, view=view)
+            for view in ("decode", "prefill")}
         self._aot: Dict[Tuple, object] = {}
         self._aot_names = _Loaded(self._aot)
 
@@ -312,11 +318,22 @@ class GenerativeModel:
         tracing, the host's time is named ``serving.<kind>.args`` (scope
         lookups) and ``.dispatch`` (until the async call
         returns, and the state write-back), ``<kind>`` being ``prefill``
-        or ``decode`` by ``aot_key[0]`` (a verify step is a decode)."""
+        or ``decode`` by ``aot_key[0]`` (a verify step is a decode).
+        Before the call it asks whether the device has run dry (``_dry``)
+        and counts the dispatch that found it so
+        (``paddle_serving_dispatch_starved_total{model, view}``; while
+        tracing also a zero-length marker ``serving.starved.<kind>``)."""
         from paddle_tpu.observability import memory as obs_memory
         from paddle_tpu.utils import faults
         trace_on = tctx.active()
         t0 = time.perf_counter() if trace_on else 0.0
+        view = "prefill" if aot_key[0].startswith("prefill") else "decode"
+        if self.dist is None and self._dry():
+            self._m_starved[view].inc()
+            if trace_on:
+                # zero-length: it marks the instant and covers no gap
+                tctx.record_span("serving.starved." + view, t0, t0,
+                                 ctx=tctx.current(), model=self.name)
         args = self._args(cb, feeds)
         t1 = time.perf_counter() if trace_on else 0.0
         try:
@@ -357,14 +374,31 @@ class GenerativeModel:
             raise
         for n, v in new_state.items():
             self.scope.set_var(n, v)
-        kind = ("serving.prefill" if aot_key[0].startswith("prefill")
-                else "serving.decode")
+        self._prev_output = fetches[0]
+        kind = "serving." + view
         if trace_on:
             ctx = tctx.current()
             tctx.record_span(kind + ".args", t0, t1, ctx=ctx)
             tctx.record_span(kind + ".dispatch", t1, time.perf_counter(),
                              ctx=ctx)
         return fetches[0], kind
+
+    def _dry(self) -> bool:
+        """Has the device run everything this engine queued? The output
+        of the previous dispatch answers (``jax.Array.is_ready()``: no
+        wait, no transfer): one in-order stream, so ready means the
+        device is idle now and the dispatch about to be made starts late
+        by what the host still has to do. False where nothing can answer:
+        no dispatch yet, an output without the method, a deleted array
+        (asked first: jax 0.9's ``is_ready()`` on one ends the process)."""
+        prev = self._prev_output
+        ready = getattr(prev, "is_ready", None)
+        if ready is None:
+            return False
+        deleted = getattr(prev, "is_deleted", None)
+        if deleted is not None and deleted():
+            return False
+        return bool(ready())
 
     def _fetch(self, out, kind) -> np.ndarray:
         """The blocking fetch of a launched dispatch's output (span

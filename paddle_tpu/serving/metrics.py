@@ -73,6 +73,20 @@ TOKENS_GENERATED = _metrics.counter(
 DECODE_STEPS = _metrics.counter(
     "paddle_serving_decode_steps_total",
     "Single-token decode executable dispatches", labelnames=("model",))
+DISPATCH_STARVED = _metrics.counter(
+    "paddle_serving_dispatch_starved_total",
+    "Dispatches of an engine that found the device DRY: the output of "
+    "the engine's previous dispatch was already ready (one is_ready() a "
+    "dispatch, no wait and no transfer), so the in-order stream had run "
+    "everything queued and this dispatch starts late by what the host "
+    "still had to do. view = decode | prefill. The first dispatch after "
+    "the engine was empty is dry by construction; never asked under a "
+    "mesh", labelnames=("model", "view"))
+SCHEDULER_ERRORS = _metrics.counter(
+    "paddle_serving_scheduler_errors_total",
+    "Exceptions the slot scheduler's loop swallowed outside a step (a "
+    "bookkeeping error): each one backs the loop off for 50 ms, which a "
+    "trace shows as the span serving.sched.error", labelnames=("model",))
 SAMPLING_STEPS = _metrics.counter(
     "paddle_sampling_steps_total",
     "Slot-engine decode (or verify) steps dispatched with at least one "

@@ -423,6 +423,7 @@ class _SlotHostedModel(_HostedModel):
         # the loop's per-step and per-token metric children, bound once
         self._m_batches = smetrics.BATCHES.labels(model=name)
         self._m_inter_token = smetrics.INTER_TOKEN.labels(model=name)
+        self._m_sched_errors = smetrics.SCHEDULER_ERRORS.labels(model=name)
         super().__init__(name, engine, max_queue_depth, linger_s,
                          dedup_capacity, oom_exit=oom_exit)
 
@@ -605,11 +606,18 @@ class _SlotHostedModel(_HostedModel):
                     tctx.record_span(
                         "serving.sched.commit", now,
                         time.perf_counter(), model=self.name)
-            except Exception:
+            except Exception as e:
                 # never let the scheduler die; back off so a
                 # persistent bookkeeping error can't hot-spin the
-                # thread, then re-evaluate from the maps
+                # thread, then re-evaluate from the maps. Counted and,
+                # while tracing, named: 50 ms of the device's idle time
+                # that is the program's own doing, not the host's
+                t_err = time.perf_counter()
+                self._m_sched_errors.inc()
                 time.sleep(0.05)
+                tctx.record_span(
+                    "serving.sched.error", t_err, time.perf_counter(),
+                    model=self.name, error=type(e).__name__)
                 continue
 
     def mean_occupancy(self) -> float:

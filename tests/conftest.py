@@ -104,12 +104,23 @@ STEP_MODULES_SINCE_PR37 = {
 # file whole. A ``benchmark`` PR drops the pin (PERF.md section 7 (42)).
 LAST_METRIC_OF_PR44 = "moe_grouped_held_rows_pct.prefill"
 LAST_CELL_OF_PR44 = "serve_granite_sessions_closed"
+# ``tests/chipbench/test_chipbench_serve_lfm2.py`` (PR 51) pins ITS four
+# metrics as the last four of the per-layer list in the same way; PR 53
+# appended four more (``host_pause_pct.*``, ``dispatch_starved_pct.decode``).
+# That module is shown the list cut behind PR 51's last entry — so it
+# does not see the four new metrics on its cell's list either, which
+# ``tests/chipbench/test_chipbench_host_gaps.py`` checks — and every
+# other module reads the file whole. The same ``benchmark`` PR drops it.
+LAST_METRIC_OF_PR51 = "attn_ms_per_prefill"
+LIST_CUT_BEHIND = {"test_chipbench_moe_grouped": LAST_METRIC_OF_PR44,
+                   "test_chipbench_serve_lfm2": LAST_METRIC_OF_PR51}
 
 
 @pytest.fixture(autouse=True)
-def _per_layer_list_as_pr44_left_it(request, monkeypatch):
-    if request.module.__name__.rsplit(".", 1)[-1] != \
-            "test_chipbench_moe_grouped":
+def _per_layer_list_as_the_module_pinned_it(request, monkeypatch):
+    module = request.module.__name__.rsplit(".", 1)[-1]
+    last = LIST_CUT_BEHIND.get(module)
+    if last is None:
         return
     from chipbench import harness
     real = harness.load_benchmark
@@ -117,11 +128,13 @@ def _per_layer_list_as_pr44_left_it(request, monkeypatch):
     def load():
         bench = real()
         names = [m["name"] for m in bench["per_layer"]]
-        del bench["per_layer"][names.index(LAST_METRIC_OF_PR44) + 1:]
-        cells = [w["name"] for w in bench["workloads"]]
-        later = set(cells[cells.index(LAST_CELL_OF_PR44) + 1:])
-        for m in bench["per_layer"][-2:]:
-            m["workloads"] = [w for w in m["workloads"] if w not in later]
+        del bench["per_layer"][names.index(last) + 1:]
+        if last == LAST_METRIC_OF_PR44:
+            cells = [w["name"] for w in bench["workloads"]]
+            later = set(cells[cells.index(LAST_CELL_OF_PR44) + 1:])
+            for m in bench["per_layer"][-2:]:
+                m["workloads"] = [w for w in m["workloads"]
+                                  if w not in later]
         return bench
     monkeypatch.setattr(harness, "load_benchmark", load)
 

@@ -155,14 +155,16 @@ def test_merged_multi_trainer_timeline(tmp_path):
     assert labels == {0: "trainer0", 1: "trainer1"}
     # each lane carries that rank's training span(s), no other rank's,
     # and that rank's own executor spans (recorded whenever a profiler
-    # is active: executor.run with its prepare / dispatch parts)
+    # is active: executor.run with its prepare / dispatch parts; and
+    # what the pause watch records while one is: a host.pause wherever
+    # this machine held the process up)
     for pid, label in labels.items():
         rank_events = [e["name"] for e in trace["traceEvents"]
                        if e["ph"] == "X" and e["pid"] == pid]
         mine = [n for n in rank_events if n.startswith(f"rank{pid}/")]
         rest = [n for n in rank_events if n not in mine]
         assert mine, rank_events
-        assert rest and all(n.startswith(("executor.", "compile."))
+        assert rest and all(n.startswith(("executor.", "compile.", "host."))
                             for n in rest), rank_events
         assert "executor.run" in rest and "executor.dispatch" in rest
 
