@@ -279,6 +279,9 @@ def _preregister_catalog():
                 # which way a latent-attention decode layer attends
                 # (paddle_mla_decode_lowered_total{path})
                 "paddle_tpu.ops.mla",
+                # whose weights an expert layer's dense way streams
+                # (paddle_expert_dense_lowered_total{path})
+                "paddle_tpu.ops.expert_ffn",
                 # which tier advances a KDA decode layer's state
                 # (paddle_kda_decode_lowered_total{path})
                 "paddle_tpu.ops.kda",

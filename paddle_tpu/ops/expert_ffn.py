@@ -42,15 +42,45 @@ number of tokens in the call (a static shape, never a flag):
   the sum over experts is part of that one product. Below the chip's
   ridge point (197 TFLOP/s over 819 GB/s: 240 multiply-accumulate rows
   a bf16 weight) multiplying all tokens costs no more time than reading
-  an expert's weights does, nearly every held expert is hit in a step
-  anyway (128 tokens x 8 picks over 320 experts: 96 %), and the step's
-  cost no longer depends on the routing draw: on the chip the grouped
-  products of a 128-token step read 38 % of the HBM roofline and moved
-  with the seed. Above the ridge point the dense way goes on winning
-  for a while, because the grouped product's row tiles are mostly
-  padding at a few tokens an expert: one layer's held experts took
-  1.76 / 1.89 / 3.47 ms dense against 4.41 / 4.71 / 4.93 ms grouped at
-  128 / 256 / 512 tokens, the lines crossing near 700 (PERF.md, PR 31).
+  an expert's weights does, so the weights' bytes are the cost, and
+  WHOSE weights a call streams is chosen from the shapes again
+  (:func:`dense_tier`, counted by
+  ``paddle_expert_dense_lowered_total{path}``):
+
+  - ``all``, every held expert's, in two fusions — where nearly every
+    held expert is hit in a step anyway (128 tokens x 8 picks over 320
+    experts: 96 %): the step's cost does not depend on the routing
+    draw, and the fusions run at 85-91 % of the HBM rate. On the chip
+    the grouped products of a 128-token step read 38 % of the HBM
+    roofline and moved with the seed. Above the ridge point this goes
+    on winning for a while, because the grouped product's row tiles are
+    mostly padding at a few tokens an expert: one layer's held experts
+    took 1.76 / 1.89 / 3.47 ms dense against 4.41 / 4.71 / 4.93 ms
+    grouped at 128 / 256 / 512 tokens, the lines crossing near 700
+    (PERF.md, PR 31);
+  - ``skip``, the HIT experts' alone, in one kernel through the down
+    product (``ops/pallas/expert_stream.py``, PR 54) — where a uniform
+    router would leave at least a tenth of the held experts without a
+    token (``SKIP_MIN_UNPICKED``: ``(1 - top_k / n_experts) **
+    n_tokens``) in a call at or under the ridge, on a chip, off a mesh,
+    at widths of whole lane tiles. The held experts' ``sizes`` are on
+    the device before the products start; an expert with none would add
+    exact zeros, so its weights are not read and the sum is the same
+    sum. The step's cost now FOLLOWS THE DRAW: one layer at GLM-5's
+    shape 1.66 ms with 16 of 16 held experts hit, 0.96 with 9 (the two
+    fusions 1.65 whatever the draw; PERF.md, PR 54).
+
+  | cell (decode step) | tokens x picks / experts | expected unpicked | read in the cell | the step streams |
+  |---|---|---|---|---|
+  | ``serve_glm5_decode_longctx`` | 32 x 8 / 256 | 36 % | 40 % | the hit experts (``skip``) |
+  | ``serve_trinity_decode_mixedctx`` | 32 x 8 / 128 | 12.7 % | 13 % | the hit experts (``skip``) |
+  | ``serve_solar_decode_closed`` | 128 x 8 / 320 | 3.9 % | 7 % | every held expert (``all``) |
+  | ``serve_granite_sessions_closed`` | 128 x 10 / 72 | ~0 | 5 % | every held expert (``all``) |
+  | ``serve_lfm2_extract_closed`` | 64 x 4 / 32 | 0.02 % | 0.02 % | every held expert (``all``) |
+
+  Solar's prefills of up to 512 tokens, every call under a mesh of more
+  than one device and every call off the chip stream every held
+  expert's, as before.
 
 ``parallel/moe.py`` is the trainer's top-1 layer with capacity drops (op
 ``moe_ffn``) and is not this.
@@ -69,6 +99,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.registry import first, register_op
 from paddle_tpu.observability import device_scopes as _device_scopes
+from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.ops.math_ops import amp_dtypes, dense
 
 F32 = jnp.float32
@@ -115,6 +146,64 @@ def swiglu(x, w_gate, w_up, w_down):
 # docstring: the largest prompt bucket under the measured crossover of
 # ~700 tokens, so every size a cell serves takes one way)
 DENSE_MAX_TOKENS = 512
+# The dense way streams the HIT experts' weights alone
+# (ops/pallas/expert_stream.py) where a uniform router would leave at
+# least this share of the held experts unpicked in a call: an expert of
+# ``n_experts`` misses one token's ``top_k`` picks with probability
+# 1 - top_k / n_experts, all ``n_tokens`` of them with that to the power
+# n_tokens (:func:`unpicked_share`; a trained router is more skewed, not
+# less). GLM-5's step (32 x 8 / 256) expects 36 % and reads 40 % in its
+# cell, Trinity's (32 x 8 / 128) 12.7 % and 13 %; Solar's (128 x 8 /
+# 320: 3.9 %), Granite's (128 x 10 / 72) and LFM2's (64 x 4 / 32) are
+# under a tenth: nearly every expert is hit, the fusions already run at
+# 85-91 % of the HBM rate and a step whose cost does not move with the
+# draw is worth keeping. Measured before the constant was set (the op
+# alone on one v5e, PERF.md section 6, PR 54): at EVERY held expert hit
+# the kernel takes the fusions' time or less (GLM-5's layer 1.653
+# against 1.658 ms, Trinity's 2.193 against 2.319), so nothing is lost
+# at the worst draw and an unpicked share is that share of the bytes
+# saved (0.958 ms at 9 of 16 hit, 1.939 at 113 of 128): Trinity, nearest
+# the line, gains a tenth of its step and falls on the engaging side.
+SKIP_MIN_UNPICKED = 0.1
+# ... and the call is a decode-sized one: at or under the chip's ridge
+# (module docstring: 240 rows a bf16 weight), where the bytes and not
+# the products set the time
+SKIP_MAX_TOKENS = 240
+
+# exporter-catalog family (docs/serving.md "Metric names"). Counts
+# LOWERINGS, not steps: one increment each time an expert layer is
+# traced the dense way, labelled with what :func:`dense_tier` chose. In
+# the window the engaged share is 100 - moe_experts_hit_pct (row 1 of
+# ``Counts``).
+EXPERT_DENSE_LOWERED = _metrics.counter(
+    "paddle_expert_dense_lowered_total",
+    "Expert layers lowered the dense way, by whose weights a call "
+    "streams (skip: the hit experts'|all: every held expert's)",
+    labelnames=("path",))
+
+
+def unpicked_share(n_tokens: int, top_k: int, n_experts: int) -> float:
+    """The share of experts a uniform router leaves without a token in
+    a call of ``n_tokens`` tokens of ``top_k`` picks over ``n_experts``."""
+    return (1.0 - top_k / n_experts) ** n_tokens
+
+
+def dense_tier(n_tokens: int, top_k: int, n_experts: int, d_model: int,
+               d_expert: int, mesh=None) -> str:
+    """Whose weights the dense way streams, decided from the shapes the
+    op sees and never from a flag: ``"skip"`` (the hit experts' alone,
+    through the kernel) for a decode-sized call whose expected unpicked
+    share is at least ``SKIP_MIN_UNPICKED``, where the kernel may run (a
+    TPU, no mesh of more than one device, widths of whole lane tiles —
+    ``kernel_enabled`` — or the tests' interpreter); ``"all"`` (every
+    held expert's, the two fusions) otherwise."""
+    from paddle_tpu.ops import pallas as _plk
+    engages = (
+        n_tokens <= SKIP_MAX_TOKENS
+        and unpicked_share(n_tokens, top_k, n_experts) >= SKIP_MIN_UNPICKED
+        and d_model % 128 == d_expert % 128 == 0
+        and (_plk.kernel_enabled(mesh=mesh) or _plk.forced_interpret()))
+    return "skip" if engages else "all"
 
 
 def grouped_rows(n: int, k: int, n_held: int, n_experts: int) -> int:
@@ -131,16 +220,20 @@ def grouped_rows(n: int, k: int, n_held: int, n_experts: int) -> int:
 
 
 def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
-                      held_start: int, valid=None, n_experts=None):
+                      held_start: int, valid=None, n_experts=None,
+                      mesh=None):
     """The routed part of the layer that the held experts give: x [N, M],
     combine / idx [N, K], w_gate / w_up [E_held, M, F], w_down
     [E_held, F, M] -> (y [N, M] float32, tokens per held expert
     [E_held] int32). Tokens with ``valid`` false are routed nowhere.
     ``n_experts`` is the router's width (the grouped way sizes its
     buffers by the held share, :func:`grouped_rows`; every assignment's
-    worth when None)."""
+    worth when None — the held experts are all there are), ``mesh`` the
+    one the caller lowers under (:func:`dense_tier`)."""
     n, k = idx.shape
     n_held = w_gate.shape[0]
+    if n_experts is None:
+        n_experts = n_held
     if n <= DENSE_MAX_TOKENS:
         # the products multiply in x's dtype (a no-op but for float32
         # master weights under the mixed-precision rewrite; the grouped
@@ -159,6 +252,17 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
         with _phase("route"):
             # [N, E]: the token's combine weight for the expert, or zero
             w = jnp.sum(jnp.where(picked, combine[:, :, None], 0.0), axis=1)
+        tier = dense_tier(n, k, n_experts, x.shape[1], w_gate.shape[2], mesh)
+        EXPERT_DENSE_LOWERED.labels(path=tier).inc()
+        if tier == "skip":
+            # one kernel through the down product (the hidden rows stay
+            # in VMEM); an unhit expert would add exact zeros
+            from paddle_tpu.ops import pallas as _plk
+            from paddle_tpu.ops.pallas.expert_stream import hit_experts
+            with _phase("up"):
+                y = hit_experts(x, w, sizes, w_gate, w_up, w_down,
+                                interpret=_plk.interpret_mode())
+            return y, sizes
         with _phase("up"):
             hidden = jax.nn.silu(_all_tokens(x, w_gate)) \
                 * _all_tokens(x, w_up) * w[:, :, None]           # [N, E, F]
@@ -167,8 +271,7 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
                 hidden.astype(x.dtype), w_down,
                 (((1, 2), (0, 1)), ((), ())), preferred_element_type=F32)
         return y, sizes
-    rows = grouped_rows(n, k, n_held, n_held if n_experts is None
-                        else n_experts)
+    rows = grouped_rows(n, k, n_held, n_experts)
     return _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes,
                         rows), sizes
 
@@ -423,7 +526,7 @@ def _expert_ffn_held(ctx, ins, attrs):
     y, sizes = held_experts_part(
         x2, combine, idx, first(ins, "WGate"), first(ins, "WUp"),
         first(ins, "WDown"), int(attrs.get("held_start", 0)), valid,
-        first(ins, "RouterW").shape[1])
+        first(ins, "RouterW").shape[1], getattr(ctx, "mesh", None))
     if first(ins, "SGate") is not None:
         with _phase("shared"):
             y = y + swiglu(x2, *(first(ins, n).astype(dt)

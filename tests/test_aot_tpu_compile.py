@@ -179,6 +179,15 @@ def _fused_lstm():
              ((b, 1), I32), ((b, h), F32), ((b, h), F32)])
 
 
+def _hit_experts(n, m, f, held):
+    # a decode step's tokens through the HIT held experts (ISSUE 54) at
+    # the committed table's tile of d_expert
+    from paddle_tpu.ops.pallas import expert_stream as es
+    return es.hit_experts, [((n, m), BF16), ((n, held), F32), ((held,), I32),
+                            ((held, m, f), BF16), ((held, m, f), BF16),
+                            ((held, f, m), BF16)]
+
+
 CASES = {
     "paged_gather-f32-4096x1024": lambda: _paged_gather(F32),
     "paged_gather-bf16-4096x1024": lambda: _paged_gather(BF16),
@@ -201,6 +210,11 @@ CASES = {
     "fused_ce-fwd+bwd-8192x512x32000": _fused_ce,
     "fused_lstm-fwd+bwd-100x64x512": _fused_lstm,
     "kda_state-f32-128x64x128x128": _kda_state,
+    # GLM-5's expert layer (16 of 256 held) and Trinity's (all 128)
+    "hit_experts-bf16-32x6144x2048x16": lambda: _hit_experts(32, 6144, 2048,
+                                                              16),
+    "hit_experts-bf16-32x2048x1024x128": lambda: _hit_experts(32, 2048, 1024,
+                                                               128),
 }
 
 
@@ -738,19 +752,28 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
     (``rows``: the same geometry under a smaller ratio) the step is
     PR 33's: ``jax.lax.top_k``, the chosen rows ordered by position and
     gathered ``bf16[65536,640]``. Neither way copies or gathers a whole
-    plane."""
-    from paddle_tpu.ops import mla
+    plane. Each of the four expert layers (32 tokens of 8 picks over a
+    router 256 wide: a uniform draw leaves 36 % of the held experts
+    unpicked) streams its HIT experts' weights through ONE kernel whose
+    result is the float32 sum ``f32[32,6144]`` (ISSUE 54): no product of
+    every held expert over every token (``[32,16,2048]``) is left."""
+    from paddle_tpu.ops import expert_ffn, mla
     eng, programs = glm5_engine
     if path == "rows":
         monkeypatch.setattr(mla, "ATTEND_PAGES_MAX_RATIO", 4)
         jax.clear_caches()          # the view was traced under the other
     lowered = {p: mla.MLA_DECODE_LOWERED.labels(path=p).value
                for p in ("pages", "rows")}
+    experts = {p: expert_ffn.EXPERT_DENSE_LOWERED.labels(path=p).value
+               for p in ("skip", "all")}
     compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
                              eng._decode_feeds(), monkeypatch)
     for p, was in lowered.items():
         assert mla.MLA_DECODE_LOWERED.labels(path=p).value - was \
             == (5 if p == path else 0)
+    for p, was in experts.items():
+        assert expert_ffn.EXPERT_DENSE_LOWERED.labels(path=p).value - was \
+            == (4 if p == "skip" else 0)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
     planes = 5 * 24576 * 16 * (640 + 128) * 2
@@ -760,7 +783,9 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
     ops = _hlo_ops(text)
     in_place = path == "pages"
     assert entry.count("custom_call_target=\"tpu_custom_call\"") \
-        == (10 if in_place else 5)
+        == (14 if in_place else 9)
+    assert len(re.findall(r"= f32\[32,6144\]\S* custom-call\(", entry)) == 4
+    assert "[32,16,2048]" not in text
     assert len(re.findall(r"= bf16\[393216,128\]\S* custom-call\(", entry)) \
         == 5
     assert len(re.findall(r"= bf16\[32,64,640\]\S* custom-call\(", entry)) \
@@ -862,6 +887,11 @@ def test_window_decode_step_compiles_for_v5e(chip, trinity_engine,
         == 8
     assert len(re.findall(r"= bf16\[655360,512\]\S* custom-call\(", entry)) \
         == 2
+    # 32 tokens of 8 picks over 128 experts leave 12.7 % of them
+    # unpicked: each of the four expert layers streams its hit experts
+    # alone through one kernel (ISSUE 54)
+    assert len(re.findall(r"= f32\[32,2048\]\S* custom-call\(", entry)) == 4
+    assert "[32,128,1024]" not in text
     plane = 4128 * 16 * 512
     assert not [line for opcode, count, _a, line in _hlo_ops(text).values()
                 if opcode in ("copy", "transpose", "gather")
@@ -1102,16 +1132,30 @@ DENSE_WAY_AT_THE_PARENT = {
 }
 
 
+@pytest.mark.parametrize("on_chip", [False, True], ids=["refer", "chip"])
 @pytest.mark.parametrize("case", sorted(DENSE_WAY_AT_THE_PARENT))
-def test_the_dense_way_lowers_as_at_the_parent(chip, case):
+def test_the_dense_way_lowers_as_at_the_parent(chip, case, on_chip,
+                                               monkeypatch):
     """Up to ``DENSE_MAX_TOKENS`` tokens the op lowers op for op as
     before the grouped way changed: a step of Granite's and of GLM-5's
     view, Solar's largest prefill — the text's hash without source
-    locations, as recorded in the parent's checkout by this function."""
+    locations, as recorded in the parent's checkout by this function.
+    On a chip (steered) as off it, but for GLM-5's step there: 32 tokens
+    of 8 picks over 256 experts engage the kernel that streams the hit
+    experts alone (ISSUE 54, ``expert_ffn.dense_tier``), Granite's and
+    Solar's shapes do not."""
     from paddle_tpu.ops import expert_ffn
+    from paddle_tpu.ops import pallas as pk
+    monkeypatch.setattr(pk, "on_tpu", lambda: on_chip)
     args, want = DENSE_WAY_AT_THE_PARENT[case]
     assert args[0] <= expert_ffn.DENSE_MAX_TOKENS == 512
-    assert _scrubbed_sha(_expert_layer(chip, *args).as_text()) == want
+    text = _expert_layer(chip, *args).as_text()
+    if on_chip and case == "glm5_step":
+        assert text.count("tpu_custom_call") == 1
+        assert _scrubbed_sha(text) != want
+    else:
+        assert "tpu_custom_call" not in text
+        assert _scrubbed_sha(text) == want
 
 
 def test_the_grouped_way_holds_no_worst_case_buffer_on_v5e(chip):
