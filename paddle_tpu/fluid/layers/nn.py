@@ -664,19 +664,26 @@ def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
     were); ``gqa = {"n_kv_head", "head_dim", "gate"}`` (and where the
     layer has them ``qk_norm`` with ``rms_eps``, ``rope_theta``,
     ``window``, ``attn_scale``: what multiplies the scores in place of
-    ``head_dim ** -0.5``) declares a grouped-KV layer in x's dtype — Wq / Wg
-    [M, H*D], Wk / Wv [M, n_kv*D], Wo [H*D, M], names ``<base>.wq`` ...
-    ``.wg`` — and sets the attrs the op reads them by."""
+    ``head_dim ** -0.5``; ``v_head_dim``: a value head of another size
+    than a key head; ``rotary_dim``: the leading share of a head the
+    rotation turns; ``value_scale``: what multiplies V; ``sink``: a
+    learned float32 logit a query head, ``.sink`` [H], in a window
+    layer's softmax) declares a grouped-KV layer in x's dtype — Wq
+    [M, H*D], Wk [M, n_kv*D], Wv [M, n_kv*Dv], Wg [M, H*Dv], Wo
+    [H*Dv, M], names ``<base>.wq`` ... ``.wg`` — and sets the attrs the
+    op reads them by."""
     if gqa is None:
         return _attention_projection_params(helper, d_model, param_attr), {}
     import copy
     n_kv, d = int(gqa["n_kv_head"]), int(gqa["head_dim"])
+    dv = int(gqa.get("v_head_dim") or d)
     attrs.update(n_kv_head=n_kv, head_dim=d)
-    wide, narrow = int(n_head) * d, n_kv * d
-    shapes = {"wq": [d_model, wide], "wk": [d_model, narrow],
-              "wv": [d_model, narrow], "wo": [wide, d_model]}
+    if dv != d:
+        attrs["v_head_dim"] = dv
+    shapes = {"wq": [d_model, int(n_head) * d], "wk": [d_model, n_kv * d],
+              "wv": [d_model, n_kv * dv], "wo": [int(n_head) * dv, d_model]}
     if gqa.get("gate"):
-        shapes["wg"] = [d_model, wide]
+        shapes["wg"] = [d_model, int(n_head) * dv]
     ws = {}
     for tag, shape in shapes.items():
         a = copy.deepcopy(param_attr)
@@ -695,10 +702,19 @@ def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
                 dtype=x.dtype, default_initializer=ConstantInitializer(1.0))]
     if gqa.get("rope_theta"):
         attrs["rope_theta"] = float(gqa["rope_theta"])
+        if gqa.get("rotary_dim") and int(gqa["rotary_dim"]) != d:
+            attrs["rotary_dim"] = int(gqa["rotary_dim"])
     if gqa.get("window"):
         attrs["window"] = int(gqa["window"])
     if gqa.get("attn_scale"):
         attrs["attn_scale"] = float(gqa["attn_scale"])
+    if gqa.get("value_scale"):
+        attrs["value_scale"] = float(gqa["value_scale"])
+    if gqa.get("sink"):
+        a = copy.deepcopy(param_attr)
+        a.name = f"{a.name}.sink"
+        extra["Sink"] = [helper.create_parameter(
+            a, shape=[int(n_head)], dtype="float32")]
     return [ws[t] for t in ("wq", "wk", "wv", "wo")], extra
 
 

@@ -210,6 +210,14 @@ _HYBRID_KEYS = {
     # rotary positions on the "gqa" layers themselves (rotate-half, all
     # of a head's dimensions, this base; None: no positions)
     "gqa_rope_theta": None,
+    # a geometry that differs by kind: the "swa" layers' own KV heads
+    # (None: n_kv_head), a value head of another size than a key head
+    # (both kinds; None: head_dim), the leading share of a head that the
+    # rotation turns (both kinds; None: all of it), what multiplies V
+    # before it is cached, and a learned logit a query head that joins a
+    # window layer's softmax and carries no value
+    "swa_n_kv_head": None, "gqa_v_head_dim": None, "rotary_dim": None,
+    "value_scale": None, "swa_sink": False,
     # what multiplies the attention scores ("gqa" layers) in place of
     # head_dim ** -0.5
     "attn_scale": None,
@@ -263,10 +271,11 @@ _INDEXER_KEYS = ("index_n_heads", "index_head_dim", "index_topk")
 
 
 # sizes whose None is a value (the op's own default), not an omission
-_OPTIONAL = ("attn_scale", "d_shared", "gqa_rope_theta")
+_OPTIONAL = ("attn_scale", "d_shared", "gqa_rope_theta", "swa_n_kv_head",
+             "gqa_v_head_dim", "rotary_dim", "value_scale")
 
 
-def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
+def hybrid_arch(arch: dict, mode: str, n_layer: int, n_head=None) -> dict:
     """The hybrid block's sizes, checked: every key of ``_HYBRID_KEYS``,
     the ones whose default is None required (a kind's own only where
     ``layer_kinds`` has the kind). ``kinds`` is the layer kind of each
@@ -320,6 +329,7 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
     if hy["attn_scale"] is not None and "swa" in period:
         raise ValueError("attn_scale is read by 'gqa' layers alone: a "
                          "window layer scales by head_dim ** -0.5")
+    _check_grouped_geometry(hy, period, n_head)
     if not 0 < hy["n_experts_held"] <= hy["n_routed_experts"] \
             - hy["held_start"]:
         raise ValueError("n_experts_held must lie inside the router's "
@@ -328,15 +338,48 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int) -> dict:
     return hy
 
 
+def _check_grouped_geometry(hy, period, n_head):
+    """What the grouped kinds' per-kind geometry has to satisfy."""
+    grouped = {"gqa", "swa"} & set(period)
+    set_keys = sorted(k for k in ("swa_n_kv_head", "gqa_v_head_dim",
+                                  "rotary_dim", "value_scale")
+                      if hy[k] is not None)
+    if set_keys and not grouped:
+        raise ValueError(f"{set_keys} are read by 'gqa' and 'swa' layers "
+                         f"alone; layer_kinds {period} has neither")
+    if (hy["swa_n_kv_head"] is not None or hy["swa_sink"]) \
+            and "swa" not in period:
+        raise ValueError("swa_n_kv_head and swa_sink are a window layer's: "
+                         f"layer_kinds {period} has no 'swa'")
+    if not grouped:
+        return
+    rot = hy["rotary_dim"]
+    if rot is not None:
+        if rot % 2 or not 0 < rot <= hy["head_dim"]:
+            raise ValueError(f"rotary_dim {rot}: the rotated share of a "
+                             f"head is even and at most head_dim "
+                             f"{hy['head_dim']}")
+        if not (hy["gqa_rope_theta"] or "swa" in period):
+            raise ValueError("rotary_dim without a rotation: no 'swa' "
+                             "layer and no gqa_rope_theta")
+    if n_head is not None:
+        for kind, key in (("gqa", "n_kv_head"), ("swa", "swa_n_kv_head")):
+            n_kv = hy[key] if hy[key] is not None else hy["n_kv_head"]
+            if kind in period and (n_kv < 1 or n_head % n_kv):
+                raise ValueError(f"{key} {n_kv} does not divide n_head "
+                                 f"{n_head} ('{kind}' layers)")
+
+
 def hybrid_weight_std(name: str, shape) -> float:
     """The standard deviation a hybrid block's weight matrix is drawn
     with (the program's start-up and the benchmark's drawer use this one
     rule): 1 for the embedding (nothing scales it and an RMSNorm follows),
     taps**-0.5 for the depthwise conv (its output keeps its input's
     variance), 0.1 for a conv's bias, 0.01 for a router's correction bias (small beside the
-    scores' spread, so that picking and weighing differ), Glorot's sqrt(2 / (fan_in + fan_out)) over the last two
+    scores' spread, so that picking and weighing differ), 1 for a window layer's sink logits (a trained one is O(1)
+    beside scores of O(1)), Glorot's sqrt(2 / (fan_in + fan_out)) over the last two
     dimensions otherwise."""
-    if name.endswith("_emb"):
+    if name.endswith("_emb") or name.endswith(".sink"):
         return 1.0
     if name.endswith(".conv"):
         return float(shape[0]) ** -0.5
@@ -442,22 +485,30 @@ def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
         # another table: serving/kv_pool.py "Window group")
         swa = kind == "swa"
         d = hy["head_dim"]
+        # a kind's own KV heads; K rows of n_kv * d, V rows of n_kv * dv
+        n_kv = hy["swa_n_kv_head"] if swa and hy["swa_n_kv_head"] \
+            else hy["n_kv_head"]
+        dv = hy["gqa_v_head_dim"] or d
         plane = "page_w" if swa else "page_"
         shape = [pools["window_pages"] if swa else pools["shape"][0],
                  pools["shape"][1]]
-        pshape = shape + [hy["n_kv_head"] * d]
-        pk = pool_var(f"{name}_{plane}k_{i}", pshape, pools["dtype"])
-        pv = pool_var(f"{name}_{plane}v_{i}", pshape, pools["dtype"])
+        pk = pool_var(f"{name}_{plane}k_{i}", shape + [n_kv * d],
+                      pools["dtype"])
+        pv = pool_var(f"{name}_{plane}v_{i}", shape + [n_kv * dv],
+                      pools["dtype"])
         pks = pvs = None
         if pools["codec"] == "int8":
-            sshape = shape + [hy["n_kv_head"]]
+            sshape = shape + [n_kv]
             pks = pool_var(f"{name}_{plane}ks_{i}", sshape)
             pvs = pool_var(f"{name}_{plane}vs_{i}", sshape)
-        gqa = dict(n_kv_head=hy["n_kv_head"], head_dim=d,
+        gqa = dict(n_kv_head=n_kv, head_dim=d, v_head_dim=dv,
                    gate=hy["gqa_gate"], qk_norm=hy["qk_norm"],
-                   rms_eps=eps, attn_scale=hy["attn_scale"])
+                   rms_eps=eps, attn_scale=hy["attn_scale"],
+                   rotary_dim=hy["rotary_dim"],
+                   value_scale=hy["value_scale"])
         if swa:
             gqa.update(window=hy["window"], rope_theta=hy["rope_theta"],
+                       sink=hy["swa_sink"],
                        attended_name=f"{name}_l{i}_attn_attended")
         elif hy["gqa_rope_theta"]:
             gqa.update(rope_theta=hy["gqa_rope_theta"])
@@ -664,7 +715,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     layer of which this program holds a share (a dense SwiGLU layer of
     ``d_inner`` in the first ``first_k_dense`` layers). Without it the
     views are the multi-head ReLU family's, unchanged."""
-    hy = hybrid_arch(arch, mode, n_layer) if arch else None
+    hy = hybrid_arch(arch, mode, n_layer, n_head) if arch else None
     # all geometry validation + defaulting lives in ONE record shared
     # with the cross-view family verifier (analysis/contracts.py) —
     # the view consumes the normalized constants instead of re-deriving

@@ -265,7 +265,9 @@ def _preregister_catalog():
                 # the model-server families (paddle_serving_*): request
                 # latency/outcomes, queue depth, batch occupancy, the
                 # zero-steady-state compile counter, and the predictor's
-                # AOT-fallback counter — import-light (docs/serving.md)
+                # AOT-fallback counter — import-light (docs/serving.md);
+                # the page pool's too, the full layers' rows attended
+                # and gathered and paddle_kv_row_bytes{group} among them
                 "paddle_tpu.serving.metrics",
                 # sharded embedding tables: hot-rows cache hit/miss/
                 # eviction/occupancy and per-shard wire bytes

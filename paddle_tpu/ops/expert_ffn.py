@@ -136,10 +136,27 @@ def route(x, router_w, top_k: int, norm_topk: bool, scaling: float,
     return vals * scaling, idx
 
 
+# a call whose hidden activations [N, F] hold more values than this runs
+# in blocks of ``SWIGLU_ROW_BLOCK`` rows: a 32768-token prefill of a dense
+# layer 16384 wide keeps 2.1 GB of float32 beside 1.1 GB of bfloat16
+# otherwise, and does not fit beside the weights and the pages (PR 56).
+# Every call of the configurations served before is under it (the
+# largest: 16384 rows x 6144) and lowers what it did
+SWIGLU_WHOLE_MAX = 1 << 28
+SWIGLU_ROW_BLOCK = 4096
+
+
 def swiglu(x, w_gate, w_up, w_down):
     """W_down (SiLU(W_gate x) * W_up x) for x [N, M] -> [N, M] float32."""
-    hidden = jax.nn.silu(dense(x, w_gate)) * dense(x, w_up)
-    return dense(hidden.astype(x.dtype), w_down)
+    def whole(rows):
+        hidden = jax.nn.silu(dense(rows, w_gate)) * dense(rows, w_up)
+        return dense(hidden.astype(x.dtype), w_down)
+
+    n, blk = x.shape[0], SWIGLU_ROW_BLOCK
+    if n * w_gate.shape[1] > SWIGLU_WHOLE_MAX and n % blk == 0:
+        return jax.lax.map(whole, x.reshape(n // blk, blk, -1)
+                           ).reshape(n, -1)
+    return whole(x)
 
 
 # at or under this many tokens a call takes the dense way (module
@@ -276,6 +293,14 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
                         rows), sizes
 
 
+# the grouped way's combine is written out pick by pick while a token
+# result [N, M] holds at most this many values; a longer prefill's loops
+# over its picks (8 gathered float32 results of a 32768-token prefill at
+# a width of 4096 are 4 GB at once otherwise; PR 56). Every prefill of the
+# configurations served before is under it (the largest: 16384 x 2048)
+COMBINE_UNROLLED_MAX = 1 << 26
+
+
 def _plan(key, sizes, rows):
     """The grouped way's bookkeeping, from each assignment's ``key``
     [N, K] (its held expert, or E_held for one held elsewhere) and the
@@ -341,6 +366,19 @@ def _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes, rows):
             # weighed
             here = held & (at >= lo) & (at < lo + rows)
             row = jnp.clip(at - lo, 0, rows - 1)
+            if n * x.shape[1] > COMBINE_UNROLLED_MAX:
+                # a pick at a time: written out, the compiler keeps all
+                # k gathered [N, M] float32 results at once
+                def add_pick(j, acc):
+                    col = lambda z: jax.lax.dynamic_index_in_dim(  # noqa
+                        z, j, axis=1, keepdims=False)
+                    return acc + jnp.where(
+                        col(here)[:, None],
+                        jnp.take(ys, col(row), axis=0, mode="clip")
+                        * col(combine)[:, None], 0.0)
+                # (a single turn starts from the scalar 0.0)
+                return jax.lax.fori_loop(
+                    0, k, add_pick, y + jnp.zeros((n, x.shape[1]), F32))
             for j in range(k):
                 y = y + jnp.where(
                     here[:, j, None],

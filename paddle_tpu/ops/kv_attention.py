@@ -105,15 +105,30 @@ GQA_PREFILL_ATTEND_LOWERED = _metrics.counter(
     labelnames=("path",))
 
 
-def _scores_to_probs(s, mask, dt):
+def _scores_to_probs(s, mask, dt, sink=None):
     """fp32 scaled+masked scores -> storage-dtype probabilities, the
-    shared softmax spelling (mirrors attention_block._fwd_impl)."""
+    shared softmax spelling (mirrors attention_block._fwd_impl). With
+    ``sink`` (float32, one logit a query head, broadcastable against
+    ``s`` with a last axis of 1) the softmax has one more column that
+    carries no value: the maximum is taken over the scores AND the
+    sink, and exp(sink - m) joins the denominator alone."""
     s = jnp.where(mask, s, _ab._NEG)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        p = _softmax_beside_sink(s, jnp.max(s, axis=-1, keepdims=True), sink)
     return p.astype(dt)
 
 
-def _decode_contract(q, k, v, valid, dt, n_kv=None, scale=None):
+def _softmax_beside_sink(s, row_max, sink):
+    """softmax of s over its last axis with one more column, ``sink``,
+    that carries no value: its share of the denominator alone."""
+    m = jnp.maximum(row_max, sink)
+    e = jnp.exp(s - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def _decode_contract(q, k, v, valid, dt, n_kv=None, scale=None, sink=None):
     """The decode-side attention contraction, over a cache that keeps
     the model width on its minor dimension: q [B, K1, H, Dk] (K1 query
     rows per batch row: 1 for a decode step, the window for a verify),
@@ -123,7 +138,11 @@ def _decode_contract(q, k, v, valid, dt, n_kv=None, scale=None):
     cache is [B, S, n_kv * Dk] and row (k1, h) of the block-diagonal
     query holds q's head h in the lanes of KV head h // (H / n_kv).
     ``scale`` multiplies the scores (Dk ** -0.5 when None: a latent
-    cache's rows are wider than the head the model scales by).
+    cache's rows are wider than the head the model scales by). The
+    value plane may keep heads of another size: v [B, S, n_kv * Dv]
+    gives a context [B, K1, H, Dv] (the block-diagonal query spans the
+    K lanes alone). ``sink`` [H] float32: a logit a query head that
+    joins the softmax's denominator (:func:`_scores_to_probs`).
 
     The cache is never reshaped to [.., H, Dk]: a 64-wide head is half
     a lane tile, and a per-head contraction of one query row made the
@@ -157,25 +176,36 @@ def _decode_contract(q, k, v, valid, dt, n_kv=None, scale=None):
                             preferred_element_type=jnp.float32)
     s = s.astype(jnp.float32).reshape(b, k1, h, -1) \
         * (float(d) ** -0.5 if scale is None else scale)
-    p = _scores_to_probs(s, valid[:, :, None, :], dt)    # [B,K1,H,S]
+    p = _scores_to_probs(
+        s, valid[:, :, None, :], dt,
+        None if sink is None else sink.reshape(1, 1, h, 1))  # [B,K1,H,S]
     c = jax.lax.dot_general(p.reshape(b, k1 * h, -1), v,
                             (((2,), (1,)), ((0,), (0,))),
                             precision=prec,
                             preferred_element_type=jnp.float32)
     # row (k1, h) keeps head h's own lanes: the sum adds exact zeros
-    c = jnp.where(own, c.reshape(b, k1, h, n_kv, d), 0).sum(axis=3)
+    c = jnp.where(own, c.reshape(b, k1, h, n_kv, v.shape[2] // n_kv),
+                  0).sum(axis=3)
     return c.astype(dt)
 
 
 def _gqa(attrs):
-    """(H, n_kv, Dk) of a grouped-KV, output-gated attention layer, or
-    None: the attrs ``n_kv_head`` / ``head_dim`` are set only by a model
-    that has such layers, so the programs of one that has not carry
-    neither (and stay what they were)."""
+    """(H, n_kv, Dk, Dv) of a grouped-KV, output-gated attention layer,
+    or None: the attrs ``n_kv_head`` / ``head_dim`` are set only by a
+    model that has such layers, so the programs of one that has not
+    carry neither (and stay what they were). ``v_head_dim`` is set only
+    where a value head is of another size than a key head."""
     if "n_kv_head" not in attrs:
         return None
-    return (int(attrs["n_head"]), int(attrs["n_kv_head"]),
-            int(attrs["head_dim"]))
+    d = int(attrs["head_dim"])
+    return (int(attrs["n_head"]), int(attrs["n_kv_head"]), d,
+            int(attrs.get("v_head_dim", d)))
+
+
+def _sink(ins):
+    """A window layer's learned sink logits [H] in float32, or None."""
+    sink = first(ins, "Sink")
+    return None if sink is None else sink.astype(jnp.float32)
 
 
 def _gqa_heads(x, w, heads, d):
@@ -192,10 +222,16 @@ def _gqa_output(x, c, wg, wo):
     return dense(c, wo, x.dtype)
 
 
-def rope_half(x, pos, theta: float):
+def rope_half(x, pos, theta: float, rotary=None):
     """x [B, T, heads, D] float32 rotated at positions pos [B, T], the
     rotate-half convention: (x[i], x[i + D/2]) turns by pos *
-    theta^(-2i/D) — all D dimensions, no scaling."""
+    theta^(-2i/D) — all D dimensions, no scaling. With ``rotary`` < D
+    only the first ``rotary`` values of a head turn (rotate-half INSIDE
+    them: x[i] pairs with x[i + rotary/2]); the others pass."""
+    if rotary is not None and int(rotary) != x.shape[-1]:
+        r = int(rotary)
+        return jnp.concatenate(
+            [rope_half(x[..., :r], pos, theta), x[..., r:]], axis=-1)
     d = x.shape[-1]
     inv = float(theta) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[:, :, None, None] * inv    # [B,T,1,D/2]
@@ -210,9 +246,22 @@ def _rms(x, scale, eps):
     return x * inv * scale.astype(jnp.float32)
 
 
+# the float32 heads [T, heads, D] of a projection that is normalised or
+# rotated hold at most this many values at once: a longer prompt's are
+# made ``GQA_PROJECT_ROW_BLOCK`` rows at a time (64 heads of 192 over
+# 32768 rows are 1.6 GB in float32 beside 0.8 in bfloat16; PR 56). Every
+# projection of the configurations served before is under it (the
+# largest: 16384 rows x 32 heads of 128) and lowers what it did
+GQA_PROJECT_WHOLE_MAX = 1 << 28
+GQA_PROJECT_ROW_BLOCK = 4096
+
+
 def _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa, positions, grouped=False):
-    """(q [B,T,H,D], k, v [B,T,n_kv,D]) of a grouped-KV layer in x's
-    dtype; with ``grouped`` q is [B,T,n_kv,G,D]. With attr ``qk_norm``
+    """(q [B,T,H,D], k [B,T,n_kv,D], v [B,T,n_kv,Dv]) of a grouped-KV
+    layer in x's dtype; with ``grouped`` q is [B,T,n_kv,G,D]. With attr
+    ``value_scale`` v is that times the projection, and with
+    ``rotary_dim`` the rotation turns a head's first values alone
+    (:func:`rope_half`). With attr ``qk_norm``
     every head of q and k is RMS-normalised over its D values (gains
     ``QNorm`` / ``KNorm`` [D], eps ``rms_eps``) and with attr
     ``rope_theta`` rotated at ``positions()`` [B,T] (the tokens' TRUE
@@ -220,34 +269,57 @@ def _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa, positions, grouped=False):
     normalised, rotated key. Without either the projections are what
     they were, op for op (q grouped before k is projected: a plain
     layer's programs keep their compile-cache keys)."""
-    h, n_kv, d = gqa
+    h, n_kv, d, dv = gqa
     qshape = x.shape[:2] + ((n_kv, h // n_kv, d) if grouped else (h, d))
     qk_norm, theta = bool(attrs.get("qk_norm")), attrs.get("rope_theta")
+
+    def value():
+        if not attrs.get("value_scale"):
+            return _gqa_heads(x, wv, n_kv, dv)
+        # what is cached is the scaled value, rounded once
+        return (dense(x, wv) * float(attrs["value_scale"])).astype(
+            x.dtype).reshape(x.shape[:2] + (n_kv, dv))
+
     if not qk_norm and not theta:
         return (_gqa_heads(x, wq, h, d).reshape(qshape),
-                _gqa_heads(x, wk, n_kv, d), _gqa_heads(x, wv, n_kv, d))
+                _gqa_heads(x, wk, n_kv, d), value())
     eps = float(attrs.get("rms_eps", 1e-5))
+    b, t = x.shape[:2]
     out = []
     for w, heads, gain in ((wq, h, "QNorm"), (wk, n_kv, "KNorm")):
-        y = dense(x, w).reshape(x.shape[:2] + (heads, d))
-        if qk_norm:
-            y = _rms(y, first(ins, gain), eps)
-        if theta:
-            y = rope_half(y, positions(), theta)
-        out.append(y.astype(x.dtype))
-    return out[0].reshape(qshape), out[1], _gqa_heads(x, wv, n_kv, d)
+        def rows(xr, pos, w=w, heads=heads, gain=gain):
+            y = dense(xr, w).reshape(xr.shape[:2] + (heads, d))
+            if qk_norm:
+                y = _rms(y, first(ins, gain), eps)
+            if theta:
+                y = rope_half(y, pos(), theta, attrs.get("rotary_dim"))
+            return y.astype(x.dtype)
+
+        blk = GQA_PROJECT_ROW_BLOCK
+        if t * heads * d <= GQA_PROJECT_WHOLE_MAX or t % blk:
+            out.append(rows(x, positions))
+            continue
+        # the float32 heads of a long prompt a block of rows at a time
+        split = lambda z: jnp.swapaxes(                        # noqa: E731
+            z.reshape((b, t // blk, blk) + z.shape[2:]), 0, 1)
+        y = jax.lax.map(lambda a: rows(a[0], lambda: a[1]),
+                        (split(x), split(positions())))
+        out.append(jnp.swapaxes(y, 0, 1).reshape(b, t, heads, d))
+    return out[0].reshape(qshape), out[1], value()
 
 
-def _softmax_rows(s):
+def _softmax_rows(s, sink=None):
     """softmax over the last axis with the row maximum behind an
     optimization barrier: fused with the subtraction, XLA's TPU
     pipeline turned ``max`` over 8192 keys into a ``reduce-window`` of
     16383 taps for EVERY score — 47 ms a block of 256 queries, 7.5 of a
     prefill's 8.2 s (PERF.md, PR 33). Every row has its own key, so no
-    row is all -inf."""
+    row is all -inf. ``sink``: as in :func:`_scores_to_probs`."""
     m = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
-    e = jnp.exp(s - m)
-    return e / jnp.sum(e, axis=-1, keepdims=True)
+    if sink is None:
+        e = jnp.exp(s - m)
+        return e / jnp.sum(e, axis=-1, keepdims=True)
+    return _softmax_beside_sink(s, m, sink)
 
 
 # queries a block of a long grouped-KV prefill: a prompt bucket longer
@@ -264,9 +336,9 @@ def _attended(keep, first_col=0):
                       jnp.sum(keep, axis=-1)], axis=-1).astype(jnp.int32)
 
 
-def _gqa_attend_tier(t, d, window, mesh=None):
+def _gqa_attend_tier(t, d, window, mesh=None, dv=None):
     """Which implementation attends a grouped-KV prefill of ``t`` rows
-    at heads of ``d``, decided from what is being lowered and never
+    at heads of ``d`` (values of ``dv``: ``d`` when None), decided from what is being lowered and never
     from a flag (as ``_gather_tier``): ``("whole", None)`` for a bucket
     of at most ``GQA_QUERY_BLOCK`` rows (one square of scores),
     ``("flash", (bq, bk))`` for a longer bucket of a FULL layer where
@@ -280,36 +352,42 @@ def _gqa_attend_tier(t, d, window, mesh=None):
         return "whole", None
     if window is None:
         from paddle_tpu.ops import pallas as _plk
-        blocks = _plk.causal_blocks(t, d, d)
+        dv = d if dv is None else dv
+        blocks = _plk.causal_blocks(t, d, dv)
         if None not in blocks and (
-                _plk.kernel_enabled(64, t, d, mesh=mesh)
+                _plk.kernel_enabled(64, t, d, dv, mesh=mesh)
                 or _plk.forced_interpret()):
             return "flash", blocks
     return "blocked", None
 
 
-def _gqa_attend(q, k, v, window=None, scale=None, mesh=None):
-    """Causal attention of q [B,T,n_kv,G,D] over k, v [B,T,n_kv,D] ->
-    ([B,T,n_kv,G,D] in q's dtype, with a window what each query
-    attends [T, 2]: ``_attended``). With ``window`` query t sees the
-    keys s with 0 <= t - s < window: a band, and a block of queries
-    reads only the keys its band can reach. ``scale`` multiplies the
-    scores (D ** -0.5 when None). One algorithm, the implementation
+def _gqa_attend(q, k, v, window=None, scale=None, mesh=None, sink=None):
+    """Causal attention of q [B,T,n_kv,G,D] over k [B,T,n_kv,D] and v
+    [B,T,n_kv,Dv] -> ([B,T,n_kv,G,Dv] in q's dtype, with a window what
+    each query attends [T, 2]: ``_attended``). With ``window`` query t
+    sees the keys s with 0 <= t - s < window: a band, and a block of
+    queries reads only the keys its band can reach. ``scale`` multiplies
+    the scores (D ** -0.5 when None). ``sink`` [n_kv * G] float32 (a
+    window layer's alone): one more column of every softmax, with no
+    value. One algorithm, the implementation
     chosen by shape (:func:`_gqa_attend_tier`, under ``mesh``): float32
     scores, maximum, denominator and accumulator, the probabilities in
     q's dtype for ``p . V``, whichever runs."""
     b, t, n_kv, g, d = q.shape
     dt = q.dtype
     scale = float(d) ** -0.5 if scale is None else float(scale)
-    tier, blocks = _gqa_attend_tier(t, d, window, mesh)
+    dv = v.shape[-1]
+    tier, blocks = _gqa_attend_tier(t, d, window, mesh, dv)
     GQA_PREFILL_ATTEND_LOWERED.labels(path=tier).inc()
+    if sink is not None:
+        sink = sink.reshape(1, n_kv, g, 1, 1)                  # "bkgts"
     if tier == "whole":
         s = jnp.einsum("btkgd,bskd->bkgts", q, k,
                        preferred_element_type=jnp.float32) * scale
         keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
         if window is not None:
             keep &= jnp.arange(t)[:, None] - jnp.arange(t)[None, :] < window
-        p = _scores_to_probs(s, keep, dt)
+        p = _scores_to_probs(s, keep, dt, sink)
         c = jnp.einsum("bkgts,bskd->btkgd", p, v,
                        preferred_element_type=jnp.float32).astype(dt)
         return c, (None if window is None else _attended(keep))
@@ -322,7 +400,7 @@ def _gqa_attend(q, k, v, window=None, scale=None, mesh=None):
             heads_first(q.reshape(b, t, n_kv * g, d)), heads_first(k),
             heads_first(v), True, scale, blocks[0], blocks[1],
             _plk.interpret_mode())
-        return heads_first(o).reshape(q.shape), None
+        return heads_first(o).reshape(b, t, n_kv, g, dv), None
     blk = GQA_QUERY_BLOCK
     if t % blk:
         raise ValueError(f"a prompt bucket of {t} is not a whole number "
@@ -341,13 +419,13 @@ def _gqa_attend(q, k, v, window=None, scale=None, mesh=None):
         s = jnp.einsum("btkgd,bskd->bkgts", cut(q, t0, blk),
                        cut(k, k0, span),
                        preferred_element_type=jnp.float32) * scale
-        p = _softmax_rows(jnp.where(keep, s, -jnp.inf)).astype(dt)
+        p = _softmax_rows(jnp.where(keep, s, -jnp.inf), sink).astype(dt)
         c = jnp.einsum("bkgts,bskd->btkgd", p, cut(v, k0, span),
                        preferred_element_type=jnp.float32).astype(dt)
         return c, _attended(keep, k0)
 
     o, seen = jax.lax.map(block, jnp.arange(0, t, blk))  # [T/blk,B,blk,..]
-    return (jnp.moveaxis(o, 0, 1).reshape(q.shape),
+    return (jnp.moveaxis(o, 0, 1).reshape(b, t, n_kv, g, dv),
             None if window is None else seen.reshape(t, 2))
 
 
@@ -358,15 +436,15 @@ def _gqa_causal_prefill(x, wq, wk, wv, wo, wg, ins, attrs, gqa, mesh=None):
     positions the mask alone orders it; a layer's attrs may give its
     heads a norm and rotary positions (:func:`_gqa_qkv`: a prompt's
     rows ARE its positions) and its queries a ``window``."""
-    h, n_kv, d = gqa
+    h, _, _, dv = gqa
     b, t, _ = x.shape
     q, k, v = _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa,
                        lambda: jnp.broadcast_to(jnp.arange(t), (b, t)),
                        grouped=True)
     window = attrs.get("window")
     c, seen = _gqa_attend(q, k, v, int(window) if window else None,
-                          attrs.get("attn_scale"), mesh)
-    out = _gqa_output(x, c.reshape(b, t, h * d), wg, wo)
+                          attrs.get("attn_scale"), mesh, _sink(ins))
+    out = _gqa_output(x, c.reshape(b, t, h * dv), wg, wo)
     return out, k.reshape(b, t, -1), v.reshape(b, t, -1), seen
 
 
@@ -479,7 +557,7 @@ def _paged_pools(ins, codec):
     n_pages, ps, m = (int(d) for d in page_k.shape)
     rtot = n_pages * ps
     flat_k = page_k.reshape(rtot, m)
-    flat_v = page_v.reshape(rtot, m)
+    flat_v = page_v.reshape(rtot, int(page_v.shape[2]))  # n_kv * Dv
     fks = fvs = None
     if codec == "int8":
         fks = first(ins, "PageKS").reshape(rtot, -1)
@@ -556,9 +634,10 @@ def _kv_attention_prefill_paged(ctx, ins, attrs):
                 ctx.mesh)
     else:
         out, k, v = _causal_prefill(x, wq, wk, wv, wo, h)
-    m = flat_k.shape[1]
-    flat_k, fks = _paged_write(flat_k, fks, rows, k.reshape(-1, m))
-    flat_v, fvs = _paged_write(flat_v, fvs, rows, v.reshape(-1, m))
+    flat_k, fks = _paged_write(flat_k, fks, rows,
+                               k.reshape(-1, flat_k.shape[1]))
+    flat_v, fvs = _paged_write(flat_v, fvs, rows,
+                               v.reshape(-1, flat_v.shape[1]))
     return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages, ps, seen)
 
 
@@ -571,7 +650,7 @@ def window_ring(window: int, page_size: int) -> int:
 
 
 def _window_decode(q, k_t, v_t, table, true_pos, active, window, n_kv,
-                   mesh, pools):
+                   mesh, pools, sink=None):
     """A window layer's decode step over its group's pools: ``table``
     [B, ring] is the slot's RING of pages, entry ``e`` holding the
     logical page ``lp`` of true positions with ``lp % ring == e``
@@ -586,7 +665,7 @@ def _window_decode(q, k_t, v_t, table, true_pos, active, window, n_kv,
     ``_attended``, the pools)."""
     flat_k, flat_v, fks, fvs, ps, rtot = pools
     b, ring = table.shape
-    dt, mk = q.dtype, flat_k.shape[1]
+    dt, mk, mv = q.dtype, flat_k.shape[1], flat_v.shape[1]
     phase = functools.partial(_device_scopes.phase,
                               "kv_attention_decode_paged/window")
     cur = true_pos // ps
@@ -595,7 +674,7 @@ def _window_decode(q, k_t, v_t, table, true_pos, active, window, n_kv,
                                     axis=1)[:, 0]
         wrow = jnp.where(active, wpage * ps + true_pos % ps, rtot)
         flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, mk))
-        flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mk))
+        flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mv))
     with phase("gather"):
         kk = _paged_gather(flat_k, fks, table, ps, dt, mesh)
         vv = _paged_gather(flat_v, fvs, table, ps, dt, mesh)
@@ -607,7 +686,8 @@ def _window_decode(q, k_t, v_t, table, true_pos, active, window, n_kv,
              ).reshape(b, ring * ps)
         ahead = true_pos[:, None] - j
         valid = (j >= 0) & (ahead >= 0) & (ahead < window)
-        c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv)
+        c = _decode_contract(q, kk, vv, valid[:, None], dt, n_kv,
+                             sink=sink)
         seen = jnp.stack(
             [jnp.min(jnp.where(valid, j, jnp.iinfo(jnp.int32).max), -1),
              jnp.sum(valid, -1)], axis=-1).astype(jnp.int32)
@@ -656,10 +736,10 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
     gqa = _gqa(attrs)
     n_kv = None
     if gqa is not None:
-        h, n_kv, d = gqa
+        h, n_kv = gqa[:2]
         # each token's TRUE position: generated rows start at the bucket
         true_pos = lambda: lens + pos - gen0                 # noqa: E731
-        # q [B,1,H,D], k_t / v_t [B,1,n_kv,D]
+        # q [B,1,H,D], k_t [B,1,n_kv,D], v_t [B,1,n_kv,Dv]
         q, k_t, v_t = _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa,
                                lambda: true_pos()[:, None])
         if attrs.get("window"):
@@ -668,7 +748,7 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
                 c, seen, flat_k, flat_v, fks, fvs = _window_decode(
                     q, k_t, v_t, table, true_pos(), active,
                     int(attrs["window"]), n_kv, ctx.mesh,
-                    (flat_k, flat_v, fks, fvs, ps, rtot))
+                    (flat_k, flat_v, fks, fvs, ps, rtot), _sink(ins))
             out = _gqa_output(x, c.reshape(b, 1, -1), first(ins, "Wg"), wo)
             return _paged_result(out, flat_k, flat_v, fks, fvs, n_pages,
                                  ps, seen)
@@ -676,7 +756,7 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
         q = _ab._proj(x, wq, h)                     # [B,1,H,D]
         k_t = _ab._proj(x, wk, h)
         v_t = _ab._proj(x, wv, h)
-    mk = flat_k.shape[1]                        # the pool's row width
+    mk, mv = flat_k.shape[1], flat_v.shape[1]   # the pools' row widths
 
     # this step's write row through the page table, sentinel (dropped)
     # for inactive slots — a free slot's pages are bit-identical before
@@ -688,7 +768,7 @@ def _kv_attention_decode_paged(ctx, ins, attrs):
                                     axis=1)[:, 0]
         wrow = jnp.where(active, wpage * ps + pos % ps, rtot)
         flat_k, fks = _paged_write(flat_k, fks, wrow, k_t.reshape(b, mk))
-        flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mk))
+        flat_v, fvs = _paged_write(flat_v, fvs, wrow, v_t.reshape(b, mv))
 
     # gather every slot's logical cache through its table row
     with phase("gather"):

@@ -797,6 +797,29 @@ class SlotGenerativeModel(GenerativeModel):
         self._pending_rows_w: Optional[np.ndarray] = None
         self._m_window_rows = smetrics.KV_WINDOW_ROWS_ATTENDED.labels(
             model=self.name) if window_vars else None
+        # the full group's softmax layers (a latent plane is not one):
+        # what their decode steps attend and what they gather
+        self._full_layers = sum(
+            1 for n in gvars if re.search(r"_page_k_\d+$", n))
+        self._m_full_rows = {
+            what: family.labels(model=self.name) for what, family in (
+                ("attended", smetrics.KV_FULL_ROWS_ATTENDED),
+                ("gathered", smetrics.KV_FULL_ROWS_GATHERED))}
+        # bytes a position costs in each group: every plane of the
+        # group's layers (K, V, their scales, a latent layer's two) by
+        # its OWN row width (K and V rows, and one group's rows and
+        # another's, need not be as wide)
+        self.row_bytes = {}
+        for group, mark in (("full", "_page_"), ("window", "_page_w")):
+            planes = [v for n, v in gvars.items() if re.search(
+                mark + r"(k|v|ks|vs|c|i)_\d+$", n)]
+            if planes:
+                self.row_bytes[group] = sum(
+                    int(v.shape[2]) * (4 if v.dtype == "float32" else
+                                       1 if v.dtype == "int8" else 2)
+                    for v in planes)
+                smetrics.KV_ROW_BYTES.labels(
+                    model=self.name, group=group).set(self.row_bytes[group])
 
     def _discover_state(self, dec_main, pre_feeds):
         """The second kind of per-slot state (docs/serving.md "Recurrent
@@ -1526,17 +1549,23 @@ class SlotGenerativeModel(GenerativeModel):
         if self._conv_layers:
             self._m_conv_tokens["decode"].inc(
                 len(slots) * self._conv_layers)
+        # each running slot's live rows, before this step's own
+        live = self._seq[slots] + self._gen_count[slots]
         if self._dsa_layers:
-            live = self._seq[slots] + self._gen_count[slots]
             self._m_dsa_scored.inc(int(live.sum()) * self._dsa_layers)
             self._m_dsa_selected.inc(int(np.minimum(
                 live, self._dsa_topk).sum()) * self._dsa_layers)
         if self.window:
-            # what the window layers attend this step: each running
-            # slot's live rows, at most a window's
-            live = self._seq[slots] + self._gen_count[slots]
+            # what the window layers attend this step: at most a window's
             self._m_window_rows.inc(int(np.minimum(
                 live, self.window).sum()) * self._window_layers)
+        if self._full_layers:
+            # a full layer attends a running slot's live rows and
+            # gathers every slot's whole table
+            self._m_full_rows["attended"].inc(
+                int(live.sum()) * self._full_layers)
+            self._m_full_rows["gathered"].inc(
+                self.n_slots * self.cache_len * self._full_layers)
         self._gen_count[slots] += 1
         last = self._gen_count[slots] >= self._budget[slots]
         self._closing[slots[last]] = True

@@ -185,6 +185,25 @@ KV_WINDOW_ROWS_ATTENDED = _metrics.counter(
     "slot and window layer min(the slot's positions, the window), "
     "counted on the host from the slots' own lengths",
     labelnames=("model",))
+KV_FULL_ROWS_ATTENDED = _metrics.counter(
+    "paddle_kv_full_rows_attended_total",
+    "Cache rows the full-attention layers' decode steps attended: per "
+    "step, slot and full layer the slot's LIVE rows (its positions), "
+    "counted on the host from the slots' own lengths",
+    labelnames=("model",))
+KV_FULL_ROWS_GATHERED = _metrics.counter(
+    "paddle_kv_full_rows_gathered_total",
+    "Cache rows the full-attention layers' decode steps GATHERED: per "
+    "step and full layer every row of every slot's page table, live or "
+    "not (attended / gathered is the share of the gather that a step "
+    "used)", labelnames=("model",))
+KV_ROW_BYTES = _metrics.gauge(
+    "paddle_kv_row_bytes",
+    "Bytes one cache position costs in one layer group of the pool, all "
+    "the group's layers and both planes, codec scales included (group: "
+    "full | window): the groups' layers may differ in KV heads and a "
+    "value head in size from a key head, so pages turn into bytes per "
+    "group", labelnames=("model", "group"))
 KV_PREFIX_SHARED_PAGES = _metrics.gauge(
     "paddle_kv_prefix_shared_pages",
     "Pages physically referenced by >= 2 in-flight slots via the "

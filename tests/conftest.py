@@ -84,6 +84,9 @@ STEP_MODULES_SINCE_PR37 = {
     "train_joyai_seq8k_1chip": "jit_block3_s5b8b_x2",
     # PR 51: the digest carries ``shortconv_decode``'s row of the phases
     "serve_lfm2_extract_closed": "jit_lm_decode_paged_se045",
+    # PR 56: the digest carries the window variant's row (no new phase:
+    # the grouped kinds' new geometry is attributes, so Trinity's module)
+    "serve_mimo_decode_deepctx": "jit_lm_decode_paged_s367a",
 }
 
 
@@ -112,23 +115,49 @@ LAST_CELL_OF_PR44 = "serve_granite_sessions_closed"
 # ``tests/chipbench/test_chipbench_host_gaps.py`` checks — and every
 # other module reads the file whole. The same ``benchmark`` PR drops it.
 LAST_METRIC_OF_PR51 = "attn_ms_per_prefill"
+LAST_CELL_OF_PR51 = "serve_lfm2_extract_closed"
+LAST_CONFIG_OF_PR51 = "lfm2_8b_a1b_d12"
 LIST_CUT_BEHIND = {"test_chipbench_moe_grouped": LAST_METRIC_OF_PR44,
                    "test_chipbench_serve_lfm2": LAST_METRIC_OF_PR51}
+# ``tests/chipbench/test_chipbench_serve_trinity.py`` (PR 37) pins its
+# four metrics' ``workloads`` to its one cell, and PR 56's cell, the
+# second with a window group, belongs on ``kv_window_pages_*``'s lists:
+# that module is shown those lists without the cells added behind its
+# own (``tests/chipbench/test_chipbench_serve_mimo.py`` checks that the
+# new cell is on them); the same ``benchmark`` PR drops this too.
+WINDOW_GROUP_LISTS_AS_PINNED = {
+    "test_chipbench_serve_trinity": "serve_trinity_decode_mixedctx"}
 
 
 @pytest.fixture(autouse=True)
 def _per_layer_list_as_the_module_pinned_it(request, monkeypatch):
     module = request.module.__name__.rsplit(".", 1)[-1]
     last = LIST_CUT_BEHIND.get(module)
-    if last is None:
+    own = WINDOW_GROUP_LISTS_AS_PINNED.get(module)
+    if last is None and own is None:
         return
     from chipbench import harness
     real = harness.load_benchmark
 
     def load():
         bench = real()
+        if own is not None:
+            cells = [w["name"] for w in bench["workloads"]]
+            later = set(cells[cells.index(own) + 1:])
+            for m in bench["per_layer"]:
+                if m["name"].startswith("kv_window_pages_"):
+                    m["workloads"] = [w for w in m["workloads"]
+                                      if w not in later]
+            return bench
         names = [m["name"] for m in bench["per_layer"]]
         del bench["per_layer"][names.index(last) + 1:]
+        if last == LAST_METRIC_OF_PR51:
+            # PR 51's module also pins its cell and its configuration
+            # as the last of their lists (PR 56 appended one of each)
+            for group, own_last in (("workloads", LAST_CELL_OF_PR51),
+                                    ("configs", LAST_CONFIG_OF_PR51)):
+                names = [e["name"] for e in bench[group]]
+                del bench[group][names.index(own_last) + 1:]
         if last == LAST_METRIC_OF_PR44:
             cells = [w["name"] for w in bench["workloads"]]
             later = set(cells[cells.index(LAST_CELL_OF_PR44) + 1:])

@@ -922,6 +922,101 @@ def test_window_prefill_compiles_for_v5e(chip, trinity_engine, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# grouped attention with a geometry per layer kind (PR 56): the decode step
+# and the 32768-token prefill of mimo_v2_flash_ep16_d7 at the cell's sizes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def mimo_engine():
+    import json
+    import os
+    from paddle_tpu import serving
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.ops import pallas as pk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "mimo_v2_flash_ep16_d7.json")) as f:
+        cfg = json.load(f)
+    build = cfg["build"]
+    was, pk.on_tpu = pk.on_tpu, lambda: True
+    try:
+        programs = T.build_decoder_lm_programs(
+            name="lm", modes=T.slot_modes(cfg["kv_layout"]),
+            kv_codec=cfg["kv_codec"],
+            **{**build, "prompt_buckets": tuple(build["prompt_buckets"]),
+               "layer_kinds": tuple(build["layer_kinds"])})
+        yield serving.make_slot_model("lm", programs, init=False), programs
+    finally:
+        pk.on_tpu = was
+
+
+def test_grouped_kv_decode_step_compiles_for_v5e(chip, mimo_engine,
+                                                 monkeypatch):
+    """24 slots, bf16, two full layers of 4 KV heads and five window
+    layers of 8, keys of 192 beside values of 128: the step fits one
+    chip beside both page groups, donated and aliased in place. A full
+    layer gathers every slot's table — K rows of 768, V rows of 512 —
+    and a window layer its ring of 9 pages alone (rows of 1536 and
+    1024); no plane is copied or transposed; each of the six expert
+    layers streams its hit experts through one kernel (24 tokens padded
+    to 32 rows)."""
+    eng, programs = mimo_engine
+    assert (eng.window, eng.window_ring, eng.n_window_pages) \
+        == (128, 9, 24 * 9)
+    assert (eng.n_pages, eng.max_pages) == (29184, 2304)
+    assert eng.row_bytes == {"full": 2 * 4 * 320 * 2,
+                             "window": 5 * 8 * 320 * 2}
+    compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
+                             eng._decode_feeds(), monkeypatch)
+    mem = compiled.memory_analysis()
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 9e9 < peak < 13e9
+    pages = (2 * 29184 * 16 * 4 + 5 * 216 * 16 * 8) * 320 * 2
+    assert mem.alias_size_in_bytes >= pages
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    rows, ring = 24 * 2304 * 16, 24 * 9 * 16
+    for shape, count in ((f"{rows},768", 2), (f"{rows},512", 2),
+                         (f"{ring},1536", 5), (f"{ring},1024", 5)):
+        assert len(re.findall(rf"= bf16\[{shape}\]\S* custom-call\(",
+                              entry)) == count, shape
+    assert len(re.findall(r"= f32\[32,4096\]\S* custom-call\(", entry)) == 6
+    # no PLANE is copied (the compiler does re-lay the rotated
+    # projections' weights, wq and wk, every step: PERF.md section 7)
+    plane = 216 * 16 * 1024
+    moved = [line for opcode, count, _a, line in _hlo_ops(text).values()
+             if opcode in ("copy", "transpose", "gather") and count >= plane]
+    assert all(re.search(r"bf16\[4096,(12288|1536)\]", line)
+               for line in moved), moved
+    # the module's name carries the window variant's row, as Trinity's
+    assert text.startswith("HloModule jit_lm_decode_paged_s367a,")
+
+
+def test_grouped_kv_prefill_compiles_for_v5e(chip, mimo_engine, monkeypatch):
+    """The 32 768-token prefill beside the weights and both page groups:
+    under 14.5 GB of the chip's 15.75 — the dense layer's hidden rows,
+    the rotated projections' float32 heads and the grouped way's combine
+    run a block at a time (with all three whole it is 16.7 GB and does
+    not compile). The two full layers take the flash forward at 64 / 4
+    heads of 192 / 128, the five window layers their loop of query
+    blocks over a band of 640 keys with the sink in its softmax."""
+    eng, programs = mimo_engine
+    compiled = _compile_view(chip, programs, "prefill_paged@32768",
+                             eng._cb_prefill[32768],
+                             eng._prefill_feeds(32768), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    text = compiled.as_text()
+    assert "reduce-window" not in text
+    assert len(_flash_forward_calls(text, "bf16[64,32768,128]")) == 2
+    assert "f32[32768,16384]" not in text
+    assert "f32[1,32768,64,192]" not in text
+    assert "f32[8,8,512,32768]" not in text
+    assert _count_opcode(text, "while") >= 5
+
+
+# ---------------------------------------------------------------------------
 # state-space layers beside one attention layer (PR 42): the decode step
 # and the 2048-token prefill of granite4_h_small_ep4_d10 at the cell's sizes
 # ---------------------------------------------------------------------------

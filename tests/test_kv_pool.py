@@ -243,7 +243,10 @@ def test_kv_gauges_preregistered_in_exporter_catalog():
                 "paddle_kv_page_evictions_total",
                 "paddle_kv_group_pages_total", "paddle_kv_group_pages_free",
                 "paddle_kv_window_pages_released_total",
-                "paddle_kv_window_rows_attended_total"):
+                "paddle_kv_window_rows_attended_total",
+                "paddle_kv_full_rows_attended_total",
+                "paddle_kv_full_rows_gathered_total",
+                "paddle_kv_row_bytes"):
         assert fam in snap, fam
 
 

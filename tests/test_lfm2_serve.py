@@ -482,7 +482,7 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault):
         from paddle_tpu.ops import kv_attention
         real_rope = kv_attention.rope_half
         monkeypatch.setattr(
-            kv_attention, "rope_half", lambda x, pos, theta:
+            kv_attention, "rope_half", lambda x, pos, theta, rotary=None:
             real_rope(x, pos, theta) if x.shape[2] == 4 else x)
     elif fault == "picks_without_bias":
         changes["router_bias"] = False
