@@ -696,9 +696,16 @@ def _attention_weights(helper, x, d_model, n_head, param_attr, gqa, attrs):
     if gqa.get("qk_norm"):
         from paddle_tpu.fluid.param_attr import ParamAttr
         attrs.update(qk_norm=True, rms_eps=float(gqa["rms_eps"]))
-        for slot, tag in (("QNorm", "q_norm"), ("KNorm", "k_norm")):
+        # "projection": ONE norm over all of a projection's heads (gains
+        # [H*D] and [n_kv*D]: Olmo's) in place of one a head
+        whole = gqa["qk_norm"] == "projection"
+        if whole:
+            attrs["qk_norm_whole"] = True
+        for slot, tag, heads in (("QNorm", "q_norm", int(n_head)),
+                                 ("KNorm", "k_norm", n_kv)):
             extra[slot] = [helper.create_parameter(
-                ParamAttr(name=f"{param_attr.name}.{tag}"), shape=[d],
+                ParamAttr(name=f"{param_attr.name}.{tag}"),
+                shape=[heads * d if whole else d],
                 dtype=x.dtype, default_initializer=ConstantInitializer(1.0))]
     if gqa.get("rope_theta"):
         attrs["rope_theta"] = float(gqa["rope_theta"])
@@ -889,6 +896,68 @@ def ssd(x, state, conv, d_model, sizes, base, init, epsilon=1e-5,
              "epsilon": float(epsilon)}
     if prefill:
         attrs["chunk"] = int(sizes["ssd_chunk"])
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(op, inputs=inputs,
+                     outputs={"Out": [out], "StateOut": [state],
+                              "ConvOut": [conv]}, attrs=attrs)
+    return out
+
+
+def gdn(x, state, conv, d_model, sizes, base, init, epsilon=1e-5,
+        seq_len=None, slot=None, active=None, name=None):
+    """One Gated DeltaNet layer (ops/gdn.py) over the persistable
+    per-slot ``state`` [n_slots, H, Dk, Dv] float32 and ``conv``
+    [n_slots, taps-1, 2*H*Dk + H*Dv], both read and written under their
+    own names (donated). ``sizes``: gdn_heads (H), gdn_key_dim (Dk),
+    gdn_value_dim (Dv), gdn_conv_taps, gdn_chunk. With ``seq_len`` and
+    ``slot`` it is the prefill of ONE request, x [1, T, M], writing slot
+    ``slot``; with ``active`` the decode step of every slot, x
+    [n_slots, 1, M]. Weights ``<base>.<tag>``; the decay starts as
+    Mamba-2 and Gated DeltaNet start it, a head at a time: A =
+    exp(A_log) spread evenly over [1, 16] across the heads and a dt_bias
+    whose softplus is spread log-evenly over [0.001, 0.1] across them —
+    so a head forgets within a token or two, or remembers for
+    thousands."""
+    from paddle_tpu.fluid.initializer import NumpyArrayInitializer
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    prefill = seq_len is not None
+    op = "gdn_prefill" if prefill else "gdn_decode"
+    helper = LayerHelper(op, name=name)
+    h, dk, dv = (int(sizes[k]) for k in ("gdn_heads", "gdn_key_dim",
+                                         "gdn_value_dim"))
+    taps = int(sizes["gdn_conv_taps"])
+    dt0 = np.exp(np.linspace(np.log(1e-3), np.log(0.1), h))
+    # tag: (the op's slot, shape, the fixed float32 start or None: drawn)
+    table = {
+        "wq": ("Wq", [d_model, h * dk], None),
+        "wk": ("Wk", [d_model, h * dk], None),
+        "wv": ("Wv", [d_model, h * dv], None),
+        "wz": ("Wz", [d_model, h * dv], None),
+        "wo": ("Wo", [h * dv, d_model], None),
+        "conv": ("ConvW", [taps, 2 * h * dk + h * dv], None),
+        "a_log": ("ALog", [h], np.log(np.linspace(1.0, 16.0, h))),
+        "dt_bias": ("DtBias", [h], dt0 + np.log(-np.expm1(-dt0))),
+        "wa": ("Wa", [d_model, h], None),
+        "wb": ("Wb", [d_model, h], None),
+        "onorm": ("ONorm", [dv], np.ones(dv))}
+    inputs = {}
+    for tag, (slot_name, shape, fixed) in table.items():
+        attr = ParamAttr(
+            name=f"{base}.{tag}",
+            initializer=init if fixed is None else NumpyArrayInitializer(
+                fixed.astype(np.float32)))
+        inputs[slot_name] = [helper.create_parameter(
+            attr, shape=shape,
+            dtype=x.dtype if fixed is None else "float32")]
+    inputs.update(X=[x], State=[state], Conv=[conv])
+    if prefill:
+        inputs.update(SeqLen=[seq_len], Slot=[slot])
+    else:
+        inputs.update(Active=[active])
+    attrs = {"n_head": h, "key_dim": dk, "value_dim": dv,
+             "epsilon": float(epsilon)}
+    if prefill:
+        attrs["chunk"] = int(sizes["gdn_chunk"])
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(op, inputs=inputs,
                      outputs={"Out": [out], "StateOut": [state],

@@ -186,15 +186,17 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 # ``gqa_rope_theta``; an output gate where ``gqa_gate``), "swa" (a
 # "gqa" layer over a sliding window, with rotary positions, in a page
 # group of its own), "kda" (Kimi Delta
-# Attention, a fixed-size recurrent state per slot), "ssd" (Mamba-2's
+# Attention, a fixed-size recurrent state per slot), "gdn" (Gated
+# DeltaNet: the same delta rule with one decay a head, a state that is
+# not square and a chunked prefill), "ssd" (Mamba-2's
 # state-space dual: another fixed-size state per slot, a chunked scan as
 # its prefill), "conv" (the LFM2 family's gated short convolution: a
 # third fixed-size state per slot, the last rows of one elementwise
 # product) or "mla" (latent attention with rotary positions and the
 # DSA indexer's sparse selection: a latent plane and an indexer-key plane
 # in the paged pool), and an expert layer of which this program holds a
-# share — or, in the first ``first_k_dense`` layers, a dense SwiGLU layer
-# of width ``d_inner``. One scope serves the prefill and the decode view:
+# share — or, in the first ``first_k_dense`` layers (all of them, in a
+# dense model), a dense SwiGLU layer of width ``d_inner``. One scope serves the prefill and the decode view:
 # every weight and every state variable is named.
 # ---------------------------------------------------------------------------
 
@@ -205,6 +207,8 @@ _HYBRID_KEYS = {
     # norm of every q and k head. "swa" layers are "gqa" layers that
     # attend the last ``window`` positions alone, with rotary positions
     # (``rope_theta``), cached in a page group of their own
+    # (True; "projection": ONE norm over the whole q and the whole k
+    # projection, Olmo's)
     "n_kv_head": None, "head_dim": None, "gqa_gate": True,
     "qk_norm": False, "window": None,
     # rotary positions on the "gqa" layers themselves (rotate-half, all
@@ -225,11 +229,18 @@ _HYBRID_KEYS = {
     # factor on the embedding, on each sub-layer's result before it
     # joins the residual (x + r f(Norm(x))) and under the logits
     # (logits / logits_scale); a head that is the embedding's own table
-    "post_norms": False, "embed_scale": 1.0, "residual_scale": 1.0,
+    # ``pre_norms`` False with ``post_norms`` is Olmo's reordered norm:
+    # x + Norm(f(x)), none before a sub-layer
+    "post_norms": False, "pre_norms": True,
+    "embed_scale": 1.0, "residual_scale": 1.0,
     "logits_scale": 1.0, "tie_embeddings": False,
     # "kda" layers
     "kda_heads": None, "kda_head_dim": None, "kda_conv_taps": 4,
     "kda_gate_rank": None,
+    # "gdn" layers: heads, a key's and a value's size (the state is
+    # [key, value] a head), the conv's taps, the prefill's chunk
+    "gdn_heads": None, "gdn_key_dim": None, "gdn_value_dim": None,
+    "gdn_conv_taps": 4, "gdn_chunk": 64,
     # "ssd" layers: heads, a head's channels, the state's size, the
     # groups that share B and C, the conv's taps, the prefill's chunk
     "ssd_heads": None, "ssd_head_dim": None, "ssd_d_state": None,
@@ -260,6 +271,7 @@ _KIND_KEYS = {
     "gqa": ("n_kv_head", "head_dim"),
     "swa": ("n_kv_head", "head_dim", "window", "rope_theta"),
     "kda": ("kda_heads", "kda_head_dim", "kda_gate_rank"),
+    "gdn": ("gdn_heads", "gdn_key_dim", "gdn_value_dim"),
     "ssd": ("ssd_heads", "ssd_head_dim", "ssd_d_state", "ssd_chunk"),
     "conv": ("conv_taps",),
     "mla": ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
@@ -270,6 +282,10 @@ _KIND_KEYS = {
 _INDEXER_KEYS = ("index_n_heads", "index_head_dim", "index_topk")
 
 
+# the expert layer's sizes: read by no one where every layer's
+# feed-forward is dense (``first_k_dense`` >= n_layer)
+_EXPERT_KEYS = ("n_routed_experts", "n_experts_held", "n_experts_per_tok",
+                "d_expert")
 # sizes whose None is a value (the op's own default), not an omission
 _OPTIONAL = ("attn_scale", "d_shared", "gqa_rope_theta", "swa_n_kv_head",
              "gqa_v_head_dim", "rotary_dim", "value_scale")
@@ -292,6 +308,9 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int, n_head=None) -> dict:
                          f"{sorted(_KIND_KEYS)}")
     unused = {k for keys in _KIND_KEYS.values() for k in keys} \
         - {k for kind in period for k in _KIND_KEYS[kind]}
+    dense_only = hy["first_k_dense"] >= n_layer
+    if dense_only:
+        unused |= set(_EXPERT_KEYS)
     no_indexer = hy["index_topk"] is None
     missing = sorted(k for k, v in hy.items()
                      if v is None and k not in unused
@@ -330,8 +349,11 @@ def hybrid_arch(arch: dict, mode: str, n_layer: int, n_head=None) -> dict:
         raise ValueError("attn_scale is read by 'gqa' layers alone: a "
                          "window layer scales by head_dim ** -0.5")
     _check_grouped_geometry(hy, period, n_head)
-    if not 0 < hy["n_experts_held"] <= hy["n_routed_experts"] \
-            - hy["held_start"]:
+    if not hy["pre_norms"] and not hy["post_norms"]:
+        raise ValueError("pre_norms False without post_norms: a sub-layer "
+                         "has a norm before it, after it, or both")
+    if not dense_only and not 0 < hy["n_experts_held"] \
+            <= hy["n_routed_experts"] - hy["held_start"]:
         raise ValueError("n_experts_held must lie inside the router's "
                          "n_routed_experts from held_start")
     hy["kinds"] = tuple(period[i % len(period)] for i in range(n_layer))
@@ -473,7 +495,13 @@ def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
         return {"counts": pool_var(f"{name}_moe_grouped_{i}",
                                    [2, hy["n_experts_held"]], "int32")}
 
-    y = layers.rms_norm(x, eps, pa(f"{tag}_ln1_scale"))
+    def before(x, which):
+        """The norm before a sub-layer, where the block has one."""
+        if not hy["pre_norms"]:
+            return x
+        return layers.rms_norm(x, eps, pa(f"{tag}_{which}_scale"))
+
+    y = before(x, "ln1")
     if full:
         sizes = {k: hy[k] for k in _KIND_KEYS["mla"]
                  if k != "rope_theta" and k not in _INDEXER_KEYS}
@@ -558,6 +586,17 @@ def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
             eps,
             **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
                if prefill else dict(active=feeds["active"])))
+    elif kind == "gdn":
+        sizes = {k: hy[k] for k in _HYBRID_KEYS if k.startswith("gdn_")}
+        h, dk, dv = (hy[k] for k in _KIND_KEYS["gdn"])
+        state = pool_var(f"{name}_gdn_state_{i}", [n_slots, h, dk, dv])
+        conv = pool_var(
+            f"{name}_gdn_conv_{i}",
+            [n_slots, hy["gdn_conv_taps"] - 1, 2 * h * dk + h * dv], dt)
+        y = layers.gdn(
+            y, state, conv, d_model, sizes, f"{name}_l{i}_gdn", init, eps,
+            **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
+               if prefill else dict(active=feeds["active"])))
     elif kind == "conv":
         conv = pool_var(f"{name}_conv_state_{i}",
                         [n_slots, hy["conv_taps"] - 1, d_model], dt)
@@ -579,7 +618,7 @@ def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
     if hy["post_norms"]:
         y = layers.rms_norm(y, eps, pa(f"{tag}_ln1_post_scale"))
     x = join(x, y)
-    y = layers.rms_norm(x, eps, pa(f"{tag}_ln2_scale"))
+    y = before(x, "ln2")
     if dense_ffn:
         y = layers.swiglu_ffn(y, d_model, d_inner, f"{name}_{tag}_ffn",
                               init)
@@ -706,7 +745,9 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     ops and pools, without positions unless ``gqa_rope_theta``; "swa"
     over a sliding window),
     a Kimi Delta Attention mixer ("kda", a fixed-size recurrent state
-    per slot beside the pages), a Mamba-2 state-space mixer ("ssd",
+    per slot beside the pages), a Gated DeltaNet mixer ("gdn": the same
+    delta rule with one decay a head, a state [key, value] a head,
+    prefilled chunk by chunk), a Mamba-2 state-space mixer ("ssd",
     another fixed-size state, prefilled by a chunked scan), a gated
     short convolution ("conv", a window of its last rows per slot) or
     latent attention with rotary positions and the DSA indexer's sparse
@@ -866,7 +907,8 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             # for every position behind the first decode step's window
             page_rows_w = sdata("page_rows_w", [t, 1])
             feed_specs["page_rows_w"] = ([t, 1], "int64")
-        if hy is not None and {"kda", "ssd", "conv"} & set(hy["kinds"]):
+        if hy is not None and {"kda", "gdn", "ssd", "conv"} \
+                & set(hy["kinds"]):
             # which slot's recurrent state this request's prompt lands
             # in (>= n_slots: nowhere — the warm-up's dispatch)
             state_slot = sdata("state_slot", [1, 1])

@@ -43,6 +43,8 @@ PHASES = {
     "kv_attention_decode_paged": ("write", "gather", "attend"),
     "kv_attention_verify_paged": ("write", "gather", "attend"),
     "kda_decode": ("conv", "state"),
+    "gdn_decode": ("conv", "state", "gate"),
+    "gdn_prefill": ("conv", "scan", "gate"),
     "ssd_decode": ("conv", "state"),
     "ssd_prefill": ("conv", "scan"),
     "shortconv_decode": ("project", "conv", "out"),

@@ -24,6 +24,7 @@ from paddle_tpu.ops import infra_ops  # noqa: F401
 from paddle_tpu.ops import kv_attention  # noqa: F401
 from paddle_tpu.ops import parallel_ops  # noqa: F401
 from paddle_tpu.ops import kda  # noqa: F401
+from paddle_tpu.ops import gdn  # noqa: F401
 from paddle_tpu.ops import ssd  # noqa: F401
 from paddle_tpu.ops import shortconv  # noqa: F401
 from paddle_tpu.ops import expert_ffn  # noqa: F401

@@ -35,13 +35,13 @@ precision HIGHEST would cost more MXU passes than the state costs HBM
 time.
 
 ``kda_decode``'s update has two tiers of one algorithm, chosen at
-lowering by what the code can observe (``_state_tier``;
+lowering by what the code can observe (``state_tier``;
 ``paddle_kda_decode_lowered_total{path}`` counts which):
 
 - ``kernel``: ``ops/pallas/kda_state.py`` — a block of heads of one slot
   in VMEM, ONE read and one write of the state a step, in place. On the
-  chip, off a mesh, for a float32 state whose [D, D] tile is whole lane
-  tiles.
+  chip, off a mesh, for a float32 state whose tile the kernel is
+  written for (``kda_state.supported``).
 - ``refer``: ``_delta_step`` as XLA fuses it — a reduction over the old
   state, then an elementwise pass that reads it again: two reads and
   one write. Everywhere else, and always ``kda_prefill``'s loop body.
@@ -66,13 +66,16 @@ L2_EPS = 1e-6
 _phase = functools.partial(_device_scopes.phase, "kda_decode")
 
 # exporter-catalog family (docs/observability.md). Counts LOWERINGS, as
-# ``paddle_kv_gather_lowered_total`` does: one increment per KDA layer
-# each time a decode program is traced, labelled with the tier that
+# ``paddle_kv_gather_lowered_total`` does: one increment per delta-rule
+# layer (``kda_decode`` here, ``gdn_decode`` in ops/gdn.py: one
+# recurrence, one kernel) each
+# time a decode program is traced, labelled with the tier that
 # advances its state — ``kernel`` (one read of the state a step) or
 # ``refer`` (``_delta_step``: two).
 KDA_DECODE_LOWERED = _metrics.counter(
     "paddle_kda_decode_lowered_total",
-    "KDA decode layers lowered, by the state update's tier (kernel|refer)",
+    "Delta-rule (KDA, GDN) decode layers lowered, by the state update's "
+    "tier (kernel|refer)",
     labelnames=("path",))
 
 _WEIGHTS = ("Wq", "Wk", "Wv", "Wo", "ConvW", "ALog", "DtBias", "WaDown",
@@ -107,8 +110,10 @@ def _qkv(c, h, d):
 
 
 def _delta_step(s, q, k, v, g, beta):
-    """One step of the recurrence on s [..., D, D] (rows: key channel)
-    with q, k, v, g [..., D] and beta [...]: (s_new, o [..., D]).
+    """One step of the recurrence on s [..., Dk, Dv] (rows: key channel)
+    with q, k [..., Dk], v [..., Dv], the log-decay g [..., Dk] (a key
+    channel: KDA) or [..., 1] (a head: Gated DeltaNet, ``ops/gdn.py``)
+    and beta [...]: (s_new, o [..., Dv]).
     ``(k * alpha)^T s`` and ``(q * alpha)^T s`` come out of ONE
     reduction over the old state, the new state out of one elementwise
     pass, and o = q^T s_new follows without reading it again:
@@ -123,15 +128,15 @@ def _delta_step(s, q, k, v, g, beta):
     return s_new, o
 
 
-def _state_tier(state, mesh=None) -> str:
+def state_tier(state, mesh=None) -> str:
     """``kernel`` where the Pallas update runs — on a TPU, off a mesh,
-    a float32 state of whole lane tiles — or is forced onto the
-    interpreter (``pallas.forced_interpret``, the tests' way in);
-    ``refer`` otherwise."""
-    _, h, d, _ = state.shape
-    if not _ks.supported(h, d, state.dtype):
+    a float32 state [.., H, Dk, Dv] whose tile the kernel is written
+    for (``kda_state.supported``) — or is forced onto the interpreter
+    (``pallas.forced_interpret``, the tests' way in); ``refer``
+    otherwise."""
+    if not _ks.supported(*state.shape[2:], state.dtype):
         return "refer"
-    if _plk.kernel_enabled(128, d, mesh=mesh) or _plk.forced_interpret():
+    if _plk.kernel_enabled(mesh=mesh) or _plk.forced_interpret():
         return "kernel"
     return "refer"
 
@@ -225,7 +230,7 @@ def _kda_decode(ctx, ins, attrs):
                     axis=1)
         q, k, v = _qkv(c, h, d)
     beta = beta.reshape(b, h)
-    tier = _state_tier(state, ctx.mesh)
+    tier = state_tier(state, ctx.mesh)
     KDA_DECODE_LOWERED.labels(path=tier).inc()
     with _phase("state"):
         if tier == "kernel":

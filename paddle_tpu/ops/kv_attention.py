@@ -263,7 +263,9 @@ def _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa, positions, grouped=False):
     ``rotary_dim`` the rotation turns a head's first values alone
     (:func:`rope_half`). With attr ``qk_norm``
     every head of q and k is RMS-normalised over its D values (gains
-    ``QNorm`` / ``KNorm`` [D], eps ``rms_eps``) and with attr
+    ``QNorm`` / ``KNorm`` [D], eps ``rms_eps``; with ``qk_norm_whole``
+    the WHOLE projection over its heads * D values, gains of that
+    length: Olmo's) and with attr
     ``rope_theta`` rotated at ``positions()`` [B,T] (the tokens' TRUE
     positions), in that order and in float32: what is cached is the
     normalised, rotated key. Without either the projections are what
@@ -272,6 +274,7 @@ def _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa, positions, grouped=False):
     h, n_kv, d, dv = gqa
     qshape = x.shape[:2] + ((n_kv, h // n_kv, d) if grouped else (h, d))
     qk_norm, theta = bool(attrs.get("qk_norm")), attrs.get("rope_theta")
+    whole = bool(attrs.get("qk_norm_whole"))
 
     def value():
         if not attrs.get("value_scale"):
@@ -288,8 +291,11 @@ def _gqa_qkv(x, wq, wk, wv, ins, attrs, gqa, positions, grouped=False):
     out = []
     for w, heads, gain in ((wq, h, "QNorm"), (wk, n_kv, "KNorm")):
         def rows(xr, pos, w=w, heads=heads, gain=gain):
-            y = dense(xr, w).reshape(xr.shape[:2] + (heads, d))
-            if qk_norm:
+            y = dense(xr, w)
+            if whole:       # one norm over all of the projection's heads
+                y = _rms(y, first(ins, gain), eps)
+            y = y.reshape(xr.shape[:2] + (heads, d))
+            if qk_norm and not whole:
                 y = _rms(y, first(ins, gain), eps)
             if theta:
                 y = rope_half(y, pos(), theta, attrs.get("rotary_dim"))

@@ -825,9 +825,9 @@ class SlotGenerativeModel(GenerativeModel):
         """The second kind of per-slot state (docs/serving.md "Recurrent
         state"): the variables a hybrid family's mixers DECLARE as
         per-slot state (``register_op(..., slot_state=(kind, slots))``:
-        a ``kda`` layer's delta-rule state and conv window, an ``ssd``
-        layer's state-space state and conv window, a ``shortconv``
-        layer's window alone), [n_slots, ...] each,
+        a ``kda`` or ``gdn`` layer's delta-rule state and conv window,
+        an ``ssd`` layer's state-space state and conv window, a
+        ``shortconv`` layer's window alone), [n_slots, ...] each,
         fixed-size per slot — so admission stays by pages and free
         slots. The prefill view writes the slot its ``state_slot`` feed
         names, the decode view updates every active slot in place.
@@ -916,6 +916,15 @@ class SlotGenerativeModel(GenerativeModel):
         self._m_ssd_tokens = smetrics.SSD_TOKENS_SCANNED.labels(
             model=self.name)
         self._m_ssd_rows = smetrics.SSD_CHUNK_ROWS.labels(model=self.name)
+        # a Gated DeltaNet layer's prefill scans whole blocks of chunks
+        # up to the prompt's true length: counted as the SSD layers' are
+        self._gdn_layers = sum(
+            op.type == "gdn_decode"
+            for op in dec_main.desc.global_block.ops)
+        self._gdn_chunks: Dict[int, int] = {}       # by prompt bucket
+        self._m_gdn_tokens = smetrics.GDN_TOKENS_SCANNED.labels(
+            model=self.name)
+        self._m_gdn_rows = smetrics.GDN_CHUNK_ROWS.labels(model=self.name)
         # a gated short convolution convolves a prompt's true tokens and
         # a step's running slots: counted on the host, in every such layer
         self._conv_layers = sum(
@@ -926,15 +935,30 @@ class SlotGenerativeModel(GenerativeModel):
                                                    view=view)
             for view in ("prefill", "decode")} if self._conv_layers else {}
 
+    def _prefill_chunk(self, p_len: int, op_type: str) -> int:
+        """The ``chunk`` the first ``op_type`` op of the ``p_len``
+        prefill view was built with."""
+        return int(next(
+            op for op in
+            self._cb_prefill[p_len]._program_desc.global_block.ops
+            if op.type == op_type).attrs["chunk"])
+
+    def _gdn_scan_rows(self, length: int, p_len: int) -> int:
+        """Rows the chunked scan of the ``p_len`` prefill view computes
+        for a prompt of ``length`` tokens (``ops/gdn.py:scan_rows`` at
+        the op's ``chunk``, looked up once a bucket)."""
+        from paddle_tpu.ops.gdn import scan_rows
+        if p_len not in self._gdn_chunks:
+            self._gdn_chunks[p_len] = self._prefill_chunk(p_len,
+                                                          "gdn_prefill")
+        return scan_rows(length, p_len, self._gdn_chunks[p_len])
+
     def _ssd_chunk(self, p_len: int) -> int:
         """Rows a turn of the chunked scan of the ``p_len`` prefill view
         (``ops/ssd.py``: the op's ``chunk``, at most the bucket)."""
         if p_len not in self._ssd_chunks:
-            op = next(
-                op for op in
-                self._cb_prefill[p_len]._program_desc.global_block.ops
-                if op.type == "ssd_prefill")
-            self._ssd_chunks[p_len] = min(int(op.attrs["chunk"]), p_len)
+            self._ssd_chunks[p_len] = min(
+                self._prefill_chunk(p_len, "ssd_prefill"), p_len)
         return self._ssd_chunks[p_len]
 
     # decode steps between two snapshots of the expert counters
@@ -1400,6 +1424,10 @@ class SlotGenerativeModel(GenerativeModel):
             self._m_ssd_tokens.inc(length * self._ssd_layers)
             self._m_ssd_rows.inc(-(-length // chunk) * chunk
                                  * self._ssd_layers)
+        if self._gdn_layers:
+            self._m_gdn_tokens.inc(length * self._gdn_layers)
+            self._m_gdn_rows.inc(self._gdn_scan_rows(length, p_len)
+                                 * self._gdn_layers)
         if self._conv_layers:
             self._m_conv_tokens["prefill"].inc(length * self._conv_layers)
         first = int(np.asarray(tok).reshape(-1)[0])

@@ -220,7 +220,7 @@ RECURRENT_STATE_BYTES = _metrics.gauge(
     "paddle_recurrent_state_bytes",
     "Bytes of per-slot recurrent and conv state a hybrid model keeps "
     "beside its KV pages, by the kind of mixer that keeps it "
-    "(kda|ssd|shortconv; "
+    "(kda|gdn|ssd|shortconv; "
     "static: fixed-size per slot, n_slots of them; no sample for a "
     "model with none)", labelnames=("model", "kind"))
 SSD_TOKENS_SCANNED = _metrics.counter(
@@ -233,6 +233,16 @@ SSD_CHUNK_ROWS = _metrics.counter(
     "Rows ssd_prefill's chunked scan computed: the whole chunks a "
     "prompt's true length fills, summed over the model's SSD layers",
     labelnames=("model",))
+GDN_TOKENS_SCANNED = _metrics.counter(
+    "paddle_gdn_tokens_scanned_total",
+    "True prompt tokens through gdn_prefill's chunked scan, summed over "
+    "the model's GDN layers (counted on the host at admission)",
+    labelnames=("model",))
+GDN_CHUNK_ROWS = _metrics.counter(
+    "paddle_gdn_chunk_rows_total",
+    "Rows gdn_prefill's chunked scan computed: the whole blocks of chunks "
+    "a prompt's true length fills (ops/gdn.py:scan_rows), summed over the "
+    "model's GDN layers", labelnames=("model",))
 SHORTCONV_TOKENS = _metrics.counter(
     "paddle_shortconv_tokens_total",
     "True tokens through the gated short convolutions, summed over the "

@@ -87,6 +87,8 @@ STEP_MODULES_SINCE_PR37 = {
     # PR 56: the digest carries the window variant's row (no new phase:
     # the grouped kinds' new geometry is attributes, so Trinity's module)
     "serve_mimo_decode_deepctx": "jit_lm_decode_paged_s367a",
+    # PR 59: the digest carries ``gdn_decode``'s row of the phases
+    "serve_olmo_hybrid_docqa_closed": "jit_lm_decode_paged_scfb5",
 }
 
 
@@ -117,8 +119,22 @@ LAST_CELL_OF_PR44 = "serve_granite_sessions_closed"
 LAST_METRIC_OF_PR51 = "attn_ms_per_prefill"
 LAST_CELL_OF_PR51 = "serve_lfm2_extract_closed"
 LAST_CONFIG_OF_PR51 = "lfm2_8b_a1b_d12"
-LIST_CUT_BEHIND = {"test_chipbench_moe_grouped": LAST_METRIC_OF_PR44,
-                   "test_chipbench_serve_lfm2": LAST_METRIC_OF_PR51}
+# ``tests/chipbench/test_chipbench_serve_mimo.py`` (PR 56) pins ITS five
+# metrics, its cell and its configuration as the last of their lists and
+# counts eleven cells and nine configurations; PR 59 appended five
+# metrics, a cell and a configuration. That module is shown the three
+# lists cut behind PR 56's last entries, as PR 51's is; the same
+# ``benchmark`` PR drops it.
+LAST_METRIC_OF_PR56 = "full_kv_live_rows_pct.decode"
+# module -> (its last metric, its last cell or None, its last
+# configuration or None): the lists it is shown end there
+LIST_CUT_BEHIND = {
+    "test_chipbench_moe_grouped": (LAST_METRIC_OF_PR44, None, None),
+    "test_chipbench_serve_lfm2": (LAST_METRIC_OF_PR51, LAST_CELL_OF_PR51,
+                                  LAST_CONFIG_OF_PR51),
+    "test_chipbench_serve_mimo": (LAST_METRIC_OF_PR56,
+                                  "serve_mimo_decode_deepctx",
+                                  "mimo_v2_flash_ep16_d7")}
 # ``tests/chipbench/test_chipbench_serve_trinity.py`` (PR 37) pins its
 # four metrics' ``workloads`` to its one cell, and PR 56's cell, the
 # second with a window group, belongs on ``kv_window_pages_*``'s lists:
@@ -132,7 +148,8 @@ WINDOW_GROUP_LISTS_AS_PINNED = {
 @pytest.fixture(autouse=True)
 def _per_layer_list_as_the_module_pinned_it(request, monkeypatch):
     module = request.module.__name__.rsplit(".", 1)[-1]
-    last = LIST_CUT_BEHIND.get(module)
+    last, last_cell, last_config = LIST_CUT_BEHIND.get(
+        module, (None, None, None))
     own = WINDOW_GROUP_LISTS_AS_PINNED.get(module)
     if last is None and own is None:
         return
@@ -151,11 +168,11 @@ def _per_layer_list_as_the_module_pinned_it(request, monkeypatch):
             return bench
         names = [m["name"] for m in bench["per_layer"]]
         del bench["per_layer"][names.index(last) + 1:]
-        if last == LAST_METRIC_OF_PR51:
-            # PR 51's module also pins its cell and its configuration
-            # as the last of their lists (PR 56 appended one of each)
-            for group, own_last in (("workloads", LAST_CELL_OF_PR51),
-                                    ("configs", LAST_CONFIG_OF_PR51)):
+        # PR 51's and PR 56's modules also pin their cell and their
+        # configuration as the last of their lists
+        for group, own_last in (("workloads", last_cell),
+                                ("configs", last_config)):
+            if own_last is not None:
                 names = [e["name"] for e in bench[group]]
                 del bench[group][names.index(own_last) + 1:]
         if last == LAST_METRIC_OF_PR44:

@@ -158,6 +158,17 @@ def _kda_state():
              ((b,), I32)])
 
 
+def _gdn_state():
+    # Olmo-Hybrid's cell: 8 slots x 30 heads of [96, 192] float32, one
+    # decay a head (two blocks of 15 heads, [96, 256] each in VMEM)
+    from paddle_tpu.ops.pallas import kda_state as ks
+    b, h, dk, dv = 8, 30, 96, 192
+    key = ((b, h, dk), F32)
+    return (ks.kda_state_update,
+            [((b, h, dk, dv), F32), key, key, ((b, h, dv), F32),
+             ((b, h, 1), F32), ((b, h), F32), ((b,), I32)])
+
+
 def _fused_ce():
     from paddle_tpu.ops.pallas import fused_linear_ce
 
@@ -210,6 +221,7 @@ CASES = {
     "fused_ce-fwd+bwd-8192x512x32000": _fused_ce,
     "fused_lstm-fwd+bwd-100x64x512": _fused_lstm,
     "kda_state-f32-128x64x128x128": _kda_state,
+    "kda_state-f32-8x30x96x192-head-decay": _gdn_state,
     # GLM-5's expert layer (16 of 256 held) and Trinity's (all 128)
     "hit_experts-bf16-32x6144x2048x16": lambda: _hit_experts(32, 6144, 2048,
                                                               16),
@@ -614,7 +626,8 @@ def test_hybrid_decode_step_compiles_for_v5e(chip, monkeypatch):
         r"%(kda_state_update[\w.]*) = \((f32\[[\d,]+\])\S* (f32\[[\d,]+\])"
         r"\S* custom-call\(", entry)
     assert len(kernels) == 3 and all(
-        k[1:] == ("f32[128,64,128,128]", "f32[128,64,128]")
+        # o as the kernel writes it: [slots, blocks, a block's heads, Dv]
+        k[1:] == ("f32[128,64,128,128]", "f32[128,2,32,128]")
         for k in kernels), kernels
     assert not [n for n, (opcode, _c, args, _l) in ops.items()
                 if opcode == "fusion"

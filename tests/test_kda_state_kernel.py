@@ -31,18 +31,21 @@ TOL = 2e-5
 F32 = np.float32
 
 
-def inputs(b, h, d, seed=0):
+def inputs(b, h, d, seed=0, dv=None, head_decay=False):
     """A state and one token's q, k, v, g, beta as ``_qkv`` and
     ``_token_terms`` give them: q, k normalised, g <= 0, beta in
-    (0, 2)."""
+    (0, 2). ``dv``: a value head of another size than the key head's
+    ``d`` (a rectangular state); ``head_decay``: g [b, h, 1], one decay
+    a head (``ops/gdn.py``'s)."""
     r = np.random.RandomState(seed)
-    s = r.randn(b, h, d, d).astype(F32)
+    dv = dv or d
+    s = r.randn(b, h, d, dv).astype(F32)
     q = r.randn(b, h, d).astype(F32)
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * d ** 0.5
     k = r.randn(b, h, d).astype(F32)
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    v = r.randn(b, h, d).astype(F32)
-    g = -np.abs(r.randn(b, h, d)).astype(F32) * 0.1
+    v = r.randn(b, h, dv).astype(F32)
+    g = -np.abs(r.randn(b, h, 1 if head_decay else d)).astype(F32) * 0.1
     beta = (2.0 / (1.0 + np.exp(-r.randn(b, h)))).astype(F32)
     return s, q, k, v, g, beta
 
@@ -53,11 +56,11 @@ def rel(a, b):
 
 
 def errors(b, h, d, heads, active, update=ks.kda_state_update, seed=0,
-           unroll=ks.UNROLL):
+           unroll=ks.UNROLL, **shape):
     """(state error, output error) of the kernel over the ACTIVE slots,
     relative to ``_delta_step``'s largest value, and whether every
     inactive slot kept its state to the bit."""
-    s, q, k, v, g, beta = inputs(b, h, d, seed)
+    s, q, k, v, g, beta = inputs(b, h, d, seed, **shape)
     active = np.asarray(active, np.int32)
     new, o = update(jnp.asarray(s), q, k, v, g, beta, active, heads=heads,
                     unroll=unroll, interpret=True)
@@ -81,6 +84,19 @@ CASES = {
     "two_blocks_of_32": (1, 64, 128, 0, [1]),
     "one_block_of_24": (2, 24, 128, 0, [1, 0]),
     "head_size_256": (2, 8, 256, 0, [0, 1]),
+    # heads that are no whole sublane tiles: a block's vectors are padded
+    "twenty_heads": (2, 20, 128, 0, [1, 0]),
+    "three_heads": (2, 3, 128, 0, [1, 1]),
+}
+# a rectangular state [Dk, Dv] that is no whole lane tiles, one decay a
+# head (Olmo-Hybrid's 30 heads of 96 x 192: two blocks of 15, three
+# heads written out a turn): slots, heads, Dk, Dv, heads a grid step,
+# which slots decode
+RECTANGULAR = {
+    "olmo_30x96x192": (3, 30, 96, 192, 0, [1, 0, 1]),
+    "olmo_blocks_of_10": (2, 30, 96, 192, 10, [0, 1]),
+    "wide_key_64x320": (2, 4, 64, 320, 0, [1, 1]),
+    "tall_256x64": (2, 6, 256, 64, 3, [1, 0]),
 }
 
 
@@ -90,6 +106,18 @@ def test_the_kernel_is_delta_step(case):
     inactive slot's state BIT FOR BIT what it was."""
     b, h, d, heads, active = CASES[case]
     s_err, o_err, kept = errors(b, h, d, heads, active)
+    assert kept
+    assert s_err <= TOL and o_err <= TOL, (s_err, o_err)
+
+
+@pytest.mark.parametrize("head_decay", [False, True])
+@pytest.mark.parametrize("case", sorted(RECTANGULAR))
+def test_the_kernel_is_delta_step_on_a_rectangular_state(case, head_decay):
+    """As above for a state [Dk, Dv] with Dk != Dv, with a decay a key
+    channel and with ONE a head: the same kernel, the same step."""
+    b, h, dk, dv, heads, active = RECTANGULAR[case]
+    s_err, o_err, kept = errors(b, h, dk, heads, active, dv=dv,
+                                head_decay=head_decay)
     assert kept
     assert s_err <= TOL and o_err <= TOL, (s_err, o_err)
 
@@ -104,35 +132,46 @@ def test_heads_written_out_or_looped_over_are_the_same_update(unroll):
     assert s_err <= TOL and o_err <= TOL, (s_err, o_err)
 
 
-def test_a_group_of_heads_divides_its_block():
+def test_a_block_of_heads_divides_the_heads():
+    """The heads written out a turn are the largest count under
+    ``unroll`` that divides the block (16 asked of 24: 12); a block
+    that does not divide the heads is refused."""
     s, q, k, v, g, beta = inputs(1, 24, 128)
-    with pytest.raises(ValueError, match="do not divide"):
-        ks.kda_state_update(s, q, k, v, g, beta, np.ones(1, np.int32),
-                            unroll=16, interpret=True)
+    new, _o = ks.kda_state_update(s, q, k, v, g, beta,
+                                  np.ones(1, np.int32), unroll=16,
+                                  interpret=True)
+    want, _ = kda._delta_step(jnp.asarray(s), q, k, v, g, beta)
+    assert rel(new, want) <= TOL
     with pytest.raises(ValueError, match="heads divides"):
         ks.kda_state_update(s, q, k, v, g, beta, np.ones(1, np.int32),
                             heads=16, interpret=True)
 
 
-@pytest.mark.parametrize("n_head,head_dim,want", [
-    (64, 128, 32),      # the hybrid cell: 2 MB a block
-    (32, 128, 32), (8, 128, 8), (24, 128, 24), (40, 128, 8), (96, 128, 32),
-    (64, 256, 8),       # a larger tile, fewer heads: the same 2 MB
-    (20, 128, 0),       # no divisor of 20 is whole sublane tiles
-    (4, 128, 0),
+@pytest.mark.parametrize("n_head,key_dim,value_dim,want", [
+    (64, 128, 128, 32),     # the hybrid cell: 2 MB a block
+    (32, 128, 128, 32), (8, 128, 128, 8), (24, 128, 128, 24),
+    (40, 128, 128, 20), (96, 128, 128, 32),
+    (64, 256, 256, 8),      # a larger tile, fewer heads: the same 2 MB
+    (20, 128, 128, 20),     # no whole sublane tiles: padded vectors
+    (4, 128, 128, 4),
+    (30, 96, 192, 15),      # Olmo-Hybrid: [96, 256] in VMEM, 21 fit
 ])
-def test_head_block(n_head, head_dim, want):
-    assert ks.head_block(n_head, head_dim) == want
-    assert ks.supported(n_head, head_dim, jnp.float32) == bool(want)
+def test_head_block(n_head, key_dim, value_dim, want):
+    assert ks.head_block(n_head, key_dim, value_dim) == want
+    assert ks.supported(key_dim, value_dim, jnp.float32)
 
 
-@pytest.mark.parametrize("n_head,head_dim,dtype", [
-    (64, 64, jnp.float32),      # half a lane tile
-    (4, 16, jnp.float32),       # tests/test_hybrid_lm.py's family
-    (64, 128, jnp.bfloat16),    # a narrower state is another result
+@pytest.mark.parametrize("n_head,key_dim,value_dim,dtype", [
+    (4, 16, 16, jnp.float32),       # tests/test_hybrid_lm.py's family
+    (4, 16, 32, jnp.float32),       # tests/test_gdn.py's
+    (64, 64, 64, jnp.float32),      # half a lane tile
+    (30, 100, 192, jnp.float32),    # key channels off the sublane tiles
+    (64, 128, 128, jnp.bfloat16),   # a narrower state is another result
+    (2, 1024, 1024, jnp.float32),   # one tile over a block's bytes
 ])
-def test_what_the_kernel_is_not_written_for(n_head, head_dim, dtype):
-    assert not ks.supported(n_head, head_dim, dtype)
+def test_what_the_kernel_is_not_written_for(n_head, key_dim, value_dim,
+                                            dtype):
+    assert not ks.supported(key_dim, value_dim, dtype)
 
 
 def test_the_state_is_aliased_to_the_result_and_comes_first():
@@ -153,8 +192,9 @@ def test_the_state_is_aliased_to_the_result_and_comes_first():
     (call,) = calls(jaxpr.jaxpr)
     assert tuple(call.params["input_output_aliases"]) == ((3, 0),)
     assert call.invars[3].aval.shape == (2, 8, 128, 128)
+    # o as the kernel writes it: [slots, blocks, a block's heads, Dv]
     assert [o.aval.shape for o in call.outvars] == [(2, 8, 128, 128),
-                                                    (2, 8, 128)]
+                                                    (2, 1, 8, 128)]
 
 
 def _bf16_products(orig):
@@ -230,7 +270,7 @@ def _lowered_by(ins, attrs, mesh=None):
     ("chip", "kernel"),                 # the benchmark's cell
     ("chip-mesh2", "refer"),            # XLA cannot partition a Mosaic call
     ("chip-d64", "refer"),              # half a lane tile
-    ("chip-heads20", "refer"),          # no block of whole sublane tiles
+    ("chip-heads20", "kernel"),         # a block's vectors are padded
     ("cpu-forced", "kernel"),           # the tests' way in: interpreted
     ("cpu-forced-d64", "refer"),        # ... where the kernel is written for
 ])

@@ -233,6 +233,22 @@ spec("kda_prefill", {"X": [f(1, 5, 8)], **_KDA,
                      "SeqLen": [lens(3).reshape(1, 1)],
                      "Slot": [lens(1).reshape(1, 1)]},
      {"n_head": 2, "head_dim": 4})
+# Gated DeltaNet (ops/gdn.py): two slots, two heads with keys of four
+# and values of six, a conv of four taps, a chunk of three
+_GDN = {"Wq": [f(8, 8, seed=2)], "Wk": [f(8, 8, seed=3)],
+        "Wv": [f(8, 12, seed=4)], "Wz": [f(8, 12, seed=5)],
+        "Wo": [f(12, 8, seed=6)], "ConvW": [f(4, 28, seed=7)],
+        "ALog": [f(2, seed=8)], "DtBias": [f(2, seed=9)],
+        "Wa": [f(8, 2, seed=10)], "Wb": [f(8, 2, seed=11)],
+        "ONorm": [pos(6)], "State": [f(2, 2, 4, 6, seed=12)],
+        "Conv": [f(2, 3, 28, seed=13)]}
+_GDN_ATTRS = {"n_head": 2, "key_dim": 4, "value_dim": 6}
+spec("gdn_decode", {"X": [f(2, 1, 8)], **_GDN,
+                    "Active": [ints(2, 1, hi=2, seed=3)]}, _GDN_ATTRS)
+spec("gdn_prefill", {"X": [f(1, 6, 8)], **_GDN,
+                     "SeqLen": [lens(4).reshape(1, 1)],
+                     "Slot": [lens(1).reshape(1, 1)]},
+     {**_GDN_ATTRS, "chunk": 3})
 # the state-space mixer (ops/ssd.py): two slots, two heads of four
 # channels, a state of eight, one group, a conv of four taps with bias
 _SSD = {"WIn": [f(8, 34, seed=2)], "WOut": [f(8, 8, seed=3)],

@@ -1,8 +1,11 @@
-"""Every configuration the benchmark served before MiMo-V2-Flash lowers
-the program text of the parent commit (PR 56: the grouped kinds' new
-keys — per-kind KV heads, a value head of its own size, a partial
-rotation, a value scale, a sink — are parameters whose defaults change
-nothing).
+"""Every configuration the benchmark served before this PR's lowers the
+program text of the parent commit (PR 56: the grouped kinds' new keys —
+per-kind KV heads, a value head of its own size, a partial rotation, a
+value scale, a sink — are parameters whose defaults change nothing; PR
+59: the delta rule's rectangular state and decay a head, the block
+without a norm before a sub-layer, the norm over a whole projection and
+the stack without an expert layer are too, and MiMo-V2-Flash's and the
+multi-head family's views join the table).
 
 One case a configuration and backend: the tiny preset of the
 configuration's own cell test is built through
@@ -40,13 +43,18 @@ PRESETS = {
     "trinity_mini_26b_d5": "test_chipbench_serve_trinity",
     "granite4_h_small_ep4_d10": "test_chipbench_serve_granite",
     "lfm2_8b_a1b_d12": "test_chipbench_serve_lfm2",
+    "mimo_v2_flash_ep16_d7": "test_chipbench_serve_mimo",
+    # the multi-head family: the preset is chipbench_tiny's own
+    "gpt2_medium_d12": "chipbench_tiny",
 }
 
 
 def tiny_config(name):
     sys.path.insert(0, os.path.join(HERE, "chipbench"))
     try:
-        return importlib.import_module(PRESETS[name]).tiny_config()
+        module = importlib.import_module(PRESETS[name])
+        return getattr(module, "tiny_config", None)() \
+            if hasattr(module, "tiny_config") else module.serve_config()
     finally:
         sys.path.remove(os.path.join(HERE, "chipbench"))
 
@@ -61,7 +69,8 @@ def view_digests(cfg, sharding) -> dict:
         name="lm", modes=T.slot_modes(cfg["kv_layout"]),
         kv_codec=cfg["kv_codec"],
         **{**build, "prompt_buckets": tuple(build["prompt_buckets"]),
-           "layer_kinds": tuple(build["layer_kinds"])})
+           **({"layer_kinds": tuple(build["layer_kinds"])}
+              if "layer_kinds" in build else {})})
     eng = serving.make_slot_model("lm", programs, init=False)
 
     def struct(shape, dtype):
