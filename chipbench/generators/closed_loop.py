@@ -14,6 +14,11 @@ in a server that has been running, and not all together after the
 shortest budget. Measured without it (PERF.md, PR 23): the first
 completions bunched around the 30 s mark, and a window that closed one
 decode step earlier or later held 3 or 6 prefills.
+
+A traffic file with ``"sampling": {"temperature": t, "top_k": k}`` has
+its requests SAMPLED by the server (on the device, each request keyed by
+a seed of its own drawn from ``--seed``); without the key every request
+is greedy, as before PR 60.
 """
 
 from __future__ import annotations
@@ -45,14 +50,24 @@ def make(traffic: dict, config: dict, seed: int, seconds: float) -> dict:
         prompt, budget = reqs[0]
         reqs[0] = (prompt, max(traffic["first_round_min"],
                                int(round(budget * share))))
-    return {"clients": clients}
+    plan = {"clients": clients}
+    if "sampling" in traffic:
+        # drawn after everything else, so that a traffic file that gains
+        # the key keeps its prompts and budgets: one seed a request, and
+        # a sampled stream is keyed by it and the token's index alone —
+        # the same --seed decodes the same tokens whatever the timing
+        plan["sampling"] = {
+            **traffic["sampling"],
+            "seeds": rng.randint(0, 2 ** 31 - 1, (n, rounds)).tolist()}
+    return plan
 
 
 class _Client(threading.Thread):
-    def __init__(self, idx, requests, server, model, stop):
+    def __init__(self, idx, requests, server, model, stop, sampling=None):
         super().__init__(daemon=True, name=f"chipbench-client-{idx}")
         self.idx, self.requests = idx, requests
         self.server, self.model, self.stop = server, model, stop
+        self.sampling = sampling   # None: greedy, as the server defaults
         self.log = []          # [submit_t, done_t | None, budget, ok]
         self.current = None    # request id in flight
 
@@ -60,9 +75,13 @@ class _Client(threading.Thread):
         j = 0
         while not self.stop.is_set():
             # round 0 (the staggered one) is served once, never again
-            prompt, budget = self.requests[
-                j if j < len(self.requests)
-                else 1 + (j - 1) % (len(self.requests) - 1)]
+            r = j if j < len(self.requests) \
+                else 1 + (j - 1) % (len(self.requests) - 1)
+            prompt, budget = self.requests[r]
+            how = {} if self.sampling is None else {
+                "temperature": self.sampling["temperature"],
+                "top_k": self.sampling["top_k"],
+                "seed": self.sampling["seeds"][self.idx][r]}
             rid = f"c{self.idx}-{j}"
             row = [time.perf_counter(), None, budget, None]
             self.log.append(row)
@@ -70,7 +89,7 @@ class _Client(threading.Thread):
             try:
                 out = self.server.submit_generate(
                     self.model, [prompt], max_new=budget,
-                    request_id=rid).result(timeout=3600)[0]
+                    request_id=rid, **how).result(timeout=3600)[0]
                 row[3] = bool(len(out) == budget and out.min() >= 0)
             except BaseException:
                 row[3] = False
@@ -81,7 +100,8 @@ class _Client(threading.Thread):
 def prime(ctx) -> None:
     ctx.stop = threading.Event()
     admitted = ctx.prefills() + len(ctx.plan["clients"])
-    ctx.clients = [_Client(i, reqs, ctx.server, ctx.model, ctx.stop)
+    ctx.clients = [_Client(i, reqs, ctx.server, ctx.model, ctx.stop,
+                           ctx.plan.get("sampling"))
                    for i, reqs in enumerate(ctx.plan["clients"])]
     for c in ctx.clients:
         c.start()

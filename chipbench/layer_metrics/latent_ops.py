@@ -1,45 +1,54 @@
-"""The DSA indexer and the sparse latent attention of a decode step,
-read from the device trace (milliseconds a step, or the share of the
-roofline: ``flops_latent``'s bytes over the peak bandwidth or its
-operations over the peak rate, whichever is longer, over the ops' time)
-and from the program's row counters (``obs["dsa_rows"]``: the rows the
-indexer scored and the rows attended over the window's decode steps,
-all layers).
+"""The DSA indexer and the sparse latent attention of a decode step:
+milliseconds a step of the device's ops, and the share of the roofline
+they reach (``flops_latent``'s bytes over the peak bandwidth or its
+operations over the peak rate, whichever is longer, over that time), the
+bytes and operations from the program's row counters
+(``obs["dsa_rows"]``: the rows the indexer scored and the rows attended
+over the window's decode steps, all layers).
 
-``group`` selects the ops, among those of the program executions that
-the ``serving.decode_step`` spans caused, by kernel name or by RESULT
-SHAPE — shapes that nothing else in the step has:
+The ops are those of the executions of the decode module wholly inside
+the window (``scope_ms.table``: the engine's snapshots of the expert
+counters are other modules and no steps), a STEP is one such execution,
+and the counters' rows — the whole window's, ``obs["units"]
+["decode_steps"]`` steps — are scaled to the executions the trace holds
+(685 of 687 in the recorded run). ``group`` names the mechanism:
 
-- ``index``: the indexer's products over the cached keys and the
-  selection — the ``gather_pages`` kernel whose result is the index
-  plane's rows ``[n_slots * cache_len, index_head_dim]``, the scoring
-  ``f32[n_slots, cache_len / 128, 128]`` (ops/mla.py:_slot_scores) and
-  the ``sort`` of ``f32[n_slots, cache_len]`` that ``jax.lax.top_k``
-  becomes (the routers' own top-8 sorts 256 scores). Today the kernel gathers
-  every row of every slot's table and the sort orders all of them,
-  while the bytes counted are the LIVE rows' keys read once: the share
-  reads low, and says how far a kernel that stops at a slot's length
-  and selects without sorting could go.
-- ``sparse``: the gather of the selected latent rows ``[n_slots *
-  index_topk, W]`` and the two absorbed contractions over them, scores
-  and probabilities ``[n_slots, n_head, index_topk]`` and the attended
-  latent ``[n_slots, n_head, W]``.
+- ``index`` — by SCOPE, ``mla_decode_paged/index`` of the fused serving
+  op (an op is found by (module, instruction) -> scope path, whatever
+  kernel or fusion implements it; until PR 60 the ops were found by
+  kernel name and result shape, and a program that scored the index
+  plane in place, without the ``gather_pages`` kernel, would have left
+  the share silent): the indexer's products over the cached keys, today
+  a ``gather_pages`` kernel over every row of every slot's table and
+  the scoring ``f32[n_slots, cache_len / 128, 128]``
+  (ops/mla.py:_slot_scores). The bytes counted are the LIVE rows' keys
+  read once: the share reads low, and says how far a kernel that stops
+  at a slot's length and reads the plane in place could go. (Picking the
+  index_topk best of the scores lies under ``mla_decode_paged/select``
+  and is not in it: it reads no cache row.)
+- ``sparse`` — by RESULT SHAPE, as since PR 33 (the series is unbroken):
+  the gather of the selected latent rows ``[n_slots * index_topk, W]``
+  and the two absorbed contractions over them, scores and probabilities
+  ``[n_slots, n_head, index_topk]`` and the attended latent ``[n_slots,
+  n_head, W]`` — shapes nothing else in the step has. The scope
+  ``mla_decode_paged/attend`` also holds the page table's copy and three
+  small products (2.598 against 2.47 ms on one traced run, PERF.md
+  PR 60), so the group goes by scope only once the program gives the
+  kernel a sub-scope of its own.
 - ``selected_pct`` (no trace): 100 x rows attended / rows scored.
 
-A decode STEP, for both groups, is one run of the index plane's gather
-in each latent-attention layer: the steps in the trace are counted from
-those kernels, not from program executions — the engine's snapshot of
-the expert counters (four device copies every 32 steps) runs under the
-same spans and would count as 12.5 % more steps (PERF.md, PR 33).
-
-A program without latent-attention layers or without the counters (a
-parent of PR 33) gives nothing to read: None.
+A program without latent-attention layers, without the counters (a
+parent of PR 33) or, for ``index``, without device scopes gives nothing
+to read, and so does a group of which no op ran: None, never 0.
 """
 
-from chipbench import flops, flops_latent
-from chipbench import trace_reduce as tr
+import re
 
-SPAN = "serving.decode_step"
+from chipbench import flops, flops_latent
+from chipbench.layer_metrics import scope_ms
+
+MODULE = r"jit_\w+_decode_paged(_s[0-9a-f]{4})?"
+INDEX_SCOPES = ["mla_decode_paged/index"]
 LANES = 128
 _ITEMSIZE = {"float32": 4, "bfloat16": 2}
 _NEEDS = ("index_topk", "index_head_dim", "kv_lora_rank")
@@ -50,32 +59,6 @@ def latent_width(build: dict) -> int:
     return -(-wide // LANES) * LANES
 
 
-def key_gather(build: dict):
-    """Is an event the ``gather_pages`` kernel over the index plane?"""
-    dt = "f32" if build["dtype"] == "float32" else "bf16"
-    cache_len = build["prompt_len"] + build["max_new"]
-    keys = f"{dt}[{build['n_slots'] * cache_len}," \
-           f"{build['index_head_dim']}]"
-
-    def keep(ev):
-        words = ev[0].split()
-        return words[0].startswith("gather_pages") and keys in words
-    return keep
-
-
-def index_keep(build: dict):
-    cache_len = build["prompt_len"] + build["max_new"]
-    scores = f"f32[{build['n_slots']},{cache_len // LANES},{LANES}]"
-    ordered = f"f32[{build['n_slots']},{cache_len}]"
-    gather = key_gather(build)
-
-    def keep(ev):
-        words = ev[0].split()
-        return gather(ev) or scores in words \
-            or (len(words) > 2 and words[1] == "sort" and ordered in words)
-    return keep
-
-
 def sparse_keep(build: dict):
     dt = "f32" if build["dtype"] == "float32" else "bf16"
     b, h, k = build["n_slots"], build["n_head"], build["index_topk"]
@@ -84,9 +67,38 @@ def sparse_keep(build: dict):
               f"f32[{b},{h},{k}]", f"{dt}[{b},{h},{k}]",
               f"f32[{b},{h},{wide}]", f"{dt}[{b},{h},{wide}]"}
 
-    def keep(ev):
+    def keep(scope, ev):
         return any(w in shapes for w in ev[0].split())
     return keep
+
+
+def index_keep(scope, ev):
+    return scope_ms.in_scope(scope, INDEX_SCOPES)
+
+
+def seconds_and_steps(obs, group):
+    """(seconds of the group's ops, executions of the decode module)
+    wholly inside the window, averaged over the devices; None where no
+    such execution lies there or, for the group that goes by scope, the
+    program gave no map for the module."""
+    tab = scope_ms.table(obs)
+    wanted = re.compile(MODULE)
+    keep = index_keep if group == "index" \
+        else sparse_keep(obs["config"]["build"])
+    seconds, steps = [], []
+    for device, runs in tab["runs"].items():
+        n = sum(1 for name, _s, _e in runs if wanted.fullmatch(name))
+        ops = [(scope, ev) for name, scope, ev in tab["ops"][device]
+               if wanted.fullmatch(name)]
+        if not n or (group == "index"
+                     and any(scope is None for scope, _ev in ops)):
+            return None
+        steps.append(n)
+        seconds.append(sum(ev[2] for scope, ev in ops
+                           if keep(scope, ev)) / 1e9)
+    if not steps:
+        return None
+    return sum(seconds) / len(seconds), sum(steps) / len(steps)
 
 
 def read(obs, group, what=None):
@@ -96,24 +108,11 @@ def read(obs, group, what=None):
         return None
     if group == "selected_pct":
         return 100.0 * rows["selected"] / rows["scored"]
-    red = obs["reduced"]
-    spans = tr.spans_named(red, SPAN)
-    counted = obs["units"]["decode_steps"]
-    if not spans or not counted:
+    counted = (obs.get("units") or {}).get("decode_steps")
+    found = seconds_and_steps(obs, group) if counted else None
+    if not found or not found[0]:
         return None
-    period = build["layer_kinds"]
-    layers = [period[i % len(period)]
-              for i in range(build["n_layer"])].count("mla")
-    gather = key_gather(build)
-    steps = sum(sum(1 for ev in events if gather(ev))
-                for events in tr.ops_of_spans(red, spans)[0].values()) \
-        / layers / len(red["devices"])
-    if not steps:
-        return None
-    keep = index_keep(build) if group == "index" else sparse_keep(build)
-    seconds = tr.mean_seconds(red, keep, spans)
-    if seconds == 0.0:
-        return None
+    seconds, steps = found
     if what == "ms":
         return 1e3 * seconds / steps
     size = _ITEMSIZE[build["dtype"]]

@@ -21,7 +21,12 @@ differs:
 - the program's ``paddle_dsa_rows_scored_total`` and
   ``paddle_dsa_rows_selected_total`` are read at the window's edges
   (``obs["dsa_rows"]``): what the indexer's and the sparse attention's
-  roofline shares count their bytes from.
+  roofline shares count their bytes from;
+- a configuration with ``router_balance`` has the held experts' entries
+  of the router's correction bias balanced in set-up, after the warm-up
+  and before the check (``balance_router_bias``): a decode step streams
+  the HIT held experts' weights alone, so a step's time follows the
+  load the router gives them, and a drawn bias leaves that to the seed.
 """
 
 from __future__ import annotations
@@ -156,16 +161,73 @@ def compare_with_reference(cfg: dict, engine, rng, **ref_kwargs) -> tuple:
 build_engine = serve_hybrid.build_engine
 
 
+def balance_router_bias(cfg: dict, engine, seed: int, device) -> dict:
+    """The HELD experts' correction bias as a balanced deployment has
+    it: the configuration's ``router_balance`` rounds of the published
+    aux-loss-free rule (``b_e += gamma * sign(mean - load_e)``, gamma
+    times ``decay`` a round) on ``bucket`` calibration tokens drawn from
+    ``--seed``, through the served prefill view fed as its warm-up feeds
+    it (every page row and the state slot a sentinel: nothing is
+    written). A chip of the deployment sees its own experts' loads —
+    the program's own per-expert counters beside each expert layer —
+    and the known mean ``tokens x top_k / n_routed_experts``; the
+    absent experts' entries stay the draw. Before the check, so that
+    the check, the reference and the window read the one bias."""
+    import jax
+    rb, build = cfg["router_balance"], cfg["build"]
+    p_len, lo = rb["bucket"], build.get("held_start", 0)
+    cb = engine._cb_prefill[p_len]
+    ops = [op for op in cb._program_desc.global_block.ops
+           if op.type == "expert_ffn_held" and op.inputs.get("Counts")
+           and op.inputs.get("RouterBias")]
+    bias = {op.inputs["RouterBias"][0]: np.array(
+        engine.scope.find_var(op.inputs["RouterBias"][0])) for op in ops}
+    drawn = {n: b.copy() for n, b in bias.items()}
+
+    def counts():
+        return np.stack([np.asarray(engine.scope.find_var(
+            op.inputs["Counts"][0]))[0] for op in ops]).astype(np.int64)
+
+    rng = np.random.RandomState((seed + 2) % 2 ** 32)
+    gamma, uneven = rb["gamma"], []
+    for _ in range(rb["rounds"]):
+        feeds = engine._prefill_feeds(p_len)
+        feeds["ids"][0, :, 0] = rng.randint(1, build["vocab"], p_len)
+        feeds["seq_len"][:] = p_len
+        before = counts()
+        engine._run(cb, (engine.PREFILL, p_len), feeds)
+        engine._grouped_given_done += engine._grouped_given[p_len]
+        load = (counts() - before) % (1 << 32)
+        for op, held in zip(ops, load):
+            name = op.inputs["RouterBias"][0]
+            b = bias[name]
+            mean = p_len * int(op.attrs["top_k"]) / b.shape[1]
+            b[0, lo:lo + held.size] += (
+                gamma * np.sign(mean - held)).astype(b.dtype)
+            engine.scope.set_var(name, jax.device_put(b, device))
+        uneven.append(float(np.mean(load.max(axis=1) / load.mean(axis=1))))
+        gamma *= rb["decay"]
+    moved = np.stack([bias[n] - drawn[n] for n in bias])
+    return {"max_over_mean_by_round": uneven,
+            "held_bias_moved_max": float(np.abs(moved).max()),
+            "absent_bias_moved": float(np.abs(np.delete(
+                moved, np.s_[lo:lo + load.shape[1]], axis=2)).max())}
+
+
 def bring_up(run: harness.Run):
     from paddle_tpu import serving
     with run.phase("build"):
         engine = build_engine(run.config, run.seed, run.devices[0])
     with run.phase("warm"):
         engine.warmup()
+        balanced = balance_router_bias(
+            run.config, engine, run.seed, run.devices[0]) \
+            if "router_balance" in run.config else None
     with run.phase("check"):
         correct, seen, (prompts, tokens) = compare_with_reference(
             run.config, engine,
             np.random.RandomState((run.seed + 1) % 2 ** 32))
+        seen["router_balance"] = balanced
     server = serving.ModelServer()
     try:
         with run.phase("warm"):
@@ -249,4 +311,10 @@ def run(run: harness.Run) -> dict:
                   "counted_ok": counted_ok, "clean": clean,
                   "requests_shed": shed},
     }
+    # the program's counters over the window, as a traced run's
+    # per-layer metrics read them, in an untraced run's notes too: a
+    # step's time follows the held experts' hit share (PERF.md, PR 60)
+    obs["notes"]["dsa_rows"] = obs["dsa_rows"]
+    obs["notes"]["moe_steps"] = int(obs["moe_steps"])
+    obs["notes"]["moe_counts"] = np.asarray(obs["moe_counts"]).tolist()
     return harness.add_device_observations(run, win, obs)

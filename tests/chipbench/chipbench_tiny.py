@@ -58,6 +58,102 @@ def serve_traffic(name):
     return tr
 
 
+class SetupLog(list):
+    """A tiny run's engine dispatches up to the window's opening, in the
+    scheduler's own order: ``(program key, shapes of its feeds)`` each;
+    ``prompts[i]`` is the prompt that entry i prefilled (None for a
+    decode step)."""
+
+    prompts: list
+
+
+def is_admission(entry) -> bool:
+    return entry[0][0].startswith("prefill")
+
+
+def admissions(setup) -> list:
+    return [entry for entry in setup if is_admission(entry)]
+
+
+def logged_run(monkeypatch, config, traffic, seed, seconds=0.4):
+    """Run the tiny cell; (run, observations, the ``SetupLog``)."""
+    import numpy as np
+    from paddle_tpu.serving import engine as eng
+    log, prompts, opened = [], [], []
+    real_run = eng.GenerativeModel._run
+    real_open = harness.Run.open_window
+
+    def spy(self, cb, aot_key, feeds):
+        log.append((aot_key, tuple(sorted(
+            (k, tuple(np.shape(v))) for k, v in feeds.items()))))
+        prompts.append(
+            tuple(int(t) for t in np.asarray(feeds["ids"])[
+                0, :int(np.asarray(feeds["seq_len"]).reshape(-1)[0]), 0])
+            if is_admission(log[-1]) else None)
+        return real_run(self, cb, aot_key, feeds)
+
+    def open_window(self):
+        opened.append(len(log))
+        return real_open(self)
+
+    for cls in (eng.GenerativeModel, eng.SlotGenerativeModel):
+        monkeypatch.setattr(cls, "_run", spy)
+    monkeypatch.setattr(harness.Run, "open_window", open_window)
+    run, obs = run_cell(config, traffic, seed, seconds)
+    setup = SetupLog(log[:opened[0]])
+    setup.prompts = prompts[:opened[0]]
+    return run, obs, setup
+
+
+def priming(setup: SetupLog, config, traffic, seed, seconds, before: int):
+    """A closed loop's set-up split at the scheduler's OWN events, never
+    by the clock (under six loaded test workers the runner sees a count
+    tens of decode steps late, and a client whose 14-token request has
+    ended by then has had its next one admitted): ``head``, the log up
+    to the first admission after the ``before`` that warm-up and check
+    make — one list of dispatches whatever the load; ``first``, the
+    admissions of the clients' first requests, known by their prompts
+    (the plan is the seed's); ``later``, admissions of a client's next
+    request; ``steps``, the decode dispatches after ``head`` (never
+    fewer than the priming asks for, whatever the load).
+
+    How far a priming may run PAST its count is bounded by events too:
+    the window opens before ANY client's second further request is
+    admitted (at most one ``later`` admission a client — the plan serves
+    round 0 once and then repeats its later rounds, so a client's
+    further requests are known by their prompts), and within two
+    requests' budgets of decode steps after the last of the clients'
+    first admissions, the event at which the count can be full. A
+    priming that ran on for any number of steps would admit a client's
+    third request and fail here; a tight count of steps would fail with
+    the host's load (under ten busy loops a client's first request has
+    ended before the last client's is admitted, and that one comes a
+    step before the window opens)."""
+    from chipbench.generators import closed_loop
+    plan = closed_loop.make(traffic, config, seed, seconds)["clients"]
+    firsts = [tuple(int(t) for t in reqs[0][0]) for reqs in plan]
+    nexts = [tuple(int(t) for t in prompt)
+             for reqs in plan for prompt, _budget in reqs[1:]]
+    budget = max(b for reqs in plan for _prompt, b in reqs)
+    cut = [i for i, entry in enumerate(setup) if is_admission(entry)][before]
+    tail = range(cut, len(setup))
+    first_at = [i for i in tail if setup.prompts[i] in firsts]
+    later_at = [i for i in tail
+                if is_admission(setup[i]) and i not in first_at]
+    # every client's first request exactly once; whatever else was
+    # admitted is a request of the plan's later rounds, once a client
+    assert sorted(setup.prompts[i] for i in first_at) == sorted(firsts)
+    later = [setup.prompts[i] for i in later_at]
+    assert all(p in nexts for p in later)
+    assert len(set(later)) == len(later) <= len(plan), later
+    past = sum(1 for i in range(max(first_at) + 1, len(setup))
+               if not is_admission(setup[i]))
+    assert past <= traffic["prime_decode_steps"] + 2 * budget, past
+    return (setup[:cut], [setup[i] for i in first_at],
+            [setup[i] for i in later_at],
+            len(tail) - len(first_at) - len(later_at))
+
+
 def run_cell(config, traffic, seed, seconds=0.5, chips=1):
     cell = {"name": "tiny", "chips": chips, "config": "-", "traffic": "-"}
     run = harness.Run(BENCH, cell, config, traffic, seed, seconds, False,

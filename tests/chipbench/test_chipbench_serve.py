@@ -12,37 +12,24 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import chipbench_tiny as tiny  # noqa: E402
 
-from chipbench import harness  # noqa: E402
 from chipbench.reference import gpt2_medium_d12 as ref  # noqa: E402
 
 
 def logged_run(monkeypatch, traffic, seed, seconds=0.4):
     """Run the tiny cell; log every engine dispatch as (program key,
-    shapes of its feeds) and where in the log the window opened."""
-    from paddle_tpu.serving import engine as eng
-    log, opened = [], []
-    real_run = eng.GenerativeModel._run
-    real_open = harness.Run.open_window
-
-    def spy(self, cb, aot_key, feeds):
-        log.append((aot_key, tuple(sorted(
-            (k, tuple(np.shape(v))) for k, v in feeds.items()))))
-        return real_run(self, cb, aot_key, feeds)
-
-    def open_window(self):
-        opened.append(len(log))
-        return real_open(self)
-
-    for cls in (eng.GenerativeModel, eng.SlotGenerativeModel):
-        monkeypatch.setattr(cls, "_run", spy)
-    monkeypatch.setattr(harness.Run, "open_window", open_window)
-    run, obs = tiny.run_cell(tiny.serve_config(),
-                             tiny.serve_traffic(traffic), seed, seconds)
-    return run, obs, log[:opened[0]]
+    shapes of its feeds) up to where the window opened."""
+    return tiny.logged_run(monkeypatch, tiny.serve_config(),
+                           tiny.serve_traffic(traffic), seed, seconds)
 
 
-def admissions(setup_log):
-    return [entry for entry in setup_log if entry[0][0].startswith("prefill")]
+admissions = tiny.admissions
+
+
+def primed(setup, traffic, seed, seconds):
+    """(head, first, later, steps) of a closed loop's set-up: warm-up's
+    3 buckets and the 2 compared requests come before the clients."""
+    return tiny.priming(setup, tiny.serve_config(),
+                        tiny.serve_traffic(traffic), seed, seconds, 3 + 2)
 
 
 @pytest.mark.parametrize("traffic", ["closed_decode", "open_prefill"])
@@ -61,8 +48,13 @@ def test_tiny_serve_cell_agrees_with_the_reference(monkeypatch, traffic):
         assert obs["units"]["decode_steps"] > 0
         assert 0 < obs["slot_occupancy"] <= 1
         # primed by count: warm-up's 3 buckets, the 2 compared requests,
-        # then exactly one admission per client before the window opens
-        assert len(admissions(setup)) == 3 + 2 + tr["clients"]
+        # then exactly one admission per client's first request before
+        # the window opens (counted by the scheduler's own admissions:
+        # a request after a client's first is its next one)
+        head, first, later, steps = primed(setup, traffic, 21, 0.4)
+        assert len(admissions(head)) == 3 + 2
+        assert len(first) == tr["clients"] >= len(later)
+        assert steps >= tr["prime_decode_steps"]
     else:
         assert obs["end_to_end"]["ttft_p95_ms"] >= \
             obs["end_to_end"]["ttft_p50_ms"] > 0
@@ -73,23 +65,36 @@ def test_tiny_serve_cell_agrees_with_the_reference(monkeypatch, traffic):
 
 @pytest.mark.parametrize("traffic", ["closed_decode", "open_prefill"])
 def test_setup_dispatches_the_same_work_for_two_seeds(monkeypatch, traffic):
-    _r1, _o1, setup1 = logged_run(monkeypatch, traffic, 3, 0.2)
-    _r2, _o2, setup2 = logged_run(monkeypatch, traffic, 2 ** 31 + 5, 0.2)
-    # every admission: same program (bucket), same shapes, same order
-    # (warm-up's 3, the 2 compared requests, then the priming by count)
+    seeds = (3, 2 ** 31 + 5)
+    _r1, _o1, setup1 = logged_run(monkeypatch, traffic, seeds[0], 0.2)
+    _r2, _o2, setup2 = logged_run(monkeypatch, traffic, seeds[1], 0.2)
     tr = tiny.serve_traffic(traffic)
-    n = 3 + 2 + tr.get("clients", tr.get("prime_requests"))
-    assert admissions(setup1)[:n] == admissions(setup2)[:n]
-    assert len(admissions(setup1)) >= n
     steps = [len(s) - len(admissions(s)) for s in (setup1, setup2)]
     if traffic == "open_prefill":
+        # every admission: same program (bucket), same shapes, same
+        # order (warm-up's 3, the 2 compared requests, then the priming
+        # by count: one thread submits, in the schedule's order)
+        n = 3 + 2 + tr["prime_requests"]
+        assert admissions(setup1)[:n] == admissions(setup2)[:n]
+        assert len(admissions(setup1)) >= n
         assert steps[0] == steps[1]
-    else:
-        # at this size a decode step lasts under a millisecond, so the
-        # scheduler may be a step or two past the count when the runner
-        # sees it; never short of it
-        floor = 1 + 2 * 5 + tr["prime_decode_steps"]
-        assert min(steps) >= floor and abs(steps[0] - steps[1]) <= 6
+        return
+    # a closed loop's clients are threads, and the scheduler admits them
+    # as they come: what is the same for two seeds is counted by its own
+    # events — the dispatches up to the first client's admission, one
+    # for one; then the clients' first requests, the same programs and
+    # shapes as a multiset; and never fewer decode steps than the
+    # priming asks for (a decode step lasts under a millisecond here, so
+    # the scheduler is some steps past the count when the runner sees
+    # it: how many is the host's load, not the seed's)
+    (head1, first1, _l1, after1), (head2, first2, _l2, after2) = (
+        primed(s, traffic, seed, 0.2)
+        for s, seed in zip((setup1, setup2), seeds))
+    assert head1 == head2 and len(admissions(head1)) == 3 + 2
+    assert len(head1) - 5 >= 1 + 2 * 5
+    assert sorted(first1) == sorted(first2) and len(first1) == tr["clients"]
+    assert min(after1, after2) >= tr["prime_decode_steps"]
+    assert min(steps) >= 1 + 2 * 5 + tr["prime_decode_steps"]
 
 
 def test_reference_catches_a_wrong_token():

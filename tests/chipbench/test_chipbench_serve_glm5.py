@@ -4,10 +4,12 @@ rows, the runner at a tiny size on the CPU (contract of the
 observations), the new readers on a small hand-recorded trace, and the
 operations-and-bytes functions against hand counts."""
 
+import importlib
 import json
 import os
 import sys
 
+import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -16,7 +18,7 @@ import chipbench_tiny as tiny  # noqa: E402
 from chipbench import flops_latent, harness  # noqa: E402
 from chipbench import trace_reduce as tr  # noqa: E402
 from chipbench.generators import closed_loop  # noqa: E402
-from chipbench.layer_metrics import latent_ops, moe_counts  # noqa: E402
+from chipbench.layer_metrics import latent_ops, moe_counts, scope_ms  # noqa: E402
 
 NAME = "glm5_744b_ep16_d5"
 CELL = "serve_glm5_decode_longctx"
@@ -38,6 +40,9 @@ def committed_traffic():
 def tiny_config():
     cfg = committed()
     cfg["kv_codec"] = "none"
+    # no bucket at this size takes the prefill's grouped way, whose
+    # counters the balancing reads: ``balanced_config`` has one
+    del cfg["router_balance"]
     cfg["build"].update(
         n_layer=3, d_model=64, d_inner=96, n_head=4, vocab=96,
         prompt_len=32, max_new=16, prompt_buckets=[16, 32], n_slots=4,
@@ -199,6 +204,177 @@ def test_tiny_glm5_cell_agrees_with_the_reference():
     assert rows["scored"] > rows["selected"] > 0
     assert rows["selected"] <= 3 * 8 * 4 * (steps + 2)
     assert 0 < latent_ops.read(obs, "selected_pct") < 100
+    # what the per-layer metrics read is in an untraced run's notes too
+    notes = obs["notes"]
+    assert notes["dsa_rows"] == rows
+    assert notes["moe_steps"] == obs["moe_steps"] > 0
+    assert notes["moe_counts"] == obs["moe_counts"].tolist()
+    # this tiny size has no bucket the balancing could run on
+    assert notes["reference"]["router_balance"] is None
+    # the committed traffic samples its tokens: every request of the
+    # plan has a seed of its own, and the same --seed draws the same
+    plan = closed_loop.make(tiny_traffic(), tiny_config(), 2 ** 31 + 9, 0.4)
+    again = closed_loop.make(tiny_traffic(), tiny_config(), 2 ** 31 + 9, 0.4)
+    assert plan["sampling"] == again["sampling"]
+    assert plan["sampling"]["temperature"] == 1.0
+    assert np.asarray(plan["sampling"]["seeds"]).shape == (4, 2)
+    assert len(set(np.asarray(plan["sampling"]["seeds"]).ravel())) == 8
+    greedy = {k: v for k, v in tiny_traffic().items() if k != "sampling"}
+    same = closed_loop.make(greedy, tiny_config(), 2 ** 31 + 9, 0.4)
+    assert "sampling" not in same
+    for mine, theirs in zip(plan["clients"], same["clients"]):
+        for (p1, b1), (p2, b2) in zip(mine, theirs):
+            assert b1 == b2 and (p1 == p2).all()
+
+
+# ------------------------------------------------- the router's balancing
+
+BALANCE = dict(bucket=768, rounds=14, gamma=0.1, decay=0.8)
+
+
+def balanced_config():
+    """The tiny configuration with a bucket the prefill's grouped way
+    takes (more than 512 tokens: its per-expert counters are what the
+    balancing reads) and a schedule for widths at which one step of the
+    bias moves a load much less than at the cell's."""
+    cfg = tiny_config()
+    cfg["build"].update(prompt_len=768, prompt_buckets=[16, 768],
+                        page_size=16)
+    cfg["router_balance"] = dict(BALANCE)
+    return cfg
+
+
+def test_the_committed_schedule_fits_the_cell():
+    """The cell's own schedule: a bucket the cell serves and whose
+    prefill counts per expert (the grouped way: more than 512 tokens);
+    steps that can travel as far as a held expert's entry had to on the
+    chip (0.038 at most over eight seeds, PERF.md PR 60) and end finer
+    than a tenth of the draw's spread; the traffic samples."""
+    cfg = committed()
+    rb, build = cfg["router_balance"], cfg["build"]
+    assert rb["bucket"] in build["prompt_buckets"] and rb["bucket"] > 512
+    assert 0 < rb["decay"] < 1 and rb["rounds"] >= 6
+    steps = [rb["gamma"] * rb["decay"] ** i for i in range(rb["rounds"])]
+    assert sum(steps) >= 0.05 and steps[-1] <= 0.002
+    assert build["router_bias"] and "router_balance" in cfg["assumed"]["router"]
+    assert committed_traffic()["sampling"] == {"temperature": 1.0,
+                                               "top_k": 0}
+
+
+def held_loads(engine, cfg, tokens):
+    """[expert layers, held]: the held experts' tokens of one dispatch
+    of the served prefill view over ``tokens``, nothing written."""
+    p_len = len(tokens)
+    cb = engine._cb_prefill[p_len]
+    names = [op.inputs["Counts"][0]
+             for op in cb._program_desc.global_block.ops
+             if op.type == "expert_ffn_held" and op.inputs.get("Counts")]
+
+    def counts():
+        return np.stack([np.asarray(engine.scope.find_var(n))[0]
+                         for n in names]).astype(np.int64)
+    feeds = engine._prefill_feeds(p_len)
+    feeds["ids"][0, :, 0] = tokens
+    feeds["seq_len"][:] = p_len
+    before = counts()
+    engine._run(cb, (engine.PREFILL, p_len), feeds)
+    return counts() - before
+
+
+def router_biases(engine):
+    return {n: np.array(engine.scope.find_var(n))
+            for n in sorted(engine._cb_decode.sig.const_names)
+            if n.endswith(".router_bias")}
+
+
+@pytest.fixture(scope="module")
+def skewed_engines():
+    """Two seeds' engines whose drawn bias is made a bad draw (one held
+    expert favoured, one starved: the busiest held expert far over
+    twice the mean), each balanced; (engine, bias before, bias after,
+    what the balancing saw) by seed."""
+    import jax
+    from chipbench.runners import serve_glm5
+    cfg, dev, out = balanced_config(), jax.devices()[0], {}
+    for seed in (3, 2 ** 31 + 11):
+        engine = serve_glm5.build_engine(cfg, seed, dev)
+        engine.warmup()
+        for name, b in router_biases(engine).items():
+            b[0, 0] += 0.3
+            b[0, 1] -= 0.3
+            engine.scope.set_var(name, jax.device_put(b, dev))
+        before = router_biases(engine)
+        seen = serve_glm5.balance_router_bias(cfg, engine, seed, dev)
+        out[seed] = (engine, before, router_biases(engine), seen)
+    return cfg, out
+
+
+def test_balancing_evens_the_held_experts_load(skewed_engines):
+    """From a draw that leaves the busiest held expert over twice the
+    mean, the rule ends under 1.4 on tokens it has not seen, with the
+    held experts' mean load at the known mean tokens x top_k / experts
+    — so a step of T tokens hits the share 1 - (1 - k/E)^T of them that
+    a uniform router hits, within 3 points."""
+    cfg, engines = skewed_engines
+    build = cfg["build"]
+    k, n_e = build["n_experts_per_tok"], build["n_routed_experts"]
+    for seed, (engine, _before, _after, seen) in engines.items():
+        assert seen["max_over_mean_by_round"][0] > 2.0, seed
+        fresh = np.random.RandomState(seed % 1000 + 77).randint(
+            1, build["vocab"], 768)
+        load = held_loads(engine, cfg, fresh).astype(float)
+        assert (load.max(axis=1) / load.mean(axis=1)).mean() < 1.4, load
+        mean = 768 * k / n_e
+        assert abs(load.mean() / mean - 1) < 0.1, load
+        # a decode step's tokens (the cell's 32) spread by these loads
+        p = load / 768
+        hit = (1 - (1 - p) ** 32).mean()
+        assert abs(hit - (1 - (1 - k / n_e) ** 32)) < 0.03, hit
+
+
+def test_balancing_is_the_seeds_and_leaves_the_absent_experts(skewed_engines):
+    import jax
+    from chipbench.runners import serve_glm5
+    cfg, engines = skewed_engines
+    held = cfg["build"]["n_experts_held"]
+    for seed, (engine, before, after, seen) in engines.items():
+        assert seen["absent_bias_moved"] == 0.0
+        for name in before:
+            assert (before[name][0, held:] == after[name][0, held:]).all()
+            assert (before[name][0, :held] != after[name][0, :held]).any()
+        # the same seed from the same start: the same bias to the bit
+        for name, b in before.items():
+            engine.scope.set_var(name, jax.device_put(b, jax.devices()[0]))
+        again = serve_glm5.balance_router_bias(cfg, engine, seed,
+                                               jax.devices()[0])
+        assert again == seen
+        for name, b in router_biases(engine).items():
+            assert (b == after[name]).all(), name
+
+
+def test_check_reads_the_balanced_bias_on_a_fresh_engine(skewed_engines):
+    """After the balancing the engine is as warm-up left it — no slot
+    live, every page free, the decode view's expert counters at zero —
+    and the check passes: program and reference read the one bias (the
+    reference takes it from the scope the window is served from)."""
+    from chipbench.runners import serve_glm5
+    cfg, engines = skewed_engines
+    seed, (engine, _before, after, _seen) = next(iter(engines.items()))
+    assert engine.active_count() == 0
+    assert engine.free_pages() == engine.n_pages
+    counted = engine.expert_token_counts(sync=True)
+    assert counted["steps"] == 0 and not counted["counts"].any()
+    cfg = dict(cfg, check={**cfg["check"], "prompt_lens": [9, 700, 14]})
+    correct, seen, _served = serve_glm5.compare_with_reference(
+        cfg, engine, np.random.RandomState(seed + 1))
+    assert correct, seen
+    for name, b in router_biases(engine).items():
+        assert (b == after[name]).all(), name
+    # and a reference that read the DRAWN bias would not agree: the
+    # skewed entries pick other experts
+    ref = importlib.import_module("chipbench.reference." + cfg["reference"])
+    assert any(n.endswith(".router_bias")
+               for n in ref.param_names(cfg["build"], serve_glm5.MODEL))
 
 
 # ----------------------------------------------------------- the readers
@@ -208,143 +384,178 @@ BUILD = dict(n_slots=4, n_layer=1, layer_kinds=["mla"], n_head=4,
              prompt_len=128, max_new=128, kv_lora_rank=16,
              qk_rope_head_dim=8, index_n_heads=2, index_head_dim=16,
              index_topk=8)
-SPAN = latent_ops.SPAN
 MS = 1e6       # nanoseconds
-OPS = [("gather_pages.3 custom-call bf16[1024,16] tpu_custom_call ", 0.0, 0.5),
-       ("fusion.65 fusion f32[4,2,128] ", 1.0, 0.25),
-       ("sort.1 sort f32[4,256] ", 2.0, 1.25),
-       ("fusion.7 fusion f32[4,256] ", 3.5, 0.5),          # not counted
-       ("gather_pages.4 custom-call bf16[1024,128] tpu_custom_call ",
-        4.0, 0.5),                                         # another plane
-       ("fusion.11 fusion bf16[32,128] ", 5.0, 0.5),
-       ("fusion.100 fusion f32[4,4,8] ", 6.0, 0.25),
-       ("fusion.101 fusion bf16[4,4,8] ", 6.5, 0.125),
-       ("fusion.102 fusion f32[4,4,128] ", 7.0, 0.125),
-       ("fusion.99 fusion f32[4,64] ", 8.0, 1.0)]
+MODULE = "jit_lm_decode_paged_sf6d7"
+# (scope, instruction stem, opcode and shape, ms a step, instances)
+GROUPS = [
+    ("mla_decode_paged/index", "gather_pages",
+     "custom-call bf16[1024,16] tpu_custom_call", 0.5, 1),
+    ("mla_decode_paged/index", "fusion", "fusion f32[4,2,128]", 0.25, 1),
+    ("mla_decode_paged/select", "sort", "sort f32[4,256]", 1.25, 1),
+    ("mla_decode_paged/project", "fusion", "fusion f32[4,256]", 0.5, 1),
+    # another plane's gather, by the same kernel: the attention's
+    ("mla_decode_paged/attend", "gather_pages",
+     "custom-call bf16[1024,128] tpu_custom_call", 0.5, 1),
+    ("mla_decode_paged/attend", "fusion", "fusion bf16[32,128]", 0.5, 1),
+    ("mla_decode_paged/attend/scores", "fusion", "fusion f32[4,4,8]",
+     0.25, 2),
+    ("expert_ffn_held/up", "fusion", "fusion f32[4,64]", 1.0, 1),
+    ("", "copy", "copy s32[2,4]", 0.125, 1)]
 
 
-def recorded(ops):
-    """Two decode executions of 10 ms, each under a
-    ``serving.decode_step`` span, holding ``ops`` (name, offset ms,
-    duration ms); a prefill execution between them holds the same ops
-    and must not be counted."""
-    events, modules, spans = [], [], []
-    for start, span in ((10, SPAN), (30, "serving.prefill@8"), (50, SPAN)):
-        modules.append(["jit_fn(1)", start * MS, 10 * MS])
-        spans.append((span, (start - 1) * MS, (start + 10) * MS))
-        events += [[name, (start + off) * MS, dur * MS]
-                   for name, off, dur in ops]
+def observations(monkeypatch, groups=GROUPS, executions=2, counted=None,
+                 module=MODULE, scopes="map", **extra):
+    """``groups`` laid out as ``executions`` decode executions back to
+    back, each followed by a ``jit_copy`` execution (the engine's
+    snapshot of the expert counters: no step) and one prefill execution
+    holding the same ops; the program's map of the module is the
+    groups' own scopes (``scopes="map"``) or none at all. The window
+    counted ``counted`` steps with ``rows`` a step."""
+    events, modules, table, at = [], [], {module: {}}, 1.0
+    for run in range(executions + 1):
+        start, number = at, 0
+        prefill = run == executions
+        for scope, stem, what, ms, count in groups:
+            for i in range(count):
+                name = f"{stem}.{number}"
+                if not prefill:
+                    table[module][name] = scope
+                events.append([f"{name} {what} ", at * MS, ms / count * MS])
+                at += ms / count
+                number += 1
+        modules.append([("jit_lm_prefill_paged_32_s5dba(9)" if prefill
+                         else f"{module}(17)"), start * MS,
+                        (at - start) * MS])
+        events.append(["copy.1 copy s32[2,4] ", at * MS, 0.001 * MS])
+        modules.append(["jit_copy(5)", at * MS, 0.001 * MS])
+        at += 0.5
     trace = {"planes": [{"name": "/device:TPU:0", "lines": [
         {"name": tr.OPS_LINE, "events": events},
         {"name": tr.MODULES_LINE, "events": modules}]}]}
-    return tr.reduce_window(trace, 0.0, 70 * MS, spans)
-
-
-def observations(ops=OPS, **extra):
-    return {"reduced": recorded(ops), "config": {"build": dict(BUILD)},
-            # the window counted 4 steps; the trace holds 2 whole ones
-            "units": {"decode_steps": 4},
-            "dsa_rows": {"scored": 4 * 400.0, "selected": 4 * 32.0},
+    counted = counted or executions
+    monkeypatch.setattr(scope_ms, "program_scopes", lambda: {
+        "map": (table, {"seconds": 0.1}), "none": (None, None)}[scopes])
+    return {"reduced": tr.reduce_window(trace, 0.0, (at + 1.0) * MS, []),
+            "config": {"name": "not-a-cell", "build": dict(BUILD)},
+            "traffic": {}, "units": {"decode_steps": counted},
+            "dsa_rows": {"scored": counted * 400.0,
+                         "selected": counted * 32.0},
             "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
             **extra}
 
 
-def test_readers_select_by_name_and_shape():
-    obs = observations()
+def test_readers_select_by_scope(monkeypatch):
+    obs = observations(monkeypatch)
     assert latent_ops.latent_width(BUILD) == 128
-    # the index plane's gather (by name AND shape), the scoring, the sort
-    assert latent_ops.read(obs, "index", "ms") == pytest.approx(2.0)
-    # the selected rows' gather, scores, probabilities, attended latent
-    assert latent_ops.read(obs, "sparse", "ms") == pytest.approx(1.0)
+    # whatever lies under the index scope: the plane's gather and the
+    # scoring — not the selection, not the other plane's gather
+    assert latent_ops.read(obs, "index", "ms") == pytest.approx(0.75)
+    # by result shape, as since PR 33: the selected rows' gather and the
+    # scores — not the other ops of the attend scope (the page table's
+    # copy, the attention's own plane's gather)
+    assert latent_ops.read(obs, "sparse", "ms") == pytest.approx(0.75)
     assert latent_ops.read(obs, "selected_pct") == pytest.approx(8.0)
-    # two of the window's four steps are in the trace: half the rows
-    scored, selected = 2 * 400.0, 2 * 32.0
-    bytes_ = flops_latent.index_bytes(scored, 16, 2)
+    # the window's rows over the window's steps' time
+    bytes_ = flops_latent.index_bytes(2 * 400.0, 16, 2)
     assert latent_ops.read(obs, "index", "roofline") == pytest.approx(
-        100 * bytes_ / 819e9 / 4e-3)
-    bytes_ = flops_latent.sparse_bytes(selected, 16, 8, 2)
+        100 * bytes_ / 819e9 / 1.5e-3)
+    bytes_ = flops_latent.sparse_bytes(2 * 32.0, 16, 8, 2)
     assert latent_ops.read(obs, "sparse", "roofline") == pytest.approx(
-        100 * bytes_ / 819e9 / 2e-3)
-    # a step is one run of the index plane's gather a layer: a helper's
-    # tiny execution under the same span is no step
-    red = obs["reduced"]
-    dev = next(iter(red["modules"]))
-    red["modules"][dev].append(["jit_copy(2)", 15 * MS, 0.001 * MS])
-    assert latent_ops.read(obs, "index", "ms") == pytest.approx(2.0)
-    renamed = observations([("fusion.3 fusion bf16[1024,16] ", 0.0, 0.5)])
-    assert latent_ops.read(renamed, "index", "ms") is None
+        100 * bytes_ / 819e9 / 1.5e-3)
+    # two of the window's four counted steps lie whole in the trace: a
+    # step is an execution, and half the counters' rows are theirs
+    half = observations(monkeypatch, counted=4)
+    assert half["dsa_rows"]["scored"] == 4 * 400.0
+    for group, what in (("index", "ms"), ("index", "roofline"),
+                        ("sparse", "ms"), ("sparse", "roofline")):
+        assert latent_ops.read(half, group, what) == pytest.approx(
+            latent_ops.read(obs, group, what)), (group, what)
+    # the same work whatever implements it: a program that scores the
+    # plane in place (no ``gather_pages`` kernel, other shapes) is read
+    # as before, and its share is the same bytes over the shorter time
+    in_place = [("mla_decode_paged/index", "score_pages",
+                 "custom-call f32[4,256] tpu_custom_call", 0.25, 1)] \
+        + [g for g in GROUPS if g[0] != "mla_decode_paged/index"]
+    obs = observations(monkeypatch, in_place)
+    assert latent_ops.read(obs, "index", "ms") == pytest.approx(0.25)
+    assert latent_ops.read(obs, "index", "roofline") == pytest.approx(
+        100 * flops_latent.index_bytes(800.0, 16, 2) / 819e9 / 0.5e-3)
+    assert latent_ops.read(obs, "sparse", "ms") == pytest.approx(0.75)
 
 
-def test_readers_find_nothing_where_there_is_nothing():
+def test_readers_find_nothing_where_there_is_nothing(monkeypatch):
     """A configuration without latent layers, a program without the
-    counters (the parent), a window without decode steps: None."""
-    obs = observations()
+    counters (the parent) or without device scopes, a window without
+    decode steps, a scope under which nothing ran: None, never 0."""
+    obs = observations(monkeypatch)
     del obs["config"]["build"]["index_topk"]
     assert latent_ops.read(obs, "index", "ms") is None
     assert latent_ops.read(obs, "selected_pct") is None
     for rows in (None, {}, {"scored": 0, "selected": 0}):
-        obs = observations(dsa_rows=rows)
+        obs = observations(monkeypatch, dsa_rows=rows)
         assert latent_ops.read(obs, "sparse", "roofline") is None
         assert latent_ops.read(obs, "selected_pct") is None
-    obs = observations()
+    obs = observations(monkeypatch)
     del obs["dsa_rows"]
     assert latent_ops.read(obs, "index", "roofline") is None
-    obs = observations([])
+    obs = observations(monkeypatch, units={"decode_steps": 0})
     assert latent_ops.read(obs, "index", "ms") is None
-    obs["reduced"]["host_spans"] = []
+    obs = observations(monkeypatch, [g for g in GROUPS if "index" not in g[0]])
+    assert latent_ops.read(obs, "index", "ms") is None
+    assert latent_ops.read(obs, "index", "roofline") is None
+    assert latent_ops.read(obs, "sparse", "ms") == pytest.approx(0.75)
+    obs = observations(monkeypatch, [g for g in GROUPS
+                                     if "attend" not in g[0]])
+    assert latent_ops.read(obs, "sparse", "ms") is None
+    assert latent_ops.read(obs, "sparse", "roofline") is None
+    # no device scopes: nothing by scope; the shapes need no map
+    obs = observations(monkeypatch, scopes="none")
+    assert latent_ops.read(obs, "index", "ms") is None
+    assert latent_ops.read(obs, "index", "roofline") is None
+    assert latent_ops.read(obs, "sparse", "ms") == pytest.approx(0.75)
+    obs = observations(monkeypatch, module="jit_something_else")
     assert latent_ops.read(obs, "sparse", "ms") is None
 
 
-def recorded_step_observations():
-    """``tests/chipbench/data/glm5_decode_trace.json`` laid out as two
-    decode steps under their spans, each group as its instances."""
+def recorded_step():
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "data", "glm5_decode_trace.json")) as f:
-        rec = json.load(f)
-    ops, at = [], 0.0
-    for name, ms, instances in rec["groups"]:
-        for _ in range(max(instances, 1)):
-            ops.append((name, at, ms / max(instances, 1)))
-            at += ms / max(instances, 1)
-    events, modules, spans = [], [], []
-    for start in (1.0, 2.0 + at):
-        modules.append(["jit_fn(1)", start * MS, at * MS])
-        spans.append((SPAN, (start - 0.5) * MS, (start + at) * MS))
-        events += [[n, (start + off) * MS, d * MS] for n, off, d in ops]
-    trace = {"planes": [{"name": "/device:TPU:0", "lines": [
-        {"name": tr.OPS_LINE, "events": events},
-        {"name": tr.MODULES_LINE, "events": modules}]}]}
-    obs = {"reduced": tr.reduce_window(trace, 0.0, (3.0 + 2 * at) * MS,
-                                       spans),
-           "config": committed(), "units": {"decode_steps": 2},
-           "dsa_rows": {k: 2 * v for k, v in rec["rows_per_step"].items()},
-           "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}}
-    return rec, obs
+                           "data", "glm5_decode_scopes.json")) as f:
+        return json.load(f)
 
 
-def test_readers_on_the_recorded_trace():
+def test_readers_on_the_recorded_step(monkeypatch):
     """The op groups of one decode step of the cell as the chip ran it
-    (my chip run, PR 33): the readers find what PERF.md section 5 lists
-    under their names, and both shares of the roofline are under 100."""
-    rec, obs = recorded_step_observations()
-    want = rec["readings"]
-    assert latent_ops.read(obs, "index", "ms") == pytest.approx(
-        want["dsa_index_ms_per_step"], rel=1e-3)
-    assert latent_ops.read(obs, "sparse", "ms") == pytest.approx(
-        want["mla_sparse_ms_per_step"], rel=1e-3)
-    for group, name in (("index", "dsa_index_roofline"),
-                        ("sparse", "mla_sparse_roofline")):
-        share = latent_ops.read(obs, group, "roofline")
-        assert share == pytest.approx(want[name], rel=1e-3)
-        assert 0 < share < 100
+    (my chip run, PR 60), under the scopes the program gave them: the
+    indexer's reader finds by scope what the run itself read, within a
+    fifth of what the reader of PRs 33-59 found by kernel name and
+    result shape; the sparse attention's reader finds by shape what that
+    reader found; both shares of the roofline are under 100."""
+    rec = recorded_step()
+    obs = observations(
+        monkeypatch, [tuple(g[:4]) + (max(int(round(g[4])), 1),)
+                      for g in rec["groups"]], module=rec["module"],
+        dsa_rows={k: 2 * v for k, v in rec["rows_per_step"].items()})
+    obs["config"] = committed()
+    by_scope, by_shape = rec["readings"], rec["by_shape"]
+    for group, what, name, want in (
+            ("index", "ms", "dsa_index_ms_per_step", by_scope),
+            ("index", "roofline", "dsa_index_roofline", by_scope),
+            ("sparse", "ms", "mla_sparse_ms_per_step", by_shape),
+            ("sparse", "roofline", "mla_sparse_roofline", by_shape)):
+        assert latent_ops.read(obs, group, what) == pytest.approx(
+            want[name], rel=2e-3), name
+    for group in ("index", "sparse"):
+        assert 0 < latent_ops.read(obs, group, "roofline") < 100
     assert latent_ops.read(obs, "selected_pct") == pytest.approx(
-        want["dsa_selected_pct.decode"], rel=1e-3)
-    # by hand from the file: the gather, the scoring and the sort
-    by_name = {n.strip(): ms for n, ms, _i in rec["groups"]}
-    assert want["dsa_index_ms_per_step"] == pytest.approx(
-        by_name["gather_pages custom-call bf16[393216,128] tpu_custom_call"]
-        + by_name["fusion fusion f32[32,96,128]"]
-        + by_name["sort sort f32[32,12288]"], rel=1e-3)
+        by_scope["dsa_selected_pct.decode"], rel=1e-3)
+    # by hand from the file, as the older reader chose them: the index
+    # plane's gather and the scoring
+    ms = {(g[1], g[2]): g[3] for g in rec["groups"]}
+    assert by_shape["dsa_index_ms_per_step"] == pytest.approx(
+        ms["gather_pages", "custom-call bf16[393216,128] tpu_custom_call"]
+        + ms["fusion", "fusion f32[32,96,128]"], rel=1e-3)
+    for name in ("dsa_index_ms_per_step", "dsa_index_roofline"):
+        assert abs(by_scope[name] / by_shape[name] - 1) < 0.2, name
 
 
 def test_every_new_metric_has_its_reader_file():

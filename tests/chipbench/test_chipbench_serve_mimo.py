@@ -315,7 +315,9 @@ def test_tiny_mimo_cell_agrees_with_the_reference(tiny_run):
     # live rows a slot; every step gathers 4 slots x 48 rows a full layer
     assert 0 < obs["window_rows"] <= 5 * 8 * 4 * (steps + 2)
     assert 0 < obs["full_rows"] <= 2 * 48 * 4 * (steps + 2)
-    assert 2 * 4 * 48 * steps <= obs["full_rows_gathered"] \
+    # (the two counters are read one after the other at a window's edge:
+    # under load a step ends between the readings, either way)
+    assert 2 * 4 * 48 * (steps - 2) <= obs["full_rows_gathered"] \
         <= 2 * 4 * 48 * (steps + 2)
     assert obs["notes"]["kv_row_bytes"] == {
         "full": 2 * 2 * (24 + 16) * 4, "window": 5 * 4 * (24 + 16) * 4}
