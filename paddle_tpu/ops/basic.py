@@ -67,6 +67,7 @@ def _gaussian_random(ctx, ins, attrs):
     return single(x.astype(dtype))
 
 
+@jax.jit(static_argnums=(0, 1, 2))
 def hash_normal(shape, dtype, std, seed, salt):
     """Normal(0, std) values of ``shape`` in ``dtype``, each a pure
     function of (its flat index, ``seed``, ``salt``): two murmur-mixed
@@ -75,7 +76,15 @@ def hash_normal(shape, dtype, std, seed, salt):
     no memory beside its outputs (``jax.random.normal`` keeps a
     threefry bit buffer per tensor: 10.5 GB of temporaries beside 9.4 GB
     of outputs for the hybrid model's start-up). ``seed`` and ``salt``
-    are uint32 scalars, traced or not; at most 2**32 elements."""
+    are uint32 scalars, traced or not; at most 2**32 elements.
+
+    Jitted with ``shape``, ``dtype`` and ``std`` static (hashable: a
+    tuple, a dtype's name, a float), so that this body is traced once a
+    (shape, dtype, std) a process, by JAX's own trace cache: shape
+    inference and the start-up's lowering of a family's every view find
+    the draw of each later parameter of a signature already traced. A
+    nested ``jit`` is inlined before XLA fuses, so a start-up still
+    writes each draw straight into its result."""
     n = 1
     for d in shape:
         n *= int(d)

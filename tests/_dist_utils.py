@@ -16,6 +16,26 @@ from paddle_tpu.utils.net import PortReservation, bound_listener  # noqa: F401
 
 
 
+def stop_pserver(ps, timeout: float = 5.0) -> None:
+    """``ps.stop()``, and see the threads it started END. Closing a
+    listening socket does not wake a thread that sits in ``accept()`` on
+    it (Linux keeps the socket for the call), so ``stop()`` alone leaves
+    ``accept_loop`` alive in the test's process for good: a connection
+    that says nothing is made first — the handshake fails on its EOF and
+    the loop, told to stop, ends."""
+    import socket
+    ps._stopping.set()
+    try:
+        socket.create_connection(ps._listener.address, timeout).close()
+    except (OSError, AttributeError):
+        pass            # never served, or closed already: nothing to wake
+    ps.stop()
+    for t in ps._threads:
+        t.join(timeout)
+    alive = [t.name for t in ps._threads if t.is_alive()]
+    assert not alive, f"pserver threads outlive stop(): {alive}"
+
+
 def build_deepfm_small(is_train: bool = True):
     """Deterministic names (unique_name.guard) + fixed seed: trainer,
     pserver, and eval processes all rebuild this exact graph."""

@@ -674,6 +674,47 @@ def test_hybrid_decode_step_compiles_for_v5e(chip, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 5
 
 
+def test_hybrid_startup_writes_every_draw_into_its_result_for_v5e(chip):
+    """The start-up of the benchmark's hybrid configuration (65 matrices
+    drawn by ``hash_normal_random``, 9.36 GB of parameters, pools and
+    state) for a described v5e: ``hash_normal`` is jitted, so the module
+    calls ONE private function a (shape, dtype, std) and not a body a
+    parameter — and XLA still inlines each call before it fuses: the
+    program keeps under a megabyte beside its outputs (the bit buffers of
+    ``jax.random.normal`` were 10.5 GB beside them)."""
+    import json
+    import os
+    import re
+    from paddle_tpu.core.lowering import CompiledBlock
+    from paddle_tpu.models import transformer as T
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "solar_open2_250b_ep8_d4.json")) as f:
+        cfg = json.load(f)
+    build = cfg["build"]
+    programs = T.build_decoder_lm_programs(
+        name="lm", modes=("decode_paged",), kv_codec=cfg["kv_codec"],
+        **{**build, "prompt_buckets": tuple(build["prompt_buckets"]),
+           "layer_kinds": tuple(build["layer_kinds"])})
+    startup = programs["decode_paged"][1]
+    draws = [op for op in startup.desc.global_block.ops
+             if op.type == "hash_normal_random"]
+    signatures = {(tuple(op.attrs["shape"]), op.attrs["dtype"],
+                   op.attrs["std"]) for op in draws}
+    assert len(draws) == 65 and len(signatures) < len(draws) // 3
+    cb = CompiledBlock(startup.desc, 0, [], [], is_test=False)
+    lowered = cb.fn.lower({}, {}, {}, jax.ShapeDtypeStruct(
+        (), jnp.uint32, sharding=chip))
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @hash_normal", text)) \
+        == len(signatures)
+    assert len(re.findall(r"call @hash_normal", text)) == len(draws)
+    mem = lowered.compile().memory_analysis()
+    assert mem.output_size_in_bytes > 9.3e9
+    assert mem.temp_size_in_bytes < 1e6
+
+
 # ---------------------------------------------------------------------------
 # token_sample (PR 32): what a greedy batch pays for, as the chip
 # compiles it
@@ -808,7 +849,9 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
     eng, programs = glm5_engine
     if path == "rows":
         monkeypatch.setattr(mla, "ATTEND_PAGES_MAX_RATIO", 4)
-        jax.clear_caches()          # the view was traced under the other
+        # the view was traced under the other ratio, and ``lower`` would
+        # find that trace: drop this one function's, not the worker's
+        eng._cb_decode.fn.clear_cache()
     lowered = {p: mla.MLA_DECODE_LOWERED.labels(path=p).value
                for p in ("pages", "rows")}
     experts = {p: expert_ffn.EXPERT_DENSE_LOWERED.labels(path=p).value

@@ -18,6 +18,7 @@ from paddle_tpu.distributed import AsyncPServer, AsyncTrainerClient
 from paddle_tpu.fluid.transpiler import DistributeTranspiler
 from paddle_tpu import models
 from _dist_utils import bound_listener as _bound_listener
+from _dist_utils import stop_pserver
 
 
 def _build_deepfm(seed=3):
@@ -107,7 +108,7 @@ def test_deepfm_two_process_async_converges():
         for w in workers:
             if w.poll() is None:
                 w.kill()
-        ps.stop()
+        stop_pserver(ps)
     assert ps.n_applied >= 2 * steps * len(t.send_vars) * 0.9
 
     # evaluate the async-trained params vs a synchronous baseline
@@ -243,4 +244,4 @@ def test_dc_asgd_over_the_wire_trainer_id():
         c1.stop_server()
         c1.close()
     finally:
-        ps.stop()
+        stop_pserver(ps)

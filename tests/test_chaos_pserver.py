@@ -20,6 +20,7 @@ from paddle_tpu.distributed.resilience import (CircuitBreaker,
 from paddle_tpu.fluid.transpiler import DistributeTranspiler
 from paddle_tpu.utils import faults
 from _dist_utils import bound_listener as _bound_listener
+from _dist_utils import stop_pserver
 
 pytestmark = pytest.mark.chaos
 
@@ -82,7 +83,7 @@ def test_push_retried_through_connect_fault_applies_exactly_once():
                                    rtol=1e-6)
         c.close()
     finally:
-        ps.stop()
+        stop_pserver(ps)
 
 
 def test_pull_retried_through_transient_fault():
@@ -97,7 +98,7 @@ def test_pull_retried_through_transient_fault():
         assert pname in params
         c.close()
     finally:
-        ps.stop()
+        stop_pserver(ps)
 
 
 def test_breaker_fast_fails_a_dead_pserver():
@@ -131,4 +132,4 @@ def test_breaker_fast_fails_a_dead_pserver():
         assert resilience.BREAKER_STATE.labels(name="chaos-ps").value == 2
         c.close()
     finally:
-        ps.stop()
+        stop_pserver(ps)

@@ -23,6 +23,7 @@ from paddle_tpu.observability import tracing
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.runners import serve_glm5, serve_hybrid  # noqa: E402
 
 
@@ -63,12 +64,11 @@ def family_engine(family):
         return gpt2_engine()
     cfg = dict(kv_layout="paged", kv_codec="none")
     if family == "hybrid":
-        return serve_hybrid.build_engine(
-            {**cfg, "build": HYBRID,
-             "reference": "solar_open2_250b_ep8_d4"}, 5, jax.devices()[0])
-    return serve_glm5.build_engine(
-        {**cfg, "build": LATENT, "reference": "glm5_744b_ep16_d5"}, 5,
-        jax.devices()[0])
+        return families.Family(serve_hybrid, {
+            **cfg, "build": HYBRID,
+            "reference": "solar_open2_250b_ep8_d4"}).shared()
+    return families.Family(serve_glm5, {
+        **cfg, "build": LATENT, "reference": "glm5_744b_ep16_d5"}).shared()
 
 
 def train_program():

@@ -28,6 +28,7 @@ from _dist_utils import eval_deepfm_loss as _eval_loss
 from _dist_utils import noisy_deepfm_labels as _noisy_labels
 from _dist_utils import PortReservation as _PortReservation
 from _dist_utils import bound_listener as _bound_listener
+from _dist_utils import stop_pserver as _stop_pserver
 
 TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
 REPO_ROOT = os.path.dirname(TESTS_DIR)
@@ -104,7 +105,7 @@ def _run_pserver_mode(dc_asgd, steps=40, nprocs=2):
             scope.set_var(n, np.asarray(ps.scope.find_var(n)))
         return results, _eval_loss(scope)
     finally:
-        ps.stop()
+        _stop_pserver(ps)
 
 
 def _untrained_eval_deepfm() -> float:

@@ -26,7 +26,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu import recordio
-from _dist_utils import build_deepfm_small, bound_listener, eval_deepfm_loss
+from _dist_utils import (build_deepfm_small, bound_listener,
+                         eval_deepfm_loss, stop_pserver)
 from paddle_tpu.core import native
 from paddle_tpu.data.master import Master
 from paddle_tpu.data.master_service import MASTER_ENV, MasterServer
@@ -134,7 +135,7 @@ def test_edl_master_plus_pserver_with_trainer_death(tmp_path):
             if w.poll() is None:
                 w.kill()
         srv.stop()
-        ps.stop()
+        stop_pserver(ps)
 
     # exactly-once data plane: survivors completed every chunk except
     # those the victim landed before dying (0 or 1 — its first finish is

@@ -107,3 +107,54 @@ def test_persistable_state_updates():
     exe.run(main, feed={"x": xv}, fetch_list=[])
     (wv,) = exe.run(main, feed={"x": xv}, fetch_list=["w_state"])
     np.testing.assert_allclose(wv, np.full((1, 2), 4.0), rtol=1e-6)
+
+
+@pytest.mark.parametrize("shape,dtype,std", [
+    ((24, 16), "bfloat16", 0.02),       # a stored dtype narrower than the draw
+    ((1,), "float32", 1.0),             # a single element
+    ((300, 256), "float32", 0.5)])      # past 2**16 elements
+def test_the_jitted_draw_is_the_bare_functions_bit_for_bit(shape, dtype, std):
+    """``hash_normal`` sits behind one ``jax.jit`` (shape, dtype and std
+    static) so that its body is traced once a signature: the values are
+    what the bare function gives."""
+    from paddle_tpu.ops.basic import hash_normal
+    for seed, salt in ((0, 1), (0x9E3779B9, 0xFFFFFFFF)):
+        seed, salt = np.uint32(seed), np.uint32(salt)
+        got = hash_normal(shape, dtype, std, seed, salt)
+        want = hash_normal.__wrapped__(shape, dtype, std, seed, salt)
+        assert got.shape == shape and got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        assert np.asarray(got, np.float32).std() > 0 or shape == (1,)
+
+
+def test_a_second_parameter_of_a_seen_shape_traces_no_draw(monkeypatch):
+    """Eight parameters of one (shape, dtype, std): the draw's Python
+    body runs for the first one's shape inference and never again — not
+    for the other seven, not when the start-up program is lowered — and
+    each parameter is still its own draw (the op's key salts it)."""
+    import jax
+    from paddle_tpu.fluid.initializer import HashNormalInitializer
+    from paddle_tpu.ops.basic import hash_normal
+    bodies, real = [], jax.lax.iota
+
+    def counted(*args, **kwargs):       # the body's first call
+        bodies.append(args)
+        return real(*args, **kwargs)
+    monkeypatch.setattr(jax.lax, "iota", counted)
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 3
+    with fluid.program_guard(main, startup):
+        for i in range(8):
+            # a signature no other test of the worker draws
+            layers.create_parameter(
+                [13, 21], "float32", name=f"w{i}",
+                default_initializer=HashNormalInitializer(0.0371))
+            assert len(bodies) == 1, i
+    size = hash_normal._cache_size()
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    assert len(bodies) == 1 and hash_normal._cache_size() == size
+    drawn = [np.asarray(scope.find_var(f"w{i}")) for i in range(8)]
+    assert all(w.shape == (13, 21) and 0.02 < w.std() < 0.06 for w in drawn)
+    assert len({w.tobytes() for w in drawn}) == 8

@@ -158,14 +158,14 @@ def _served(forced, monkeypatch):
     """Three requests decoded together by a fresh engine whose programs
     are traced with the kernel forced on (interpreted) or off: (tokens,
     logits, expert layers lowered under ``skip``, under ``all``)."""
+    import families
     from chipbench.runners import serve_hybrid
     cfg = dict(build=BUILD, kv_layout="paged", kv_codec="none")
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", forced)
     count = lambda: {p: expert_ffn.EXPERT_DENSE_LOWERED.labels(  # noqa: E731
         path=p).value for p in ("skip", "all")}
     before = count()
-    engine = serve_hybrid.build_engine(cfg, 7, jax.devices()[0])
-    engine.warmup()
+    engine = families.Family(serve_hybrid, cfg).fresh(seed=7)
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, BUILD["vocab"], n) for n in (5, 11, 2)]
     served = serve_hybrid.serve_together(

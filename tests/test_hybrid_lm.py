@@ -24,6 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.reference import solar_open2_250b_ep8_d4 as ref  # noqa: E402
 from chipbench.runners import serve_hybrid  # noqa: E402
 from paddle_tpu import serving  # noqa: E402
@@ -44,11 +45,7 @@ CFG = dict(build=BUILD, kv_layout="paged", kv_codec="none",
            reference="solar_open2_250b_ep8_d4")
 
 
-def make_engine(seed=5, **changes):
-    cfg = {**CFG, "build": {**BUILD, **changes}}
-    engine = serve_hybrid.build_engine(cfg, seed, jax.devices()[0])
-    engine.warmup()
-    return engine
+FAMILY = families.Family(serve_hybrid, CFG, ref, serve_hybrid.LogitProbe)
 
 
 @pytest.fixture(params=["dense", "grouped"])
@@ -63,30 +60,18 @@ def way(request, monkeypatch):
 
 @pytest.fixture(scope="module", params=["dense", "grouped"])
 def engine(request):
-    # the programs are traced and compiled inside warmup(), under the
-    # threshold set here; later dispatches reuse the executables
-    old = expert_ffn.DENSE_MAX_TOKENS
-    if request.param == "grouped":
-        expert_ffn.DENSE_MAX_TOKENS = 0
-    try:
-        return make_engine()
-    finally:
-        expert_ffn.DENSE_MAX_TOKENS = old
+    return FAMILY.shared(
+        patches=families.GROUPED if request.param == "grouped" else ())
 
 
-def params_of(engine, build=BUILD):
-    return {n: engine.scope.find_var(n) for n in ref.param_names(build)}
-
-
-def worst(engine, prompt_len, max_new=10, seed=1):
+def worst(engine, prompt_len, max_new=10, seed=1, build=BUILD, params=None):
     """The largest relative error of the served logits and of the slot's
     recurrent state against the reference, over one request."""
-    prompt = np.random.RandomState(seed).randint(1, BUILD["vocab"],
-                                                 prompt_len)
-    toks, logits, states = serve_hybrid.serve_one(engine, prompt, max_new)
+    prompt, toks, logits, states = FAMILY.request(engine, prompt_len,
+                                                  max_new, seed)
     logit_err, state_err, margin, _slow = ref.compare(
-        params_of(engine), prompt, toks, logits, states, BUILD)
-    assert len(toks) == max_new
+        params or FAMILY.params_of(engine, build), prompt, toks, logits,
+        states, build)
     return max(logit_err.max(), state_err.max()), margin.max()
 
 
@@ -140,19 +125,24 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault):
     """The tolerance bites: the system with one fault in it (or, for the
     conv tap, the reference given conv weights with a tap zeroed) misses
     ``TOL`` by orders of magnitude."""
+    # one period of (gqa, kda, kda, kda) and the one bucket the prompt
+    # of 11 takes show the same miss as two periods and both buckets
+    # do; the reference is told the same
+    depth = dict(n_layer=len(BUILD["layer_kinds"]), prompt_buckets=[16])
+    build = FAMILY.build(**depth)
     if fault in FAULTS:
         module, attr, wrap = FAULTS[fault]
         monkeypatch.setattr(module, attr, wrap(getattr(module, attr)))
-    eng = make_engine()
-    prompt = np.random.RandomState(3).randint(1, BUILD["vocab"], 11)
-    toks, logits, states = serve_hybrid.serve_one(eng, prompt, 8)
-    params = params_of(eng)
+        eng = FAMILY.fresh(**depth)
+    else:
+        eng = FAMILY.shared(**depth)
+    params = FAMILY.params_of(eng, build)
     if fault == "dropped_conv_tap":
         for name in [n for n in params if n.endswith("kda.conv")]:
             params[name] = params[name].at[0].set(0.0)
-    logit_err, state_err, _, _ = ref.compare(params, prompt, toks, logits,
-                                             states, BUILD)
-    assert max(logit_err.max(), state_err.max()) > 100 * TOL
+    err, _margin = worst(eng, 11, max_new=8, seed=3, build=build,
+                         params=params)
+    assert err > 100 * TOL
 
 
 # ------------------------------------------------------ the expert layer
@@ -350,16 +340,23 @@ def test_the_grouped_rows_counter_reads_all_held_where_all_are(monkeypatch):
             model=engine.name, rows=r).value for r in ("given", "held")])
 
     for n_held, full in ((16, True), (4, False)):
-        engine = make_engine(n_experts_held=n_held)
+        # a quarter held and the grouped way is the module's own engine
+        engine = FAMILY.shared(patches=families.GROUPED,
+                               n_experts_held=n_held)
+        engine.reset()
         assert len(engine._grouped_vars) == BUILD["n_layer"]
         before = rows(engine)
         steps0 = engine.expert_token_counts()["counts"].sum()
-        for length in (8, 16, 16):
-            engine.admit(rng.randint(1, 96, length), max_new=2)
-        given, held = rows(engine) - before
+        try:
+            for length in (8, 16, 16):
+                engine.admit(rng.randint(1, 96, length), max_new=2)
+            given, held = rows(engine) - before
+            steps = engine.expert_token_counts()["counts"].sum()
+        finally:
+            engine.reset()
         assert given == (8 + 16 + 16) * 4 * BUILD["n_layer"]
         assert held == given if full else 0 < held < given
-        assert engine.expert_token_counts()["counts"].sum() == steps0
+        assert steps == steps0
 
 
 # ------------------------------------------------------------ the engine
@@ -381,7 +378,7 @@ def test_a_slots_state_is_overwritten_on_readmission(engine):
     assert slot == 0
     after_reuse = _states(engine, 0)
     engine.release(0)
-    fresh = make_engine()
+    fresh = FAMILY.fresh()
     slot, _tok, _done = fresh.admit(second, max_new=4)
     # to TOL and not to the bit: ``fresh`` takes the dense way through
     # the expert layer whichever way ``engine`` takes
@@ -424,7 +421,7 @@ def test_a_prefix_shared_admission_yields_the_same_state(engine):
 
 
 def test_warmup_leaves_every_slots_state_as_startup_left_it():
-    eng = make_engine()
+    eng = FAMILY.fresh()
     for n in eng.state_vars:
         assert not np.asarray(eng.scope.find_var(n)).any(), n
     assert eng.free_count() == eng.n_slots

@@ -25,16 +25,27 @@ BUILD = dict(
     dtype="float32", mtp_layers=1, mtp_weight=0.3, bias_update_gamma=1e-3)
 
 
-def trainer(seq_len, **over):
+def programs(seq_len, **over):
     build = {**BUILD, "seq_len": seq_len, **over}
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 1
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         loss, totals, _ = T.build_lm(**build)
+    return build, main, startup, loss, totals
+
+
+def started(exe, startup):
+    """A scope with the weights as start-up draws them (its seed is
+    fixed)."""
     scope = fluid.Scope()
-    exe = fluid.Executor(fluid.TPUPlace())
     exe.run(startup, scope=scope)
-    return build, main, loss, totals, scope, exe
+    return scope
+
+
+def trainer(seq_len, **over):
+    build, main, startup, loss, totals = programs(seq_len, **over)
+    exe = fluid.Executor(fluid.TPUPlace())
+    return build, main, loss, totals, started(exe, startup), exe
 
 
 def sample(build, batch=2, seed=0):
@@ -124,7 +135,9 @@ def test_the_shares_gradients_add_up_to_the_uncut_layers(n_tokens):
 
 
 def test_the_mtp_module_shifts_by_two_and_shares_head_and_table():
-    build, main, loss, _totals, scope, exe = trainer(32)
+    build, main, startup, loss, _totals = programs(32)
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = started(exe, startup)
     ops = main.global_block().ops
     head = [op for op in ops if op.type == "dense"
             and op.desc.input("W") == ["lm_head_w"]]
@@ -134,10 +147,11 @@ def test_the_mtp_module_shifts_by_two_and_shares_head_and_table():
     feed = sample(build)
 
     def run(feed):
-        # the trained program: a step moves the weights, so a fresh scope
-        _b, m, l, _t, s, e = trainer(32)
-        out = e.run(m, feed=feed, scope=s,
-                    fetch_list=[l.name, "lm_head_w@GRAD", "lm_emb@GRAD"])
+        # the trained program: a step moves the weights, so a fresh
+        # scope a run — of the same programs, compiled once
+        out = exe.run(main, feed=feed, scope=started(exe, startup),
+                      fetch_list=[loss.name, "lm_head_w@GRAD",
+                                  "lm_emb@GRAD"])
         return [np.asarray(o) for o in out]
     base = run(feed)
     # position T's MTP target is beyond the T - 1 positions of the mean

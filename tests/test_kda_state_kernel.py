@@ -321,15 +321,11 @@ def _served(monkeypatch, forced):
     size the kernel takes (one period: gqa, kda, kda, kda; 8 KDA heads of
     128), decoded together: tokens, each slot's states, and how the KDA
     layers of the decode view were lowered."""
-    from tests.test_hybrid_lm import BUILD, CFG
-    from chipbench.runners import serve_hybrid
+    from test_hybrid_lm import FAMILY
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1" if forced else "0")
     fam = kda.KDA_DECODE_LOWERED
     before = {p: fam.labels(path=p).value for p in ("kernel", "refer")}
-    cfg = {**CFG, "build": {**BUILD, "n_layer": 4, "kda_heads": 8,
-                            "kda_head_dim": 128}}
-    engine = serve_hybrid.build_engine(cfg, 5, jax.devices()[0])
-    engine.warmup()
+    engine = FAMILY.fresh(n_layer=4, kda_heads=8, kda_head_dim=128)
     grew = {p: fam.labels(path=p).value - before[p] for p in before}
     rng = np.random.RandomState(12)
     toks = {}

@@ -15,7 +15,6 @@ programs lower and the rows the engine counts follow from it.
 import os
 import sys
 
-import jax
 import numpy as np
 import pytest
 
@@ -23,6 +22,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.runners import serve_hybrid  # noqa: E402
 from paddle_tpu.ops import kv_attention as kv  # noqa: E402
 from paddle_tpu.serving import metrics as smetrics  # noqa: E402
@@ -61,8 +61,7 @@ def engines():
                 patch.setattr(kv, "_gather_tier",
                               lambda flat, scales, ps, mesh=None: "pages")
             before = lowered()
-            engine = serve_hybrid.build_engine(CFG, 11, jax.devices()[0])
-            engine.warmup()
+            engine = families.Family(serve_hybrid, CFG).fresh(seed=11)
             out[path] = (engine, serve_hybrid.LogitProbe(engine),
                          {p: n - before[p] for p, n in lowered().items()})
         yield out

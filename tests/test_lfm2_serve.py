@@ -25,13 +25,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.reference import lfm2_8b_a1b_d12 as ref  # noqa: E402
 from chipbench.runners import serve_hybrid, serve_lfm2  # noqa: E402
 from paddle_tpu import serving  # noqa: E402
 from paddle_tpu.analysis import contracts  # noqa: E402
 from paddle_tpu.core.registry import slot_state_vars  # noqa: E402
 from paddle_tpu.models import transformer as T  # noqa: E402
-from paddle_tpu.ops import expert_ffn, shortconv  # noqa: E402
+from paddle_tpu.ops import shortconv  # noqa: E402
 
 TOL = 2e-5
 # two periods of (conv, conv, gqa, conv), the first two layers dense;
@@ -57,47 +58,34 @@ CFG = dict(build=BUILD, kv_layout="paged", kv_codec="none",
 CONV_LAYERS = (0, 1, 3, 4, 5, 7)
 
 
-def make_engine(seed=5, **changes):
-    cfg = {**CFG, "build": {**BUILD, **changes}}
-    engine = serve_lfm2.build_engine(cfg, seed, jax.devices()[0])
+def _thirty_times_the_bias(engine, _build, _seed):
     # among 8 experts a bias of 0.01 seldom changes a pick: thirty times
     # the drawn one, so that picking by score + bias and by score differ
     for n in engine.scope.local_var_names():
         if n.endswith(".router_bias"):
             engine.scope.set_var(n, 30.0 * engine.scope.find_var(n))
-    engine.warmup()
-    return engine
+
+
+FAMILY = families.Family(serve_lfm2, CFG, ref, serve_lfm2.PicksProbe,
+                         prepare=_thirty_times_the_bias)
+params_of = FAMILY.params_of
 
 
 @pytest.fixture(scope="module", params=["dense", "grouped"])
 def engine(request):
-    # the programs are traced and compiled inside warmup(), under the
-    # threshold set here; later dispatches reuse the executables
-    old = expert_ffn.DENSE_MAX_TOKENS
-    if request.param == "grouped":
-        expert_ffn.DENSE_MAX_TOKENS = 0
-    try:
-        return make_engine()
-    finally:
-        expert_ffn.DENSE_MAX_TOKENS = old
-
-
-def params_of(engine, build=BUILD):
-    return {n: engine.scope.find_var(n) for n in ref.param_names(build)}
+    return FAMILY.shared(
+        patches=families.GROUPED if request.param == "grouped" else ())
 
 
 def worst(engine, prompt_len, max_new=10, seed=1, build=BUILD, forced=True,
           **ref_kwargs):
-    prompt = np.random.RandomState(seed).randint(1, build["vocab"],
-                                                 prompt_len)
-    toks, logits, windows, picks = serve_lfm2.serve_one(engine, prompt,
-                                                        max_new)
+    prompt, toks, logits, windows, picks = FAMILY.request(
+        engine, prompt_len, max_new, seed, build)
     assert picks.shape == (sum(i >= build["first_k_dense"] for i in range(
         build["n_layer"])), prompt_len + max_new - 1, 4)
     logit_err, window_err, margin, gaps = ref.compare(
         params_of(engine, build), prompt, toks, logits, windows, build,
         served_picks=picks if forced else None, **ref_kwargs)
-    assert len(toks) == max_new
     return max(logit_err.max(), window_err.max(), gaps.max()), \
         margin.max()
 
@@ -131,8 +119,8 @@ def flash_engine():
         patch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
         patch.setattr(kv, "GQA_QUERY_BLOCK", 64)
         patch.setattr(pk, "causal_blocks", lambda t, d, dv: (64, 128))
-        engine = make_engine(prompt_buckets=build["prompt_buckets"],
-                             prompt_len=build["prompt_len"])
+        engine = FAMILY.fresh(prompt_buckets=build["prompt_buckets"],
+                              prompt_len=build["prompt_len"])
     lowered = kv.GQA_PREFILL_ATTEND_LOWERED.labels(path="flash").value
     return engine, build, lowered - before
 
@@ -271,7 +259,11 @@ def test_windows_do_not_leak_across_release_and_reuse():
     for bit through another slot's admission and steps."""
     rng = np.random.RandomState(8)
     first, second = (rng.randint(1, BUILD["vocab"], n) for n in (23, 1))
-    used, fresh = make_engine(), make_engine()
+    # the worker's honest engine with every slot free: what this test
+    # puts in it is released again, and a window is overwritten at the
+    # next admission (which is the claim)
+    used, fresh = FAMILY.shared(), FAMILY.fresh()
+    used.reset()
     slot, _t, _d = used.admit(first, max_new=5)
     while any(not done for _s, _t, done in used.step()):
         pass
@@ -380,7 +372,7 @@ def test_convolved_tokens_are_counted_and_the_span_names_the_kind():
     was named for."""
     from paddle_tpu.observability import tracing
     from paddle_tpu.serving import metrics as sm
-    engine = make_engine(n_layer=4)                      # 3 conv layers
+    engine = FAMILY.fresh(n_layer=4)                     # 3 conv layers
     count = {v: sm.SHORTCONV_TOKENS.labels(model="lm", view=v)
              for v in ("prefill", "decode")}
     p0, d0 = count["prefill"].value, count["decode"].value
@@ -467,7 +459,10 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault):
     """The tolerance bites: the system with one fault in it (the
     reference is fed the honest configuration), or the honest system
     against a reference control, lies far outside it."""
-    changes, ref_kwargs = {"n_layer": 4}, {}
+    # one period, and the one bucket the prompt of 13 takes
+    changes, ref_kwargs = {"n_layer": 4, "prompt_buckets": [16],
+                           "prompt_len": 16}, {}
+    honest = FAMILY.build(**changes)      # what the reference is told
     if fault == "window_at_buckets_end":
         real = jax.lax.dynamic_slice
         monkeypatch.setattr(
@@ -492,10 +487,11 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault):
         ref_kwargs["use_bias"] = False
     else:
         ref_kwargs["window_end"] = 16
-    jax.clear_caches()
-    honest = {**BUILD, "n_layer": 4}      # what the reference is told
     try:
-        engine = make_engine(seed=9, **changes)
+        # a control that faults the reference alone takes the worker's
+        # honest engine of that depth
+        engine = (FAMILY.shared if ref_kwargs else FAMILY.fresh)(
+            seed=9, **changes)
         if fault == "picks_without_bias":
             # the faulty family has no bias to hand the reference: it is
             # given one of the size the honest family draws
@@ -508,5 +504,4 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault):
                              **ref_kwargs)
     finally:
         monkeypatch.undo()
-        jax.clear_caches()
     assert err > 100 * TOL

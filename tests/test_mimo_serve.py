@@ -28,6 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.reference import mimo_v2_flash_ep16_d7 as ref  # noqa: E402
 from chipbench.runners import serve_mimo, serve_trinity  # noqa: E402
 from paddle_tpu.models import transformer as T  # noqa: E402
@@ -50,23 +51,25 @@ CFG = dict(build=BUILD, kv_layout="paged", kv_codec="none",
            reference="mimo_v2_flash_ep16_d7")
 
 
+def _drawn_gains(engine, build, seed):
+    rng = np.random.RandomState(seed)
+    for name in ref.param_names(build):
+        if name.endswith("_scale"):
+            shape = np.shape(engine.scope.find_var(name))
+            engine.scope.set_var(name, jax.device_put(
+                rng.uniform(0.5, 1.5, shape).astype(np.float32)))
+
+
+FAMILY = families.Family(serve_mimo, CFG, ref, serve_trinity.AttendedProbe,
+                         prepare=_drawn_gains)
+params_of = FAMILY.params_of
+
+
 @pytest.fixture(scope="module")
 def engine():
     """The engine with the weights (and sinks) of seed 5 and every norm's
     gain drawn from 0.5-1.5."""
-    eng = serve_mimo.build_engine(CFG, 5, jax.devices()[0])
-    rng = np.random.RandomState(5)
-    for name in ref.param_names(BUILD):
-        if name.endswith("_scale"):
-            shape = np.shape(eng.scope.find_var(name))
-            eng.scope.set_var(name, jax.device_put(
-                rng.uniform(0.5, 1.5, shape).astype(np.float32)))
-    eng.warmup()
-    return eng
-
-
-def params_of(engine):
-    return {n: engine.scope.find_var(n) for n in ref.param_names(BUILD)}
+    return FAMILY.shared()
 
 
 @pytest.fixture(scope="module")
@@ -78,8 +81,8 @@ def served(engine):
     out = {}
     for n in (3, 7, 8, 9, 16, 21, 30):
         prompt = np.random.RandomState(n).randint(1, BUILD["vocab"], n)
-        out[n] = (prompt,) + tuple(serve_trinity.serve_one(engine, prompt,
-                                                           14))
+        out[n] = (prompt,) + tuple(serve_trinity.serve_one(
+            engine, prompt, 14, probe=FAMILY.probe(engine)))
     return out
 
 
@@ -145,6 +148,9 @@ def test_each_kinds_planes_have_its_own_row_width(engine):
     assert shape("lm_l1_attn.wo") == (8 * 16, 64)
     assert engine.row_bytes == {"full": 2 * 2 * (24 + 16) * 4,
                                 "window": 5 * 4 * (24 + 16) * 4}
+    # the gauge is what the LAST engine of this name was built with: one
+    # built here, not the worker's shared one
+    assert FAMILY.fresh(warm=False).row_bytes == engine.row_bytes
     for group, value in engine.row_bytes.items():
         assert smetrics.KV_ROW_BYTES.labels(
             model="lm", group=group).value == value

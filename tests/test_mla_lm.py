@@ -27,6 +27,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.reference import glm5_744b_ep16_d5 as ref  # noqa: E402
 from chipbench.runners import serve_glm5  # noqa: E402
 from paddle_tpu import serving  # noqa: E402
@@ -52,32 +53,23 @@ CFG = dict(build=BUILD, kv_layout="paged", kv_codec="none",
            reference="glm5_744b_ep16_d5")
 
 
-def make_engine(seed=5, **changes):
-    cfg = {**CFG, "build": {**BUILD, **changes}}
-    engine = serve_glm5.build_engine(cfg, seed, jax.devices()[0])
-    engine.warmup()
-    return engine
+FAMILY = families.Family(serve_glm5, CFG, ref, serve_glm5.PickProbe)
+params_of = FAMILY.params_of
 
 
 @pytest.fixture(scope="module")
 def engine():
-    return make_engine()
+    return FAMILY.shared()
 
 
-def params_of(engine):
-    return {n: engine.scope.find_var(n) for n in ref.param_names(BUILD)}
-
-
-def worst(engine, prompt_len, max_new=8, seed=1):
+def worst(engine, prompt_len, max_new=8, seed=1, build=BUILD):
     """(the largest relative error of the served logits, the smallest
     overlap of the attended sets, the largest margin) of one request
     against the reference."""
-    prompt = np.random.RandomState(seed).randint(1, BUILD["vocab"],
-                                                 prompt_len)
-    toks, logits, picks = serve_glm5.serve_one(engine, prompt, max_new)
+    prompt, toks, logits, picks = FAMILY.request(engine, prompt_len,
+                                                 max_new, seed)
     logit_err, overlaps, margin = ref.compare(
-        params_of(engine), prompt, toks, logits, picks, BUILD)
-    assert len(toks) == max_new
+        params_of(engine, build), prompt, toks, logits, picks, build)
     return logit_err.max(), overlaps.min(), margin.max()
 
 
@@ -116,7 +108,7 @@ def test_requests_live_together_and_through_the_server(engine):
     prompts = [rng.randint(1, BUILD["vocab"], n) for n in (9, 27, 14)]
     budgets = [8, 5, 7]
     served = serve_glm5.serve_together(
-        engine, serve_glm5.PickProbe(engine), prompts, budgets)
+        engine, FAMILY.probe(engine), prompts, budgets)
     for prompt, (toks, logits, picks) in zip(prompts, served):
         logit_err, overlaps, _ = ref.compare(
             params_of(engine), prompt, toks, logits, picks, BUILD)
@@ -161,19 +153,20 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault):
     """Each fault, built into an engine of its own, against the
     unchanged reference: the logits leave ``TOL`` a hundredfold, or the
     attended sets stop agreeing."""
-    changes = {}
+    # the dense layer and ONE latent layer show the same miss as two do,
+    # in the one bucket the prompt of 19 takes
+    changes = {"n_layer": BUILD["first_k_dense"] + len(BUILD["layer_kinds"]),
+               "prompt_buckets": [32]}
+    honest = FAMILY.build(**changes)      # what the reference is told
     if fault == "dense_attention":
         changes["index_topk"] = 64        # every row attended
     else:
         FAULTS[fault](monkeypatch)
-    eng = make_engine(**changes)
-    prompt = np.random.RandomState(2).randint(1, BUILD["vocab"], 19)
-    toks, logits, picks = serve_glm5.serve_one(eng, prompt, 8)
-    logit_err, overlaps, _ = ref.compare(
-        params_of(eng), prompt, toks, logits, picks, BUILD)
-    assert logit_err.max() > 100 * TOL
+    eng = FAMILY.fresh(**changes)
+    err, overlap, _ = worst(eng, 19, seed=2, build=honest)
+    assert err > 100 * TOL
     if fault in ("padding_rows_live", "dense_attention"):
-        assert overlaps.min() < 0.8
+        assert overlap < 0.8
 
 
 # ------------------------------------------------------- the two ways
@@ -297,6 +290,9 @@ def test_rows_scored_and_selected_are_counted(engine):
     assert scored.value - s0 == 3 * (6 + 7 + 8 + 9 + 10)
     assert selected.value - k0 == 3 * (6 + 7 + 8 + 8 + 8)
     assert engine.free_count() == engine.n_slots
+    # the gauges are what the LAST engine of this name was built with:
+    # one built here, not the worker's shared one
+    FAMILY.fresh(warm=False)
     pool_rows = 40 * 4
     assert smetrics.LATENT_CACHE_BYTES.labels(model="lm").value \
         == 3 * pool_rows * 128 * 4

@@ -24,6 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.reference import olmo_hybrid_7b_pp2_d16 as ref  # noqa: E402
 from chipbench.runners import serve_hybrid, serve_olmo_hybrid  # noqa: E402
 from paddle_tpu.core.registry import get_op  # noqa: E402
@@ -54,11 +55,12 @@ def programs(**over):
            "layer_kinds": tuple(build["layer_kinds"])})
 
 
+FAMILY = families.Family(serve_hybrid, CFG, ref)
+
+
 @pytest.fixture(scope="module")
 def engine():
-    eng = serve_hybrid.build_engine(CFG, 5, jax.devices()[0])
-    eng.warmup()
-    return eng
+    return FAMILY.shared()
 
 
 @pytest.fixture(scope="module")
@@ -150,6 +152,9 @@ def test_the_state_is_found_by_role_and_sized(engine):
     assert state.shape == (6, 3, 8, 16) and str(state.dtype) == "float32"
     assert engine.scope.find_var("lm_gdn_conv_0").shape == (6, 3, 96)
     want = 3 * 6 * (3 * 8 * 16 * 4 + 3 * 96 * 4)      # float32 windows here
+    # the gauge is what the LAST engine of this name was built with: one
+    # built here, not the worker's shared one
+    FAMILY.fresh(warm=False)
     assert sm.RECURRENT_STATE_BYTES.labels(
         model=engine.name, kind="gdn").value == want
 

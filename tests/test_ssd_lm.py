@@ -25,6 +25,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.reference import granite4_h_small_ep4_d10 as ref  # noqa: E402
 from chipbench.runners import serve_granite  # noqa: E402
 from paddle_tpu.analysis import contracts  # noqa: E402
@@ -51,37 +52,22 @@ CFG = dict(build=BUILD, kv_layout="paged", kv_codec="none",
            reference="granite4_h_small_ep4_d10")
 
 
-def make_engine(seed=5, **changes):
-    cfg = {**CFG, "build": {**BUILD, **changes}}
-    engine = serve_granite.build_engine(cfg, seed, jax.devices()[0])
-    engine.warmup()
-    return engine
+FAMILY = families.Family(serve_granite, CFG, ref,
+                         serve_granite.serve_hybrid.LogitProbe)
+params_of = FAMILY.params_of
 
 
 @pytest.fixture(scope="module", params=["dense", "grouped"])
 def engine(request):
-    # the programs are traced and compiled inside warmup(), under the
-    # threshold set here; later dispatches reuse the executables
-    old = expert_ffn.DENSE_MAX_TOKENS
-    if request.param == "grouped":
-        expert_ffn.DENSE_MAX_TOKENS = 0
-    try:
-        return make_engine()
-    finally:
-        expert_ffn.DENSE_MAX_TOKENS = old
-
-
-def params_of(engine, build=BUILD):
-    return {n: engine.scope.find_var(n) for n in ref.param_names(build)}
+    return FAMILY.shared(
+        patches=families.GROUPED if request.param == "grouped" else ())
 
 
 def worst(engine, prompt_len, max_new=10, seed=1, build=BUILD):
-    prompt = np.random.RandomState(seed).randint(1, build["vocab"],
-                                                 prompt_len)
-    toks, logits, states = serve_granite.serve_one(engine, prompt, max_new)
+    prompt, toks, logits, states = FAMILY.request(engine, prompt_len,
+                                                  max_new, seed, build)
     logit_err, state_err, margin, _slow = ref.compare(
         params_of(engine, build), prompt, toks, logits, states, build)
-    assert len(toks) == max_new
     return max(logit_err.max(), state_err.max()), margin.max()
 
 
@@ -106,8 +92,7 @@ def test_requests_live_together_leave_each_other_alone(engine):
     prompts = [rng.randint(1, BUILD["vocab"], n) for n in (5, 19, 11)]
     budgets = [9, 3, 6]
     served = serve_granite.serve_together(
-        engine, serve_granite.serve_hybrid.LogitProbe(engine), prompts,
-        budgets)
+        engine, FAMILY.probe(engine), prompts, budgets)
     for prompt, (toks, logits, states) in zip(prompts, served):
         logit_err, state_err, _m, _s = ref.compare(
             params_of(engine), prompt, toks, logits, states, BUILD)
@@ -127,7 +112,11 @@ def test_state_does_not_leak_across_release_and_reuse():
     leaves the state where it is."""
     rng = np.random.RandomState(8)
     first, second = (rng.randint(1, BUILD["vocab"], n) for n in (23, 6))
-    used, fresh = make_engine(), make_engine()
+    # the worker's honest engine with every slot free: what this test
+    # puts in it is released again, and a slot's state is overwritten at
+    # the next admission (which is the claim)
+    used, fresh = FAMILY.shared(), FAMILY.fresh()
+    used.reset()
     slot, _t, _d = used.admit(first, max_new=5)
     while any(not done for _s, _t, done in used.step()):
         pass
@@ -333,7 +322,8 @@ def test_the_table_is_tied_and_scaled(engine):
     assert len(heads) == 1 and heads[0].input("W") == ["lm_emb"]
     assert heads[0].attrs["scale"] == pytest.approx(1 / 4.0)
     # untied, unscaled families keep the op's attrs as they were
-    plain = make_engine(tie_embeddings=False, logits_scale=1.0, n_layer=4)
+    plain = FAMILY.shared(warm=False, tie_embeddings=False,
+                          logits_scale=1.0, n_layer=4)
     gvars = plain._cb_decode._program_desc.global_block.vars
     assert "lm_head_w" in gvars
     assert not [op for op in plain._cb_decode._program_desc.global_block.ops
@@ -368,7 +358,7 @@ def test_scanned_tokens_and_chunk_rows_are_counted():
     and ``paddle_ssd_chunk_rows_total`` the whole chunks the scan
     computed, both summed over the SSD layers, at admission."""
     from paddle_tpu.serving import metrics as sm
-    engine = make_engine(n_layer=4)
+    engine = FAMILY.fresh(n_layer=4)
     tokens = sm.SSD_TOKENS_SCANNED.labels(model="lm")
     rows = sm.SSD_CHUNK_ROWS.labels(model="lm")
     t0, r0 = tokens.value, rows.value
@@ -460,7 +450,9 @@ FAULTS = {
 def test_a_fault_fails_the_comparison(monkeypatch, fault):
     """The tolerance bites: the system with one fault in it (the
     reference is fed the honest configuration) lies far outside it."""
-    changes = {"n_layer": 4}
+    # one period, and the one bucket the prompt of 13 takes
+    changes = {"n_layer": 4, "prompt_buckets": [16], "prompt_len": 16}
+    honest = FAMILY.build(**changes)      # what the reference is told
     if fault in FAULTS:
         module, name, wrap = FAULTS[fault]
         monkeypatch.setattr(module, name, wrap(getattr(module, name)))
@@ -476,12 +468,9 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault):
             return real(x + 1.0, b, c, dt + 0.05, log_a - 0.05,
                         x.shape[0] // chunk, chunk)
         monkeypatch.setattr(ssd, "chunk_scan", whole_bucket)
-    jax.clear_caches()
     try:
-        engine = make_engine(seed=9, **changes)
-        err, _margin = worst(engine, 13, max_new=8,
-                             build={**BUILD, "n_layer": 4})
+        engine = FAMILY.fresh(seed=9, **changes)
+        err, _margin = worst(engine, 13, max_new=8, build=honest)
     finally:
         monkeypatch.undo()
-        jax.clear_caches()
     assert err > 100 * TOL

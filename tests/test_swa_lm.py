@@ -27,6 +27,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import families  # noqa: E402
 from chipbench.reference import trinity_mini_26b_d5 as ref  # noqa: E402
 from chipbench.runners import serve_trinity  # noqa: E402
 from paddle_tpu import serving  # noqa: E402
@@ -49,30 +50,26 @@ CFG = dict(build=BUILD, kv_layout="paged", kv_codec="none",
            reference="trinity_mini_26b_d5")
 
 
-def make_engine(seed=5, **changes):
-    """The engine with the weights of ``seed`` and every gain (the
-    norms', q_norm, k_norm) drawn from 0.5-1.5: a gain of 1 commutes
-    with the rotation and would hide the order of norm and positions."""
-    build = {**BUILD, **changes}
-    engine = serve_trinity.build_engine({**CFG, "build": build}, seed,
-                                        jax.devices()[0])
+def _drawn_gains(engine, build, seed):
+    """Every gain (the norms', q_norm, k_norm) drawn from 0.5-1.5: a
+    gain of 1 commutes with the rotation and would hide the order of
+    norm and positions."""
     rng = np.random.RandomState(seed)
     for name in ref.param_names(build):
         if name.endswith(("_scale", "_norm")):
             shape = np.shape(engine.scope.find_var(name))
             engine.scope.set_var(name, jax.device_put(
                 rng.uniform(0.5, 1.5, shape).astype(np.float32)))
-    engine.warmup()
-    return engine, build
+
+
+FAMILY = families.Family(serve_trinity, CFG, ref,
+                         serve_trinity.AttendedProbe, prepare=_drawn_gains)
+params_of = FAMILY.params_of
 
 
 @pytest.fixture(scope="module")
 def engine():
-    return make_engine()[0]
-
-
-def params_of(engine, build=BUILD):
-    return {n: engine.scope.find_var(n) for n in ref.param_names(build)}
+    return FAMILY.shared()
 
 
 def released():
@@ -83,10 +80,8 @@ def worst(engine, prompt_len, max_new=14, seed=1, build=BUILD, **control):
     """(the largest relative error of the served logits, the number of
     (step, window layer) readings of what was attended that differ from
     the reference's, the largest margin) of one request."""
-    prompt = np.random.RandomState(seed).randint(1, build["vocab"],
-                                                 prompt_len)
-    toks, logits, seen = serve_trinity.serve_one(engine, prompt, max_new)
-    assert len(toks) == max_new
+    prompt, toks, logits, seen = FAMILY.request(engine, prompt_len,
+                                                max_new, seed, build)
     err, margin, positions = ref.compare(params_of(engine, build), prompt,
                                          toks, logits, build, **control)
     want = ref.attended(build, positions, control.get("window"))
@@ -135,9 +130,12 @@ def test_logits_are_the_same_with_and_without_release():
     prompt = np.random.RandomState(4).randint(1, BUILD["vocab"], 5)
     logits = []
     for page_size, returns in ((4, True), (16, False)):
-        eng, _ = make_engine(page_size=page_size)
+        # pages of 4 rows are the module's own engine
+        eng = FAMILY.shared(page_size=page_size)
+        eng.reset()
         before = released()
-        toks, rows, _seen = serve_trinity.serve_one(eng, prompt, 16)
+        toks, rows, _seen = serve_trinity.serve_one(
+            eng, prompt, 16, probe=FAMILY.probe(eng))
         assert (released() > before) == returns
         logits.append(rows)
     np.testing.assert_allclose(logits[0], logits[1], atol=1e-5, rtol=1e-5)
@@ -148,12 +146,10 @@ def test_a_long_prompt_attends_in_blocks(monkeypatch):
     of queries, a window layer's block over the keys its band reaches
     alone: the same numbers."""
     monkeypatch.setattr(kv_attention, "GQA_QUERY_BLOCK", 8)
-    jax.clear_caches()
-    eng, _ = make_engine()
+    eng = FAMILY.fresh()
     for prompt_len in (13, 27, 32):
         err, wrong, _ = worst(eng, prompt_len, max_new=4)
         assert err <= TOL and wrong == 0
-    jax.clear_caches()
 
 
 def test_requests_live_together_and_through_the_server(engine):
@@ -165,7 +161,7 @@ def test_requests_live_together_and_through_the_server(engine):
     prompts = [rng.randint(1, BUILD["vocab"], n) for n in (2, 6, 19, 27)]
     budgets = [5, 12, 16, 9]
     served = serve_trinity.serve_together(
-        engine, serve_trinity.AttendedProbe(engine), prompts, budgets)
+        engine, FAMILY.probe(engine), prompts, budgets)
     for prompt, (toks, logits, _seen) in zip(prompts, served):
         err, _margin, _pos = ref.compare(params_of(engine), prompt, toks,
                                          logits, BUILD)

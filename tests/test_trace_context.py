@@ -359,7 +359,7 @@ def test_master_rpc_propagates_context():
 
 def test_pserver_rpc_propagates_context():
     import paddle_tpu.fluid as fluid
-    from _dist_utils import bound_listener
+    from _dist_utils import bound_listener, stop_pserver
     from paddle_tpu import models
     from paddle_tpu.distributed import AsyncPServer, AsyncTrainerClient
     from paddle_tpu.fluid import unique_name
@@ -389,7 +389,7 @@ def test_pserver_rpc_propagates_context():
             client.pull([pname])
     finally:
         client.close()
-        ps.stop()
+        stop_pserver(ps)
     for op in ("pserver.push", "pserver.pull"):
         pair = [s for s in spans if s.name == op]
         assert len(pair) == 2, [s.name for s in spans]
