@@ -284,7 +284,9 @@ def _preregister_catalog():
                 # (paddle_mla_decode_lowered_total{path})
                 "paddle_tpu.ops.mla",
                 # whose weights an expert layer's dense way streams
-                # (paddle_expert_dense_lowered_total{path})
+                # (paddle_expert_dense_lowered_total{path}) and what
+                # runs its grouped way's products
+                # (paddle_expert_grouped_lowered_total{path})
                 "paddle_tpu.ops.expert_ffn",
                 # which tier advances a KDA decode layer's state
                 # (paddle_kda_decode_lowered_total{path})

@@ -24,9 +24,9 @@ number of tokens in the call (a static shape, never a flag):
   all of them where the member holds every expert), never a capacity
   factor: a draw that holds more takes another turn of a loop whose trip
   count the device decides, and nothing is dropped. What moves is what
-  is computed: the held rows' inputs are gathered into the buffer, one
-  grouped product per projection (``jax.lax.ragged_dot``: a single
-  Mosaic kernel on the TPU) runs over it, and each token then reads its
+  is computed: the held rows' inputs are gathered into the buffer, the
+  three grouped products run over it (below: WHAT runs them is chosen
+  from the shapes, :func:`grouped_path`), and each token then reads its
   picks' result rows where they lie and sums them weighed, in one pass
   (until PR 44 every buffer was ``[n_tokens * top_k, ·]`` and the result
   went back through a permute, a select, a reshape and a sum of that
@@ -34,7 +34,40 @@ number of tokens in the call (a static shape, never a flag):
   of which nobody computed). A pick held elsewhere, a padded token's or
   another turn's is SELECTED out, not weighed by zero: the grouped
   product leaves the rows past its groups as it found them, NaN on the
-  chip now and then;
+  chip now and then.
+
+  The three products (:func:`grouped_path`, counted by
+  ``paddle_expert_grouped_lowered_total{path}``):
+
+  - ``kernel`` — on a TPU, off a mesh of more than one device, at
+    widths of whole lane tiles and a buffer of whole row tiles: two
+    calls of ONE row-tiled Mosaic kernel
+    (``ops/pallas/grouped_matmul.py``, PR 64). A table built from the
+    turn's ``cut`` maps each grid step to (row tile, expert); the tiles
+    past the last group do no product and fetch no weight, so the time
+    follows the rows HELD, not the buffer's. The first call multiplies
+    a row tile by the expert's gate AND up tiles and writes
+    ``silu(g) * u`` — float32 through the activation, cast once — as
+    the hidden rows (the two ``f32[R, F]`` products are never written);
+    the second is the down product, float32 out. The backward's
+    recompute of a turn takes the second body for all three;
+  - ``ragged_dot`` — everywhere else (the CPU, a program lowered under
+    a mesh of several devices, odd widths): ``jax.lax.ragged_dot`` per
+    projection, the text every earlier PR lowered. On the v5e it runs
+    at 19-70 TFLOP/s and its time follows the BUFFER's rows (PERF.md
+    section 6, PRs 44 and 64).
+
+  | prefill / sequence | buffer rows x M x F | held | row tile x gate/up columns, down columns |
+  |---|---|---|---|
+  | LFM2, 4 096 and 2 048 tokens x 4 of 32 | 16 384 and 8 192 x 2 048 x 1 792 | 32 | 128 x 1 792, 2 048 |
+  | Granite, 2 048 and 1 024 x 10 of 72 | 6 400 and 3 328 x 4 096 x 768 | 18 | 128 x 768, 4 096 |
+  | JoyAI (trained), 8 192 x 8 of 256 | 5 120 x 2 048 x 768 | 16 | 128 x 768, 2 048 |
+  | GLM-5 (priming), 8 192 x 8 of 256 | 5 120 x 6 144 x 2 048 | 16 | 128 x 512, 2 048 |
+  | Trinity (priming), 4 096 x 8 of 128 | 32 768 x 2 048 x 1 024 | 128 | 128 x 1 024, 2 048 |
+  | MiMo (priming), 16 of 256 held | rows x 4 096 x 2 048 | 16 | 128 x 1 024, 2 048 |
+
+  (``grouped_matmul.tiles``: the widest whole lane tiles that divide
+  the columns and keep one weight tile at or under 8 MB.)
 - **dense** (a decode step, a prefill of up to 512 tokens): every held
   expert
   multiplies EVERY token, and the combine weight — zero where the token
@@ -223,6 +256,37 @@ def dense_tier(n_tokens: int, top_k: int, n_experts: int, d_model: int,
     return "skip" if engages else "all"
 
 
+# exporter-catalog family (docs/serving.md "Metric names"). Counts
+# LOWERINGS of the grouped way, labelled with what :func:`grouped_path`
+# chose for its three products.
+EXPERT_GROUPED_LOWERED = _metrics.counter(
+    "paddle_expert_grouped_lowered_total",
+    "Expert layers lowered the grouped way, by what runs their three "
+    "grouped products (kernel: ops/pallas/grouped_matmul.py|ragged_dot: "
+    "jax.lax.ragged_dot)",
+    labelnames=("path",))
+
+
+def grouped_path(rows: int, d_model: int, d_expert: int, itemsize: int,
+                 mesh=None) -> str:
+    """What runs the grouped way's three products over a buffer of
+    ``rows`` rows, decided from the shapes the op sees and never from a
+    flag: ``"kernel"`` (``ops/pallas/grouped_matmul.py``: row tiles that
+    follow the groups, gate and up in one pass with the activation)
+    where the kernel may run (a TPU, no mesh of more than one device —
+    ``kernel_enabled`` — or the tests' interpreter) and the shapes give
+    whole tiles (``grouped_matmul.tiles``: widths of whole lane tiles,
+    rows in whole tiles of 128); ``"ragged_dot"``
+    (``jax.lax.ragged_dot``, three calls) otherwise."""
+    from paddle_tpu.ops import pallas as _plk
+    from paddle_tpu.ops.pallas import grouped_matmul as _gm
+    engages = (
+        all(_gm.tiles(rows, k, n, itemsize)[0]
+            for k, n in ((d_model, d_expert), (d_expert, d_model)))
+        and (_plk.kernel_enabled(mesh=mesh) or _plk.forced_interpret()))
+    return "kernel" if engages else "ragged_dot"
+
+
 def grouped_rows(n: int, k: int, n_held: int, n_experts: int) -> int:
     """Rows of the grouped way's buffers for ``n`` tokens of ``k`` picks
     over a router ``n_experts`` wide of which ``n_held`` are held here:
@@ -289,8 +353,11 @@ def held_experts_part(x, combine, idx, w_gate, w_up, w_down,
                 (((1, 2), (0, 1)), ((), ())), preferred_element_type=F32)
         return y, sizes
     rows = grouped_rows(n, k, n_held, n_experts)
+    path = grouped_path(rows, x.shape[1], w_gate.shape[2], x.dtype.itemsize,
+                        mesh)
+    EXPERT_GROUPED_LOWERED.labels(path=path).inc()
     return _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes,
-                        rows), sizes
+                        rows, path == "kernel"), sizes
 
 
 # the grouped way's combine is written out pick by pick while a token
@@ -325,12 +392,14 @@ def _plan(key, sizes, rows):
     return order, at, token, ends - sizes, ends
 
 
-def _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes, rows):
+def _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes, rows,
+                 kernel):
     """The grouped way (module docstring): y [N, M] float32 from the
-    held assignments alone, ``rows`` sorted positions a turn. The
-    weights multiply in x's dtype (float32 master weights under the
-    mixed-precision rewrite are cast here, so that their gradient comes
-    back float32)."""
+    held assignments alone, ``rows`` sorted positions a turn, the
+    products through the ``kernel`` or ``ragged_dot``
+    (:func:`grouped_path`). The weights multiply in x's dtype (float32
+    master weights under the mixed-precision rewrite are cast here, so
+    that their gradient comes back float32)."""
     n, k = key.shape
     w_gate, w_up, w_down = (w.astype(x.dtype) for w in (w_gate, w_up,
                                                         w_down))
@@ -352,10 +421,9 @@ def _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes, rows):
             cut = jnp.clip(ends, lo, lo + rows) \
                 - jnp.clip(starts, lo, lo + rows)
         with _phase("up"):
-            hidden = jax.nn.silu(_grouped(xs, w_gate, cut)) \
-                * _grouped(xs, w_up, cut)
+            hidden = _grouped_hidden(xs, w_gate, w_up, cut, kernel)
         with _phase("down"):
-            ys = _grouped(hidden.astype(x.dtype), w_down, cut)   # [R, M]
+            ys = _product(kernel)(hidden, w_down, cut)           # [R, M]
             # back to token order in one pass: a token reads its picks'
             # rows where they are. Rows past the turn's groups are never
             # computed: on the chip the grouped product leaves them as
@@ -395,13 +463,13 @@ def _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes, rows):
 
 
 def _grouped_way_fwd(x, combine, w_gate, w_up, w_down, held, key, sizes,
-                     rows):
+                     rows, kernel):
     y = _grouped_way(x, combine, w_gate, w_up, w_down, held, key, sizes,
-                     rows)
+                     rows, kernel)
     return y, (x, combine, w_gate, w_up, w_down, held, key, sizes)
 
 
-def _grouped_way_bwd(rows, res, dy):
+def _grouped_way_bwd(rows, kernel, res, dy):
     """The grouped way's backward, grouped too: per turn the held rows'
     products are made again from their inputs (nothing of a turn is
     kept), their cotangents go through the transposed grouped products
@@ -439,12 +507,12 @@ def _grouped_way_bwd(rows, res, dy):
                                                   (rows,))[:, None],
                 0.0).astype(cdt)
         with _phase("up"):
-            g, u = _grouped(xs, wg, cut), _grouped(xs, wu, cut)
+            g, u = (_product(kernel)(xs, w, cut) for w in (wg, wu))
             sg = jax.nn.sigmoid(g)
             act = g * sg
             hidden = (act * u).astype(cdt)
         with _phase("down"):
-            ys = _grouped(hidden, wd, cut)
+            ys = _product(kernel)(hidden, wd, cut)
             d_weight = jnp.where(live[:, 0], jnp.sum(ys * dys, axis=-1), 0.0)
             dh = _grouped_into(d_ys, wd, cut)                    # [R, F]
             dwd = dwd + _grouped_outer(hidden, d_ys, cut)
@@ -480,7 +548,7 @@ def _grouped_way_bwd(rows, res, dy):
             dwd.astype(w_down.dtype), None, None, None)
 
 
-_grouped_way = jax.custom_vjp(_grouped_way, nondiff_argnums=(8,))
+_grouped_way = jax.custom_vjp(_grouped_way, nondiff_argnums=(8, 9))
 _grouped_way.defvjp(_grouped_way_fwd, _grouped_way_bwd)
 
 
@@ -492,8 +560,36 @@ def _all_tokens(x, w):
 
 
 def _grouped(rows, w, sizes):
+    """Each group's rows [R, A] through its expert's w [E, A, B] ->
+    [R, B] float32; rows past the groups hold anything."""
     return jax.lax.ragged_dot(rows.astype(w.dtype), w, sizes,
                               preferred_element_type=F32)
+
+
+def _grouped_kernel(rows, w, sizes):
+    """:func:`_grouped` through ``ops/pallas/grouped_matmul.py``."""
+    from paddle_tpu.ops import pallas as _plk
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    return grouped_matmul(rows.astype(w.dtype), w, sizes,
+                          interpret=_plk.interpret_mode())
+
+
+def _product(kernel):
+    """The grouped product :func:`grouped_path` chose."""
+    return _grouped_kernel if kernel else _grouped
+
+
+def _grouped_hidden(rows, w_gate, w_up, sizes, kernel):
+    """SiLU(rows W_gate) * (rows W_up) by group, in float32, cast once
+    to the rows' dtype: [R, F]. The kernel makes both products in one
+    pass over the rows and writes the hidden rows alone."""
+    if kernel:
+        from paddle_tpu.ops import pallas as _plk
+        from paddle_tpu.ops.pallas.grouped_matmul import grouped_swiglu
+        return grouped_swiglu(rows, w_gate, w_up, sizes,
+                              interpret=_plk.interpret_mode())
+    return (jax.nn.silu(_grouped(rows, w_gate, sizes))
+            * _grouped(rows, w_up, sizes)).astype(rows.dtype)
 
 
 def _grouped_into(d_out, w, sizes):
@@ -521,9 +617,12 @@ def _grouped_outer(rows, d_out, sizes):
              ref="one expert-parallel member's share of a top-k routed "
                  "expert layer with a shared expert — dropless, static "
                  "shapes; every held expert over all tokens for a "
-                 "step's few, one grouped product over the held experts "
-                 "for a prefill's or a training step's many, its "
-                 "backward grouped too (ops/expert_ffn.py)")
+                 "step's few, grouped products over the held experts' "
+                 "sorted rows for a prefill's or a training step's many "
+                 "(a row-tiled Mosaic kernel on one TPU, "
+                 "ops/pallas/grouped_matmul.py; jax.lax.ragged_dot "
+                 "elsewhere), its backward grouped too "
+                 "(ops/expert_ffn.py)")
 def _expert_ffn_held(ctx, ins, attrs):
     """X [B,T,M], RouterW [M,E], WGate/WUp [E_held,M,F], WDown
     [E_held,F,M], optional SGate/SUp [M,Fs], SDown [Fs,M] (the shared

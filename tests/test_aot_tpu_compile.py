@@ -212,6 +212,19 @@ def _hit_experts(n, m, f, held):
                             ((held, f, m), BF16)]
 
 
+def _grouped_products(rows, m, f, held, dtype=BF16):
+    # a prefill's grouped products (ISSUE 64) at the tiles the shapes
+    # give: gate and up with the epilogue, then down
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    def both(x, w_gate, w_up, w_down, sizes):
+        hidden = gm.grouped_swiglu(x, w_gate, w_up, sizes)
+        return gm.grouped_matmul(hidden, w_down, sizes)
+    return both, [((rows, m), dtype), ((held, m, f), dtype),
+                  ((held, m, f), dtype), ((held, f, m), dtype),
+                  ((held,), I32)]
+
+
 CASES = {
     "paged_gather-f32-4096x1024": lambda: _paged_gather(F32),
     "paged_gather-bf16-4096x1024": lambda: _paged_gather(BF16),
@@ -254,6 +267,25 @@ CASES = {
                                                               16),
     "hit_experts-bf16-32x2048x1024x128": lambda: _hit_experts(32, 2048, 1024,
                                                                128),
+    # LFM2's prefills of 4 096 and 2 048 tokens (4 picks, all 32 held),
+    # Granite's of 2 048 and 1 024 (18 of 72 held), JoyAI's trained
+    # sequence (16 of 256), GLM-5's and Trinity's priming prefills
+    "grouped_products-bf16-16384x2048x1792x32":
+        lambda: _grouped_products(16384, 2048, 1792, 32),
+    "grouped_products-bf16-8192x2048x1792x32":
+        lambda: _grouped_products(8192, 2048, 1792, 32),
+    "grouped_products-bf16-6400x4096x768x18":
+        lambda: _grouped_products(6400, 4096, 768, 18),
+    "grouped_products-bf16-3328x4096x768x18":
+        lambda: _grouped_products(3328, 4096, 768, 18),
+    "grouped_products-bf16-5120x2048x768x16":
+        lambda: _grouped_products(5120, 2048, 768, 16),
+    "grouped_products-bf16-5120x6144x2048x16":
+        lambda: _grouped_products(5120, 6144, 2048, 16),
+    "grouped_products-bf16-32768x2048x1024x128":
+        lambda: _grouped_products(32768, 2048, 1024, 128),
+    "grouped_products-f32-1024x256x384x4":
+        lambda: _grouped_products(1024, 256, 384, 4, F32),
 }
 
 
@@ -804,6 +836,14 @@ def _flash_forward_calls(text, result):
             and f"= ({result}" in line]
 
 
+def _grouped_kernel_calls(text):
+    """{kernel: its Mosaic calls in a compiled module} for the two
+    bodies of ``ops/pallas/grouped_matmul.py`` (ISSUE 64)."""
+    return {name: len(re.findall(rf"%{name}[.\d]* = \S+ custom-call\(",
+                                 text))
+            for name in ("grouped_swiglu", "grouped_matmul")}
+
+
 def _compile_view(chip, programs, key, cb, feeds, monkeypatch):
     import numpy as np
     from paddle_tpu.ops import pallas as pk
@@ -1131,7 +1171,8 @@ def test_ssd_prefill_compiles_for_v5e(chip, granite_engine, monkeypatch):
     128 slots); the scan is a ``while`` over the chunks a prompt fills;
     the slot's state lands by an in-place update of the donated variable
     (no copy of a state's size); 2048 tokens take the experts' grouped
-    way (three ``ragged-dot`` an expert layer)."""
+    way, its products through the row-tiled kernel (ISSUE 64: two calls
+    an expert layer, no ``ragged-dot``)."""
     eng, programs = granite_engine
     compiled = _compile_view(chip, programs, "prefill_paged@2048",
                              eng._cb_prefill[2048],
@@ -1146,6 +1187,9 @@ def test_ssd_prefill_compiles_for_v5e(chip, granite_engine, monkeypatch):
                 if opcode in ("copy", "transpose")
                 and count >= 128 * 128 * 8192]
     assert len(_flash_forward_calls(text, "bf16[32,2048,128]")) == 1
+    assert _grouped_kernel_calls(text) == {"grouped_swiglu": 10,
+                                           "grouped_matmul": 10}
+    assert "ragged_dot_tiling" not in text
     assert text.startswith("HloModule jit_lm_prefill_paged_2048_s0b8b,")
 
 
@@ -1185,8 +1229,10 @@ def test_shortconv_decode_step_compiles_for_v5e(chip, lfm2_engine,
 def test_shortconv_prefill_compiles_for_v5e(chip, lfm2_engine, monkeypatch):
     """The 4096-token prefill beside the weights and the pages: under
     10.5 GB; 4096 tokens take the experts' grouped way over buffers of
-    every assignment (the member holds every expert: three ``ragged-dot``
-    an expert layer, no loop of turns); each of the three attention
+    every assignment (the member holds every expert: no loop of turns),
+    its products through the row-tiled kernel (ISSUE 64: gate and up in
+    one call, down in a second, ten expert layers; no ``ragged-dot``);
+    each of the three attention
     layers attends through ONE causal flash forward kernel over 32
     query heads of 64 (ISSUE 52: 8 key heads, read by the index maps) —
     no loop of query blocks, no float32 block of scores."""
@@ -1198,7 +1244,9 @@ def test_shortconv_prefill_compiles_for_v5e(chip, lfm2_engine, monkeypatch):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
     text = compiled.as_text()
     assert "reduce-window" not in text
-    assert text.count("ragged_dot_tiling") == 30
+    assert _grouped_kernel_calls(text) == {"grouped_swiglu": 10,
+                                           "grouped_matmul": 10}
+    assert "ragged_dot_tiling" not in text
     assert len(_flash_forward_calls(text, "bf16[32,4096,64]")) == 3
     assert "f32[8,4,512,4096]" not in text       # a block's scores
     assert text.startswith("HloModule jit_lm_prefill_paged_4096_sfa9e,")
@@ -1322,21 +1370,35 @@ def test_the_dense_way_lowers_as_at_the_parent(chip, case, on_chip,
         assert _scrubbed_sha(text) == want
 
 
-def test_the_grouped_way_holds_no_worst_case_buffer_on_v5e(chip):
+@pytest.mark.parametrize("on_chip", [False, True], ids=["refer", "chip"])
+def test_the_grouped_way_holds_no_worst_case_buffer_on_v5e(chip, on_chip,
+                                                           monkeypatch):
     """Granite's 2 048-token prefill, 18 of 72 experts held, 10 picks a
     token: the optimised module holds the three grouped products over a
     buffer of the HELD share (6 400 rows: a quarter of the 20 480
     assignments and a quarter more), inside a loop whose turns the
     draw's held rows decide, and nothing of the worst case's size — no
     ``[N*K, M]`` value in float32 or in the storage dtype, no
-    ``[N, K, M]`` value."""
+    ``[N, K, M]`` value. On a chip (steered) the products are the
+    row-tiled kernel's two calls (ISSUE 64), off it three
+    ``ragged-dot``."""
     from paddle_tpu.ops import expert_ffn
+    from paddle_tpu.ops import pallas as pk
+    monkeypatch.setattr(pk, "on_tpu", lambda: on_chip)
     assert expert_ffn.grouped_rows(2048, 10, 18, 72) == 6400
     text = _expert_layer(
         chip, 2048, 72, 18, 10, 4096, 768,
         {"scoring": "softmax_topk", "held_start": 0},
         lambda s: {"SeqLen": s((1, 1), I32)}).compile().as_text()
-    assert text.count("ragged-dot-none") >= 3
+    if on_chip:
+        assert _grouped_kernel_calls(text) == {"grouped_swiglu": 1,
+                                               "grouped_matmul": 1}
+        assert "ragged-dot-none" not in text
+        # the two float32 products of gate and up are never written
+        assert "f32[6400,768]" not in text
+    else:
+        assert sum(_grouped_kernel_calls(text).values()) == 0
+        assert text.count("ragged-dot-none") >= 3
     assert "f32[6400,4096]" in text and "bf16[6400,4096]" in text
     assert _count_opcode(text, "while") == 1
     for shape in ("[20480,4096]", "[20480,768]", "[2048,10,4096]",
@@ -1344,7 +1406,9 @@ def test_the_grouped_way_holds_no_worst_case_buffer_on_v5e(chip):
         assert shape not in text, shape
 
 
-def test_the_grouped_ways_backward_is_grouped_on_v5e(chip):
+@pytest.mark.parametrize("on_chip", [False, True], ids=["refer", "chip"])
+def test_the_grouped_ways_backward_is_grouped_on_v5e(chip, on_chip,
+                                                     monkeypatch):
     """A training step's expert layer at JoyAI-LLM-Flash's share (8 192
     tokens, 8 picks over a router of 256, 16 experts of 768 held, float32
     master weights under bfloat16 activations): forward and backward
@@ -1353,8 +1417,13 @@ def test_the_grouped_ways_backward_is_grouped_on_v5e(chip):
     weights — over the HELD share's 5 120 rows; nothing of every expert
     times every token (``[8192,16,768]``), nothing of the worst case's
     size (65 536 assignments), and one loop each way whose turns the
-    draw decides."""
+    draw decides. On a chip (steered) the forward's products and the
+    backward's recompute of them are the row-tiled kernel's (ISSUE 64:
+    gate and up in one call and down forward, three plain calls again
+    backward); the transposes stay ``ragged_dot_general``."""
     from paddle_tpu.ops import expert_ffn
+    from paddle_tpu.ops import pallas as pk
+    monkeypatch.setattr(pk, "on_tpu", lambda: on_chip)
     n, e, held, k, m, f = 8192, 256, 16, 8, 2048, 768
     assert expert_ffn.grouped_rows(n, k, held, e) == 5120
 
@@ -1372,7 +1441,13 @@ def test_the_grouped_ways_backward_is_grouped_on_v5e(chip):
         s((n, m)), s((m, e), F32), s((1, e), F32), s((held, m, f), F32),
         s((held, m, f), F32), s((held, f, m), F32),
         s((n, m), F32)).compile().as_text()
-    assert text.count("ragged-dot-none") >= 13
+    if on_chip:
+        assert _grouped_kernel_calls(text) == {"grouped_swiglu": 1,
+                                               "grouped_matmul": 4}
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3
+    else:
+        assert sum(_grouped_kernel_calls(text).values()) == 0
+        assert text.count("ragged-dot-none") >= 13
     assert "[5120,2048]" in text and "[5120,768]" in text
     assert _count_opcode(text, "while") == 2
     for shape in ("[8192,16,768]", "[16,8192,768]", "[65536,2048]",
@@ -1444,7 +1519,12 @@ def test_joyai_train_step_compiles_for_v5e(chip, monkeypatch):
     assert len(forward) == 6 and not any("grad/" in n for n in forward)
     assert len(attend) == 18
     assert sum("grad/mtp/mla_full" in n for _, n in attend) == 2
-    assert text.count("ragged-dot-none") >= 5 * 16
+    # five expert layers: the forward's two kernel calls (ISSUE 64), the
+    # backward's three for its recompute, and the three transposes to
+    # the weights on ``ragged_dot_general``
+    calls = _grouped_kernel_calls(text)
+    assert calls["grouped_swiglu"] >= 5 and calls["grouped_matmul"] >= 5 * 4
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 5 * 3
     for m in re.finditer(r"(?:f32|bf16)\[([\d,]+)\]", text):
         dims = [int(d) for d in m.group(1).split(",")]
         assert dims.count(t) < 2, m.group(0)

@@ -31,7 +31,12 @@ so their views stay the parent's and say nothing of the new path):
   the same but the decode views of the four configurations whose full
   layers attend in place, which are LISTED (``IN_PLACE_LAYERS``) and
   must differ, with ``paddle_kv_decode_attend_lowered_total`` read
-  beside each;
+  beside each — and, since PR 64, the prefill views of more than 512
+  tokens of the five configurations whose expert layers take the
+  grouped way (``GROUPED_KERNEL_PREFILLS``, listed, must differ: their
+  three grouped products are the row-tiled kernel's two calls on a
+  chip, ``ops/expert_ffn.py:grouped_path``), with
+  ``paddle_expert_grouped_lowered_total`` read beside each;
 - the ONE op ``kv_attention_decode_paged`` lowered alone at each of the
   seven served configurations' PUBLISHED full-layer geometries for the
   described v5e (structs in, text out: nothing allocated, compiled or
@@ -184,6 +189,17 @@ IN_PLACE_LAYERS = {"mimo_v2_flash_ep16_d7": 2, "trinity_mini_26b_d5": 1,
 # ... or all by copy (GLM-5 has no such layer: its planes are latent)
 COPY_LAYERS = {"gpt2_medium_d12": 12, "solar_open2_250b_ep8_d4": 1,
                "lfm2_8b_a1b_d12": 3, "glm5_744b_ep16_d5": 0}
+# configuration -> its prompt buckets over ``DENSE_MAX_TOKENS``: the
+# prefill views whose expert layers take the grouped way, their products
+# through ``ops/pallas/grouped_matmul.py`` on a chip (PR 64) — the only
+# prefill views that do not lower the parent's text. Solar's buckets end
+# at 512 (the dense way); gpt2's and Olmo-Hybrid's stacks hold no expert
+GROUPED_KERNEL_PREFILLS = {
+    "glm5_744b_ep16_d5": (6144, 8192),
+    "granite4_h_small_ep4_d10": (1024, 2048),
+    "lfm2_8b_a1b_d12": (2048, 4096),
+    "mimo_v2_flash_ep16_d7": (4096, 8192, 16384, 32768),
+    "trinity_mini_26b_d5": (2048, 4096, 8192, 16384)}
 
 
 @pytest.mark.parametrize("sharding", ["chip"], indirect=True)
@@ -193,16 +209,23 @@ def test_the_committed_size_lowers_the_parents_text_but_in_place(
     """The cell's own configuration file, every view: the parent's text
     letter for letter, but the decode view of a configuration whose full
     layers attend in place — and there the path counter says so for
-    every full layer, as it says ``copy`` for every one elsewhere."""
+    every full layer, as it says ``copy`` for every one elsewhere — and
+    the long prefill views whose grouped products are the kernel's
+    (PR 64), where that path's counter says ``kernel`` and never
+    ``ragged_dot``."""
+    from paddle_tpu.ops import expert_ffn
     from paddle_tpu.ops import kv_attention as kv
     with open(os.path.join(os.path.dirname(HERE), "chipbench", "configs",
                            name + ".json")) as f:
         cfg = json.load(f)
     read = lambda: {p: kv.KV_DECODE_ATTEND_LOWERED.labels(     # noqa: E731
         path=p).value for p in ("in_place", "copy")}
-    before = read()
+    grouped = lambda: {p: expert_ffn.EXPERT_GROUPED_LOWERED.labels(  # noqa
+        path=p).value for p in ("kernel", "ragged_dot")}
+    before, grouped_before = read(), grouped()
     got = view_digests(cfg, sharding)
     grew = {p: n - before[p] for p, n in read().items()}
+    grouped_grew = {p: n - grouped_before[p] for p, n in grouped().items()}
     if WRITE:
         return record(name, "committed", got)
     with open(DATA) as f:
@@ -210,8 +233,14 @@ def test_the_committed_size_lowers_the_parents_text_but_in_place(
     assert sorted(got) == sorted(want)
     path, layers = ("in_place", IN_PLACE_LAYERS[name]) \
         if name in IN_PLACE_LAYERS else ("copy", COPY_LAYERS[name])
-    assert [k for k in sorted(want) if got[k] != want[k]] \
-        == ["decode_paged"] * (path == "in_place")
+    kernel_views = [f"prefill_paged@{p}"
+                    for p in GROUPED_KERNEL_PREFILLS.get(name, ())]
+    assert all(p > expert_ffn.DENSE_MAX_TOKENS
+               for p in GROUPED_KERNEL_PREFILLS.get(name, ()))
+    assert [k for k in sorted(want) if got[k] != want[k]] == sorted(
+        ["decode_paged"] * (path == "in_place") + kernel_views)
+    assert not grouped_grew["ragged_dot"]
+    assert bool(grouped_grew["kernel"]) == bool(kernel_views)
     # one increment a full layer and trace of the decode view
     other = "copy" if path == "in_place" else "in_place"
     assert not grew[other] and grew[path] % max(layers, 1) == 0
