@@ -13,7 +13,7 @@ from paddle_tpu.fluid.layers.nn import (  # noqa: F401
     cos_sim, crf_decoding, cross_entropy, dropout, embedding, expand, fc,
     fused_linear_cross_entropy, fused_multi_head_attention,
     kv_attention_prefill_paged, kv_attention_decode_paged,
-    kv_attention_verify_paged, rms_norm, dense, kda, gdn, ssd, shortconv,
+    kv_attention_verify_paged, rms_norm, dense, kda, gdn, ssd, s6, shortconv,
     expert_ffn_held,
     swiglu_ffn, mla, mla_full, router_bias_update,
     token_sample,

@@ -903,6 +903,68 @@ def ssd(x, state, conv, d_model, sizes, base, init, epsilon=1e-5,
     return out
 
 
+def s6(x, state, conv, d_model, sizes, base, init, epsilon=1e-6,
+       seq_len=None, slot=None, active=None, name=None):
+    """One Mamba-1 (S6) mixer layer (ops/s6.py) over the persistable
+    per-slot ``state`` [n_slots, N, C] float32 and ``conv``
+    [n_slots, taps-1, C], both read and written under their own names
+    (donated). ``sizes``: s6_d_inner (C), s6_d_state (N), s6_dt_rank (R),
+    s6_conv_taps, s6_chunk. With ``seq_len`` and ``slot`` it is the
+    prefill of ONE request, x [1, T, M], writing slot ``slot``; with
+    ``active`` the decode step of every slot, x [n_slots, 1, M]. Weights
+    ``<base>.<tag>``; the decay starts as Mamba-1 starts it: A =
+    exp(A_log) = 1..N along the state index in every channel (kept flat,
+    [N * C]: ops/s6.py says why), a dt_bias whose softplus is spread
+    log-evenly over [0.001, 0.1] across the channels, D = 1; the three
+    norms' gains 1."""
+    from paddle_tpu.fluid.initializer import NumpyArrayInitializer
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    prefill = seq_len is not None
+    op = "s6_prefill" if prefill else "s6_decode"
+    helper = LayerHelper(op, name=name)
+    inner, n, r = (int(sizes[k]) for k in ("s6_d_inner", "s6_d_state",
+                                           "s6_dt_rank"))
+    taps = int(sizes["s6_conv_taps"])
+    dt0 = np.exp(np.linspace(np.log(1e-3), np.log(0.1), inner))
+    # tag: (the op's slot, shape, the fixed float32 start or None: drawn)
+    table = {
+        "w_in": ("WIn", [d_model, 2 * inner], None),
+        "w_out": ("WOut", [inner, d_model], None),
+        "conv": ("ConvW", [taps, inner], None),
+        "conv_bias": ("ConvB", [1, inner], None),
+        "w_x": ("WX", [inner, r + 2 * n], None),
+        "dt_norm": ("DtNorm", [r], np.ones(r)),
+        "b_norm": ("BNorm", [n], np.ones(n)),
+        "c_norm": ("CNorm", [n], np.ones(n)),
+        "w_dt": ("WDt", [r, inner], None),
+        "dt_bias": ("DtBias", [inner], dt0 + np.log(-np.expm1(-dt0))),
+        "a_log": ("ALog", [n * inner],
+                  np.repeat(np.log(np.arange(1.0, n + 1.0)), inner)),
+        "d": ("D", [inner], np.ones(inner))}
+    inputs = {}
+    for tag, (slot_name, shape, fixed) in table.items():
+        attr = ParamAttr(
+            name=f"{base}.{tag}",
+            initializer=init if fixed is None else NumpyArrayInitializer(
+                fixed.astype(np.float32)))
+        inputs[slot_name] = [helper.create_parameter(
+            attr, shape=shape,
+            dtype=x.dtype if fixed is None else "float32")]
+    inputs.update(X=[x], State=[state], Conv=[conv])
+    if prefill:
+        inputs.update(SeqLen=[seq_len], Slot=[slot])
+    else:
+        inputs.update(Active=[active])
+    attrs = {"epsilon": float(epsilon)}
+    if prefill:
+        attrs["chunk"] = int(sizes["s6_chunk"])
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(op, inputs=inputs,
+                     outputs={"Out": [out], "StateOut": [state],
+                              "ConvOut": [conv]}, attrs=attrs)
+    return out
+
+
 def gdn(x, state, conv, d_model, sizes, base, init, epsilon=1e-5,
         seq_len=None, slot=None, active=None, name=None):
     """One Gated DeltaNet layer (ops/gdn.py) over the persistable

@@ -190,7 +190,9 @@ def transformer(src_ids, tgt_ids, src_vocab, tgt_vocab, max_len,
 # DeltaNet: the same delta rule with one decay a head, a state that is
 # not square and a chunked prefill), "ssd" (Mamba-2's
 # state-space dual: another fixed-size state per slot, a chunked scan as
-# its prefill), "conv" (the LFM2 family's gated short convolution: a
+# its prefill), "s6" (Mamba-1's selective scan: a decay per channel AND
+# state index, a normed low-rank step, no matrix form), "conv" (the LFM2
+# family's gated short convolution: a
 # third fixed-size state per slot, the last rows of one elementwise
 # product) or "mla" (latent attention with rotary positions and the
 # DSA indexer's sparse selection: a latent plane and an indexer-key plane
@@ -245,6 +247,11 @@ _HYBRID_KEYS = {
     # groups that share B and C, the conv's taps, the prefill's chunk
     "ssd_heads": None, "ssd_head_dim": None, "ssd_d_state": None,
     "ssd_groups": 1, "ssd_conv_taps": 4, "ssd_chunk": None,
+    # "s6" layers: the inner width (channels), the state's size, the
+    # rank the step passes through, the conv's taps, the rows a turn of
+    # the prefill's scan walks
+    "s6_d_inner": None, "s6_d_state": None, "s6_dt_rank": None,
+    "s6_conv_taps": 4, "s6_chunk": 64,
     # "conv" layers: the depthwise conv's taps (the cache keeps taps - 1)
     "conv_taps": None,
     # "mla" layers: the query's and the cache's latent widths, a head's
@@ -273,6 +280,7 @@ _KIND_KEYS = {
     "kda": ("kda_heads", "kda_head_dim", "kda_gate_rank"),
     "gdn": ("gdn_heads", "gdn_key_dim", "gdn_value_dim"),
     "ssd": ("ssd_heads", "ssd_head_dim", "ssd_d_state", "ssd_chunk"),
+    "s6": ("s6_d_inner", "s6_d_state", "s6_dt_rank"),
     "conv": ("conv_taps",),
     "mla": ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
             "qk_rope_head_dim", "v_head_dim", "rope_theta",
@@ -400,7 +408,9 @@ def hybrid_weight_std(name: str, shape) -> float:
     variance), 0.1 for a conv's bias, 0.01 for a router's correction bias (small beside the
     scores' spread, so that picking and weighing differ), 1 for a window layer's sink logits (a trained one is O(1)
     beside scores of O(1)), Glorot's sqrt(2 / (fan_in + fan_out)) over the last two
-    dimensions otherwise."""
+    dimensions otherwise (an "s6" layer's ``w_x`` and ``w_dt`` among them:
+    no suffix of that kind has a rule of its own; its ``a_log`` is kept
+    flat so that no drawer of matrices takes it for one)."""
     if name.endswith("_emb") or name.endswith(".sink"):
         return 1.0
     if name.endswith(".conv"):
@@ -586,6 +596,17 @@ def _hybrid_layer(blk, x, i, kind, tag, dense_ffn):
             eps,
             **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
                if prefill else dict(active=feeds["active"])))
+    elif kind == "s6":
+        sizes = {k: hy[k] for k in _HYBRID_KEYS if k.startswith("s6_")}
+        state = pool_var(f"{name}_s6_state_{i}",
+                         [n_slots, hy["s6_d_state"], hy["s6_d_inner"]])
+        conv = pool_var(
+            f"{name}_s6_conv_{i}",
+            [n_slots, hy["s6_conv_taps"] - 1, hy["s6_d_inner"]], dt)
+        y = layers.s6(
+            y, state, conv, d_model, sizes, f"{name}_l{i}_s6", init, eps,
+            **(dict(seq_len=feeds["seq_len"], slot=feeds["state_slot"])
+               if prefill else dict(active=feeds["active"])))
     elif kind == "gdn":
         sizes = {k: hy[k] for k in _HYBRID_KEYS if k.startswith("gdn_")}
         h, dk, dv = (hy[k] for k in _KIND_KEYS["gdn"])
@@ -748,7 +769,9 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
     per slot beside the pages), a Gated DeltaNet mixer ("gdn": the same
     delta rule with one decay a head, a state [key, value] a head,
     prefilled chunk by chunk), a Mamba-2 state-space mixer ("ssd",
-    another fixed-size state, prefilled by a chunked scan), a gated
+    another fixed-size state, prefilled by a chunked scan), a Mamba-1
+    selective-scan mixer ("s6": a decay per channel and state index,
+    prefilled row by row with the state on the chip), a gated
     short convolution ("conv", a window of its last rows per slot) or
     latent attention with rotary positions and the DSA indexer's sparse
     selection ("mla": a latent plane and an indexer-key plane in the
@@ -907,7 +930,7 @@ def decoder_lm(mode: str, prompt_len: int = 16, max_new: int = 16,
             # for every position behind the first decode step's window
             page_rows_w = sdata("page_rows_w", [t, 1])
             feed_specs["page_rows_w"] = ([t, 1], "int64")
-        if hy is not None and {"kda", "gdn", "ssd", "conv"} \
+        if hy is not None and {"kda", "gdn", "ssd", "s6", "conv"} \
                 & set(hy["kinds"]):
             # which slot's recurrent state this request's prompt lands
             # in (>= n_slots: nowhere — the warm-up's dispatch)

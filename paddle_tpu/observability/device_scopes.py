@@ -47,6 +47,8 @@ PHASES = {
     "gdn_prefill": ("conv", "scan", "gate"),
     "ssd_decode": ("conv", "state"),
     "ssd_prefill": ("conv", "scan"),
+    "s6_decode": ("conv", "project", "state"),
+    "s6_prefill": ("conv", "project", "scan"),
     "shortconv_decode": ("project", "conv", "out"),
     "shortconv_prefill": ("project", "conv", "out"),
     "expert_ffn_held": ("route", "up", "down", "shared"),

@@ -291,6 +291,11 @@ def _preregister_catalog():
                 # which tier advances a KDA decode layer's state
                 # (paddle_kda_decode_lowered_total{path})
                 "paddle_tpu.ops.kda",
+                # what walks the rows of a Mamba-1 layer's prefill
+                # (paddle_s6_scan_lowered_total{path}) and what updates
+                # its decode step's state
+                # (paddle_s6_state_lowered_total{path})
+                "paddle_tpu.ops.s6",
                 # what runs a fused attention block's core
                 # (paddle_attention_block_lowered_total{path, d_head})
                 "paddle_tpu.ops.nn_ops",

@@ -26,6 +26,7 @@ from paddle_tpu.ops import parallel_ops  # noqa: F401
 from paddle_tpu.ops import kda  # noqa: F401
 from paddle_tpu.ops import gdn  # noqa: F401
 from paddle_tpu.ops import ssd  # noqa: F401
+from paddle_tpu.ops import s6  # noqa: F401
 from paddle_tpu.ops import shortconv  # noqa: F401
 from paddle_tpu.ops import expert_ffn  # noqa: F401
 from paddle_tpu.ops import mla  # noqa: F401

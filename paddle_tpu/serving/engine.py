@@ -854,8 +854,8 @@ class SlotGenerativeModel(GenerativeModel):
         state"): the variables a hybrid family's mixers DECLARE as
         per-slot state (``register_op(..., slot_state=(kind, slots))``:
         a ``kda`` or ``gdn`` layer's delta-rule state and conv window,
-        an ``ssd`` layer's state-space state and conv window, a
-        ``shortconv`` layer's window alone), [n_slots, ...] each,
+        an ``ssd`` or ``s6`` layer's state-space state and conv window,
+        a ``shortconv`` layer's window alone), [n_slots, ...] each,
         fixed-size per slot — so admission stays by pages and free
         slots. The prefill view writes the slot its ``state_slot`` feed
         names, the decode view updates every active slot in place.
@@ -940,10 +940,19 @@ class SlotGenerativeModel(GenerativeModel):
         ssd_ops = [op for op in dec_main.desc.global_block.ops
                    if op.type == "ssd_decode"]
         self._ssd_layers = len(ssd_ops)
-        self._ssd_chunks: Dict[int, int] = {}       # by prompt bucket
+        # the chunk a bucket's prefill view scans by, by (op type, bucket)
+        self._scan_chunks: Dict[Tuple[str, int], int] = {}
         self._m_ssd_tokens = smetrics.SSD_TOKENS_SCANNED.labels(
             model=self.name)
         self._m_ssd_rows = smetrics.SSD_CHUNK_ROWS.labels(model=self.name)
+        # a Mamba-1 (S6) layer's prefill walks whole chunks too: counted
+        # as the SSD layers' are
+        self._s6_layers = sum(
+            op.type == "s6_decode"
+            for op in dec_main.desc.global_block.ops)
+        self._m_s6_tokens = smetrics.S6_TOKENS_SCANNED.labels(
+            model=self.name)
+        self._m_s6_rows = smetrics.S6_CHUNK_ROWS.labels(model=self.name)
         # a Gated DeltaNet layer's prefill scans whole blocks of chunks
         # up to the prompt's true length: counted as the SSD layers' are
         self._gdn_layers = sum(
@@ -981,13 +990,17 @@ class SlotGenerativeModel(GenerativeModel):
                                                           "gdn_prefill")
         return scan_rows(length, p_len, self._gdn_chunks[p_len])
 
-    def _ssd_chunk(self, p_len: int) -> int:
-        """Rows a turn of the chunked scan of the ``p_len`` prefill view
-        (``ops/ssd.py``: the op's ``chunk``, at most the bucket)."""
-        if p_len not in self._ssd_chunks:
-            self._ssd_chunks[p_len] = min(
-                self._prefill_chunk(p_len, "ssd_prefill"), p_len)
-        return self._ssd_chunks[p_len]
+    def _scan_rows(self, length: int, p_len: int, op_type: str) -> int:
+        """Rows the scan of the ``p_len`` prefill view's ``op_type`` ops
+        (``ssd_prefill``, ``s6_prefill``) walks for a prompt of
+        ``length`` tokens: the whole chunks the length fills, at the
+        op's ``chunk`` (at most the bucket; looked up once a bucket)."""
+        key = (op_type, p_len)
+        if key not in self._scan_chunks:
+            self._scan_chunks[key] = min(
+                self._prefill_chunk(p_len, op_type), p_len)
+        chunk = self._scan_chunks[key]
+        return -(-length // chunk) * chunk
 
     # decode steps between two snapshots of the expert counters
     COUNT_SNAPSHOT_STEPS = 32
@@ -1448,10 +1461,14 @@ class SlotGenerativeModel(GenerativeModel):
         self._m_admissions.inc()
         self._m_tokens.inc()
         if self._ssd_layers:
-            chunk = self._ssd_chunk(p_len)
             self._m_ssd_tokens.inc(length * self._ssd_layers)
-            self._m_ssd_rows.inc(-(-length // chunk) * chunk
+            self._m_ssd_rows.inc(self._scan_rows(length, p_len,
+                                                 "ssd_prefill")
                                  * self._ssd_layers)
+        if self._s6_layers:
+            self._m_s6_tokens.inc(length * self._s6_layers)
+            self._m_s6_rows.inc(self._scan_rows(length, p_len, "s6_prefill")
+                                * self._s6_layers)
         if self._gdn_layers:
             self._m_gdn_tokens.inc(length * self._gdn_layers)
             self._m_gdn_rows.inc(self._gdn_scan_rows(length, p_len)

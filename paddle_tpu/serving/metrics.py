@@ -223,7 +223,7 @@ RECURRENT_STATE_BYTES = _metrics.gauge(
     "paddle_recurrent_state_bytes",
     "Bytes of per-slot recurrent and conv state a hybrid model keeps "
     "beside its KV pages, by the kind of mixer that keeps it "
-    "(kda|gdn|ssd|shortconv; "
+    "(kda|gdn|ssd|s6|shortconv; "
     "static: fixed-size per slot, n_slots of them; no sample for a "
     "model with none)", labelnames=("model", "kind"))
 SSD_TOKENS_SCANNED = _metrics.counter(
@@ -246,6 +246,16 @@ GDN_CHUNK_ROWS = _metrics.counter(
     "Rows gdn_prefill's chunked scan computed: the whole blocks of chunks "
     "a prompt's true length fills (ops/gdn.py:scan_rows), summed over the "
     "model's GDN layers", labelnames=("model",))
+S6_TOKENS_SCANNED = _metrics.counter(
+    "paddle_s6_tokens_scanned_total",
+    "True prompt tokens through s6_prefill's selective scan, summed over "
+    "the model's S6 layers (counted on the host at admission)",
+    labelnames=("model",))
+S6_CHUNK_ROWS = _metrics.counter(
+    "paddle_s6_chunk_rows_total",
+    "Rows s6_prefill's scan walked: the whole chunks a prompt's true "
+    "length fills, summed over the model's S6 layers",
+    labelnames=("model",))
 SHORTCONV_TOKENS = _metrics.counter(
     "paddle_shortconv_tokens_total",
     "True tokens through the gated short convolutions, summed over the "

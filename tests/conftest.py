@@ -89,6 +89,8 @@ STEP_MODULES_SINCE_PR37 = {
     "serve_mimo_decode_deepctx": "jit_lm_decode_paged_s367a",
     # PR 59: the digest carries ``gdn_decode``'s row of the phases
     "serve_olmo_hybrid_docqa_closed": "jit_lm_decode_paged_scfb5",
+    # PR 65: the digest carries ``s6_decode``'s row of the phases
+    "serve_jamba2_reasoning_closed": "jit_lm_decode_paged_s6ffc",
 }
 
 
@@ -126,6 +128,12 @@ LAST_CONFIG_OF_PR51 = "lfm2_8b_a1b_d12"
 # lists cut behind PR 56's last entries, as PR 51's is; the same
 # ``benchmark`` PR drops it.
 LAST_METRIC_OF_PR56 = "full_kv_live_rows_pct.decode"
+# ``tests/chipbench/test_chipbench_serve_olmo_hybrid.py`` (PR 59) pins its
+# five metrics and its cell as the last of their lists; PR 65 appended
+# five metrics, a cell and a configuration. Shown the lists cut behind
+# PR 59's last entries, as the two before it; the same ``benchmark`` PR
+# drops it. (PR 65's own module pins nothing as last.)
+LAST_METRIC_OF_PR59 = "gdn_state_roofline.decode"
 # module -> (its last metric, its last cell or None, its last
 # configuration or None): the lists it is shown end there
 LIST_CUT_BEHIND = {
@@ -134,7 +142,10 @@ LIST_CUT_BEHIND = {
                                   LAST_CONFIG_OF_PR51),
     "test_chipbench_serve_mimo": (LAST_METRIC_OF_PR56,
                                   "serve_mimo_decode_deepctx",
-                                  "mimo_v2_flash_ep16_d7")}
+                                  "mimo_v2_flash_ep16_d7"),
+    "test_chipbench_serve_olmo_hybrid": (LAST_METRIC_OF_PR59,
+                                         "serve_olmo_hybrid_docqa_closed",
+                                         "olmo_hybrid_7b_pp2_d16")}
 # ``tests/chipbench/test_chipbench_serve_trinity.py`` (PR 37) pins its
 # four metrics' ``workloads`` to its one cell, and PR 56's cell, the
 # second with a window group, belongs on ``kv_window_pages_*``'s lists:
@@ -168,8 +179,8 @@ def _per_layer_list_as_the_module_pinned_it(request, monkeypatch):
             return bench
         names = [m["name"] for m in bench["per_layer"]]
         del bench["per_layer"][names.index(last) + 1:]
-        # PR 51's and PR 56's modules also pin their cell and their
-        # configuration as the last of their lists
+        # PR 51's, PR 56's and PR 59's modules also pin their cell and
+        # their configuration as the last of their lists
         for group, own_last in (("workloads", last_cell),
                                 ("configs", last_config)):
             if own_last is not None:

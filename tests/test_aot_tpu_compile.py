@@ -182,6 +182,27 @@ def _gdn_state():
              ((b, h, 1), F32), ((b, h), F32), ((b,), I32)])
 
 
+def _s6_scan(t, n_true_chunks=None):
+    # Jamba2's prefill: a bucket of t rows of 5120 channels, a state of
+    # 16, chunks of 64 rows, the state tile [16, 1280] in VMEM
+    from paddle_tpu.ops.pallas import s6_scan as k
+    row = ((t, 5120), F32)
+    return (lambda x, dt, b, c, a, n: k.s6_scan(x, dt, b, c, a, n,
+                                                chunk=64),
+            [row, row, ((t, 16), F32), ((t, 16), F32), ((16, 5120), F32),
+             ((), I32)])
+
+
+def _s6_state():
+    # Jamba2's decode step: 256 slots of [16, 5120] float32, blocks of
+    # 8 slots by 2560 channels, in place
+    from paddle_tpu.ops.pallas import s6_state as k
+    row = ((256, 5120), F32)
+    return (k.s6_state_update,
+            [((256, 16, 5120), F32), row, row, ((256, 16), F32),
+             ((256, 16), F32), ((16, 5120), F32), ((256,), I32)])
+
+
 def _fused_ce():
     from paddle_tpu.ops.pallas import fused_linear_ce
 
@@ -262,6 +283,9 @@ CASES = {
     "fused_lstm-fwd+bwd-100x64x512": _fused_lstm,
     "kda_state-f32-128x64x128x128": _kda_state,
     "kda_state-f32-8x30x96x192-head-decay": _gdn_state,
+    "s6_scan-f32-1024x5120x16-chunk64": lambda: _s6_scan(1024),
+    "s6_scan-f32-512x5120x16-chunk64": lambda: _s6_scan(512),
+    "s6_state-f32-256x16x5120": _s6_state,
     # GLM-5's expert layer (16 of 256 held) and Trinity's (all 128)
     "hit_experts-bf16-32x6144x2048x16": lambda: _hit_experts(32, 6144, 2048,
                                                               16),
@@ -1301,6 +1325,87 @@ def test_dense_decode_step_compiles_for_v5e(chip, olmo_engine, monkeypatch):
 # parent's, the grouped way's optimised module holds no buffer of the
 # worst case
 # ---------------------------------------------------------------------------
+
+# ---------------------------------------------------------------------------
+# Mamba-1's selective scan beside multi-query attention (PR 65): the decode
+# step and the 1024-token prefill of jamba2_3b — the WHOLE model — at the
+# cell's sizes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def jamba2_engine():
+    yield from _cell_engine("jamba2_3b")
+
+
+def test_s6_decode_step_compiles_for_v5e(chip, jamba2_engine, monkeypatch):
+    """256 slots, bf16, all 28 layers (26 Mamba, 2 multi-query), the
+    whole tied vocabulary: the step's arguments are the 9.52 GB the
+    configuration's file counts and it fits one chip with its
+    temporaries; each Mamba layer's state (``f32[256,16,5120]``, 84 MB)
+    is touched by ONE instruction, the ``s6_state_update`` kernel, whose
+    results are the state and ``y f32[256,5120]`` — no fusion, copy or
+    transpose of its size is in the step; state, windows and pages are
+    donated and aliased in place; each attention layer reads its one KV
+    head's live pages in place."""
+    eng, programs = jamba2_engine
+    assert (eng.n_slots, eng.max_pages, eng.page_size) == (256, 256, 16)
+    compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
+                             eng._decode_feeds(), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert 9.45e9 < mem.argument_size_in_bytes < 9.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
+    state = 26 * 256 * 16 * 5120 * 4
+    assert mem.alias_size_in_bytes >= state + 4 * 65536 * 16 * 128 * 2
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    touching = [line for line in entry.splitlines()[1:]
+                if "f32[256,16,5120]" in line.split(" = ", 1)[-1]
+                and not re.search(r"\b(parameter|get-tuple-element|bitcast|"
+                                  r"tuple)\(", line)]
+    assert len(touching) == 26, touching[:3]
+    assert all(re.search(r"%s6_state_update[\w.]* = \(f32\[256,16,5120\]\S*, "
+                         r"f32\[256,5120\]\S*\) custom-call\(", line)
+               for line in touching)
+    assert "gather_pages" not in entry
+    assert len(re.findall(r"= bf16\[256,20,128\]\S* custom-call\(",
+                          entry)) == 2
+    # the module's name carries ``s6_decode``'s row of the phases
+    assert text.startswith("HloModule jit_lm_decode_paged_s6ffc,")
+
+
+def test_s6_prefill_compiles_for_v5e(chip, jamba2_engine, monkeypatch):
+    """The 1024-token prefill beside the weights, the state and the
+    pages: each Mamba layer's scan is ONE ``s6_scan`` call (``y
+    f32[1024,5120]`` and the state ``f32[16,5120]``), no array of the
+    bucket's states (``[1024,16,5120]``) and no loop over tokens is in
+    it; the slot's state lands by an in-place update of the donated
+    variable (no copy of a state's size); the largest values are the
+    bucket's rows of the inner width and of the feed-forward."""
+    eng, programs = jamba2_engine
+    compiled = _compile_view(chip, programs, "prefill_paged@1024",
+                             eng._cb_prefill[1024],
+                             eng._prefill_feeds(1024), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
+    assert mem.alias_size_in_bytes >= 26 * 256 * 16 * 5120 * 4
+    text = compiled.as_text()
+    ops = _hlo_ops(text)
+    scans = re.findall(r"%s6_scan[\w.]* = \((f32\[[\d,]+\])\S*, "
+                       r"(f32\[[\d,]+\])\S*\) custom-call\(", text)
+    assert scans == [("f32[1024,5120]", "f32[16,5120]")] * 26
+    assert not re.search(r"f32\[1024,16,5120\]|f32\[1024,5120,16\]", text)
+    assert not [line for opcode, count, _a, line in ops.values()
+                if opcode in ("copy", "transpose")
+                and count >= 256 * 16 * 5120]
+    # what a prefill holds at most: [1024, 10240] and [1024, 8192] rows
+    biggest = max(count for opcode, count, _a, _l in ops.values()
+                  if opcode not in ("parameter", "get-tuple-element",
+                                    "tuple", "bitcast", "dynamic-update-slice")
+                  and count < 256 * 3 * 5120)
+    assert biggest <= 1024 * 10240, biggest
+    assert len(_flash_forward_calls(text, "bf16[20,1024,128]")) == 2
+    assert text.startswith("HloModule jit_lm_prefill_paged_1024_s617b,")
+
 
 def _expert_layer(chip, tokens, n_experts, n_held, top_k, m, f, attrs, told):
     """``expert_ffn_held`` alone, lowered for a described v5e: X

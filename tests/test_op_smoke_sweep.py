@@ -263,6 +263,19 @@ spec("ssd_prefill", {"X": [f(1, 6, 8)], **_SSD,
                      "SeqLen": [lens(4).reshape(1, 1)],
                      "Slot": [lens(1).reshape(1, 1)]},
      {**_SSD_ATTRS, "chunk": 3})
+# Mamba-1's selective scan (ops/s6.py): two slots, twelve channels, a
+# state of four, a step rank of three, a conv of four taps with bias
+_S6 = {"WIn": [f(8, 24, seed=2)], "WOut": [f(12, 8, seed=3)],
+       "ConvW": [f(4, 12, seed=4)], "ConvB": [f(1, 12, seed=5)],
+       "WX": [f(12, 11, seed=6)], "WDt": [f(3, 12, seed=7)],
+       "DtNorm": [pos(3)], "BNorm": [pos(4)], "CNorm": [pos(4)],
+       "DtBias": [f(12, seed=8)], "ALog": [f(48, seed=9)], "D": [pos(12)],
+       "State": [f(2, 4, 12, seed=10)], "Conv": [f(2, 3, 12, seed=11)]}
+spec("s6_decode", {"X": [f(2, 1, 8)], **_S6,
+                   "Active": [ints(2, 1, hi=2, seed=3)]})
+spec("s6_prefill", {"X": [f(1, 6, 8)], **_S6,
+                    "SeqLen": [lens(4).reshape(1, 1)],
+                    "Slot": [lens(1).reshape(1, 1)]}, {"chunk": 3})
 # the gated short convolution (ops/shortconv.py): two slots, eight
 # channels, three taps
 _SHORTCONV = {"WIn": [f(8, 24, seed=2)], "ConvW": [f(3, 8, seed=3)],
