@@ -37,10 +37,14 @@ indexer keys, which the scoring reads alone.
   (``kv_attention._kth_largest`` and a tie count), never by sorting —
   and scatters latent rows and indexer keys through ``Rows``.
 - ``mla_decode_paged`` writes this step's two rows and scores every
-  LIVE row of every slot against the gathered indexer keys (rows
-  between a prompt's end and its bucket are padding: never scored,
-  never selected). It attends ABSORBED — ``q~_i = W_UK,i^T q_nope,i``
-  against ``cKV`` directly, ``o_i = W_UV,i sum_s a cKV_s``: one key
+  LIVE row of every slot against its indexer keys (rows between a
+  prompt's end and its bucket are padding: never scored, never
+  selected) — on the chip by ``ops/pallas/paged_attention.py:
+  score_pages``, which reads each slot's live pages of the index plane
+  where they lie; elsewhere against a gathered copy of every table's
+  every page (``scores_in_place`` says which, counted in
+  ``paddle_dsa_index_lowered_total{path}``). It attends ABSORBED —
+  ``q~_i = W_UK,i^T q_nope,i`` against ``cKV`` directly, ``o_i = W_UV,i sum_s a cKV_s``: one key
   head of the plane's width under all H query rows, the same numbers as
   the expanded way — in one of two ways, chosen at lowering from what
   the op observes (``attends_in_place``; counted in
@@ -107,6 +111,29 @@ ATTEND_PAGES_MAX_RATIO = 12
 MLA_DECODE_LOWERED = _metrics.counter(
     "paddle_mla_decode_lowered_total",
     "Latent-attention decode layers lowered, by path (pages|rows)",
+    labelnames=("path",))
+
+# The DSA indexer of a decode step scores the index plane's pages IN
+# PLACE (``score_pages``: each slot's live pages read once, where they
+# lie) wherever ``scores_in_place`` holds, and gathers the plane — every
+# page of every table, live or not — for ``_slot_scores`` elsewhere. The
+# kernel costs by a slot's LIVE rows (0.08 + 0.031 ms a thousand rows a
+# slot: a page is a 4 KB copy, ~15 ns each, 270 GB/s), the gather by the
+# tables. One layer alone, 32 slots, 32 heads x bf16[128] keys in pages
+# of 16, tables of 12 288 rows on the v5e, host-timed calls, ms (my chip
+# run, PR 66; the scores equal bit for bit on every live row):
+#   4 140 live rows a slot: in place 0.209, gather + scores 0.905
+#   6 420:                  in place 0.282,                 0.906
+#   9 130:                  in place 0.361,                 0.905
+# Blocks of 512 and 2 048 rows read the same within 0.03 ms, and so does
+# ONE wait a tile in place of a wait a page: the walk is bound by the
+# rate page copies START. Same family of counter as above: ``pages``
+# where the kernel scores in place, ``rows`` where the plane is gathered
+# (and where a cache no longer than index_topk scores nothing at all).
+DSA_INDEX_LOWERED = _metrics.counter(
+    "paddle_dsa_index_lowered_total",
+    "Latent-attention decode layers lowered, by how the DSA indexer "
+    "reads its keys (pages|rows)",
     labelnames=("path",))
 
 _phase = functools.partial(_device_scopes.phase, "mla_decode_paged")
@@ -335,20 +362,36 @@ def selected_rows(keep, k):
     return jnp.where(at < s_len, at, -1)
 
 
+def _walks_in_place(flat, ps, s_len, mesh=None) -> bool:
+    """Can a kernel of ``ops/pallas/paged_attention.py`` walk this
+    plane's pages in place? The plane is one ``_paged_gather`` would read
+    a page per DMA (on the chip, no mesh, pages of whole sublane tiles)
+    and its table has a block of whole lane tiles."""
+    from paddle_tpu.ops.pallas import paged_attention as _pk
+    return (_kv._gather_tier(flat, None, ps, mesh) == "pages"
+            and _pk.attend_block_pages(
+                s_len // ps, ps, row_bytes=_pk.attend_row_bytes(flat)) > 0)
+
+
 def attends_in_place(flat_c, ps, s_len, topk, mesh=None) -> bool:
     """Does a decode step over this geometry attend the latent plane's
     pages in place? Decided from what is being lowered: there is a
     selection (the cache is longer than index_topk), at most
-    ``ATTEND_PAGES_MAX_RATIO`` times longer, the plane is one
-    ``_paged_gather`` would read a page per DMA (on the chip, no mesh,
-    pages of whole sublane tiles) and its table has a block of whole
-    lane tiles."""
-    from paddle_tpu.ops.pallas import paged_attention as _pk
+    ``ATTEND_PAGES_MAX_RATIO`` times longer, and the plane is one a
+    kernel walks (``_walks_in_place``)."""
     return (topk < s_len <= ATTEND_PAGES_MAX_RATIO * topk
-            and _kv._gather_tier(flat_c, None, ps, mesh) == "pages"
-            and _pk.attend_block_pages(
-                s_len // ps, ps,
-                row_bytes=_pk.attend_row_bytes(flat_c)) > 0)
+            and _walks_in_place(flat_c, ps, s_len, mesh))
+
+
+def scores_in_place(flat_i, ps, s_len, topk, mesh=None) -> bool:
+    """Does a decode step over this geometry score the index plane's
+    pages in place? Decided from what is being lowered, as
+    ``attends_in_place``: there is a selection (the cache is longer than
+    index_topk) and the plane is one a kernel walks. No upper ratio:
+    unlike the attended plane's, whose other way reads index_topk rows
+    alone, the index plane's other way copies every row of every table,
+    and scoring in place never reads more than that."""
+    return s_len > topk and _walks_in_place(flat_i, ps, s_len, mesh)
 
 
 def live_rows(j, lens, gen0, pos):
@@ -422,6 +465,8 @@ def _mla_decode_paged(ctx, ins, attrs):
     int (the token's TRUE position: the rotation's) -> Out [B,1,M],
     PageCOut, PageIOut, Selected [B, min(index_topk, S)] int32 (the rows
     attended, -1 where fewer are live: what a check reads)."""
+    from paddle_tpu.ops import pallas as _plk
+    from paddle_tpu.ops.pallas import paged_attention as _pk
     x = first(ins, "X")
     w, a = _weights(ins), _sizes(attrs)
     b, dt = x.shape[0], x.dtype
@@ -451,13 +496,25 @@ def _mla_decode_paged(ctx, ins, attrs):
     topk = a["index_topk"]
     in_place = attends_in_place(flat_c, ps, s_len, topk, ctx.mesh)
     MLA_DECODE_LOWERED.labels(path="pages" if in_place else "rows").inc()
-    if s_len > topk:
+    index_in_place = scores_in_place(flat_i, ps, s_len, topk, ctx.mesh)
+    DSA_INDEX_LOWERED.labels(
+        path="pages" if index_in_place else "rows").inc()
+    # what a kernel walks of each table: an idle slot's rows are none
+    walked = lambda: (jnp.where(active, lens, 0), gen0,        # noqa: E731
+                      jnp.where(active, pos, -1))
+    if index_in_place:
+        # the scores of each slot's live pages where they lie: what a
+        # dead row's entry holds is not a score, and both selections
+        # below mask with ``valid`` before anything else
+        with _phase("index"):
+            scores = _pk.score_pages(
+                qi.astype(flat_i.dtype), wi, flat_i, table, *walked(), ps,
+                interpret=_plk.interpret_mode())
+    elif s_len > topk:
         with _phase("index"):
             keys = _kv._paged_gather(flat_i, None, table, ps, dt, ctx.mesh)
             scores = _slot_scores(qi, wi, keys)
     if in_place:
-        from paddle_tpu.ops import pallas as _plk
-        from paddle_tpu.ops.pallas import paged_attention as _pk
         # the selection as a MASK (a row of no live entry would keep
         # everything: the two thresholds are both -inf there), and the
         # kernel reads each active slot's live pages whole under it
@@ -467,8 +524,7 @@ def _mla_decode_paged(ctx, ins, attrs):
         with _phase("attend"):
             u = _pk.attend_pages(
                 absorbed_query(q, w, a, flat_c.dtype, flat_c.shape[-1]),
-                flat_c, table, jnp.where(active, lens, 0), gen0,
-                jnp.where(active, pos, -1), keep, ps, attention_scale(a),
+                flat_c, table, *walked(), keep, ps, attention_scale(a),
                 value_width=latent_width(a["kv_lora_rank"], 0),
                 interpret=_plk.interpret_mode())
             o = absorbed_context(u.astype(dt), w, a)

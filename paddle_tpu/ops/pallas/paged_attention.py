@@ -12,8 +12,9 @@ was transposed into this view, whole, before every call). So every run
 of ``page_size`` gathered rows is ONE WHOLE PAGE, contiguous in the
 pool and starting at a multiple of ``page_size`` rows. Three kernels
 COPY it out, and ``ops/kv_attention.py:_paged_gather`` picks between
-them from the storage dtype, the page size and the codec; a fourth,
-:func:`attend_pages`, copies nothing (below):
+them from the storage dtype, the page size and the codec; a fourth and
+a fifth, :func:`attend_pages` and :func:`score_pages`, copy nothing
+(below):
 
 - :func:`gather_pages` — ``pool.reshape(n_pages, ps, D)[pages]``, a
   page per DMA. Page ids ride in SMEM by scalar prefetch, pool and
@@ -58,6 +59,17 @@ attends it reads the copy again.
   key plane and a VALUE plane of another width under the same table (a
   full grouped-KV layer's decode step:
   ``ops/kv_attention.py:attends_in_place`` says where, PR 62).
+- :func:`score_pages` — the DSA indexer's scores ``sum_j w_j ReLU(q_j .
+  row)`` of a slot's LIVE rows of the index plane, read where they lie
+  (``ops/mla.py:scores_in_place`` says where, PR 66). It is
+  ``attend_pages``' walk with another block of work: ONE ``_page_walk``
+  in this file holds what the two share — the plan (``_attend_plan``:
+  the page to read for every table entry, the live blocks), the scalar
+  prefetch, the two tiles, the page copies and their waits, the
+  hand-over of the tiles from a slot to the next — and each kernel
+  brings what it does with a landed tile: an online softmax and ``p .
+  rows`` there, one product, a ReLU and a weighted sum over the
+  indexer's heads here, stored to the block's row of the slot's scores.
 
 Page WRITES (one row per decode step per slot, a whole prompt per
 prefill) stay on the jnp scatter-with-drop path in
@@ -190,40 +202,40 @@ def attend_block_pages(max_pages: int, page_size: int,
     return 0
 
 
-def _attend_pages_kernel(pages_ref, n1_ref, start2_ref, n_ref, q_ref,
-                         bias_ref, pool_hbm, *rest, ps, mp, pb, vw, n_slots,
-                         scale, precision, two_planes=False):
-    """One slot a grid step. SMEM (scalar prefetch): pages_ref [B * mp]
-    the page to READ for each entry of each slot's table (a dead entry of
-    a live block names a live page of that block: ``_attend_plan``),
-    n1_ref / start2_ref / n_ref [B] the slot's live blocks — block k of
-    its n is table block ``k`` while ``k < n1`` (the prompt's), ``start2
-    + k - n1`` after (the generated rows'). q_ref [1, H, W] and bias_ref
-    [1, mp / pb, pb * ps] float32 (0 where a row is attended, -inf where
-    not) in VMEM, pool_hbm [R, W] in HBM, out_ref [1, H, W]. buf
-    [2, pb * ps, W] the two tiles, sem [2] a DMA semaphore each, state
-    [2] in SMEM: the tile the next block lands in, and whether the
-    previous slot already started this slot's first block.
+def _tile_precision(dtype):
+    """How the in-place kernels multiply a tile: float32 planes at
+    precision HIGHEST, narrower ones as the MXU takes them."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
-    With ``two_planes`` the values are a plane of their own: values_hbm
-    [R, Wv] follows pool_hbm (the KEY plane), out_ref is [1, H, Wv], and
-    vbuf [2, pb * ps, Wv] / vsem [2] follow state — a page of the table
-    is a copy out of each plane, into the same rows of that plane's tile.
 
-    A block is pb pages aligned in the table (its rows' bias is one row
-    of bias_ref), a page a DMA, every block pb of them whatever it
-    holds: no branch in the walk. While block k is multiplied the copies
-    of the block after it are in flight — the NEXT slot's first after
-    this slot's last, and where nothing comes after, this block again,
-    waited for behind the loop and never read."""
-    if two_planes:
-        values_hbm, out_ref, buf, sem, state, vbuf, vsem = rest
-        planes = ((pool_hbm, buf, sem), (values_hbm, vbuf, vsem))
-    else:
-        out_ref, buf, sem, state = rest
-        planes = ((pool_hbm, buf, sem),)
+def _page_walk(pages_ref, n1_ref, start2_ref, n_ref, planes, state, *,
+               ps, mp, pb, n_slots):
+    """The walk over a slot's live blocks that the in-place kernels
+    share, one slot a grid step. SMEM (scalar prefetch): pages_ref
+    [B * mp] the page to READ for each entry of each slot's table (a dead
+    entry of a live block names a live page of that block:
+    ``_attend_plan``), n1_ref / start2_ref / n_ref [B] the slot's live
+    blocks — block k of its n is table block ``k`` while ``k < n1`` (the
+    prompt's), ``start2 + k - n1`` after (the generated rows'). planes:
+    one ``(hbm [R, W], tiles [2, pb * ps, W], sems [2])`` a plane read —
+    a page of the table is a copy out of each plane, into the same rows
+    of that plane's tile. state [2] in SMEM: the tile the next block
+    lands in, and whether the previous slot already started this slot's
+    first block.
+
+    A block is pb pages aligned in the table, a page a DMA, every block
+    pb of them whatever it holds: no branch in the walk. While block k
+    is worked on the copies of the block after it are in flight — the
+    NEXT slot's first after this slot's last, and where nothing comes
+    after, this block again, waited for behind the loop and never read.
+
+    Starts this slot's first block (unless the slot before did) and
+    returns ``(block_at, run)``: ``block_at(k)`` the table block the
+    slot's k-th live block is, and ``run(work, carry)``, which walks the
+    live blocks — ``work(k, side, carry) -> carry`` finds block k landed
+    in tile ``side`` of every plane — and hands the tiles over to the
+    next slot."""
     b = pl.program_id(0)
-    f32 = jnp.float32
 
     def block_of(slot, k):
         n1 = n1_ref[slot]
@@ -286,21 +298,60 @@ def _attend_pages_kernel(pages_ref, n1_ref, start2_ref, n_ref, q_ref,
     nxt = jnp.minimum(b + 1, n_slots - 1)
     follows = (b + 1 < n_slots) & (n_ref[nxt] > 0)
     state[1] = ((n > 0) & follows).astype(jnp.int32)
+
+    def run(work, carry):
+        def block(k, carry):
+            side = (side0 + k) % 2
+            last = k + 1 == n
+            start(jnp.where(last & follows, nxt, b),
+                  jnp.where(last, jnp.where(follows, 0, k), k + 1),
+                  1 - side, inline=True)
+            wait(side, inline=True)
+            return work(k, side, carry)
+
+        carry = jax.lax.fori_loop(0, n, block, carry)
+
+        @pl.when((n > 0) & jnp.logical_not(follows))
+        def _():
+            wait((side0 + n) % 2, inline=False)
+        state[0] = (side0 + n) % 2
+        return carry
+
+    return (lambda k: block_of(b, k)), run
+
+
+def _attend_pages_kernel(pages_ref, n1_ref, start2_ref, n_ref, q_ref,
+                         bias_ref, pool_hbm, *rest, ps, mp, pb, vw, n_slots,
+                         scale, precision, two_planes=False):
+    """One slot a grid step, over the blocks ``_page_walk`` brings (its
+    docstring has the scalar-prefetch operands, the tiles, the
+    semaphores and ``state``). q_ref [1, H, W] and bias_ref [1, mp / pb,
+    pb * ps] float32 (0 where a row is attended, -inf where not) in
+    VMEM, pool_hbm [R, W] in HBM, out_ref [1, H, W], buf [2, pb * ps, W]
+    the two tiles, sem [2] a DMA semaphore each.
+
+    With ``two_planes`` the values are a plane of their own: values_hbm
+    [R, Wv] follows pool_hbm (the KEY plane), out_ref is [1, H, Wv], and
+    vbuf [2, pb * ps, Wv] / vsem [2] follow state. A block's rows' bias
+    is one row of bias_ref."""
+    if two_planes:
+        values_hbm, out_ref, buf, sem, state, vbuf, vsem = rest
+        planes = ((pool_hbm, buf, sem), (values_hbm, vbuf, vsem))
+    else:
+        out_ref, buf, sem, state = rest
+        planes = ((pool_hbm, buf, sem),)
+    f32 = jnp.float32
+    block_at, run = _page_walk(pages_ref, n1_ref, start2_ref, n_ref, planes,
+                               state, ps=ps, mp=mp, pb=pb, n_slots=n_slots)
     q = q_ref[0]
 
-    def block(k, carry):
+    def work(k, side, carry):
         m, l, acc = carry
-        side = (side0 + k) % 2
-        last = k + 1 == n
-        start(jnp.where(last & follows, nxt, b),
-              jnp.where(last, jnp.where(follows, 0, k), k + 1), 1 - side,
-              inline=True)
-        wait(side, inline=True)
         tile = buf[side]
         s = jax.lax.dot_general(q, tile, (((1,), (1,)), ((), ())),
                                 precision=precision,
                                 preferred_element_type=f32)
-        s = s * scale + bias_ref[0, pl.ds(block_of(b, k), 1), :]
+        s = s * scale + bias_ref[0, pl.ds(block_at(k), 1), :]
         top = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # a row with nothing attended yet keeps -inf: exp of (-inf - 0)
         # is the 0 it should be, of (-inf + inf) a NaN
@@ -315,14 +366,8 @@ def _attend_pages_kernel(pages_ref, n1_ref, start2_ref, n_ref, q_ref,
         return top, fade * l + jnp.sum(p, axis=-1, keepdims=True), acc
 
     h, w = q.shape
-    m, l, acc = jax.lax.fori_loop(
-        0, n, block, (jnp.full((h, 1), -jnp.inf, f32),
-                      jnp.zeros((h, 1), f32), jnp.zeros((h, vw), f32)))
-
-    @pl.when((n > 0) & jnp.logical_not(follows))
-    def _():
-        wait((side0 + n) % 2, inline=False)
-    state[0] = (side0 + n) % 2
+    m, l, acc = run(work, (jnp.full((h, 1), -jnp.inf, f32),
+                           jnp.zeros((h, 1), f32), jnp.zeros((h, vw), f32)))
     out = (acc / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
     if two_planes:
         out_ref[0] = out
@@ -330,6 +375,34 @@ def _attend_pages_kernel(pages_ref, n1_ref, start2_ref, n_ref, q_ref,
     if vw < w:
         out_ref[0, :, vw:] = jnp.zeros((h, w - vw), out_ref.dtype)
     out_ref[0, :, :vw] = out
+
+
+def _score_pages_kernel(pages_ref, n1_ref, start2_ref, n_ref, q_ref, w_ref,
+                        pool_hbm, out_ref, buf, sem, state, *, ps, mp, pb,
+                        n_slots, precision):
+    """One slot a grid step, over the blocks ``_page_walk`` brings:
+    q_ref [1, J, W] the indexer's queries and w_ref [1, J, 1] float32
+    their head weights in VMEM, pool_hbm [R, W] the index plane in HBM,
+    out_ref [1, mp / pb, pb * ps] float32 the slot's scores, a table
+    block a row: zeros, then row ``block_at(k)`` = ``sum_j w_j ReLU(q_j .
+    tile^T)`` for each live block k. buf [2, pb * ps, W], sem [2] and
+    state [2] as the walk's."""
+    f32 = jnp.float32
+    block_at, run = _page_walk(pages_ref, n1_ref, start2_ref, n_ref,
+                               ((pool_hbm, buf, sem),), state, ps=ps, mp=mp,
+                               pb=pb, n_slots=n_slots)
+    out_ref[...] = jnp.zeros(out_ref.shape, f32)
+    q, w = q_ref[0], w_ref[0]
+
+    def work(k, side, carry):
+        dots = jax.lax.dot_general(q, buf[side], (((1,), (1,)), ((), ())),
+                                   precision=precision,
+                                   preferred_element_type=f32)
+        out_ref[0, pl.ds(block_at(k), 1), :] = jnp.sum(
+            jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)
+        return carry
+
+    run(work, 0)
 
 
 def _live_blocks(lens, gen0, pos, ps, pb, xp):
@@ -462,9 +535,7 @@ def attend_pages(q, pool, table, lens, gen0, pos, keep, page_size: int,
         functools.partial(
             _attend_pages_kernel, ps=page_size, mp=mp, pb=pb, vw=vw,
             n_slots=b, scale=float(scale),
-            precision=(jax.lax.Precision.HIGHEST
-                       if pool.dtype == jnp.float32 else None),
-            two_planes=two_planes),
+            precision=_tile_precision(pool.dtype), two_planes=two_planes),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, wo), pool.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -472,6 +543,67 @@ def attend_pages(q, pool, table, lens, gen0, pos, keep, page_size: int,
         interpret=interpret,
         name="attend_pages",
     )(*plan, q, bias, pool, *([values] if two_planes else []))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "page_size", "block_rows", "interpret"))
+def score_pages(qi, wi, pool, table, lens, gen0, pos, page_size: int,
+                block_rows: int = _ATTEND_ROWS, interpret=False):
+    """The DSA indexer's scores of each slot's LIVE rows of a paged
+    index plane, read IN PLACE: qi [B, J, W] (the pool's dtype) the
+    slot's J indexer queries, wi [B, J] float32 their weights, pool
+    [R, W] (the flat view of ``R // page_size`` pages of indexer keys),
+    table [B, MP] int page ids, lens / gen0 / pos [B] int the slot's live
+    rows as :func:`attend_pages` takes them -> [B, MP * page_size]
+    float32: ``sum_j wi_j ReLU(qi_j . row)`` at every live row — the
+    products in the pool's dtype (float32 at precision HIGHEST),
+    accumulated, weighed and summed over J in float32
+    (``ops/mla.py:_slot_scores`` over the gathered plane, without the
+    gathered plane).
+
+    It walks what :func:`attend_pages` walks (``_attend_plan``,
+    ``_page_walk``: a live page a DMA into one of two VMEM tiles, the
+    next block's under this block's product) and costs by the live rows.
+    A table block the walk never visits — past a slot's live rows, an
+    idle slot's — holds ZEROS, and the dead rows of a live block hold the
+    scores of whatever live page was read in their place: only a live
+    row's entry is a score, and every reader masks with the live rows
+    first (``ops/mla.py:select_topk`` and ``jax.lax.top_k``'s branch
+    both do)."""
+    b, j, w = qi.shape
+    mp = table.shape[1]
+    pb = attend_block_pages(mp, page_size, block_rows,
+                            attend_row_bytes(pool))
+    if not pb:
+        raise ValueError(f"no block of whole lane tiles divides a table "
+                         f"of {mp} pages of {page_size} rows")
+    rows = pb * page_size
+    plan = _attend_plan(table, lens, gen0, pos, page_size, pb,
+                        pool.shape[0] // page_size)
+    per_slot = lambda *block: pl.BlockSpec(                    # noqa: E731
+        (1,) + block, lambda i, *_: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,          # the pages and the blocks: SMEM
+        grid=(b,),
+        in_specs=[per_slot(j, w), per_slot(j, 1),
+                  pl.BlockSpec(memory_space=pl.ANY)],   # the plane in HBM
+        out_specs=per_slot(mp // pb, rows),
+        scratch_shapes=[pltpu.VMEM((2, rows, w), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((2,), jnp.int32)],
+    )
+    scores = pl.pallas_call(
+        functools.partial(
+            _score_pages_kernel, ps=page_size, mp=mp, pb=pb, n_slots=b,
+            precision=_tile_precision(pool.dtype)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, mp // pb, rows), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),    # slots in order
+        interpret=interpret,
+        name="score_pages",
+    )(*plan, qi, wi.astype(jnp.float32)[:, :, None], pool)
+    return scores.reshape(b, mp * page_size)
 
 
 def gather_rows_dequant(pool, scales, rows, heads: int,

@@ -80,6 +80,17 @@ def _attend_pages(dtype, ps):
          ((b, s_len), jnp.bool_)])
 
 
+def _score_pages(dtype, ps):
+    # the latent cell's indexer (ISSUE 66): 32 slots of 12 288 rows of
+    # indexer keys 128 wide, 32 indexer heads, the scores float32
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    b, s_len = 32, 12288
+    return (lambda qi, wi, pool, t, lens, g0, pos: pa.score_pages(
+        qi, wi, pool, t, lens, g0, pos, page_size=ps),
+        [((b, 32, 128), dtype), ((b, 32), F32), ((b * s_len, 128), dtype),
+         ((b, s_len // ps), I32), ((b,), I32), ((b,), I32), ((b,), I32)])
+
+
 def _attend_two_planes(b, s_len, wk, wv, heads):
     # a full grouped-KV layer's decode attention in place (ISSUE 62) at
     # a cell's own size: b slots of s_len rows in bf16 pages of 16, the
@@ -254,6 +265,8 @@ CASES = {
     "paged_gather_pages-bf16-49152x1024": lambda: _paged_gather_pages(BF16),
     "attend_pages-bf16-ps16-32x12288x640": lambda: _attend_pages(BF16, 16),
     "attend_pages-f32-ps8-32x12288x640": lambda: _attend_pages(F32, 8),
+    "score_pages-bf16-ps16-32x12288x128": lambda: _score_pages(BF16, 16),
+    "score_pages-f32-ps8-32x12288x128": lambda: _score_pages(F32, 8),
     # MiMo's full layers (64 heads over 4 of 192 / 128), Trinity's (32
     # over 4 of 128) and Granite's (32 over 8 of 128, blocks of 256 rows:
     # 176 pages have no longer one of whole lane tiles)
@@ -891,24 +904,27 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
                                              monkeypatch, path):
     """32 slots of 12 288 rows, bf16, five ``mla`` layers (one dense, four
     with 16 of 256 experts): the step fits one chip with the 3.02 GB of
-    latent and index planes donated and aliased in place; per layer it
-    holds one ``gather_pages`` of the INDEX plane alone and the scoring
-    under a result shape of its own (``f32[32,96,128]``: what the
-    benchmark's reader selects). The cache is 6 x index_topk long, so
-    the selection is a mask found by threshold and ``attend_pages``
-    reads the latent plane's live pages under it (``pages``, PR 34): a
-    second kernel a layer whose result is the attended latent
-    ``bf16[32,64,640]``, no ``sort`` of a slot's 12 288 scores (nor of
-    the mask's positions: ``Selected`` is not fetched, and gone), no
-    array of the 65 536 selected rows. Past ``ATTEND_PAGES_MAX_RATIO``
-    (``rows``: the same geometry under a smaller ratio) the step is
-    PR 33's: ``jax.lax.top_k``, the chosen rows ordered by position and
-    gathered ``bf16[65536,640]``. Neither way copies or gathers a whole
-    plane. Each of the four expert layers (32 tokens of 8 picks over a
-    router 256 wide: a uniform draw leaves 36 % of the held experts
-    unpicked) streams its HIT experts' weights through ONE kernel whose
-    result is the float32 sum ``f32[32,6144]`` (ISSUE 54): no product of
-    every held expert over every token (``[32,16,2048]``) is left."""
+    latent and index planes donated and aliased in place. Per layer the
+    indexer's scores are ONE ``score_pages`` call over the index plane's
+    live pages where they lie (ISSUE 66), its result a table block a row
+    (``f32[32,12,1024]``): no ``gather_pages`` of the plane's 393 216
+    rows and no scoring of a copy (``f32[32,96,128]``) is left. The
+    cache is 6 x index_topk long, so the selection is a mask found by
+    threshold and ``attend_pages`` reads the latent plane's live pages
+    under it (``pages``, PR 34): a second kernel a layer whose result is
+    the attended latent ``bf16[32,64,640]``, no ``sort`` of a slot's
+    12 288 scores (nor of the mask's positions: ``Selected`` is not
+    fetched, and gone), no array of the 65 536 selected rows. Past
+    ``ATTEND_PAGES_MAX_RATIO`` (``rows``: the same geometry under a
+    smaller ratio) what follows the scores is PR 33's:
+    ``jax.lax.top_k``, the chosen rows ordered by position and gathered
+    ``bf16[65536,640]`` — the scores still come in place, the indexer
+    has no ratio. Neither way copies or gathers a whole plane. Each of
+    the four expert layers (32 tokens of 8 picks over a router 256 wide:
+    a uniform draw leaves 36 % of the held experts unpicked) streams its
+    HIT experts' weights through ONE kernel whose result is the float32
+    sum ``f32[32,6144]`` (ISSUE 54): no product of every held expert
+    over every token (``[32,16,2048]``) is left."""
     from paddle_tpu.ops import expert_ffn, mla
     eng, programs = glm5_engine
     if path == "rows":
@@ -918,6 +934,8 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
         eng._cb_decode.fn.clear_cache()
     lowered = {p: mla.MLA_DECODE_LOWERED.labels(path=p).value
                for p in ("pages", "rows")}
+    index = {p: mla.DSA_INDEX_LOWERED.labels(path=p).value
+             for p in ("pages", "rows")}
     experts = {p: expert_ffn.EXPERT_DENSE_LOWERED.labels(path=p).value
                for p in ("skip", "all")}
     compiled = _compile_view(chip, programs, "decode_paged", eng._cb_decode,
@@ -925,6 +943,9 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
     for p, was in lowered.items():
         assert mla.MLA_DECODE_LOWERED.labels(path=p).value - was \
             == (5 if p == path else 0)
+    for p, was in index.items():
+        assert mla.DSA_INDEX_LOWERED.labels(path=p).value - was \
+            == (5 if p == "pages" else 0)
     for p, was in experts.items():
         assert expert_ffn.EXPERT_DENSE_LOWERED.labels(path=p).value - was \
             == (4 if p == "skip" else 0)
@@ -940,11 +961,14 @@ def test_latent_decode_step_compiles_for_v5e(chip, glm5_engine,
         == (14 if in_place else 9)
     assert len(re.findall(r"= f32\[32,6144\]\S* custom-call\(", entry)) == 4
     assert "[32,16,2048]" not in text
-    assert len(re.findall(r"= bf16\[393216,128\]\S* custom-call\(", entry)) \
+    # the indexer: five ``score_pages``, no copy of an index plane and
+    # no scoring of one
+    assert len(re.findall(r"= f32\[32,12,1024\]\S* custom-call\(", entry)) \
         == 5
+    assert not re.findall(r"= bf16\[393216,128\]\S* custom-call\(", entry)
+    assert "[32,96,128]" not in text
     assert len(re.findall(r"= bf16\[32,64,640\]\S* custom-call\(", entry)) \
         == (5 if in_place else 0)
-    assert len(re.findall(r"= f32\[32,96,128\]\S* fusion\(", entry)) == 5
     rows = len(re.findall(r"= bf16\[65536,640\]\S* fusion\(", entry))
     # the selection's five full sorts, or none
     sorts = len(re.findall(r"= \(f32\[32,12288\]\S*, s32\[32,12288\]\S*\) "

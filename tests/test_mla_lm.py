@@ -330,13 +330,14 @@ def test_selected_is_where_the_mask_holds(case):
                                       want)
 
 
-def _decode_op(rng, s_len, ps, topk, n_pages=None, b=3):
+def _decode_op(rng, s_len, ps, topk, n_pages=None, b=3, di=16):
     """(emit, ins) of one ``mla_decode_paged`` layer over ``b`` slots of
-    ``s_len`` rows in pages of ``ps``: slot 0 long past index_topk, slot
-    1 under it with padding before its bucket, slot 2 inactive."""
+    ``s_len`` rows in pages of ``ps``, indexer keys ``di`` wide: slot 0
+    long past index_topk, slot 1 under it with padding before its
+    bucket, slot 2 inactive."""
     import types
     from paddle_tpu.core.registry import get_op
-    a = {**SIZES, "index_topk": topk}
+    a = {**SIZES, "index_topk": topk, "index_head_dim": di}
     mp = s_len // ps
     n_pages = n_pages or b * mp
     w = _layer_weights(rng, a)
@@ -345,7 +346,7 @@ def _decode_op(rng, s_len, ps, topk, n_pages=None, b=3):
     col = lambda *v: jnp.asarray(v, jnp.int32)[:, None]  # noqa: E731
     ins = {**w, "X": jnp.asarray(rng.randn(b, 1, 64), jnp.float32),
            "PageC": jnp.asarray(rng.randn(n_pages, ps, width), jnp.float32),
-           "PageI": jnp.asarray(rng.randn(n_pages, ps, 16), jnp.float32),
+           "PageI": jnp.asarray(rng.randn(n_pages, ps, di), jnp.float32),
            "PageTable": jnp.asarray(
                rng.permutation(n_pages)[:b * mp].reshape(b, mp), jnp.int32),
            "SeqLen": col(bucket - 3, 5, 0), "GenStart": col(bucket, bucket, 0),
@@ -362,15 +363,22 @@ def _decode_op(rng, s_len, ps, topk, n_pages=None, b=3):
     return emit, ins
 
 
-def test_in_place_and_gathered_decode_agree(monkeypatch):
+@pytest.mark.parametrize("index", ["pages", "rows"])
+def test_in_place_and_gathered_decode_agree(monkeypatch, index):
     """One layer's decode step both ways over the same planes — the
-    kernel interpreted, engaged as it would be on the chip — gives the
-    same context and the same ``Selected``."""
+    kernels interpreted, engaged as they would be on the chip — gives
+    the same context and the same ``Selected``: the latent plane
+    attended in place under scores of the index plane read in place
+    (``pages``, PR 66) or of its gathered copy (``rows``, PR 34's)."""
     from paddle_tpu.ops import kv_attention as kv
     emit, ins = _decode_op(np.random.RandomState(23), s_len=256, ps=8,
                            topk=64)
+    if index == "rows":
+        monkeypatch.setattr(mla, "scores_in_place", lambda *a: False)
+    lowered = mla.DSA_INDEX_LOWERED.labels(path=index)
     with jax.default_matmul_precision("highest"):
         rows = emit(ins)
+        was = lowered.value
         monkeypatch.setattr(
             kv, "_gather_tier", lambda flat, scales, ps, mesh=None: "pages")
         monkeypatch.setattr(
@@ -378,6 +386,7 @@ def test_in_place_and_gathered_decode_agree(monkeypatch):
             jnp.take(flat.reshape(-1, ps, flat.shape[-1]), table, axis=0)
             .reshape(table.shape[0], -1, flat.shape[-1]).astype(dt))
         pages = emit(ins)
+    assert lowered.value == was + 1
     live = np.asarray(ins["Active"])[:, 0] > 0
     np.testing.assert_array_equal(pages["Selected"], rows["Selected"])
     assert (np.asarray(pages["Selected"])[0] >= 0).all()          # 64 of 211
@@ -391,29 +400,41 @@ def test_in_place_and_gathered_decode_agree(monkeypatch):
         np.testing.assert_array_equal(pages[plane], rows[plane])
 
 
-@pytest.mark.parametrize("s_len,path", [
-    (128 * mla.ATTEND_PAGES_MAX_RATIO, "pages"),
-    (128 * mla.ATTEND_PAGES_MAX_RATIO + 128, "rows"),      # past the ratio
-    (64, "rows")])                        # everything attended: no selection
-def test_the_lowering_counter_names_the_path(monkeypatch, s_len, path):
-    """``paddle_mla_decode_lowered_total``: one increment a layer each
+@pytest.mark.parametrize("s_len,path,index", [
+    (128 * mla.ATTEND_PAGES_MAX_RATIO, "pages", "pages"),
+    # past the ratio the selected rows are gathered; the indexer, whose
+    # other way copies the whole plane, still scores in place
+    (128 * mla.ATTEND_PAGES_MAX_RATIO + 128, "rows", "pages"),
+    # 184 pages of 8 rows: no block of whole lane tiles divides them
+    (1472, "rows", "rows"),
+    (64, "rows", "rows")])      # everything attended: no selection, no score
+def test_the_lowering_counter_names_the_path(monkeypatch, s_len, path,
+                                             index):
+    """``paddle_mla_decode_lowered_total`` and
+    ``paddle_dsa_index_lowered_total``: one increment each a layer each
     time a decode program is traced, by what the geometry got — decided
-    from the cache's length over index_topk where the plane is one the
-    chip reads a page per DMA, and ``rows`` everywhere off the chip."""
+    from the cache's length over index_topk and the table's blocks where
+    the plane is one the chip reads a page per DMA, and ``rows``
+    everywhere off the chip."""
     from paddle_tpu.ops import pallas as pk
+    # indexer keys a lane tile wide, as the chip's kernels ask
     emit, ins = _decode_op(np.random.RandomState(1), s_len=s_len, ps=8,
-                           topk=128)
-    read = lambda: {p: mla.MLA_DECODE_LOWERED.labels(path=p).value  # noqa
-                    for p in ("pages", "rows")}
+                           topk=128, di=128)
+    want = {mla.MLA_DECODE_LOWERED: path, mla.DSA_INDEX_LOWERED: index}
+    read = lambda: {c: {p: c.labels(path=p).value              # noqa: E731
+                        for p in ("pages", "rows")} for c in want}
     before = read()
     jax.eval_shape(emit, ins)                       # here: the CPU
-    assert read() == {**before, "rows": before["rows"] + 1}
+    assert read() == {c: {**was, "rows": was["rows"] + 1}
+                      for c, was in before.items()}
     monkeypatch.setattr(pk, "on_tpu", lambda: True)
     jax.eval_shape(lambda i: emit(i), ins)          # as on the chip
-    after = read()
-    assert after[path] == before[path] + 1 + (path == "rows")
-    assert sum(after.values()) == sum(before.values()) + 2
+    for counter, after in read().items():
+        was, got = before[counter], want[counter]
+        assert after[got] == was[got] + 1 + (got == "rows")
+        assert sum(after.values()) == sum(was.values()) + 2
     from paddle_tpu.observability import exporters, metrics as obs_metrics
     exporters._preregister_catalog()
-    assert "paddle_mla_decode_lowered_total" in \
-        obs_metrics.default_registry().snapshot()
+    for family in ("paddle_mla_decode_lowered_total",
+                   "paddle_dsa_index_lowered_total"):
+        assert family in obs_metrics.default_registry().snapshot()

@@ -842,6 +842,57 @@ def test_attend_pages_matches_the_gathered_path(case, dtype):
         assert np.abs(got - ignored[:, None, :got.shape[-1]]).max() > 1.0
 
 
+# ------------------------------------------------------------------ ISSUE 66
+# score_pages: the DSA indexer's scores of a slot's live pages in place,
+# against the gathered path (the page gather, then mla._slot_scores)
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("case", [
+    "random_masks", "inactive_slot", "bucket_padding", "part_written_page",
+    "sentinel_pages"])
+def test_score_pages_matches_the_gathered_scores(case, dtype):
+    """The kernel interpreted against ``_paged_gather`` +
+    ``_slot_scores``, the path it replaces, on every LIVE row (a dead
+    row's entry is no score): generated rows that start a block of their
+    own, an idle slot and a prompt of no row, prompts shorter than their
+    bucket (the padding holds 1e30), a part-written last page, sentinel
+    table entries. A table block without a live row is never walked and
+    holds zeros."""
+    from paddle_tpu.ops import kv_attention as kv
+    from paddle_tpu.ops import mla
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    qi, pool, table, lens, gen0, pos, _, ps = _attend_case(case, dtype)
+    b, s_len = table.shape[0], table.shape[1] * ps
+    wi = jnp.asarray(np.random.RandomState(5).randn(b, qi.shape[1]),
+                     jnp.float32)
+    got = np.asarray(pa.score_pages(qi, wi, pool, table, lens, gen0, pos,
+                                    ps, block_rows=128, interpret=True))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(mla._slot_scores(
+            qi, wi, kv._paged_gather(pool, None, table, ps, dtype)))
+    live = np.asarray(mla.live_rows(jnp.arange(s_len), lens, gen0, pos))
+    assert got.shape == want.shape == (b, s_len) and got.dtype == np.float32
+    assert live.any(axis=1).sum() == (b - 1 if case == "inactive_slot"
+                                      else b)
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    np.testing.assert_allclose(got[live], want[live],
+                               atol=tol * np.abs(want[live]).max())
+    dead = ~live.reshape(b, -1, 128).any(axis=-1)
+    assert not got.reshape(b, -1, 128)[dead].any()
+    if case == "inactive_slot":
+        assert dead[1].all()
+
+
+def test_score_pages_refuses_a_table_without_a_block():
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)           # noqa: E731
+    with pytest.raises(ValueError, match="no block of whole lane tiles"):
+        pa.score_pages(jnp.zeros((2, 4, 128)), jnp.zeros((2, 4)),
+                       jnp.zeros((80, 128)), i32(2, 5), i32(2), i32(2),
+                       i32(2), 8, interpret=True)
+
+
 # ------------------------------------------------------------ ISSUEs 58, 62
 # attend_pages with a VALUE plane: a full grouped-KV layer's decode
 # attention over its live pages in place, against the path it replaces

@@ -25,18 +25,18 @@ attends its live pages in place where ``ops/kv_attention.py:
 attends_in_place`` holds; the tiny presets' tables lie under its floor,
 so their views stay the parent's and say nothing of the new path):
 
-- every view of the eight served configurations AT THE COMMITTED SIZE
+- every view of the nine served configurations AT THE COMMITTED SIZE
   (``chipbench/configs/<name>.json``, ``init=False``: structs in, text
-  out) lowered for the described v5e against the parent's digest: all
-  the same but the decode views of the four configurations whose full
-  layers attend in place, which are LISTED (``IN_PLACE_LAYERS``) and
-  must differ, with ``paddle_kv_decode_attend_lowered_total`` read
-  beside each — and, since PR 64, the prefill views of more than 512
-  tokens of the five configurations whose expert layers take the
-  grouped way (``GROUPED_KERNEL_PREFILLS``, listed, must differ: their
-  three grouped products are the row-tiled kernel's two calls on a
-  chip, ``ops/expert_ffn.py:grouped_path``), with
-  ``paddle_expert_grouped_lowered_total`` read beside each;
+  out) lowered for the described v5e against the parent's digest. Since
+  PR 66 the digests are written anew on each PR's PARENT (PR 62's
+  in-place decode views and PR 64's grouped prefill views are in them),
+  so all are the same but the views ``DIFFERS`` lists — what this PR
+  changed on purpose: GLM-5's decode view, whose five latent layers
+  score their index planes in place, with
+  ``paddle_dsa_index_lowered_total`` read beside it. The older paths'
+  counters (``paddle_kv_decode_attend_lowered_total``,
+  ``paddle_expert_grouped_lowered_total``) are still read beside every
+  configuration: they hold what the parent's text holds;
 - the ONE op ``kv_attention_decode_paged`` lowered alone at each of the
   seven served configurations' PUBLISHED full-layer geometries for the
   described v5e (structs in, text out: nothing allocated, compiled or
@@ -47,7 +47,8 @@ so their views stay the parent's and say nothing of the new path):
   value width): the digest taken on the parent. The views' digests cut
   every kernel's serialized body out, so they cannot see a kernel
   change; the jaxpr is what the body is lowered from, and it carries no
-  source location.
+  source location. ``score_pages`` (PR 66), which walks the same pages
+  through the same helper, has its own recorded beside it.
 """
 
 import functools
@@ -184,28 +185,41 @@ def test_the_configuration_lowers_the_parents_text(name, sharding, request):
 
 
 # configuration -> its full softmax layers, all of which attend IN PLACE
-# in the decode view at the committed size (the ONE view of each that
-# does not lower the parent's text) ...
+# in the decode view at the committed size ...
 IN_PLACE_LAYERS = {"mimo_v2_flash_ep16_d7": 2, "trinity_mini_26b_d5": 1,
                    "granite4_h_small_ep4_d10": 1,
                    "olmo_hybrid_7b_pp2_d16": 4, "jamba2_3b": 2}
-# a configuration added AFTER the in-place attend and the grouped kernel:
-# its committed digests were taken with both, so no view differs
-OWN_TEXT = {"jamba2_3b"}
 # ... or all by copy (GLM-5 has no such layer: its planes are latent)
 COPY_LAYERS = {"gpt2_medium_d12": 12, "solar_open2_250b_ep8_d4": 1,
                "lfm2_8b_a1b_d12": 3, "glm5_744b_ep16_d5": 0}
 # configuration -> its prompt buckets over ``DENSE_MAX_TOKENS``: the
 # prefill views whose expert layers take the grouped way, their products
-# through ``ops/pallas/grouped_matmul.py`` on a chip (PR 64) — the only
-# prefill views that do not lower the parent's text. Solar's buckets end
-# at 512 (the dense way); gpt2's and Olmo-Hybrid's stacks hold no expert
+# through ``ops/pallas/grouped_matmul.py`` on a chip (PR 64). Solar's
+# buckets end at 512 (the dense way); gpt2's, Olmo-Hybrid's and Jamba2's
+# stacks hold no expert
 GROUPED_KERNEL_PREFILLS = {
     "glm5_744b_ep16_d5": (6144, 8192),
     "granite4_h_small_ep4_d10": (1024, 2048),
     "lfm2_8b_a1b_d12": (2048, 4096),
     "mimo_v2_flash_ep16_d7": (4096, 8192, 16384, 32768),
     "trinity_mini_26b_d5": (2048, 4096, 8192, 16384)}
+# configuration -> the views that do NOT lower the parent's text, and
+# must not: what THIS PR changed on purpose. The committed digests are
+# the parent's own (written anew on it: PR 62's in-place decode views
+# and PR 64's grouped prefill views are in them), so the list is one
+# PR's — PR 66: GLM-5's decode step scores its five latent layers' index
+# planes in place (``ops/mla.py:scores_in_place``)
+DIFFERS = {"glm5_744b_ep16_d5": ["decode_paged"]}
+# configuration -> its latent layers, whose indexer scores in place
+INDEX_IN_PLACE_LAYERS = {"glm5_744b_ep16_d5": 5}
+
+
+def _grew(counter, paths):
+    """A reader of how far ``counter`` grew, by path, since this call."""
+    read = lambda: {p: counter.labels(path=p).value            # noqa: E731
+                    for p in paths}
+    before = read()
+    return lambda: {p: n - before[p] for p, n in read().items()}
 
 
 @pytest.mark.parametrize("sharding", ["chip"], indirect=True)
@@ -213,45 +227,46 @@ GROUPED_KERNEL_PREFILLS = {
 def test_the_committed_size_lowers_the_parents_text_but_in_place(
         name, sharding):
     """The cell's own configuration file, every view: the parent's text
-    letter for letter, but the decode view of a configuration whose full
-    layers attend in place — and there the path counter says so for
-    every full layer, as it says ``copy`` for every one elsewhere — and
-    the long prefill views whose grouped products are the kernel's
-    (PR 64), where that path's counter says ``kernel`` and never
-    ``ragged_dot``."""
-    from paddle_tpu.ops import expert_ffn
+    letter for letter, but the views ``DIFFERS`` lists — there the path
+    counter of what changed says so for every layer it changed (the
+    indexer's, ``pages`` five times a trace of GLM-5's decode view), and
+    nowhere else does it move. The older paths' counters hold what the
+    parent's text holds: a full layer's decode attends ``in_place`` or
+    by ``copy`` for every one of a configuration, and the long prefill
+    views' grouped products are the ``kernel``'s (PR 64), never
+    ``ragged_dot``'s."""
+    from paddle_tpu.ops import expert_ffn, mla
     from paddle_tpu.ops import kv_attention as kv
     with open(os.path.join(os.path.dirname(HERE), "chipbench", "configs",
                            name + ".json")) as f:
         cfg = json.load(f)
-    read = lambda: {p: kv.KV_DECODE_ATTEND_LOWERED.labels(     # noqa: E731
-        path=p).value for p in ("in_place", "copy")}
-    grouped = lambda: {p: expert_ffn.EXPERT_GROUPED_LOWERED.labels(  # noqa
-        path=p).value for p in ("kernel", "ragged_dot")}
-    before, grouped_before = read(), grouped()
+    attend = _grew(kv.KV_DECODE_ATTEND_LOWERED, ("in_place", "copy"))
+    grouped = _grew(expert_ffn.EXPERT_GROUPED_LOWERED,
+                    ("kernel", "ragged_dot"))
+    index = _grew(mla.DSA_INDEX_LOWERED, ("pages", "rows"))
     got = view_digests(cfg, sharding)
-    grew = {p: n - before[p] for p, n in read().items()}
-    grouped_grew = {p: n - grouped_before[p] for p, n in grouped().items()}
+    attend, grouped, index = attend(), grouped(), index()
     if WRITE:
         return record(name, "committed", got)
     with open(DATA) as f:
         want = json.load(f)["digests"][name]["committed"]
     assert sorted(got) == sorted(want)
+    assert [k for k in sorted(want) if got[k] != want[k]] \
+        == DIFFERS.get(name, [])
+    # one increment a latent layer and trace of the decode view
+    layers = INDEX_IN_PLACE_LAYERS.get(name, 0)
+    assert not index["rows"] and index["pages"] % max(layers, 1) == 0
+    assert bool(index["pages"]) == bool(layers)
+    kernel_views = GROUPED_KERNEL_PREFILLS.get(name, ())
+    assert all(p > expert_ffn.DENSE_MAX_TOKENS for p in kernel_views)
+    assert not grouped["ragged_dot"]
+    assert bool(grouped["kernel"]) == bool(kernel_views)
+    # one increment a full layer and trace of the decode view
     path, layers = ("in_place", IN_PLACE_LAYERS[name]) \
         if name in IN_PLACE_LAYERS else ("copy", COPY_LAYERS[name])
-    kernel_views = [f"prefill_paged@{p}"
-                    for p in GROUPED_KERNEL_PREFILLS.get(name, ())]
-    assert all(p > expert_ffn.DENSE_MAX_TOKENS
-               for p in GROUPED_KERNEL_PREFILLS.get(name, ()))
-    assert [k for k in sorted(want) if got[k] != want[k]] == (
-        [] if name in OWN_TEXT else sorted(
-            ["decode_paged"] * (path == "in_place") + kernel_views))
-    assert not grouped_grew["ragged_dot"]
-    assert bool(grouped_grew["kernel"]) == bool(kernel_views)
-    # one increment a full layer and trace of the decode view
     other = "copy" if path == "in_place" else "in_place"
-    assert not grew[other] and grew[path] % max(layers, 1) == 0
-    assert bool(grew[path]) == bool(layers)
+    assert not attend[other] and attend[path] % max(layers, 1) == 0
+    assert bool(attend[path]) == bool(layers)
 
 
 def _served_full_layers():
@@ -325,6 +340,38 @@ def _latent_attend_jaxprs() -> dict:
             value_width=vw))(*args)
         out[case] = hashlib.sha256(str(jaxpr).encode()).hexdigest()
     return out
+
+
+def _index_score_jaxprs() -> dict:
+    """{case: sha256 of the jaxpr} of ``score_pages`` as GLM-5's cell
+    calls it (32 slots of 12 288 rows of 128, 32 indexer heads:
+    ``ops/mla.py:_mla_decode_paged``) and as its float32 twin would."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    S = jax.ShapeDtypeStruct
+    b, s_len, w, j = 32, 12288, 128, 32
+    out = {}
+    for case, dtype, ps in (("bf16-ps16", jnp.bfloat16, 16),
+                            ("f32-ps8", jnp.float32, 8)):
+        args = [S((b, j, w), dtype), S((b, j), jnp.float32),
+                S((b * s_len, w), dtype), S((b, s_len // ps), jnp.int32)] \
+            + [S((b,), jnp.int32)] * 3
+        jaxpr = jax.make_jaxpr(functools.partial(
+            pa.score_pages.__wrapped__, page_size=ps))(*args)
+        out[case] = hashlib.sha256(str(jaxpr).encode()).hexdigest()
+    return out
+
+
+def test_the_index_score_kernel_traces_its_recorded_jaxpr():
+    """``score_pages`` as GLM-5's decode calls it (PR 66) traces the
+    jaxpr recorded when it was measured — kernel body, grid, scratch and
+    plan: the views' digests cut every kernel's body out, and the walk
+    it shares with ``attend_pages`` is the other kernel's too."""
+    got = _index_score_jaxprs()
+    if WRITE:
+        return record("score_pages", "jaxpr", got)
+    with open(DATA) as f:
+        want = json.load(f)["digests"]["score_pages"]["jaxpr"]
+    assert got == want
 
 
 def test_the_latent_attend_kernel_traces_the_parents_jaxpr():
