@@ -31,14 +31,17 @@ tile), chosen by ``ops/kda.py:state_tier`` and counted in
   3.3 of the paper): with ``gamma`` the running sum of g inside a chunk
   and ``Gamma_ij = exp(gamma_i - gamma_j)``,
 
-      T = (I + tril(diag(beta) (Gamma * K K^T), -1))^-1 diag(beta)
-      W = T (K * exp(gamma)),  U = T V              (no state in them)
+      A = tril(diag(beta) (Gamma * K K^T), -1)
+      (I + A) [W | U] = diag(beta) [K * exp(gamma) | V]  (no state in them)
       V' = U - W S
       O = (Q * exp(gamma)) S + tril(Gamma * Q K^T) V'
       S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
   Only the last three lines are sequential, a chunk a turn; the first
-  two are made for ``BLOCK_CHUNKS`` chunks at once. The scan runs over
+  two are made for ``BLOCK_CHUNKS`` chunks at once, the unit lower
+  triangular system solved in diagonal blocks of ``SOLVE_ROWS`` rows
+  (:func:`_unit_lower_solve`: a row a turn inside the blocks, all of
+  them at once, then a block a turn). The scan runs over
   the blocks that hold a TRUE token (a padded bucket's empty blocks cost
   nothing) and rows at and past ``seq_len`` have ``beta = g = 0`` and
   ``u = 0``: they change neither the state nor the conv window. No
@@ -51,9 +54,13 @@ tile), chosen by ``ops/kda.py:state_tier`` and counted in
 Precision: the projections multiply in the storage dtype with float32
 accumulation; conv, norms, decay, beta, the gate and the whole recurrence
 are float32 — the scan's products too (matmul precision HIGHEST), and
-the triangular inverse is forward substitution in float32 on the VPU: the
-state a prefill leaves is the state the decode steps' float32 update
-would have left, to float32 rounding.
+a chunk's triangular system is solved by forward substitution alone, in
+float32: the 16 x 16 blocks on its diagonal inverted a row a turn on the
+VPU (15 turns over every block of 16 chunks at once, where the whole
+64 x 64 inverse took 63), ``[W | U]`` a block a turn by HIGHEST products
+against those inverses; neither the chunk's inverse nor a power of ``A``
+is formed. The state a prefill leaves is the state the decode steps'
+float32 update would have left, to float32 rounding.
 """
 
 from __future__ import annotations
@@ -72,12 +79,18 @@ from paddle_tpu.ops.pallas import kda_state as _ks
 
 F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
+# every product of the scan: float32 in, six passes of the MXU
+_mm = functools.partial(jnp.einsum, precision=_HIGHEST)
 _decode_phase = functools.partial(_device_scopes.phase, "gdn_decode")
 _prefill_phase = functools.partial(_device_scopes.phase, "gdn_prefill")
 # chunks whose state-free part (T, W, U, the chunk's own attention) is
 # made at once, and the granule of the scan's loop: a prompt's rows are
 # computed up to the next whole block (:func:`scan_rows`)
 BLOCK_CHUNKS = 16
+# rows of the diagonal blocks a chunk's triangular system is solved in
+# (:func:`_unit_lower_solve`): a row a turn inside them, a block a turn
+# (products) under them
+SOLVE_ROWS = 16
 
 _WEIGHTS = ("Wq", "Wk", "Wv", "Wz", "Wo", "ConvW", "ALog", "DtBias", "Wa",
             "Wb", "ONorm")
@@ -145,8 +158,15 @@ def scan_rows(length: int, bucket: int, chunk: int) -> int:
     return -(-int(length) // rows) * rows
 
 
+def solve_rows(chunk: int) -> int:
+    """Rows of a diagonal block of a chunk's triangular system:
+    ``SOLVE_ROWS``, or as many under it as divide the chunk's."""
+    return next(s for s in range(min(SOLVE_ROWS, int(chunk)), 0, -1)
+                if chunk % s == 0)
+
+
 def _unit_lower_inverse(a):
-    """(I + a)^-1 for a [..., C, C] strictly lower triangular, by forward
+    """(I + a)^-1 for a [..., S, S] strictly lower triangular, by forward
     substitution a row a turn: row_i = e_i - sum_{j<i} a_ij row_j.
     Multiply-and-reduce in float32 (the VPU): no product is rounded, and
     no power of ``a`` is formed — with beta near 2 they grow before they
@@ -163,6 +183,29 @@ def _unit_lower_inverse(a):
         1, c, row, jnp.broadcast_to(eye, a.shape).astype(F32))
 
 
+def _unit_lower_solve(a, rhs):
+    """(I + a)^-1 rhs for a [..., C, C] strictly lower triangular and rhs
+    [..., C, D], in diagonal blocks of S = ``solve_rows(C)`` rows: the
+    C / S blocks ``D_i`` on the diagonal are inverted all at once
+    (:func:`_unit_lower_inverse`: S - 1 turns whatever C) and the rest
+    is forward substitution a BLOCK a turn, by products:
+    ``X_i = D_i^-1 (R_i - sum_{j<i} A_ij X_j)``. The inverse of the whole
+    is never formed, nor a power of ``a``."""
+    c = a.shape[-1]
+    s = solve_rows(c)
+    cuts = [slice(i, i + s) for i in range(0, c, s)]
+    d_inv = _unit_lower_inverse(
+        jnp.stack([a[..., rows, rows] for rows in cuts], axis=-3))
+    xs = []
+    for i, rows in enumerate(cuts):
+        r_i = rhs[..., rows, :]
+        if i:
+            r_i = r_i - _mm("...ij,...jd->...id", a[..., rows, :i * s],
+                           jnp.concatenate(xs, axis=-2))
+        xs.append(_mm("...ij,...jd->...id", d_inv[..., i, :, :], r_i))
+    return jnp.concatenate(xs, axis=-2)
+
+
 def chunk_scan(q, k, v, g, beta, n_blocks, chunk: int, rows: int):
     """The chunked form of the gated delta rule from a zero state over
     q, k [T, H, Dk], v [T, H, Dv], g, beta [T, H] (all float32; rows that
@@ -175,7 +218,6 @@ def chunk_scan(q, k, v, g, beta, n_blocks, chunk: int, rows: int):
     c, nb = int(chunk), int(rows) // int(chunk)
     at_or_below = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
     below = jnp.arange(c)[:, None] > jnp.arange(c)[None, :]
-    mm = functools.partial(jnp.einsum, precision=_HIGHEST)
 
     def block(i, carry):
         s, out = carry                              # [H,Dk,Dv], [T,H,Dv]
@@ -192,19 +234,21 @@ def chunk_scan(q, k, v, g, beta, n_blocks, chunk: int, rows: int):
         decay = jnp.exp(jnp.where(
             at_or_below, gam[..., :, None] - gam[..., None, :], -jnp.inf))
         a = jnp.where(below, bb[..., :, None] * decay
-                      * mm("nhik,nhjk->nhij", kb, kb), 0.0)
+                      * _mm("nhik,nhjk->nhij", kb, kb), 0.0)
         e_gam = jnp.exp(gam)[..., None]
-        wu = mm("nhij,nhjd->nhid", _unit_lower_inverse(a),
-                bb[..., None] * jnp.concatenate([kb * e_gam, vb], axis=-1))
-        attn = decay * mm("nhik,nhjk->nhij", qb, kb)
+        # beta times each part, not times the joined pair: the compiled
+        # 8192-token view holds 31 MB less (tests/test_aot_tpu_compile.py)
+        wu = _unit_lower_solve(a, jnp.concatenate(
+            [bb[..., None] * kb * e_gam, bb[..., None] * vb], axis=-1))
+        attn = decay * _mm("nhik,nhjk->nhij", qb, kb)
         last = gam[..., -1:]                                  # [nb,H,1]
         k_end = kb * jnp.exp(last - gam)[..., None]
 
         def one(s, xs):
             w, u, p, q_in, k_out, a_end = xs
-            v_new = u - mm("hck,hkv->hcv", w, s)
-            o = mm("hck,hkv->hcv", q_in, s) + mm("hij,hjv->hiv", p, v_new)
-            s = a_end[..., None] * s + mm("hck,hcv->hkv", k_out, v_new)
+            v_new = u - _mm("hck,hkv->hcv", w, s)
+            o = _mm("hck,hkv->hcv", q_in, s) + _mm("hij,hjv->hiv", p, v_new)
+            s = a_end[..., None] * s + _mm("hck,hcv->hkv", k_out, v_new)
             return s, o
 
         s, o = jax.lax.scan(one, s, (wu[..., :dk], wu[..., dk:], attn,

@@ -1344,6 +1344,34 @@ def test_dense_decode_step_compiles_for_v5e(chip, olmo_engine, monkeypatch):
         jax.ShapeDtypeStruct((1, 3840), BF16)) <= pa._ATTEND_TILE_BYTES
 
 
+def test_dense_prefill_compiles_for_v5e(chip, olmo_engine, monkeypatch):
+    """The 8192-token prefill beside the weights and the pages, in the
+    cell nearest the chip's memory (``peak_hbm_gb.decode`` 14.53 of 16).
+    Arguments and temporaries were 12.644 + 1.549 GB on the parent of
+    PR 67 and are 12.644 + 1.586 since: the block turns of the blocked
+    solve bring ONE more ``[16,30,64,288]`` float32 buffer (36 MB, the
+    same whether three, seven or fourteen products make the turns) and
+    nothing else — a layout of q, k, v or o made once a layer brought
+    0.11-0.39 GB and was not kept. Each Gated DeltaNet layer solves a
+    chunk's triangular system in diagonal blocks of 16 rows
+    (``ops/gdn.py:_unit_lower_solve``): the substitution's loop carries
+    ``f32[16,30,4,16,16]``, and the only loop left that carries a
+    ``[16,30,64,64]`` array is the scan over a block's chunks (its
+    attention), one a layer."""
+    eng, programs = olmo_engine
+    compiled = _compile_view(chip, programs, "prefill_paged@8192",
+                             eng._cb_prefill[8192],
+                             eng._prefill_feeds(8192), monkeypatch)
+    mem = compiled.memory_analysis()
+    assert 12.6e9 < mem.argument_size_in_bytes < 12.7e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.25e9
+    loops = [line.split(" while(")[0]
+             for line in compiled.as_text().splitlines()
+             if re.match(r"\s*%while[.\d]* = \(.* while\(", line)]
+    assert sum("f32[16,30,4,16,16]" in carried for carried in loops) >= 12
+    assert sum("f32[16,30,64,64]" in carried for carried in loops) == 12
+
+
 # ---------------------------------------------------------------------------
 # the expert layer's two ways (PR 44): the dense way's text is the
 # parent's, the grouped way's optimised module holds no buffer of the

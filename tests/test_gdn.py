@@ -7,8 +7,9 @@ float32.
 
 ``TOL`` is ``tests/test_hybrid_lm.py``'s: both sides compute in float32,
 so what separates them is the order of the sums; the mutants of
-``test_a_mutant_fails`` (a bfloat16 inverse inside the chunk, a bfloat16
-state between chunks) move a result by a hundred times that."""
+``test_a_mutant_fails`` (a bfloat16 inverse of a chunk's diagonal
+blocks, a bfloat16 solution of its triangular system, a bfloat16 state
+between chunks) move a result by a hundred times that."""
 
 import os
 import sys
@@ -41,7 +42,9 @@ def rel(a, b):
 def tokens(t, seed=0, beta="random", decay="random", h=H, dk=DK, dv=DV):
     """q, k, v, g, beta of ``t`` tokens as ``_qkv`` and ``_token_terms``
     give them: q, k normalised, g <= 0, beta in (0, 2). ``beta="two"``:
-    every beta within 1e-3 of 2 (the eigenvalue at -1); ``decay``:
+    every beta within 1e-3 of 2 (the eigenvalue at -1), ``"zero"``: every
+    beta within 1e-3 of 0 (a token that writes next to nothing);
+    ``decay``:
     ``"none"`` g = -1e-6 (a head that never forgets), ``"fast"`` g in
     [-60, -20] (exp(g) underflows within a chunk), ``"mixed"`` a head of
     each."""
@@ -58,7 +61,8 @@ def tokens(t, seed=0, beta="random", decay="random", h=H, dk=DK, dv=DV):
                             -np.abs(r.randn(t))] * h, 1)[:, :h],
          }[decay].astype(F32)
     b = {"random": 2.0 / (1.0 + np.exp(-r.randn(t, h))),
-         "two": 2.0 - 1e-3 * r.rand(t, h)}[beta].astype(F32)
+         "two": 2.0 - 1e-3 * r.rand(t, h),
+         "zero": 1e-3 * r.rand(t, h)}[beta].astype(F32)
     return q, k, v, g, b
 
 
@@ -86,6 +90,19 @@ def chunked(q, k, v, g, beta, n, chunk, scan=gdn.chunk_scan):
     return np.asarray(o), np.asarray(s)
 
 
+def scan_matches_token_loop(bucket, chunk, n, beta, decay):
+    """The true rows' outputs and the state after them, chunked against
+    token by token, to ``TOL``; nothing NaN or inf."""
+    q, k, v, g, b = tokens(bucket, seed=n, beta=beta, decay=decay)
+    o, s = chunked(q, k, v, g, b, n, chunk)
+    want_o, want_s = token_loop(q, k, v, g, b, n)
+    assert np.isfinite(o).all() and np.isfinite(s).all()
+    scale = np.abs(np.asarray(want_o)).max()
+    assert np.abs(o[:n] - np.asarray(want_o)).max() <= TOL * scale
+    assert np.abs(s - np.asarray(want_s)).max() \
+        <= TOL * max(np.abs(np.asarray(want_s)).max(), 1.0)
+
+
 # bucket, chunk, true length: whole chunks and not, one token, a bucket
 # that is one block and several, a length that ends a block
 LENGTHS = [(32, 4, 32), (32, 4, 17), (32, 4, 1), (32, 4, 4), (64, 8, 50),
@@ -101,14 +118,56 @@ def test_the_chunked_scan_is_the_token_loop(bucket, chunk, n, beta, decay):
     with beta near 2 and decays near 0 and near 1: the outputs of the
     true rows and the state after them, to ``TOL``; nothing is NaN or
     inf however fast a head forgets."""
-    q, k, v, g, b = tokens(bucket, seed=n, beta=beta, decay=decay)
-    o, s = chunked(q, k, v, g, b, n, chunk)
-    want_o, want_s = token_loop(q, k, v, g, b, n)
-    assert np.isfinite(o).all() and np.isfinite(s).all()
-    scale = np.abs(np.asarray(want_o)).max()
-    assert np.abs(o[:n] - np.asarray(want_o)).max() <= TOL * scale
-    assert np.abs(s - np.asarray(want_s)).max() \
-        <= TOL * max(np.abs(np.asarray(want_s)).max(), 1.0)
+    scan_matches_token_loop(bucket, chunk, n, beta, decay)
+
+
+# a chunk of 64 is four diagonal blocks of 16 rows (the cell's), of 32
+# two, of 48 three, of 20 two of 10, of 6 one: true lengths that end
+# inside a diagonal block, at its edge, in a chunk's first and last one,
+# and in a bucket's second block of 16 chunks
+BLOCKED = [(128, 64, 70), (128, 64, 17), (128, 64, 16), (128, 64, 113),
+           (256, 64, 255), (2048, 64, 1030), (64, 32, 40), (96, 48, 70),
+           (40, 20, 33), (12, 6, 7)]
+
+
+@pytest.mark.parametrize("beta,decay", [
+    ("two", "none"), ("two", "mixed"), ("zero", "none"), ("zero", "fast"),
+    ("random", "mixed")])
+@pytest.mark.parametrize("bucket,chunk,n", BLOCKED)
+def test_the_blocked_solve_is_the_token_loop(bucket, chunk, n, beta, decay):
+    """A chunk's triangular system solved in diagonal blocks of
+    ``gdn.solve_rows(chunk)`` rows: with beta at both its ends (near 2
+    the powers of ``A`` would grow before they vanish: none is formed),
+    a head that never forgets beside one that forgets within a token,
+    to ``TOL``."""
+    assert chunk % gdn.solve_rows(chunk) == 0 < gdn.solve_rows(chunk) <= 16
+    scan_matches_token_loop(bucket, chunk, n, beta, decay)
+
+
+def _scan_lengths(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn.params["length"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _scan_lengths(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("chunk,sub", [(64, 16), (32, 16), (48, 16),
+                                       (20, 10), (8, 8), (4, 4)])
+def test_the_substitution_has_a_turn_a_row_of_a_diagonal_block(chunk, sub):
+    """The one loop that goes a ROW a turn runs over a diagonal block's
+    rows (``sub - 1`` turns, all of a chunk's blocks at once), not over
+    the chunk's (``chunk - 1``); the other scan is the block's chunks."""
+    assert gdn.solve_rows(chunk) == sub
+    t = 4 * chunk
+    rows = gdn.block_rows(t, chunk)
+    S = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda *a: gdn.chunk_scan(*a, chunk, rows))(
+        S((t, H, DK), F32), S((t, H, DK), F32), S((t, H, DV), F32),
+        S((t, H), F32), S((t, H), F32), S((), np.int32))
+    assert sorted(_scan_lengths(jaxpr.jaxpr, [])) \
+        == sorted([sub - 1, rows // chunk])
 
 
 @pytest.mark.parametrize("bucket,chunk,n", [(64, 4, 17), (256, 4, 130)])
@@ -154,6 +213,12 @@ def _bf16_inverse(monkeypatch):
                         lambda a: _bf16(real(a)))
 
 
+def _bf16_solve(monkeypatch):
+    real = gdn._unit_lower_solve
+    monkeypatch.setattr(gdn, "_unit_lower_solve",
+                        lambda *a: _bf16(real(*a)))
+
+
 def _bf16_state(monkeypatch):
     real = jax.lax.scan
 
@@ -165,19 +230,23 @@ def _bf16_state(monkeypatch):
     monkeypatch.setattr(gdn.jax.lax, "scan", scan)
 
 
-@pytest.mark.parametrize("mutant", [None, "bf16_inverse", "bf16_state"])
-def test_a_mutant_fails(monkeypatch, mutant):
-    """``TOL`` bites: the scan with the chunk's triangular inverse
-    rounded to bfloat16, or with the state rounded to bfloat16 between
-    chunks, is a hundred times outside it; the scan as written inside."""
-    if mutant == "bf16_inverse":
-        _bf16_inverse(monkeypatch)
-    elif mutant == "bf16_state":
-        _bf16_state(monkeypatch)
-    q, k, v, g, b = tokens(64, seed=1, decay="none")
-    o, s = chunked(q, k, v, g, b, 50, 8)
-    want_o, want_s = token_loop(q, k, v, g, b, 50)
-    err = max(rel(o[:50], want_o), rel(s, want_s))
+@pytest.mark.parametrize("bucket,chunk,n", [(64, 8, 50), (128, 64, 100)])
+@pytest.mark.parametrize("mutant", [None, "bf16_inverse", "bf16_solve",
+                                    "bf16_state"])
+def test_a_mutant_fails(monkeypatch, mutant, bucket, chunk, n):
+    """``TOL`` bites, in chunks of one diagonal block (8 rows) and of
+    four (64: the block turns' products lie between the two roundings):
+    the scan with the inverses of a chunk's diagonal blocks rounded to
+    bfloat16, with the solution of its triangular system rounded so, or
+    with the state rounded to bfloat16 between chunks, is a hundred
+    times outside it; the scan as written inside."""
+    if mutant:
+        {"bf16_inverse": _bf16_inverse, "bf16_solve": _bf16_solve,
+         "bf16_state": _bf16_state}[mutant](monkeypatch)
+    q, k, v, g, b = tokens(bucket, seed=1, decay="none")
+    o, s = chunked(q, k, v, g, b, n, chunk)
+    want_o, want_s = token_loop(q, k, v, g, b, n)
+    err = max(rel(o[:n], want_o), rel(s, want_s))
     if mutant:
         assert err > 100 * TOL, err
     else:

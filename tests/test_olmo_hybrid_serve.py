@@ -277,8 +277,10 @@ def _loops(jaxpr, found):
 def test_the_prefill_of_a_linear_layer_has_no_loop_over_tokens():
     """``gdn_prefill`` at the cell's sizes (abstractly): ONE loop of
     unknown length, over the blocks a prompt fills; inside it a scan over
-    the block's 16 chunks and the 63 turns of the substitution. No loop
-    runs a token a turn (``kda_prefill``'s does, by design: S9)."""
+    the block's 16 chunks and the 15 turns of the substitution inside a
+    chunk's four diagonal blocks of 16 rows (63 over the whole chunk
+    until PR 67). No loop runs a token a turn (``kda_prefill``'s does, by
+    design: S9)."""
     t, m, h, dk, dv = 8192, 64, 30, 96, 192
     wide = 2 * h * dk + h * dv
 
@@ -297,7 +299,7 @@ def test_the_prefill_of_a_linear_layer_has_no_loop_over_tokens():
         types.SimpleNamespace(mesh=None), i, attrs))(ins)
     loops = _loops(jaxpr.jaxpr, [])
     assert sorted(loops, key=str) == sorted(
-        [("while", None), ("scan", 16), ("scan", 63)], key=str), loops
+        [("while", None), ("scan", 16), ("scan", 15)], key=str), loops
     # Solar's keeps its loop over the prompt: one while, a token a turn
     kda_ins = {"X": z(1, 64, m), "Wq": z(m, 256), "Wk": z(m, 256),
                "Wv": z(m, 256), "Wo": z(256, m), "ConvW": z(4, 768),

@@ -31,11 +31,13 @@ so their views stay the parent's and say nothing of the new path):
   PR 66 the digests are written anew on each PR's PARENT (PR 62's
   in-place decode views and PR 64's grouped prefill views are in them),
   so all are the same but the views ``DIFFERS`` lists — what this PR
-  changed on purpose: GLM-5's decode view, whose five latent layers
-  score their index planes in place, with
-  ``paddle_dsa_index_lowered_total`` read beside it. The older paths'
-  counters (``paddle_kv_decode_attend_lowered_total``,
-  ``paddle_expert_grouped_lowered_total``) are still read beside every
+  changed on purpose (PR 67: Olmo-Hybrid's two prefill views, whose
+  twelve Gated DeltaNet layers solve a chunk's triangular system in
+  blocks of 16 rows; PR 66's GLM-5 decode view, whose five latent
+  layers score their index planes in place, is the parent's text now).
+  The paths' counters (``paddle_dsa_index_lowered_total``,
+  ``paddle_kv_decode_attend_lowered_total``,
+  ``paddle_expert_grouped_lowered_total``) are read beside every
   configuration: they hold what the parent's text holds;
 - the ONE op ``kv_attention_decode_paged`` lowered alone at each of the
   seven served configurations' PUBLISHED full-layer geometries for the
@@ -207,9 +209,11 @@ GROUPED_KERNEL_PREFILLS = {
 # must not: what THIS PR changed on purpose. The committed digests are
 # the parent's own (written anew on it: PR 62's in-place decode views
 # and PR 64's grouped prefill views are in them), so the list is one
-# PR's — PR 66: GLM-5's decode step scores its five latent layers' index
-# planes in place (``ops/mla.py:scores_in_place``)
-DIFFERS = {"glm5_744b_ep16_d5": ["decode_paged"]}
+# PR's — PR 67: Olmo-Hybrid's prefills solve a chunk's triangular system
+# in diagonal blocks of 16 rows (``ops/gdn.py:_unit_lower_solve``; no
+# counter: there is no second path)
+DIFFERS = {"olmo_hybrid_7b_pp2_d16": ["prefill_paged@4096",
+                                      "prefill_paged@8192"]}
 # configuration -> its latent layers, whose indexer scores in place
 INDEX_IN_PLACE_LAYERS = {"glm5_744b_ep16_d5": 5}
 
@@ -227,11 +231,11 @@ def _grew(counter, paths):
 def test_the_committed_size_lowers_the_parents_text_but_in_place(
         name, sharding):
     """The cell's own configuration file, every view: the parent's text
-    letter for letter, but the views ``DIFFERS`` lists — there the path
-    counter of what changed says so for every layer it changed (the
-    indexer's, ``pages`` five times a trace of GLM-5's decode view), and
-    nowhere else does it move. The older paths' counters hold what the
-    parent's text holds: a full layer's decode attends ``in_place`` or
+    letter for letter, but the views ``DIFFERS`` lists (PR 67's have
+    no path counter: every ``gdn_prefill`` takes the one solve). The
+    paths' counters hold what the parent's text holds: the indexer's
+    reads ``pages`` five times a trace of GLM-5's decode view and moves
+    nowhere else, a full layer's decode attends ``in_place`` or
     by ``copy`` for every one of a configuration, and the long prefill
     views' grouped products are the ``kernel``'s (PR 64), never
     ``ragged_dot``'s."""
